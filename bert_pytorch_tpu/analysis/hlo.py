@@ -15,6 +15,9 @@ structured report the rule framework (analysis/passes.py) and the CI gate
   group sizes, and an estimated bytes-moved figure per kind;
 - copy/transpose/fusion/dot op counts (the layout-regression smells the
   round-6 kernel work was chasing);
+- kernel inventory: the Pallas kernels in the program (`tpu_custom_call`
+  instructions) counted by the name their `pallas_call` gave them — how a
+  run proves it took the flash / LayerNorm kernels and not the XLA path;
 - the input→output buffer-donation table: which donated parameters XLA
   actually aliased (`input_output_alias`) vs accepted-but-never-aliased
   (`buffer_donor` — the double-HBM miss `donate_argnums` silently allows);
@@ -56,12 +59,12 @@ _DTYPE_BYTES = {
 }
 
 # one HLO instruction: `%name = <result-shape> opcode(operands...)`.
-# The result shape is either a tuple `(f32[..]{..}, ...)` (no nested
-# parens in HLO shape syntax — layouts use braces) or a single
-# `dtype[dims]{layout}`.
-_INSTR_RE = re.compile(
-    r"=\s*(?:\([^)]*\)|[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)\s+"
-    r"(?P<op>[a-z][a-z0-9-]*)\(")
+# The result shape is a tuple `(f32[..]{..}, ...)` or a single
+# `dtype[dims]{layout}`. A TPU layout carries parentheses of its own
+# (`{1,0:T(8,128)(2,1)S(1)}`: tiling, memory space) but never a lowercase
+# word followed by `(` after whitespace — so the opcode is the first such
+# word after the `=`.
+_INSTR_RE = re.compile(r"=\s.*?\s(?P<op>[a-z][a-z0-9-]*)\(")
 
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 
@@ -69,6 +72,12 @@ _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 # explicit `replica_groups={{0,1},{2,3}}` form
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{(\{[^}]*\})")
+
+# a Mosaic (Pallas TPU) kernel, and the jax op path in its metadata: a
+# pallas_call(name=...) shows as `.../<name>/pallas_call`
+_KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_INNERMOST_RE = re.compile(r"\(([^()]*)\)+$")
 
 _ALIAS_ENTRY_RE = re.compile(
     r"\{[0-9,\s]*\}:\s*\(\s*(\d+)\s*,\s*\{[0-9,\s]*\}\s*(?:,\s*[\w-]+\s*)?\)")
@@ -86,6 +95,10 @@ def _result_shapes(line: str, async_start: bool = False) -> list:
            else line.split("=", 1)[1])
     shapes = _SHAPE_RE.findall(lhs)
     if async_start and len(shapes) > 1:
+        # the TPU compiler appends u32[] context scalars after the output
+        # (collective-permute-start: (operand, output, u32[], u32[]))
+        while len(shapes) > 2 and shapes[-1] == ("u32", ""):
+            shapes.pop()
         shapes = shapes[-1:]
     return shapes
 
@@ -162,6 +175,7 @@ def parse_hlo_module(text: str) -> Dict[str, Any]:
     coll_bytes: Dict[str, int] = {k: 0 for k in COLLECTIVE_KINDS}
     est_moved: Dict[str, int] = {k: 0 for k in COLLECTIVE_KINDS}
     shapes: Dict[str, int] = {}
+    kernels: Dict[str, int] = {}
     num_partitions = None
     header = ""
     for line in text.splitlines():
@@ -175,6 +189,10 @@ def parse_hlo_module(text: str) -> Dict[str, Any]:
         if m is None:
             continue
         op = m.group("op")
+        if op == "custom-call" and _KERNEL_TARGET in line:
+            name = _kernel_name(line)
+            kernels[name] = kernels.get(name, 0) + 1
+            continue
         if op.endswith("-done"):
             continue
         base = op[:-6] if op.endswith("-start") else op
@@ -213,8 +231,31 @@ def parse_hlo_module(text: str) -> Dict[str, Any]:
         "collective_est_bytes_moved": est_moved,
         "collective_shapes": dict(sorted(shapes.items())),
         "op_counts": op_counts,
+        "kernel_counts": dict(sorted(kernels.items())),
         "donation": donation,
     }
+
+
+def _kernel_name(line: str) -> str:
+    """Name of the Pallas kernel behind one tpu_custom_call instruction:
+    the op-path component before `pallas_call`, or "unnamed"."""
+    m = _OP_NAME_RE.search(line)
+    parts = m.group(1).split("/") if m else []
+    if "pallas_call" in parts[1:]:
+        # autodiff wraps the name: `transpose(jvp(flash_bwd_dqkv))`; an
+        # unnamed call leaves an empty innermost pair: `jvp()`
+        scope = parts[parts.index("pallas_call", 1) - 1]
+        inner = _INNERMOST_RE.search(scope)
+        name = inner.group(1) if inner else scope
+        if name and not scope.startswith("jit("):
+            return name
+    return "unnamed"
+
+
+def kernel_counts(text: str) -> Dict[str, int]:
+    """{kernel name: instructions} of the Pallas TPU kernels in an HLO
+    text (empty on backends that run no Mosaic kernel)."""
+    return parse_hlo_module(text)["kernel_counts"]
 
 
 def collective_counts(text: str) -> Dict[str, int]:
@@ -439,7 +480,7 @@ def fingerprint_of(report: Dict[str, Any]) -> Dict[str, Any]:
             "donated_unaliased": donation.get("donated_unaliased", [])}
     counts = {k: v for k, v in
               report.get("collective_counts", {}).items() if v}
-    return {
+    fp = {
         "collective_counts": counts,
         "n_aliased": donation.get("n_aliased", 0),
         "n_donated_unaliased": donation.get("n_donated_unaliased", 0),
@@ -447,6 +488,11 @@ def fingerprint_of(report: Dict[str, Any]) -> Dict[str, Any]:
         "num_partitions": report.get("num_partitions"),
         "hash": _short_hash({"collectives": counts, "donation": dsum}),
     }
+    if report.get("kernel_counts"):
+        # informational (not part of the hash): which Pallas kernels the
+        # program runs. Absent where it runs none, e.g. every CPU program
+        fp["kernel_counts"] = report["kernel_counts"]
+    return fp
 
 
 def program_fingerprint(compiled: Any) -> Dict[str, Any]:

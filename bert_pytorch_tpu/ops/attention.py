@@ -56,20 +56,14 @@ def active_mesh():
     sharded operands forces a replicate-then-repartition ("involuntary full
     rematerialization"). Under a nontrivial mesh the kernels must therefore
     go through shard_map so each device runs on its local shard."""
-    # set by jax.sharding.use_mesh; trace-safe, unlike get_mesh(). Absent
-    # on older jax (< 0.4.38) — fall through to the legacy context probe.
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    m = get_am() if get_am is not None else None
-    if m is None or m.empty:
-        # legacy `with mesh:` context; jax._src.mesh is where the deprecated
-        # jax.interpreters.pxla.thread_resources alias actually lives
-        try:
-            from jax._src.mesh import thread_resources
+    # set by jax.sharding.use_mesh; trace-safe, unlike get_mesh()
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty:
+        # legacy `with mesh:` context, which the entry points still use
+        from jax._src.mesh import thread_resources
 
-            m = thread_resources.env.physical_mesh
-        except ImportError:  # pragma: no cover - future jax refactors
-            return None
-    if m is None or m.empty or m.size == 1:
+        m = thread_resources.env.physical_mesh
+    if m.empty or m.size == 1:
         return None
     return m
 
@@ -98,17 +92,31 @@ def flat_batch_head_shard(sizes) -> jax.Array:
             + jax.lax.axis_index("model"))
 
 
+def _require_flash(q, k, interpret: bool) -> None:
+    """Raise unless the flash kernel can serve this call."""
+    if jax.default_backend() != "tpu" and not interpret:
+        raise ValueError(
+            f"attention impl='pallas' needs a TPU backend (this is "
+            f"{jax.default_backend()!r}); set BPT_PALLAS_INTERPRET=1 to run "
+            "the kernel in interpret mode, or use impl='auto'/'xla'")
+    if q.shape[1] % 128 or q.shape != k.shape:
+        raise ValueError(
+            "attention impl='pallas' needs self-attention shapes with seq "
+            f"a multiple of 128, got q{tuple(q.shape)} k{tuple(k.shape)}; "
+            "use impl='auto'/'xla'")
+
+
 def _flash_sharded(mesh, q, k, v, bias, segment_ids, seed, rate: float,
                    interpret: bool):
     """flash_attention under shard_map: batch over (data, fsdp), heads over
     model; seq/head_dim local. Returns None when the mesh layout rules out
-    the kernel (caller falls back to XLA attention).
+    the kernel (under impl="auto" the caller then takes XLA attention).
 
     Dropout: the positional hash seed is decorrelated per shard by folding
     in the flat shard index — without this every batch/head shard would
     reuse identical keep-masks. segment_ids (packing) shard like the bias:
     batch over (data, fsdp), sequence local."""
-    from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, h, d = q.shape
@@ -151,7 +159,7 @@ def _flash_sharded(mesh, q, k, v, bias, segment_ids, seed, rate: float,
                                interpret=interpret)
 
     return shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=spec_qkv, check_rep=False)(*args)
+                     out_specs=spec_qkv, check_vma=False)(*args)
 
 # Additive mask bias. The reference used -10000.0 (src/modeling.py:851); that
 # value is representable in bf16 and large enough at fp32 softmax precision.
@@ -220,6 +228,7 @@ def dot_product_attention(
     bias gradient is exact.
     """
     seq = q.shape[1]
+    requested = impl
     if impl == "auto":
         impl = "pallas" if seq > 256 else "xla"
     interpret = jax.default_backend() != "tpu" and _pallas_interpret()
@@ -243,28 +252,38 @@ def dot_product_attention(
             # no seq-sharded mesh (single chip / tests): dense math is exact
             return _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                                   dropout_rate, deterministic)
-    if (impl == "pallas" and not trainable_bias
-            and (jax.default_backend() == "tpu" or interpret)
-            and seq % 128 == 0 and q.shape == k.shape):
-        from bert_pytorch_tpu.ops.pallas.flash_attention import flash_attention
+    if impl == "pallas" and not trainable_bias:
+        if requested == "pallas":
+            # asked for by name: the caller gets the kernel or an error,
+            # never a silent XLA result ("auto" chooses, and may choose XLA)
+            _require_flash(q, k, interpret)
+        if ((jax.default_backend() == "tpu" or interpret)
+                and seq % 128 == 0 and q.shape == k.shape):
+            from bert_pytorch_tpu.ops.pallas.flash_attention import (
+                flash_attention)
 
-        rate = 0.0 if deterministic else dropout_rate
-        seed = None
-        if rate > 0.0:
-            # fold the dropout key into a 32-bit positional-hash seed
-            seed = jax.random.randint(dropout_rng, (), 0, 2 ** 31 - 1,
-                                      dtype=jnp.int32)
-        mesh = active_mesh()
-        if mesh is not None:
+            rate = 0.0 if deterministic else dropout_rate
+            seed = None
+            if rate > 0.0:
+                # fold the dropout key into a 32-bit positional-hash seed
+                seed = jax.random.randint(dropout_rng, (), 0, 2 ** 31 - 1,
+                                          dtype=jnp.int32)
+            mesh = active_mesh()
+            if mesh is None:
+                return flash_attention(q, k, v, bias=bias,
+                                       segment_ids=segment_ids,
+                                       dropout_seed=seed, dropout_rate=rate,
+                                       interpret=interpret)
             out = _flash_sharded(mesh, q, k, v, bias, segment_ids, seed,
                                  rate, interpret)
             if out is not None:
                 return out
-        else:
-            return flash_attention(q, k, v, bias=bias,
-                                   segment_ids=segment_ids,
-                                   dropout_seed=seed, dropout_rate=rate,
-                                   interpret=interpret)
+            if requested == "pallas":
+                raise ValueError(
+                    f"attention impl='pallas': mesh {dict(mesh.shape)} cannot "
+                    f"shard the kernel for q{tuple(q.shape)} (batch over "
+                    "data*fsdp, heads over model, seq local); use "
+                    "impl='auto'/'xla'")
 
     if impl == "xla_checkpoint":
         ckpt = jax.checkpoint(

@@ -35,33 +35,27 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                eps: float = 1e-12, fused: bool = False) -> jax.Array:
     """LayerNorm over the last axis. eps default matches the reference (1e-12).
 
-    fused=True routes to the Pallas TPU kernel when the backend supports it;
-    any non-TPU backend silently falls back to the XLA path so tests run on
-    CPU unchanged.
+    fused=True routes to the Pallas kernel on a TPU backend (or, with
+    BPT_PALLAS_INTERPRET=1, its interpret mode elsewhere); other backends
+    take the XLA path, which computes the same thing.
     """
     if fused and x.shape[-1] % 128 == 0:
-        try:
-            from bert_pytorch_tpu.ops.pallas.layernorm import layer_norm_pallas
+        from bert_pytorch_tpu.ops.attention import (_pallas_interpret,
+                                                    active_mesh)
+        from bert_pytorch_tpu.ops.pallas.layernorm import layer_norm_pallas
 
-            from bert_pytorch_tpu.ops.attention import _pallas_interpret
-
-            on_tpu = jax.default_backend() == "tpu"
-            # BPT_PALLAS_INTERPRET=1: run the real kernel in interpret mode
-            # on CPU so the multi-chip dryrun covers the production path
-            interpret = not on_tpu and _pallas_interpret()
-            if on_tpu or interpret:
-                from bert_pytorch_tpu.ops.attention import active_mesh
-
-                mesh = active_mesh()
-                if mesh is None:
-                    return layer_norm_pallas(x, scale, bias, eps=eps,
-                                             interpret=interpret)
-                out = _layer_norm_sharded(mesh, x, scale, bias, eps,
-                                          interpret)
-                if out is not None:
-                    return out
-        except ImportError:
-            pass
+        on_tpu = jax.default_backend() == "tpu"
+        # BPT_PALLAS_INTERPRET=1: run the real kernel in interpret mode
+        # on CPU so the multi-chip dryrun covers the production path
+        interpret = not on_tpu and _pallas_interpret()
+        if on_tpu or interpret:
+            mesh = active_mesh()
+            if mesh is None:
+                return layer_norm_pallas(x, scale, bias, eps=eps,
+                                         interpret=interpret)
+            out = _layer_norm_sharded(mesh, x, scale, bias, eps, interpret)
+            if out is not None:
+                return out
     return _layer_norm_xla(x, scale, bias, eps)
 
 
@@ -124,27 +118,22 @@ def add_dropout_layer_norm(x, residual, scale, bias, seed, rate: float,
     seed: int32 scalar, fresh per call (derive from the step rng).
     """
     if fused and x.shape[-1] % 128 == 0:
-        try:
-            from bert_pytorch_tpu.ops.pallas.layernorm import (
-                add_dropout_layer_norm_pallas)
+        from bert_pytorch_tpu.ops.attention import (_pallas_interpret,
+                                                    active_mesh)
+        from bert_pytorch_tpu.ops.pallas.layernorm import (
+            add_dropout_layer_norm_pallas)
 
-            from bert_pytorch_tpu.ops.attention import _pallas_interpret
-
-            on_tpu = jax.default_backend() == "tpu"
-            interpret = not on_tpu and _pallas_interpret()
-            if on_tpu or interpret:
-                from bert_pytorch_tpu.ops.attention import active_mesh
-
-                mesh = active_mesh()
-                if mesh is None:
-                    return add_dropout_layer_norm_pallas(
-                        x, residual, scale, bias, seed, rate, eps, interpret)
-                out = _adln_sharded(mesh, x, residual, scale, bias, seed,
-                                    rate, eps, interpret)
-                if out is not None:
-                    return out
-        except ImportError:
-            pass
+        on_tpu = jax.default_backend() == "tpu"
+        interpret = not on_tpu and _pallas_interpret()
+        if on_tpu or interpret:
+            mesh = active_mesh()
+            if mesh is None:
+                return add_dropout_layer_norm_pallas(
+                    x, residual, scale, bias, seed, rate, eps, interpret)
+            out = _adln_sharded(mesh, x, residual, scale, bias, seed, rate,
+                                eps, interpret)
+            if out is not None:
+                return out
     return _add_dropout_layer_norm_xla(x, residual, scale, bias, seed, rate,
                                        eps)
 
@@ -155,7 +144,7 @@ def _adln_sharded(mesh, x, residual, scale, bias, seed, rate, eps,
     _layer_norm_sharded). Each shard folds its (data, seq) coordinates into
     the seed so shards draw decorrelated masks — without this, every batch
     shard would reuse the same (local-row, col) mask pattern."""
-    from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from bert_pytorch_tpu.ops.pallas.layernorm import (
@@ -182,7 +171,7 @@ def _adln_sharded(mesh, x, residual, scale, bias, seed, rate, eps,
     return shard_map(
         local, mesh=mesh,
         in_specs=(spec_x, spec_x, P(None), P(None), P()),  # seed: rank-0
-        out_specs=spec_x, check_rep=False)(
+        out_specs=spec_x, check_vma=False)(
             x, residual, scale, bias, jnp.asarray(seed, jnp.int32))
 
 
@@ -191,7 +180,7 @@ def _layer_norm_sharded(mesh, x, scale, bias, eps, interpret):
     seq over seq, E local). None -> caller falls back to XLA. Same rationale
     as ops/attention._flash_sharded: an SPMD-partitioned pallas_call would
     otherwise replicate its operands."""
-    from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from bert_pytorch_tpu.ops.pallas.layernorm import layer_norm_pallas
@@ -207,4 +196,4 @@ def _layer_norm_sharded(mesh, x, scale, bias, eps, interpret):
     return shard_map(
         lambda lx, ls, lb: layer_norm_pallas(lx, ls, lb, eps, interpret),
         mesh=mesh, in_specs=(spec_x, P(None), P(None)), out_specs=spec_x,
-        check_rep=False)(x, scale, bias)
+        check_vma=False)(x, scale, bias)

@@ -16,26 +16,24 @@ PRNG primitives don't).
 Layout contract: q/k/v are (B, S, H, D); bias broadcastable (B, 1, 1, S)
 additive mask. S must divide by the q/k block size (ops/attention.py gates).
 
-Two kernel-grid layouts exist behind the same public function:
+Two kernel-grid layouts exist behind the same public function, chosen by
+shape alone (`_use_native`) and served by the SAME forward / fused-backward
+kernels, which address a (rows, S, lanes) array on a (rows, head-groups, ...)
+grid:
 
-- **native** (default where it fits): the kernels consume the model's
-  (B, S, H, D) arrays directly — grid (B, S/BLK_Q) forward / (B,) fused
-  backward, blocks span the FULL (H, D) trailing dims (Mosaic's tiling rule
-  rejects head-singleton (1, D<128) blocks, so the head axis is folded into
-  an in-kernel loop instead of the grid), and each program iterates heads
-  internally on (S, D) slices. No (B,S,H,D)->(BH,S,D) transpose pass on
-  q/k/v/do/outputs — the 4.9% layout-copy bucket in the seq512 step-time
-  budget (docs/PERF.md) disappears. Per-program VMEM grows by H, so the
-  path is gated on S*H*D (FLASH_NATIVE_VMEM budget, default 12 MiB for the
-  ~9 resident (S, H, D) bf16 tensors of the fused backward); BERT-Large
-  seq512 (S=512, H=16, D=64 -> 1 MiB/tensor) fits comfortably.
-- **bh** (fallback, and FLASH_LAYOUT=bh forces it): the original
-  (BH, S, D) grid with a transpose pass either side — unbounded S via the
-  split backward kernels.
+- **native** (wherever heads tile into 128-lane blocks and the fused
+  backward fits them — every BERT-Base/Large shape up to S=1024): the kernels
+  consume the (B, S, H*D) row-major view of the model's (B, S, H, D) arrays,
+  a reshape that moves no bytes. Mosaic's tiling rule wants a block's lane
+  dim to be a multiple of 128, so a program owns the 128 // D heads that
+  share one lane block (two at D=64) and works on their static D-lane
+  slices. No (B,S,H,D)->(BH,S,D) transpose pass on q/k/v/do/outputs.
+- **bh** (other head shapes, and S*D beyond the fused backward's VMEM
+  bound, where the split backward kernels take over): the (BH, S, D) view
+  with a transpose pass either side — one head per program, unbounded S.
 
-Both layouts draw identical dropout masks (the (batch*heads + head) counter
-the native head-loop folds in equals the bh grid's program id), so they are
-the same training run.
+Both layouts draw identical dropout masks (the kernels' counter is
+batch * H + head in either addressing), so they are the same training run.
 
 **Packed sequences** (`segment_ids`, the round-9 unpadded-pretraining path):
 a (B, S) int32 array assigning each position a packing segment (1..n per
@@ -61,6 +59,8 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -88,8 +88,8 @@ _SEG_BIG = 2 ** 30  # sentinel above any real segment index
 
 def _seg_skip_enabled() -> bool:
     """FLASH_SEG_SKIP=0 disables block-level tile skipping (the masked
-    tiles are computed and contribute exact zeros instead). A/B hatch in
-    the style of FLASH_LAYOUT/FLASH_BWD."""
+    tiles are computed and contribute exact zeros instead). A/B hatch
+    for the skipped-vs-masked bit-identity tests."""
     return os.environ.get("FLASH_SEG_SKIP", "1") != "0"
 
 
@@ -164,71 +164,86 @@ def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 o_ref, lse_ref, *, scale: float, blk_k: int, rate: float,
-                has_bias: bool, has_segments: bool):
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
+                has_bias: bool, has_segments: bool, heads_per_prog: int,
+                heads_per_row: int):
+    """One program per (row, head group, q-block) of a (rows, S, lanes)
+    array; it loops the `heads_per_prog` heads that share its lane block
+    (static lane slices of width D), then the k-blocks. Serves both layouts
+    (_Layout): `heads_per_row` is H for the native layout (a row is a batch
+    element) and 1 for bh (a row is already one (batch, head)), so the
+    dropout counter `row * heads_per_row + head` is the same
+    batch * H + head in both."""
+    row = pl.program_id(0)
+    group = pl.program_id(1)
+    qi = pl.program_id(2)
     bq = q_ref.shape[1]
-    d = q_ref.shape[2]
+    d = q_ref.shape[2] // heads_per_prog
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
-
-    # matmul inputs stay in the stored dtype (bf16): the MXU multiplies
-    # bf16 x bf16 into an fp32 accumulator at full rate, while fp32 inputs
-    # run at a fraction of it. Softmax statistics and accumulators are fp32
-    # — identical numerics to the XLA attention path (probs cast to the
-    # compute dtype before the PV matmul).
-    q = q_ref[0]
     segq = segq_ref[0, 0] if has_segments else None
-    carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
-             jnp.zeros((bq, 1), jnp.float32),
-             jnp.zeros((bq, d), jnp.float32))
 
-    for j in range(nk):
-        segk = (segk_ref[0, 0, j * blk_k:(j + 1) * blk_k]
-                if has_segments else None)
+    for t in range(heads_per_prog):
+        lanes = slice(t * d, (t + 1) * d)
+        bh = row * heads_per_row + group * heads_per_prog + t
+        # matmul inputs stay in the stored dtype (bf16): the MXU multiplies
+        # bf16 x bf16 into an fp32 accumulator at full rate, while fp32
+        # inputs run at a fraction of it. Softmax statistics and
+        # accumulators are fp32 — identical numerics to the XLA attention
+        # path (probs cast to the compute dtype before the PV matmul).
+        q = q_ref[0, :, lanes]
+        carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((bq, 1), jnp.float32),
+                 jnp.zeros((bq, d), jnp.float32))
 
-        def tile(carry, j=j, segk=segk):
-            m, l, acc = carry
-            kb = k_ref[0, j * blk_k:(j + 1) * blk_k, :]
-            vb = v_ref[0, j * blk_k:(j + 1) * blk_k, :]
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if has_bias:
-                s = s + bias_ref[0, 0, j * blk_k:(j + 1) * blk_k][None, :]
-            if has_segments:
-                s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            if rate > 0.0:
-                keep = _keep_mask(seed_ref[0], bh, qi * bq, j * blk_k, bq,
-                                  blk_k, rate)
-                p_acc = jnp.where(keep, p, 0.0)
-            else:
-                p_acc = p
-            acc = acc * alpha + jnp.dot(p_acc.astype(vb.dtype), vb,
-                                        preferred_element_type=jnp.float32)
-            return m_new, l, acc
+        for j in range(nk):
+            segk = (segk_ref[0, 0, j * blk_k:(j + 1) * blk_k]
+                    if has_segments else None)
 
-        carry = _maybe_skip(has_segments, segq, segk, tile, carry)
+            def tile(carry, lanes=lanes, bh=bh, j=j, q=q, segk=segk):
+                m, l, acc = carry
+                kb = k_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
+                vb = v_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
+                s = jax.lax.dot_general(
+                    q, kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if has_bias:
+                    s = s + bias_ref[0, 0,
+                                     j * blk_k:(j + 1) * blk_k][None, :]
+                if has_segments:
+                    s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                if rate > 0.0:
+                    keep = _keep_mask(seed_ref[0], bh, qi * bq, j * blk_k,
+                                      bq, blk_k, rate)
+                    p_acc = jnp.where(keep, p, 0.0)
+                else:
+                    p_acc = p
+                acc = acc * alpha + jnp.dot(
+                    p_acc.astype(vb.dtype), vb,
+                    preferred_element_type=jnp.float32)
+                return m_new, l, acc
 
-    m, l, acc = carry
-    l_safe = jnp.maximum(l, 1e-30)
-    out = acc / l_safe
-    if rate > 0.0:
-        out = out / (1.0 - rate)
-    if has_segments:
-        # pad (segment-0) rows attend nowhere; without this their softmax
-        # degenerates to skip-/tile-layout-dependent garbage (uniform over
-        # whatever tiles ran). Zeroing makes every path — skip on/off, both
-        # layouts, XLA fallback — emit identical pad activations, which
-        # keeps downstream consumers of full (B, S, E) hiddens (K-FAC
-        # factor taps) bit-independent of the kernel configuration.
-        out = jnp.where(segq[:, None] > 0, out, 0.0)
-    o_ref[0] = out.astype(o_ref.dtype)
-    lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
+            carry = _maybe_skip(has_segments, segq, segk, tile, carry)
+
+        m, l, acc = carry
+        l_safe = jnp.maximum(l, 1e-30)
+        out = acc / l_safe
+        if rate > 0.0:
+            out = out / (1.0 - rate)
+        if has_segments:
+            # pad (segment-0) rows attend nowhere; without this their
+            # softmax degenerates to skip-/tile-layout-dependent garbage
+            # (uniform over whatever tiles ran). Zeroing makes every path —
+            # skip on/off, both layouts, XLA fallback — emit identical pad
+            # activations, which keeps downstream consumers of full
+            # (B, S, E) hiddens (K-FAC factor taps) bit-independent of the
+            # kernel configuration.
+            out = jnp.where(segq[:, None] > 0, out, 0.0)
+        o_ref[0, :, lanes] = out.astype(o_ref.dtype)
+        lse_ref[0, 0, t, :] = (m + jnp.log(l_safe))[:, 0]
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
@@ -343,192 +358,47 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
                  delta_ref, do_ref, dq_ref, dk_ref, dv_ref, *, scale: float,
                  blk_q: int, blk_k: int, rate: float, has_bias: bool,
-                 has_segments: bool):
-    """Fused backward: one program per (batch*head) computes dq, dk and dv
-    together, so the score tiles, softmax exp and dropout keep-masks are
-    evaluated ONCE instead of once in _dq_kernel and again in _dkv_kernel.
-    All accumulators live in VMEM — (S, D) fp32 x3 — which bounds this path
-    to moderate S (the wrapper gates on S <= 2048; 3 x 2048 x 64 x 4B =
-    1.5 MB); longer sequences fall back to the split kernels."""
-    bh = pl.program_id(0)
+                 has_segments: bool, heads_per_prog: int, heads_per_row: int):
+    """Fused backward: one program per (row, head group) computes dq, dk
+    and dv together for each of its heads, so the score tiles, softmax exp
+    and dropout keep-masks are evaluated ONCE instead of once in _dq_kernel
+    and again in _dkv_kernel. Same (rows, S, lanes) addressing as
+    _fwd_kernel. The per-head accumulators live in VMEM — (S, D) fp32 x3 —
+    which bounds this path to moderate S (_FUSED_BWD_MAX_PANEL); longer
+    sequences take the split kernels."""
+    row = pl.program_id(0)
+    group = pl.program_id(1)
     s_len = q_ref.shape[1]
-    d = q_ref.shape[2]
+    d = q_ref.shape[2] // heads_per_prog
     nq = s_len // blk_q
     nk = s_len // blk_k
 
-    # per-k-block accumulators as plain Python lists — a (S, D) functional
-    # scatter would lower to ops pallas rejects; disjoint static blocks
-    # written once at the end need no scatter at all
-    dk_blocks = [jnp.zeros((blk_k, d), jnp.float32) for _ in range(nk)]
-    dv_blocks = [jnp.zeros((blk_k, d), jnp.float32) for _ in range(nk)]
-
-    for i in range(nq):
-        qb = q_ref[0, i * blk_q:(i + 1) * blk_q, :]
-        dob = do_ref[0, i * blk_q:(i + 1) * blk_q, :]
-        segq = (seg_ref[0, 0, i * blk_q:(i + 1) * blk_q]
-                if has_segments else None)
-        lse = lse_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
-        delta = delta_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
-        dq_i = jnp.zeros((blk_q, d), jnp.float32)
-        for j in range(nk):
-            segk = (seg_ref[0, 0, j * blk_k:(j + 1) * blk_k]
-                    if has_segments else None)
-
-            def tile(carry, i=i, j=j, qb=qb, dob=dob, segq=segq, segk=segk,
-                     lse=lse, delta=delta):
-                dq_i, dk_j, dv_j = carry
-                kb = k_ref[0, j * blk_k:(j + 1) * blk_k, :]
-                vb = v_ref[0, j * blk_k:(j + 1) * blk_k, :]
-                s = jax.lax.dot_general(
-                    qb, kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if has_bias:
-                    s = s + bias_ref[0, 0, j * blk_k:(j + 1) * blk_k][None, :]
-                if has_segments:
-                    s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
-                p = jnp.exp(s - lse)
-                dp = jax.lax.dot_general(
-                    dob, vb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                if rate > 0.0:
-                    keep = _keep_mask(seed_ref[0], bh, i * blk_q, j * blk_k,
-                                      blk_q, blk_k, rate)
-                    p_drop = jnp.where(keep, p / (1.0 - rate), 0.0)
-                    dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
-                else:
-                    p_drop = p
-                ds = (p * (dp - delta)).astype(qb.dtype)
-                dq_i = dq_i + jnp.dot(
-                    ds, kb, preferred_element_type=jnp.float32) * scale
-                dk_j = dk_j + jax.lax.dot_general(
-                    ds, qb, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                dv_j = dv_j + jax.lax.dot_general(
-                    p_drop.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                return dq_i, dk_j, dv_j
-
-            dq_i, dk_blocks[j], dv_blocks[j] = _maybe_skip(
-                has_segments, segq, segk, tile,
-                (dq_i, dk_blocks[j], dv_blocks[j]))
-        dq_ref[0, i * blk_q:(i + 1) * blk_q, :] = dq_i.astype(dq_ref.dtype)
-
-    for j in range(nk):
-        sl = slice(j * blk_k, (j + 1) * blk_k)
-        dk_ref[0, sl, :] = dk_blocks[j].astype(dk_ref.dtype)
-        dv_ref[0, sl, :] = dv_blocks[j].astype(dv_ref.dtype)
-
-
-# ---------------------------------------------------------------------------
-# native-layout kernels: (B, S, H, D) in, no transpose pass
-# ---------------------------------------------------------------------------
-
-
-def _fwd_kernel_native(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
-                       segk_ref, o_ref, lse_ref, *, scale: float, blk_k: int,
-                       rate: float, has_bias: bool, has_segments: bool,
-                       n_heads: int):
-    """One program per (batch, q-block): loops heads, then k-blocks. Blocks
-    span the full (H, D) trailing dims (Mosaic rejects head-singleton
-    blocks); per-head (S, D) panels are static slices of the VMEM block.
-    Math and dropout counters identical to _fwd_kernel — bh there is
-    program_id(0) over a (B*H,) grid, here bi * n_heads + h."""
-    bi = pl.program_id(0)
-    qi = pl.program_id(1)
-    bq = q_ref.shape[1]
-    d = q_ref.shape[3]
-    s_len = k_ref.shape[1]
-    nk = s_len // blk_k
-    segq = segq_ref[0, 0] if has_segments else None
-
-    for hh in range(n_heads):
-        q = q_ref[0, :, hh, :]
-        carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
-                 jnp.zeros((bq, 1), jnp.float32),
-                 jnp.zeros((bq, d), jnp.float32))
-
-        for j in range(nk):
-            segk = (segk_ref[0, 0, j * blk_k:(j + 1) * blk_k]
-                    if has_segments else None)
-
-            def tile(carry, hh=hh, j=j, q=q, segk=segk):
-                m, l, acc = carry
-                kb = k_ref[0, j * blk_k:(j + 1) * blk_k, hh, :]
-                vb = v_ref[0, j * blk_k:(j + 1) * blk_k, hh, :]
-                s = jax.lax.dot_general(
-                    q, kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if has_bias:
-                    s = s + bias_ref[0, 0,
-                                     j * blk_k:(j + 1) * blk_k][None, :]
-                if has_segments:
-                    s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-                if rate > 0.0:
-                    keep = _keep_mask(seed_ref[0], bi * n_heads + hh,
-                                      qi * bq, j * blk_k, bq, blk_k, rate)
-                    p_acc = jnp.where(keep, p, 0.0)
-                else:
-                    p_acc = p
-                acc = acc * alpha + jnp.dot(
-                    p_acc.astype(vb.dtype), vb,
-                    preferred_element_type=jnp.float32)
-                return m_new, l, acc
-
-            carry = _maybe_skip(has_segments, segq, segk, tile, carry)
-
-        m, l, acc = carry
-        l_safe = jnp.maximum(l, 1e-30)
-        out = acc / l_safe
-        if rate > 0.0:
-            out = out / (1.0 - rate)
-        if has_segments:
-            # zero pad-row outputs — see _fwd_kernel
-            out = jnp.where(segq[:, None] > 0, out, 0.0)
-        o_ref[0, :, hh, :] = out.astype(o_ref.dtype)
-        lse_ref[0, hh, :] = (m + jnp.log(l_safe))[:, 0]
-
-
-def _dqkv_kernel_native(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref,
-                        lse_ref, delta_ref, do_ref, dq_ref, dk_ref, dv_ref,
-                        *, scale: float, blk_q: int, blk_k: int, rate: float,
-                        has_bias: bool, has_segments: bool, n_heads: int):
-    """Fused backward, one program per batch element: loops heads, then the
-    (q-block, k-block) tiles of _dqkv_kernel. dq/dk/dv write straight into
-    the (1, S, H, D) native-layout blocks — no epilogue transposes. VMEM
-    holds ~7 (S, H, D) bf16 tensors plus per-head fp32 accumulators; the
-    wrapper gates on that budget and falls back to the (BH, S, D) split
-    path beyond it."""
-    bi = pl.program_id(0)
-    s_len = q_ref.shape[1]
-    d = q_ref.shape[3]
-    nq = s_len // blk_q
-    nk = s_len // blk_k
-
-    for hh in range(n_heads):
+    for t in range(heads_per_prog):
+        lanes = slice(t * d, (t + 1) * d)
+        bh = row * heads_per_row + group * heads_per_prog + t
+        # per-k-block accumulators as plain Python lists — a (S, D)
+        # functional scatter would lower to ops pallas rejects; disjoint
+        # static blocks written once at the end need no scatter at all
         dk_blocks = [jnp.zeros((blk_k, d), jnp.float32) for _ in range(nk)]
         dv_blocks = [jnp.zeros((blk_k, d), jnp.float32) for _ in range(nk)]
 
         for i in range(nq):
-            qb = q_ref[0, i * blk_q:(i + 1) * blk_q, hh, :]
-            dob = do_ref[0, i * blk_q:(i + 1) * blk_q, hh, :]
+            qb = q_ref[0, i * blk_q:(i + 1) * blk_q, lanes]
+            dob = do_ref[0, i * blk_q:(i + 1) * blk_q, lanes]
             segq = (seg_ref[0, 0, i * blk_q:(i + 1) * blk_q]
                     if has_segments else None)
-            lse = lse_ref[0, hh, i * blk_q:(i + 1) * blk_q][:, None]
-            delta = delta_ref[0, hh, i * blk_q:(i + 1) * blk_q][:, None]
+            lse = lse_ref[0, 0, t, i * blk_q:(i + 1) * blk_q][:, None]
+            delta = delta_ref[0, 0, t, i * blk_q:(i + 1) * blk_q][:, None]
             dq_i = jnp.zeros((blk_q, d), jnp.float32)
             for j in range(nk):
                 segk = (seg_ref[0, 0, j * blk_k:(j + 1) * blk_k]
                         if has_segments else None)
 
-                def tile(carry, hh=hh, i=i, j=j, qb=qb, dob=dob, segq=segq,
-                         segk=segk, lse=lse, delta=delta):
+                def tile(carry, lanes=lanes, bh=bh, i=i, j=j, qb=qb, dob=dob,
+                         segq=segq, segk=segk, lse=lse, delta=delta):
                     dq_i, dk_j, dv_j = carry
-                    kb = k_ref[0, j * blk_k:(j + 1) * blk_k, hh, :]
-                    vb = v_ref[0, j * blk_k:(j + 1) * blk_k, hh, :]
+                    kb = k_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
+                    vb = v_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
                     s = jax.lax.dot_general(
                         qb, kb, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
@@ -542,9 +412,8 @@ def _dqkv_kernel_native(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref,
                         dob, vb, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
                     if rate > 0.0:
-                        keep = _keep_mask(seed_ref[0], bi * n_heads + hh,
-                                          i * blk_q, j * blk_k, blk_q, blk_k,
-                                          rate)
+                        keep = _keep_mask(seed_ref[0], bh, i * blk_q,
+                                          j * blk_k, blk_q, blk_k, rate)
                         p_drop = jnp.where(keep, p / (1.0 - rate), 0.0)
                         dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
                     else:
@@ -564,18 +433,27 @@ def _dqkv_kernel_native(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref,
                 dq_i, dk_blocks[j], dv_blocks[j] = _maybe_skip(
                     has_segments, segq, segk, tile,
                     (dq_i, dk_blocks[j], dv_blocks[j]))
-            dq_ref[0, i * blk_q:(i + 1) * blk_q, hh, :] = dq_i.astype(
+            dq_ref[0, i * blk_q:(i + 1) * blk_q, lanes] = dq_i.astype(
                 dq_ref.dtype)
 
         for j in range(nk):
-            sl = slice(j * blk_k, (j + 1) * blk_k)
-            dk_ref[0, sl, hh, :] = dk_blocks[j].astype(dk_ref.dtype)
-            dv_ref[0, sl, hh, :] = dv_blocks[j].astype(dv_ref.dtype)
+            rows = slice(j * blk_k, (j + 1) * blk_k)
+            dk_ref[0, rows, lanes] = dk_blocks[j].astype(dk_ref.dtype)
+            dv_ref[0, rows, lanes] = dv_blocks[j].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
 # host-side wrappers
 # ---------------------------------------------------------------------------
+
+# Fused-backward bound on S * lanes of a program's (S, lanes) panels: its
+# VMEM footprint is 8 double-buffered input/output panels plus 3 fp32 (S, D)
+# accumulators per head. S=2048 with one D=64 head per program is the largest
+# shape the v5e compiler accepts (tests/test_tpu_compile.py); two heads per
+# program (native, D=64) or D=128 heads halve the admissible S. Beyond the
+# bound the split dq / dkv kernels run, which exist in the bh layout only.
+_FUSED_BWD_MAX_PANEL = 2048 * 64
+
 
 def _to_bh(x):
     """(B, S, H, D) -> (B*H, S, D)."""
@@ -588,19 +466,61 @@ def _from_bh(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+def _heads_per_prog(h: int, d: int) -> int:
+    """Heads that share one 128-lane block of the (B, S, H*D) view, or 0
+    when the shape cannot be tiled that way. Mosaic wants a block's lane
+    dim to be a multiple of 128, so D=64 heads go two to a program (static
+    64-lane slices inside the kernel), D>=128 heads one."""
+    if d % 128 == 0:
+        return 1
+    if 128 % d == 0 and h % (128 // d) == 0:
+        return 128 // d
+    return 0
+
+
 def _use_native(s: int, h: int, d: int) -> bool:
-    """Native (B, S, H, D) kernels iff the fused backward's per-program
-    working set fits VMEM: ~9 resident (S, H, D)-sized tensors (7 bf16
-    q/k/v/do/dq/dk/dv blocks + fp32 accumulators/score tiles rounded up).
-    FLASH_LAYOUT=bh forces the transpose path (A/B isolation); FLASH_BWD=
-    split implies it too (the split backward kernels only exist in bh
-    layout, and they are what serves S beyond the VMEM gate anyway)."""
-    if os.environ.get("FLASH_LAYOUT", "native") == "bh":
-        return False
-    if os.environ.get("FLASH_BWD", "fused") == "split":
-        return False
-    budget = _env_int("FLASH_NATIVE_VMEM", 12 * 2 ** 20)
-    return 9 * s * h * d * 2 <= budget
+    """Layout choice, by shape alone: native wherever heads tile into
+    128-lane blocks and the fused backward fits them."""
+    hp = _heads_per_prog(h, d)
+    return hp > 0 and s * hp * d <= _FUSED_BWD_MAX_PANEL
+
+
+class _Layout(NamedTuple):
+    """How (B, S, H, D) operands are presented to the kernels as a
+    (rows, S, groups * lanes) array walked by a (rows, groups, ...) grid."""
+    native: bool
+    heads: int           # H
+    rows: int            # B (native) or B*H (bh)
+    groups: int          # lane blocks per row: H // heads_per_prog, or 1
+    heads_per_prog: int
+
+    @property
+    def heads_per_row(self) -> int:
+        """Stride of the kernels' dropout counter (row * this + head)."""
+        return self.heads if self.native else 1
+
+    def pack(self, x):
+        if self.native:
+            b, s, h, d = x.shape
+            return x.reshape(b, s, h * d)  # row-major view: moves no bytes
+        return _to_bh(x)
+
+    def unpack(self, x, b, s, d):
+        if self.native:
+            return x.reshape(b, s, self.heads, d)
+        return _from_bh(x, b, self.heads)
+
+    def batch(self, row):
+        """Grid row -> batch index (for the per-batch bias / segment
+        operands)."""
+        return row if self.native else row // self.heads
+
+
+def _layout(b: int, s: int, h: int, d: int) -> _Layout:
+    if _use_native(s, h, d):
+        hp = _heads_per_prog(h, d)
+        return _Layout(True, h, b, h // hp, hp)
+    return _Layout(False, h, b * h, 1, 1)
 
 
 def _seg_operand(segment_ids, b, s):
@@ -610,6 +530,22 @@ def _seg_operand(segment_ids, b, s):
     if segment_ids is None:
         return jnp.zeros((1, 1, 1), jnp.int32)
     return segment_ids.reshape(b, 1, s).astype(jnp.int32)
+
+
+def _seed_operand(seed):
+    return (jnp.zeros((1,), jnp.int32) if seed is None
+            else jnp.asarray(seed, jnp.int32).reshape(1))
+
+
+_DUMMY_BLOCK = (1, 1, 1)
+
+
+def _per_batch_spec(present: bool, width: int, index_map):
+    """BlockSpec of a (B, 1, S)-shaped per-batch operand (bias, segment
+    ids), or of its (1, 1, 1) dummy when the operand is absent."""
+    if present:
+        return pl.BlockSpec((1, 1, width), index_map)
+    return pl.BlockSpec(_DUMMY_BLOCK, lambda *_: (0, 0, 0))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -638,87 +574,46 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret):
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_segments = segment_ids is not None
+    lay = _layout(b, s, h, d)
+    hp = lay.heads_per_prog
+    lanes = hp * d
     # shared by both layouts: the cross-layout bit-parity contract depends
     # on identical bias flattening and seed packing, so they are built once
     bias2 = (bias.reshape(b, 1, s).astype(jnp.float32) if has_bias
-             else jnp.zeros((1, 1, 1), jnp.float32))
+             else jnp.zeros(_DUMMY_BLOCK, jnp.float32))
     seg2 = _seg_operand(segment_ids, b, s)
-    seed_arr = (jnp.zeros((1,), jnp.int32) if seed is None
-                else jnp.asarray(seed, jnp.int32).reshape(1))
+    qx, kx, vx = lay.pack(q), lay.pack(k), lay.pack(v)
 
-    if _use_native(s, h, d):
-        bias_bs = (pl.BlockSpec((1, 1, s), lambda bi, qi: (bi, 0, 0))
-                   if has_bias
-                   else pl.BlockSpec((1, 1, 1), lambda bi, qi: (0, 0, 0)))
-        segq_bs = (pl.BlockSpec((1, 1, blk_q), lambda bi, qi: (bi, 0, qi))
-                   if has_segments
-                   else pl.BlockSpec((1, 1, 1), lambda bi, qi: (0, 0, 0)))
-        segk_bs = (pl.BlockSpec((1, 1, s), lambda bi, qi: (bi, 0, 0))
-                   if has_segments
-                   else pl.BlockSpec((1, 1, 1), lambda bi, qi: (0, 0, 0)))
-        grid = (b, s // blk_q)
-        out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_native, scale=scale, blk_k=blk_k,
-                              rate=rate, has_bias=has_bias,
-                              has_segments=has_segments, n_heads=h),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1,), lambda bi, qi: (0,)),      # seed
-                pl.BlockSpec((1, blk_q, h, d), lambda bi, qi: (bi, qi, 0, 0)),
-                pl.BlockSpec((1, s, h, d), lambda bi, qi: (bi, 0, 0, 0)),
-                pl.BlockSpec((1, s, h, d), lambda bi, qi: (bi, 0, 0, 0)),
-                bias_bs,
-                segq_bs,
-                segk_bs,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, blk_q, h, d), lambda bi, qi: (bi, qi, 0, 0)),
-                pl.BlockSpec((1, h, blk_q), lambda bi, qi: (bi, 0, qi)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
-                jax.ShapeDtypeStruct((b, h, s), jnp.float32),
-            ],
-            interpret=interpret,
-        )(seed_arr, q, k, v, bias2, seg2, seg2)
-        return out, (q, k, v, bias2, seg2, lse, out)
-
-    qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
-    bias_blockspec = (pl.BlockSpec((1, 1, s), lambda bh, qi: (bh // h, 0, 0))
-                      if has_bias
-                      else pl.BlockSpec((1, 1, 1), lambda bh, qi: (0, 0, 0)))
-    segq_bs = (pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh // h, 0, qi))
-               if has_segments
-               else pl.BlockSpec((1, 1, 1), lambda bh, qi: (0, 0, 0)))
-    segk_bs = (pl.BlockSpec((1, 1, s), lambda bh, qi: (bh // h, 0, 0))
-               if has_segments
-               else pl.BlockSpec((1, 1, 1), lambda bh, qi: (0, 0, 0)))
-
-    grid = (b * h, s // blk_q)
+    q_bs = pl.BlockSpec((1, blk_q, lanes), lambda r, g, qi: (r, qi, g))
+    kv_bs = pl.BlockSpec((1, s, lanes), lambda r, g, qi: (r, 0, g))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, blk_k=blk_k, rate=rate,
-                          has_bias=has_bias, has_segments=has_segments),
-        grid=grid,
+                          has_bias=has_bias, has_segments=has_segments,
+                          heads_per_prog=hp,
+                          heads_per_row=lay.heads_per_row),
+        grid=(lay.rows, lay.groups, s // blk_q),
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, qi: (0,)),      # seed
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            bias_blockspec,
-            segq_bs,
-            segk_bs,
+            pl.BlockSpec((1,), lambda r, g, qi: (0,)),      # seed
+            q_bs, kv_bs, kv_bs,
+            _per_batch_spec(has_bias, s,
+                            lambda r, g, qi: (lay.batch(r), 0, 0)),
+            _per_batch_spec(has_segments, blk_q,
+                            lambda r, g, qi: (lay.batch(r), 0, qi)),
+            _per_batch_spec(has_segments, s,
+                            lambda r, g, qi: (lay.batch(r), 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi)),
+            q_bs,
+            pl.BlockSpec((1, 1, hp, blk_q), lambda r, g, qi: (r, g, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
+            jax.ShapeDtypeStruct(qx.shape, q.dtype),
+            jax.ShapeDtypeStruct((lay.rows, lay.groups, hp, s), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
-    )(seed_arr, qb, kb, vb, bias2, seg2, seg2)
-    return _from_bh(out, b, h), (qb, kb, vb, bias2, seg2, lse, out)
+    )(_seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
+    return lay.unpack(out, b, s, d), (qx, kx, vx, bias2, seg2, lse, out)
 
 
 def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret):
@@ -728,192 +623,107 @@ def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret):
 
 
 def _flash_bwd_rule(rate, interpret, saved, g):
-    (qb, kb, vb, bias2, seg2, lse, outb), seed, qshape, has_bias, \
+    # residuals are in the kernel layout _flash_fwd chose (same
+    # deterministic shape gate); lse is (rows, groups, heads_per_prog, S)
+    (qx, kx, vx, bias2, seg2, lse, outx), seed, qshape, has_bias, \
         has_segments = saved
     b, s, h, d = qshape
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
     blk_k = _pick_block(s, DEFAULT_BLK_K)
     scale = 1.0 / (d ** 0.5)
+    lay = _layout(b, s, h, d)
+    hp = lay.heads_per_prog
+    lanes = hp * d
+    gx = lay.pack(g)
+    # delta = rowsum(dO * O) per head (cheap elementwise — jnp, not a kernel)
+    delta = jnp.sum(
+        (gx.astype(jnp.float32) * outx.astype(jnp.float32))
+        .reshape(lay.rows, s, lay.groups, hp, d), axis=-1
+    ).transpose(0, 2, 3, 1)
+    seed_arr = _seed_operand(seed)
+    kw = dict(scale=scale, rate=rate, has_bias=has_bias,
+              has_segments=has_segments)
 
-    if _use_native(s, h, d):
-        # residuals are in native (B, S, H, D) layout (same deterministic
-        # gate as _flash_fwd); lse is (B, H, S)
-        q, k, v, out = qb, kb, vb, outb
-        delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
-                           out.astype(jnp.float32))
-        seed_arr = (jnp.zeros((1,), jnp.int32) if seed is None
-                    else jnp.asarray(seed, jnp.int32).reshape(1))
-        bias_bs = (pl.BlockSpec((1, 1, s), lambda bi: (bi, 0, 0))
-                   if has_bias
-                   else pl.BlockSpec((1, 1, 1), lambda bi: (0, 0, 0)))
-        seg_bs = (pl.BlockSpec((1, 1, s), lambda bi: (bi, 0, 0))
-                  if has_segments
-                  else pl.BlockSpec((1, 1, 1), lambda bi: (0, 0, 0)))
-        qkv_bs = pl.BlockSpec((1, s, h, d), lambda bi: (bi, 0, 0, 0))
-        hs_bs = pl.BlockSpec((1, h, s), lambda bi: (bi, 0, 0))
+    if s * lanes <= _FUSED_BWD_MAX_PANEL:
+        # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated
+        # once instead of twice
+        qkv_bs = pl.BlockSpec((1, s, lanes), lambda r, g: (r, 0, g))
+        stat_bs = pl.BlockSpec((1, 1, hp, s), lambda r, g: (r, g, 0, 0))
+        per_batch = lambda r, g: (lay.batch(r), 0, 0)  # noqa: E731
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_dqkv_kernel_native, scale=scale, blk_q=blk_q,
-                              blk_k=blk_k, rate=rate, has_bias=has_bias,
-                              has_segments=has_segments, n_heads=h),
-            grid=(b,),
+            functools.partial(_dqkv_kernel, blk_q=blk_q, blk_k=blk_k,
+                              heads_per_prog=hp,
+                              heads_per_row=lay.heads_per_row, **kw),
+            grid=(lay.rows, lay.groups),
             in_specs=[
-                pl.BlockSpec((1,), lambda bi: (0,)),
-                qkv_bs, qkv_bs, qkv_bs, bias_bs, seg_bs, hs_bs, hs_bs,
-                qkv_bs,
+                pl.BlockSpec((1,), lambda r, g: (0,)),
+                qkv_bs, qkv_bs, qkv_bs,
+                _per_batch_spec(has_bias, s, per_batch),
+                _per_batch_spec(has_segments, s, per_batch),
+                stat_bs, stat_bs, qkv_bs,
             ],
             out_specs=[qkv_bs, qkv_bs, qkv_bs],
-            out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
-                jax.ShapeDtypeStruct(k.shape, k.dtype),
-                jax.ShapeDtypeStruct(v.shape, v.dtype),
-            ],
+            out_shape=[jax.ShapeDtypeStruct(qx.shape, qx.dtype)] * 3,
+            name="flash_bwd_dqkv",
             interpret=interpret,
-        )(seed_arr, q, k, v, bias2, seg2, lse, delta, g)
-        dbias = jnp.zeros((b, 1, 1, s), bias2.dtype) if has_bias else None
-        dseg = None if not has_segments else jax.custom_derivatives \
-            .zero_from_primal(seg2.reshape(b, s))
-        dseed = None if seed is None else jax.custom_derivatives \
-            .zero_from_primal(jnp.asarray(seed, jnp.int32))
-        return dq, dk, dv, dbias, dseg, dseed
+        )(seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx)
+    else:
+        # split kernels, bh layout only (_use_native excludes these shapes)
+        lse = lse.reshape(b * h, 1, s)
+        delta = delta.reshape(b * h, 1, s)
+        row_bs = pl.BlockSpec((1, 1, s), lambda bh, i: (bh, 0, 0))
+        full_bs = pl.BlockSpec((1, s, d), lambda bh, i: (bh, 0, 0))
+        per_batch = lambda bh, i: (bh // h, 0, 0)  # noqa: E731
+        per_batch_blk = lambda bh, i: (bh // h, 0, i)  # noqa: E731
 
-    gb = _to_bh(g)
-    # delta = rowsum(dO * O) (cheap elementwise — jnp, not a kernel)
-    delta = jnp.sum(gb.astype(jnp.float32) * outb.astype(jnp.float32),
-                    axis=-1)[:, None, :]
-    seed_arr = (jnp.zeros((1,), jnp.int32) if seed is None
-                else jnp.asarray(seed, jnp.int32).reshape(1))
-
-    # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated once
-    # instead of twice. VMEM-bounded by the per-program footprint — 8 (S, D)
-    # input/output arrays plus 3 fp32 (S, D) accumulators — so gate on the
-    # S*D byte budget (S=2048 at D=64 was the measured-safe point), not S
-    # alone: D=128 heads halve the admissible S. FLASH_BWD=split forces the
-    # two-kernel path.
-    if s * d <= 2048 * 64 and os.environ.get("FLASH_BWD", "fused") != "split":
-        bias_bs = (pl.BlockSpec((1, 1, s), lambda bh: (bh // h, 0, 0))
-                   if has_bias
-                   else pl.BlockSpec((1, 1, 1), lambda bh: (0, 0, 0)))
-        seg_bs = (pl.BlockSpec((1, 1, s), lambda bh: (bh // h, 0, 0))
-                  if has_segments
-                  else pl.BlockSpec((1, 1, 1), lambda bh: (0, 0, 0)))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_dqkv_kernel, scale=scale, blk_q=blk_q,
-                              blk_k=blk_k, rate=rate, has_bias=has_bias,
-                              has_segments=has_segments),
-            grid=(b * h,),
+        blk_bs = pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0))
+        stat_blk_bs = pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi))
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, blk_k=blk_k, **kw),
+            grid=(b * h, s // blk_q),
             in_specs=[
-                pl.BlockSpec((1,), lambda bh: (0,)),
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
-                bias_bs,
-                seg_bs,
-                pl.BlockSpec((1, 1, s), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, 1, s), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
+                pl.BlockSpec((1,), lambda bh, qi: (0,)),
+                blk_bs, full_bs, full_bs,
+                _per_batch_spec(has_bias, s, per_batch),
+                _per_batch_spec(has_segments, blk_q, per_batch_blk),
+                _per_batch_spec(has_segments, s, per_batch),
+                stat_blk_bs, stat_blk_bs, blk_bs,
             ],
-            out_specs=[
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, s, d), lambda bh: (bh, 0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct(qb.shape, qb.dtype),
-                jax.ShapeDtypeStruct(kb.shape, kb.dtype),
-                jax.ShapeDtypeStruct(vb.shape, vb.dtype),
-            ],
+            out_specs=blk_bs,
+            out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
+            name="flash_bwd_dq",
             interpret=interpret,
-        )(seed_arr, qb, kb, vb, bias2, seg2, lse, delta, gb)
-        return _bwd_epilogue(dq, dk, dv, b, h, s, bias2, has_bias, seg2,
-                             has_segments, seed)
+        )(seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
-    bias_blockspec_q = (pl.BlockSpec((1, 1, s), lambda bh, qi: (bh // h, 0, 0))
-                        if has_bias
-                        else pl.BlockSpec((1, 1, 1), lambda bh, qi: (0, 0, 0)))
-    segq_bs = (pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh // h, 0, qi))
-               if has_segments
-               else pl.BlockSpec((1, 1, 1), lambda bh, qi: (0, 0, 0)))
-    segk_full_bs = (pl.BlockSpec((1, 1, s), lambda bh, qi: (bh // h, 0, 0))
-                    if has_segments
-                    else pl.BlockSpec((1, 1, 1), lambda bh, qi: (0, 0, 0)))
+        blk_bs = pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0))
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, blk_q=blk_q, **kw),
+            grid=(b * h, s // blk_k),
+            in_specs=[
+                pl.BlockSpec((1,), lambda bh, kj: (0,)),
+                full_bs, blk_bs, blk_bs,
+                _per_batch_spec(has_bias, blk_k, per_batch_blk),
+                _per_batch_spec(has_segments, s, per_batch),
+                _per_batch_spec(has_segments, blk_k, per_batch_blk),
+                row_bs, row_bs, full_bs,
+            ],
+            out_specs=[blk_bs, blk_bs],
+            out_shape=[jax.ShapeDtypeStruct(kx.shape, kx.dtype)] * 2,
+            name="flash_bwd_dkv",
+            interpret=interpret,
+        )(seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, blk_k=blk_k, rate=rate,
-                          has_bias=has_bias, has_segments=has_segments),
-        grid=(b * h, s // blk_q),
-        in_specs=[
-            pl.BlockSpec((1,), lambda bh, qi: (0,)),
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, qi: (bh, 0, 0)),
-            bias_blockspec_q,
-            segq_bs,
-            segk_full_bs,
-            pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(qb.shape, qb.dtype),
-        interpret=interpret,
-    )(seed_arr, qb, kb, vb, bias2, seg2, seg2, lse, delta, gb)
-
-    bias_blockspec_k = (pl.BlockSpec((1, 1, blk_k),
-                                     lambda bh, kj: (bh // h, 0, kj))
-                        if has_bias
-                        else pl.BlockSpec((1, 1, 1), lambda bh, kj: (0, 0, 0)))
-    segq_full_bs = (pl.BlockSpec((1, 1, s), lambda bh, kj: (bh // h, 0, 0))
-                    if has_segments
-                    else pl.BlockSpec((1, 1, 1), lambda bh, kj: (0, 0, 0)))
-    segk_bs = (pl.BlockSpec((1, 1, blk_k), lambda bh, kj: (bh // h, 0, kj))
-               if has_segments
-               else pl.BlockSpec((1, 1, 1), lambda bh, kj: (0, 0, 0)))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, blk_q=blk_q, rate=rate,
-                          has_bias=has_bias, has_segments=has_segments),
-        grid=(b * h, s // blk_k),
-        in_specs=[
-            pl.BlockSpec((1,), lambda bh, kj: (0,)),
-            pl.BlockSpec((1, s, d), lambda bh, kj: (bh, 0, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0)),
-            bias_blockspec_k,
-            segq_full_bs,
-            segk_bs,
-            pl.BlockSpec((1, 1, s), lambda bh, kj: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, s), lambda bh, kj: (bh, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda bh, kj: (bh, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(kb.shape, kb.dtype),
-            jax.ShapeDtypeStruct(vb.shape, vb.dtype),
-        ],
-        interpret=interpret,
-    )(seed_arr, qb, kb, vb, bias2, seg2, seg2, lse, delta, gb)
-
-    return _bwd_epilogue(dq, dk, dv, b, h, s, bias2, has_bias, seg2,
-                         has_segments, seed)
-
-
-def _bwd_epilogue(dq, dk, dv, b, h, s, bias2, has_bias, seg2, has_segments,
-                  seed):
-    """Shared cotangent packaging: bias is non-differentiable by contract
-    (zero cotangent; see flash_attention docstring), segment ids and seed
-    likewise — the integer primals get float0 cotangents per JAX's
-    convention (int32 zeros trip stricter custom_vjp aval checking)."""
-    dbias = None
-    if has_bias:
-        dbias = jnp.zeros((b, 1, 1, s), bias2.dtype)
+    # bias is non-differentiable by contract (zero cotangent; see the
+    # flash_attention docstring), segment ids and seed likewise — the
+    # integer primals get float0 cotangents per JAX's convention (int32
+    # zeros trip stricter custom_vjp aval checking)
+    dbias = jnp.zeros((b, 1, 1, s), bias2.dtype) if has_bias else None
     dseg = None if not has_segments else jax.custom_derivatives \
         .zero_from_primal(seg2.reshape(b, s))
     dseed = None if seed is None else jax.custom_derivatives \
         .zero_from_primal(jnp.asarray(seed, jnp.int32))
-    return (_from_bh(dq, b, h), _from_bh(dk, b, h), _from_bh(dv, b, h),
-            dbias, dseg, dseed)
+    return (lay.unpack(dq, b, s, d), lay.unpack(dk, b, s, d),
+            lay.unpack(dv, b, s, d), dbias, dseg, dseed)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -136,6 +136,7 @@ def _stage1_flat(scal, g, mu, nu, pf, wd, *, b1, b2, eps, use_pallas):
         ],
         out_specs=[_blk(), _blk(), _blk()],
         out_shape=[jax.ShapeDtypeStruct((Rp, LANES), jnp.float32)] * 3,
+        name="lamb_stage1",
         interpret=jax.default_backend() != "tpu",
     )(scal, g2, mu2, nu2, pf2, wd2)
     return (mu3.reshape(-1)[:n], nu3.reshape(-1)[:n], u3.reshape(-1)[:n])
@@ -153,6 +154,7 @@ def _stage2_flat(t, u, *, use_pallas):
         in_specs=[_blk(), _blk()],
         out_specs=_blk(),
         out_shape=jax.ShapeDtypeStruct((Rp, LANES), jnp.float32),
+        name="lamb_stage2",
         interpret=jax.default_backend() != "tpu",
     )(t2, u2)
     return out.reshape(-1)[:n]
@@ -173,13 +175,13 @@ def _maybe_shard_map(fn, mesh, specs, idxs, n_groups, outs_per_leaf):
     the same specs (elementwise -> zero collectives inside)."""
     if mesh is None or specs is None:
         return fn
-    from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
 
     sp = tuple(_leaf_spec(specs[i]) for i in idxs)
     out_specs = tuple(s for s in sp for _ in range(outs_per_leaf))
     return shard_map(fn, mesh=mesh,
                      in_specs=(PartitionSpec(),) + sp * n_groups,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 def lamb_stage1(g_leaves: Sequence[Any], mu_leaves: Sequence[Any],

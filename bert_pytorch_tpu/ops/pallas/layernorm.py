@@ -108,6 +108,7 @@ def _forward(x, scale, bias, eps, interpret):
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
+        name="layernorm_fwd",
         interpret=interpret,
     )(x2, scale.reshape(1, E), bias.reshape(1, E))
     return y[:R].reshape(orig_shape), mean, rstd
@@ -146,6 +147,7 @@ def _bwd_rule(eps, interpret, res, g):
             jax.ShapeDtypeStruct((1, E), jnp.float32),
             jax.ShapeDtypeStruct((1, E), jnp.float32),
         ],
+        name="layernorm_bwd",
         interpret=interpret,
     )(x2, scale.reshape(1, E), mean, rstd, g2)
     return (dx[:R].reshape(orig_shape),
@@ -287,6 +289,7 @@ def _adln_forward(x, residual, scale, bias, seed, rate, eps, interpret):
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
             jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
+        name="add_dropout_layernorm_fwd",
         interpret=interpret,
     )(seed_arr, x2, r2, scale.reshape(1, E), bias.reshape(1, E))
     return y[:R].reshape(orig_shape), mean, rstd
@@ -332,6 +335,7 @@ def _adln_bwd_rule(rate, eps, interpret, res, g):
             jax.ShapeDtypeStruct((1, E), jnp.float32),
             jax.ShapeDtypeStruct((1, E), jnp.float32),
         ],
+        name="add_dropout_layernorm_bwd",
         interpret=interpret,
     )(seed_arr, x2, r2, scale.reshape(1, E), mean, rstd, g2)
     return (dx[:R].reshape(orig_shape), dres[:R].reshape(orig_shape),

@@ -176,7 +176,7 @@ def _jitted_ring(mesh, rate: float, has_bias: bool, has_drop: bool,
     ring work when called eagerly (tests/debug) — under an outer jit the
     trace is simply inlined — and caching it keeps repeat eager calls from
     re-tracing; jax.jit's own cache handles shape changes."""
-    from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from bert_pytorch_tpu.ops.attention import flat_batch_head_shard
@@ -210,7 +210,7 @@ def _jitted_ring(mesh, rate: float, has_bias: bool, has_drop: bool,
         return ring(lq, lk, lv, lbias, lseg)
 
     return jax.jit(shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=spec_qkv, check_rep=False))
+                             out_specs=spec_qkv, check_vma=False))
 
 
 def ring_sharded(mesh, q, k, v, bias, dropout_rng, rate: float,

@@ -311,7 +311,7 @@ class KFAC:
         divide-after-all-reduce order."""
         from jax.sharding import PartitionSpec as P
 
-        from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+        from jax import shard_map
 
         in_specs, args = [], []
         for path, a, g, stacked in sites:
@@ -360,7 +360,7 @@ class KFAC:
         outs = shard_map(local_contract, mesh=self.mesh,
                          in_specs=tuple(in_specs),
                          out_specs=tuple(out_specs),
-                         check_rep=False)(*args)
+                         check_vma=False)(*args)
 
         results = {self._pathkey(p): {"A": outs[2 * i], "G": outs[2 * i + 1]}
                    for i, (p, _a, _g, _s) in enumerate(sites)}
@@ -421,7 +421,7 @@ class KFAC:
         from jax.sharding import PartitionSpec as P
 
         from bert_pytorch_tpu.parallel.coalesce import _bucketize
-        from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+        from jax import shard_map
 
         cfg = self.config
         flat = jax.tree_util.tree_flatten_with_path(stats)
@@ -452,7 +452,7 @@ class KFAC:
         outs = shard_map(reduce_buckets, mesh=self.mesh,
                          in_specs=in_specs,
                          out_specs=tuple(P() for _ in leaves),
-                         check_rep=False)(*[x for _p, x in leaves])
+                         check_vma=False)(*[x for _p, x in leaves])
 
         reduced = []
         for (path, x), vec in zip(leaves, outs):

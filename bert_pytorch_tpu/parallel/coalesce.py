@@ -141,7 +141,7 @@ class NormReducer:
         import jax
         import jax.numpy as jnp
 
-        from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+        from jax import shard_map
 
         flat_pf, treedef = jax.tree_util.tree_flatten(pf_tree)
         flat_u = jax.tree.leaves(u_tree)
@@ -222,7 +222,7 @@ class NormReducer:
                 reduce_group, mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=tuple(PartitionSpec() for _ in idxs),
-                check_rep=False,
+                check_vma=False,
             )(*[flat_pf[i] for i in idxs], *[flat_u[i] for i in idxs])
             for j, i in enumerate(idxs):
                 nb = flat_nb[i]
@@ -250,7 +250,7 @@ class NormReducer:
 
         from jax.sharding import PartitionSpec
 
-        from bert_pytorch_tpu.ops.shard_map_compat import shard_map
+        from jax import shard_map
 
         flat = [jnp.asarray(x).astype(jnp.float32)
                 for x in jax.tree.leaves(tree)]
@@ -270,7 +270,7 @@ class NormReducer:
                 group_sums, mesh=self.mesh,
                 in_specs=tuple(self._specs[i] for i in idxs),
                 out_specs=PartitionSpec(),
-                check_rep=False)(*[flat[i] for i in idxs])
+                check_vma=False)(*[flat[i] for i in idxs])
             for j, i in enumerate(idxs):
                 totals[i] = vec[j]
         return jnp.sqrt(sum(totals))

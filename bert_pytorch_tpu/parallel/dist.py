@@ -2,65 +2,38 @@
 
 The reference wrapped torch.distributed rank/world/barrier calls
 (src/utils.py:22-74) around an NCCL process group initialized from env://
-rendezvous (run_pretraining.py:175). On TPU-VM the runtime already knows the
-topology: `jax.distributed.initialize()` (no-op on a single host) and the
-process_* APIs replace the whole launcher layer (SURVEY §5.8).
+rendezvous (run_pretraining.py:175). On TPU the runtime already knows the
+topology: `jax.distributed.initialize()` and the process_* APIs replace the
+whole launcher layer (SURVEY §5.8).
 """
 
 from __future__ import annotations
+
+import os
 
 import jax
 
 
 def _cluster_env_present() -> bool:
-    """True when this process is on a multi-worker TPU pod slice (GCE/GKE
-    metadata present). Deliberately restricted to the TPU cluster detectors:
-    auto-init on Slurm/MPI/K8s envs would make a plain single-process
-    `python run_pretraining.py` inside an unrelated allocation block in
-    jax.distributed.initialize() waiting for peers that never start. Those
-    clusters keep the explicit-args path. BPT_NO_AUTO_DIST=1 opts out
-    entirely."""
-    import os
+    """True when the environment names a multi-worker TPU slice: a worker
+    list with more than one host (TPU_PROCESS_ADDRESSES /
+    TPU_WORKER_HOSTNAMES, as GKE and the TPU runtime export them) or a
+    multislice job (MEGASCALE_NUM_SLICES > 1).
 
-    if os.environ.get("BPT_NO_AUTO_DIST") == "1":
-        return False
-    try:
-        from jax._src.clusters.cluster import ClusterEnv
-
-        return any(
-            "tpu" in env.__name__.lower() and env.is_env_present()
-            for env in ClusterEnv._cluster_types)
-    except Exception as e:  # private API moved: fall back to explicit-args only
-        import warnings
-
-        # Loud, not silent: on a pod slice this fallback means
-        # jax.distributed NEVER initializes (orbax cross-process checkpoint
-        # coordination and process_index() are then wrong), and the run
-        # would fail in confusing ways far from the cause. Single-host runs
-        # can ignore this. Re-verify the private import on JAX upgrades.
-        warnings.warn(
-            "bert_pytorch_tpu.parallel.dist: probing jax's private cluster "
-            f"detection API failed ({type(e).__name__}: {e}); multi-host "
-            "TPU auto-init is DISABLED. If this is a multi-worker pod "
-            "slice, pass coordinator_address/num_processes/process_id "
-            "explicitly to dist.initialize() or fix the probe for this "
-            "JAX version. Set BPT_NO_AUTO_DIST=1 to silence.",
-            RuntimeWarning, stacklevel=2)
-        return False
-
-
-def is_initialized() -> bool:
-    """True once jax.distributed is up. jax >= 0.5 exposes
-    jax.distributed.is_initialized(); on older versions the global client
-    object is the source of truth (private, but the only probe there is —
-    covered by tests/test_multihost.py so an API move fails loudly)."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    from jax._src import distributed as _dist  # jax < 0.5
-
-    state = getattr(_dist, "global_state", None)
-    return state is not None and state.client is not None
+    Decided from environment variables alone. jax's own detector asks the
+    GCE metadata server from any machine that holds a TPU, which on a
+    single host with a directly attached chip is a network round-trip (or,
+    with no network, a failed one) to learn that there is nothing to
+    initialize; and auto-init on Slurm/MPI/K8s envs would make a plain
+    single-process `python run_pretraining.py` inside an unrelated
+    allocation block waiting for peers that never start. A GCE pod slice
+    whose shell does not carry the worker list exports it (or passes
+    explicit args) — jax.distributed.initialize() then discovers the rest."""
+    hosts = (os.environ.get("TPU_PROCESS_ADDRESSES")
+             or os.environ.get("TPU_WORKER_HOSTNAMES") or "")
+    n_workers = sum(1 for h in hosts.split(",") if h.strip())
+    slices = os.environ.get("MEGASCALE_NUM_SLICES", "").strip()
+    return n_workers > 1 or (slices.isdigit() and int(slices) > 1)
 
 
 def initialize(coordinator_address=None, num_processes=None, process_id=None):
@@ -68,13 +41,14 @@ def initialize(coordinator_address=None, num_processes=None, process_id=None):
 
     The reference initialized its NCCL process group unconditionally
     (run_pretraining.py:175); the equivalent here is: on a multi-worker TPU
-    pod slice (and ONLY there — see _cluster_env_present), call
+    slice (and ONLY there — see _cluster_env_present), call
     jax.distributed.initialize() argless and let it auto-discover
     coordinator/rank — so orbax's cross-process checkpoint coordination and
     process_index() are always correct on a pod without any CLI plumbing.
     Slurm/MPI/K8s and CPU/DCN clusters use the explicit-args path
-    (e.g. tests/test_multihost.py). Plain single-host runs no-op."""
-    if is_initialized():
+    (e.g. tests/test_multihost.py). A single host — one attached chip or
+    four — is a plain no-op: no lookup, no coordinator."""
+    if jax.distributed.is_initialized():
         return
     if num_processes is not None and num_processes > 1:
         jax.distributed.initialize(

@@ -176,6 +176,19 @@ def assert_moments_sharded(moments: Any, plan: Zero1Plan,
         str(f) for f in bad)
 
 
+def placement_bytes(tree: Any) -> dict:
+    """{device id: bytes of `tree` resident on that device}, summed over
+    every leaf's addressable shards — where the state actually sits, not
+    what a sharding spec promises. A replicated tree shows its full size on
+    each device; a ZeRO-1 state about 1/N of it."""
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            dev = int(shard.device.id)
+            out[dev] = out.get(dev, 0) + int(shard.data.nbytes)
+    return dict(sorted(out.items()))
+
+
 def _gather_leaf(p, p_sh: NamedSharding):
     """One leaf's gather-on-use constraint, with an IDENTITY backward.
 

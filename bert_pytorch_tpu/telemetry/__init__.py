@@ -54,6 +54,8 @@ _EXPORTS = {
                       "flops_per_seq"),
     "lookup_peak_flops": ("bert_pytorch_tpu.telemetry.stepwatch",
                           "lookup_peak_flops"),
+    "device_peak_flops": ("bert_pytorch_tpu.telemetry.stepwatch",
+                          "device_peak_flops"),
     "CompileWatch": ("bert_pytorch_tpu.telemetry.compile_watch",
                      "CompileWatch"),
     "hbm_snapshot": ("bert_pytorch_tpu.telemetry.compile_watch",

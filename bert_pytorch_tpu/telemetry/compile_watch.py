@@ -21,29 +21,32 @@ from typing import Callable, Dict, List, Optional
 
 # jax fires these through jax.monitoring.record_event_duration_secs (the
 # names live in jax._src.dispatch; matched by substring so a path shuffle
-# in a future jax degrades to "no events seen", never an ImportError)
+# in a future jax degrades to "no events seen", never an ImportError).
+# The event wraps compile-or-load: it fires for a persistent-cache hit too,
+# with the (short) retrieval time as its duration.
 _COMPILE_EVENT_SUBSTRINGS = ("backend_compile",)
+# plain event (jax._src.compiler): one per executable loaded from the
+# persistent compilation cache instead of compiled
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class CompileWatch:
     """Counts XLA compiles via jax.monitoring; loud after warmup.
 
-    install() registers the listener (idempotent); uninstall() detaches it.
-    jax.monitoring has no public unregister, so uninstall best-effort uses
-    the private helper and otherwise leaves an inert callback behind — the
-    `_active` flag makes a stale registration a no-op either way.
+    install() registers the listeners (idempotent); uninstall() detaches
+    them.
     """
 
     def __init__(self, warn: Optional[Callable[[str], None]] = None,
                  registry=None):
         self._warn = warn
-        self._active = False
         self._installed = False
         self._steady = False
         self._lock = threading.Lock()
         self.compiles = 0
         self.compile_secs = 0.0
         self.compiles_after_steady = 0
+        self.cache_hits = 0
         self.durations: List[float] = []
         # registry publication (telemetry/registry.py): compiles tick live
         # so a /metrics scrape sees a recompile storm as it happens
@@ -60,29 +63,28 @@ class CompileWatch:
     def install(self) -> "CompileWatch":
         import jax.monitoring
 
-        self._active = True
         if not self._installed:
             jax.monitoring.register_event_duration_secs_listener(
                 self._on_duration)
+            jax.monitoring.register_event_listener(self._on_event)
             self._installed = True
         return self
 
     def uninstall(self) -> None:
-        self._active = False
-        if not self._installed:
-            return
-        try:
-            from jax._src import monitoring as _m
+        import jax.monitoring
 
-            _m._unregister_event_duration_listener_by_callback(
+        if self._installed:
+            jax.monitoring.unregister_event_duration_listener(
                 self._on_duration)
+            jax.monitoring.unregister_event_listener(self._on_event)
             self._installed = False
-        except Exception:
-            pass  # inert via _active; nothing leaks but a dead callback
+
+    def _on_event(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.cache_hits += 1
 
     def _on_duration(self, event: str, duration_secs: float, **kw) -> None:
-        if not self._active:
-            return
         if not any(s in event for s in _COMPILE_EVENT_SUBSTRINGS):
             return
         with self._lock:
@@ -115,6 +117,7 @@ class CompileWatch:
                 "compiles": self.compiles,
                 "compile_secs": round(self.compile_secs, 3),
                 "recompiles_after_warmup": self.compiles_after_steady,
+                "compile_cache_hits": self.cache_hits,
             }
 
 

@@ -52,9 +52,11 @@ from bert_pytorch_tpu.telemetry.registry import MetricsRegistry
 
 # every phase's StepWatch interval record carries at least these keys —
 # the "identical perf schema" contract the e2e tests pin per entry point
+# (`mfu` / `peak_flops` join them on an accelerator only: the CPU backend
+# has no peak to quote against, stepwatch.device_peak_flops)
 PERF_RECORD_CORE_KEYS = (
     "steps", "step_time_ms", "seq_per_sec", "tokens_per_sec",
-    "model_flops_per_sec", "mfu", "peak_flops",
+    "model_flops_per_sec",
 )
 
 # health-pack keys every phase's train record may carry; the subset that

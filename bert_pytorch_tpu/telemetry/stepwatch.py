@@ -36,8 +36,12 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Dict, Optional
 
-# Peak bf16 FLOP/s per chip by device kind (public figures). Longest
-# matching key wins ('TPU v5 lite' must not hit a 'TPU v5' prefix).
+# Peak dense bf16 FLOP/s per chip by device kind. Source: Google Cloud TPU
+# documentation, the per-generation system-architecture pages ("TPU v4",
+# "TPU v5e": 197 TFLOP/s bf16 per chip, "TPU v5p", "TPU v6e"). Longest
+# matching key wins ('TPU v5 lite' must not hit a 'TPU v5' prefix). A kind
+# that is not here is an error (lookup_peak_flops), never a default: MFU
+# against a guessed peak is a wrong number under a trusted name.
 # The MXU runs f32 matmuls at half the bf16 rate on every listed
 # generation, so the f32 peak is derived rather than tabled —
 # lookup_peak_flops(kind, dtype="f32") halves these numbers. MFU must be
@@ -53,7 +57,6 @@ PEAK_FLOPS = {
     "TPU v6 lite": 918e12,   # v6e / Trillium
     "TPU v6e": 918e12,
 }
-DEFAULT_PEAK = 275e12
 _F32_PEAK_RATIO = 0.5
 
 # Cost accounting price knob, shared by training (StepWatch) and serving
@@ -77,23 +80,33 @@ def resolve_cost_per_device_hour(value: Optional[float] = None) -> float:
     return DEFAULT_COST_PER_DEVICE_HOUR
 
 
-def lookup_peak_flops(device_kind: str,
-                      dtype: str = "bf16") -> Optional[float]:
-    """Known peak FLOP/s for a device kind at the given compute dtype
-    ("bf16" or "f32"/"float32"), else None (CPU, unknown TPU
-    generations). Callers decide the fallback — bench.py uses
-    DEFAULT_PEAK so its ratio stays comparable across rounds."""
+def lookup_peak_flops(device_kind: str, dtype: str = "bf16") -> float:
+    """Peak FLOP/s of one chip of `device_kind` at the given compute dtype
+    ("bf16" or "f32"/"float32"). Raises ValueError for a kind the table
+    does not know — add it to PEAK_FLOPS with its source."""
     kind = device_kind.lower()
     hits = [v for k, v in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0]))
             if k.lower() in kind]
     if not hits:
-        return None
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r}; add it "
+            "to telemetry.stepwatch.PEAK_FLOPS (with its source) before "
+            "quoting MFU on it")
     d = dtype.lower()
     if d in ("f32", "float32", "fp32"):
         return hits[0] * _F32_PEAK_RATIO
     if d in ("bf16", "bfloat16"):
         return hits[0]
     raise ValueError(f"unknown compute dtype for peak lookup: {dtype!r}")
+
+
+def device_peak_flops(device, dtype: str = "bf16") -> Optional[float]:
+    """Peak FLOP/s of a jax device for MFU: None on the CPU backend (no MFU
+    is reported there), the table's figure on an accelerator — an unknown
+    accelerator kind raises (lookup_peak_flops)."""
+    if device.platform == "cpu":
+        return None
+    return lookup_peak_flops(device.device_kind, dtype)
 
 
 def flops_per_seq(cfg, seq_len: int, vocab: int, n_pred: int) -> float:
@@ -124,9 +137,9 @@ class StepWatch:
     --steps_per_loop > 1 pass n=steps_per_loop to step_done; the interval
     math divides by optimization steps, so MFU/seq_per_sec stay exact.
 
-    `peak_flops=None` (unknown hardware, e.g. the CPU backend) reports
-    mfu=0.0 and carries peak_flops=0 in the record so the number is
-    self-describing rather than silently wrong.
+    `peak_flops=None` (the CPU backend, see device_peak_flops) leaves
+    `mfu` and `peak_flops` out of the record: there is no peak to quote
+    against.
     """
 
     def __init__(self, flops_per_step: float, seqs_per_step: float,
@@ -240,10 +253,10 @@ class StepWatch:
             "seq_per_sec": round(seqs_per_sec, 2),
             "tokens_per_sec": round(seqs_per_sec * self.seq_len, 1),
             "model_flops_per_sec": round(achieved, 1),
-            "mfu": (round(achieved / self.peak_flops, 6)
-                    if self.peak_flops else 0.0),
-            "peak_flops": self.peak_flops or 0,
         }
+        if self.peak_flops:
+            rec["mfu"] = round(achieved / self.peak_flops, 6)
+            rec["peak_flops"] = self.peak_flops
         if self._noted_tokens:
             # slot tokens = everything the device computed (pad included);
             # real tokens = training progress. packing_efficiency is their

@@ -36,6 +36,7 @@ import os
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
 import orbax.checkpoint as ocp
 
 from bert_pytorch_tpu.resilience.manifest import (CorruptCheckpointError,
@@ -43,6 +44,24 @@ from bert_pytorch_tpu.resilience.manifest import (CorruptCheckpointError,
                                                   step_dir_path,
                                                   verify_step_dir,
                                                   write_step_manifest)
+
+
+def _placed_here(abstract_state: Any) -> Any:
+    """The restore template with every leaf that names no sharding placed
+    on this process's first device. A checkpoint records the devices that
+    wrote it; restored without a target, orbax re-creates that placement
+    and fails wherever those devices do not exist — a CPU-built serving
+    fixture on the TPU, a 4-chip training checkpoint on one chip. Leaves
+    that carry a sharding (a resuming trainer's state) keep it."""
+    here = jax.sharding.SingleDeviceSharding(jax.local_devices()[0])
+
+    def place(leaf):
+        if (isinstance(leaf, jax.ShapeDtypeStruct)
+                and getattr(leaf, "sharding", None) is None):
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=here)
+        return leaf
+
+    return jax.tree.map(place, abstract_state)
 
 
 class CheckpointManager:
@@ -187,11 +206,7 @@ class CheckpointManager:
         forces a directory re-scan (the fallback walk needs fresh truth
         after a quarantine rename)."""
         if read:
-            try:
-                self._mgr.reload()
-            except AttributeError:  # older orbax: read kwarg instead
-                return sorted(int(s)
-                              for s in self._mgr.all_steps(read=True))
+            self._mgr.reload()
         return sorted(int(s) for s in self._mgr.all_steps())
 
     def verify(self, step: int) -> Optional[list]:
@@ -221,7 +236,7 @@ class CheckpointManager:
         restored = self._mgr.restore(
             step,
             args=ocp.args.Composite(
-                state=ocp.args.StandardRestore(abstract_state)),
+                state=ocp.args.StandardRestore(_placed_here(abstract_state))),
         )
         extra = self._read_extra(step)
         return restored["state"], extra, step
@@ -364,8 +379,19 @@ class CheckpointManager:
         if step is None:
             raise FileNotFoundError(
                 f"no checkpoint found under {self.directory}")
+        # "as saved" means the saved TREE, not the saved placement: with no
+        # target orbax re-creates the sharding the writer used, and fails
+        # wherever those devices are not this process's (a pretraining
+        # checkpoint from a 4-chip mesh seeding a 1-chip finetune). Build
+        # the target from the checkpoint's own array metadata instead.
+        saved = ocp.StandardCheckpointer().metadata(
+            os.path.join(step_dir_path(self.directory, step), "state"))
+        abstract = jax.tree.map(
+            lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype),
+            saved.item_metadata.tree)
         restored = self._mgr.restore(
-            step, args=ocp.args.Composite(state=ocp.args.StandardRestore()))
+            step, args=ocp.args.Composite(
+                state=ocp.args.StandardRestore(_placed_here(abstract))))
         return restored["state"], step
 
     def _read_extra(self, step: int) -> Dict[str, Any]:

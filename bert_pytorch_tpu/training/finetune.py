@@ -707,6 +707,7 @@ def run_task(spec, args) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
+    from bert_pytorch_tpu.compile_cache import enable_compile_cache
     from bert_pytorch_tpu.config import BertConfig, pad_vocab_size
     from bert_pytorch_tpu.parallel import dist
     from bert_pytorch_tpu.resilience import PreemptionGuard
@@ -714,14 +715,14 @@ def run_task(spec, args) -> Dict[str, Any]:
         finetune_emergency_save
     from bert_pytorch_tpu.resilience.watchdog import arm_watchdog
     from bert_pytorch_tpu.telemetry import (collect_provenance,
-                                            flops_per_seq, init_run,
-                                            lookup_peak_flops)
-    from bert_pytorch_tpu.telemetry.stepwatch import DEFAULT_PEAK
+                                            device_peak_flops,
+                                            flops_per_seq, init_run)
     from bert_pytorch_tpu.training import TrainState, make_sharded_state
     from bert_pytorch_tpu.training.checkpoint import CheckpointManager
     from bert_pytorch_tpu.training.pretrain import build_pretrain_step
 
     np.random.seed(args.seed)
+    enable_compile_cache()
     config = BertConfig.from_json_file(args.model_config_file)
     config = config.replace(vocab_size=pad_vocab_size(config.vocab_size, 8))
 
@@ -787,15 +788,15 @@ def run_task(spec, args) -> Dict[str, Any]:
             else:
                 rows = run.rows_per_step or (
                     run.batch_size * run.accum_steps * run.group_size)
-            peak = lookup_peak_flops(
-                jax.devices()[0].device_kind,
+            peak = device_peak_flops(
+                jax.devices()[0],
                 dtype=getattr(args, "dtype", None) or config.dtype)
             sw = tel.make_stepwatch(
                 flops_per_step=flops_per_seq(
                     config, run.seq_len, config.vocab_size, 0) * rows,
                 seqs_per_step=rows,
                 seq_len=run.seq_len,
-                peak_flops=(peak or DEFAULT_PEAK) * jax.device_count(),
+                peak_flops=peak and peak * jax.device_count(),
                 log_freq=run.perf_log_freq,
                 n_devices=jax.device_count())
             watchdog = arm_watchdog(

@@ -22,10 +22,8 @@ import sys
 
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
+os.environ["JAX_PLATFORMS"] = "cpu"
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 

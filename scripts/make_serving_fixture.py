@@ -16,6 +16,11 @@ answers but real latency — exactly what a load test measures.
     # -> /tmp/fixture/{vocab.txt, model_config.json, <task>_ckpt/...,
     #    serve_args.txt}
 
+`--model_config_file configs/bert_large_uncased_config.json` builds the
+fixture at a real model's width and depth instead of the tiny default
+(chip_smoke.py serves BERT-Large this way; `--tasks` keeps that to the
+heads it needs — a BERT-Large checkpoint is 1.3 GB per task).
+
 `serve_args.txt` holds the ready-made run_server.py argument list for
 the whole battery (one token per line; check_serve.sh consumes it).
 The NER head is sized for the canonical 5-label CoNLL set
@@ -42,16 +47,15 @@ _VOCAB = ["[PAD]", "[UNK]", "[CLS]", "[SEP]", "[MASK]"] + (
     "and is was . , ?").split()
 
 
-def _force_cpu() -> None:
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
-
 def build(out_dir: str, hidden: int = 32, layers: int = 2, heads: int = 4,
           max_pos: int = 128, stacked_params: bool = True,
-          max_segments: int = 8) -> dict:
+          max_segments: int = 8, model_config_file: str = None,
+          tasks=None, seed: int = 0) -> dict:
+    """Write the fixture under out_dir; returns {name: path}. The model is
+    `model_config_file`'s when given (its vocab_file replaced by the
+    fixture's tiny vocab — the embedding table keeps the file's
+    vocab_size), else the tiny hidden/layers/heads/max_pos one. `tasks`
+    restricts the battery (default: every registered task)."""
     import jax
     import jax.numpy as jnp
 
@@ -65,15 +69,27 @@ def build(out_dir: str, hidden: int = 32, layers: int = 2, heads: int = 4,
     with open(vocab_path, "w", encoding="utf-8") as f:
         f.write("\n".join(_VOCAB) + "\n")
 
-    model_cfg = {
-        "vocab_size": len(_VOCAB), "hidden_size": hidden,
-        "num_hidden_layers": layers, "num_attention_heads": heads,
-        "intermediate_size": hidden * 2, "max_position_embeddings": max_pos,
-        "hidden_dropout_prob": 0.0, "attention_probs_dropout_prob": 0.0,
-        "tokenizer": "wordpiece", "vocab_file": vocab_path,
-        "fused_ops": False, "attention_impl": "xla",
-        "stacked_params": stacked_params,
-    }
+    if model_config_file:
+        with open(model_config_file, encoding="utf-8") as f:
+            model_cfg = json.load(f)
+        if model_cfg["vocab_size"] < len(_VOCAB):
+            raise SystemExit(
+                f"{model_config_file}: vocab_size {model_cfg['vocab_size']} "
+                f"< the fixture vocab's {len(_VOCAB)} entries")
+        model_cfg.update(vocab_file=vocab_path, tokenizer="wordpiece",
+                         stacked_params=stacked_params)
+        max_pos = model_cfg["max_position_embeddings"]
+    else:
+        model_cfg = {
+            "vocab_size": len(_VOCAB), "hidden_size": hidden,
+            "num_hidden_layers": layers, "num_attention_heads": heads,
+            "intermediate_size": hidden * 2,
+            "max_position_embeddings": max_pos,
+            "hidden_dropout_prob": 0.0, "attention_probs_dropout_prob": 0.0,
+            "tokenizer": "wordpiece", "vocab_file": vocab_path,
+            "fused_ops": False, "attention_impl": "xla",
+            "stacked_params": stacked_params,
+        }
     cfg_path = os.path.join(out_dir, "model_config.json")
     with open(cfg_path, "w", encoding="utf-8") as f:
         json.dump(model_cfg, f, indent=1, sort_keys=True)
@@ -93,11 +109,11 @@ def build(out_dir: str, hidden: int = 32, layers: int = 2, heads: int = 4,
                   "--labels", *NER_LABELS,
                   "--class_names", *CLASS_NAMES,
                   "--num_choices", str(NUM_CHOICES)]
-    for task in registry.all_tasks():
+    for task in tasks or registry.all_tasks():
         spec = registry.get(task)
         model = spec.build_serving_model(config, jnp.float32, serve_opts)
-        params = unbox(model.init(jax.random.PRNGKey(0),
-                                  sample, sample, sample)["params"])
+        params = unbox(jax.jit(model.init)(jax.random.PRNGKey(seed),
+                                           sample, sample, sample)["params"])
         ckpt_dir = os.path.join(out_dir, f"{task}_ckpt")
         mgr = CheckpointManager(ckpt_dir)
         mgr.save(0, {"params": params})
@@ -114,6 +130,14 @@ def build(out_dir: str, hidden: int = 32, layers: int = 2, heads: int = 4,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", required=True)
+    ap.add_argument("--model_config_file", default=None,
+                    help="BertConfig JSON to build the fixture at (width, "
+                         "depth, heads, positions, kernels); replaces "
+                         "--hidden/--layers/--heads/--max_pos")
+    ap.add_argument("--tasks", nargs="+", default=None,
+                    help="registered tasks to build (default: all)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the random weights")
     ap.add_argument("--hidden", type=int, default=32)
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--heads", type=int, default=4)
@@ -126,7 +150,9 @@ def main(argv=None) -> int:
     paths = build(args.out, hidden=args.hidden, layers=args.layers,
                   heads=args.heads, max_pos=args.max_pos,
                   stacked_params=not args.unstacked,
-                  max_segments=args.max_segments)
+                  max_segments=args.max_segments,
+                  model_config_file=args.model_config_file,
+                  tasks=args.tasks, seed=args.seed)
     for k, v in sorted(paths.items()):
         print(f"fixture: {k}: {v}")
     print(f"fixture: ner labels: {' '.join(NER_LABELS)}")
@@ -134,5 +160,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    _force_cpu()
+    # the fixture is built on the host CPU, whatever accelerator is attached
+    # (a server may own it); read by jax when main() imports it
+    os.environ["JAX_PLATFORMS"] = "cpu"
     sys.exit(main())

@@ -6,18 +6,19 @@
 # one MULTICHIP json — so the scaling investigation is reproducible in CI
 # and on TPU with the same command.
 #
-# On a box with >= N real chips the bench runs on them; otherwise it forces
-# an N-device CPU mesh (XLA_FLAGS --xla_force_host_platform_device_count,
-# handled by bench.py itself). The per-variant time_breakdown lands inside
+# The bench needs N real chips; --cpu asks instead for a forced N-device
+# CPU mesh (XLA_FLAGS --xla_force_host_platform_device_count, set by
+# bench.py for its child). The per-variant time_breakdown lands inside
 # the output json; this wrapper additionally runs tools/trace_summary.py on
 # a standalone --profile_steps trace of run_pretraining when --train-trace
 # is requested, exercising the full operator workflow end to end.
 #
 # Usage:
-#   scripts/profile_multichip.sh [--devices N] [--out PATH] [--budget SECS]
+#   scripts/profile_multichip.sh [--devices N] [--cpu] [--out PATH] [--budget SECS]
 #   scripts/profile_multichip.sh --summarize TRACE_DIR [--steps K] [--devices N]
 #
 #   --devices N     mesh size (default 8)
+#   --cpu           measure the forced-CPU virtual mesh, not chips
 #   --out PATH      output json (default MULTICHIP_r07.json in the repo root)
 #   --budget SECS   wall-clock budget for the sweep (default 1500)
 #   --summarize D   skip the bench; just bucket an existing profiler trace
@@ -32,10 +33,12 @@ OUT=""
 BUDGET=1500
 SUMMARIZE=""
 STEPS=""
+CPU=()
 
 while [[ $# -gt 0 ]]; do
   case "$1" in
     --devices) DEVICES="$2"; DEVICES_SET=1; shift 2 ;;
+    --cpu) CPU=(--cpu); shift ;;
     --out) OUT="$2"; shift 2 ;;
     --budget) BUDGET="$2"; shift 2 ;;
     --summarize) SUMMARIZE="$2"; shift 2 ;;
@@ -57,11 +60,11 @@ fi
 ENV=(MULTICHIP_BUDGET_S="$BUDGET")
 [[ -n "$OUT" ]] && ENV+=(MULTICHIP_OUT="$OUT")
 
-# bench.py --multichip: bootstraps the mesh (forcing an N-device CPU mesh
-# when the box lacks real chips), measures every variant with an extra
-# traced window each, and embeds the trace_summary buckets per variant as
+# bench.py --multichip: bootstraps the mesh (N chips, or the forced CPU
+# mesh under --cpu), measures every variant with an extra traced window
+# each, and embeds the trace_summary buckets per variant as
 # variants.<label>.time_breakdown
-env "${ENV[@]}" python bench.py --multichip --devices "$DEVICES"
+env "${ENV[@]}" python bench.py --multichip --devices "$DEVICES" "${CPU[@]}"
 
 OUT_PATH=${OUT:-$REPO/MULTICHIP_r07.json}
 echo

@@ -2,7 +2,7 @@
 """seq512 tuning sweep: runs bench.py --child over a grid of flash block
 sizes x batch x remat policy (the policy rides the --remat child flag),
 each in a fresh subprocess with per-candidate env (FLASH_BLK_Q/K,
-BENCH_DROPOUT, FLASH_BWD).
+BENCH_DROPOUT).
 
 Appends every measurement to results/sweep512.jsonl so an interrupted sweep
 keeps its partial results. Run: python scripts/sweep512.py [--steps 20]
@@ -32,9 +32,8 @@ GRID = [
     ("blk512_b32_mlponly", 32, "auto", "mlp_only", {}),
     ("blk512_b32_dots", 32, "auto", "dots", {}),
     ("blk512_b48_mlponly", 48, "auto", "mlp_only", {}),
-    # diagnostics: dropout-mask cost and fused-vs-split backward
+    # diagnostic: dropout-mask cost
     ("blk512_b16_nodrop", 16, "auto", False, {"BENCH_DROPOUT": "0"}),
-    ("blk512_b16_splitbwd", 16, "auto", False, {"FLASH_BWD": "split"}),
     # ablation budget map: each knob isolates one subsystem's cost
     ("abl_b16_sgd", 16, "auto", False, {"BENCH_OPT": "sgd"}),
     ("abl_b16_xla_ln", 16, "auto", False, {"BENCH_FUSED": "0"}),

@@ -38,9 +38,9 @@ def main() -> None:
         sys.argv[1], int(sys.argv[2]), int(sys.argv[3]))
     ckpt_dir = sys.argv[4] if len(sys.argv) > 4 else None
 
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_procs,

@@ -10,6 +10,7 @@ driver's MULTICHIP run and the standalone dryrun."""
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -27,15 +28,17 @@ class _FakeProc:
 
 @pytest.fixture
 def cachedir(tmp_path, monkeypatch):
-    """Point the dryrun at a scratch repo dir with a pre-populated cache."""
+    """Point the dryrun at a scratch repo dir with a pre-populated private
+    cache, on a box with no accelerator and no cache given from outside."""
     here = tmp_path / "repo"
     here.mkdir()
-    cache = here / ".jax_cache"
-    cache.mkdir()
-    (cache / "jit_entry-cache").write_text("fake executable")
     monkeypatch.setattr(graft, "__file__", str(here / "__graft_entry__.py"))
-    monkeypatch.setenv("BPT_DRYRUN_FORCE_VIRTUAL", "1")
+    cache = Path(graft._dryrun_cache_dir(str(here)))
+    cache.mkdir(parents=True)
+    (cache / "jit_entry-cache").write_text("fake executable")
+    monkeypatch.setattr(graft, "accelerator_count", lambda: 0)
     monkeypatch.delenv(graft._CHILD_MARKER, raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setattr(graft, "_assert_reshard_gate_alive", lambda: None)
     return cache
 
@@ -94,3 +97,101 @@ def test_timeout_wipes_cache(cachedir, monkeypatch):
     with pytest.raises(RuntimeError, match="timed out"):
         graft.dryrun_multichip(8)
     assert not cachedir.exists()
+
+
+def test_post_gate_bench_failure_is_reported(cachedir, monkeypatch):
+    """The measurement after the gate is part of the run: when it fails the
+    dryrun fails — with the gate-clean cache kept."""
+    def fake_run(cmd, *a, **kw):
+        return _FakeProc(rc=1 if "--multichip" in cmd else 0)
+
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    with pytest.raises(RuntimeError, match="post-gate multichip bench"):
+        graft.dryrun_multichip(8)
+    assert (cachedir / "jit_entry-cache").exists()
+    assert not os.path.exists(str(cachedir) + ".dirty")
+
+
+@pytest.mark.parametrize("rc", [0, 1])
+def test_inherited_cache_dir_is_neither_set_nor_wiped(cachedir, monkeypatch,
+                                                      tmp_path, rc):
+    """A cache directory given from outside is someone else's: the gate
+    child runs with the cache off (warm entries would skip the compiles
+    that emit the warning), no directory is set over it, and no failure
+    path wipes it — nor the private one, which this run never used."""
+    outside = tmp_path / "outside_cache"
+    outside.mkdir()
+    (outside / "entry").write_text("theirs")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+    seen = {}
+
+    def fake_run(cmd, *a, env=None, **kw):
+        if "-c" in cmd:  # the gate child (the bench runs from bench.py)
+            seen.update(env)
+        return _FakeProc(rc=rc)
+
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    if rc:
+        with pytest.raises(RuntimeError, match="child failed"):
+            graft.dryrun_multichip(8)
+    else:
+        graft.dryrun_multichip(8)
+    assert seen["JAX_COMPILATION_CACHE_DIR"] == str(outside)
+    assert seen["JAX_ENABLE_COMPILATION_CACHE"] == "0"
+    assert (outside / "entry").read_text() == "theirs"
+    assert (cachedir / "jit_entry-cache").exists()
+    assert not os.path.exists(str(cachedir) + ".dirty")
+
+
+def test_failed_device_probe_is_an_error(monkeypatch):
+    """A probe that cannot ask JAX is a failure, never "0 chips"."""
+    monkeypatch.setattr(graft, "_probe_cache", {})
+    monkeypatch.setattr(
+        subprocess, "run",
+        lambda *a, **kw: _FakeProc(rc=1, stderr="libtpu: no such device"))
+    with pytest.raises(RuntimeError, match="device probe failed"):
+        graft.accelerator_count()
+
+
+@pytest.mark.parametrize("stdout,want", [
+    ("BPT_PROBE tpu 4\n", 4),
+    ("noise\nBPT_PROBE cpu 8\n", 0),   # forced host devices are not chips
+])
+def test_device_probe_counts_accelerators_only(monkeypatch, stdout, want):
+    monkeypatch.setattr(graft, "_probe_cache", {})
+    monkeypatch.setattr(subprocess, "run",
+                        lambda *a, **kw: _FakeProc(rc=0, stdout=stdout))
+    assert graft.accelerator_count() == want
+
+
+def test_compile_cache_helper_honours_inherited_dir(monkeypatch, tmp_path):
+    """bert_pytorch_tpu.compile_cache: JAX_COMPILATION_CACHE_DIR set ->
+    the directory is left to JAX and none is set in code."""
+    import jax
+
+    from bert_pytorch_tpu import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "given"))
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    assert compile_cache.enable_compile_cache() == str(tmp_path / "given")
+    assert updates == []
+
+
+def test_compile_cache_helper_defaults_to_fixed_checkout_path(monkeypatch):
+    """...and without it, the fixed <checkout>/.jax_cache — the same path on
+    every call and in every process (no temp name, pid or time)."""
+    import jax
+
+    from bert_pytorch_tpu import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert compile_cache.enable_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)] * 2

@@ -51,7 +51,73 @@ replica_groups=[1,8]<=[8], dimensions={0}
 """
 
 
+# what the TPU compiler prints: tiled layouts carry parentheses of their
+# own (T(8,128)(2,1), memory space S(1)), async collectives return tuples
+# of them, and Pallas kernels are tpu_custom_call instructions whose jax op
+# path names the kernel (wrapped by autodiff: transpose(jvp(NAME)))
+TPU_FIXTURE_HLO = """\
+HloModule jit_train_step, is_scheduled=true, num_partitions=4
+
+  %ag-start = (bf16[256,1024]{1,0:T(8,128)(2,1)S(1)}, \
+bf16[1024,1024]{1,0:T(8,128)(2,1)}) all-gather-start(%p), \
+replica_groups=[1,4]<=[4], dimensions={0}
+  %ag-done = bf16[1024,1024]{1,0:T(8,128)(2,1)} all-gather-done(%ag-start)
+  %cp-start = (bf16[96,1024]{1,0:T(8,128)(2,1)S(1)}, \
+bf16[96,1024]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) \
+collective-permute-start(%s), source_target_pairs={{0,1},{1,2},{2,3}}
+  %jvp_flash_fwd_.1 = (bf16[16,512,1024]{2,1,0:T(8,128)(2,1)S(1)}, \
+f32[16,8,2,512]{3,2,1,0:T(2,128)}) custom-call(%c, %q, %k, %v), \
+custom_call_target="tpu_custom_call", metadata={op_name=\
+"jit(train_step)/while/body/jvp(flash_fwd)/pallas_call" stack_frame_id=15}
+  %bwd.3 = (bf16[16,512,1024]{2,1,0:T(8,128)(2,1)}) custom-call(%c, %q), \
+custom_call_target="tpu_custom_call", metadata={op_name=\
+"jit(train_step)/transpose(jvp(flash_bwd_dqkv))/pallas_call"}
+  %ln.1 = bf16[8192,1024]{1,0:T(8,128)(2,1)} custom-call(%x), \
+custom_call_target="tpu_custom_call", metadata={op_name=\
+"jit(train_step)/layernorm_fwd/pallas_call"}
+  %ln.2 = bf16[8192,1024]{1,0:T(8,128)(2,1)} custom-call(%y), \
+custom_call_target="tpu_custom_call", metadata={op_name=\
+"jit(train_step)/layernorm_fwd/pallas_call"}
+  %anon = f32[8,128]{1,0:T(8,128)} custom-call(%z), \
+custom_call_target="tpu_custom_call", metadata={op_name=\
+"jit(train_step)/jvp()/pallas_call"}
+  %alloc = bf16[24,64]{1,0:T(8,128)(2,1)} custom-call(), \
+custom_call_target="AllocateBuffer"
+"""
+
+
 # --- parser units -------------------------------------------------------
+
+
+def test_parse_tpu_layouts_and_kernel_inventory():
+    """TPU tiled layouts must not hide instructions from the parser (they
+    did: every collective behind a T(8,128)(2,1) tuple went uncounted), and
+    the Pallas kernels are inventoried by the name their pallas_call gave
+    them — how chip_smoke.py proves a step took the flash / LayerNorm
+    kernels and not the XLA path."""
+    rep = hlo.parse_hlo_module(TPU_FIXTURE_HLO)
+    assert rep["num_partitions"] == 4
+    assert rep["collective_counts"] == {
+        "all-gather": 1, "all-reduce": 0, "reduce-scatter": 0,
+        "collective-permute": 1, "all-to-all": 0}
+    # an async start's tuple: only the LAST element is the collective's
+    # output... for all-gather; sized from the parsed result shapes
+    assert rep["collective_bytes"]["all-gather"] == 1024 * 1024 * 2
+    # ...and not the u32[] context scalars the TPU compiler appends
+    assert rep["collective_bytes"]["collective-permute"] == 96 * 1024 * 2
+    assert rep["kernel_counts"] == {
+        "flash_bwd_dqkv": 1, "flash_fwd": 1, "layernorm_fwd": 2,
+        "unnamed": 1}
+    assert hlo.kernel_counts(TPU_FIXTURE_HLO) == rep["kernel_counts"]
+    fp = hlo.fingerprint_of(rep)
+    assert fp["kernel_counts"] == rep["kernel_counts"]
+    # ...while a program that runs no Mosaic kernel (every CPU program)
+    # keeps the fingerprint it always had
+    cpu = hlo.parse_hlo_module(FIXTURE_HLO)
+    assert cpu["kernel_counts"] == {}
+    assert "kernel_counts" not in hlo.fingerprint_of(cpu)
+
+
 
 
 def test_parse_hlo_counts_collectives_and_ops():

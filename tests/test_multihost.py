@@ -74,11 +74,7 @@ def test_initialize_autodetects_cluster(monkeypatch):
 
     calls = []
     monkeypatch.setattr(dist, "_cluster_env_present", lambda: True)
-    # raising=False: jax < 0.5 has no is_initialized attribute at all —
-    # dist.is_initialized() probes it with getattr and falls back to the
-    # private global-state check, so injecting it here covers both paths
-    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False,
-                        raising=False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
     monkeypatch.setattr(jax.distributed, "initialize",
                         lambda *a, **k: calls.append((a, k)))
     dist.initialize()
@@ -102,9 +98,46 @@ def test_initialize_noop_when_already_up(monkeypatch):
 
     from bert_pytorch_tpu.parallel import dist
 
-    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True,
-                        raising=False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
     monkeypatch.setattr(
         jax.distributed, "initialize",
         lambda *a, **k: (_ for _ in ()).throw(AssertionError("re-init")))
     dist.initialize(num_processes=2)  # must not raise
+
+
+@pytest.mark.parametrize("env,want", [
+    ({}, False),                                     # one attached chip
+    ({"TPU_WORKER_HOSTNAMES": "localhost"}, False),  # one host, 4 chips
+    ({"TPU_WORKER_HOSTNAMES": "w0,w1,w2,w3"}, True),
+    ({"TPU_PROCESS_ADDRESSES": "10.0.0.1:8476,10.0.0.2:8476"}, True),
+    ({"MEGASCALE_NUM_SLICES": "2"}, True),
+    ({"MEGASCALE_NUM_SLICES": "1"}, False),
+])
+def test_cluster_detection_reads_env_only(monkeypatch, env, want):
+    """Single host = plain no-op: the detector decides from environment
+    variables alone — no metadata-server lookup that a sealed single-chip
+    machine would have to wait out — and initialize() starts no
+    coordinator there."""
+    import jax
+
+    from bert_pytorch_tpu.parallel import dist
+
+    for name in ("TPU_WORKER_HOSTNAMES", "TPU_PROCESS_ADDRESSES",
+                 "MEGASCALE_NUM_SLICES"):
+        monkeypatch.delenv(name, raising=False)
+    for name, value in env.items():
+        monkeypatch.setenv(name, value)
+
+    def no_network(*a, **k):
+        raise AssertionError("cluster detection touched the network")
+
+    monkeypatch.setattr(socket, "getaddrinfo", no_network)
+    monkeypatch.setattr(socket, "create_connection", no_network)
+    assert dist._cluster_env_present() is want
+
+    calls = []
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
+    monkeypatch.setattr(jax.distributed, "initialize",
+                        lambda *a, **k: calls.append((a, k)))
+    dist.initialize()
+    assert calls == ([((), {})] if want else [])

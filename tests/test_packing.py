@@ -131,7 +131,8 @@ def _dense_block_diag(q, k, v, seg):
     ("bh", "split", "1"),
 ])
 def test_flash_segments_match_dense_reference(layout, bwd, skip,
-                                              monkeypatch):
+                                              monkeypatch,
+                                              force_flash_path):
     """Packed forward/backward vs the block-diagonal dense reference, on
     every kernel path: native + bh layouts, fused + split backwards, block
     skipping on and off. 128-wide blocks force multi-tile rows so the
@@ -141,8 +142,7 @@ def test_flash_segments_match_dense_reference(layout, bwd, skip,
     fa = importlib.import_module(
         'bert_pytorch_tpu.ops.pallas.flash_attention')
 
-    monkeypatch.setenv("FLASH_LAYOUT", layout)
-    monkeypatch.setenv("FLASH_BWD", bwd)
+    force_flash_path(layout, bwd)
     monkeypatch.setenv("FLASH_SEG_SKIP", skip)
     monkeypatch.setattr(fa, "DEFAULT_BLK_Q", 128)
     monkeypatch.setattr(fa, "DEFAULT_BLK_K", 128)
@@ -171,7 +171,8 @@ def test_flash_segments_match_dense_reference(layout, bwd, skip,
                                    rtol=5e-4, atol=5e-4)
 
 
-def test_flash_segments_with_dropout_layout_parity(monkeypatch):
+def test_flash_segments_with_dropout_layout_parity(monkeypatch,
+                                                   force_flash_path):
     """Dropout + segments: native and bh layouts draw identical keep-masks
     (cross-layout bit-parity contract), so outputs agree to float tolerance
     and zero patterns exactly on valid positions."""
@@ -186,7 +187,7 @@ def test_flash_segments_with_dropout_layout_parity(monkeypatch):
 
     outs = {}
     for layout in ("native", "bh"):
-        monkeypatch.setenv("FLASH_LAYOUT", layout)
+        force_flash_path(layout)
         outs[layout] = np.asarray(fa.flash_attention(
             q, k, v, segment_ids=seg, dropout_seed=seed, dropout_rate=0.3,
             interpret=True))
@@ -194,7 +195,8 @@ def test_flash_segments_with_dropout_layout_parity(monkeypatch):
                                rtol=1e-6, atol=1e-6)
 
 
-def test_flash_segments_no_cross_contamination_bit_identical(monkeypatch):
+def test_flash_segments_no_cross_contamination_bit_identical(
+        monkeypatch, force_flash_path):
     """Perturbing every token of segment 1 leaves segments 2 and 3 of the
     same row BIT-identical — cross-segment probabilities are exact fp32
     zeros, not merely small."""
@@ -204,7 +206,7 @@ def test_flash_segments_no_cross_contamination_bit_identical(monkeypatch):
     monkeypatch.setattr(fa, "DEFAULT_BLK_Q", 128)
     monkeypatch.setattr(fa, "DEFAULT_BLK_K", 128)
     for layout in ("native", "bh"):
-        monkeypatch.setenv("FLASH_LAYOUT", layout)
+        force_flash_path(layout)
         q, k, v, seg = _packed_qkv()
         seg_np = np.asarray(seg)
         q2 = q.at[0, :100].add(1.0)
@@ -237,7 +239,7 @@ def test_xla_fallback_matches_flash_segments():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_pad_rows_zeroed_on_every_path(monkeypatch):
+def test_pad_rows_zeroed_on_every_path(monkeypatch, force_flash_path):
     """Pad (segment-0) positions produce EXACT-zero attention outputs on
     every forward path — both kernel layouts, skip on and off, and the XLA
     fallback — so downstream consumers of full (B, S, E) hidden states
@@ -254,7 +256,7 @@ def test_pad_rows_zeroed_on_every_path(monkeypatch):
     assert pad.any()
     for layout in ("native", "bh"):
         for skip in ("1", "0"):
-            monkeypatch.setenv("FLASH_LAYOUT", layout)
+            force_flash_path(layout)
             monkeypatch.setenv("FLASH_SEG_SKIP", skip)
             out = np.asarray(fa.flash_attention(q, k, v, segment_ids=seg,
                                                 interpret=True))

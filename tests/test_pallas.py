@@ -217,10 +217,10 @@ def test_flash_forward_no_bias():
 
 
 @pytest.mark.parametrize("bwd", ["fused", "split"])
-def test_flash_grads_match_reference(bwd, monkeypatch):
+def test_flash_grads_match_reference(bwd, force_flash_path):
     # both backward paths: the fused dq/dk/dv kernel (default, S <= 2048)
-    # and the split two-kernel fallback that serves longer sequences
-    monkeypatch.setenv("FLASH_BWD", bwd)
+    # and the split two-kernel path that serves longer sequences
+    force_flash_path("native" if bwd == "fused" else "bh", bwd)
     q, k, v, bias = _qkv(s=128)
 
     def loss_flash(q, k, v):
@@ -263,7 +263,7 @@ def test_flash_dropout_deterministic_and_unbiased():
 
 
 @pytest.mark.parametrize("bwd", ["fused", "split"])
-def test_flash_dropout_grads_flow(bwd, monkeypatch):
+def test_flash_dropout_grads_flow(bwd, force_flash_path):
     """The dropout backward (masks regenerated in-kernel) must equal
     autodiff of a pure-jnp mirror applying the IDENTICAL keep mask. This
     replaces the original single-coordinate finite-difference check, which
@@ -273,7 +273,7 @@ def test_flash_dropout_grads_flow(bwd, monkeypatch):
     to 1e-8 against the exact-mask mirror)."""
     from bert_pytorch_tpu.ops.pallas.flash_attention import _keep_mask
 
-    monkeypatch.setenv("FLASH_BWD", bwd)
+    force_flash_path("native" if bwd == "fused" else "bh", bwd)
     b, s, h, d = 2, 128, 4, 64
     q, k, v, bias = _qkv(s=s)
     seed = jnp.array(3, jnp.int32)
@@ -304,12 +304,12 @@ def test_flash_dropout_grads_flow(bwd, monkeypatch):
         np.testing.assert_allclose(arr, np.asarray(r), rtol=5e-4, atol=5e-5)
 
 
-def test_flash_native_layout_matches_bh_layout(monkeypatch):
-    """The native (B, S, H, D) kernels (default where VMEM allows) and the
-    transposing (BH, S, D) grid are the SAME computation: outputs match to
-    float tolerance and the dropout keep-masks are bit-identical (the
-    native head loop folds batch*H + head into the hash counter — exactly
-    the bh grid's program id)."""
+def test_flash_native_layout_matches_bh_layout(force_flash_path):
+    """The native (B, S, H*D) addressing (default where heads tile into
+    128-lane blocks) and the transposing (BH, S, D) grid are the SAME
+    computation: outputs match to float tolerance and the dropout
+    keep-masks are bit-identical (both fold batch*H + head into the hash
+    counter)."""
     from bert_pytorch_tpu.ops.pallas.flash_attention import _use_native
 
     q, k, v, bias = _qkv(s=256)
@@ -317,7 +317,7 @@ def test_flash_native_layout_matches_bh_layout(monkeypatch):
     assert _use_native(256, 4, 64)
 
     def run(layout):
-        monkeypatch.setenv("FLASH_LAYOUT", layout)
+        force_flash_path(layout)
         out = flash_attention(q, k, v, bias=bias, interpret=True)
         drop = flash_attention(q, k, v, bias=bias, dropout_seed=seed,
                                dropout_rate=0.3, interpret=True)
@@ -340,15 +340,50 @@ def test_flash_native_layout_matches_bh_layout(monkeypatch):
                                    rtol=5e-5, atol=5e-5)
 
 
-def test_flash_native_gate_respects_vmem_budget(monkeypatch):
+def test_flash_layout_chosen_by_shape_alone(monkeypatch):
+    """One layout per shape, whatever the environment says: native where
+    heads tile into 128-lane blocks and the fused backward fits them, the
+    transposing grid elsewhere."""
     from bert_pytorch_tpu.ops.pallas.flash_attention import _use_native
 
-    monkeypatch.delenv("FLASH_LAYOUT", raising=False)
-    monkeypatch.delenv("FLASH_BWD", raising=False)
-    assert _use_native(512, 16, 64)        # BERT-Large phase 2: fits
-    assert not _use_native(2048, 16, 64)   # long context: transpose path
-    monkeypatch.setenv("FLASH_BWD", "split")  # split kernels are bh-only
-    assert not _use_native(512, 16, 64)
+    monkeypatch.setenv("FLASH_LAYOUT", "bh")   # the removed switches
+    monkeypatch.setenv("FLASH_BWD", "split")
+    assert _use_native(512, 16, 64)        # BERT-Large phase 2
+    assert _use_native(128, 12, 64)        # BERT-Base phase 1
+    assert _use_native(1024, 8, 128)
+    assert not _use_native(2048, 16, 64)   # fused bwd: one head / program
+    assert not _use_native(4096, 16, 64)   # long context: split kernels
+    assert not _use_native(512, 3, 64)     # odd head count at D=64
+    assert not _use_native(512, 4, 48)     # D does not tile 128 lanes
+
+
+@pytest.mark.parametrize("seq,interpret,match", [
+    (128, "0", "needs a TPU backend"),        # off-TPU, no interpret mode
+    (96, "1", "multiple of 128"),             # kernel cannot tile the shape
+])
+def test_explicit_pallas_raises_instead_of_silent_xla(seq, interpret, match,
+                                                      monkeypatch):
+    """impl="pallas" asked for by name gets the kernel or an error — never
+    the XLA result under the kernel's name. "auto" may still choose XLA."""
+    from bert_pytorch_tpu.ops import attention
+
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", interpret)
+    q, k, v, _ = _qkv(s=seq)
+    with pytest.raises(ValueError, match=match):
+        attention.dot_product_attention(q, k, v, impl="pallas")
+    auto = attention.dot_product_attention(q, k, v, impl="auto")
+    xla = attention.dot_product_attention(q, k, v, impl="xla")
+    np.testing.assert_array_equal(np.asarray(auto), np.asarray(xla))
+
+
+def test_explicit_pallas_runs_the_kernel_in_interpret_mode(monkeypatch):
+    from bert_pytorch_tpu.ops import attention
+
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    q, k, v, bias = _qkv(s=128)
+    got = attention.dot_product_attention(q, k, v, bias=bias, impl="pallas")
+    want = flash_attention(q, k, v, bias=bias, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # -- multi-tensor -----------------------------------------------------------

@@ -202,12 +202,12 @@ def test_index_regenerates_deterministically(tmp_path):
         open(os.path.join(REPO, "RUNS.md"), "rb").read())
 
 
-def test_index_contents_cover_all_rounds():
+def test_index_contents_cover_all_rounds(tmp_path):
     records = index_records(REPO)
     bench = [r for r in records if r["kind"] == "bench"]
     mc = [r for r in records if r["kind"] == "multichip"]
     assert [r["round"] for r in bench] == [1, 2, 3, 4, 5, 6]
-    assert [r["round"] for r in mc] == [1, 2, 3, 4, 5, 6, 7, 8, 9]
+    assert [r["round"] for r in mc] == [2, 3, 4, 5, 6, 7, 8, 9]
     r07 = next(r for r in mc if r["round"] == 7)
     assert r07["measured"] and r07["ok"]
     assert r07["metrics"]["dp_zero1_overlap.scaling_efficiency"] == 0.2206
@@ -222,8 +222,13 @@ def test_index_contents_cover_all_rounds():
 
     assert metric_direction("stream.tokens_per_sec") == "higher"
     assert metric_direction("stream.data_wait_fraction") == "lower"
-    # failed artifacts indexed honestly, not dropped
-    r01 = next(r for r in mc if r["round"] == 1)
+    # failed artifacts indexed honestly, not dropped (a dryrun-era record
+    # of a run that died: rc != 0, no variants)
+    (tmp_path / "MULTICHIP_r01.json").write_text(json.dumps(
+        {"n_devices": 8, "rc": 1, "ok": False, "skipped": False,
+         "tail": "RuntimeError: 1 devices visible, need 8"}))
+    (r01,) = index_records(str(tmp_path))
+    assert r01["round"] == 1 and r01["kind"] == "multichip"
     assert not r01["ok"] and not r01["measured"]
 
 

@@ -257,10 +257,70 @@ def test_stepwatch_steps_per_loop_counting():
     assert rec["seq_per_sec"] == pytest.approx(16.0)
 
 
+def test_compile_watch_counts_persistent_cache_hits_and_detaches():
+    """`compile_cache_hits` = executables JAX loaded from the persistent
+    cache instead of compiling (its own monitoring event) — the number
+    chip_smoke.py prints so a cache that never hits is visible. uninstall()
+    really detaches (jax.monitoring's public unregister)."""
+    import jax.monitoring
+    from jax._src import monitoring as _m
+
+    cw = CompileWatch().install()
+    n_listeners = len(_m.get_event_listeners())
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert cw.snapshot()["compile_cache_hits"] == 2
+    cw.uninstall()
+    assert len(_m.get_event_listeners()) == n_listeners - 1
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    assert cw.snapshot()["compile_cache_hits"] == 2
+    cw.uninstall()      # idempotent
+
+
 def test_lookup_peak_flops():
     assert lookup_peak_flops("TPU v5 lite") == 197e12
     assert lookup_peak_flops("TPU v5p chip") == 459e12
-    assert lookup_peak_flops("cpu") is None
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v9 mega", ""])
+def test_lookup_peak_flops_unknown_kind_raises(kind):
+    """A device the table does not know is an error, never a default peak:
+    MFU against a guessed figure is a wrong number under a trusted name."""
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        lookup_peak_flops(kind)
+    from bert_pytorch_tpu.telemetry import stepwatch
+
+    assert not hasattr(stepwatch, "DEFAULT_PEAK")
+
+
+def test_device_peak_flops_by_platform():
+    """The MFU entry points' lookup: no MFU on the CPU backend (None), the
+    table on an accelerator, an error for an accelerator it does not know."""
+    from types import SimpleNamespace as Dev
+
+    from bert_pytorch_tpu.telemetry import device_peak_flops
+
+    assert device_peak_flops(jax.devices()[0]) is None      # the CPU mesh
+    assert device_peak_flops(
+        Dev(platform="tpu", device_kind="TPU v5 lite")) == 197e12
+    assert device_peak_flops(
+        Dev(platform="tpu", device_kind="TPU v5 lite"),
+        dtype="float32") == 98.5e12
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        device_peak_flops(Dev(platform="tpu", device_kind="TPU v9 mega"))
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        device_peak_flops(Dev(platform="gpu", device_kind="H100"))
+
+
+def test_stepwatch_without_peak_reports_no_mfu():
+    clock = [0.0]
+    sw = StepWatch(flops_per_step=1e9, seqs_per_step=8, seq_len=128,
+                   peak_flops=None, log_freq=1, time_fn=lambda: clock[0])
+    clock[0] = 1.0
+    rec = sw.step_done()
+    assert rec["model_flops_per_sec"] == 1e9
+    assert "mfu" not in rec and "peak_flops" not in rec
 
 
 def test_lookup_peak_flops_dtype_aware():
@@ -274,7 +334,6 @@ def test_lookup_peak_flops_dtype_aware():
     assert lookup_peak_flops("TPU v5p chip", dtype="float32") == 229.5e12
     # config.dtype strings pass straight through
     assert lookup_peak_flops("TPU v5 lite", dtype="float32") == 98.5e12
-    assert lookup_peak_flops("cpu", dtype="f32") is None
     with pytest.raises(ValueError):
         lookup_peak_flops("TPU v4", dtype="int8")
 
@@ -406,7 +465,9 @@ def test_run_pretraining_logs_perf_and_health_through_sinks(workdir):
         assert r["step_time_ms"] > 0
         assert r["seq_per_sec"] > 0
         assert r["tokens_per_sec"] > 0
-        assert "mfu" in r and r["peak_flops"] > 0
+        # the CPU backend has no peak to quote against: no MFU reported
+        assert r["model_flops_per_sec"] > 0
+        assert "mfu" not in r and "peak_flops" not in r
         assert "data_wait_ms" in r and "dispatch_ms" in r
         assert r["compiles"] >= 1
     # warmup closed at the first interval; no recompiles in this run
@@ -415,8 +476,8 @@ def test_run_pretraining_logs_perf_and_health_through_sinks(workdir):
     # same fields reached the CSV sink (header-union schema)
     header = open(out / "logfile_metrics.csv",
                   encoding="utf-8").readline().strip().split(",")
-    for col in ("step_loss", "grad_nonfinite", "seq_per_sec", "mfu",
-                "data_wait_ms"):
+    for col in ("step_loss", "grad_nonfinite", "seq_per_sec",
+                "model_flops_per_sec", "data_wait_ms"):
         assert col in header
     # and the text sink
     txt = (out / "logfile.txt").read_text()

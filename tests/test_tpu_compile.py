@@ -1,0 +1,188 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler for a
+described (not attached) v5e at BERT-Large shapes.
+
+Interpret mode proves a kernel's arithmetic; it cannot show what Mosaic
+refuses — a store it cannot lay out, a block that breaks the tiling rule,
+more VMEM than a kernel may use. libtpu is installed with the test
+environment and compiles for a topology that is only described
+(jax.experimental.topologies), so these checks need no chip: each lowers
+one jitted call on ShapeDtypeStructs placed on a described v5e device and
+asserts the executable holds the expected kernels, by name
+(analysis/hlo.kernel_counts over its `tpu_custom_call`s). Nothing runs,
+so nothing here says a result or a time is right — tests/test_pallas.py and
+chip_smoke.py do that.
+
+Skipped only where the topology cannot be described (no libtpu).
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from bert_pytorch_tpu.analysis import hlo
+from bert_pytorch_tpu.ops.pallas import fused_optim
+from bert_pytorch_tpu.ops.pallas.layernorm import (
+    add_dropout_layer_norm_pallas, layer_norm_pallas)
+
+fa = importlib.import_module("bert_pytorch_tpu.ops.pallas.flash_attention")
+
+H, D, E = 16, 64, 1024      # configs/bert_large_uncased_config.json
+
+# kernels of one differentiated flash call, by backward variant
+FUSED = {"flash_fwd": 1, "flash_bwd_dqkv": 1}
+SPLIT = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding that places an abstract array on one described v5e chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / topology unknown to it
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """An executable compiled for a described chip can be written to the
+    persistent cache but not read back without one: keep the cache off
+    around these compiles, whatever the environment enabled."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _kernels(fn, *args) -> dict:
+    """Compile fn(*args) for the described chip — raising what the chip's
+    compiler would raise — and name the Mosaic kernels in the executable."""
+    return hlo.kernel_counts(jax.jit(fn).lower(*args).compile().as_text())
+
+
+def _flash_case(v5e, b, s, *, bias, segments, rate, grad):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+    q = sds((b, s, H, D), jnp.bfloat16)
+    bias_a = sds((b, 1, 1, s), jnp.float32) if bias else None
+    seg_a = sds((b, s), jnp.int32) if segments else None
+    seed_a = sds((), jnp.int32) if rate > 0 else None
+
+    def fwd(q, k, v, bias, seg, seed):
+        return fa.flash_attention(q, k, v, bias, seg, seed, rate, False)
+
+    def bwd(q, k, v, bias, seg, seed):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v, bias, seg, seed)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    return _kernels(bwd if grad else fwd, q, q, q, bias_a, seg_a, seed_a)
+
+
+# (batch, seq) of the phase-1 / phase-2 recipes on one 16 GB chip
+@pytest.mark.parametrize("b,s", [(64, 128), (16, 512)])
+@pytest.mark.parametrize("bias,segments,rate", [
+    (True, False, 0.1),     # padded batches: mask bias + dropout
+    (False, True, 0.1),     # packed batches: segment ids + dropout
+])
+def test_flash_train_step_kernels_compile(v5e, b, s, bias, segments, rate):
+    assert fa._use_native(s, H, D)
+    assert _flash_case(v5e, b, s, bias=bias, segments=segments, rate=rate,
+                       grad=True) == FUSED
+
+
+@pytest.mark.parametrize("bias,segments", [(True, False), (False, True)])
+def test_flash_serving_forward_compiles(v5e, bias, segments):
+    # run_server's bucket 512 at its default batch_rows, packing on/off
+    assert _flash_case(v5e, 8, 512, bias=bias, segments=segments, rate=0.0,
+                       grad=False) == {"flash_fwd": 1}
+
+
+@pytest.mark.parametrize("bias,segments", [(True, False), (False, True)])
+def test_flash_split_backward_compiles(v5e, bias, segments,
+                                       force_flash_path):
+    """The split dq / dkv kernels (the long-sequence backward, bh layout)
+    at the BERT-Large head shape."""
+    force_flash_path("bh", "split")
+    assert _flash_case(v5e, 16, 512, bias=bias, segments=segments, rate=0.1,
+                       grad=True) == SPLIT
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("b,s,split", [
+    (32, 384, False),   # SQuAD finetune length (scripts/run_squad.sh)
+    (8, 1024, False),   # largest native shape: two D=64 heads per program
+    (4, 2048, False),   # bh layout, fused backward at its VMEM bound
+    (2, 4096, True),    # beyond it: split backward
+])
+def test_flash_other_lengths_compile(v5e, b, s, split):
+    want = SPLIT if split else FUSED
+    assert _flash_case(v5e, b, s, bias=True, segments=False, rate=0.1,
+                       grad=True) == want
+    assert _flash_case(v5e, b, s, bias=False, segments=True, rate=0.1,
+                       grad=True) == want
+
+
+# rows x E of a seq-512 batch of 16 and a seq-128 batch of 64, as the model
+# calls them (B, S, E)
+@pytest.mark.parametrize("shape", [(16, 512, E), (64, 128, E)])
+@pytest.mark.parametrize("fused_residual", [False, True])
+def test_layernorm_kernels_compile(v5e, shape, fused_residual):
+    sds = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=v5e)  # noqa: E731
+    x = sds(shape, jnp.bfloat16)
+    w = sds((E,), jnp.float32)
+    seed = sds((), jnp.int32)
+
+    if fused_residual:
+        def f(x, res, scale, bias, seed):
+            return add_dropout_layer_norm_pallas(
+                x, res, scale, bias, seed, 0.1, 1e-12, False)
+        args = (x, x, w, w, seed)
+        diff = (0, 1, 2, 3)
+    else:
+        def f(x, scale, bias):
+            return layer_norm_pallas(x, scale, bias, 1e-12, False)
+        args = (x, w, w)
+        diff = (0, 1, 2)
+
+    def g(*a):
+        return jax.grad(lambda *b: f(*b).astype(jnp.float32).sum(),
+                        argnums=diff)(*a)
+
+    name = "add_dropout_layernorm" if fused_residual else "layernorm"
+    assert _kernels(f, *args) == {f"{name}_fwd": 1}
+    assert _kernels(g, *args) == {f"{name}_fwd": 1, f"{name}_bwd": 1}
+
+
+def test_fused_optimizer_stages_compile(v5e, monkeypatch):
+    """Both multi-tensor LAMB stages over one bucket of the default size
+    (parallel/coalesce.DEFAULT_BUCKET_BYTES)."""
+    # the stage dispatchers pick interpret mode from the live backend,
+    # which is the CPU here: steer them onto the kernel path in the test
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = fused_optim.DEFAULT_BUCKET_BYTES // 4
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=v5e)
+    scal = jax.ShapeDtypeStruct((1, 3), jnp.float32, sharding=v5e)
+
+    def stage1(scal, g, mu, nu, pf, wd):
+        return fused_optim._stage1_flat(scal, g, mu, nu, pf, wd, b1=0.9,
+                                        b2=0.999, eps=1e-6, use_pallas=True)
+
+    def stage2(t, u):
+        return fused_optim._stage2_flat(t, u, use_pallas=True)
+
+    assert _kernels(stage1, scal, vec, vec, vec, vec, vec) == {
+        "lamb_stage1": 1}
+    assert _kernels(stage2, vec, vec) == {"lamb_stage2": 1}

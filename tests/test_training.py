@@ -330,3 +330,63 @@ def test_lamb_per_layer_trust_ratio():
                                     {"w": stacked_p[i]})
         np.testing.assert_allclose(np.asarray(upd_stacked["w"][i]),
                                    np.asarray(upd_i["w"]), rtol=1e-6)
+
+
+_SAVE_ON_DEVICES_4_TO_7 = """
+import sys
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from bert_pytorch_tpu.training.checkpoint import CheckpointManager
+mesh = Mesh(np.asarray(jax.devices()[4:8]), ("data",))
+w = jax.device_put(jnp.arange(32.0).reshape(8, 4),
+                   NamedSharding(mesh, P("data")))
+b = jax.device_put(jnp.ones((3,)), NamedSharding(mesh, P()))
+mgr = CheckpointManager(sys.argv[1])
+mgr.save(0, {"params": {"w": w, "b": b}})
+mgr.close()
+"""
+
+_RESTORE_ON_ONE_DEVICE = """
+import sys
+import jax, jax.numpy as jnp, numpy as np
+from bert_pytorch_tpu.training.checkpoint import CheckpointManager
+assert jax.device_count() == 1
+mgr = CheckpointManager(sys.argv[1])
+raw, step = mgr.restore_raw()
+template = {"params": {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
+                       "b": jax.ShapeDtypeStruct((3,), jnp.float32)}}
+state, _, _ = mgr.restore_either_layout(template)
+mgr.close()
+for tree in (raw, state):
+    np.testing.assert_array_equal(np.asarray(tree["params"]["w"]),
+                                  np.arange(32.0).reshape(8, 4))
+    assert tree["params"]["w"].devices() == {jax.devices()[0]}
+print("RESTORED_ELSEWHERE_OK")
+"""
+
+
+def test_checkpoint_restores_on_other_devices_than_wrote_it(tmp_path):
+    """A checkpoint names the devices that wrote it. Restored with a
+    template that names no sharding (the serving restore) or with none at
+    all (restore_raw, the transfer load), it must land on THIS process's
+    devices — the chip run found a CPU-built serving fixture unservable on
+    the TPU ("Device TFRT_CPU_0 was not found"), and a 4-chip training
+    checkpoint would seed no 1-chip finetune. Two processes: devices 4-7
+    of an 8-device platform write, a 1-device platform reads."""
+    import re
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ckpt = str(tmp_path / "ckpt")
+    for code, n_devices in ((_SAVE_ON_DEVICES_4_TO_7, 8),
+                            (_RESTORE_ON_ONE_DEVICE, 1)):
+        flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
+                       f"--xla_force_host_platform_device_count={n_devices}",
+                       os.environ["XLA_FLAGS"])
+        proc = subprocess.run(
+            [sys.executable, "-c", code, ckpt], capture_output=True,
+            text=True, timeout=300, cwd=repo,
+            env=dict(os.environ, XLA_FLAGS=flags, PYTHONPATH=repo))
+        assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "RESTORED_ELSEWHERE_OK" in proc.stdout

@@ -310,9 +310,6 @@ def _force_cpu_devices() -> None:
             flags + f" --xla_force_host_platform_device_count={N_DEVICES}"
         ).strip()
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 
 def _gate_config(dtype: str, kfac: bool, layers: int = 2):

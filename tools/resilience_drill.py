@@ -177,7 +177,9 @@ def subprocess_env() -> Dict[str, str]:
     """Child env: CPU backend, 8-device host platform (matching
     tests/conftest.py so every session compiles the identical sharded
     program), repo importable. NOTE: deliberately no persistent
-    compilation cache — a SIGKILLed session can tear the cache entry it
+    compilation cache (run_pretraining enables one by default; it is
+    switched off here through JAX's own setting, whatever directory the
+    environment names) — a SIGKILLed session can tear the cache entry it
     was writing and the restarted session segfaults loading it (the
     drill found its own torn-write failure in that layer)."""
     env = dict(os.environ)
@@ -195,7 +197,7 @@ def subprocess_env() -> Dict[str, str]:
          " --xla_backend_optimization_level=0").strip()
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
     return env
 
 
@@ -219,9 +221,6 @@ def _ensure_cpu8() -> None:
         os.environ["XLA_FLAGS"] = \
             (flags + " --xla_force_host_platform_device_count=8").strip()
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 
 def final_params(out_dir: str) -> Dict[str, "object"]:
