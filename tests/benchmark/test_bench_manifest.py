@@ -1,0 +1,183 @@
+"""The manifest and the files it names: the contract's form, every file
+found by name, and that a cell, a configuration, a traffic mix, a per-layer
+metric and a reader are added as NEW files with no edit of an existing one."""
+
+import json
+import os
+import re
+import shutil
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import sys  # noqa: E402
+
+sys.path.insert(0, ROOT)
+from benchmark.harness import spec  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+MANIFEST = spec.load_manifest(ROOT)
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+
+
+def test_manifest_keys_and_sizes():
+    assert sorted(MANIFEST) == sorted([
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"])
+    assert 1 <= MANIFEST["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 65536
+    assert MANIFEST["paths"] == ["benchmark", "tests/benchmark"]
+    four = [w for w in MANIFEST["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 4)
+    assert {w["config"] for w in MANIFEST["workloads"]} == {
+        c["name"] for c in MANIFEST["configs"]}
+    assert any(m["name"] == "setup_s" and m["bound"] <= 0.1
+               for m in MANIFEST["end_to_end"])
+    for m in MANIFEST["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+
+
+def _names():
+    for kind in ("configs", "workloads", "end_to_end", "per_layer"):
+        for entry in MANIFEST[kind]:
+            yield kind, entry["name"]
+    for w in MANIFEST["workloads"]:
+        yield "traffic", w["traffic"]
+    for c in MANIFEST["configs"]:
+        for key in c["reduced"]:
+            yield "reduced", key
+
+
+@pytest.mark.parametrize("kind,name", sorted(set(_names())))
+def test_name_is_of_the_allowed_characters(kind, name):
+    assert NAME.match(name), (kind, name)
+
+
+@pytest.mark.parametrize("metric", MANIFEST["end_to_end"]
+                         + MANIFEST["per_layer"], ids=lambda m: m["name"])
+def test_metric_entry(metric):
+    assert UNIT.match(metric["unit"])
+    assert metric["better"] in ("lower", "higher")
+    assert metric["source"] in ("device_trace", "program_span",
+                                "program_counter", "host_clock")
+    allowed = {"name", "unit", "better", "source", "workloads"}
+    allowed |= ({"bound"} if "bound" in metric else {"layer", "moves"})
+    assert set(metric) <= allowed
+    for cell in metric.get("workloads", []):
+        assert cell in CELLS
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_found_by_name(cell):
+    found = spec.find_cell(MANIFEST, cell, ROOT)
+    assert found["config"]["hidden_size"] in (768, 1024)
+    traffic = found["traffic"]
+    spec.load_driver(traffic["driver"], ROOT)
+    assert traffic["data_shards"] == found["chips"]
+    for key in ("loss_rel", "grad_gap", "delta_gap", "grad_diff"):
+        assert traffic["limits"][key] > 0
+    # every cell reports setup_s, one more end-to-end and one per-layer metric
+    e2e = [m["name"] for m in spec.metrics_of_cell(MANIFEST, cell,
+                                                   "end_to_end")]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert spec.metrics_of_cell(MANIFEST, cell, "per_layer")
+
+
+@pytest.mark.parametrize("config", MANIFEST["configs"],
+                         ids=lambda c: c["name"])
+def test_configuration_file(config):
+    cfg = spec.load_json(os.path.join(ROOT, config["file"]))
+    assert config["file"].startswith("benchmark/configs/")
+    assert cfg["source"] == config["source"]
+    assert sorted(cfg["reduced"]) == sorted(config["reduced"])
+    widths = ("hidden_size", "intermediate_size", "num_attention_heads")
+    assert not [k for k in config["reduced"]
+                if k in widths or k.endswith(("_dim", "_rank"))]
+    assert cfg["hidden_size"] // cfg["num_attention_heads"] == 64
+
+
+@pytest.mark.parametrize("name", LAYER)
+def test_layer_metric_file_matches_the_manifest(name):
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+    on_file = spec.load_layer_metric(name, ROOT)
+    for key in ("layer", "unit", "better", "source", "moves"):
+        assert on_file[key] == entry[key], key
+    assert callable(spec.load_reader(on_file["reader"], ROOT))
+    moved = next(m for m in MANIFEST["end_to_end"]
+                 if m["name"] == entry["moves"])
+    for cell in entry.get("workloads", CELLS):
+        assert "workloads" not in moved or cell in moved["workloads"]
+
+
+def test_additions_are_new_files_only(tmp_path):
+    """A later PR's cell, configuration, traffic mix, metric and reader:
+    five new files and four new entries, nothing edited."""
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmark"),
+                    os.path.join(root, "benchmark"))
+    manifest = json.loads(json.dumps(MANIFEST))
+    base = spec.find_cell(MANIFEST, CELLS[0], ROOT)
+    cfg = dict(base["config"], num_hidden_layers=2, source="paper:dummy")
+    with open(os.path.join(root, "benchmark/configs/dummy.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(root, "benchmark/traffic/dummy-mix.json"),
+              "w") as f:
+        json.dump(dict(base["traffic"], seq_len=64), f)
+    with open(os.path.join(root, "benchmark/layer_metrics/dummy_ms.json"),
+              "w") as f:
+        json.dump({"layer": "host loop and data plane", "unit": "ms",
+                   "better": "lower", "source": "program_span",
+                   "moves": "train_tokens_per_s_chip",
+                   "reader": "dummy_reader", "args": {"scale": 2.0}}, f)
+    with open(os.path.join(root, "benchmark/readers/dummy_reader.py"),
+              "w") as f:
+        f.write("def read(ctx, scale):\n    return ctx['x'] * scale\n")
+    manifest["configs"].append({
+        "name": "dummy", "source": "paper:dummy",
+        "file": "benchmark/configs/dummy.json",
+        "reduced": ["num_hidden_layers"], "why": "test"})
+    manifest["workloads"].append({
+        "name": "dummy.cell", "config": "dummy", "traffic": "dummy-mix",
+        "chips": 1, "why": "test"})
+    manifest["per_layer"].append({
+        "name": "dummy_ms", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "host loop and data plane",
+        "moves": "train_tokens_per_s_chip", "workloads": ["dummy.cell"]})
+    found = spec.find_cell(manifest, "dummy.cell", root)
+    assert found["config"]["num_hidden_layers"] == 2
+    assert found["traffic"]["seq_len"] == 64
+    got = spec.read_layer_metrics(
+        {**manifest, "per_layer": manifest["per_layer"][-1:]},
+        "dummy.cell", {"x": 21.0}, root)
+    assert got == {"dummy_ms": {"value": 42.0, "unit": "ms"}}
+    # and the cells that were there do not report the new metric
+    assert "dummy_ms" not in [
+        m["name"] for m in spec.metrics_of_cell(manifest, CELLS[0],
+                                                "per_layer")]
+
+
+def test_unknown_names_are_errors():
+    with pytest.raises(spec.SpecError):
+        spec.find_cell(MANIFEST, "no-such-cell", ROOT)
+    with pytest.raises(spec.SpecError):
+        spec.load_reader("no_such_reader", ROOT)
+
+
+@pytest.mark.parametrize("peak,limit,rehearse,want", [
+    (6_869_903_872, 16_909_336_064, False, 6_869_903_872),
+    (0, 16_909_336_064, False, None),            # the compiler states none
+    (17_670_484_992, 16_909_336_064, False, None),   # more than the chip has
+    (0, 0, True, 0),                             # the CPU states none
+], ids=["fits", "none-stated", "over-the-device", "rehearsal"])
+def test_memory_peak_is_the_compilers_and_has_to_fit(peak, limit, rehearse,
+                                                     want):
+    driver = spec._load_module(
+        os.path.join(ROOT, "benchmark", "drivers", "train.py"), "driver")
+    mem = {"peak_memory": peak, "bytes_limit": limit,
+           "runtime_peak_bytes": 1, "argument": 2, "temp": 3}
+    assert driver.program_peak_bytes(mem, rehearse) == want
