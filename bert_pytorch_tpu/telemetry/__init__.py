@@ -50,6 +50,7 @@ _EXPORTS = {
     "init_telemetry_state": ("bert_pytorch_tpu.telemetry.health",
                              "init_telemetry_state"),
     "StepWatch": ("bert_pytorch_tpu.telemetry.stepwatch", "StepWatch"),
+    "SetupWatch": ("bert_pytorch_tpu.telemetry.stepwatch", "SetupWatch"),
     "flops_per_seq": ("bert_pytorch_tpu.telemetry.stepwatch",
                       "flops_per_seq"),
     "lookup_peak_flops": ("bert_pytorch_tpu.telemetry.stepwatch",

@@ -15,7 +15,11 @@ the last K inputs continuously, dump them when something dies.
   numpy batch (packed fields included), the dispatch PRNG key, and the
   step id. References, not copies: the loader materializes fresh arrays
   per batch, so holding them costs zero extra memcpy and the bound is
-  `window * batch_nbytes`;
+  `window * batch_nbytes`. The key is kept AS HANDED OVER (a device array
+  in the train loop) and turned into host memory only when a bundle is
+  dumped: the key of step N is the result of a device program queued
+  behind step N-1, so reading it at dispatch time made the loop wait a
+  whole step where none of its phases showed it (PERF.md, PR 24);
 - a bounded tail of the most recent flushed metric records (the health
   pack's readback), so the bundle says WHAT tripped, not just WITH WHAT;
 - `dump()` writes a self-contained repro bundle — `batches.npz` plus a
@@ -43,6 +47,7 @@ import math
 import os
 import re
 import signal
+import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
@@ -86,8 +91,39 @@ REQUIRED_MANIFEST_KEYS = (
 )
 
 
+# how long one dump() waits, in all, for dispatch keys to reach host
+# memory. A live device hands them over within the step in flight; a hung
+# one (the watchdog's dump) never does, and the dump must still end.
+KEY_READ_PATIENCE_S = 10.0
+
+
 def _npz_key(step: int, field: str) -> str:
     return f"s{step:08d}__{field}"
+
+
+def _host_key(rng, patience_s: float) -> Optional[np.ndarray]:
+    """The dispatch key in host memory, or None if it cannot be had within
+    `patience_s` (read on a helper thread: a device-to-host read has no
+    time limit of its own, and a hung device would hold dump() forever)."""
+    if isinstance(rng, np.ndarray):
+        return rng
+    box: List[np.ndarray] = []
+
+    def read():
+        try:
+            box.append(np.asarray(rng))
+        except Exception:
+            pass    # reported by the caller as a key it could not read
+
+    reader = threading.Thread(target=read, name="recorder-key-read",
+                              daemon=True)
+    try:
+        reader.start()
+    except RuntimeError:    # no new threads this late in interpreter exit
+        read()
+    else:
+        reader.join(patience_s)
+    return box[0] if box else None
 
 
 def per_host_dir(out_dir: str) -> str:
@@ -198,13 +234,13 @@ class FlightRecorder:
         if len(self._staged) > max(self.window, 1):
             del self._staged[0]
 
-    def record_dispatch(self, first_step: int, n_steps: int,
-                        rng: np.ndarray) -> None:
+    def record_dispatch(self, first_step: int, n_steps: int, rng) -> None:
         """Bind the trailing `n_steps` staged batches to the dispatch that
         just consumed them: steps first_step .. first_step+n_steps-1, all
         sharing the dispatch PRNG key (a --steps_per_loop chunk derives
-        inner-step keys by fold_in(rng, pos) — replay reproduces that)."""
-        rng = np.asarray(rng)
+        inner-step keys by fold_in(rng, pos) — replay reproduces that).
+        `rng` is kept as it is (no device-to-host read here: that read
+        cannot return before the step in flight ends); dump() converts."""
         take = self._staged[-n_steps:]
         offset = n_steps - len(take)
         for i, batch in enumerate(take):
@@ -258,11 +294,21 @@ class FlightRecorder:
 
         arrays: Dict[str, np.ndarray] = {}
         records_meta = []
+        unkeyed = []
+        deadline = time.monotonic() + KEY_READ_PATIENCE_S
         for rec in self._records:
             sid = rec["step"]
+            rng = _host_key(rec["rng"],
+                            max(0.2, deadline - time.monotonic()))
+            if rng is None:
+                # no key, no replay of this step: leave the record out and
+                # say so, rather than write a bundle that fails validation
+                unkeyed.append(sid)
+                continue
+            rec["rng"] = rng
             for k, v in rec["batch"].items():
                 arrays[_npz_key(sid, k)] = v
-            arrays[_npz_key(sid, "rng")] = rec["rng"]
+            arrays[_npz_key(sid, "rng")] = rng
             records_meta.append({"step": sid, "pos": rec["pos"],
                                  "n_steps": rec["n_steps"],
                                  "fields": sorted(rec["batch"])})
@@ -291,6 +337,8 @@ class FlightRecorder:
             "program_fingerprint": self.program_fingerprint,
             "stream": None,
         }
+        if unkeyed:
+            manifest["unkeyed_steps"] = unkeyed
         if self.stream_info_fn is not None:
             try:
                 manifest["stream"] = self.stream_info_fn()

@@ -33,6 +33,9 @@ What the handle owns:
   cross-host min/mean/max and straggler warnings into its record.
 - `.stepwatch` / `.recorder` — attached later (`make_stepwatch`,
   `attach_recorder`) because their parameters only exist mid-setup.
+- `.setup` — the entry point's SetupWatch (`attach_setup`; it starts at
+  the entry of `main()`, before this handle can exist): its `setup_*_s`
+  counters ride in every perf record `log_perf` writes.
 
 `log_train` / `log_perf` are the phase-agnostic record paths: they update
 the registry + `/healthz` state, run the multi-host fold, then fan out
@@ -88,6 +91,7 @@ class TelemetryRun:
         self.server = server
         self.aggregator = aggregator
         self.stepwatch = None
+        self.setup = None
         self.recorder = None
         self.stream_loader = None
         self.ckpt_manager = None
@@ -153,6 +157,13 @@ class TelemetryRun:
         self.stepwatch = StepWatch(**kwargs)
         return self.stepwatch
 
+    def attach_setup(self, setup) -> None:
+        """The run's set-up account (telemetry/stepwatch.SetupWatch): it
+        discounts what this handle's CompileWatch counts as compiling, and
+        every perf record carries its cumulative `setup_*_s` counters."""
+        self.setup = setup
+        setup.compile_watch = self.compile_watch
+
     def attach_recorder(self, recorder) -> None:
         """Cross-wire the flight recorder: its bundle manifests gain the
         registry snapshot at dump time and a `metrics_tail_source`
@@ -216,6 +227,8 @@ class TelemetryRun:
         the (possibly fold-augmented) record actually logged."""
         step = int(step)
         record = dict(record)
+        if self.setup is not None:
+            record.update(self.setup.snapshot())
         if self.aggregator is not None:
             self.aggregator.publish(step, record)
             if self.aggregator.process_index == 0:

@@ -7,10 +7,20 @@ of the run (run_pretraining.py:574-580), which is exactly when it is no
 longer useful. StepWatch keeps per-interval accounting while the job runs:
 
 - wall time per optimization step,
-- named host phases (data_wait, h2d, dispatch, metric_flush — where the
-  host actually spends its loop time; in steady state `metric_flush` is
-  where the one-step-lag readback blocks and therefore approximates the
-  device step time),
+- named host phases that tile the main thread's time from one dispatch
+  to the next: `data_wait`, `data_prep`, `h2d`, `dispatch`, `metric_flush`,
+  `log`, `checkpoint`, `profile`. `metric_flush` is the ONE place the
+  pretraining loop waits for the device (the one-step-lag readback of the
+  previous step's metrics; on the v5e it reads 662-665 ms of a 669 ms
+  step, PERF.md section 5) — until PR 24 it read 6-7 ms, because the loop
+  waited, under no span, in the flight recorder's read of the dispatch
+  key. `log` is everything the loop does with a record once it has it
+  (log_train / log_perf and their sinks, recorder notes, HBM and compile
+  snapshots, SLO and halt checks, the fingerprint hand-over); `profile` is
+  jax.profiler's start and stop. Each phase is also a `host/<name>`
+  TraceAnnotation, opened and closed with the phase's own clock readings,
+  so a --profile_steps trace shows the same spans the record sums. What
+  the phases leave over is `loop_unaccounted_ms`,
 - seq/s and tokens/s,
 - real tokens/s, pad fraction and packing efficiency when the caller feeds
   per-batch real-token counts (`note_tokens`, from the attention mask):
@@ -32,9 +42,10 @@ host-device sync. Timing uses time.perf_counter (injectable for tests).
 from __future__ import annotations
 
 import os
+import sys
 import time
-from contextlib import contextmanager
-from typing import Callable, Dict, Optional
+from contextlib import contextmanager, nullcontext
+from typing import Callable, ContextManager, Dict, List, Optional
 
 # Peak dense bf16 FLOP/s per chip by device kind. Source: Google Cloud TPU
 # documentation, the per-generation system-architecture pages ("TPU v4",
@@ -122,6 +133,89 @@ def flops_per_seq(cfg, seq_len: int, vocab: int, n_pred: int) -> float:
     return 6.0 * (trunk + head) + 12.0 * L * E * seq_len * seq_len
 
 
+def host_annotation(name: str) -> ContextManager:
+    """A `host/...` span in jax.profiler's trace. A process that never
+    imported jax (bench.py's parent) has no profiler to write to and gets
+    a no-op; this module stays importable without jax."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+class SetupWatch:
+    """The account of a run's set-up: the wall time from the entry of
+    `main()` to the first step's loss on the host, under named spans.
+
+        setup = SetupWatch(start=<clock at main()'s entry>)  # opens 'backend'
+        setup.end("backend")
+        with setup.span("data"): ...
+        setup.begin("first_step") ... setup.end("first_step")  # closes it
+
+    Each span is a `host/setup/<name>` TraceAnnotation when a trace is
+    open, and its seconds, LESS what `compile_watch` counted as compiling
+    meanwhile, ride in every [perf] record as the cumulative counter
+    `setup_<name>_s` beside `compile_secs` (`snapshot()`); a span still
+    open counts nothing yet, so the counters only grow. What is left of the
+    wall time after the spans and `compile_secs` is `setup_unaccounted_s`,
+    taken at the last span's end: time between spans, less compiling that
+    no span was open for. `end("first_step")` closes the account; later
+    calls change nothing.
+    """
+
+    SPANS = ("backend", "data", "state", "lower", "first_step")
+
+    def __init__(self, start: float,
+                 time_fn: Callable[[], float] = time.perf_counter,
+                 annotate: Callable[[str], ContextManager] = host_annotation):
+        self._start = start
+        self._time = time_fn
+        self._annotate = annotate
+        self.compile_watch = None   # set once init_run has installed it
+        self._secs = {name: 0.0 for name in self.SPANS}
+        self._open: Dict[str, tuple] = {}
+        self._unaccounted = 0.0
+        self._closed = False
+        self.begin("backend", at=start)
+
+    def _compile_secs(self) -> float:
+        return self.compile_watch.compile_secs if self.compile_watch else 0.0
+
+    def begin(self, name: str, at: Optional[float] = None) -> None:
+        if self._closed or name in self._open:
+            return
+        note = self._annotate("host/setup/" + name)
+        note.__enter__()
+        self._open[name] = (self._time() if at is None else at,
+                            self._compile_secs(), note)
+
+    def end(self, name: str) -> None:
+        if name not in self._open:
+            return
+        t0, compiled0, note = self._open.pop(name)
+        now, compiled = self._time(), self._compile_secs()
+        note.__exit__(None, None, None)
+        self._secs[name] += (now - t0) - (compiled - compiled0)
+        self._unaccounted = (now - self._start - sum(self._secs.values())
+                             - compiled)
+        if name == "first_step":
+            self._closed = True
+
+    @contextmanager
+    def span(self, name: str):
+        self.begin(name)
+        try:
+            yield
+        finally:
+            self.end(name)
+
+    def snapshot(self) -> Dict[str, float]:
+        out = {f"setup_{name}_s": round(secs, 3)
+               for name, secs in self._secs.items()}
+        out["setup_unaccounted_s"] = round(self._unaccounted, 3)
+        return out
+
+
 class StepWatch:
     """Interval accounting for the host train loop.
 
@@ -148,7 +242,8 @@ class StepWatch:
                  time_fn: Callable[[], float] = time.perf_counter,
                  registry=None,
                  n_devices: int = 1,
-                 cost_per_device_hour: Optional[float] = None):
+                 cost_per_device_hour: Optional[float] = None,
+                 annotate: Callable[[str], ContextManager] = host_annotation):
         self.flops_per_step = float(flops_per_step)
         self.seqs_per_step = float(seqs_per_step)
         self.seq_len = int(seq_len)
@@ -162,7 +257,10 @@ class StepWatch:
             cost_per_device_hour)
         self.log_freq = max(1, int(log_freq))
         self._time = time_fn
+        self._annotate = annotate
         self._phases: Dict[str, float] = {}
+        # seconds spent in phases entered from inside each open phase
+        self._open: List[List[float]] = []
         # optional fn(name, entering: bool) fired on every phase
         # enter/exit — the hung-step watchdog's feed
         # (resilience/watchdog.py); None costs one attribute load per
@@ -187,17 +285,29 @@ class StepWatch:
 
     @contextmanager
     def phase(self, name: str):
+        """Time a leaf phase of the loop and show it as `host/<name>` in a
+        profiler trace: the annotation opens and closes at the phase's own
+        clock readings. A phase entered from inside another counts once:
+        its seconds go to it and come off the one around it, so the phases
+        of an interval never sum past its wall time."""
         listener = self.phase_listener
         if listener is not None:
             listener(name, True)
-        t0 = self._time()
-        try:
-            yield
-        finally:
-            self._phases[name] = (self._phases.get(name, 0.0)
-                                  + self._time() - t0)
-            if listener is not None:
-                listener(name, False)
+        inside = [0.0]
+        self._open.append(inside)
+        with self._annotate("host/" + name):
+            t0 = self._time()
+            try:
+                yield
+            finally:
+                spent = self._time() - t0
+                self._open.pop()
+                if self._open:
+                    self._open[-1][0] += spent
+                self._phases[name] = (self._phases.get(name, 0.0)
+                                      + spent - inside[0])
+                if listener is not None:
+                    listener(name, False)
 
     def add_phase(self, name: str, seconds: float) -> None:
         self._phases[name] = self._phases.get(name, 0.0) + seconds
@@ -281,6 +391,9 @@ class StepWatch:
             self._step_hist.observe(rec["step_time_ms"])
         for name, secs in sorted(self._phases.items()):
             rec[f"{name}_ms"] = round(secs / steps * 1e3, 3)
+        # the residual of the host account: wall time no leaf phase covers
+        rec["loop_unaccounted_ms"] = round(
+            (wall - sum(self._phases.values())) / steps * 1e3, 3)
         self._phases = {}
         self._steps = 0
         self._interval_start = now

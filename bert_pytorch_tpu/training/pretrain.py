@@ -16,6 +16,7 @@ run_pretraining.py:436 — same result, computed exactly).
 
 from __future__ import annotations
 
+import re
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -31,6 +32,38 @@ from bert_pytorch_tpu.telemetry.health import (HealthConfig,
 from bert_pytorch_tpu.training.state import TrainState
 
 Batch = Dict[str, jax.Array]
+
+# The account of the compiled step: every operation of jit(train_step)
+# belongs to the FIRST entry of this list that is a component of its
+# `op_name` path (jax.named_scope and flax module names; a transform's
+# wrapper, as in `jvp(loss)`, does not hide the name). So `attention`
+# inside `encoder` is `attention`, what is left of `encoder` is the layer
+# scan's own slicing and stacking (the benchmark's scan_carry_share.train),
+# what is left of `bert` is model glue (the attention bias), and what is
+# left of `grad_accum` is the micro-batch scan: its carry's adds, the
+# batch slicing, the final division. `rematted_computation` cuts across
+# the list (a recomputed attention operation is `attention`), so it is
+# not in it. The benchmark's unscoped_share.train carries a copy of this
+# list as its argument (tests/test_step_scopes.py holds the two equal), and
+# the same test compiles both step builders and finds every instruction
+# that has an op_name under one of these.
+STEP_SCOPES = (
+    "attention", "mlp", "mlm_head", "nsp_head", "pooler", "embeddings",
+    "loss", "optimizer", "grad_norm", "health", "param_cast", "metrics",
+    "encoder", "bert", "grad_accum",
+)
+_SCOPE_PATTERNS = tuple(
+    (name, re.compile(rf"(?:^|[/(]){re.escape(name)}\)*(?:/|$)"))
+    for name in STEP_SCOPES)
+
+
+def step_scope(op_name: str) -> Optional[str]:
+    """The STEP_SCOPES entry an operation's `op_name` belongs to (first
+    match), or None."""
+    for name, pattern in _SCOPE_PATTERNS:
+        if pattern.search(op_name):
+            return name
+    return None
 
 
 def _apply_health(health: Optional[HealthConfig], state: TrainState,
@@ -54,16 +87,17 @@ def _apply_health(health: Optional[HealthConfig], state: TrainState,
     """
     if health is None:
         return params, opt_state, precond_state, state.telemetry
-    hmetrics, bad = health_signals(loss, grads, grad_norm)
-    if health.action == "skip":
-        params = select_state(bad, state.params, params)
-        opt_state = select_state(bad, state.opt_state, opt_state)
-        if precond_state is not None:
-            precond_state = select_state(bad, state.precond_state,
-                                         precond_state)
-        hmetrics["skipped_nonfinite"] = bad.astype(jnp.int32)
-    telemetry, ema_metrics = health_update(health, state.telemetry,
-                                           grad_norm, bad, params)
+    with jax.named_scope("health"):
+        hmetrics, bad = health_signals(loss, grads, grad_norm)
+        if health.action == "skip":
+            params = select_state(bad, state.params, params)
+            opt_state = select_state(bad, state.opt_state, opt_state)
+            if precond_state is not None:
+                precond_state = select_state(bad, state.precond_state,
+                                             precond_state)
+            hmetrics["skipped_nonfinite"] = bad.astype(jnp.int32)
+        telemetry, ema_metrics = health_update(health, state.telemetry,
+                                               grad_norm, bad, params)
     metrics.update(hmetrics)
     metrics.update(ema_metrics)
     return params, opt_state, precond_state, telemetry
@@ -133,6 +167,13 @@ def _accum_zeros(gparams, accum_steps: int):
 _global_norm_f32 = global_norm_f32
 
 
+@jax.named_scope("grad_norm")
+def _grad_norm(grads, norm_reducer):
+    """The logged gradient norm of both step builders."""
+    return (norm_reducer.global_norm_f32(grads) if norm_reducer is not None
+            else _global_norm_f32(grads))
+
+
 def gather_masked_labels(masked_lm_labels: jax.Array, max_predictions: int
                          ) -> Tuple[jax.Array, jax.Array]:
     """(B, S) dense labels (-1 = unmasked) -> ((B, P) positions, (B, P)
@@ -151,6 +192,22 @@ def gather_masked_labels(masked_lm_labels: jax.Array, max_predictions: int
     return positions, labels
 
 
+def _gathered_labels(mlm_labels: jax.Array,
+                     max_predictions: Optional[int]):
+    """(labels, masked positions or None, masked positions dropped) as the
+    gathered MLM head wants them; the dense labels, None and 0 without a
+    `max_predictions`. The labels' side of the `loss` scope."""
+    if max_predictions is None:
+        return mlm_labels, None, jnp.zeros([], jnp.int32)
+    with jax.named_scope("loss"):
+        dense_total = jnp.sum(mlm_labels != -1).astype(jnp.int32)
+        masked_positions, mlm_labels = gather_masked_labels(
+            mlm_labels, max_predictions)
+        # rows with > max_predictions masks lose the excess; surface it
+        dropped = dense_total - jnp.sum(mlm_labels != -1).astype(jnp.int32)
+    return mlm_labels, masked_positions, dropped
+
+
 def _packed_kwargs(batch: Batch) -> Dict[str, Any]:
     """The packed-sequence fields (data/packing.py batch contract), passed
     through to the model only when the loader emitted them — an unpacked
@@ -163,15 +220,8 @@ def _pretrain_loss_fn(model, max_predictions: Optional[int] = None
                       ) -> Callable:
     def loss_fn(params, batch: Batch, dropout_rng,
                 deterministic: bool = False) -> Tuple[jax.Array, Dict]:
-        mlm_labels = batch["masked_lm_labels"]
-        masked_positions = None
-        dropped = jnp.zeros([], jnp.int32)
-        if max_predictions is not None:
-            dense_total = jnp.sum(mlm_labels != -1).astype(jnp.int32)
-            masked_positions, mlm_labels = gather_masked_labels(
-                mlm_labels, max_predictions)
-            # rows with > max_predictions masks lose the excess; surface it
-            dropped = dense_total - jnp.sum(mlm_labels != -1).astype(jnp.int32)
+        mlm_labels, masked_positions, dropped = _gathered_labels(
+            batch["masked_lm_labels"], max_predictions)
         mlm_logits, nsp_logits = model.apply(
             {"params": params},
             batch["input_ids"],
@@ -182,16 +232,18 @@ def _pretrain_loss_fn(model, max_predictions: Optional[int] = None
             rngs=None if deterministic else {"dropout": dropout_rng},
             **_packed_kwargs(batch),
         )
-        loss = losses.pretraining_loss(
-            mlm_logits, mlm_labels,
-            nsp_logits, batch.get("next_sentence_labels"))
-        correct, total = losses.mlm_accuracy(mlm_logits, mlm_labels)
+        with jax.named_scope("loss"):
+            loss = losses.pretraining_loss(
+                mlm_logits, mlm_labels,
+                nsp_logits, batch.get("next_sentence_labels"))
+            correct, total = losses.mlm_accuracy(mlm_logits, mlm_labels)
         return loss, {"mlm_correct": correct, "mlm_total": total,
                       "mlm_dropped": dropped}
 
     return loss_fn
 
 
+@jax.named_scope("optimizer")
 def _zero1_update(tx, grads, state, zero1):
     """The optimizer tail shared by both step builders, with the optional
     ZeRO-1 sharding constraints (parallel/zero.py) around it.
@@ -247,7 +299,8 @@ def _zero1_update(tx, grads, state, zero1):
     return params, opt_state, grads
 
 
-def _use_params(state, zero1, cast_params):
+@jax.named_scope("param_cast")
+def _use_params(state, zero1, cast_params, nan_inject_step=None):
     """The params the forward/backward consume: cast to the grad dtype and —
     for a gather-on-use Zero1Plan — re-constrained from the 1/N resting
     layout to the train-step layout, leaf by leaf (parallel/zero.py
@@ -255,7 +308,8 @@ def _use_params(state, zero1, cast_params):
     the all-gather then moves the bf16 copy (half the bytes of the fp32
     masters) while the masters stay shard-resident for the update. With
     grad_dtype=None the cast is identity and the gather moves fp32 —
-    exactly what the non-overlap path's end-of-step gather moved."""
+    exactly what the non-overlap path's end-of-step gather moved.
+    `nan_inject_step` is the fault-injection drill (inject_nonfinite)."""
     gparams = cast_params(state.params)
     if zero1 is not None:
         from bert_pytorch_tpu.parallel.zero import gather_params
@@ -269,6 +323,9 @@ def _use_params(state, zero1, cast_params):
         # GSPMD partitions the backward's wgrad reductions differently and
         # the paths drift ~1e-9/step.
         gparams = gather_params(gparams, zero1)
+    if nan_inject_step is not None:
+        gparams = inject_nonfinite(
+            gparams, state.step + 1 == nan_inject_step)
     return gparams
 
 
@@ -365,17 +422,9 @@ def _build_rs_micro(model, zero1, max_predictions=None,
         return jax.tree_util.tree_unflatten(tdef, out)
 
     def prep_labels(micro):
-        mlm_labels = micro["masked_lm_labels"]
-        masked_positions = None
-        dropped = jnp.zeros([], jnp.int32)
-        if max_predictions is not None:
-            dense_total = jnp.sum(mlm_labels != -1).astype(jnp.int32)
-            masked_positions, mlm_labels = gather_masked_labels(
-                mlm_labels, max_predictions)
-            dropped = dense_total - jnp.sum(
-                mlm_labels != -1).astype(jnp.int32)
-        return mlm_labels, masked_positions, dropped
+        return _gathered_labels(micro["masked_lm_labels"], max_predictions)
 
+    @jax.named_scope("loss")
     def global_counts(mlm_labels, nsp_labels):
         # label-only, psum'd OUTSIDE the differentiated function — exact
         # int sums, and the backward never sees a collective
@@ -386,6 +435,7 @@ def _build_rs_micro(model, zero1, max_predictions=None,
             if nsp_labels is not None else None)
         return c_mlm, c_nsp
 
+    @jax.named_scope("loss")
     def terms_to_loss(mlm_logits, nsp_logits, mlm_labels, nsp_labels,
                       c_mlm, c_nsp):
         (mlm_sum, _), nsp = losses.pretraining_loss_terms(
@@ -398,6 +448,7 @@ def _build_rs_micro(model, zero1, max_predictions=None,
         correct, total = losses.mlm_accuracy(mlm_logits, mlm_labels)
         return lloc, mlm_sum, nsp_sum, correct, total
 
+    @jax.named_scope("loss")
     def metric_loss(mlm_sum, nsp_sum, nsp_labels, c_mlm, c_nsp):
         loss = jax.lax.psum(mlm_sum, ax_entry) / c_mlm
         if nsp_labels is not None:
@@ -458,7 +509,8 @@ def _build_rs_micro(model, zero1, max_predictions=None,
         (_, (mlm_sum, nsp_sum, correct, total, acts)), \
             (pgrads, pert_grads) = jax.value_and_grad(
                 local_loss, argnums=(0, 1), has_aux=True)(params, perts)
-        stats = kfac.local_partial_stats(acts, pert_grads)
+        with jax.named_scope("optimizer/kfac"):
+            stats = kfac.local_partial_stats(acts, pert_grads)
         loss = metric_loss(mlm_sum, nsp_sum, nsp_labels, c_mlm, c_nsp)
         aux = {"mlm_correct": jax.lax.psum(correct, ax_entry),
                "mlm_total": jax.lax.psum(total, ax_entry)}
@@ -621,12 +673,39 @@ def build_pretrain_step(
             return loss, aux, grads
 
     def train_step(state: TrainState, batch: Batch, rng: jax.Array):
-        rngs = jax.random.split(rng, accum_steps)
-        gparams = _use_params(state, zero1, cast_params)
-        if nan_inject_step is not None:
-            gparams = inject_nonfinite(
-                gparams, state.step + 1 == nan_inject_step)
+        gparams = _use_params(state, zero1, cast_params, nan_inject_step)
+        with jax.named_scope("grad_accum"):
+            loss, aux, grads = accumulate(gparams, batch, rng)
+        params, opt_state, grads = _zero1_update(tx, grads, state, zero1)
+        grad_norm = _grad_norm(grads, norm_reducer)
 
+        metrics = {
+            "loss": loss,
+            "grad_norm": grad_norm,
+        }
+        params, opt_state, _, telemetry = _apply_health(
+            health, state, loss, grads, grad_norm, params, opt_state,
+            metrics)
+        with jax.named_scope("metrics"):
+            new_state = state.replace(step=state.step + 1, params=params,
+                                      opt_state=opt_state,
+                                      telemetry=telemetry)
+            if "mlm_correct" in aux and "mlm_total" in aux:
+                metrics["mlm_accuracy"] = (
+                    aux["mlm_correct"] / jnp.maximum(aux["mlm_total"], 1))
+            if "mlm_dropped" in aux:
+                # masked positions beyond max_predictions lose supervision;
+                # a nonzero value means the data pipeline and step config
+                # disagree
+                metrics["mlm_dropped"] = aux["mlm_dropped"]
+            if schedule is not None:
+                metrics["learning_rate"] = schedule(state.step)
+        return new_state, metrics
+
+    def accumulate(gparams, batch: Batch, rng: jax.Array):
+        """(loss, aux, grads) averaged over the micro-batches; everything
+        here that no inner scope claims is the `grad_accum` scope."""
+        rngs = jax.random.split(rng, accum_steps)
         if accum_steps == 1:
             micro = jax.tree.map(lambda x: x[0], batch)
             loss, aux, grads = one_micro(gparams, micro, rngs[0])
@@ -655,31 +734,7 @@ def build_pretrain_step(
             (grads, loss, aux), _ = jax.lax.scan(body, init, (batch, rngs))
             grads = jax.tree.map(lambda g: g / accum_steps, grads)
             loss = loss / accum_steps
-
-        params, opt_state, grads = _zero1_update(tx, grads, state, zero1)
-        grad_norm = (norm_reducer.global_norm_f32(grads)
-                     if norm_reducer is not None
-                     else _global_norm_f32(grads))
-
-        metrics = {
-            "loss": loss,
-            "grad_norm": grad_norm,
-        }
-        params, opt_state, _, telemetry = _apply_health(
-            health, state, loss, grads, grad_norm, params, opt_state,
-            metrics)
-        new_state = state.replace(step=state.step + 1, params=params,
-                                  opt_state=opt_state, telemetry=telemetry)
-        if "mlm_correct" in aux and "mlm_total" in aux:
-            metrics["mlm_accuracy"] = (
-                aux["mlm_correct"] / jnp.maximum(aux["mlm_total"], 1))
-        if "mlm_dropped" in aux:
-            # masked positions beyond max_predictions lose supervision; a
-            # nonzero value means the data pipeline and step config disagree
-            metrics["mlm_dropped"] = aux["mlm_dropped"]
-        if schedule is not None:
-            metrics["learning_rate"] = schedule(state.step)
-        return new_state, metrics
+        return loss, aux, grads
 
     return train_step
 
@@ -773,7 +828,9 @@ class StepProgram:
     def __call__(self, *args):
         if self.compiled is None and not self._aot_broken:
             try:
-                self.compile(*args)
+                # a program the caller has lowered already (the entry point
+                # times lowering as a set-up span) is not lowered again
+                self.compile(*(() if self.lowered is not None else args))
             except Exception as e:
                 # fall back to plain jit, but never silently: a broken AOT
                 # compile also means no program fingerprint for this run's
@@ -935,14 +992,9 @@ def build_kfac_pretrain_step(
     too — its factor statistics are exactly the kind of state a NaN
     poisons silently).
     """
-    from bert_pytorch_tpu.models import losses as _losses
-
     def loss_fn(params, perts, micro: Batch, rng):
-        mlm_labels = micro["masked_lm_labels"]
-        masked_positions = None
-        if max_predictions is not None:
-            masked_positions, mlm_labels = gather_masked_labels(
-                mlm_labels, max_predictions)
+        mlm_labels, masked_positions, _ = _gathered_labels(
+            micro["masked_lm_labels"], max_predictions)
         (mlm_logits, nsp_logits), mut = model.apply(
             {"params": params, "perturbations": perts},
             micro["input_ids"], micro.get("token_type_ids"),
@@ -951,10 +1003,11 @@ def build_kfac_pretrain_step(
             rngs={"dropout": rng},
             mutable=["kfac_in"],
             **_packed_kwargs(micro))
-        loss = _losses.pretraining_loss(
-            mlm_logits, mlm_labels,
-            nsp_logits, micro.get("next_sentence_labels"))
-        correct, total = _losses.mlm_accuracy(mlm_logits, mlm_labels)
+        with jax.named_scope("loss"):
+            loss = losses.pretraining_loss(
+                mlm_logits, mlm_labels,
+                nsp_logits, micro.get("next_sentence_labels"))
+            correct, total = losses.mlm_accuracy(mlm_logits, mlm_labels)
         return loss, ({"mlm_correct": correct, "mlm_total": total},
                       mut["kfac_in"])
 
@@ -973,16 +1026,52 @@ def build_kfac_pretrain_step(
         def one_micro(params, micro, rng):
             (loss, (aux, acts)), (pgrads, pert_grads) = grad_fn(
                 params, zeros_perts, micro, rng)
-            stats = kfac.compute_stats(acts, pert_grads)
+            with jax.named_scope("optimizer/kfac"):
+                stats = kfac.compute_stats(acts, pert_grads)
             return loss, aux, pgrads, stats
 
     def train_step(state: TrainState, batch: Batch, rng: jax.Array):
-        rngs = jax.random.split(rng, accum_steps)
-        gparams = _use_params(state, zero1, cast_params)
-        if nan_inject_step is not None:
-            gparams = inject_nonfinite(
-                gparams, state.step + 1 == nan_inject_step)
+        gparams = _use_params(state, zero1, cast_params, nan_inject_step)
+        with jax.named_scope("grad_accum"):
+            loss, aux, grads, stats = accumulate(gparams, batch, rng)
+        with jax.named_scope("optimizer/kfac"):
+            lr = (schedule(state.step) if schedule is not None
+                  else kfac.config.learning_rate)
+            if rs:
+                # preconditioning contracts FULL grad tensors against the
+                # factor inverses; the region's grads arrive reduce-
+                # scattered, so gather them at the point of use (same per-
+                # leaf all-gather economics as gather_on_use params) —
+                # _zero1_update re-pins the preconditioned output to the
+                # shard layout
+                grads = jax.lax.with_sharding_constraint(
+                    grads, zero1.param_shardings)
+            kstate, grads = kfac.step(state.precond_state, stats, grads, lr)
+        params, opt_state, grads = _zero1_update(tx, grads, state, zero1)
+        grad_norm = _grad_norm(grads, norm_reducer)
+        with jax.named_scope("metrics"):
+            metrics = {
+                "loss": loss,
+                "grad_norm": grad_norm,
+                "mlm_accuracy": (aux["mlm_correct"]
+                                 / jnp.maximum(aux["mlm_total"], 1)),
+            }
+        params, opt_state, kstate, telemetry = _apply_health(
+            health, state, loss, grads, grad_norm, params, opt_state,
+            metrics, precond_state=kstate)
+        with jax.named_scope("metrics"):
+            new_state = state.replace(step=state.step + 1, params=params,
+                                      opt_state=opt_state,
+                                      precond_state=kstate,
+                                      telemetry=telemetry)
+            if schedule is not None:
+                metrics["learning_rate"] = schedule(state.step)
+        return new_state, metrics
 
+    def accumulate(gparams, batch: Batch, rng: jax.Array):
+        """(loss, aux, grads, factor statistics) averaged over the
+        micro-batches, as in build_pretrain_step."""
+        rngs = jax.random.split(rng, accum_steps)
         if accum_steps == 1:
             micro = jax.tree.map(lambda x: x[0], batch)
             loss, aux, grads, stats = one_micro(gparams, micro, rngs[0])
@@ -1013,36 +1102,7 @@ def build_kfac_pretrain_step(
             stats = jax.tree.map(lambda s: s / accum_steps, stats)
             loss = loss / accum_steps
             aux = {"mlm_correct": correct, "mlm_total": total}
-
-        lr = (schedule(state.step) if schedule is not None
-              else kfac.config.learning_rate)
-        if rs:
-            # preconditioning contracts FULL grad tensors against the
-            # factor inverses; the region's grads arrive reduce-scattered,
-            # so gather them at the point of use (same per-leaf all-gather
-            # economics as gather_on_use params) — _zero1_update re-pins
-            # the preconditioned output to the shard layout
-            grads = jax.lax.with_sharding_constraint(
-                grads, zero1.param_shardings)
-        kstate, grads = kfac.step(state.precond_state, stats, grads, lr)
-        params, opt_state, grads = _zero1_update(tx, grads, state, zero1)
-        grad_norm = (norm_reducer.global_norm_f32(grads)
-                     if norm_reducer is not None
-                     else _global_norm_f32(grads))
-        metrics = {
-            "loss": loss,
-            "grad_norm": grad_norm,
-            "mlm_accuracy": aux["mlm_correct"] / jnp.maximum(aux["mlm_total"], 1),
-        }
-        params, opt_state, kstate, telemetry = _apply_health(
-            health, state, loss, grads, grad_norm, params, opt_state,
-            metrics, precond_state=kstate)
-        new_state = state.replace(step=state.step + 1, params=params,
-                                  opt_state=opt_state, precond_state=kstate,
-                                  telemetry=telemetry)
-        if schedule is not None:
-            metrics["learning_rate"] = schedule(state.step)
-        return new_state, metrics
+        return loss, aux, grads, stats
 
     return train_step
 

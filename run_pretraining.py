@@ -25,6 +25,7 @@ import json
 import math
 import os
 import sys
+import threading
 import time
 from pathlib import Path
 
@@ -584,6 +585,7 @@ def make_optimizer(name: str, schedule, norm_reducer=None, fused="off"):
 
 
 def main(argv=None):
+    entered = time.perf_counter()   # the set-up account starts here
     args = parse_arguments(argv)
     if not (args.input_dir or args.stream_dir) or not args.output_dir:
         raise SystemExit("--output_dir and one data plane (--input_dir for "
@@ -614,8 +616,8 @@ def main(argv=None):
     from bert_pytorch_tpu.optim import schedulers
     from bert_pytorch_tpu.parallel import dist, mesh as mesh_lib
     from bert_pytorch_tpu.telemetry import (
-        HealthConfig, collect_provenance, flops_per_seq, hbm_snapshot,
-        device_peak_flops, init_run, init_telemetry_state)
+        HealthConfig, SetupWatch, collect_provenance, flops_per_seq,
+        hbm_snapshot, device_peak_flops, init_run, init_telemetry_state)
     from bert_pytorch_tpu.resilience import ChaosMonkey, PreemptionGuard
     from bert_pytorch_tpu.resilience.preemption import (emergency_save,
                                                         is_preemption_exit)
@@ -626,6 +628,10 @@ def main(argv=None):
                                                     stack_microbatches,
                                                     chain_steps)
 
+    # set-up spans (telemetry/stepwatch.SetupWatch): backend | data | state
+    # | lower | first_step, closed by the first step's loss on the host;
+    # 'backend' has been open since main()'s entry
+    setup = SetupWatch(start=entered)
     compile_cache_dir = enable_compile_cache()
     dist.initialize()
     np.random.seed(args.seed + dist.get_rank())
@@ -654,6 +660,7 @@ def main(argv=None):
         multihost_dir=(os.path.join(args.output_dir, "metrics_hosts")
                        if n_hosts > 1 else None),
         process_index=dist.get_rank(), process_count=n_hosts)
+    tel.attach_setup(setup)
     logger = tel.logger
     compile_watch = tel.compile_watch
     # every resource created below is released in the finally block, on the
@@ -825,6 +832,8 @@ def main(argv=None):
                 factor_sync_freq=args.kfac_factor_sync_freq)
 
         # -- dataset --------------------------------------------------------
+        setup.end("backend")
+        setup.begin("data")
         mask_id = find_mask_token_index(args, config)
         if args.stream_dir:
             # streaming plane (data/streaming.py, docs/DATA.md): raw text
@@ -917,6 +926,8 @@ def main(argv=None):
                                         pending=()))
         stacked = stack_microbatches(sample, accum_steps)
         seq_len = int(np.asarray(sample["input_ids"]).shape[-1])
+        setup.end("data")
+        setup.begin("state")
 
         # gathered-MLM-head budget: a packed row pools several examples'
         # masked positions, so the per-ROW cap grows beyond the per-example
@@ -1109,6 +1120,7 @@ def main(argv=None):
             # is identical with the pack on or off (state.py contract)
             state = state.replace(telemetry=init_telemetry_state())
 
+        setup.end("state")
         # StepProgram = jit + explicit first-dispatch lower/compile: same
         # one XLA compile, but the executable's HLO stays reachable for
         # the program fingerprint below (and tools/graphcheck.py gates the
@@ -1352,7 +1364,30 @@ def main(argv=None):
         dispatches = 0  # jit calls made; gates compile-warmup closure
         fp_holder = [None]  # program fingerprint, filled by a worker thread
         fp_logged = [False]
-        fp_thread = [None]
+
+        def fingerprint_worker():
+            """Program fingerprint (collective counts + donation hash) of
+            whichever program the first dispatch AOT-compiled: stamped into
+            every flight-recorder bundle and re-logged as a header extension
+            so tools/replay.py can warn when a replay's program structure
+            diverges from the recorded run's. The HLO text render + parse
+            runs on this worker thread — at BERT-Large scale the optimized
+            HLO is tens of MB and must not stall dispatch 2; the header is
+            logged from the MAIN thread once the result lands (MetricLogger
+            is not thread-safe)."""
+            for prog, n in ((jit_chunk, steps_per_loop), (jit_step, 1)):
+                f = prog.fingerprint() if prog is not None else None
+                if f is not None:
+                    fp = dict(f, steps_per_loop=n)
+                    if recorder is not None:
+                        recorder.program_fingerprint = fp
+                    # later checkpoints' integrity sidecars carry it too
+                    manager.manifest_context["program_fingerprint"] = fp
+                    fp_holder[0] = fp
+                    return
+
+        fp_thread = threading.Thread(target=fingerprint_worker,
+                                     name="program-fingerprint", daemon=True)
 
         def maybe_log_fingerprint():
             """Main-thread consumer of the fingerprint worker: append the
@@ -1372,14 +1407,21 @@ def main(argv=None):
                         fp.get("kernel_counts", {}).items())))
 
         def flush_pending():
-            nonlocal pending, loss_sum, loss_n, warned_dropped, halt_pending
+            nonlocal pending
             if pending is None:
                 return
             step_i, epoch_i, m = pending
             pending = None
-            with sw.phase("metric_flush"), \
-                    jax.profiler.TraceAnnotation("host/metric_flush"):
+            # the ONE place the loop waits for the device: step N's metrics
+            # are read after step N+1 is in flight
+            with sw.phase("metric_flush"):
                 vals = {k: float(v) for k, v in m.items()}
+            setup.end("first_step")     # the first loss is on the host
+            with sw.phase("log"):
+                log_flushed(step_i, epoch_i, vals)
+
+        def log_flushed(step_i, epoch_i, vals):
+            nonlocal loss_sum, loss_n, warned_dropped, halt_pending
             if recorder is not None:
                 # metrics tail rides in the bundle: the black box records
                 # what tripped, not just the inputs
@@ -1524,86 +1566,84 @@ def main(argv=None):
             prefetch the pair's device half was put while the PREVIOUS step
             computed (DevicePrefetcher); without it the loop does the
             stack+put itself and the device half is None."""
-            if use_h2d_prefetch:
-                from bert_pytorch_tpu.data.sharded import DevicePrefetcher
-
-                def waited():
-                    it = iter(loader)
-                    while True:
-                        with sw.phase("data_wait"), \
-                                jax.profiler.TraceAnnotation(
-                                    "host/data_wait"):
-                            try:
-                                b = next(it)
-                            except StopIteration:
-                                return
-                        yield b
-
-                def put_fn(b):
-                    with sw.phase("data_prep"), \
-                            jax.profiler.TraceAnnotation("host/data_prep"):
-                        st = stack_microbatches(b, accum_steps)
-                    with sw.phase("h2d"), \
-                            jax.profiler.TraceAnnotation("host/h2d"):
-                        return mesh_lib.host_to_device_batch(mesh, st)
-
-                pf = DevicePrefetcher(
-                    waited(), put_fn, depth=h2d_depth,
-                    state_fn=loader.state_dict,
-                    batch_tap=(recorder.capture_batch
-                               if recorder is not None else None))
-                pf_holder[0] = pf
-                yield from pf
-            else:
+            def waited():
                 it = iter(loader)
                 while True:
-                    with sw.phase("data_wait"), \
-                            jax.profiler.TraceAnnotation("host/data_wait"):
+                    with sw.phase("data_wait"):
                         try:
-                            batch = next(it)
+                            b = next(it)
                         except StopIteration:
                             return
-                    yield batch, None
+                    yield b
+
+            if not use_h2d_prefetch:
+                yield from ((b, None) for b in waited())
+                return
+            from bert_pytorch_tpu.data.sharded import DevicePrefetcher
+
+            def put_fn(b):
+                with sw.phase("data_prep"):
+                    st = stack_microbatches(b, accum_steps)
+                with sw.phase("h2d"):
+                    return mesh_lib.host_to_device_batch(mesh, st)
+
+            pf = DevicePrefetcher(
+                waited(), put_fn, depth=h2d_depth,
+                state_fn=loader.state_dict,
+                batch_tap=(recorder.capture_batch
+                           if recorder is not None else None))
+            pf_holder[0] = pf
+            yield from pf
+
+        def check_halts():
+            """Raise for a flagged non-finite step, or for a page-severity
+            train SLO that has fired past --slo_halt_after_s."""
+            if halt_pending:
+                raise NonFiniteHalt(halt_pending)
+            if slo_engine is None or args.slo_action != "halt":
+                return
+            since = slo_engine.page_firing_since()
+            if (since is not None
+                    and time.time() - since >= args.slo_halt_after_s):
+                firing = sorted({a["slo"] for a in
+                                 slo_engine.alerts_view()["firing"]})
+                raise SLOBreachHalt(
+                    f"train SLO breach: page alert(s) {firing} firing for "
+                    f"{time.time() - since:.0f}s (>= --slo_halt_after_s "
+                    f"{args.slo_halt_after_s:g}) at step {global_step} — "
+                    "exiting EXIT_SLO_BREACH(76) for the supervisor to "
+                    "restart")
 
         # logical_rules must be active while the step traces (first jit_step
         # call), or every nn.with_logical_constraint inside the model
         # becomes a silent no-op and SPMD layout falls back to pure
         # propagation
         chunk_buf = []  # steps_per_loop>1: host-side batch staging
+        limit = min(target_step, session_limit)
 
+        # Every statement of the loop that can take time runs under ONE
+        # StepWatch phase (each also a host/<phase> trace annotation), so
+        # the phases tile the main thread from one dispatch to the next and
+        # a [perf] record's loop_unaccounted_ms reads near zero.
         with mesh, mesh_lib.logical_rules():
+            setup.begin("data")     # the first batch, up to the first dispatch
             while not done:
                 for batch_np, dev_batch in timed_batches():
-                    if global_step >= min(target_step, session_limit):
+                    if global_step >= limit:
                         done = True
                         break
-                    if halt_pending:
-                        raise NonFiniteHalt(halt_pending)
-                    if slo_engine is not None and args.slo_action == "halt":
-                        since = slo_engine.page_firing_since()
-                        if (since is not None and
-                                time.time() - since >= args.slo_halt_after_s):
-                            firing = sorted({a["slo"] for a in
-                                             slo_engine.alerts_view()["firing"]})
-                            raise SLOBreachHalt(
-                                f"train SLO breach: page alert(s) {firing} "
-                                f"firing for "
-                                f"{time.time() - since:.0f}s (>= "
-                                f"--slo_halt_after_s "
-                                f"{args.slo_halt_after_s:g}) at step "
-                                f"{global_step} — exiting "
-                                "EXIT_SLO_BREACH(76) for the supervisor "
-                                "to restart")
-                    if chaos is not None:
-                        chaos.before_dispatch(global_step + 1)
+                    with sw.phase("log"):
+                        check_halts()
+                        if chaos is not None:
+                            chaos.before_dispatch(global_step + 1)
                     if (profile_range and not trace_active
                             and profile_range[0] <= global_step
                             < profile_range[1]):
-                        jax.profiler.start_trace(
-                            os.path.join(args.output_dir, "traces"))
+                        with sw.phase("profile"):
+                            jax.profiler.start_trace(
+                                os.path.join(args.output_dir, "traces"))
                         trace_active = True
-                    with sw.phase("data_prep"), \
-                            jax.profiler.TraceAnnotation("host/data_prep"):
+                    with sw.phase("data_prep"):
                         if dev_batch is None:
                             stacked = stack_microbatches(batch_np,
                                                          accum_steps)
@@ -1613,111 +1653,75 @@ def main(argv=None):
                         sw.note_tokens(
                             float(np.asarray(batch_np["attention_mask"])
                                   .sum()) * n_hosts)
-                    remaining = min(target_step, session_limit) - global_step
-                    if steps_per_loop > 1 and remaining >= steps_per_loop:
+                    remaining = limit - global_step
+                    chunked = (steps_per_loop > 1
+                               and remaining >= steps_per_loop)
+                    if chunked:
                         # stage until a full device-side loop's worth is ready
                         chunk_buf.append(stacked)
                         if len(chunk_buf) < steps_per_loop:
                             continue
-                        with sw.phase("data_prep"), \
-                                jax.profiler.TraceAnnotation("host/data_prep"):
-                            chunk = {k: np.stack([b[k] for b in chunk_buf])
-                                     for k in chunk_buf[0]}
+                        with sw.phase("data_prep"):
+                            stacked = {k: np.stack([b[k] for b in chunk_buf])
+                                       for k in chunk_buf[0]}
                             chunk_buf = []
-                        with sw.phase("h2d"), \
-                                jax.profiler.TraceAnnotation("host/h2d"):
+                    if chunked or dev_batch is None:
+                        with sw.phase("h2d"):
                             batch = mesh_lib.host_to_device_batch(
-                                mesh, chunk, n_leading=2)
-                        step_rng = jax.random.fold_in(rng_base,
-                                                      global_step + 1)
-                        with sw.phase("dispatch"), \
-                                jax.profiler.TraceAnnotation("host/dispatch"):
-                            if chaos is not None:
-                                chaos.stall(global_step + 1)
-                            state, metrics = jit_chunk(state, batch, step_rng)
-                        stepped = steps_per_loop
+                                mesh, stacked, n_leading=2 if chunked else 1)
                     else:
-                        if dev_batch is not None:
-                            batch = dev_batch  # put while the last step ran
-                        else:
-                            with sw.phase("h2d"), \
-                                    jax.profiler.TraceAnnotation("host/h2d"):
-                                batch = mesh_lib.host_to_device_batch(
-                                    mesh, stacked)
+                        batch = dev_batch  # put while the last step ran
+                    program, stepped = ((jit_chunk, steps_per_loop)
+                                        if chunked else (jit_step, 1))
+                    with sw.phase("dispatch"):
                         step_rng = jax.random.fold_in(rng_base,
                                                       global_step + 1)
-                        with sw.phase("dispatch"), \
-                                jax.profiler.TraceAnnotation("host/dispatch"):
-                            if chaos is not None:
-                                chaos.stall(global_step + 1)
-                            state, metrics = jit_step(state, batch, step_rng)
-                        stepped = 1
-                    if recorder is not None:
-                        # bind the staged loader batches to the steps this
-                        # dispatch performs + the dispatch PRNG key
-                        recorder.record_dispatch(global_step + 1, stepped,
-                                                 np.asarray(step_rng))
-                    global_step += stepped
-                    sampler_coherent[0] = (global_step, sampler_state(),
-                                           epoch)
-                    dispatches += 1
-                    if dispatches == 1:
-                        # program fingerprint (collective counts + donation
-                        # hash) of whichever program the first dispatch
-                        # AOT-compiled: stamped into every flight-recorder
-                        # bundle and re-logged as a header extension so
-                        # tools/replay.py can warn when a replay's program
-                        # structure diverges from the recorded run's. The
-                        # HLO text render + parse runs on a worker thread —
-                        # at BERT-Large scale the optimized HLO is tens of
-                        # MB and must not stall dispatch 2; the header is
-                        # logged from THIS thread once the result lands
-                        # (MetricLogger is not thread-safe).
-                        import threading
-
-                        def _fingerprint_worker():
-                            for prog, n in ((jit_chunk, steps_per_loop),
-                                            (jit_step, 1)):
-                                f = (prog.fingerprint()
-                                     if prog is not None else None)
-                                if f is not None:
-                                    fp = dict(f, steps_per_loop=n)
-                                    if recorder is not None:
-                                        recorder.program_fingerprint = fp
-                                    # later checkpoints' integrity
-                                    # sidecars carry it too
-                                    manager.manifest_context[
-                                        "program_fingerprint"] = fp
-                                    fp_holder[0] = fp
-                                    return
-
-                        fp_thread[0] = threading.Thread(
-                            target=_fingerprint_worker,
-                            name="program-fingerprint", daemon=True)
-                        fp_thread[0].start()
-                    maybe_log_fingerprint()
+                        if dispatches == 0:
+                            setup.end("data")
+                            with setup.span("lower"):
+                                program.lower(state, batch, step_rng)
+                            setup.begin("first_step")
+                        if chaos is not None:
+                            chaos.stall(global_step + 1)
+                        state, metrics = program(state, batch, step_rng)
+                    with sw.phase("log"):
+                        if recorder is not None:
+                            # bind the staged loader batches to the steps
+                            # this dispatch performs + the dispatch PRNG key
+                            # (kept as the device array it is: reading it
+                            # here would wait for the step in flight)
+                            recorder.record_dispatch(global_step + 1,
+                                                     stepped, step_rng)
+                        global_step += stepped
+                        sampler_coherent[0] = (global_step, sampler_state(),
+                                               epoch)
+                        dispatches += 1
+                        if dispatches == 1:
+                            fp_thread.start()
+                        maybe_log_fingerprint()
                     flush_pending()
                     pending = (global_step, epoch, metrics)
                     perf = sw.step_done(stepped)
                     if perf is not None:
-                        # warmup closes at the first interval with >=3
-                        # dispatches behind it: jit legitimately compiles
-                        # twice (first call sees uncommitted input
-                        # shardings, the donated output commits them), so
-                        # only a compile past dispatch 3 is a true mid-run
-                        # recompile worth a loud warning
-                        if dispatches >= 3:
-                            compile_watch.mark_steady()
-                        perf.update(compile_watch.snapshot())
-                        perf.update(hbm_snapshot())
-                        tel.log_perf(global_step, perf)
+                        with sw.phase("log"):
+                            # warmup closes at the first interval with >=3
+                            # dispatches behind it: jit legitimately compiles
+                            # twice (first call sees uncommitted input
+                            # shardings, the donated output commits them),
+                            # so only a compile past dispatch 3 is a true
+                            # mid-run recompile worth a loud warning
+                            if dispatches >= 3:
+                                compile_watch.mark_steady()
+                            perf.update(compile_watch.snapshot())
+                            perf.update(hbm_snapshot())
+                            tel.log_perf(global_step, perf)
                     if trace_active and global_step >= profile_range[1]:
-                        jax.profiler.stop_trace()
+                        with sw.phase("profile"):
+                            jax.profiler.stop_trace()
                         trace_active = False
                     if (not args.skip_checkpoint
                             and global_step % args.num_steps_per_checkpoint
-                            < (steps_per_loop if remaining >= steps_per_loop
-                               else 1)):
+                            < (steps_per_loop if chunked else 1)):
                         flush_pending()
                         if halt_pending:
                             # never checkpoint past a halt-flagged step: the
@@ -1737,16 +1741,18 @@ def main(argv=None):
                         if chaos is not None:
                             chaos.after_checkpoint(manager, global_step)
                 else:
-                    loader.reset_epoch()
+                    with sw.phase("data_wait"):
+                        # drains what the prefetch executor has in flight
+                        loader.reset_epoch()
                     pf_holder[0] = None  # next epoch builds a fresh one
                     epoch += 1
 
         flush_pending()
-        if fp_thread[0] is not None:
+        if dispatches:
             # short runs can finish before the fingerprint parse does;
             # give it a moment so the header extension still lands (the
             # thread is daemonic — a stuck parse never blocks shutdown)
-            fp_thread[0].join(timeout=10.0)
+            fp_thread.join(timeout=10.0)
             maybe_log_fingerprint()
         if halt_pending:
             raise NonFiniteHalt(halt_pending)

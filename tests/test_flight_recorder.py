@@ -18,11 +18,13 @@ import shutil
 import signal
 import sys
 
+import jax
 import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from bert_pytorch_tpu.telemetry import flight_recorder as fr
 from bert_pytorch_tpu.telemetry.flight_recorder import (FlightRecorder,
                                                         validate_bundle)
 from tests.test_data import write_shard  # noqa: E402
@@ -403,6 +405,81 @@ def test_ring_buffer_bound_under_prefetch_and_packing(tmp_path):
             assert rec.nbytes() <= 3 * per_batch
     finally:
         loader.close()
+
+
+# -- the dispatch key: kept as handed over, read at dump time ------------------
+
+class _DeviceKey:
+    """Stands for the dispatch key as the train loop hands it over, a
+    device array: turning it into numpy IS the device-to-host read, which
+    cannot return before the step in flight has ended."""
+
+    def __init__(self, value):
+        self.value, self.readable, self.reads = value, False, 0
+
+    def __array__(self, dtype=None, copy=None):
+        if not self.readable:
+            raise RuntimeError("the dispatch key was read off the device")
+        self.reads += 1
+        return self.value
+
+
+def test_record_dispatch_leaves_the_key_on_the_device(tmp_path):
+    """record_dispatch must not wait for the device (until PR 24 it did,
+    for most of a step, under no span of the loop): the key is converted
+    when a bundle is dumped, and the bundle holds the same key."""
+    rec = FlightRecorder(str(tmp_path / "fr"), window=4,
+                         run_info=dict.fromkeys(fr.REQUIRED_RUN_KEYS, 0),
+                         model_config={"hidden_size": 8,
+                                       "num_hidden_layers": 1})
+    keys = [_DeviceKey(np.array([7, i], np.uint32)) for i in range(3)]
+    for i, key in enumerate(keys):
+        rec.capture_batch(_fake_batch(i))
+        rec.record_dispatch(i + 1, 1, key)      # raises if it converts
+    assert [k.reads for k in keys] == [0, 0, 0]
+    assert rec.nbytes() > 0
+    for key in keys:
+        key.readable = True
+    bundle = rec.dump("nonfinite", trigger_step=3)
+    assert fr.validate_bundle(bundle) == []
+    with np.load(os.path.join(bundle, "batches.npz")) as npz:
+        for i in range(3):
+            np.testing.assert_array_equal(npz[f"s{i + 1:08d}__rng"],
+                                          [7, i])
+
+
+def test_dump_ends_without_a_key_it_cannot_read(tmp_path, monkeypatch):
+    """A hung device never hands the key of the step in flight over (the
+    watchdog's dump): that step is left out and named, the bundle is
+    valid, and dump() returns."""
+    monkeypatch.setattr(fr, "KEY_READ_PATIENCE_S", 0.3)
+    rec = FlightRecorder(str(tmp_path / "fr"), window=4,
+                         run_info=dict.fromkeys(fr.REQUIRED_RUN_KEYS, 0),
+                         model_config={"hidden_size": 8,
+                                       "num_hidden_layers": 1})
+    done, hung = _DeviceKey(np.array([1, 2], np.uint32)), _DeviceKey(None)
+    done.readable = True
+    for step, key in ((1, done), (2, hung)):
+        rec.capture_batch(_fake_batch(step))
+        rec.record_dispatch(step, 1, key)
+    bundle = rec.dump("watchdog_device_hang")
+    assert fr.validate_bundle(bundle) == []
+    manifest = json.load(open(os.path.join(bundle, "manifest.json")))
+    assert [r["step"] for r in manifest["records"]] == [1]
+    assert manifest["unkeyed_steps"] == [2]
+
+
+def test_bundle_keys_are_the_loops_dispatch_keys(nan_run):
+    """End to end: the loop hands the recorder device arrays, and the
+    bundle that replays bit-identically (below) holds fold_in(base, step)
+    for every recorded step."""
+    bundle = nan_run["bundles"][0]
+    base = jax.random.PRNGKey(42 + 1000)    # --seed default + 1000 + rank 0
+    with np.load(os.path.join(bundle, "batches.npz")) as npz:
+        for step in (1, 2, 3):
+            np.testing.assert_array_equal(
+                npz[f"s{step:08d}__rng"],
+                np.asarray(jax.random.fold_in(base, step)))
 
 
 # -- crash safety ------------------------------------------------------------

@@ -246,6 +246,137 @@ def test_stepwatch_mfu_and_phases_hand_computed():
     assert sw.step_done() is None
 
 
+class _Spans:
+    """An injected annotation factory: records when each span opened and
+    closed on the injected clock."""
+
+    def __init__(self, clock):
+        self.clock, self.log = clock, []
+
+    def __call__(self, name):
+        from contextlib import contextmanager
+
+        @contextmanager
+        def span():
+            self.log.append((name, "open", self.clock[0]))
+            try:
+                yield
+            finally:
+                self.log.append((name, "close", self.clock[0]))
+        return span()
+
+
+def test_stepwatch_phase_is_its_own_trace_annotation():
+    """phase(x) opens `host/x` itself, at its own clock readings: no site
+    pairs the two by hand, and the span a trace shows is the span the
+    record sums (also when the phase is left by an exception)."""
+    clock = [0.0]
+    spans = _Spans(clock)
+    sw = StepWatch(flops_per_step=1.0, seqs_per_step=1, seq_len=1,
+                   peak_flops=None, log_freq=1, time_fn=lambda: clock[0],
+                   annotate=spans)
+    heard = []
+    sw.phase_listener = lambda name, entering: heard.append((name, entering))
+    clock[0] = 1.0
+    with sw.phase("dispatch"):
+        clock[0] = 1.25
+    with pytest.raises(KeyError):
+        with sw.phase("checkpoint"):
+            clock[0] = 1.75
+            raise KeyError("lost")
+    assert spans.log == [("host/dispatch", "open", 1.0),
+                         ("host/dispatch", "close", 1.25),
+                         ("host/checkpoint", "open", 1.25),
+                         ("host/checkpoint", "close", 1.75)]
+    assert heard == [("dispatch", True), ("dispatch", False),
+                     ("checkpoint", True), ("checkpoint", False)]
+    rec = sw.step_done()
+    assert rec["dispatch_ms"] == pytest.approx(250.0)
+    assert rec["checkpoint_ms"] == pytest.approx(500.0)
+
+
+def test_stepwatch_loop_unaccounted_is_wall_less_leaf_phases():
+    """Every [perf] record carries the residual of the host account; a
+    phase entered from inside another counts once (its seconds come off
+    the one around it), so the phases never sum past the wall time."""
+    clock = [0.0]
+    sw = StepWatch(flops_per_step=1.0, seqs_per_step=1, seq_len=1,
+                   peak_flops=None, log_freq=2, time_fn=lambda: clock[0],
+                   annotate=_Spans(clock))
+    for _ in range(2):
+        with sw.phase("data_wait"):
+            clock[0] += 0.010
+        clock[0] += 0.003                   # under no phase
+        with sw.phase("log"):
+            clock[0] += 0.004
+            with sw.phase("metric_flush"):  # entered from inside `log`
+                clock[0] += 0.100
+            clock[0] += 0.001
+        rec = sw.step_done()
+    assert rec["step_time_ms"] == pytest.approx(118.0)
+    assert rec["data_wait_ms"] == pytest.approx(10.0)
+    assert rec["metric_flush_ms"] == pytest.approx(100.0)
+    assert rec["log_ms"] == pytest.approx(5.0)      # 105 less the 100 inside
+    assert rec["loop_unaccounted_ms"] == pytest.approx(3.0)
+    phases = sum(v for k, v in rec.items() if k.endswith("_ms")
+                 and k not in ("step_time_ms", "loop_unaccounted_ms"))
+    assert phases + rec["loop_unaccounted_ms"] == \
+        pytest.approx(rec["step_time_ms"])
+
+
+def test_setup_watch_counters_add_up_and_only_grow():
+    """The set-up account: spans less the compiling inside them, the
+    compile counter, and the residual add up to the wall time from
+    main()'s entry to the first loss; the counters never fall, an open
+    span counts nothing yet, and nothing moves after the account closes."""
+    from types import SimpleNamespace
+
+    from bert_pytorch_tpu.telemetry import SetupWatch
+
+    clock = [100.0]
+    spans = _Spans(clock)
+    setup = SetupWatch(start=100.0, time_fn=lambda: clock[0], annotate=spans)
+    compiles = setup.compile_watch = SimpleNamespace(compile_secs=0.0)
+    seen = [setup.snapshot()]
+    clock[0] = 112.0
+    setup.end("backend")                    # 12 s
+    clock[0] = 112.5                        # 0.5 s between spans
+    with setup.span("data"):
+        clock[0] = 114.5                    # 2 s
+    seen.append(setup.snapshot())
+    with setup.span("state"):
+        clock[0] = 120.5                    # 6 s, 1.5 s of them compiling
+        compiles.compile_secs = 1.5
+    setup.begin("data")                     # the first batch: 0.25 s more
+    clock[0] = 120.75
+    setup.end("data")
+    with setup.span("lower"):
+        clock[0] = 123.75                   # 3 s
+    setup.begin("first_step")
+    seen.append(setup.snapshot())           # first [perf] record: still open
+    clock[0] = 133.75                       # 10 s, 8 s of them compiling
+    compiles.compile_secs = 9.5
+    setup.end("first_step")
+    seen.append(setup.snapshot())
+    clock[0] = 500.0
+    compiles.compile_secs = 11.0            # a later recompile: not set-up
+    with setup.span("state"):
+        clock[0] = 600.0
+    seen.append(setup.snapshot())
+    assert seen[3] == {
+        "setup_backend_s": 12.0, "setup_data_s": 2.25, "setup_state_s": 4.5,
+        "setup_lower_s": 3.0, "setup_first_step_s": 2.0,
+        "setup_unaccounted_s": 0.5}
+    assert seen[2]["setup_first_step_s"] == 0.0
+    assert seen[4] == seen[3]
+    assert sum(seen[3].values()) + 9.5 == pytest.approx(133.75 - 100.0)
+    for before, after in zip(seen, seen[1:]):
+        assert all(after[k] >= before[k] for k in before), (before, after)
+    assert [e[:2] for e in spans.log][:4] == [
+        ("host/setup/backend", "open"), ("host/setup/backend", "close"),
+        ("host/setup/data", "open"), ("host/setup/data", "close")]
+
+
 def test_stepwatch_steps_per_loop_counting():
     clock = [0.0]
     sw = StepWatch(flops_per_step=1e9, seqs_per_step=8, seq_len=64,
