@@ -65,14 +65,25 @@ class BertConfig:
     #   "auto"           xla through seq 256, pallas beyond (measured v5e
     #                    crossover)
     attention_impl: str = "auto"
-    # Remat policy when checkpoint_activations=True: "nothing" recomputes the
-    # whole layer in backward (max memory savings, most recompute — the
-    # reference's torch.utils.checkpoint behavior); "dots" saves matmul
-    # outputs and recomputes only elementwise/LayerNorm/dropout chains
-    # (jax.checkpoint_policies.dots_saveable) — nearly no-remat speed at a
-    # fraction of the activation memory, usually the best throughput/batch
-    # trade on TPU.
-    remat_policy: str = "nothing"
+    # What the backward pass finds saved when checkpoint_activations=True
+    # (models/bert.py _REMAT_POLICIES; nothing is read without the flag):
+    #   "auto"     "dense" where the compiled step fits the device, else
+    #              "nothing": run_pretraining.py compiles the step once in
+    #              set-up and holds the compiler's peak against the device's
+    #              bytes_limit (training/pretrain.resolve_remat_policy). A
+    #              model built outside that entry point takes "dense".
+    #   "dense"    the layer's input and the outputs of its qkv and
+    #              mlp_output projections (2T(3E+E) bytes a layer): those
+    #              two matmuls run once, the rest of the layer twice. The
+    #              other two projections' outputs cost more to keep than
+    #              to recompute on a v5e (PERF.md, PR 25)
+    #   "nothing"  the layer's input alone: the whole layer runs twice (max
+    #              memory savings — the reference's torch.utils.checkpoint)
+    #   "dots"     every matmul output, the attention core's (B, H, S, S)
+    #              scores and context included (dots_saveable)
+    #   "mlp_only" everything but the (B, S, F) wide-MLP activations
+    # A value other than "auto" is taken as written.
+    remat_policy: str = "auto"
     # lax.scan unroll factor for the layer stack. 1 = compiled while loop
     # (O(1) compile time in depth — the multi-chip default). Higher values
     # unroll the loop body; num_hidden_layers removes the loop entirely,

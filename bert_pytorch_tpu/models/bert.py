@@ -206,6 +206,7 @@ class BertSelfAttention(nn.Module):
             name="qkv")(hidden)
         if cfg.kfac_taps:
             qkv = self.perturb("qkv_tap", qkv)
+        qkv = checkpoint_name(qkv, "qkv_out")   # DENSE_SAVED, below
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
         # "auto" resolves by sequence length inside dot_product_attention
@@ -307,6 +308,7 @@ class BertLayer(nn.Module):
                 name="mlp_output")(inter)
             if cfg.kfac_taps:
                 mlp_out = self.perturb("mlp_output_tap", mlp_out)
+            mlp_out = checkpoint_name(mlp_out, "mlp_out")   # DENSE_SAVED
             hidden = ResidualDropoutLayerNorm(
                 rate=cfg.hidden_dropout_prob, fused=cfg.fused_ops,
                 fused_dropout=cfg.fused_dropout_ln,
@@ -331,8 +333,20 @@ class _EncoderBody(nn.Module):
         return hidden, None
 
 
+# What remat_policy="dense" keeps of a layer besides its input, by
+# checkpoint_name: the outputs of the qkv and mlp_output projections. These
+# two pay for their bytes on a v5e: a 0.27 ms and a 0.38 ms matmul spared
+# for 50 MB and 17 MB that cross HBM three times (stacked by the forward
+# scan, sliced out and read by the backward scan). The attention output
+# projection and anything (T, F)-wide (the intermediate projection, the
+# activation) cost more to keep than to redo, the latter because XLA then
+# fuses the erf-GELU into the input of its consumers (PERF.md, PR 25: the
+# subsets raced at BERT-Large b64 s128).
+DENSE_SAVED = ("qkv_out", "mlp_out")
+
 _REMAT_POLICIES = {
     "nothing": jax.checkpoint_policies.nothing_saveable,
+    "dense": jax.checkpoint_policies.save_only_these_names(*DENSE_SAVED),
     "dots": jax.checkpoint_policies.dots_saveable,
     # recompute ONLY the (B, S, F) wide-MLP activations (tagged
     # checkpoint_name "mlp_wide" in BertLayer); attention stays
@@ -340,6 +354,12 @@ _REMAT_POLICIES = {
     "mlp_only": jax.checkpoint_policies
     .save_anything_except_these_names("mlp_wide"),
 }
+# remat_policy="auto", in order of preference: the entry point takes the
+# first whose compiled step the device holds and the last whatever it
+# needs (training/pretrain.resolve_remat_policy); a model built with
+# "auto" still in its config takes the first.
+REMAT_AUTO_ORDER = ("dense", "nothing")
+_REMAT_POLICIES["auto"] = _REMAT_POLICIES[REMAT_AUTO_ORDER[0]]
 
 
 class BertEncoder(nn.Module):
@@ -355,7 +375,13 @@ class BertEncoder(nn.Module):
     leading L axis, wgrads write straight into per-layer leaves (no DUS
     traffic — docs/PERF.md seq512 budget), compile time O(L).
     checkpoint_activations=True wraps the (scanned or per-layer) body in
-    nn.remat (reference: torch checkpointing in sqrt(L) chunks).
+    nn.remat (reference: torch checkpointing in sqrt(L) chunks). What the
+    backward pass then finds saved is config.remat_policy's to say, by the
+    one table above for both layouts: by default ("auto") the layer's input
+    and DENSE_SAVED, so the qkv and mlp_output matmuls run once and the
+    rest of the layer twice; "nothing" (the layer's input alone, the whole
+    layer runs twice) where the entry point found, from the compiled step's
+    memory against the device's, that the saved values do not fit.
     """
 
     config: BertConfig

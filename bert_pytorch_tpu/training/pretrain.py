@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import optax
 
 from bert_pytorch_tpu.models import losses
+from bert_pytorch_tpu.models.bert import REMAT_AUTO_ORDER
 from bert_pytorch_tpu.telemetry.health import (HealthConfig,
                                                global_norm_f32,
                                                health_signals, health_update,
@@ -856,6 +857,18 @@ class StepProgram:
     def as_text(self) -> Optional[str]:
         return self.compiled.as_text() if self.compiled is not None else None
 
+    def peak_bytes(self) -> int:
+        """The compiler's own statement of the program's peak (arguments,
+        outputs and temporaries alive together at the worst point of its
+        schedule); 0 where nothing AOT-compiled or the backend states
+        none."""
+        if self.compiled is None:
+            return 0
+        try:
+            return int(self.compiled.memory_analysis().peak_memory_in_bytes)
+        except Exception:
+            return 0
+
     def fingerprint(self) -> Optional[Dict[str, Any]]:
         """Structural identity (collective counts + donation hash) of the
         compiled program, or None if nothing AOT-compiled (fallback mode).
@@ -865,6 +878,40 @@ class StepProgram:
         from bert_pytorch_tpu.analysis.hlo import program_fingerprint
 
         return program_fingerprint(self.compiled)
+
+
+def resolve_remat_policy(build: Callable[[str], StepProgram], args,
+                         bytes_limit: Optional[int],
+                         candidates: Tuple[str, ...] = REMAT_AUTO_ORDER,
+                         log: Callable[[str], None] = print):
+    """remat_policy="auto", decided from what can be observed: compile the
+    step under each candidate in turn and take the first whose peak, as the
+    compiler states it, the device holds. `build(policy)` returns the
+    StepProgram of the step built with that policy, `args` are the step's
+    (state, batch, rng) or their avals, `bytes_limit` the device's memory
+    (telemetry.hbm_snapshot()["hbm_bytes_limit"]; None where the backend
+    states none, and then the first candidate is taken). A candidate is
+    passed over where its peak is above the limit or its compile fails for
+    memory (the TPU compiler refuses a program it cannot place); the last
+    is taken whatever it needs. Returns (policy, program): the program is
+    compiled, so the loop that runs it compiles nothing more.
+    """
+    for policy in candidates:
+        last = policy == candidates[-1]
+        program = build(policy)
+        try:
+            program.compile(*args)
+        except jax.errors.JaxRuntimeError as e:
+            if last or "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            log(f"remat_policy auto: '{policy}' does not compile for memory "
+                f"({str(e).splitlines()[0][:200]})")
+            continue
+        peak = program.peak_bytes()
+        if last or not bytes_limit or peak <= bytes_limit:
+            return policy, program
+        log(f"remat_policy auto: '{policy}' peaks at {peak} bytes, over "
+            f"the device's {bytes_limit}")
 
 
 def step_input_expectations(abstract_state, state, batch, mesh,

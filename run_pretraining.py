@@ -626,7 +626,8 @@ def main(argv=None):
         CheckpointManager, build_pretrain_step, make_sharded_state)
     from bert_pytorch_tpu.training.pretrain import (StepProgram,
                                                     stack_microbatches,
-                                                    chain_steps)
+                                                    chain_steps,
+                                                    resolve_remat_policy)
 
     # set-up spans (telemetry/stepwatch.SetupWatch): backend | data | state
     # | lower | first_step, closed by the first step's loss on the host;
@@ -1066,27 +1067,22 @@ def main(argv=None):
                 model, kfac, state,
                 (stacked["input_ids"][0], stacked["token_type_ids"][0],
                  stacked["attention_mask"][0]))
+
+        def build_step(model):
             # gathered MLM head: score only the <=max_predictions_per_seq
             # masked positions (the loader caps masking there, so the loss
             # is exact)
-            step_fn = build_kfac_pretrain_step(
-                model, tx, kfac, pert_template, schedule=schedule,
-                accum_steps=accum_steps,
+            common = dict(
+                schedule=schedule, accum_steps=accum_steps,
                 max_predictions=max_pred_row,
                 grad_dtype=grad_dtype, zero1=plan, health=health_cfg,
                 nan_inject_step=args.inject_nonfinite_step,
                 norm_reducer=norm_reducer)
-            if kfac.bucket_assignment is not None:
-                logger.info("kfac: bucketed factor reductions — "
-                            f"{len(kfac.bucket_assignment)} bucket(s): "
-                            + json.dumps(kfac.bucket_assignment))
-        else:
-            step_fn = build_pretrain_step(
-                model, tx, schedule=schedule, accum_steps=accum_steps,
-                max_predictions=max_pred_row,
-                grad_dtype=grad_dtype, zero1=plan, health=health_cfg,
-                nan_inject_step=args.inject_nonfinite_step,
-                norm_reducer=norm_reducer)
+            if kfac is not None:
+                return build_kfac_pretrain_step(model, tx, kfac,
+                                                pert_template, **common)
+            return build_pretrain_step(model, tx, **common)
+
         epoch = 0
         if manager.latest_step() is not None:
             abstract = jax.tree.map(
@@ -1125,11 +1121,43 @@ def main(argv=None):
         # one XLA compile, but the executable's HLO stays reachable for
         # the program fingerprint below (and tools/graphcheck.py gates the
         # same builders' compiled structure in CI)
-        jit_step = StepProgram(step_fn)
+        if config.checkpoint_activations and config.remat_policy == "auto":
+            # what the rematted layer saves is decided here, once, from the
+            # compiled step's memory against the device's: the program
+            # that fits is the one the loop runs, compiled already
+            def program_for(policy):
+                return StepProgram(build_step(BertForPreTraining(
+                    config.replace(remat_policy=policy),
+                    dtype=compute_dtype)))
+
+            with mesh, mesh_lib.logical_rules(), setup.span("lower"):
+                policy, jit_step = resolve_remat_policy(
+                    program_for,
+                    # a batch and a key placed as the loop places them
+                    (state, mesh_lib.host_to_device_batch(mesh, stacked),
+                     jax.random.fold_in(jax.random.PRNGKey(args.seed), 1)),
+                    hbm_snapshot().get("hbm_bytes_limit"), log=logger.info)
+            config = config.replace(remat_policy=policy)
+            model = BertForPreTraining(config, dtype=compute_dtype)
+        else:
+            jit_step = StepProgram(build_step(model))
+        remat_name = (config.remat_policy if config.checkpoint_activations
+                      else "off")
+        logger.info(f"activation checkpointing: remat_policy={remat_name}")
+        # set once: whether the rematted layer keeps projection outputs
+        # (models/bert.DENSE_SAVED), and (after the first dispatch) the
+        # compiler's peak of the step
+        step_fields = {"remat_saves_dense": int(remat_name == "dense"),
+                       "step_peak_bytes": 0}
         steps_per_loop = max(1, args.steps_per_loop)
-        jit_chunk = (StepProgram(chain_steps(step_fn, steps_per_loop,
+        jit_chunk = (StepProgram(chain_steps(build_step(model),
+                                             steps_per_loop,
                                              per_step_batch=True))
                      if steps_per_loop > 1 else None)
+        if kfac is not None and kfac.bucket_assignment is not None:
+            logger.info("kfac: bucketed factor reductions — "
+                        f"{len(kfac.bucket_assignment)} bucket(s): "
+                        + json.dumps(kfac.bucket_assignment))
 
         # -- double-buffered h2d (round 11) ---------------------------------
         # DevicePrefetcher keeps the next batch's device_put in flight while
@@ -1399,6 +1427,7 @@ def main(argv=None):
             tel.log_header(
                 **prov,
                 program_fingerprint=fp["hash"],
+                remat_policy=remat_name,
                 program_collectives=" ".join(
                     f"{k}={v}" for k, v in sorted(
                         fp["collective_counts"].items())),
@@ -1678,8 +1707,9 @@ def main(argv=None):
                                                       global_step + 1)
                         if dispatches == 0:
                             setup.end("data")
-                            with setup.span("lower"):
-                                program.lower(state, batch, step_rng)
+                            if program.lowered is None:
+                                with setup.span("lower"):
+                                    program.lower(state, batch, step_rng)
                             setup.begin("first_step")
                         if chaos is not None:
                             chaos.stall(global_step + 1)
@@ -1698,6 +1728,8 @@ def main(argv=None):
                         dispatches += 1
                         if dispatches == 1:
                             fp_thread.start()
+                            step_fields["step_peak_bytes"] = \
+                                program.peak_bytes()
                         maybe_log_fingerprint()
                     flush_pending()
                     pending = (global_step, epoch, metrics)
@@ -1714,6 +1746,7 @@ def main(argv=None):
                                 compile_watch.mark_steady()
                             perf.update(compile_watch.snapshot())
                             perf.update(hbm_snapshot())
+                            perf.update(step_fields)
                             tel.log_perf(global_step, perf)
                     if trace_active and global_step >= profile_range[1]:
                         with sw.phase("profile"):

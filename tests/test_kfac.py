@@ -301,8 +301,13 @@ def test_kfac_taps_present_only_when_enabled():
     assert "perturbations" not in v2
 
 
-@pytest.mark.slow  # re-tiered out of tier-1's 870s wall-clock budget
-def test_kfac_taps_under_remat():
+@pytest.mark.parametrize("policy", [
+    # re-tiered out of tier-1's 870s wall-clock budget
+    pytest.param("nothing", marks=pytest.mark.slow),
+    # the default under --checkpoint_activations: the qkv and mlp_output
+    # taps' perturb sits just before a saved value
+    "dense"])
+def test_kfac_taps_under_remat(policy):
     """sow/perturb taps re-fire during nn.remat's recomputed forward:
     K-FAC under activation checkpointing must produce the same loss, grads,
     factor statistics and updated params as the un-rematted model (the
@@ -310,7 +315,7 @@ def test_kfac_taps_under_remat():
     run_pretraining.py:257-258,311-345)."""
     def one_step(remat):
         cfg = KFAC_TINY.replace(checkpoint_activations=remat,
-                                remat_policy="nothing",
+                                remat_policy=policy,
                                 hidden_dropout_prob=0.0,
                                 attention_probs_dropout_prob=0.0)
         _, _, step_fn, state, batch = _kfac_setup(accum=2, cfg=cfg)

@@ -335,13 +335,8 @@ def test_tf_conversion_emits_unstacked_layout():
             == jax.tree_util.tree_structure(jax.tree.map(np.shape, want)))
 
 
-def test_unstacked_remat_matches_no_remat():
-    ids, types, mask = _inputs()
-    m1 = BertForPreTraining(UNSTACKED, dtype=jnp.float32)
-    m2 = BertForPreTraining(UNSTACKED.replace(checkpoint_activations=True),
-                            dtype=jnp.float32)
-    params = m1.init(jax.random.PRNGKey(0), ids, types, mask)
-    out1, _ = m1.apply(params, ids, types, mask)
-    out2, _ = m2.apply(params, ids, types, mask)
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
-                               rtol=1e-5, atol=1e-5)
+@pytest.mark.parametrize("policy", [None, "dense", "nothing"])
+def test_unstacked_remat_matches_no_remat(policy):
+    from tests.test_model import assert_remat_matches
+
+    assert_remat_matches(BertForPreTraining, UNSTACKED, policy, _inputs())

@@ -4,6 +4,7 @@ numpy implementations, tied-decoder behavior, remat equivalence."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from bert_pytorch_tpu.config import BertConfig
 from bert_pytorch_tpu.models import (
@@ -125,16 +126,43 @@ def test_tied_decoder_grads_flow_to_embedding():
     assert float(jnp.abs(emb_grad).sum()) > 0
 
 
-def test_remat_matches_no_remat():
-    ids, types, mask = _inputs()
-    m1 = BertModel(TINY, dtype=jnp.float32)
-    m2 = BertModel(TINY.replace(checkpoint_activations=True),
-                   dtype=jnp.float32)
-    params = m1.init(jax.random.PRNGKey(0), ids, types, mask)
-    out1, _ = m1.apply(params, ids, types, mask)
-    out2, _ = m2.apply(params, ids, types, mask)
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
+def _out_and_grads(model, params, inputs, **kw):
+    """A forward pass with dropout on, and the gradient of a scalar of it
+    with respect to every parameter."""
+    def loss(p):
+        out = model.apply(p, *inputs, deterministic=False,
+                          rngs={"dropout": jax.random.PRNGKey(7)}, **kw)[0]
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    (_, out), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    return out, grads
+
+
+def assert_remat_matches(make_model, cfg, policy, inputs, **kw):
+    """Recomputing changes what is kept, not what is computed: outputs AND
+    gradients equal the un-rematted model's, dropout masks included."""
+    base = make_model(cfg, dtype=jnp.float32)
+    changes = dict(checkpoint_activations=True)
+    if policy is not None:
+        changes["remat_policy"] = policy
+    remat = make_model(cfg.replace(**changes), dtype=jnp.float32)
+    params = base.init(jax.random.PRNGKey(0), *inputs)
+    want_out, want = _out_and_grads(base, params, inputs, **kw)
+    got_out, got = _out_and_grads(remat, params, inputs, **kw)
+    np.testing.assert_allclose(np.asarray(got_out), np.asarray(want_out),
                                rtol=1e-5, atol=1e-5)
+    assert float(sum(jnp.abs(g).sum() for g in jax.tree.leaves(want))) > 0
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5), got, want)
+
+
+# None: the field's default ("auto"), as --checkpoint_activations leaves it
+REMAT_POLICIES = [None, "dense", "nothing", "dots", "mlp_only"]
+
+
+@pytest.mark.parametrize("policy", REMAT_POLICIES)
+def test_remat_matches_no_remat(policy):
+    assert_remat_matches(BertModel, TINY, policy, _inputs())
 
 
 def test_scan_unroll_matches_scanned():

@@ -31,17 +31,18 @@ _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S+\s+([\w\-]+)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def _toy_step(kfac_on: bool):
+def _toy_step(kfac_on: bool, **config):
     """A two-layer BERT's train step as the entry point builds it: two
     micro-batches, gathered MLM head, health pack; LAMB with remat, bf16
-    gradients and the fault-injection drill, or K-FAC."""
+    gradients and the fault-injection drill, or K-FAC. `config` overrides
+    fields of the model's."""
     cfg = BertConfig(
         vocab_size=128, hidden_size=32, num_hidden_layers=2,
         num_attention_heads=4, intermediate_size=64,
         max_position_embeddings=64, next_sentence=True, dtype="float32",
         fused_ops=False, attention_impl="xla", hidden_dropout_prob=0.1,
         attention_probs_dropout_prob=0.1, kfac_taps=kfac_on,
-        checkpoint_activations=not kfac_on)
+        checkpoint_activations=not kfac_on).replace(**config)
     model = BertForPreTraining(cfg, dtype=jnp.float32)
     sched = schedulers.make_schedule("poly", 1e-3, 100, warmup=0.1)
     tx = lamb(sched, weight_decay=0.01,
