@@ -19,7 +19,7 @@ import copy
 import dataclasses
 import json
 import re
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,6 +165,165 @@ class BertConfig:
                 f"num_attention_heads ({self.num_attention_heads})"
             )
         return self.hidden_size // self.num_attention_heads
+
+
+# Keys a model config JSON may carry that no model reads: where the
+# configuration comes from and how it was cut (benchmark/configs/*.json,
+# benchmark/README.md), and the family selector itself.
+DOCUMENTED_DATA_KEYS = ("model_type", "source", "reduced", "assumed",
+                        "layout")
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """Architecture config of the `lfm2_moe` family (LiquidAI LFM2 with
+    routed experts): a pre-norm decoder whose blocks differ in kind — a
+    gated short convolution or causal grouped-query attention as the
+    operator, a dense SwiGLU MLP in the leading layers and sigmoid-routed
+    experts after them (models/lfm2_moe.py has the equations).
+
+    Keys are the source's (`config.json` of the model). A run may hold one
+    expert-parallel rank's share of each layer: `num_experts` experts, the
+    half-open range `experts_held` out of `experts_total` (the router keeps
+    that width), and `vocab_size` rows of the vocabulary. `layers_kept`
+    names the source layers a cut stack keeps (their kinds are read from
+    the published `layer_types`); the first `num_dense_layers` of the stack
+    have the dense MLP.
+    """
+
+    model_type: str = "lfm2_moe"
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_hidden_layers: int = 40
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    layer_types: Tuple[str, ...] = ()
+    layers_kept: Optional[Tuple[int, ...]] = None
+    experts_total: Optional[int] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    norm_eps: float = 1e-5
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    rope_theta: float = 1000000.0
+    max_position_embeddings: int = 128000
+    initializer_range: float = 0.02
+    model_name: Optional[str] = None
+    # run settings, as BertConfig's
+    dtype: str = "bfloat16"
+    checkpoint_activations: bool = False
+    remat_policy: str = "auto"
+    attention_impl: str = "auto"
+
+    # source keys that carry no size of this program's (or a nested group)
+    _IGNORED = ("rope_parameters", "vocab_rows_total", "vocab_rows_held")
+
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any]) -> "Lfm2MoeConfig":
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = [k for k in d if k not in known
+                   and k not in DOCUMENTED_DATA_KEYS
+                   and k not in cls._IGNORED]
+        if unknown:
+            raise ValueError(
+                f"lfm2_moe model config: unknown key(s) {sorted(unknown)}")
+        kw = {k: v for k, v in d.items() if k in known}
+        rope = d.get("rope_parameters") or {}
+        if "rope_theta" in rope:
+            kw["rope_theta"] = float(rope["rope_theta"])
+        for key in ("layer_types", "layers_kept", "experts_held"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        cfg = cls(**kw)
+        cfg.layer_kinds  # raises on an inconsistent cut
+        return cfg
+
+    @classmethod
+    def from_json_file(cls, path: str) -> "Lfm2MoeConfig":
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_dict(json.load(f))
+
+    def to_dict(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    def replace(self, **kw: Any) -> "Lfm2MoeConfig":
+        return dataclasses.replace(self, **kw)
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def router_width(self) -> int:
+        return int(self.experts_total or self.num_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of "
+                f"num_experts={self.num_experts} out of {self.router_width}")
+        return int(lo), int(hi)
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(operator, ffn) of every layer of the stack as run: operator
+        "conv" or "attention", ffn "dense" or "moe"."""
+        kept = (self.layers_kept if self.layers_kept is not None
+                else tuple(range(self.num_hidden_layers)))
+        if len(kept) != self.num_hidden_layers:
+            raise ValueError(
+                f"layers_kept {kept} does not name num_hidden_layers="
+                f"{self.num_hidden_layers} layers")
+        if not self.layer_types or max(kept) >= len(self.layer_types):
+            raise ValueError(
+                "layer_types must give the kind of every source layer "
+                f"that is kept (have {len(self.layer_types)}, kept {kept})")
+        self.held_range
+        kinds = []
+        for j, i in enumerate(kept):
+            kind = self.layer_types[i]
+            if kind not in ("conv", "full_attention"):
+                raise ValueError(f"unknown layer type {kind!r}")
+            kinds.append(("conv" if kind == "conv" else "attention",
+                          "dense" if j < self.num_dense_layers else "moe"))
+        return tuple(kinds)
+
+
+MODEL_FAMILIES = {"bert": BertConfig, "lfm2_moe": Lfm2MoeConfig}
+
+
+def load_model_config(path: str):
+    """The model config of an entry point, by family: `model_type` picks the
+    config class ("bert" where the key is absent). An unknown `model_type`
+    is an error, and so is a key the family does not know (other than
+    DOCUMENTED_DATA_KEYS): a config of another architecture must not be
+    trimmed into a BERT silently."""
+    with open(path, "r", encoding="utf-8") as f:
+        d = json.load(f)
+    family = d.get("model_type", "bert")
+    if family not in MODEL_FAMILIES:
+        raise ValueError(
+            f"{path}: unknown model_type {family!r} (this program runs "
+            f"{sorted(MODEL_FAMILIES)})")
+    cls = MODEL_FAMILIES[family]
+    if cls is BertConfig:
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = sorted(k for k in d if k not in known
+                         and k not in DOCUMENTED_DATA_KEYS)
+        if unknown:
+            raise ValueError(
+                f"{path}: BERT model config carries key(s) BertConfig does "
+                f"not know: {unknown}")
+    return cls.from_dict(d)
 
 
 # student presets: `student_<L>l_<H>` names a depth-L, width-H student of

@@ -179,6 +179,10 @@ class HostShardSampler:
         self.index = state["index"]
 
 
+# what a batch holds under objective="clm"
+CLM_FIELDS = ("input_ids", "attention_mask", "segment_ids", "position_ids")
+
+
 class PretrainingDataLoader:
     """Iterator of ready-to-device batches with background shard prefetch.
 
@@ -218,7 +222,15 @@ class PretrainingDataLoader:
         packing_max_segments: int = 8,
         packing_lookahead: int = 4,
         batch_tap=None,
+        objective: str = "mlm",
     ):
+        if objective not in ("mlm", "clm"):
+            raise ValueError(f"unknown objective {objective!r}")
+        # "clm" (causal language modelling, the decoder families): nothing is
+        # masked and no label is made (the next token is the label); a batch
+        # is input_ids, attention_mask and, packed, segment_ids and
+        # position_ids
+        self.objective = objective
         if not 0 <= masked_lm_prob <= 1:
             raise ValueError("masked_lm_prob must be in [0,1]")
         if original_token_prob + random_token_prob > 1:
@@ -422,6 +434,8 @@ class PretrainingDataLoader:
             return None
         batch = packing_lib.pack_examples(examples, bins, seq_len,
                                           self.packing_max_segments)
+        if self.objective == "clm":
+            batch = {k: batch[k] for k in CLM_FIELDS}
         placed = {i for members in bins for i in members}
         keep = [pos for pos in range(len(self._pending_examples))
                 if pos not in placed]
@@ -437,7 +451,22 @@ class PretrainingDataLoader:
         input_ids = raw["input_ids"].astype(np.int32)
         batch: Dict[str, np.ndarray] = {}
 
-        if "special_token_positions" in raw:
+        if self.objective == "clm":
+            if "special_token_positions" not in raw:
+                raise ValueError("objective 'clm' reads the unmasked shard "
+                                 "schema (special_token_positions)")
+            mask = masking.input_mask_from_specials(
+                input_ids, raw["special_token_positions"]).astype(np.int32)
+            if not self.packing:    # one document a row
+                positions = np.arange(input_ids.shape[1], dtype=np.int32)
+                return {"input_ids": input_ids, "attention_mask": mask,
+                        "segment_ids": mask, "position_ids": positions * mask}
+            # the packer's other per-example fields, empty: the packed batch
+            # keeps CLM_FIELDS only
+            batch = {"input_ids": input_ids, "attention_mask": mask,
+                     "token_type_ids": np.zeros_like(input_ids),
+                     "masked_lm_labels": np.full_like(input_ids, -1)}
+        elif "special_token_positions" in raw:
             specials = raw["special_token_positions"]
             batch["token_type_ids"] = masking.segment_ids_from_specials(
                 input_ids, specials).astype(np.int32)

@@ -99,11 +99,18 @@ def _require_flash(q, k, interpret: bool) -> None:
             f"attention impl='pallas' needs a TPU backend (this is "
             f"{jax.default_backend()!r}); set BPT_PALLAS_INTERPRET=1 to run "
             "the kernel in interpret mode, or use impl='auto'/'xla'")
-    if q.shape[1] % 128 or q.shape != k.shape:
+    if q.shape[1] % 128 or not _self_attention_shapes(q, k):
         raise ValueError(
             "attention impl='pallas' needs self-attention shapes with seq "
             f"a multiple of 128, got q{tuple(q.shape)} k{tuple(k.shape)}; "
             "use impl='auto'/'xla'")
+
+
+def _self_attention_shapes(q, k) -> bool:
+    """Same batch, length and head size, and the query heads a multiple of
+    the key/value heads (grouped heads; equal for BERT)."""
+    return (q.shape[:2] == k.shape[:2] and q.shape[3] == k.shape[3]
+            and q.shape[2] % k.shape[2] == 0)
 
 
 def _flash_sharded(mesh, q, k, v, bias, segment_ids, seed, rate: float,
@@ -204,8 +211,15 @@ def dot_product_attention(
     impl: str = "xla",
     trainable_bias: bool = False,
     hash_dropout_impl: bool = True,
+    causal: bool = False,
 ) -> jax.Array:
     """Returns (B, Sq, H, D) in q.dtype.
+
+    `causal`: a query attends to positions <= its own (of its own segment
+    where rows are packed). k/v may carry fewer heads than q (grouped
+    heads: query head i reads key/value head i // (H // Hkv)). Both are
+    served by the flash kernels and the plain XLA path; neither by the ring
+    path nor under a mesh that shards the kernel (an error there).
 
     impl="auto" resolves by sequence length: measured on v5e, the plain XLA
     path (bf16 probs, fp32 softmax stats) beats the blockwise Pallas kernel
@@ -236,6 +250,13 @@ def dot_product_attention(
     # the seq axis via ppermute; O(S_local) memory per device) for every impl
     # except the explicitly-XLA ones, where SPMD's gather-based lowering is
     # the caller's documented choice. impl="ring" forces the ring path.
+    decoder = causal or k.shape[2] != q.shape[2]
+    if decoder and (impl in ("ring", "xla_checkpoint")
+                    or (impl == "pallas" and active_mesh() is not None)):
+        raise NotImplementedError(
+            "causal / grouped-head attention runs through the flash kernels "
+            f"on one device or the plain XLA path, not impl={impl!r} under "
+            "this mesh")
     if impl in ("ring", "pallas") and not trainable_bias:
         mesh = active_mesh()
         seq_sharded = mesh is not None and dict(mesh.shape).get("seq", 1) > 1
@@ -258,7 +279,7 @@ def dot_product_attention(
             # never a silent XLA result ("auto" chooses, and may choose XLA)
             _require_flash(q, k, interpret)
         if ((jax.default_backend() == "tpu" or interpret)
-                and seq % 128 == 0 and q.shape == k.shape):
+                and seq % 128 == 0 and _self_attention_shapes(q, k)):
             from bert_pytorch_tpu.ops.pallas.flash_attention import (
                 flash_attention)
 
@@ -273,7 +294,7 @@ def dot_product_attention(
                 return flash_attention(q, k, v, bias=bias,
                                        segment_ids=segment_ids,
                                        dropout_seed=seed, dropout_rate=rate,
-                                       interpret=interpret)
+                                       interpret=interpret, causal=causal)
             out = _flash_sharded(mesh, q, k, v, bias, segment_ids, seed,
                                  rate, interpret)
             if out is not None:
@@ -294,7 +315,8 @@ def dot_product_attention(
                     deterministic, hash_dropout_impl)
 
     return _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
-                          dropout_rate, deterministic, hash_dropout_impl)
+                          dropout_rate, deterministic, hash_dropout_impl,
+                          causal)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -335,7 +357,12 @@ hash_dropout.defvjp(_hash_dropout_fwd, _hash_dropout_bwd)
 
 def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                    dropout_rate: float, deterministic: bool,
-                   hash_dropout_impl: bool = True) -> jax.Array:
+                   hash_dropout_impl: bool = True,
+                   causal: bool = False) -> jax.Array:
+    if k.shape[2] != q.shape[2]:    # grouped heads: one copy per query head
+        group = q.shape[2] // k.shape[2]
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     depth = q.shape[-1]
     scale = 1.0 / jnp.sqrt(depth).astype(jnp.float32)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -345,6 +372,10 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
         scores = scores + bias.astype(jnp.float32)
     if segment_ids is not None:
         scores = scores + make_segment_attention_bias(segment_ids)
+    if causal:
+        sq, sk = scores.shape[-2:]
+        scores = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :],
+                           scores, SEGMENT_MASK_BIAS)
     # softmax statistics in fp32; the probabilities are cast to the compute
     # dtype BEFORE dropout so the (B, H, S, S) tensors XLA saves for the
     # backward pass (probs + dropped probs) are bf16 — this halves attention

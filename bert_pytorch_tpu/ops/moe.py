@@ -1,0 +1,144 @@
+"""Routed experts, as one expert-parallel rank computes them.
+
+The layer is told which experts it holds (a half-open range of the router's
+width). It routes every token over ALL experts, and computes, for the
+(token, expert) pairs whose expert it holds, that expert's SwiGLU MLP times
+the pair's gate; what the absent experts would add is left out (their rank
+adds it in a deployment; on one chip the partial sum goes on). Nothing here
+stands in for the other ranks or their exchange.
+
+Dropless: every selected pair of a held expert is computed, at any
+imbalance; there is no capacity factor. The device's work follows the pairs:
+they are sorted by expert and the three matrix products are grouped products
+over the held experts (`jax.lax.ragged_dot`, which XLA:TPU lowers to its own
+grouped-matmul kernel whose tiles cover the groups' rows and no others).
+Shapes are static, so the sorted pairs are worked in windows of
+`window_rows` rows (a `lax.scan`, up to all T x k pairs if every selected
+expert of every token were held): a window past the held pairs is skipped by
+a `lax.cond`, so at an even load one window runs, and each window is
+rematerialised, so one window's temporaries are alive whatever the
+imbalance. (Running the first window in line and keeping its gathered tokens
+and up-projections for the backward pass was tried: the step then needs
+18.5 of the chip's 15.75 GiB.)
+
+Scopes (training/pretrain.LM_STEP_SCOPES): `moe/router`, `moe/dispatch`,
+`moe/experts`, `moe/combine`, each opened here under its whole name: inside
+the scan, the cond and the remat an operation's `op_name` keeps the scopes
+opened in the body, not the caller's.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Routing(NamedTuple):
+    experts: jax.Array      # (T, k) int32: the selected experts, of all
+    gates: jax.Array        # (T, k) float32: their weights
+
+
+@jax.named_scope("moe/router")
+def route(x: jax.Array, kernel: jax.Array, expert_bias, k: int,
+          norm_topk: bool, scaling: float) -> Routing:
+    """Sigmoid scores of x (T, H) over the router's E outputs, in float32:
+    the k experts with the largest score + `expert_bias` are selected (the
+    bias is a buffer: it takes no gradient), the weights are the selected
+    scores WITHOUT the bias, divided by their sum + 1e-6 if `norm_topk`,
+    times `scaling`."""
+    logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    select = scores if expert_bias is None else (
+        scores + expert_bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(jax.lax.stop_gradient(select), k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-6)
+    return Routing(experts.astype(jnp.int32), gates * scaling)
+
+
+def _window_sizes(sizes: jax.Array, start, rows: int) -> jax.Array:
+    """Rows of each (expert-sorted) group that fall in [start, start + rows)."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    return jnp.clip(jnp.minimum(ends, start + rows)
+                    - jnp.maximum(starts, start), 0, None).astype(jnp.int32)
+
+
+def _swiglu_experts(xs, w1, w3, w2, sizes):
+    """Grouped SwiGLU: rows of xs grouped by expert (`sizes` rows each, in
+    order) through w2_e(silu(w1_e x) * w3_e x)."""
+    h1 = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=xs.dtype)
+    h3 = jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=xs.dtype)
+    h = (jax.nn.silu(h1.astype(jnp.float32))
+         * h3.astype(jnp.float32)).astype(xs.dtype)
+    return jax.lax.ragged_dot(h, w2, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _window(out, done, x, w1, w3, w2, tokens, gates, sizes, n_pairs, start):
+    """`out` (T, H) float32 plus the contribution of the sorted pairs in
+    [start, start + len(tokens)), and `done` plus how many of them were
+    computed: gather their tokens, the grouped experts, gate, and add into
+    the tokens' rows. Rows past the held pairs belong to no group: they are
+    zeroed going in and coming out (a grouped product leaves such rows
+    unwritten)."""
+    rows = tokens.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        live = (start + jnp.arange(rows) < n_pairs)[:, None]
+        xs = jnp.where(live, x[tokens], jnp.zeros([], x.dtype))
+        group_rows = _window_sizes(sizes, start, rows)
+    with jax.named_scope("moe/experts"):
+        ys = _swiglu_experts(xs, w1, w3, w2, group_rows)
+    with jax.named_scope("moe/combine"):
+        ys = jnp.where(live, ys * gates[:, None], 0.0)
+        return out.at[tokens].add(ys), done + jnp.sum(group_rows)
+
+
+def held_experts(x: jax.Array, routing: Routing, w1: jax.Array,
+                 w3: jax.Array, w2: jax.Array, held: Tuple[int, int],
+                 window_rows: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """sum over the selected AND held experts e of gate_e * w2_e(silu(w1_e
+    x) * w3_e x), for x (T, H); w1, w3 (E_held, H, F), w2 (E_held, F, H);
+    `held` = (lo, hi) of the router's outputs. Returns (the sum (T, H)
+    float32, tokens per held expert (E_held,) int32, held pairs NOT computed
+    () int32: zero by construction, counted so that it is seen to be)."""
+    lo, hi = held
+    n_held = hi - lo
+    t, k = routing.experts.shape
+    pairs = t * k
+    window_rows = min(int(window_rows), pairs)
+    n_windows = -(-pairs // window_rows)
+    with jax.named_scope("moe/dispatch"):
+        expert = routing.experts.reshape(-1)
+        is_held = (expert >= lo) & (expert < hi)
+        local = jnp.where(is_held, expert - lo, n_held)     # absent: last
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :],
+                        axis=0, dtype=jnp.int32)
+        n_pairs = jnp.sum(sizes)
+        pad = n_windows * window_rows - pairs       # rows of no pair
+        tokens = jnp.pad((order // k).astype(jnp.int32), (0, pad))
+        gates = jnp.pad(routing.gates.reshape(-1)[order], (0, pad))
+
+    @jax.checkpoint
+    def window(out, done, x, w1, w3, w2, tokens, gates, start):
+        # a skipped window hands the sum on untouched
+        return jax.lax.cond(
+            start < n_pairs,
+            lambda: _window(out, done, x, w1, w3, w2, tokens, gates, sizes,
+                            n_pairs, start),
+            lambda: (out, done))
+
+    def body(carry, inp):
+        return window(*carry, x, w1, w3, w2, *inp), None
+
+    (out, done), _ = jax.lax.scan(
+        body, (jnp.zeros(x.shape, jnp.float32), jnp.zeros([], jnp.int32)),
+        (tokens.reshape(n_windows, window_rows),
+         gates.reshape(n_windows, window_rows),
+         jnp.arange(n_windows, dtype=jnp.int32) * window_rows))
+    return out, sizes, n_pairs - done

@@ -53,6 +53,16 @@ tile-layout-dependent garbage), so pad activations are identical across
 skip settings, layouts and the XLA fallback — keeping full-(B, S, E)
 consumers like the K-FAC factor taps kernel-configuration-independent.
 Their gradients are zero because no loss term reads pad positions.
+
+**Causal attention and grouped heads** (`causal=True`; k/v with fewer heads
+than q — the decoder families, models/lfm2_moe.py): a query attends to
+earlier-or-equal positions only, of its own segment where rows are packed.
+The mask is one more condition of the same `jnp.where`, and a (q, k) tile
+that lies wholly above the diagonal is skipped like a tile of disjoint
+segments. With H query heads over H/G key/value heads the kernels read the
+key/value head of a query head through the block index maps (no repeated
+copy of K and V); dk and dv come out per query head in float32 and the G of
+a group are summed outside. Both take the bh layout whatever the shape.
 """
 
 from __future__ import annotations
@@ -63,6 +73,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 # Block sizes (env-overridable for tuning sweeps). 512x512 measured 13%
@@ -116,15 +127,40 @@ def _seg_overlap(segq, segk):
     return (qmx > 0) & (kmx > 0) & (qmx >= kmn) & (kmx >= qmn)
 
 
-def _maybe_skip(has_segments: bool, segq, segk, tile_fn, carry):
+def _maybe_skip(has_segments: bool, segq, segk, tile_fn, carry, live=None):
     """Run tile_fn(carry) -> carry, skipping it when segment ranges prove
     the tile all-masked. Without segments (or with FLASH_SEG_SKIP=0) the
     tile always runs; masked tiles then contribute exact zeros, so both
-    settings produce bit-identical non-pad outputs."""
-    if not has_segments or not _seg_skip_enabled():
+    settings produce bit-identical non-pad outputs. `live` (causal
+    attention): a scalar that is false where the tile lies wholly above
+    the diagonal; None where attention is bidirectional."""
+    pred = None
+    if has_segments and _seg_skip_enabled():
+        pred = _seg_overlap(segq, segk)
+    if live is not None:
+        pred = live if pred is None else pred & live
+    if pred is None:
         return tile_fn(carry)
-    return jax.lax.cond(_seg_overlap(segq, segk), tile_fn,
-                        lambda c: c, carry)
+    return jax.lax.cond(pred, tile_fn, lambda c: c, carry)
+
+
+def _causal_live(causal: bool, q0, bq: int, k0):
+    """Does the (q, k) tile at rows q0.., columns k0.. hold any pair with
+    k <= q? None where attention is not causal."""
+    return (k0 <= q0 + (bq - 1)) if causal else None
+
+
+def _mask(s, causal: bool, q0, k0, has_segments: bool, segq, segk):
+    """Scores with the disallowed pairs at NEG_INF: other segments and pad
+    (packed rows), later positions (causal)."""
+    allowed = _seg_allowed(segq, segk) if has_segments else None
+    if causal:
+        bq, bk = s.shape
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q0
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k0
+        tri = rows >= cols
+        allowed = tri if allowed is None else allowed & tri
+    return s if allowed is None else jnp.where(allowed, s, NEG_INF)
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -165,7 +201,7 @@ def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 o_ref, lse_ref, *, scale: float, blk_k: int, rate: float,
                 has_bias: bool, has_segments: bool, heads_per_prog: int,
-                heads_per_row: int):
+                heads_per_row: int, causal: bool = False):
     """One program per (row, head group, q-block) of a (rows, S, lanes)
     array; it loops the `heads_per_prog` heads that share its lane block
     (static lane slices of width D), then the k-blocks. Serves both layouts
@@ -181,6 +217,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
     segq = segq_ref[0, 0] if has_segments else None
+    q0 = qi * bq if causal else 0   # first row of this tile (causal only)
 
     for t in range(heads_per_prog):
         lanes = slice(t * d, (t + 1) * d)
@@ -209,8 +246,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 if has_bias:
                     s = s + bias_ref[0, 0,
                                      j * blk_k:(j + 1) * blk_k][None, :]
-                if has_segments:
-                    s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
+                s = _mask(s, causal, q0, j * blk_k, has_segments, segq,
+                          segk)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 p = jnp.exp(s - m_new)
@@ -226,7 +263,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                     preferred_element_type=jnp.float32)
                 return m_new, l, acc
 
-            carry = _maybe_skip(has_segments, segq, segk, tile, carry)
+            carry = _maybe_skip(has_segments, segq, segk, tile, carry,
+                                _causal_live(causal, q0, bq, j * blk_k))
 
         m, l, acc = carry
         l_safe = jnp.maximum(l, 1e-30)
@@ -248,7 +286,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                lse_ref, delta_ref, do_ref, dq_ref, *, scale: float,
-               blk_k: int, rate: float, has_bias: bool, has_segments: bool):
+               blk_k: int, rate: float, has_bias: bool, has_segments: bool,
+               causal: bool = False):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     bq = q_ref.shape[1]
@@ -258,6 +297,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
     q = q_ref[0]
     do = do_ref[0]
     segq = segq_ref[0, 0] if has_segments else None
+    q0 = qi * bq if causal else 0
     lse = lse_ref[0, 0][:, None]
     delta = delta_ref[0, 0][:, None]
     dq = jnp.zeros((q.shape[0], q.shape[1]), jnp.float32)
@@ -274,8 +314,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 preferred_element_type=jnp.float32) * scale
             if has_bias:
                 s = s + bias_ref[0, 0, j * blk_k:(j + 1) * blk_k][None, :]
-            if has_segments:
-                s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
+            s = _mask(s, causal, q0, j * blk_k, has_segments, segq, segk)
             p = jnp.exp(s - lse)
             dp = jax.lax.dot_general(
                 do, vb, (((1,), (1,)), ((), ())),
@@ -288,14 +327,16 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
             return dq + jnp.dot(ds.astype(kb.dtype), kb,
                                 preferred_element_type=jnp.float32) * scale
 
-        dq = _maybe_skip(has_segments, segq, segk, tile, dq)
+        dq = _maybe_skip(has_segments, segq, segk, tile, dq,
+                         _causal_live(causal, q0, bq, j * blk_k))
 
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 lse_ref, delta_ref, do_ref, dk_ref, dv_ref, *, scale: float,
-                blk_q: int, rate: float, has_bias: bool, has_segments: bool):
+                blk_q: int, rate: float, has_bias: bool, has_segments: bool,
+                causal: bool = False):
     bh = pl.program_id(0)
     kj = pl.program_id(1)
     bk = k_ref.shape[1]
@@ -305,6 +346,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
     kb = k_ref[0]
     vb = v_ref[0]
     segk = segk_ref[0, 0] if has_segments else None
+    k0 = kj * bk if causal else 0
     if has_bias:
         bias = bias_ref[0, 0][None, :]  # (1, BLK_K)
     carry = (jnp.zeros(kb.shape, jnp.float32),
@@ -325,8 +367,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 preferred_element_type=jnp.float32) * scale
             if has_bias:
                 s = s + bias
-            if has_segments:
-                s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
+            s = _mask(s, causal, i * blk_q, k0, has_segments, segq, segk)
             p = jnp.exp(s - lse)
             if rate > 0.0:
                 keep = _keep_mask(seed_ref[0], bh, i * blk_q, kj * bk, blk_q,
@@ -348,7 +389,8 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                 preferred_element_type=jnp.float32) * scale
             return dk, dv
 
-        carry = _maybe_skip(has_segments, segq, segk, tile, carry)
+        carry = _maybe_skip(has_segments, segq, segk, tile, carry,
+                            _causal_live(causal, i * blk_q, blk_q, k0))
 
     dk, dv = carry
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -358,7 +400,8 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
                  delta_ref, do_ref, dq_ref, dk_ref, dv_ref, *, scale: float,
                  blk_q: int, blk_k: int, rate: float, has_bias: bool,
-                 has_segments: bool, heads_per_prog: int, heads_per_row: int):
+                 has_segments: bool, heads_per_prog: int, heads_per_row: int,
+                 causal: bool = False):
     """Fused backward: one program per (row, head group) computes dq, dk
     and dv together for each of its heads, so the score tiles, softmax exp
     and dropout keep-masks are evaluated ONCE instead of once in _dq_kernel
@@ -391,6 +434,8 @@ def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
             delta = delta_ref[0, 0, t, i * blk_q:(i + 1) * blk_q][:, None]
             dq_i = jnp.zeros((blk_q, d), jnp.float32)
             for j in range(nk):
+                if causal and j * blk_k > i * blk_q + blk_q - 1:
+                    continue    # wholly above the diagonal (both static)
                 segk = (seg_ref[0, 0, j * blk_k:(j + 1) * blk_k]
                         if has_segments else None)
 
@@ -405,8 +450,8 @@ def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
                     if has_bias:
                         s = s + bias_ref[0, 0,
                                          j * blk_k:(j + 1) * blk_k][None, :]
-                    if has_segments:
-                        s = jnp.where(_seg_allowed(segq, segk), s, NEG_INF)
+                    s = _mask(s, causal, i * blk_q, j * blk_k, has_segments,
+                              segq, segk)
                     p = jnp.exp(s - lse)
                     dp = jax.lax.dot_general(
                         dob, vb, (((1,), (1,)), ((), ())),
@@ -453,6 +498,23 @@ def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
 # program (native, D=64) or D=128 heads halve the admissible S. Beyond the
 # bound the split dq / dkv kernels run, which exist in the bh layout only.
 _FUSED_BWD_MAX_PANEL = 2048 * 64
+
+
+# Beyond the fused backward's bound a program holds whole (S, D) panels of
+# K and V (or Q and dO) beside its unrolled tiles: at S = 8192 that passes
+# Mosaic's default 16 MiB of scoped VMEM (17.6 MB asked for the dq kernel).
+# Such calls ask for this much instead (a v5e core has 128 MiB); shorter
+# sequences pass no parameter and compile as they always did.
+_LONG_SEQ_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def _long_seq_params(s: int, lanes: int) -> dict:
+    if s * lanes <= _FUSED_BWD_MAX_PANEL:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_LONG_SEQ_VMEM_BYTES)}
 
 
 def _to_bh(x):
@@ -516,8 +578,10 @@ class _Layout(NamedTuple):
         return row if self.native else row // self.heads
 
 
-def _layout(b: int, s: int, h: int, d: int) -> _Layout:
-    if _use_native(s, h, d):
+def _layout(b: int, s: int, h: int, d: int, group: int = 1) -> _Layout:
+    """`group` query heads to a key/value head: grouped heads take the bh
+    layout, where a program's key/value head is a block index."""
+    if group == 1 and _use_native(s, h, d):
         hp = _heads_per_prog(h, d)
         return _Layout(True, h, b, h // hp, hp)
     return _Layout(False, h, b * h, 1, 1)
@@ -548,10 +612,32 @@ def _per_batch_spec(present: bool, width: int, index_map):
     return pl.BlockSpec(_DUMMY_BLOCK, lambda *_: (0, 0, 0))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _kv_row(h: int, hkv: int):
+    """Grid row of a query head (bh layout: batch * H + head) -> row of its
+    key/value head in the (B * Hkv, S, D) arrays. The identity without
+    grouping, so that the ungrouped kernels' index maps stay as they were."""
+    if h == hkv:
+        return lambda r: r
+    group = h // hkv
+    return lambda r: (r // h) * hkv + (r % h) // group
+
+
+def _sum_groups(x, b: int, hkv: int, group: int, dtype):
+    """(B * H, S, D) per-query-head dk or dv -> (B * Hkv, S, D)."""
+    if group == 1:
+        return x
+    _, s, d = x.shape
+    return x.reshape(b, hkv, group, s, d).sum(axis=2).astype(dtype) \
+        .reshape(b * hkv, s, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
-                    dropout_rate: float = 0.0, interpret: bool = False):
-    """q/k/v: (B, S, H, D); bias: (B, 1, 1, S) additive or None;
+                    dropout_rate: float = 0.0, interpret: bool = False,
+                    causal: bool = False):
+    """q: (B, S, H, D); k/v: (B, S, Hkv, D) with H a multiple of Hkv (query
+    head i reads key/value head i // (H // Hkv)); `causal`: a query attends
+    to positions <= its own. bias: (B, 1, 1, S) additive or None;
     segment_ids: (B, S) int32 packing segments (1..n, 0 = pad) or None —
     attention is restricted to q_seg == k_seg blocks, the packed-sequence
     block-diagonal mask. dropout_seed: () or (1,) int32 array (traced OK);
@@ -563,18 +649,27 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
     path, which differentiates through the bias correctly. segment_ids are
     integer data (zero/float0 cotangent), like the seed."""
     out, _ = _flash_fwd(q, k, v, bias, segment_ids, dropout_seed,
-                        dropout_rate, interpret)
+                        dropout_rate, interpret, causal)
     return out
 
 
-def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret):
+def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
+               causal=False):
     b, s, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv or k.shape != v.shape:
+        raise ValueError(f"flash_attention: {h} query heads over k"
+                         f"{tuple(k.shape)} v{tuple(v.shape)}")
+    kv_row = _kv_row(h, hkv)
+    # keyword only where set, so that the bidirectional kernels trace as
+    # they always did
+    ckw = {"causal": True} if causal else {}
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
     blk_k = _pick_block(s, DEFAULT_BLK_K)
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_segments = segment_ids is not None
-    lay = _layout(b, s, h, d)
+    lay = _layout(b, s, h, d, h // hkv)
     hp = lay.heads_per_prog
     lanes = hp * d
     # shared by both layouts: the cross-layout bit-parity contract depends
@@ -585,12 +680,12 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret):
     qx, kx, vx = lay.pack(q), lay.pack(k), lay.pack(v)
 
     q_bs = pl.BlockSpec((1, blk_q, lanes), lambda r, g, qi: (r, qi, g))
-    kv_bs = pl.BlockSpec((1, s, lanes), lambda r, g, qi: (r, 0, g))
+    kv_bs = pl.BlockSpec((1, s, lanes), lambda r, g, qi: (kv_row(r), 0, g))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, blk_k=blk_k, rate=rate,
                           has_bias=has_bias, has_segments=has_segments,
                           heads_per_prog=hp,
-                          heads_per_row=lay.heads_per_row),
+                          heads_per_row=lay.heads_per_row, **ckw),
         grid=(lay.rows, lay.groups, s // blk_q),
         in_specs=[
             pl.BlockSpec((1,), lambda r, g, qi: (0,)),      # seed
@@ -612,29 +707,43 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret):
         ],
         name="flash_fwd",
         interpret=interpret,
+        **_long_seq_params(s, lanes),
     )(_seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
+    if causal:
+        # names a rematerialising caller may keep (models/lfm2_moe.py
+        # DENSE_SAVED): with both saved the backward pass finds the kernel's
+        # results and does not run it again
+        out = checkpoint_name(out, "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
     return lay.unpack(out, b, s, d), (qx, kx, vx, bias2, seg2, lse, out)
 
 
-def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret):
-    out, res = _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret)
+def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret,
+                    causal=False):
+    out, res = _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
+                          causal)
     return out, (res, seed, q.shape, bias is not None,
                  segment_ids is not None)
 
 
-def _flash_bwd_rule(rate, interpret, saved, g):
+def _flash_bwd_rule(rate, interpret, causal, saved, g):
     # residuals are in the kernel layout _flash_fwd chose (same
     # deterministic shape gate); lse is (rows, groups, heads_per_prog, S)
     (qx, kx, vx, bias2, seg2, lse, outx), seed, qshape, has_bias, \
         has_segments = saved
     b, s, h, d = qshape
+    hkv = kx.size // (b * s * d)
+    group = h // hkv
+    kv_row = _kv_row(h, hkv)
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
     blk_k = _pick_block(s, DEFAULT_BLK_K)
     scale = 1.0 / (d ** 0.5)
-    lay = _layout(b, s, h, d)
+    lay = _layout(b, s, h, d, group)
     hp = lay.heads_per_prog
     lanes = hp * d
     gx = lay.pack(g)
+    # per-query-head dk/dv of a group are summed in float32
+    dkv_dtype = kx.dtype if group == 1 else jnp.float32
     # delta = rowsum(dO * O) per head (cheap elementwise — jnp, not a kernel)
     delta = jnp.sum(
         (gx.astype(jnp.float32) * outx.astype(jnp.float32))
@@ -642,12 +751,15 @@ def _flash_bwd_rule(rate, interpret, saved, g):
     ).transpose(0, 2, 3, 1)
     seed_arr = _seed_operand(seed)
     kw = dict(scale=scale, rate=rate, has_bias=has_bias,
-              has_segments=has_segments)
+              has_segments=has_segments, **({"causal": True} if causal
+                                            else {}))
 
     if s * lanes <= _FUSED_BWD_MAX_PANEL:
         # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated
         # once instead of twice
         qkv_bs = pl.BlockSpec((1, s, lanes), lambda r, g: (r, 0, g))
+        kv_in_bs = pl.BlockSpec((1, s, lanes),
+                                lambda r, g: (kv_row(r), 0, g))
         stat_bs = pl.BlockSpec((1, 1, hp, s), lambda r, g: (r, g, 0, 0))
         per_batch = lambda r, g: (lay.batch(r), 0, 0)  # noqa: E731
         dq, dk, dv = pl.pallas_call(
@@ -657,13 +769,14 @@ def _flash_bwd_rule(rate, interpret, saved, g):
             grid=(lay.rows, lay.groups),
             in_specs=[
                 pl.BlockSpec((1,), lambda r, g: (0,)),
-                qkv_bs, qkv_bs, qkv_bs,
+                qkv_bs, kv_in_bs, kv_in_bs,
                 _per_batch_spec(has_bias, s, per_batch),
                 _per_batch_spec(has_segments, s, per_batch),
                 stat_bs, stat_bs, qkv_bs,
             ],
             out_specs=[qkv_bs, qkv_bs, qkv_bs],
-            out_shape=[jax.ShapeDtypeStruct(qx.shape, qx.dtype)] * 3,
+            out_shape=[jax.ShapeDtypeStruct(qx.shape, qx.dtype)]
+            + [jax.ShapeDtypeStruct(qx.shape, dkv_dtype)] * 2,
             name="flash_bwd_dqkv",
             interpret=interpret,
         )(seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx)
@@ -673,6 +786,8 @@ def _flash_bwd_rule(rate, interpret, saved, g):
         delta = delta.reshape(b * h, 1, s)
         row_bs = pl.BlockSpec((1, 1, s), lambda bh, i: (bh, 0, 0))
         full_bs = pl.BlockSpec((1, s, d), lambda bh, i: (bh, 0, 0))
+        kv_full_bs = pl.BlockSpec((1, s, d),
+                                  lambda bh, i: (kv_row(bh), 0, 0))
         per_batch = lambda bh, i: (bh // h, 0, 0)  # noqa: E731
         per_batch_blk = lambda bh, i: (bh // h, 0, i)  # noqa: E731
 
@@ -683,7 +798,7 @@ def _flash_bwd_rule(rate, interpret, saved, g):
             grid=(b * h, s // blk_q),
             in_specs=[
                 pl.BlockSpec((1,), lambda bh, qi: (0,)),
-                blk_bs, full_bs, full_bs,
+                blk_bs, kv_full_bs, kv_full_bs,
                 _per_batch_spec(has_bias, s, per_batch),
                 _per_batch_spec(has_segments, blk_q, per_batch_blk),
                 _per_batch_spec(has_segments, s, per_batch),
@@ -693,24 +808,28 @@ def _flash_bwd_rule(rate, interpret, saved, g):
             out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
             name="flash_bwd_dq",
             interpret=interpret,
+            **_long_seq_params(s, lanes),
         )(seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
         blk_bs = pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0))
+        kv_blk_bs = pl.BlockSpec((1, blk_k, d),
+                                 lambda bh, kj: (kv_row(bh), kj, 0))
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, blk_q=blk_q, **kw),
             grid=(b * h, s // blk_k),
             in_specs=[
                 pl.BlockSpec((1,), lambda bh, kj: (0,)),
-                full_bs, blk_bs, blk_bs,
+                full_bs, kv_blk_bs, kv_blk_bs,
                 _per_batch_spec(has_bias, blk_k, per_batch_blk),
                 _per_batch_spec(has_segments, s, per_batch),
                 _per_batch_spec(has_segments, blk_k, per_batch_blk),
                 row_bs, row_bs, full_bs,
             ],
             out_specs=[blk_bs, blk_bs],
-            out_shape=[jax.ShapeDtypeStruct(kx.shape, kx.dtype)] * 2,
+            out_shape=[jax.ShapeDtypeStruct(qx.shape, dkv_dtype)] * 2,
             name="flash_bwd_dkv",
             interpret=interpret,
+            **_long_seq_params(s, lanes),
         )(seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
     # bias is non-differentiable by contract (zero cotangent; see the
@@ -722,8 +841,13 @@ def _flash_bwd_rule(rate, interpret, saved, g):
         .zero_from_primal(seg2.reshape(b, s))
     dseed = None if seed is None else jax.custom_derivatives \
         .zero_from_primal(jnp.asarray(seed, jnp.int32))
-    return (lay.unpack(dq, b, s, d), lay.unpack(dk, b, s, d),
-            lay.unpack(dv, b, s, d), dbias, dseg, dseed)
+    dq = lay.unpack(dq, b, s, d)
+    if group > 1:
+        dk = _from_bh(_sum_groups(dk, b, hkv, group, kx.dtype), b, hkv)
+        dv = _from_bh(_sum_groups(dv, b, hkv, group, kx.dtype), b, hkv)
+    else:
+        dk, dv = lay.unpack(dk, b, s, d), lay.unpack(dv, b, s, d)
+    return dq, dk, dv, dbias, dseg, dseed
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -248,8 +248,11 @@ def default_trust_batch_axes(params: Any) -> Any:
     IS a per-layer ratio there: both layouts optimize identically."""
 
     def n_batch(path: tuple) -> int:
-        keys = [getattr(k, "key", str(k)) for k in path]
-        return 1 if "layers" in keys else 0
+        keys = [str(getattr(k, "key", k)) for k in path]
+        # a routed layer's (E, ...) expert stacks: one ratio per expert
+        # matrix (models/lfm2_moe.RoutedExperts), as for a scan's layers
+        return 1 if ("layers" in keys
+                     or keys[-1].startswith("experts_")) else 0
 
     return jax.tree_util.tree_map_with_path(lambda p, _: n_batch(p), params)
 
@@ -265,6 +268,10 @@ def default_weight_decay_mask(params: Any) -> Any:
         if joined.endswith("/bias") or joined == "bias":
             return False
         if "layer_norm" in joined or "layernorm" in joined:
+            return False
+        # the decoder families' RMSNorm gains and the router's selection
+        # bias (a buffer: zero gradient, and with no decay no update)
+        if joined.endswith("_norm/scale") or joined.endswith("expert_bias"):
             return False
         return True
 

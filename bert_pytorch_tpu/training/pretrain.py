@@ -53,15 +53,31 @@ STEP_SCOPES = (
     "loss", "optimizer", "grad_norm", "health", "param_cast", "metrics",
     "encoder", "bert", "grad_accum",
 )
-_SCOPE_PATTERNS = tuple(
-    (name, re.compile(rf"(?:^|[/(]){re.escape(name)}\)*(?:/|$)"))
-    for name in STEP_SCOPES)
+# The same account for a step of the decoder families (models/lfm2_moe.py),
+# whose model opens other scopes: `rmsnorm` first (the q/k norms inside
+# `attention` are norms), `mlp` the dense FFN only, `moe` with its
+# `moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine` inside, all
+# under `decoder`. The last two are not scopes of the program: XLA:TPU
+# lowers `lax.ragged_dot` (ops/moe.py's grouped products) to kernels of its
+# own whose `op_name` is the compiler's and carries no scope. The
+# benchmark's unscoped_share.lm.train carries a copy of the list.
+LM_STEP_SCOPES = (
+    "rmsnorm", "moe", "conv", "attention", "mlp", "lm_head", "loss",
+    "embeddings", "optimizer", "grad_norm", "health", "param_cast",
+    "metrics", "decoder", "grad_accum",
+    "ragged-dot-none", "ragged-dot-metadata",
+)
+_SCOPE_PATTERNS = {
+    scopes: tuple(
+        (name, re.compile(rf"(?:^|[/(]){re.escape(name)}\)*(?:/|$)"))
+        for name in scopes)
+    for scopes in (STEP_SCOPES, LM_STEP_SCOPES)}
 
 
-def step_scope(op_name: str) -> Optional[str]:
-    """The STEP_SCOPES entry an operation's `op_name` belongs to (first
-    match), or None."""
-    for name, pattern in _SCOPE_PATTERNS:
+def step_scope(op_name: str, scopes=STEP_SCOPES) -> Optional[str]:
+    """The entry of `scopes` (STEP_SCOPES or LM_STEP_SCOPES) an operation's
+    `op_name` belongs to (first match), or None."""
+    for name, pattern in _SCOPE_PATTERNS[scopes]:
         if pattern.search(op_name):
             return name
     return None
@@ -133,12 +149,19 @@ def inject_nonfinite(params: Any, bad) -> Any:
     return jax.tree_util.tree_map_with_path(maybe, params)
 
 
-def _param_caster(grad_dtype):
+def _param_caster(grad_dtype, keep_float32: Optional[Callable] = None):
     """tree-cast fp params to grad_dtype (bf16 grads against fp32 masters,
-    the apex-O2-equivalent scheme); identity when grad_dtype is None."""
+    the apex-O2-equivalent scheme); identity when grad_dtype is None.
+    `keep_float32(path)` names leaves that are read as they are (a
+    family's float32 router)."""
     def cast(params):
         if grad_dtype is None:
             return params
+        if keep_float32 is not None:
+            return jax.tree_util.tree_map_with_path(
+                lambda path, p: p.astype(grad_dtype)
+                if jnp.issubdtype(p.dtype, jnp.floating)
+                and not keep_float32(path) else p, params)
         return jax.tree.map(
             lambda p: p.astype(grad_dtype)
             if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
@@ -592,8 +615,16 @@ def build_pretrain_step(
     health: Optional[HealthConfig] = None,
     nan_inject_step: Optional[int] = None,
     norm_reducer: Optional[Any] = None,
+    keep_float32: Optional[Callable] = None,
 ) -> Callable[[TrainState, Batch, jax.Array], Tuple[TrainState, Dict]]:
     """Returns train_step(state, batch, rng) -> (state, metrics).
+
+    `loss_fn_builder(model)` -> loss_fn(params, micro, rng, deterministic)
+    -> (loss, aux) replaces the built-in MLM + NSP loss (the decoder
+    families: models/lfm2_moe.pretrain_loss_fn_builder). A dict under
+    aux["scalars"] is summed over the step's micro-batches and returned
+    among the metrics as it is (a family's counters). `keep_float32`: see
+    _param_caster.
 
     `schedule` is only consulted for the lr metric (the optimizer owns its
     own schedule). `max_predictions` (pretraining only; ignored when a custom
@@ -664,7 +695,7 @@ def build_pretrain_step(
         loss_fn = loss_fn_builder(model)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-    cast_params = _param_caster(grad_dtype)
+    cast_params = _param_caster(grad_dtype, keep_float32)
 
     if rs:
         one_micro = _build_rs_micro(model, zero1, max_predictions)
@@ -699,6 +730,7 @@ def build_pretrain_step(
                 # a nonzero value means the data pipeline and step config
                 # disagree
                 metrics["mlm_dropped"] = aux["mlm_dropped"]
+            metrics.update(aux.get("scalars", {}))
             if schedule is not None:
                 metrics["learning_rate"] = schedule(state.step)
         return new_state, metrics

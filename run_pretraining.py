@@ -609,7 +609,8 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from bert_pytorch_tpu.compile_cache import enable_compile_cache
-    from bert_pytorch_tpu.config import BertConfig, pad_vocab_size
+    from bert_pytorch_tpu.config import (Lfm2MoeConfig, load_model_config,
+                                         pad_vocab_size)
     from bert_pytorch_tpu.data.sharded import (
         HostShardSampler, PretrainingDataLoader, ShardIndex)
     from bert_pytorch_tpu.models import BertForPreTraining
@@ -771,7 +772,24 @@ def main(argv=None):
         # -- model config --------------------------------------------------
         if not args.model_config_file:
             raise SystemExit("--model_config_file (or run config) required")
-        config = BertConfig.from_json_file(args.model_config_file)
+        # the family is the config's `model_type`; an unknown one, or a key
+        # the family does not know, is refused (never trimmed to a BERT)
+        try:
+            config = load_model_config(args.model_config_file)
+        except ValueError as e:
+            raise SystemExit(f"--model_config_file: {e}")
+        # a decoder family (causal LM over packed rows) or BERT (MLM + NSP)
+        decoder = isinstance(config, Lfm2MoeConfig)
+        if decoder:
+            from bert_pytorch_tpu.models import lfm2_moe
+        if decoder and (args.kfac or args.stream_dir
+                        or args.stacked_params != "auto"
+                        or args.steps_per_loop > 1):
+            raise SystemExit(
+                f"model_type {config.model_type!r} trains through the "
+                "offline data plane with LAMB/Adam, one step a dispatch: "
+                "--kfac, --stream_dir, --stacked_params and "
+                "--steps_per_loop do not apply to it")
         config = config.replace(
             vocab_size=pad_vocab_size(config.vocab_size,
                                       args.vocab_pad_multiple),
@@ -786,7 +804,14 @@ def main(argv=None):
                            else args.grad_dtype)
         grad_dtype = (jnp.bfloat16 if grad_dtype_name == "bfloat16"
                       else None)
-        model = BertForPreTraining(config, dtype=compute_dtype)
+
+        def make_model(config):
+            if decoder:
+                return lfm2_moe.Lfm2MoeForCausalLM(config,
+                                                   dtype=compute_dtype)
+            return BertForPreTraining(config, dtype=compute_dtype)
+
+        model = make_model(config)
 
         # -- optimizer + schedule ------------------------------------------
         schedule = schedulers.make_schedule(
@@ -806,7 +831,7 @@ def main(argv=None):
             # tests/test_kfac.py::test_kfac_taps_under_remat); the reference
             # likewise ran both together (run_pretraining.py:257-258,311-345)
             config = config.replace(kfac_taps=True)
-            model = BertForPreTraining(config, dtype=compute_dtype)
+            model = make_model(config)
             # mesh=... -> distributed factor/inverse ownership: each device
             # stores and inverts only its slice of the layer-stacked factors
             # (the reference's HYBRID_OPT work partitioning,
@@ -908,7 +933,8 @@ def main(argv=None):
                 prefetch_batches=max(0, args.prefetch_batches),
                 packing=args.packing,
                 packing_max_segments=args.packing_max_segments,
-                packing_lookahead=args.packing_lookahead)
+                packing_lookahead=args.packing_lookahead,
+                objective="clm" if decoder else "mlm")
             logger.info(f"dataset: {len(index)} samples in "
                         f"{len(index.files)} shards; host step batch "
                         f"{host_step_batch}; [MASK]={mask_id}"
@@ -938,7 +964,7 @@ def main(argv=None):
         # by segments * max_pred; mlm_dropped warns loudly if reality ever
         # exceeds this.
         max_pred_row = args.max_predictions_per_seq
-        if args.packing:
+        if args.packing and not decoder:
             max_pred_row = min(
                 seq_len,
                 args.packing_max_segments * args.max_predictions_per_seq,
@@ -949,6 +975,9 @@ def main(argv=None):
                         f"(per-example cap {args.max_predictions_per_seq})")
 
         def init_fn(rng):
+            if decoder:
+                return model.init(rng, *lfm2_moe.init_inputs(
+                    {k: v[0] for k, v in stacked.items()}))
             return model.init(rng, jnp.asarray(stacked["input_ids"][0]),
                               jnp.asarray(stacked["token_type_ids"][0]),
                               jnp.asarray(stacked["attention_mask"][0]))
@@ -1081,6 +1110,11 @@ def main(argv=None):
             if kfac is not None:
                 return build_kfac_pretrain_step(model, tx, kfac,
                                                 pert_template, **common)
+            if decoder:
+                common.update(
+                    max_predictions=None,
+                    loss_fn_builder=lfm2_moe.pretrain_loss_fn_builder,
+                    keep_float32=lfm2_moe.keep_float32)
             return build_pretrain_step(model, tx, **common)
 
         epoch = 0
@@ -1126,9 +1160,8 @@ def main(argv=None):
             # compiled step's memory against the device's: the program
             # that fits is the one the loop runs, compiled already
             def program_for(policy):
-                return StepProgram(build_step(BertForPreTraining(
-                    config.replace(remat_policy=policy),
-                    dtype=compute_dtype)))
+                return StepProgram(build_step(make_model(
+                    config.replace(remat_policy=policy))))
 
             with mesh, mesh_lib.logical_rules(), setup.span("lower"):
                 policy, jit_step = resolve_remat_policy(
@@ -1138,7 +1171,7 @@ def main(argv=None):
                      jax.random.fold_in(jax.random.PRNGKey(args.seed), 1)),
                     hbm_snapshot().get("hbm_bytes_limit"), log=logger.info)
             config = config.replace(remat_policy=policy)
-            model = BertForPreTraining(config, dtype=compute_dtype)
+            model = make_model(config)
         else:
             jit_step = StepProgram(build_step(model))
         remat_name = (config.remat_policy if config.checkpoint_activations
@@ -1198,9 +1231,15 @@ def main(argv=None):
         # therefore step_flops) is already GLOBAL across hosts — it pairs
         # with the global peak (peak_per_device * device_count) for MFU
         seqs_per_step = accum_steps * micro_global
-        step_flops = flops_per_seq(
-            config, seq_len, config.vocab_size,
-            max_pred_row) * seqs_per_step
+        if decoder:
+            # the family's own formula (never BERT's): an upper estimate
+            # for packed rows, whose documents attend less than a full row
+            step_flops = (lfm2_moe.train_flops_per_row(config, seq_len)
+                          * seqs_per_step)
+        else:
+            step_flops = flops_per_seq(
+                config, seq_len, config.vocab_size,
+                max_pred_row) * seqs_per_step
         # None on the CPU backend (no MFU there); an accelerator the peak
         # table does not know is an error
         peak = device_peak_flops(jax.devices()[0], dtype=config.dtype)
@@ -1388,6 +1427,12 @@ def main(argv=None):
         done = False
         pending = None  # (step, epoch, metrics) awaiting logging
         warned_dropped = False
+        expert_load = None  # routed layers' cumulative [perf] counters
+        if decoder:
+            from bert_pytorch_tpu.telemetry.expert_load import \
+                ExpertLoadCounters
+
+            expert_load = ExpertLoadCounters()
         halt_pending = None  # message; raised after cleanup-safe point
         dispatches = 0  # jit calls made; gates compile-warmup closure
         fp_holder = [None]  # program fingerprint, filled by a worker thread
@@ -1444,13 +1489,18 @@ def main(argv=None):
             # the ONE place the loop waits for the device: step N's metrics
             # are read after step N+1 is in flight
             with sw.phase("metric_flush"):
-                vals = {k: float(v) for k, v in m.items()}
+                # one transfer for all of the step's scalars: a decoder
+                # step returns some fifty (the routed layers' counters),
+                # and read one by one each is a transfer of its own
+                vals = {k: float(v) for k, v in jax.device_get(m).items()}
             setup.end("first_step")     # the first loss is on the host
             with sw.phase("log"):
                 log_flushed(step_i, epoch_i, vals)
 
         def log_flushed(step_i, epoch_i, vals):
             nonlocal loss_sum, loss_n, warned_dropped, halt_pending
+            if expert_load is not None:
+                expert_load.update(vals)
             if recorder is not None:
                 # metrics tail rides in the bundle: the black box records
                 # what tripped, not just the inputs
@@ -1747,6 +1797,8 @@ def main(argv=None):
                             perf.update(compile_watch.snapshot())
                             perf.update(hbm_snapshot())
                             perf.update(step_fields)
+                            if expert_load is not None:
+                                perf.update(expert_load.fields())
                             tel.log_perf(global_step, perf)
                     if trace_active and global_step >= profile_range[1]:
                         with sw.phase("profile"):
