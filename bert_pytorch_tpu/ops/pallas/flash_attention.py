@@ -44,8 +44,12 @@ every kernel exactly like the padding bias, and because segments occupy
 contiguous position ranges, a (q, k) tile whose segment ranges don't
 intersect is *skipped wholesale* (`jax.lax.cond` around the tile body — no
 scores, no dropout hash, no dots), which is where the block-diagonal FLOP
-saving is realized. FLASH_SEG_SKIP=0 disables the skip (mask-only, for A/B
-isolation); skipped and masked-but-computed tiles contribute exactly zero
+saving is realized. Where one tile is the whole sequence (S = 512 at the
+default blocks) only a row of nothing but pad can skip it — an empty slot of
+the server's fixed batch — and the one test stands around the whole program
+instead of around each head's tile (`_program`), where it builds no
+wall between the heads. FLASH_SEG_SKIP=0 disables the skip (mask-only, for
+A/B isolation); skipped and masked-but-computed tiles contribute exactly zero
 either way, so the two settings are bit-identical on every non-pad row.
 Rows of all-pad positions (segment 0) have their outputs explicitly zeroed
 in the forward epilogue (their degenerate softmax would otherwise emit
@@ -68,6 +72,7 @@ a group are summed outside. Both take the bh layout whatever the shape.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import NamedTuple
 
@@ -76,12 +81,33 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
-# Block sizes (env-overridable for tuning sweeps). 512x512 measured 13%
-# faster end-to-end than 128x128 at BERT-Large seq512 on v5e (bigger dots
-# amortize the per-tile softmax bookkeeping; the (blk_q, blk_k) fp32 score
-# tile plus q/k/v blocks is ~1.5 MB of VMEM at D=64). _pick_block halves
-# the target until it divides S, falling back to one whole-sequence block
-# only when no power-of-two fraction >= 128 does.
+# Block sizes (env-overridable for tuning sweeps): 512 x 512; _pick_block
+# halves the target until it divides S, falling back to one whole-sequence
+# block only when no power-of-two fraction >= 128 does. The (blk_q, blk_k)
+# fp32 score tile plus q/k/v blocks is ~1.5 MB of VMEM at D=64. What the
+# attached v5e said (PERF.md section 6, PR 26 and PR 27): at S = 8,192
+# causal the three kernels take 117.5 ms a step at 512 x 512, 116.2 at
+# 1024 x 1024, 213.3 at 256 x 256. At S = 512 packed (lognormal documents,
+# median 180) smaller blocks have next to nothing to skip — 99.9 % of
+# 256-wide and 84.9 % of 128-wide tiles hold an allowed pair, the documents
+# need 63.9 % of the square — and every tile under a `lax.cond` is a basic
+# block the compiler schedules alone: the one cond around the one 512 x 512
+# tile of each head cost 32 % of the forward and 34 % of the fused backward
+# kernel (device trace, ms a call of 16 rows x 16 heads: 0.785 -> 0.534 and
+# 1.010 -> 0.663), so there the test stands once around the program, on a
+# flag that comes in SMEM (`_program`, `_live_rows`), and the heads' work
+# is one block again: 0.503 and 0.615 with every item, 0.508 and 0.606
+# with no test at all.
+#
+# The rule of the tile bodies: NOTHING THAT IS A FUNCTION OF THE ROW OR THE
+# COLUMN ALONE IS COMPUTED ON THE (blk_q, blk_k) TILE. The softmax scale
+# rides on a (blk, D) dot operand where it is a power of two; the dropout
+# hash starts from a (blk_q, 1) row term and a (1, blk_k) column term; the
+# dropout rescale rides on the (blk, D) results; pad is a value of the
+# key-side segment vector that equals no query's; causal positions are two
+# vectors; a block's segment range is taken once per block, from the
+# lane-dense ids. tests/test_pallas.py::test_flash_tile_equation_ceilings
+# counts what is left on the tile, per kernel.
 
 
 def _env_int(name: str, default: int) -> int:
@@ -104,44 +130,130 @@ def _seg_skip_enabled() -> bool:
     return os.environ.get("FLASH_SEG_SKIP", "1") != "0"
 
 
-def _seg_allowed(segq, segk):
-    """(bq,) q segments x (bk,) k segments -> (bq, bk) bool, True where
-    attention is allowed: same segment, and not pad (segment 0)."""
-    qs = segq[:, None]
-    return (qs == segk[None, :]) & (qs > 0)
+def _rows(dtype, n: int):
+    """(n, 1) iota: a per-query-row vector, broadcast along the lanes."""
+    return jax.lax.broadcasted_iota(dtype, (n, 1), 0)
 
 
-def _seg_overlap(segq, segk):
-    """Scalar bool: does this (q, k) tile contain ANY allowed pair?
-    Segments occupy contiguous, increasing position ranges within a row, so
-    a tile's non-pad segment ids form a contiguous integer range — two
-    tiles share a segment iff their [min, max] ranges intersect. O(bq+bk)
-    compares instead of the O(bq*bk) mask."""
-    qs = segq[:, None]
-    ks = segk[:, None]
+def _cols(dtype, n: int):
+    """(1, n) iota: a per-key-column vector, broadcast along the sublanes."""
+    return jax.lax.broadcasted_iota(dtype, (1, n), 1)
+
+
+def _scale_operand(x, scale: float):
+    """(operand, tile_scale): where the softmax scale is a power of two
+    (D = 64: 2^-3) it rides on the (blk, D) dot operand `x` — the product is
+    the same number in bf16 and float32 alike and (x * scale) . y
+    == scale * (x . y) bit for bit — and the float32 score tile needs no
+    multiply (tile_scale None). Elsewhere (D = 128) x as it is, and the
+    scale for the tile."""
+    if math.frexp(scale)[0] != 0.5:
+        return x, scale
+    return (x.astype(jnp.float32) * scale).astype(x.dtype), None
+
+
+def _seg_keys(seg):
+    """Key-side segment ids with pad (0) sent to -1, which is no query's
+    id: a pad key then fails `_mask`'s one equality test, as a pad query
+    (0) does against every key."""
+    return jnp.where(seg > 0, seg, -1)
+
+
+def _seg_range(seg):
+    """(min, max) of a block's non-pad segment ids, in any layout. Segments
+    occupy contiguous, increasing position ranges within a row, so a
+    block's non-pad ids form a contiguous integer range. O(blk) work that
+    belongs to the block alone: a kernel takes it once per q block and once
+    per k block, not once per tile."""
     big = jnp.int32(_SEG_BIG)
-    qmx = jnp.max(qs)
-    kmx = jnp.max(ks)
-    qmn = jnp.min(jnp.where(qs > 0, qs, big))
-    kmn = jnp.min(jnp.where(ks > 0, ks, big))
+    return jnp.min(jnp.where(seg > 0, seg, big)), jnp.max(seg)
+
+
+def _seg_overlap(qrange, krange):
+    """Scalar bool: does this (q, k) tile contain ANY allowed pair? Two
+    blocks share a segment iff their `_seg_range`s intersect."""
+    (qmn, qmx), (kmn, kmx) = qrange, krange
     return (qmx > 0) & (kmx > 0) & (qmx >= kmn) & (kmx >= qmn)
 
 
-def _maybe_skip(has_segments: bool, segq, segk, tile_fn, carry, live=None):
-    """Run tile_fn(carry) -> carry, skipping it when segment ranges prove
-    the tile all-masked. Without segments (or with FLASH_SEG_SKIP=0) the
-    tile always runs; masked tiles then contribute exact zeros, so both
-    settings produce bit-identical non-pad outputs. `live` (causal
-    attention): a scalar that is false where the tile lies wholly above
-    the diagonal; None where attention is bidirectional."""
+def _maybe_skip(tile_fn, carry, qrange=None, krange=None, live=None):
+    """Run tile_fn(carry) -> carry, skipping it when the blocks' segment
+    ranges prove the tile all-masked. Without segments (ranges None, also
+    under FLASH_SEG_SKIP=0) the tile always runs; masked tiles then
+    contribute exact zeros, so both settings produce bit-identical non-pad
+    outputs. `live` (causal attention): a scalar that is false where the
+    tile lies wholly above the diagonal; None where attention is
+    bidirectional."""
     pred = None
-    if has_segments and _seg_skip_enabled():
-        pred = _seg_overlap(segq, segk)
+    if qrange is not None:
+        pred = _seg_overlap(qrange, krange)
     if live is not None:
         pred = live if pred is None else pred & live
     if pred is None:
         return tile_fn(carry)
     return jax.lax.cond(pred, tile_fn, lambda c: c, carry)
+
+
+def _tile_skip(has_segments: bool, tiles: int) -> bool:
+    """Are a kernel's tiles skipped one by one, by their blocks' segment
+    ranges? Not without segments, not under FLASH_SEG_SKIP=0, and not where
+    the one tile is the whole (S, S) square (`tiles` == 1): only a row of
+    nothing but pad can skip it, and the `lax.cond` around a tile is a wall
+    the compiler's scheduler moves nothing across, head to head (a third of
+    both kernels at S = 512: header comment). `_program` skips such a
+    row there."""
+    return has_segments and _seg_skip_enabled() and tiles > 1
+
+
+def _skip_pad_rows(has_segments: bool, tiles: int) -> bool:
+    """Where the one tile is the whole (S, S) square (`tiles` == 1:
+    `_tile_skip` makes no test there), is a row of nothing but pad skipped
+    as a whole program (`_program`)? With segments, unless FLASH_SEG_SKIP=0."""
+    return has_segments and _seg_skip_enabled() and tiles == 1
+
+
+def _live_rows(seg2, skip_pad_rows: bool) -> tuple:
+    """(in_specs, operands) of the leading operand `_program` reads where
+    it skips rows of pad: (B,) int32, 1 where the row holds any position
+    that is not pad. Taken here, outside the kernel, and handed over in
+    SMEM: taken inside, from the segment ids in VMEM, the same test is a
+    vector reduction whose way to the scalar unit every program waits for
+    (+3.6 % on the forward and +5.2 % on the backward kernel at S = 512;
+    this form -1.1 % and +1.5 %). Both empty where no row is skipped."""
+    if not skip_pad_rows:
+        return [], []
+    from jax.experimental.pallas import tpu as pltpu
+
+    live = (jnp.max(seg2, axis=(1, 2)) > 0).astype(jnp.int32)
+    return [pl.BlockSpec(memory_space=pltpu.SMEM)], [live]
+
+
+def _program(kernel, grid_rank: int, n_out: int, batch_of=None):
+    """The body of a pallas_call from `kernel(ids, *refs)`: `ids` is the
+    program's grid position, read here, at the top (interpret mode
+    resolves `program_id` nowhere else). With `batch_of` (grid row ->
+    batch index; `_skip_pad_rows`) the first operand is `_live_rows` and a
+    row of nothing but pad — an empty slot of a batch of fixed size — is
+    skipped: its program writes zeros to its `n_out` outputs and runs
+    nothing else. ONE test around the whole program, so the heads' tiles
+    inside stay one block for the scheduler. A skipped row's logsumexp
+    reads 0 and no kernel reads it back, its backward program being
+    skipped by the same test."""
+
+    def program(*refs):
+        ids = tuple(pl.program_id(axis) for axis in range(grid_rank))
+        if batch_of is None:
+            return kernel(ids, *refs)
+        live = refs[0][batch_of(ids[0])] > 0
+        refs = refs[1:]
+        pl.when(live)(lambda: kernel(ids, *refs))
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            for ref in refs[-n_out:]:
+                ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+    return program
 
 
 def _causal_live(causal: bool, q0, bq: int, k0):
@@ -150,14 +262,23 @@ def _causal_live(causal: bool, q0, bq: int, k0):
     return (k0 <= q0 + (bq - 1)) if causal else None
 
 
-def _mask(s, causal: bool, q0, k0, has_segments: bool, segq, segk):
+def _causal_pos(causal: bool, q0, k0, bq: int, bk: int):
+    """(rows, cols) of `_mask` for the tile at rows q0.., columns k0..: a
+    pair is allowed where rows >= cols. A (bq, 1) and a (1, bk) vector,
+    both relative to q0, so that the rows are the same for every tile."""
+    if not causal:
+        return None, None
+    return _rows(jnp.int32, bq), _cols(jnp.int32, bk) + (k0 - q0)
+
+
+def _mask(s, rows, cols, segq, segk):
     """Scores with the disallowed pairs at NEG_INF: other segments and pad
-    (packed rows), later positions (causal)."""
-    allowed = _seg_allowed(segq, segk) if has_segments else None
-    if causal:
-        bq, bk = s.shape
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q0
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k0
+    (packed rows: segq (bq, 1), segk (1, bk) from `_seg_keys`, allowed
+    where equal; None otherwise), later positions (causal: `_causal_pos`;
+    None otherwise). One compare per condition on the tile; everything else
+    is the vectors'."""
+    allowed = None if segq is None else segq == segk
+    if rows is not None:
         tri = rows >= cols
         allowed = tri if allowed is None else allowed & tri
     return s if allowed is None else jnp.where(allowed, s, NEG_INF)
@@ -171,10 +292,47 @@ def _pick_block(s: int, target: int) -> int:
     return s
 
 
+_HASH_ROW, _HASH_COL, _HASH_HEAD = 0x9E3779B1, 0x85EBCA77, 0xC2B2AE3D
+
+
+def _keep_rows(q0, bq: int):
+    """(bq, 1) uint32, the keep hash's row term: q_pos * _HASH_ROW."""
+    return (_rows(jnp.uint32, bq) + jnp.uint32(q0)) * jnp.uint32(_HASH_ROW)
+
+
+def _keep_cols(seed, bh, k0, bk: int):
+    """(1, bk) uint32, the keep hash's column term with the scalar folded
+    in: k_pos * _HASH_COL ^ (seed + bh * _HASH_HEAD)."""
+    cols = (_cols(jnp.uint32, bk) + jnp.uint32(k0)) * jnp.uint32(_HASH_COL)
+    return cols ^ (jnp.uint32(seed) + jnp.uint32(bh) * jnp.uint32(_HASH_HEAD))
+
+
+def _keep_tile(rows, cols, rate: float):
+    """The (bq, bk) keep mask from `_keep_rows` and `_keep_cols`: the part
+    of the hash that needs both coordinates (8 tile equations)."""
+    x = rows ^ cols
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    # top 23 bits uniform in [0, 2^23); keep iff >= rate * 2^23
+    return x >= jnp.uint32(int(rate * (1 << 23)) << 9)
+
+
 def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
     """Counter-based keep mask over global (q_pos, k_pos) — two
     multiply-xorshift rounds on a per-position counter, integer threshold
-    compare. uint32 VPU ops only.
+    compare. uint32 VPU ops only:
+
+        x = (q_pos * 0x9E3779B1) ^ (k_pos * 0x85EBCA77)
+              ^ (seed + bh * 0xC2B2AE3D)
+        x ^= x >> 16;  x *= 0x7FEB352D;  x ^= x >> 15;  x *= 0x846CA68B
+        keep = (x >> 9) >= int(rate * 2^23)
+
+    The kernels build the first line from a per-row and a per-column vector
+    (`_keep_rows`, `_keep_cols`: xor is associative, so the bits are these)
+    and compare x with the threshold shifted up instead of x shifted down
+    (the same test for unsigned x).
 
     The mask is evaluated over S^2 elements per (batch, head) in forward AND
     backward, so every op here is step-time. Two rounds are the floor that
@@ -186,22 +344,14 @@ def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
     xor-shift only feeds bits below the 23 used by the compare, and the
     int compare replaces the bitcast->f32->scale->cmp tail; both are dropped
     (~3 VPU ops/element saved, identical top-23-bit statistics)."""
-    rows = jax.lax.broadcasted_iota(jnp.uint32, (bq, bk), 0) + jnp.uint32(q0)
-    cols = jax.lax.broadcasted_iota(jnp.uint32, (bq, bk), 1) + jnp.uint32(k0)
-    x = (rows * jnp.uint32(0x9E3779B1)) ^ (cols * jnp.uint32(0x85EBCA77))
-    x = x ^ (jnp.uint32(seed) + jnp.uint32(bh) * jnp.uint32(0xC2B2AE3D))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x7FEB352D)
-    x = x ^ (x >> 15)
-    x = x * jnp.uint32(0x846CA68B)
-    # top 23 bits uniform in [0, 2^23); keep iff >= rate * 2^23
-    return (x >> 9) >= jnp.uint32(int(rate * (1 << 23)))
+    return _keep_tile(_keep_rows(q0, bq), _keep_cols(seed, bh, k0, bk), rate)
 
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
-                o_ref, lse_ref, *, scale: float, blk_k: int, rate: float,
-                has_bias: bool, has_segments: bool, heads_per_prog: int,
-                heads_per_row: int, causal: bool = False):
+def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+                segk_ref, o_ref, lse_ref, *, scale: float, blk_k: int,
+                rate: float, has_bias: bool, has_segments: bool,
+                heads_per_prog: int, heads_per_row: int,
+                causal: bool = False):
     """One program per (row, head group, q-block) of a (rows, S, lanes)
     array; it loops the `heads_per_prog` heads that share its lane block
     (static lane slices of width D), then the k-blocks. Serves both layouts
@@ -209,15 +359,21 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
     element) and 1 for bh (a row is already one (batch, head)), so the
     dropout counter `row * heads_per_row + head` is the same
     batch * H + head in both."""
-    row = pl.program_id(0)
-    group = pl.program_id(1)
-    qi = pl.program_id(2)
+    row, group, qi = ids
     bq = q_ref.shape[1]
     d = q_ref.shape[2] // heads_per_prog
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
-    segq = segq_ref[0, 0] if has_segments else None
     q0 = qi * bq if causal else 0   # first row of this tile (causal only)
+    skip = _tile_skip(has_segments, (s_len // bq) * nk)
+    # what belongs to the q block alone, once for every head and k block
+    segq = segq_ref[0, 0][:, None] if has_segments else None
+    qrange = _seg_range(segq_ref[0, 0][None, :]) if skip else None
+    keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
+    # and to a k block alone, once for every head
+    segks = [_seg_keys(segk_ref[0, 0, j * blk_k:(j + 1) * blk_k])[None, :]
+             if has_segments else None for j in range(nk)]
+    kranges = [_seg_range(segks[j]) if skip else None for j in range(nk)]
 
     for t in range(heads_per_prog):
         lanes = slice(t * d, (t + 1) * d)
@@ -227,34 +383,35 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         # inputs run at a fraction of it. Softmax statistics and
         # accumulators are fp32 — identical numerics to the XLA attention
         # path (probs cast to the compute dtype before the PV matmul).
-        q = q_ref[0, :, lanes]
+        q, tile_scale = _scale_operand(q_ref[0, :, lanes], scale)
         carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
                  jnp.zeros((bq, 1), jnp.float32),
                  jnp.zeros((bq, d), jnp.float32))
 
         for j in range(nk):
-            segk = (segk_ref[0, 0, j * blk_k:(j + 1) * blk_k]
-                    if has_segments else None)
 
-            def tile(carry, lanes=lanes, bh=bh, j=j, q=q, segk=segk):
+            def tile(carry, lanes=lanes, bh=bh, j=j, q=q):
                 m, l, acc = carry
                 kb = k_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
                 vb = v_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
                 s = jax.lax.dot_general(
                     q, kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                    preferred_element_type=jnp.float32)
+                if tile_scale is not None:
+                    s = s * tile_scale
                 if has_bias:
                     s = s + bias_ref[0, 0,
                                      j * blk_k:(j + 1) * blk_k][None, :]
-                s = _mask(s, causal, q0, j * blk_k, has_segments, segq,
-                          segk)
+                s = _mask(s, *_causal_pos(causal, q0, j * blk_k, bq, blk_k),
+                          segq, segks[j])
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 p = jnp.exp(s - m_new)
                 l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
                 if rate > 0.0:
-                    keep = _keep_mask(seed_ref[0], bh, qi * bq, j * blk_k,
-                                      bq, blk_k, rate)
+                    keep = _keep_tile(
+                        keep_rows,
+                        _keep_cols(seed_ref[0], bh, j * blk_k, blk_k), rate)
                     p_acc = jnp.where(keep, p, 0.0)
                 else:
                     p_acc = p
@@ -263,7 +420,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                     preferred_element_type=jnp.float32)
                 return m_new, l, acc
 
-            carry = _maybe_skip(has_segments, segq, segk, tile, carry,
+            carry = _maybe_skip(tile, carry, qrange, kranges[j],
                                 _causal_live(causal, q0, bq, j * blk_k))
 
         m, l, acc = carry
@@ -279,31 +436,44 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
             # activations, which keeps downstream consumers of full
             # (B, S, E) hiddens (K-FAC factor taps) bit-independent of the
             # kernel configuration.
-            out = jnp.where(segq[:, None] > 0, out, 0.0)
+            out = jnp.where(segq > 0, out, 0.0)
         o_ref[0, :, lanes] = out.astype(o_ref.dtype)
         lse_ref[0, 0, t, :] = (m + jnp.log(l_safe))[:, 0]
 
 
-def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
-               lse_ref, delta_ref, do_ref, dq_ref, *, scale: float,
+def _dropout_late(scale: float, rate: float) -> float:
+    """The constant on a backward kernel's (blk, D) dq / dk results. With
+    dropout the tile keeps where(keep, dp, 0) and where(keep, p, 0) as they
+    are and the 1 / (1 - rate) of both rides here (and on dv), as the
+    forward kernel's does on its output: ds * (1 - rate)
+    = p * (where(keep, dp, 0) - delta * (1 - rate)), and `delta` comes in
+    times (1 - rate) (_flash_bwd_rule)."""
+    return scale / (1.0 - rate) if rate > 0.0 else scale
+
+
+def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+               segk_ref, lse_ref, delta_ref, do_ref, dq_ref, *, scale: float,
                blk_k: int, rate: float, has_bias: bool, has_segments: bool,
                causal: bool = False):
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
+    bh, qi = ids
     bq = q_ref.shape[1]
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
+    out_scale = _dropout_late(scale, rate)
+    skip = _tile_skip(has_segments, (s_len // bq) * nk)
 
-    q = q_ref[0]
+    q, tile_scale = _scale_operand(q_ref[0], scale)
     do = do_ref[0]
-    segq = segq_ref[0, 0] if has_segments else None
+    segq = segq_ref[0, 0][:, None] if has_segments else None
+    qrange = _seg_range(segq_ref[0, 0][None, :]) if skip else None
+    keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
     q0 = qi * bq if causal else 0
     lse = lse_ref[0, 0][:, None]
     delta = delta_ref[0, 0][:, None]
     dq = jnp.zeros((q.shape[0], q.shape[1]), jnp.float32)
 
     for j in range(nk):
-        segk = (segk_ref[0, 0, j * blk_k:(j + 1) * blk_k]
+        segk = (_seg_keys(segk_ref[0, 0, j * blk_k:(j + 1) * blk_k])[None, :]
                 if has_segments else None)
 
         def tile(dq, j=j, segk=segk):
@@ -311,41 +481,54 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
             vb = v_ref[0, j * blk_k:(j + 1) * blk_k, :]
             s = jax.lax.dot_general(
                 q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+                preferred_element_type=jnp.float32)
+            if tile_scale is not None:
+                s = s * tile_scale
             if has_bias:
                 s = s + bias_ref[0, 0, j * blk_k:(j + 1) * blk_k][None, :]
-            s = _mask(s, causal, q0, j * blk_k, has_segments, segq, segk)
+            s = _mask(s, *_causal_pos(causal, q0, j * blk_k, bq, blk_k),
+                      segq, segk)
             p = jnp.exp(s - lse)
             dp = jax.lax.dot_general(
                 do, vb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if rate > 0.0:
-                keep = _keep_mask(seed_ref[0], bh, qi * bq, j * blk_k, bq,
-                                  blk_k, rate)
-                dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
+                keep = _keep_tile(
+                    keep_rows, _keep_cols(seed_ref[0], bh, j * blk_k, blk_k),
+                    rate)
+                dp = jnp.where(keep, dp, 0.0)
             ds = p * (dp - delta)
             return dq + jnp.dot(ds.astype(kb.dtype), kb,
-                                preferred_element_type=jnp.float32) * scale
+                                preferred_element_type=jnp.float32) \
+                * out_scale
 
-        dq = _maybe_skip(has_segments, segq, segk, tile, dq,
+        dq = _maybe_skip(tile, dq, qrange,
+                         _seg_range(segk) if skip else None,
                          _causal_live(causal, q0, bq, j * blk_k))
 
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
-                lse_ref, delta_ref, do_ref, dk_ref, dv_ref, *, scale: float,
-                blk_q: int, rate: float, has_bias: bool, has_segments: bool,
-                causal: bool = False):
-    bh = pl.program_id(0)
-    kj = pl.program_id(1)
+def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+                segk_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref, *,
+                scale: float, blk_q: int, rate: float, has_bias: bool,
+                has_segments: bool, causal: bool = False):
+    bh, kj = ids
     bk = k_ref.shape[1]
     s_len = q_ref.shape[1]
     nq = s_len // blk_q
+    out_scale = _dropout_late(scale, rate)
+    skip = _tile_skip(has_segments, nq * (s_len // bk))
 
     kb = k_ref[0]
+    # the resident block takes the scale here: (q . k * scale); dk needs
+    # the q blocks as they are
+    ks, tile_scale = _scale_operand(kb, scale)
     vb = v_ref[0]
-    segk = segk_ref[0, 0] if has_segments else None
+    segk = _seg_keys(segk_ref[0, 0])[None, :] if has_segments else None
+    krange = _seg_range(segk) if skip else None
+    keep_cols = (_keep_cols(seed_ref[0], bh, kj * bk, bk)
+                 if rate > 0.0 else None)
     k0 = kj * bk if causal else 0
     if has_bias:
         bias = bias_ref[0, 0][None, :]  # (1, BLK_K)
@@ -353,55 +536,64 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
              jnp.zeros(vb.shape, jnp.float32))
 
     for i in range(nq):
-        segq = (segq_ref[0, 0, i * blk_q:(i + 1) * blk_q]
-                if has_segments else None)
 
-        def tile(carry, i=i, segq=segq):
+        def tile(carry, i=i):
             dk, dv = carry
             qb = q_ref[0, i * blk_q:(i + 1) * blk_q, :]
             dob = do_ref[0, i * blk_q:(i + 1) * blk_q, :]
             lse = lse_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
             delta = delta_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
+            segq = (segq_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
+                    if has_segments else None)
             s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+                qb, ks, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if tile_scale is not None:
+                s = s * tile_scale
             if has_bias:
                 s = s + bias
-            s = _mask(s, causal, i * blk_q, k0, has_segments, segq, segk)
+            s = _mask(s, *_causal_pos(causal, i * blk_q, k0, blk_q, bk),
+                      segq, segk)
             p = jnp.exp(s - lse)
             if rate > 0.0:
-                keep = _keep_mask(seed_ref[0], bh, i * blk_q, kj * bk, blk_q,
-                                  bk, rate)
-                p_drop = jnp.where(keep, p / (1.0 - rate), 0.0)
+                keep = _keep_tile(_keep_rows(i * blk_q, blk_q), keep_cols,
+                                  rate)
+                p_keep = jnp.where(keep, p, 0.0)
             else:
-                p_drop = p
+                p_keep = p
             dv = dv + jax.lax.dot_general(
-                p_drop.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+                p_keep.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(
                 dob, vb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if rate > 0.0:
-                dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
+                dp = jnp.where(keep, dp, 0.0)
             ds = p * (dp - delta)
             dk = dk + jax.lax.dot_general(
                 ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+                preferred_element_type=jnp.float32) * out_scale
             return dk, dv
 
-        carry = _maybe_skip(has_segments, segq, segk, tile, carry,
+        # the range from the lane-dense ids; their (blk_q, 1) form is the
+        # tile's, which a skipped tile does not pay
+        qrange = (_seg_range(segq_ref[0, 0, i * blk_q:(i + 1) * blk_q]
+                             [None, :]) if skip else None)
+        carry = _maybe_skip(tile, carry, qrange, krange,
                             _causal_live(causal, i * blk_q, blk_q, k0))
 
     dk, dv = carry
+    if rate > 0.0:
+        dv = dv / (1.0 - rate)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
-                 delta_ref, do_ref, dq_ref, dk_ref, dv_ref, *, scale: float,
-                 blk_q: int, blk_k: int, rate: float, has_bias: bool,
-                 has_segments: bool, heads_per_prog: int, heads_per_row: int,
-                 causal: bool = False):
+def _dqkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref,
+                 lse_ref, delta_ref, do_ref, dq_ref, dk_ref, dv_ref, *,
+                 scale: float, blk_q: int, blk_k: int, rate: float,
+                 has_bias: bool, has_segments: bool, heads_per_prog: int,
+                 heads_per_row: int, causal: bool = False):
     """Fused backward: one program per (row, head group) computes dq, dk
     and dv together for each of its heads, so the score tiles, softmax exp
     and dropout keep-masks are evaluated ONCE instead of once in _dq_kernel
@@ -409,12 +601,23 @@ def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
     _fwd_kernel. The per-head accumulators live in VMEM — (S, D) fp32 x3 —
     which bounds this path to moderate S (_FUSED_BWD_MAX_PANEL); longer
     sequences take the split kernels."""
-    row = pl.program_id(0)
-    group = pl.program_id(1)
+    row, group = ids
     s_len = q_ref.shape[1]
     d = q_ref.shape[2] // heads_per_prog
     nq = s_len // blk_q
     nk = s_len // blk_k
+    out_scale = _dropout_late(scale, rate)
+    skip = _tile_skip(has_segments, nq * nk)
+    # what belongs to a q block or a k block alone, once for every head
+    segqs = [seg_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
+             if has_segments else None for i in range(nq)]
+    segks = [_seg_keys(seg_ref[0, 0, j * blk_k:(j + 1) * blk_k])[None, :]
+             if has_segments else None for j in range(nk)]
+    qranges = [_seg_range(seg_ref[0, 0, i * blk_q:(i + 1) * blk_q][None, :])
+               if skip else None for i in range(nq)]
+    kranges = [_seg_range(segks[j]) if skip else None for j in range(nk)]
+    keep_rows = [_keep_rows(i * blk_q, blk_q) if rate > 0.0 else None
+                 for i in range(nq)]
 
     for t in range(heads_per_prog):
         lanes = slice(t * d, (t + 1) * d)
@@ -427,64 +630,69 @@ def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref, lse_ref,
 
         for i in range(nq):
             qb = q_ref[0, i * blk_q:(i + 1) * blk_q, lanes]
+            # the scores take the scaled copy; dk needs q as it is
+            qs, tile_scale = _scale_operand(qb, scale)
             dob = do_ref[0, i * blk_q:(i + 1) * blk_q, lanes]
-            segq = (seg_ref[0, 0, i * blk_q:(i + 1) * blk_q]
-                    if has_segments else None)
             lse = lse_ref[0, 0, t, i * blk_q:(i + 1) * blk_q][:, None]
             delta = delta_ref[0, 0, t, i * blk_q:(i + 1) * blk_q][:, None]
             dq_i = jnp.zeros((blk_q, d), jnp.float32)
             for j in range(nk):
                 if causal and j * blk_k > i * blk_q + blk_q - 1:
                     continue    # wholly above the diagonal (both static)
-                segk = (seg_ref[0, 0, j * blk_k:(j + 1) * blk_k]
-                        if has_segments else None)
 
-                def tile(carry, lanes=lanes, bh=bh, i=i, j=j, qb=qb, dob=dob,
-                         segq=segq, segk=segk, lse=lse, delta=delta):
+                def tile(carry, lanes=lanes, bh=bh, i=i, j=j, qb=qb, qs=qs,
+                         dob=dob, lse=lse, delta=delta):
                     dq_i, dk_j, dv_j = carry
                     kb = k_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
                     vb = v_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
                     s = jax.lax.dot_general(
-                        qb, kb, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
+                        qs, kb, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    if tile_scale is not None:
+                        s = s * tile_scale
                     if has_bias:
                         s = s + bias_ref[0, 0,
                                          j * blk_k:(j + 1) * blk_k][None, :]
-                    s = _mask(s, causal, i * blk_q, j * blk_k, has_segments,
-                              segq, segk)
+                    s = _mask(s, *_causal_pos(causal, i * blk_q, j * blk_k,
+                                              blk_q, blk_k),
+                              segqs[i], segks[j])
                     p = jnp.exp(s - lse)
                     dp = jax.lax.dot_general(
                         dob, vb, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
                     if rate > 0.0:
-                        keep = _keep_mask(seed_ref[0], bh, i * blk_q,
-                                          j * blk_k, blk_q, blk_k, rate)
-                        p_drop = jnp.where(keep, p / (1.0 - rate), 0.0)
-                        dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
+                        keep = _keep_tile(
+                            keep_rows[i],
+                            _keep_cols(seed_ref[0], bh, j * blk_k, blk_k),
+                            rate)
+                        p_keep = jnp.where(keep, p, 0.0)
+                        dp = jnp.where(keep, dp, 0.0)
                     else:
-                        p_drop = p
+                        p_keep = p
                     ds = (p * (dp - delta)).astype(qb.dtype)
                     dq_i = dq_i + jnp.dot(
-                        ds, kb, preferred_element_type=jnp.float32) * scale
+                        ds, kb, preferred_element_type=jnp.float32) \
+                        * out_scale
                     dk_j = dk_j + jax.lax.dot_general(
                         ds, qb, (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
+                        preferred_element_type=jnp.float32) * out_scale
                     dv_j = dv_j + jax.lax.dot_general(
-                        p_drop.astype(dob.dtype), dob,
+                        p_keep.astype(dob.dtype), dob,
                         (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
                     return dq_i, dk_j, dv_j
 
                 dq_i, dk_blocks[j], dv_blocks[j] = _maybe_skip(
-                    has_segments, segq, segk, tile,
-                    (dq_i, dk_blocks[j], dv_blocks[j]))
+                    tile, (dq_i, dk_blocks[j], dv_blocks[j]),
+                    qranges[i], kranges[j])
             dq_ref[0, i * blk_q:(i + 1) * blk_q, lanes] = dq_i.astype(
                 dq_ref.dtype)
 
         for j in range(nk):
             rows = slice(j * blk_k, (j + 1) * blk_k)
+            dv_j = dv_blocks[j] / (1.0 - rate) if rate > 0.0 else dv_blocks[j]
             dk_ref[0, rows, lanes] = dk_blocks[j].astype(dk_ref.dtype)
-            dv_ref[0, rows, lanes] = dv_blocks[j].astype(dv_ref.dtype)
+            dv_ref[0, rows, lanes] = dv_j.astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -681,13 +889,17 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
 
     q_bs = pl.BlockSpec((1, blk_q, lanes), lambda r, g, qi: (r, qi, g))
     kv_bs = pl.BlockSpec((1, s, lanes), lambda r, g, qi: (kv_row(r), 0, g))
+    skip_rows = _skip_pad_rows(has_segments, (s // blk_q) * (s // blk_k))
+    live_spec, live = _live_rows(seg2, skip_rows)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, blk_k=blk_k, rate=rate,
-                          has_bias=has_bias, has_segments=has_segments,
-                          heads_per_prog=hp,
-                          heads_per_row=lay.heads_per_row, **ckw),
+        _program(
+            functools.partial(_fwd_kernel, scale=scale, blk_k=blk_k,
+                              rate=rate, has_bias=has_bias,
+                              has_segments=has_segments, heads_per_prog=hp,
+                              heads_per_row=lay.heads_per_row, **ckw),
+            3, 2, lay.batch if skip_rows else None),
         grid=(lay.rows, lay.groups, s // blk_q),
-        in_specs=[
+        in_specs=live_spec + [
             pl.BlockSpec((1,), lambda r, g, qi: (0,)),      # seed
             q_bs, kv_bs, kv_bs,
             _per_batch_spec(has_bias, s,
@@ -708,7 +920,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
         name="flash_fwd",
         interpret=interpret,
         **_long_seq_params(s, lanes),
-    )(_seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
+    )(*live, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
     if causal:
         # names a rematerialising caller may keep (models/lfm2_moe.py
         # DENSE_SAVED): with both saved the backward pass finds the kernel's
@@ -737,6 +949,8 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
     kv_row = _kv_row(h, hkv)
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
     blk_k = _pick_block(s, DEFAULT_BLK_K)
+    skip_rows = _skip_pad_rows(has_segments, (s // blk_q) * (s // blk_k))
+    live_spec, live = _live_rows(seg2, skip_rows)
     scale = 1.0 / (d ** 0.5)
     lay = _layout(b, s, h, d, group)
     hp = lay.heads_per_prog
@@ -749,6 +963,10 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         (gx.astype(jnp.float32) * outx.astype(jnp.float32))
         .reshape(lay.rows, s, lay.groups, hp, d), axis=-1
     ).transpose(0, 2, 3, 1)
+    if rate > 0.0:
+        # the kernels subtract it from the UNscaled dp of the kept pairs and
+        # put the dropout rescale on their (blk, D) results (_dropout_late)
+        delta = delta * (1.0 - rate)
     seed_arr = _seed_operand(seed)
     kw = dict(scale=scale, rate=rate, has_bias=has_bias,
               has_segments=has_segments, **({"causal": True} if causal
@@ -763,11 +981,13 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         stat_bs = pl.BlockSpec((1, 1, hp, s), lambda r, g: (r, g, 0, 0))
         per_batch = lambda r, g: (lay.batch(r), 0, 0)  # noqa: E731
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_dqkv_kernel, blk_q=blk_q, blk_k=blk_k,
-                              heads_per_prog=hp,
-                              heads_per_row=lay.heads_per_row, **kw),
+            _program(
+                functools.partial(_dqkv_kernel, blk_q=blk_q, blk_k=blk_k,
+                                  heads_per_prog=hp,
+                                  heads_per_row=lay.heads_per_row, **kw),
+                2, 3, lay.batch if skip_rows else None),
             grid=(lay.rows, lay.groups),
-            in_specs=[
+            in_specs=live_spec + [
                 pl.BlockSpec((1,), lambda r, g: (0,)),
                 qkv_bs, kv_in_bs, kv_in_bs,
                 _per_batch_spec(has_bias, s, per_batch),
@@ -779,7 +999,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             + [jax.ShapeDtypeStruct(qx.shape, dkv_dtype)] * 2,
             name="flash_bwd_dqkv",
             interpret=interpret,
-        )(seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx)
+        )(*live, seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx)
     else:
         # split kernels, bh layout only (_use_native excludes these shapes)
         lse = lse.reshape(b * h, 1, s)
@@ -794,9 +1014,10 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         blk_bs = pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0))
         stat_blk_bs = pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi))
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, blk_k=blk_k, **kw),
+            _program(functools.partial(_dq_kernel, blk_k=blk_k, **kw),
+                     2, 1, lay.batch if skip_rows else None),
             grid=(b * h, s // blk_q),
-            in_specs=[
+            in_specs=live_spec + [
                 pl.BlockSpec((1,), lambda bh, qi: (0,)),
                 blk_bs, kv_full_bs, kv_full_bs,
                 _per_batch_spec(has_bias, s, per_batch),
@@ -809,15 +1030,16 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             name="flash_bwd_dq",
             interpret=interpret,
             **_long_seq_params(s, lanes),
-        )(seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
+        )(*live, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
         blk_bs = pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0))
         kv_blk_bs = pl.BlockSpec((1, blk_k, d),
                                  lambda bh, kj: (kv_row(bh), kj, 0))
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, blk_q=blk_q, **kw),
+            _program(functools.partial(_dkv_kernel, blk_q=blk_q, **kw),
+                     2, 2, lay.batch if skip_rows else None),
             grid=(b * h, s // blk_k),
-            in_specs=[
+            in_specs=live_spec + [
                 pl.BlockSpec((1,), lambda bh, kj: (0,)),
                 full_bs, kv_blk_bs, kv_blk_bs,
                 _per_batch_spec(has_bias, blk_k, per_batch_blk),
@@ -830,7 +1052,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             name="flash_bwd_dkv",
             interpret=interpret,
             **_long_seq_params(s, lanes),
-        )(seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
+        )(*live, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
     # bias is non-differentiable by contract (zero cotangent; see the
     # flash_attention docstring), segment ids and seed likewise — the

@@ -2,10 +2,13 @@
 reference, flash attention fwd/bwd vs plain softmax attention, dropout mask
 consistency, multi-tensor l2norm/scale/clip."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jex_core
 
 from bert_pytorch_tpu.ops.layernorm import _layer_norm_xla
 from bert_pytorch_tpu.ops.pallas.flash_attention import flash_attention
@@ -263,19 +266,23 @@ def test_flash_dropout_deterministic_and_unbiased():
 
 
 @pytest.mark.parametrize("bwd", ["fused", "split"])
-def test_flash_dropout_grads_flow(bwd, force_flash_path):
-    """The dropout backward (masks regenerated in-kernel) must equal
-    autodiff of a pure-jnp mirror applying the IDENTICAL keep mask. This
-    replaces the original single-coordinate finite-difference check, which
-    was fp32-noise-limited: the loss is a sum over B*S*H*D squared terms,
-    so an eps=1e-3 secant carries ~1e-2 of rounding noise — 20x the true
-    gradient at the probed coordinate (the analytic value is verified here
-    to 1e-8 against the exact-mask mirror)."""
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_dropout_grads_flow(d, bwd, force_flash_path):
+    """The dropout forward and backward (masks regenerated in-kernel) must
+    equal a pure-jnp mirror applying the IDENTICAL keep mask, and its
+    autodiff. This replaces the original single-coordinate
+    finite-difference check, which was fp32-noise-limited: the loss is a
+    sum over B*S*H*D squared terms, so an eps=1e-3 secant carries ~1e-2 of
+    rounding noise — 20x the true gradient at the probed coordinate (the
+    analytic value is verified here to 1e-8 against the exact-mask mirror).
+    D = 64: the softmax scale is a power of two and rides on the (blk, D)
+    dot operand; D = 128: it is not, and stays a multiply of the score
+    tile. Both put the dropout rescale on the (blk, D) results."""
     from bert_pytorch_tpu.ops.pallas.flash_attention import _keep_mask
 
     force_flash_path("native" if bwd == "fused" else "bh", bwd)
-    b, s, h, d = 2, 128, 4, 64
-    q, k, v, bias = _qkv(s=s)
+    b, s, h = 2, 128, 4
+    q, k, v, bias = _qkv(s=s, d=d)
     seed = jnp.array(3, jnp.int32)
     rate = 0.2
 
@@ -295,6 +302,10 @@ def test_flash_dropout_grads_flow(bwd, force_flash_path):
                                        dropout_rate=rate,
                                        interpret=True) ** 2)
 
+    out = flash_attention(q, k, v, bias=bias, dropout_seed=seed,
+                          dropout_rate=rate, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(mirror(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
     g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(lambda q, k, v: jnp.sum(mirror(q, k, v) ** 2),
                      argnums=(0, 1, 2))(q, k, v)
@@ -302,6 +313,260 @@ def test_flash_dropout_grads_flow(bwd, force_flash_path):
         arr = np.asarray(a)
         assert np.isfinite(arr).all() and np.abs(arr).sum() > 0
         np.testing.assert_allclose(arr, np.asarray(r), rtol=5e-4, atol=5e-5)
+
+
+# -- nothing of the row or the column alone on the (q, k) tile (PR 27) ------
+
+_HASH = dict(row=0x9E3779B1, col=0x85EBCA77, head=0xC2B2AE3D,
+             m1=0x7FEB352D, m2=0x846CA68B)
+
+
+def _documented_keep(seed, bh, q0, k0, bq, bk, rate):
+    """`_keep_mask`'s docstring in numpy uint32, one full tile at a time."""
+    u = np.uint32
+    with np.errstate(over="ignore"):
+        rows = (np.arange(bq, dtype=u) + u(q0))[:, None]
+        cols = (np.arange(bk, dtype=u) + u(k0))[None, :]
+        x = (rows * u(_HASH["row"])) ^ (cols * u(_HASH["col"]))
+        x = x ^ (u(seed) + u(bh) * u(_HASH["head"]))
+        x = x ^ (x >> u(16))
+        x = x * u(_HASH["m1"])
+        x = x ^ (x >> u(15))
+        x = x * u(_HASH["m2"])
+    return (x >> u(9)) >= u(int(rate * (1 << 23)))
+
+
+@pytest.mark.parametrize("rate", [0.1, 0.3])
+@pytest.mark.parametrize("seed", [7, 2147483000])
+@pytest.mark.parametrize("bq,bk,q0,k0", [(128, 128, 384, 256),
+                                         (256, 512, 768, 1536),
+                                         (512, 512, 7680, 3584)])
+def test_flash_split_hash_is_the_documented_one(bq, bk, q0, k0, seed, rate):
+    """The kernels build the keep hash from a per-row and a per-column
+    vector and compare against a shifted threshold; the bits are those of
+    the documented full-tile formula, whatever the tile and its offset."""
+    fa = importlib.import_module(
+        "bert_pytorch_tpu.ops.pallas.flash_attention")
+    bh = 37
+    want = _documented_keep(seed, bh, q0, k0, bq, bk, rate)
+    got = fa._keep_tile(fa._keep_rows(q0, bq),
+                        fa._keep_cols(jnp.int32(seed), bh, k0, bk), rate)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(fa._keep_mask(jnp.int32(seed), bh, q0, k0, bq, bk, rate)),
+        want)
+
+
+def _packed_fixture(b=3, s=256, h=2, d=64, seed=0):
+    """Packed rows at the default blocks (one tile a head): a row with a
+    pad tail, a full row, and a row of nothing but pad (an empty slot of
+    the server's batch)."""
+    rng = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(rng.randn(b, s, h, d).astype(np.float32)) * 0.5
+               for _ in range(3))
+    seg = np.zeros((b, s), np.int32)
+    seg[0, :100], seg[0, 100:230] = 1, 2            # 26 pad positions
+    seg[1, :60], seg[1, 60:] = 1, 2                 # no pad
+    seg = jnp.asarray(seg)                          # row 2: all pad
+    bias = jnp.where(seg > 0, 0.0, -10000.0)[:, None, None, :] \
+        .astype(jnp.float32)
+    return q, k, v, bias, seg
+
+
+def test_flash_forward_bits_are_the_parents():
+    """Forward output at D = 64 with pad bias, segments and dropout equals,
+    bit for bit, the formula the kernel had before PR 27 took the
+    per-row / per-column work off the tile (kept here as the mirror, one
+    jitted head at a time): scale multiplied onto the float32 score tile,
+    `(qs == ks) & (qs > 0)`, the keep hash from two full-tile iotas with
+    `(x >> 9) >= t` (`_documented_keep`). Every moved piece is exact
+    (uint32 arithmetic; a power-of-two scale on the dot operand; pad keys
+    coded -1), so interpret mode shows no difference. (The backward
+    kernels put the dropout rescale on their (blk, D) results, which moves
+    float32 rounding: they are held by test_flash_dropout_grads_flow's
+    tolerances, not bit for bit.)"""
+    from bert_pytorch_tpu.ops.pallas.flash_attention import NEG_INF
+
+    q, k, v, bias, seg = _packed_fixture()
+    b, s, h, d = q.shape
+    seed, rate = 5, 0.1
+
+    @jax.jit
+    def head(qh, kh, vh, bias_row, seg_row, keep):
+        sc = jax.lax.dot_general(
+            qh, kh, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * (1.0 / d ** 0.5)
+        sc = sc + bias_row[None, :]
+        qs = seg_row[:, None]
+        sc = jnp.where((qs == seg_row[None, :]) & (qs > 0), sc, NEG_INF)
+        m = jnp.max(sc, axis=-1, keepdims=True)
+        p = jnp.exp(sc - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        acc = jnp.dot(jnp.where(keep, p, 0.0).astype(vh.dtype), vh,
+                      preferred_element_type=jnp.float32)
+        out = acc / jnp.maximum(l, 1e-30) / (1.0 - rate)
+        return jnp.where(qs > 0, out, 0.0)
+
+    got = np.asarray(flash_attention(q, k, v, bias, seg, jnp.int32(seed),
+                                     rate, True))
+    for bi in range(b):
+        for hh in range(h):
+            keep = _documented_keep(seed, bi * h + hh, 0, 0, s, s, rate)
+            want = head(q[bi, :, hh], k[bi, :, hh], v[bi, :, hh],
+                        bias[bi, 0, 0], seg[bi], keep)
+            np.testing.assert_array_equal(got[bi, :, hh], np.asarray(want))
+    # the pad bias adds 0 to every allowed pair: without it, the same bits
+    no_bias = flash_attention(q, k, v, None, seg, jnp.int32(seed), rate, True)
+    np.testing.assert_array_equal(np.asarray(no_bias), got)
+
+
+def _sub_jaxprs(eqn):
+    """The jaxprs an equation carries in its parameters (cond branches, a
+    custom_vjp's body, a kernel), one level down."""
+    for value in eqn.params.values():
+        for item in value if isinstance(value, (tuple, list)) else (value,):
+            if isinstance(item, jex_core.ClosedJaxpr):
+                yield item.jaxpr
+            elif isinstance(item, jex_core.Jaxpr):
+                yield item
+
+
+def _kernel_equations(fn, *args, count):
+    """{kernel name: equations `count(eqn)` holds for} over every
+    pallas_call under fn, from the traced kernels — cond branches included,
+    nothing run."""
+    counts = {}
+
+    def walk(jaxpr, name):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                name_in = e.params["name"]
+                counts[name_in] = 0
+                walk(e.params["jaxpr"], name_in)
+                continue
+            for sub in _sub_jaxprs(e):
+                walk(sub, name)
+            if name and count(e):
+                counts[name] += 1
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return counts
+
+
+def _tile_equations(fn, *args, tile):
+    """Vector equations whose result is `tile`-shaped, per kernel (dots
+    apart: the MXU's; an equation that only carries sub-jaxprs is counted
+    by what is inside)."""
+    return _kernel_equations(fn, *args, count=lambda e: (
+        e.primitive.name != "dot_general" and not any(_sub_jaxprs(e))
+        and any(getattr(v.aval, "shape", None) == tile for v in e.outvars)))
+
+
+# per kernel: vector equations on ONE (blk_q, blk_k) tile, as the cells
+# trace them. Before PR 27: 27 / 34 (packed-512), 14 / 16 / 17 (causal +
+# segments), 4 / 7 (plain)
+@pytest.mark.parametrize("case,shape,kw,ceilings", [
+    # large-pretrain-512-packed: native layout, fused backward, pad bias +
+    # segments + dropout, one tile a head and two heads a program
+    ("packed-512", (1, 512, 2, 2, 64),
+     dict(bias=True, segments=True, rate=0.1),
+     {"flash_fwd": 17, "flash_bwd_dqkv": 22}),
+    # lfm2's kernels at a short S: bh layout, grouped heads, causal +
+    # segments, split backward, one head and two k (or q) blocks a program
+    ("causal-segments", (1, 1024, 4, 2, 64),
+     dict(segments=True, causal=True, split=True),
+     {"flash_fwd": 8, "flash_bwd_dq": 10, "flash_bwd_dkv": 11}),
+    ("plain", (1, 512, 2, 2, 64), dict(),
+     {"flash_fwd": 3, "flash_bwd_dqkv": 6}),
+])
+def test_flash_tile_equation_ceilings(case, shape, kw, ceilings,
+                                      force_flash_path):
+    """Nothing that is a function of the row or the column alone is
+    computed on the (q, k) tile: per kernel, the count of tile-shaped
+    vector equations stays at what PR 27 reached (scale on the (blk, D) dot
+    operand at D = 64, hash prefix and pad test on vectors, dropout rescale
+    on the (blk, D) results, one compare per mask condition). An edit that
+    puts per-row or per-column work back on the tile fails here by the
+    kernel's name."""
+    fa = importlib.import_module(
+        "bert_pytorch_tpu.ops.pallas.flash_attention")
+    b, s, h, hkv, d = shape
+    if kw.get("split"):
+        force_flash_path("bh", "split")
+    blk_q = fa._pick_block(s, fa.DEFAULT_BLK_Q)
+    blk_k = fa._pick_block(s, fa.DEFAULT_BLK_K)
+    # tile bodies a program traces: its heads x the k (or q) blocks it walks
+    # (the fused backward walks both)
+    heads = 1 if kw.get("split") else fa._heads_per_prog(h, d)
+    tiles = {"flash_fwd": heads * (s // blk_k),
+             "flash_bwd_dq": s // blk_k, "flash_bwd_dkv": s // blk_q,
+             "flash_bwd_dqkv": heads * (s // blk_q) * (s // blk_k)}
+    q = jnp.zeros((b, s, h, d), jnp.bfloat16)
+    k = jnp.zeros((b, s, hkv, d), jnp.bfloat16)
+    bias = jnp.zeros((b, 1, 1, s), jnp.float32) if kw.get("bias") else None
+    seg = jnp.ones((b, s), jnp.int32) if kw.get("segments") else None
+    rate = kw.get("rate", 0.0)
+    seed = jnp.int32(1) if rate else None
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, bias, seg, seed, rate, True, kw.get("causal", False))
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    counts = _tile_equations(grads, q, k, k, tile=(blk_q, blk_k))
+    assert set(counts) == set(ceilings), counts
+    per_tile = {name: n / tiles[name] for name, n in counts.items()}
+    over = {name: (n, ceilings[name]) for name, n in per_tile.items()
+            if n > ceilings[name]}
+    assert not over, f"{case}: (tile equations, ceiling) {over}"
+
+
+@pytest.mark.parametrize("layout,bwd,skip", [
+    ("native", "fused", "1"), ("native", "fused", "0"),
+    ("bh", "split", "1"), ("bh", "split", "0"), ("bh", "fused", "1"),
+])
+def test_flash_one_tile_all_pad_row(layout, bwd, skip, monkeypatch,
+                                    force_flash_path):
+    """Segments where the one tile is the whole row (the default blocks at
+    the cells' and the server's S) and a row of the batch is nothing but
+    pad: the row's program is skipped by ONE test around it
+    (`_skip_pad_rows`; none under FLASH_SEG_SKIP=0, and none around a
+    head's tile either way), its outputs and gradients are zero, and every
+    other row's output and finite gradients are the dense block-diagonal
+    reference's."""
+    force_flash_path(layout, bwd)
+    monkeypatch.setenv("FLASH_SEG_SKIP", skip)
+    q, k, v, _, seg = _packed_fixture()
+    real = np.asarray(seg) > 0
+    assert real[:2].any(axis=1).all() and not real[2].any()
+    allowed = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] > 0)
+    dense_bias = jnp.where(allowed, 0.0, -1e30)[:, None]     # (B, 1, S, S)
+    weight = jnp.asarray(real, jnp.float32)[:, :, None, None]
+
+    def loss(attend):       # no loss term reads a pad position
+        return lambda q, k, v: jnp.sum((attend(q, k, v) * weight) ** 2)
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, None, seg, None, 0.0, True)
+    dense = lambda q, k, v: _ref_attention(q, k, v, dense_bias)  # noqa: E731
+
+    conds = _kernel_equations(
+        jax.grad(loss(flash), argnums=(0, 1, 2)), q, k, v,
+        count=lambda e: e.primitive.name == "cond")
+    assert set(conds.values()) == ({2} if skip == "1" else {0}), conds
+
+    got = np.asarray(flash(q, k, v))
+    assert (got[~real] == 0).all()
+    np.testing.assert_allclose(got[real], np.asarray(dense(q, k, v))[real],
+                               rtol=2e-5, atol=2e-5)
+    grads = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for g, want in zip(grads, wants):
+        g = np.asarray(g)
+        assert np.isfinite(g).all() and (g[2] == 0).all()
+        assert np.abs(g[:2]).sum() > 0
+        np.testing.assert_allclose(g[real], np.asarray(want)[real],
+                                   rtol=5e-4, atol=5e-5)
 
 
 def test_flash_native_layout_matches_bh_layout(force_flash_path):
