@@ -1,10 +1,10 @@
 """Structured reports over lowered/compiled XLA programs.
 
 The worst regressions this repo has hit were *program-structure* bugs that
-no unit test could see until a multichip bench ran: fail-open sharding
+no unit test could see until a multichip run: fail-open sharding
 gates (round 7), GSPMD forking the ZeRO-1 gather into extra all-gathers
 (round 11, until now guarded only by one ad-hoc regex in
-tests/test_zero1.py), 75-94%-collective-time meshes (MULTICHIP_r07). The
+tests/test_zero1.py), meshes whose time went mostly to collectives. The
 compiled program is a perfectly inspectable artifact — `jit(f).lower(...)
 .compile().as_text()` is stable HLO text — so this module parses it into a
 structured report the rule framework (analysis/passes.py) and the CI gate
@@ -30,8 +30,7 @@ structured report the rule framework (analysis/passes.py) and the CI gate
   from the recorded one.
 
 Everything that parses TEXT is stdlib-only and importable without jax
-(tools/graphcheck.py --validate-budgets relies on this, mirroring
-tools/perfboard.py); the helpers that touch compiled objects or pytrees
+(tools/graphcheck.py --validate-budgets relies on this); the helpers that touch compiled objects or pytrees
 import jax lazily inside the function.
 """
 
@@ -260,14 +259,12 @@ def kernel_counts(text: str) -> Dict[str, int]:
 
 def collective_counts(text: str) -> Dict[str, int]:
     """Just the per-kind collective counts of an HLO text — the one
-    counter tests/test_zero1.py, bench.py --multichip, and the budget pass
-    all share (replacing the ad-hoc per-test regexes)."""
+    counter tests/test_zero1.py and the budget pass share (replacing the ad-hoc per-test regexes)."""
     return parse_hlo_module(text)["collective_counts"]
 
 
 def collective_inventory(text: str) -> Dict[str, Any]:
-    """Counts + bytes + estimated wire traffic, the per-variant block
-    bench.py --multichip embeds next to its time_breakdown."""
+    """Counts + bytes + estimated wire traffic of one program."""
     rep = parse_hlo_module(text)
     return {
         "counts": {k: v for k, v in rep["collective_counts"].items() if v},

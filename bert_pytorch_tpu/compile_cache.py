@@ -3,8 +3,8 @@
 A BERT-Large step program costs the TPU compiler a minute or more, and a
 machine that runs one command and is thrown away pays it on every start
 unless the executables outlive the process. Every entry point that compiles
-(run_pretraining, run_finetune / run_distill, run_server, the bench and
-chip_smoke children) calls `enable_compile_cache()` before its first compile.
+(run_pretraining, run_finetune / run_distill, run_server, the chip_smoke
+children) calls `enable_compile_cache()` before its first compile.
 
 The directory is decided OUTSIDE the program where possible:
 

@@ -98,8 +98,8 @@ class BertConfig:
     # module whose params carry a leading (L, ...) stacked-layer axis — O(1)
     # compile time in depth, but even at full scan_unroll the backward pass
     # accumulates each layer's weight gradient via dynamic_update_slice into
-    # the (L, ...) grad buffer (a measured 9.4% of seq512 step time,
-    # docs/PERF.md). False: the encoder is built as L separate BertLayer
+    # the (L, ...) grad buffer (its share of step time is not measured on
+    # this runtime). False: the encoder is built as L separate BertLayer
     # modules (params under encoder/layer_0 .. layer_{L-1}, no leading L
     # axis), so wgrads write straight into per-layer leaves — no DUS
     # traffic, at the cost of O(L) compile time (always fully unrolled).

@@ -58,7 +58,7 @@ Design, and the invariants that make it production-grade:
   — the output stream is bit-identical to an uninjected run).
 
 No jax imports anywhere: like data/sharded.py this is plain host Python, so
-the two-process shard tests and the input bench stay backend-free.
+the two-process shard tests stay backend-free.
 
 docs/DATA.md is the operator guide; run_pretraining.py --stream_dir is the
 entry point.

@@ -73,8 +73,8 @@ class ResidualDropoutLayerNorm(nn.Module):
     sites in every BertLayer (reference src/modeling.py:439-487). The
     dropout mask comes from a counter hash (seeded from the 'dropout' rng
     per call site), evaluated inside the fused kernel in forward AND
-    backward so it never exists in HBM (ops/layernorm.add_dropout_layer_norm
-    — measured +13 MFU points at seq128 over nn.Dropout + LN). Param names
+    backward so it never exists in HBM
+    (ops/layernorm.add_dropout_layer_norm). Param names
     match LayerNorm so checkpoints are interchangeable."""
 
     rate: float
@@ -259,8 +259,8 @@ class BertLayer(nn.Module):
 
         # named_scope tags every op in the block with a stable prefix so a
         # profiler trace maps buckets to code (attention vs mlp vs head)
-        # instead of fused-op soup — the per-phase attribution that made
-        # docs/PERF.md's budget hunting possible ("Demystifying BERT")
+        # instead of fused-op soup — the per-phase attribution PERF.md's
+        # step_scope_share metrics read ("Demystifying BERT")
         with jax.named_scope("attention"):
             attn_out = BertSelfAttention(cfg, dtype=self.dtype,
                                          name="attention")(
@@ -373,7 +373,7 @@ class BertEncoder(nn.Module):
     dynamic_update_slice into the (L, ...) stacked grad buffers even at full
     scan_unroll. Unstacked: params live under encoder/layer_{i} with no
     leading L axis, wgrads write straight into per-layer leaves (no DUS
-    traffic — docs/PERF.md seq512 budget), compile time O(L).
+    traffic), compile time O(L).
     checkpoint_activations=True wraps the (scanned or per-layer) body in
     nn.remat (reference: torch checkpointing in sqrt(L) chunks). What the
     backward pass then finds saved is config.remat_policy's to say, by the

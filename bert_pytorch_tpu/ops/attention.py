@@ -5,8 +5,8 @@ The reference computes attention as explicit torch matmuls with an additive
 lives in one function with selectable implementation:
 
 - ``xla``:    plain einsum path; XLA fuses softmax and handles MXU tiling.
-  Fastest at seq 128 on v5e when the batch fits un-rematted (measured:
-  b64 plain 51.7% MFU vs b64 xla_checkpoint 51.1%).
+  What the two seq-128 benchmark cells run (PERF.md section 4); its rate
+  against the other paths is not measured on this runtime.
 - ``xla_checkpoint``: the einsum path wrapped in jax.checkpoint so the
   (B, H, S, S) probabilities are recomputed in the backward pass instead of
   saved — XLA-attention speed with flash-like activation memory. Use it to
@@ -14,8 +14,8 @@ lives in one function with selectable implementation:
   to the recompute.
 - ``pallas``: blockwise fused kernel (ops/pallas/flash_attention.py) that never
   materializes the (B, H, S, S) score matrix in HBM — the TPU analogue of
-  flash attention. Measured fastest at seq 512 (35.7% MFU vs 30.9% plain /
-  25.8% xla_checkpoint, BERT-Large b16 v5e). Where VMEM allows (BERT-Large
+  flash attention; the packed seq-512 cell and the lfm2 cell run it
+  (PERF.md section 4). Where VMEM allows (BERT-Large
   seq512 qualifies) the kernels consume the model's (B, S, H, D) layout
   directly — no (BH, S, D) transpose pass either side; longer sequences
   fall back to the transposing grid automatically.
@@ -386,8 +386,8 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
         if hash_dropout_impl:
             # positional-hash dropout with the mask regenerated in backward:
             # no (B, H, S, S) mask tensor is saved for the bwd pass
-            # (measured ~1.6 MFU points at BERT-Large seq128; the flash
-            # path already generates its mask in-kernel the same way)
+            # (the flash path already generates its mask in-kernel the
+            # same way)
             seed = jax.random.bits(dropout_rng, (),
                                    jnp.uint32).astype(jnp.int32)
             probs = hash_dropout(probs, seed, dropout_rate)

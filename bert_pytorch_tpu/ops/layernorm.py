@@ -107,8 +107,8 @@ def add_dropout_layer_norm(x, residual, scale, bias, seed, rate: float,
 
     Why this exists: with dropout expressed in the XLA graph, the keep-mask
     bits and the dropped tensor are materialized to HBM and re-read by the
-    backward pass, bloating the surrounding matmul fusions — measured 13 MFU
-    points at seq128 (results/ablate128.jsonl). The fused path evaluates the
+    backward pass, bloating the surrounding matmul fusions (the cost is not
+    measured on this runtime). The fused path evaluates the
     mask from a counter hash of (row, col, seed) inside the kernel, forward
     and backward, so it never touches HBM. The XLA fallback uses the same
     hash, so both paths drop identical units; the difference from nn.Dropout

@@ -14,7 +14,7 @@ Both stages are PURELY elementwise; the trust-ratio NORMS between them
 stay in optim/lamb.py's existing path (per-tensor or the bucketed
 parallel/coalesce.NormReducer) so the reduction grouping is untouched.
 
-Numerics contract (pinned in tests/test_fused_optim.py):
+Numerics contract (pinned in tests/test_pallas.py):
 
 - The XLA fallback (`impl="xla"`, auto-selected off-TPU) evaluates the
   SAME `_stage1_math` body PER LEAF with the same scalar/constant

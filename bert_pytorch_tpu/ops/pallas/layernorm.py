@@ -168,8 +168,7 @@ layer_norm_pallas.defvjp(_fwd_rule, _bwd_rule)
 # Pallas LN custom call forces the mask bits and the dropped tensor through
 # HBM (XLA cannot fuse elementwise producers into a custom call), and even
 # with the XLA LN the saved-for-backward mask traffic bloats every
-# surrounding matmul fusion — measured 13 MFU points at seq128
-# (results/ablate128.jsonl: no_hidden_dropout 66.1% vs baseline 53.0%).
+# surrounding matmul fusion (the cost is not measured on this runtime).
 #
 # This kernel evaluates the keep-mask from a counter-based hash of the
 # (global row, column, seed) — the same construction flash_attention.py uses

@@ -173,7 +173,7 @@ def _jitted_ring(mesh, rate: float, has_bias: bool, has_drop: bool,
                  has_seg: bool):
     """Build (and cache) the jitted shard_map program for one
     (mesh, dropout, segments) configuration. The jit makes the checkpointed
-    ring work when called eagerly (tests/debug) — under an outer jit the
+    ring work when called eagerly (tests, debugging) — under an outer jit the
     trace is simply inlined — and caching it keeps repeat eager calls from
     re-tracing; jax.jit's own cache handles shape changes."""
     from jax import shard_map

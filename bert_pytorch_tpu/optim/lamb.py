@@ -9,7 +9,7 @@ analogue) flattens the leaves into size-capped flat buckets and runs one
 launch per bucket per stage; off-TPU it auto-selects an XLA fallback that
 is bit-identical to the unfused chain (the kernel itself agrees to within
 a few ulps — see the numerics contract in fused_optim.py, pinned in
-tests/test_fused_optim.py). NVLAMB specifics honored:
+tests/test_pallas.py). NVLAMB specifics honored:
 
 1. optional pre-normalization of the *global* gradient by
    max(1, ||g||_global / max_grad_norm)  (apex FusedLAMB max_grad_norm=1.0),
@@ -89,7 +89,7 @@ def lamb(
     "pallas"/"xla" to force — the kernel agrees with the fallback to
     within a few ulps (cross-program FMA-contraction ambiguity; see the
     numerics contract in fused_optim.py). Both pinned in
-    tests/test_fused_optim.py. With a ZeRO-1-sharded state
+    tests/test_pallas.py. With a ZeRO-1-sharded state
     also pass `norm_reducer`: the fused stages reuse its mesh + leaf
     specs to run shard_mapped on local shards (zero extra collectives);
     without it GSPMD would reshard the leaves around each bucket

@@ -1,7 +1,7 @@
 """Coalesced cross-device reductions: the scalar-all-reduce storm, bucketed.
 
-MULTICHIP_r07 attribution and the graph lint agree on where multichip
-wall time goes: collectives. The largest *count* contributor in the
+Multichip wall time goes to collectives (their share is not measured on
+this runtime; the graph lint counts them). The largest *count* contributor in the
 compiled train steps is not the gradient traffic (a handful of large,
 bandwidth-bound ops) but the reduction STORM of tiny scalars — LAMB's
 per-tensor trust-ratio norms alone compile to two `f32[]`/`f32[L]`

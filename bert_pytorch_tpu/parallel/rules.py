@@ -6,8 +6,7 @@ parallel/mesh.py, the ZeRO-1 free-dim-first derivation in
 parallel/zero.py, the K-FAC stacked-factor placement in optim/kfac.py,
 the batch-input layout in mesh.batch_sharding, and the serving engine's
 implicit single-device placement — which meant every collective
-optimization (MULTICHIP_r07: 75-94% of multichip wall time is
-collectives) had to reason about specs it could not see in one place.
+optimization had to reason about specs it could not see in one place.
 This module is that one place:
 
 - `BASE_RULES`: the logical-axis -> mesh-axis table (each entry carries
@@ -115,8 +114,7 @@ BASE_RULES: Tuple[Rule, ...] = (
 # production mesh runs" is a name in the rules table, not a flag recipe
 # scattered across launch scripts. Its RULE rows are identical to
 # BASE_RULES (empty override tuple: every production mesh composes
-# through the base table — measured, not assumed, by the
-# dp_seq_packing_overlap MULTICHIP variant); what the name carries is
+# through the base table); what the name carries is
 # the feature set `production_features(mesh)` derives per mesh shape.
 PRODUCTION_CONFIG = "production"
 
@@ -192,8 +190,8 @@ def production_features(mesh=None) -> Dict[str, bool]:
       resolved config names the whole composition).
 
     run_pretraining consumes this when --mesh_config resolves to
-    `production`; bench.py's `dp_seq_packing_overlap` variant measures
-    the full composition so the default is backed by a number."""
+    `production`. The full composition's rate is not measured on this
+    runtime: no benchmark cell has a multi-chip mesh yet."""
     sizes = dict(mesh.shape) if mesh is not None else {}
     data = sizes.get("data", 1) > 1
     return {

@@ -25,8 +25,8 @@ What each flag buys the data-parallel/ZeRO-1 step (parallel/zero.py):
     layer i's compute.
 
 These are libtpu flags: on CPU/GPU backends LIBTPU_INIT_ARGS is simply never
-read, so applying the pack is a safe no-op off-TPU (the multichip CPU-mesh
-bench and the tests run with it applied). Must be called BEFORE the first
+read, so applying the pack is a safe no-op off-TPU (the CPU-mesh tests run
+with it applied). Must be called BEFORE the first
 jax device/backend touch in the process; importing jax is fine, initializing
 the backend is not.
 """
@@ -84,7 +84,7 @@ def pack_state(env: Optional[MutableMapping[str, str]] = None) -> dict:
     """Provenance view of the runtime flag state (telemetry/provenance.py):
     the full LIBTPU_INIT_ARGS value plus which pack flags are present —
     enough to reproduce the collective-overlap configuration of a run from
-    its log header or bench JSON alone."""
+    its log header alone."""
     if env is None:
         env = os.environ
     value = env.get(_ENV_VAR, "")

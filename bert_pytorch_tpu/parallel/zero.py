@@ -1,8 +1,8 @@
 """ZeRO-1 optimizer-state sharding over the data-parallel mesh axis.
 
 Under pure data parallelism every chip holds a full replica of the LAMB
-moments and redundantly executes the full once-per-step update — the HBM
-floor PERF.md pegs at ~9 MFU points at BERT-Large scale. This module is the
+moments and redundantly executes the full once-per-step update — an HBM
+floor whose cost is not measured on this runtime. This module is the
 TPU-native analog of the reference's apex `DistributedFusedLAMB` /
 K-FAC HYBRID_OPT distributed-optimizer ownership (run_pretraining.py:325-327):
 each data-parallel chip owns 1/N of every moment tensor and computes only its

@@ -55,8 +55,8 @@ DATASETS: Dict[str, Dict[str, Resource]] = {
             "enwiki-latest-pages-articles.xml.bz2",
             "enwiki-latest-pages-articles.xml.bz2", extract=True),
     },
-    # GLUE per-task archives (the canonical hosting the W4ngatang
-    # download_glue_data.py script resolves; reference defaulted to
+    # GLUE per-task archives (the canonical hosting W4ngatang's GLUE
+    # download script resolves; reference defaulted to
     # tasks=['MRPC', 'SST'], utils/download.py:81-83).
     "glue": {
         "CoLA": Resource(

@@ -13,9 +13,8 @@ Three layers, each usable on its own:
   Prometheus /metrics and /healthz every training phase already serves,
   wired through telemetry.init_run(phase="serve").
 
-`run_server.py` at the repo root assembles them; tools/loadtest.py +
-scripts/serve_bench.sh measure them; docs/SERVING.md is the operator
-guide.
+`run_server.py` at the repo root assembles them; tools/loadtest.py drives
+them; docs/SERVING.md is the operator guide.
 """
 
 from bert_pytorch_tpu.serving.batcher import (  # noqa: F401

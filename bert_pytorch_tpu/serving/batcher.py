@@ -6,11 +6,12 @@ Training Performance of the Unpadded BERT Model", PAPERS.md 2208.08124):
 several short requests share one (S,) row, segment-aware attention keeps
 them from seeing each other, and the per-request outputs are plain row
 slices because every head this server runs (QA span logits, NER token
-logits) is token-local. Packed-vs-one-per-batch responses are
-BIT-identical (tests/test_serving.py pins it): cross-segment attention
-probabilities are exactly zero on every kernel path, reductions keep the
-same length (the row is the bucket either way), and nothing else mixes
-tokens.
+logits) is token-local. Packed-vs-one-per-batch responses decode to the
+same answers, with logits equal up to the summation order of the attention
+core's `probs @ V` (tests/test_serving.py pins it; bit-identical for a
+request at the start of its row): cross-segment attention probabilities
+are exactly zero on every kernel path, reductions keep the same length
+(the row is the bucket either way), and nothing else mixes tokens.
 
 Flow control, in order:
 
@@ -53,7 +54,7 @@ spans (admit/queue_wait/pack/dispatch/compute/demux/respond, terminal
 shed/timeout/too_long/error) and retires into the scheduler's TraceRing.
 All span recording is host Python on host timestamps — nothing touches
 the batch arrays or the compiled program, which is why tracing on/off
-cannot perturb packed-vs-single bit-identity. The compute span also
+cannot perturb a response. The compute span also
 drives the cost layer: wave wall-time x replica device count =
 device-seconds, pro-rated to member requests by real tokens and
 accumulated into `bert_serve_device_seconds_total` and the per-task
@@ -584,7 +585,7 @@ class Scheduler:
     def _execute(self, i: int, wave: _Wave) -> None:
         """Forward one wave on replica i and demux. Replica choice cannot
         change results: every replica compiled the same program from the
-        same params, so packed-vs-single bit-identity holds per replica.
+        same params, so what holds packed-vs-single holds per replica.
 
         Tracing here is timestamps around existing calls — the batch
         arrays and the forward are untouched, so tracing on/off cannot

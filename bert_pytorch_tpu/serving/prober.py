@@ -11,9 +11,9 @@ way production canaries do — it IS a client:
   low fixed rate (`interval_s`), so the probe exercises the entire
   path: routing, featurization, admission, packing, forward, decode;
 - the FIRST successful decode per task is pinned as that task's
-  reference answer (the engine is deterministic — packed-vs-single and
-  replica bit-identity are proven properties, so the same payload must
-  decode identically forever);
+  reference answer (the engine is deterministic — packed-vs-single
+  equality of answers and replica bit-identity are tested properties, so
+  the same payload must decode identically forever);
 - every later probe is verified two ways: schema invariants per task
   (labels count == token count, softmax sums to 1, embedding is
   unit-norm, choice index in range) and an exact-after-rounding match

@@ -17,8 +17,7 @@ the task automatically gains
   task set against `all_tasks()`, so a registered-but-unserved (or
   served-but-unregistered) task fails the gate;
 - graph-lint eligibility (tools/graphcheck.py serve/finetune combos
-  derive expectations from the specs) and perfboard-indexed finetune
-  perf records.
+  derive expectations from the specs).
 
 A TaskSpec is data, not subclassing: callables for the model head, loss,
 featurizer, predict/decode, metric, and serving service, plus the

@@ -3,7 +3,7 @@
 The run_squad.py entry point's task-shaped half, registered: CLI parity
 with the reference run_squad.py (:729-859), featurize/train/predict/
 n-best/eval through tasks/squad.py, serving on POST /v1/squad. The
-training/eval loop itself lives in training/finetune.py (run_squad.py is
+training and eval loop itself lives in training/finetune.py (run_squad.py is
 a thin alias of run_finetune.py --task squad).
 
 Packed training (--packing): spans shift by each segment's packing

@@ -10,13 +10,13 @@ allows" goal keeps hitting blind:
   one-step-lag readback stays non-blocking.
 - `stepwatch` — host-side per-interval accounting: step wall time, data-wait
   vs dispatch vs metric-flush time, seq/s, tokens/s, and MFU from the
-  analytic BERT FLOPs formula (shared with bench.py).
+  analytic BERT FLOPs formula (`flops_per_seq`).
 - `compile_watch` — jax.monitoring listener counting XLA compiles and their
   durations, loud on recompiles after warmup (the ZeRO-1 gate saga: a
   silent recompile is a silent 2x step time), plus device memory_stats
   snapshots (peak HBM).
 - `provenance` — run stamps (git SHA, jax/jaxlib versions, mesh shape,
-  xla_flags pack) so every log header and bench JSON is self-describing.
+  xla_flags pack) so every log header is self-describing.
 - `flight_recorder` — the black box: a bounded host-side ring of the last
   K batches + RNGs + metric records, dumped as a self-contained repro
   bundle when the health pack flags a step or the process dies;
@@ -32,13 +32,13 @@ allows" goal keeps hitting blind:
   every producer above publishes through, a stdlib `/metrics` +
   `/healthz` HTTP exporter (`--metrics_port`), per-host metrics jsonl
   with a process-0 cross-host fold + straggler detection, and
-  `init_run(phase=...)` — the single wiring path all entry points and
-  bench.py construct their telemetry through.
+  `init_run(phase=...)` — the single wiring path all entry points
+  construct their telemetry through.
 
 Re-exports resolve LAZILY (PEP 562): `health` pulls in jax+flax at import
-time, and consumers like bench.py's parent process import only the pure-
-host pieces (stepwatch/provenance) while staying deliberately jax-free
-until their children own the backend.
+time, and a parent process that starts children on the chip imports only
+the pure-host pieces (stepwatch/provenance) and stays jax-free so its
+children can own the backend.
 
 docs/OBSERVABILITY.md is the operator-facing guide.
 """

@@ -370,7 +370,7 @@ class FlightRecorder:
                                ) -> None:
         """Map SIGTERM/SIGINT to SystemExit(128+sig) so the train loop's
         except-path flushes metrics and dumps the bundle before the
-        process unwinds (bench.py gives the same guarantee for its JSON).
+        process unwinds.
         Also registers an atexit backstop that dumps if the process exits
         while armed with nothing dumped yet. No-op for handlers that
         cannot be installed (non-main thread)."""

@@ -1,6 +1,6 @@
 """Run provenance stamps.
 
-A BENCH_*.json or logfile found three rounds later is only evidence if it
+A logfile found three rounds later is only evidence if it
 says WHAT produced it: which commit, which jax, which mesh, which libtpu
 flag pack. `collect()` gathers exactly that, tolerating every failure mode
 (no git, no backend up yet) by degrading fields to "unknown" rather than
@@ -36,11 +36,11 @@ def git_sha(cwd: Optional[str] = None) -> str:
 
 def collect(mesh=None, device: bool = True,
             extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """One provenance dict for log headers and bench JSONs.
+    """One provenance dict for log headers.
 
-    device=False skips every field that would touch the jax backend —
-    bench.py's parent process must not initialize the TPU while its
-    children try to attach (bench.py platform-probe contract).
+    device=False skips every field that would touch the jax backend — a
+    parent process must not initialize the TPU while its children try to
+    attach.
     """
     import jax
     import jaxlib

@@ -20,13 +20,13 @@ monitoring) so `render_prometheus()` is a serialization, not a translation:
 Every family takes declared label names; a registry may also carry
 constant labels (e.g. `phase="pretrain"`) stamped on every series, which is
 what makes the SAME instrument code phase-agnostic: run_pretraining,
-run_squad, run_ner, bench, and a future server differ only in that one
+run_squad, run_ner and run_server differ only in that one
 label. Families are get-or-create (two producers naming the same family
 share it); re-declaring a name with a different kind is a loud error.
 
 Stdlib-only and thread-safe (the exporter's http thread reads while the
 train loop writes); no jax import — the registry must be constructible in
-bench.py's deliberately backend-free parent and in jax-free tools.
+a deliberately backend-free parent process and in jax-free tools.
 
 telemetry/exporter.py serves `render_prometheus()` over HTTP;
 `snapshot()` is the strict-JSON form that rides in flight-recorder
@@ -309,8 +309,8 @@ class MetricsRegistry:
 
 
 def parse_prometheus(text: str) -> Dict[str, Dict[str, float]]:
-    """Minimal parser of the exposition format — enough for tests and the
-    perfboard to assert on a live /metrics payload without a prometheus
+    """Minimal parser of the exposition format — enough for tests and
+    tools/loadtest.py to read a live /metrics payload without a prometheus
     client dependency. Returns {metric_name: {label_str: value}} where
     label_str is the raw '{...}' chunk ('' for label-less series);
     `parse_prometheus_labels` turns a chunk back into the original

@@ -3,9 +3,9 @@
 Before this module, every entry point hand-assembled the same block —
 MetricLogger with the right sinks, CompileWatch with a warn hook into the
 logger, StepWatch from the shared FLOPs formula, provenance header — four
-slightly-different copies (run_pretraining / run_squad / run_ner /
-bench.py), and a fifth consumer (a future `serving/` process, ROADMAP
-item 1) would have made five. `init_run` is the single construction site:
+slightly-different copies (run_pretraining / run_squad / run_ner and a
+benchmark script), and a fifth consumer (the `serving/` process) would
+have made five. `init_run` is the single construction site:
 
     tel = telemetry.init_run(phase="pretrain",
                              log_prefix=os.path.join(out, "logfile"),
@@ -329,7 +329,7 @@ def init_run(phase: str,
              process_count: int = 1,
              straggler_z: float = 3.0) -> TelemetryRun:
     """Build the run's telemetry in one call — THE wiring path every
-    entry point (and bench.py) uses; see the module docstring for the
+    entry point uses; see the module docstring for the
     handle's surface.
 
     `metrics_port=None` disables the exporter; `0` binds an ephemeral

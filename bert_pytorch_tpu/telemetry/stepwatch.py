@@ -31,9 +31,9 @@ longer useful. StepWatch keeps per-interval accounting while the job runs:
 - MFU from the analytic BERT FLOPs-per-step formula below, against the
   device's known peak.
 
-The FLOPs formula is THE shared single source of truth: bench.py imports
-`flops_per_seq` / `PEAK_FLOPS` from here, so the bench headline MFU and the
-live training MFU can never drift apart.
+The FLOPs formula is the program's single source of truth for the live
+MFU. The benchmark keeps its own copy (benchmark/harness/flops.py) so that
+the yardstick does not move when the program does.
 
 Everything here is plain host Python — no device work, no added
 host-device sync. Timing uses time.perf_counter (injectable for tests).
@@ -135,8 +135,9 @@ def flops_per_seq(cfg, seq_len: int, vocab: int, n_pred: int) -> float:
 
 def host_annotation(name: str) -> ContextManager:
     """A `host/...` span in jax.profiler's trace. A process that never
-    imported jax (bench.py's parent) has no profiler to write to and gets
-    a no-op; this module stays importable without jax."""
+    imported jax (a parent that leaves the chip to its children) has no
+    profiler to write to and gets a no-op; this module stays importable
+    without jax."""
     jax = sys.modules.get("jax")
     if jax is None:
         return nullcontext()

@@ -1,7 +1,7 @@
 """Trace-event summarizer: collective vs compute vs host attribution.
 
-MULTICHIP_r06 measured 0.15–0.21 per-chip scaling efficiency and could not
-say WHERE the other 80% went — the bench only had wall clocks. jax.profiler
+A multi-chip run that scales badly cannot say WHERE the time went from
+wall clocks alone. jax.profiler
 already writes a Chrome-trace-event JSON (`*.trace.json.gz` under
 `<log_dir>/plugins/profile/<run>/`) whose per-op events carry HLO names on
 both TPU and the forced-CPU mesh, and the PR-8 host-loop TraceAnnotations
@@ -25,8 +25,7 @@ summary divides by `n_devices` when given to report per-device time.
 
 stdlib-only (gzip + json), no jax import — the summarizer must run on a
 login host against a trace scp'd out of a pod job. `tools/trace_summary.py`
-is the CLI; bench.py --multichip calls `summarize_trace` directly to land
-the breakdown in MULTICHIP_r*.json per variant.
+is the CLI.
 """
 
 from __future__ import annotations

@@ -14,7 +14,7 @@ What every task inherits from the loop, for free:
 - telemetry via the single `init_run(phase=<task>)` wiring path —
   jsonl/csv sinks, live /metrics + /healthz, CompileWatch, and StepWatch
   perf records carrying `real_tokens_per_sec` / `pad_fraction` /
-  `packing_efficiency` end to end (tools/perfboard.py indexes them);
+  `packing_efficiency` end to end;
 - the survival kit (docs/RESILIENCE.md): SIGTERM/SIGINT emergency
   checkpoint of the in-progress state, optional hung-step watchdog;
 - **packed training** (`--packing`): the greedy first-fit packer
@@ -29,8 +29,8 @@ What every task inherits from the loop, for free:
   fits their longest example instead of always padding to
   max_seq_length — a handful of compiles, most of the pad FLOPs gone;
 - a final orbax checkpoint (`<output_dir>/ckpt`) in the finetune save
-  layout run_server.py restores, and an optional FINETUNE perf artifact
-  (`--perf_artifact`) for the perfboard gate.
+  layout run_server.py restores, and an optional finetune perf summary
+  (`--perf_artifact`; no tool reads it yet — ROADMAP.md Design).
 """
 
 from __future__ import annotations
@@ -74,8 +74,7 @@ def add_common_finetune_flags(p) -> None:
     p.add_argument("--perf_artifact", type=str, default=None,
                    help="merge this run's finetune perf summary "
                         "(real_tokens_per_sec, pad_fraction, ...) into "
-                        "the given FINETUNE_*.json artifact "
-                        "(tools/perfboard.py indexes + gates it)")
+                        "the given JSON file (one entry per task)")
 
 
 def base_finetune_parser(description: str):
@@ -672,9 +671,8 @@ class TaskRun:
 
 def write_finetune_artifact(path: str, task: str,
                             record: Dict[str, Any]) -> None:
-    """Merge one task's finetune perf summary into a FINETUNE_*.json
-    artifact (tools/perfboard.py indexes these; several tasks accumulate
-    into one file)."""
+    """Merge one task's finetune perf summary into the JSON artifact at
+    path (several tasks accumulate into one file)."""
     doc: Dict[str, Any] = {"schema_version": 1, "kind": "finetune",
                            "tasks": {}}
     try:
@@ -779,7 +777,7 @@ def run_task(spec, args) -> Dict[str, Any]:
             # is rejected with --packing above),
             # a plain step batch*accum*group rows (multiple choice
             # computes C rows per example). Getting this wrong skews the
-            # perfboard-gated MFU/pad_fraction (seq_per_sec therefore
+            # reported MFU/pad_fraction (seq_per_sec therefore
             # counts rows, not examples; results[
             # "training_sequences_per_second"] below counts examples
             # actually consumed, both modes).

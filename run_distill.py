@@ -25,7 +25,7 @@ loss: scripts/check_distill.sh asserts it decreases).
 
 `--inject broken_student` (negative control, CI only): evaluate a
 fresh-random student instead of the trained one, so the distillation
-accuracy-floor gate (tools/perfboard.py --check_distill) must trip.
+accuracy-floor gate (tools/loadtest.py --check_distill) must trip.
 """
 
 from __future__ import annotations

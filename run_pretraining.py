@@ -231,12 +231,11 @@ def parse_arguments(argv=None):
                              "'production' turns on the collective-time "
                              "pack the mesh qualifies for — packing, "
                              "ZeRO-1 overlap (data>1), fsdp gather-on-use "
-                             "(fsdp>1), ring attention (seq>1) — measured "
-                             "by the dp_seq_packing_overlap MULTICHIP "
-                             "variant. 'auto' selects production on real "
+                             "(fsdp>1), ring attention (seq>1). "
+                             "'auto' selects production on real "
                              "accelerators when the mesh has a non-trivial "
                              "parallel axis (forced-CPU harness meshes "
-                             "keep 'base' so test/bench programs only "
+                             "keep 'base' so test programs only "
                              "change when asked); 'base' keeps every "
                              "feature at its own flag's default")
     parser.add_argument("--coalesce_reductions", type=str, default="off",
@@ -250,8 +249,8 @@ def parse_arguments(argv=None):
                              "the factor statistics reduce in "
                              "size-capped buckets too (--kfac_bucket_mb). "
                              "Values bit-identical for the norm paths; "
-                             "K-FAC factor parity documented in "
-                             "docs/PERF.md round 15")
+                             "K-FAC factor state allclose to the "
+                             "per-site program (tests/test_kfac.py)")
     parser.add_argument("--kfac_bucket_mb", type=float, default=4.0,
                         help="bucket size cap (MB) for coalesced K-FAC "
                              "factor reductions (--coalesce_reductions); "
@@ -609,15 +608,14 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from bert_pytorch_tpu.compile_cache import enable_compile_cache
-    from bert_pytorch_tpu.config import (Lfm2MoeConfig, load_model_config,
-                                         pad_vocab_size)
+    from bert_pytorch_tpu.config import load_model_config, pad_vocab_size
     from bert_pytorch_tpu.data.sharded import (
         HostShardSampler, PretrainingDataLoader, ShardIndex)
-    from bert_pytorch_tpu.models import BertForPreTraining
+    from bert_pytorch_tpu.models.families import family_of
     from bert_pytorch_tpu.optim import schedulers
     from bert_pytorch_tpu.parallel import dist, mesh as mesh_lib
     from bert_pytorch_tpu.telemetry import (
-        HealthConfig, SetupWatch, collect_provenance, flops_per_seq,
+        HealthConfig, SetupWatch, collect_provenance,
         hbm_snapshot, device_peak_flops, init_run, init_telemetry_state)
     from bert_pytorch_tpu.resilience import ChaosMonkey, PreemptionGuard
     from bert_pytorch_tpu.resilience.preemption import (emergency_save,
@@ -651,7 +649,7 @@ def main(argv=None):
     os.makedirs(args.output_dir, exist_ok=True)
     # ONE telemetry wiring path (telemetry/run.py): logger + compile watch
     # + registry (+ /metrics server and the multi-host perf fold when
-    # enabled) come from init_run — the same call run_squad/run_ner/bench
+    # enabled) come from init_run — the same call run_finetune/run_server
     # make, so every phase emits identically-shaped records
     tel = init_run(
         phase="pretrain",
@@ -682,10 +680,8 @@ def main(argv=None):
         # -- named mesh config (parallel/rules.py CONFIG_OVERRIDES) ---------
         # 'production' = the round-15 collective-time pack; 'auto' selects
         # it on real accelerators whenever the mesh has a non-trivial
-        # parallel axis. Forced-CPU meshes (the test/bench harness) stay
-        # on 'base' under auto so harness programs only change when asked
-        # — the composition is still measured there by bench.py's
-        # dp_seq_packing_overlap variant.
+        # parallel axis. Forced-CPU meshes (the test harness) stay on
+        # 'base' under auto so harness programs only change when asked.
         from bert_pytorch_tpu.parallel import rules as rules_lib
 
         production = (args.mesh_config == "production"
@@ -778,18 +774,13 @@ def main(argv=None):
             config = load_model_config(args.model_config_file)
         except ValueError as e:
             raise SystemExit(f"--model_config_file: {e}")
-        # a decoder family (causal LM over packed rows) or BERT (MLM + NSP)
-        decoder = isinstance(config, Lfm2MoeConfig)
-        if decoder:
-            from bert_pytorch_tpu.models import lfm2_moe
-        if decoder and (args.kfac or args.stream_dir
-                        or args.stacked_params != "auto"
-                        or args.steps_per_loop > 1):
-            raise SystemExit(
-                f"model_type {config.model_type!r} trains through the "
-                "offline data plane with LAMB/Adam, one step a dispatch: "
-                "--kfac, --stream_dir, --stacked_params and "
-                "--steps_per_loop do not apply to it")
+        # everything else this function chooses by family (BERT: MLM +
+        # NSP; a decoder family: causal LM over packed rows) is in this one
+        # record (models/families.py)
+        family = family_of(config)
+        refusal = family.refusal(args)
+        if refusal:
+            raise SystemExit(refusal)
         config = config.replace(
             vocab_size=pad_vocab_size(config.vocab_size,
                                       args.vocab_pad_multiple),
@@ -806,10 +797,7 @@ def main(argv=None):
                       else None)
 
         def make_model(config):
-            if decoder:
-                return lfm2_moe.Lfm2MoeForCausalLM(config,
-                                                   dtype=compute_dtype)
-            return BertForPreTraining(config, dtype=compute_dtype)
+            return family.make_model(config, compute_dtype)
 
         model = make_model(config)
 
@@ -934,7 +922,7 @@ def main(argv=None):
                 packing=args.packing,
                 packing_max_segments=args.packing_max_segments,
                 packing_lookahead=args.packing_lookahead,
-                objective="clm" if decoder else "mlm")
+                objective=family.objective)
             logger.info(f"dataset: {len(index)} samples in "
                         f"{len(index.files)} shards; host step batch "
                         f"{host_step_batch}; [MASK]={mask_id}"
@@ -964,7 +952,7 @@ def main(argv=None):
         # by segments * max_pred; mlm_dropped warns loudly if reality ever
         # exceeds this.
         max_pred_row = args.max_predictions_per_seq
-        if args.packing and not decoder:
+        if args.packing and family.mlm_head:
             max_pred_row = min(
                 seq_len,
                 args.packing_max_segments * args.max_predictions_per_seq,
@@ -975,12 +963,8 @@ def main(argv=None):
                         f"(per-example cap {args.max_predictions_per_seq})")
 
         def init_fn(rng):
-            if decoder:
-                return model.init(rng, *lfm2_moe.init_inputs(
-                    {k: v[0] for k, v in stacked.items()}))
-            return model.init(rng, jnp.asarray(stacked["input_ids"][0]),
-                              jnp.asarray(stacked["token_type_ids"][0]),
-                              jnp.asarray(stacked["attention_mask"][0]))
+            return model.init(rng, *family.init_inputs(
+                {k: v[0] for k, v in stacked.items()}))
 
         ckpt_dir = os.path.join(args.output_dir, "pretrain_ckpts")
         manager = CheckpointManager(ckpt_dir,
@@ -1103,19 +1087,15 @@ def main(argv=None):
             # is exact)
             common = dict(
                 schedule=schedule, accum_steps=accum_steps,
-                max_predictions=max_pred_row,
+                max_predictions=max_pred_row if family.mlm_head else None,
                 grad_dtype=grad_dtype, zero1=plan, health=health_cfg,
                 nan_inject_step=args.inject_nonfinite_step,
                 norm_reducer=norm_reducer)
             if kfac is not None:
                 return build_kfac_pretrain_step(model, tx, kfac,
                                                 pert_template, **common)
-            if decoder:
-                common.update(
-                    max_predictions=None,
-                    loss_fn_builder=lfm2_moe.pretrain_loss_fn_builder,
-                    keep_float32=lfm2_moe.keep_float32)
-            return build_pretrain_step(model, tx, **common)
+            return build_pretrain_step(model, tx, **common,
+                                       **family.step_kwargs)
 
         epoch = 0
         if manager.latest_step() is not None:
@@ -1231,15 +1211,9 @@ def main(argv=None):
         # therefore step_flops) is already GLOBAL across hosts — it pairs
         # with the global peak (peak_per_device * device_count) for MFU
         seqs_per_step = accum_steps * micro_global
-        if decoder:
-            # the family's own formula (never BERT's): an upper estimate
-            # for packed rows, whose documents attend less than a full row
-            step_flops = (lfm2_moe.train_flops_per_row(config, seq_len)
-                          * seqs_per_step)
-        else:
-            step_flops = flops_per_seq(
-                config, seq_len, config.vocab_size,
-                max_pred_row) * seqs_per_step
+        step_flops = (family.train_flops_per_row(config, seq_len,
+                                                 max_pred_row)
+                      * seqs_per_step)
         # None on the CPU backend (no MFU there); an accelerator the peak
         # table does not know is an error
         peak = device_peak_flops(jax.devices()[0], dtype=config.dtype)
@@ -1427,12 +1401,9 @@ def main(argv=None):
         done = False
         pending = None  # (step, epoch, metrics) awaiting logging
         warned_dropped = False
-        expert_load = None  # routed layers' cumulative [perf] counters
-        if decoder:
-            from bert_pytorch_tpu.telemetry.expert_load import \
-                ExpertLoadCounters
-
-            expert_load = ExpertLoadCounters()
+        # the family's cumulative [perf] counters (a routed family's expert
+        # loads), or None
+        family_counters = family.make_counters()
         halt_pending = None  # message; raised after cleanup-safe point
         dispatches = 0  # jit calls made; gates compile-warmup closure
         fp_holder = [None]  # program fingerprint, filled by a worker thread
@@ -1499,8 +1470,8 @@ def main(argv=None):
 
         def log_flushed(step_i, epoch_i, vals):
             nonlocal loss_sum, loss_n, warned_dropped, halt_pending
-            if expert_load is not None:
-                expert_load.update(vals)
+            if family_counters is not None:
+                family_counters.update(vals)
             if recorder is not None:
                 # metrics tail rides in the bundle: the black box records
                 # what tripped, not just the inputs
@@ -1563,8 +1534,7 @@ def main(argv=None):
             handler), an exception, a NonFiniteHalt — the buffered
             metrics (pending readback + StepWatch partial interval) land
             in the sinks and the flight recorder dumps its bundle BEFORE
-            the stack unwinds. bench.py has guaranteed this for its JSON
-            since round 7; the training loop now matches."""
+            the stack unwinds."""
             try:
                 flush_pending()
             except Exception:
@@ -1797,8 +1767,8 @@ def main(argv=None):
                             perf.update(compile_watch.snapshot())
                             perf.update(hbm_snapshot())
                             perf.update(step_fields)
-                            if expert_load is not None:
-                                perf.update(expert_load.fields())
+                            if family_counters is not None:
+                                perf.update(family_counters.fields())
                             tel.log_perf(global_step, perf)
                     if trace_active and global_step >= profile_range[1]:
                         with sw.phase("profile"):

@@ -6,8 +6,7 @@ registry (bert_pytorch_tpu/tasks/registry.py): every task served gets a
 `POST /v1/<task>` route, an AOT-compiled bucketed forward per sequence
 bucket, continuous packed batching, and the Prometheus /metrics +
 /healthz on one port via telemetry.init_run(phase="serve").
-docs/SERVING.md is the operator guide; tools/loadtest.py +
-scripts/serve_bench.sh drive it.
+docs/SERVING.md is the operator guide; tools/loadtest.py drives it.
 
     python run_server.py --model_config_file cfg.json --vocab_file vocab.txt \
         --task_checkpoint squad=out/ckpt --task_checkpoint ner=ner/ckpt \

@@ -14,14 +14,13 @@
 # (d) teacher + student legs assemble into a DISTILL artifact
 #     (loadtest --assemble --kind distill) carrying accuracy deltas and
 #     vs_teacher_per_chip, schema-valid,
-# (e) perfboard --check_distill PASSES on the clean student and TRIPS
+# (e) loadtest --check_distill PASSES on the clean student and TRIPS
 #     (exit nonzero) on `run_distill.py --inject broken_student` — the
 #     negative control that the accuracy floor actually gates.
 #
 #   scripts/check_distill.sh
 #
-# Fast by design (tiny model, short bursts) — the measured sweep lives
-# in scripts/distill_bench.sh.
+# Fast by design (tiny model, short bursts): it gates accuracy, not speed.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -167,8 +166,7 @@ python tools/loadtest.py --assemble "$WORK/DISTILL_smoke.json" \
 python tools/loadtest.py --validate "$WORK/DISTILL_smoke.json"
 
 echo "check_distill: (e) accuracy floor gates ..." >&2
-python tools/perfboard.py --check_distill "$WORK/DISTILL_smoke.json" \
-    --distill_max_delta 0.25
+python tools/loadtest.py --check_distill "$WORK/DISTILL_smoke.json" 0.25
 
 echo "check_distill: negative control (--inject broken_student) ..." >&2
 python run_distill.py "${COMMON_ARGS[@]}" \
@@ -183,8 +181,8 @@ python tools/loadtest.py --assemble "$WORK/DISTILL_broken.json" \
     "$WORK/mode_teacher.json" "$WORK/mode_student.json" \
     --kind distill --accuracy "teacher=$T_ACC" \
     --accuracy "student_1l_16=$BROKEN_ACC"
-if python tools/perfboard.py --check_distill "$WORK/DISTILL_broken.json" \
-    --distill_max_delta 0.25 --quiet; then
+if python tools/loadtest.py --check_distill "$WORK/DISTILL_broken.json" \
+    0.25; then
     echo "check_distill: FAIL — accuracy gate did NOT trip on the" \
          "broken_student injection (delta vs teacher: $T_ACC ->" \
          "$BROKEN_ACC)" >&2

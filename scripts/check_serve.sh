@@ -23,8 +23,8 @@
 #
 #   scripts/check_serve.sh
 #
-# Fast by design (short bursts, tiny fixture) — the measured sweep lives
-# in scripts/serve_bench.sh; this only proves the stack serves.
+# Fast by design (short bursts, tiny fixture): this only proves the stack
+# serves. Serving speed is not measured on this runtime (PERF.md section 7).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

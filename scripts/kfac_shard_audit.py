@@ -2,8 +2,8 @@
 """Per-device K-FAC state footprint: distributed ownership vs replicated.
 
 BERT-Large + K-FAC does not fit one 16G chip with replicated factors
-(measured: batch 8, accum 8, un-rematted needs 28.6G — results/
-kfac_large.jsonl notes); the reference hit the same wall on GPUs and
+(batch 8, accum 8, un-rematted, was seen to need 28.6G on an earlier
+runtime); the reference hit the same wall on GPUs and
 distributed inverse ownership (HYBRID_OPT, grad_worker_fraction,
 run_pretraining.py:325-327). This audit builds the production-shape
 KFACState for BERT-Large on an 8-device virtual mesh in both layouts and

@@ -2,7 +2,7 @@
 """Build a tiny self-contained serving fixture: vocab + model config +
 params-only checkpoints for EVERY registered task.
 
-scripts/serve_bench.sh and scripts/check_serve.sh need checkpoints the
+scripts/check_serve.sh and the serving tests need checkpoints the
 server can restore WITHOUT a training run — this writes them in seconds
 by iterating tasks/registry.py (a newly registered task automatically
 joins the fixture, and therefore the check_serve CI gate): a

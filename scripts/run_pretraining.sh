@@ -6,8 +6,7 @@
 # coordinator explicitly (bert_pytorch_tpu.parallel.dist.initialize).
 #
 #   scripts/run_pretraining.sh configs/bert_pretraining_phase1_config.json \
-#       data/encoded/sequences_lowercase_max_seq_len_128_next_seq_task_true \
-#       results/phase1
+#       <dir of encoded .hdf5 shards> <output dir>
 set -euo pipefail
 CONFIG=${1:?run config json}
 INPUT=${2:?input dir with .hdf5 shards}

@@ -86,3 +86,50 @@ def serving_fixture(tmp_path_factory):
     root = tmp_path_factory.mktemp("serving_fixture")
     paths = msf.build(str(root), max_pos=64)
     return msf, str(root), paths
+
+
+@pytest.fixture(scope="session")
+def assert_packing_invariant():
+    """What packing may and may not change in a served answer.
+
+    Followed through the serving fixture layer by layer (embeddings, qkv,
+    scores, the masked scores, exp, the softmax denominators and the
+    probabilities are bit-equal for a request served alone at the start of
+    a row and packed behind another request): the FIRST operation whose
+    output differs is the attention core's `probs @ V`
+    (`einsum("bhqk,bkhd->bqhd")`, ops/attention._xla_attention), a float32
+    contraction over the row's keys. Cross-segment probabilities are exact
+    zeros, so both sums hold the same n nonzero products, but XLA:CPU's dot
+    accumulates the key axis in vector lanes: which products share a lane,
+    and so the order they are added in, goes by the key's index in the row.
+    A request at keys 7..15 is summed in another association than at keys
+    0..8. Everything downstream is per token and carries the difference
+    on.
+
+    Two associations of a float32 sum of n terms differ by at most
+    2 (n - 1) u sum|terms| to first order, u = 2**-24 (Higham, Accuracy
+    and Stability of Numerical Algorithms, section 4.2); there is one such
+    contraction a layer, and a pooled head (classify, choice, embed) adds a
+    sum over its segment's tokens that is ordered the same way: n_sums in
+    all. So the property is: the decoded answer (the
+    argmax over an output's last axis: the span's start and end, a token's
+    label, a class, a choice) is the same, and every output is equal to
+    within n_sums * 2 * (n_keys - 1) * u at the scale of its largest
+    element. This CPU reads 1-2 ulp (bound / 30)."""
+    import numpy as np
+
+    def check(single, packed, n_keys, n_sums, ctx):
+        single = single if isinstance(single, tuple) else (single,)
+        packed = packed if isinstance(packed, tuple) else (packed,)
+        assert len(single) == len(packed), ctx
+        for x, y in zip(single, packed):
+            x, y = np.asarray(x), np.asarray(y)
+            assert x.shape == y.shape, ctx
+            assert np.array_equal(np.argmax(x, -1), np.argmax(y, -1)), ctx
+            bound = (n_sums * 2 * (n_keys - 1) * 2.0 ** -24
+                     * np.abs(x).max())
+            assert np.abs(x - y).max() <= bound, (
+                f"{ctx}: differs by {np.abs(x - y).max():.3g}, the "
+                f"contraction's order allows {bound:.3g}")
+
+    return check

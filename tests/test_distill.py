@@ -17,9 +17,9 @@ The acceptance pins:
   points at run_distill.py's student model_config.json on a
   student-checkpoint-under-teacher-config mismatch;
 - the jax-free artifact chain: loadtest --assemble --kind distill
-  computes accuracy deltas + vs_teacher_per_chip, perfboard indexes the
-  artifact and `--check_distill` trips on a student below the accuracy
-  floor (and passes a student that beats its teacher).
+  computes accuracy deltas + vs_teacher_per_chip, and its
+  `--check_distill` trips on a student below the accuracy floor (and
+  passes a student that beats its teacher).
 """
 
 import json
@@ -330,7 +330,7 @@ def test_strict_merge_depth_mismatch_hint():
                 _strict_merge(student_tree, teacher_tree)
 
 
-# -- jax-free artifact chain: loadtest assemble + perfboard gate --------------
+# -- jax-free artifact chain: loadtest assemble + accuracy-floor gate ---------
 
 
 def _mode_doc(label, tag, dtype, rps, n_chips=1):
@@ -363,7 +363,7 @@ def _write_distill_artifact(tmp_path, accuracies):
         paths.append(str(p))
     doc = assemble(paths, kind="distill", accuracies=accuracies)
     assert validate_serve(doc) == []
-    out = tmp_path / "DISTILL_r99.json"
+    out = tmp_path / "DISTILL_test.json"
     out.write_text(json.dumps(doc, sort_keys=True))
     return doc, out
 
@@ -388,41 +388,24 @@ def test_loadtest_distill_assemble(tmp_path):
     assert "vs_teacher_per_chip" not in m["teacher_f32"]["saturation"]
 
 
-def test_perfboard_distill_index_and_gate(tmp_path):
-    from tools import perfboard
+def test_loadtest_distill_accuracy_gate(tmp_path):
+    from tools import loadtest
 
-    _, artifact = _write_distill_artifact(
+    doc, artifact = _write_distill_artifact(
         tmp_path, {"teacher": 0.92, "student_6l_768": 0.90,
                    "student_4l_512": 0.93})
-    kind, metrics, _ = perfboard.extract(str(artifact))
-    assert kind == "distill"
-    assert metrics["s6_f32.accuracy_delta"] == pytest.approx(0.02)
-    assert metrics["s6_f32.saturation.vs_teacher_per_chip"] == 2.1
-    assert metrics["teacher_f32.accuracy"] == 0.92
-    # gate directions: delta lower-better, ratio + accuracy higher-better
-    assert perfboard.metric_direction("x.accuracy_delta") == "lower"
-    assert perfboard.metric_direction(
-        "x.saturation.vs_teacher_per_chip") == "higher"
-    assert perfboard.metric_direction("x.accuracy") == "higher"
-
-    # index: the distill table lands in RUNS.md with model tags
-    records = perfboard.index_records(str(tmp_path))
-    distills = [r for r in records if r["kind"] == "distill"]
-    assert len(distills) == 1 and distills[0]["measured"]
-    md = perfboard.render_markdown(records)
-    assert "## Distillation" in md
-    assert "student_6l_768" in md and "student_4l_512" in md
-
     # the accuracy floor: 0.02 passes at 0.05, trips at 0.01; the
     # teacher-beating student never trips; rc via the CLI path
-    assert perfboard.main(["--check_distill", str(artifact),
-                           "--distill_max_delta", "0.05"]) == 0
-    assert perfboard.main(["--check_distill", str(artifact),
-                           "--distill_max_delta", "0.01"]) == 1
-    failures, notes = perfboard.check_distill(str(artifact), 0.01)
+    assert loadtest.main(["--check_distill", str(artifact), "0.05"]) == 0
+    assert loadtest.main(["--check_distill", str(artifact), "0.01"]) == 1
+    failures, notes = loadtest.validate_distill(doc, 0.01)
     assert [f for f in failures if "s6" in f]
     assert not [f for f in failures if "s4_f32" in f]
+    assert [n for n in notes if "s4_f32" in n and "beats teacher" in n]
     # an unmeasured student fails loudly
-    doc2, art2 = _write_distill_artifact(tmp_path, {"teacher": 0.92})
-    failures, _ = perfboard.check_distill(str(art2), 0.5)
+    doc2, _ = _write_distill_artifact(tmp_path, {"teacher": 0.92})
+    failures, _ = loadtest.validate_distill(doc2, 0.5)
     assert failures and "no accuracy_delta" in " ".join(failures)
+    # a serve artifact is not a distill artifact
+    failures, _ = loadtest.validate_distill(dict(doc, kind="serve"), 0.5)
+    assert failures and "not a distill artifact" in failures[0]

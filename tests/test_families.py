@@ -1,0 +1,152 @@
+"""models/families.py: one record a model family, read once by
+run_pretraining.main. The table's keys are config.MODEL_FAMILIES'; each
+record builds, initialises and steps its family's model with nothing but
+what the record says; the lfm2_moe record refuses the flags it names."""
+
+import json
+import os
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from bert_pytorch_tpu.config import (MODEL_FAMILIES, BertConfig,  # noqa: E402
+                                     Lfm2MoeConfig)
+from bert_pytorch_tpu.models.families import FAMILIES, family_of  # noqa: E402
+
+VOCAB, SEQ = 2048, 64
+LFM2_TOY = {
+    "model_type": "lfm2_moe", "vocab_size": VOCAB, "hidden_size": 32,
+    "intermediate_size": 64, "moe_intermediate_size": 16,
+    "num_hidden_layers": 2, "num_dense_layers": 1, "num_attention_heads": 2,
+    "num_key_value_heads": 1, "num_experts": 2, "num_experts_per_tok": 2,
+    "experts_total": 4, "experts_held": [0, 2],
+    "layer_types": ["conv", "full_attention"], "layers_kept": [0, 1],
+    "conv_L_cache": 3, "conv_bias": False, "norm_eps": 1e-5,
+    "norm_topk_prob": True, "use_expert_bias": True,
+    "routed_scaling_factor": 1,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "max_position_embeddings": 1024,
+}
+TINY = {
+    "bert": BertConfig(
+        vocab_size=VOCAB, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, intermediate_size=64,
+        max_position_embeddings=SEQ, dtype="float32", fused_ops=False,
+        attention_impl="xla"),
+    "lfm2_moe": Lfm2MoeConfig.from_dict(LFM2_TOY).replace(dtype="float32"),
+}
+
+
+def test_family_table_has_a_record_for_every_config_family():
+    assert set(FAMILIES) == set(MODEL_FAMILIES)
+    for name, cfg in TINY.items():
+        assert family_of(cfg) is FAMILIES[name]
+    with pytest.raises(ValueError, match="no model family"):
+        family_of(object())
+
+
+def _loader_batch(tmp_path, objective, rows):
+    from benchmark.harness import corpus
+    from bert_pytorch_tpu.data.sharded import (
+        HostShardSampler, PretrainingDataLoader, ShardIndex)
+
+    d = str(tmp_path / "data")
+    corpus.write_shards(d, {"samples": 64, "shards": 2, "lengths": {
+        "kind": "lognormal", "median": 20, "sigma": 0.6, "min": 16,
+        "max": SEQ}}, SEQ, VOCAB, 11)
+    index = ShardIndex(sorted(str(p) for p in Path(d).rglob("*.hdf5")))
+    loader = PretrainingDataLoader(
+        index, HostShardSampler(len(index), world_size=1, rank=0, seed=3),
+        batch_size=rows, mask_token_index=103, max_pred_per_seq=10,
+        masked_lm_prob=0.15, vocab_size=VOCAB, seed=3, packing=True,
+        packing_max_segments=4, packing_lookahead=8, objective=objective)
+    batch = next(iter(loader))
+    loader.close()
+    return batch
+
+
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_record_builds_initialises_and_steps_its_family(name, tmp_path):
+    """Everything main chooses by family, taken from the record alone: the
+    model, model.init's inputs from a loader batch of the record's
+    objective, and one optimizer step with the record's step keywords."""
+    import run_pretraining
+    from bert_pytorch_tpu.optim import schedulers
+    from bert_pytorch_tpu.telemetry import init_telemetry_state
+    from bert_pytorch_tpu.training import build_pretrain_step
+    from bert_pytorch_tpu.training.pretrain import stack_microbatches
+    from bert_pytorch_tpu.training.state import TrainState
+
+    family, cfg, accum, max_pred_row = FAMILIES[name], TINY[name], 2, 14
+    model = family.make_model(cfg, jnp.float32)
+    stacked = stack_microbatches(
+        _loader_batch(tmp_path, family.objective, rows=2 * accum), accum)
+    params = model.init(jax.random.PRNGKey(0), *family.init_inputs(
+        {k: v[0] for k, v in stacked.items()}))["params"]
+    schedule = schedulers.make_schedule("poly", 0.004, 100, warmup=0.0)
+    tx = run_pretraining.make_optimizer("lamb", schedule)
+    state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
+                       opt_state=tx.init(params),
+                       telemetry=init_telemetry_state())
+    step = build_pretrain_step(
+        model, tx, schedule=schedule, accum_steps=accum,
+        max_predictions=max_pred_row if family.mlm_head else None,
+        **family.step_kwargs)
+    new_state, metrics = jax.jit(step)(
+        state, {k: jnp.asarray(v) for k, v in stacked.items()},
+        jax.random.PRNGKey(1))
+    assert int(new_state.step) == 1
+    # seeded weights: the loss is the uniform guess (BERT: plus NSP's,
+    # at most ln 2)
+    assert -0.5 < float(metrics["loss"]) - np.log(VOCAB) < np.log(2) + 0.5
+    moved = jax.tree.map(lambda a, b: bool(jnp.any(a != b)),
+                         state.params, new_state.params)
+    assert any(jax.tree.leaves(moved))
+    assert family.train_flops_per_row(cfg, SEQ, max_pred_row) > 0
+    # the cumulative [perf] counters read the step's own scalars
+    counters = family.make_counters()
+    routed = any(k.startswith("moe_l") for k in metrics)
+    assert (counters is not None) == routed
+    if counters is not None:
+        counters.update({k: float(v) for k, v in metrics.items()})
+        assert counters.fields()["moe_l0_dropped"] == 0
+
+
+@pytest.mark.parametrize("flags", [
+    ["--kfac"], ["--stream_dir", "corpus"], ["--stacked_params", "true"],
+    ["--steps_per_loop", "2"]], ids=lambda f: f[0].lstrip("-"))
+def test_lfm2_moe_refuses_what_it_cannot_run_with(flags, tmp_path):
+    import run_pretraining
+
+    cfg_path = tmp_path / "toy.json"
+    cfg_path.write_text(json.dumps(LFM2_TOY))
+    argv = ["--model_config_file", str(cfg_path), "--output_dir",
+            str(tmp_path / "out"), "--tensorboard", "off"] + flags
+    if "--stream_dir" not in flags:
+        argv += ["--input_dir", str(tmp_path)]
+    with pytest.raises(SystemExit) as e:
+        run_pretraining.main(argv)
+    message = str(e.value)
+    assert "model_type 'lfm2_moe'" in message
+    for flag in ("--kfac", "--stream_dir", "--stacked_params",
+                 "--steps_per_loop"):
+        assert flag in message
+
+
+def test_bert_refuses_none_of_them():
+    import argparse
+
+    args = argparse.Namespace(kfac=True, stream_dir="corpus",
+                              stacked_params="true", steps_per_loop=2)
+    assert FAMILIES["bert"].refusal(args) is None
+    assert FAMILIES["lfm2_moe"].refusal(args)
+    args = argparse.Namespace(kfac=False, stream_dir=None,
+                              stacked_params="auto", steps_per_loop=1)
+    assert FAMILIES["lfm2_moe"].refusal(args) is None

@@ -384,7 +384,7 @@ def test_run_finetune_classify_packed_e2e(finetune_env):
     """The new-head acceptance pin: classification trains through
     run_finetune.py with --packing, LEARNS the marker task, and its perf
     records carry real_tokens_per_sec / pad_fraction end to end (plus
-    the FINETUNE artifact for the perfboard gate)."""
+    the --perf_artifact summary)."""
     import run_finetune
 
     from bert_pytorch_tpu.telemetry import PERF_RECORD_CORE_KEYS

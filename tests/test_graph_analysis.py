@@ -4,7 +4,7 @@ Fast half: parser + pass-framework units on synthetic HLO text fixtures
 (no compile, no jax beyond import) — budget regression names the op,
 donation miss detected, replicated-moment leaf detected, fingerprint
 compare semantics, budget-file schema, the jax-free --validate-budgets
-contract, the repolint fallback, and perfboard's graph_report indexing.
+contract, and the repolint fallback.
 
 Slow half (the acceptance drill): the REAL production step compiled on
 the forced 8-device CPU mesh passes the checked-in budgets, and injected
@@ -362,84 +362,6 @@ def test_repo_is_lint_clean():
     from tools import repolint
 
     assert repolint.main(list(repolint.DEFAULT_TARGETS)) == 0
-
-
-def test_perfboard_indexes_graph_report(tmp_path):
-    from tools import perfboard
-
-    kind, metrics, _ = perfboard.extract(
-        os.path.join(REPO, "results", "graph_report.json"))
-    assert kind == "graph"
-    assert metrics.get("zero1_dp8.collectives.all-gather", 0) > 0
-    assert metrics.get("zero1_dp8.donation_aliased", 0) >= 80
-    assert metrics.get("zero1_dp8.sharded_inputs", 0) > 0
-    # direction: collectives regress upward, donation downward
-    assert perfboard.metric_direction(
-        "zero1_dp8.collectives.all-gather") == "lower"
-    assert perfboard.metric_direction(
-        "zero1_dp8.donation_aliased") == "higher"
-    # an extra all-gather fails the graph-kind perf gate
-    cur = json.load(open(os.path.join(REPO, "results",
-                                      "graph_report.json")))
-    cur["combos"]["zero1_dp8"]["collective_counts"]["all-gather"] += 30
-    # ...and a kind growing from ZERO (the GSPMD-forked-collective class)
-    # must trip the gate too — zero baselines are recorded, not skipped
-    assert cur["combos"]["zero1_dp8"]["collective_counts"][
-        "collective-permute"] == 0
-    cur["combos"]["zero1_dp8"]["collective_counts"][
-        "collective-permute"] = 4
-    cur_path = tmp_path / "graph_report.json"
-    cur_path.write_text(json.dumps(cur))
-    regs, _ = perfboard.check_artifacts(
-        os.path.join(REPO, "results", "graph_report.json"), str(cur_path),
-        tolerance=0.1)
-    assert any("all-gather" in r for r in regs)
-    assert any("collective-permute" in r and "left zero" in r
-               for r in regs)
-
-
-def test_perfboard_reduce_scatter_gate_is_direction_aware(tmp_path):
-    """round 16: reduce-scatter is the one collective whose appearance is
-    progress (the rs grad path), so it gates 'nonzero' — regression ONLY
-    when a combo that compiled reduce-scatters drops back to zero (the rs
-    path silently reverting to all-reduce-then-slice)."""
-    from tools import perfboard
-
-    assert perfboard.metric_direction(
-        "zero1_rs_dp8.collectives.reduce-scatter") == "nonzero"
-    # the other collectives stay lower-better — all-reduce growing or a
-    # kind leaving zero still trips the gate (pinned above)
-    assert perfboard.metric_direction(
-        "zero1_rs_dp8.collectives.all-reduce") == "lower"
-
-    base = json.load(open(os.path.join(REPO, "results",
-                                       "graph_report.json")))
-    assert base["combos"]["zero1_rs_dp8"]["collective_counts"][
-        "reduce-scatter"] > 0
-    base_path = tmp_path / "base.json"
-    base_path.write_text(json.dumps(base))
-
-    # rs count collapsing to zero: regression, named as the rs path
-    # disappearing
-    cur = json.loads(json.dumps(base))
-    cur["combos"]["zero1_rs_dp8"]["collective_counts"]["reduce-scatter"] = 0
-    cur_path = tmp_path / "cur.json"
-    cur_path.write_text(json.dumps(cur))
-    regs, _ = perfboard.check_artifacts(str(base_path), str(cur_path),
-                                        tolerance=0.1)
-    assert any("reduce-scatter" in r and "disappeared" in r for r in regs)
-
-    # rs appearing from zero (legacy baseline -> rs current) is NOT a
-    # regression — the exact move the old lower-better rule would have
-    # flagged
-    legacy = json.loads(json.dumps(base))
-    legacy["combos"]["zero1_rs_dp8"]["collective_counts"][
-        "reduce-scatter"] = 0
-    legacy_path = tmp_path / "legacy.json"
-    legacy_path.write_text(json.dumps(legacy))
-    regs, _ = perfboard.check_artifacts(str(legacy_path), str(base_path),
-                                        tolerance=0.1)
-    assert not any("reduce-scatter" in r for r in regs)
 
 
 # --- the acceptance drill: real compiled programs ----------------------

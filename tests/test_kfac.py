@@ -469,7 +469,8 @@ def test_kfac_bucketed_reduction_parity():
        per-site reductions, which replicate activations for some sites
        and therefore sum in a different grouping): loss trajectory equal
        step for step, factor state allclose at reduction-reorder
-       tolerance. Deliberately not bit-equal — docs/PERF.md round 15.
+       tolerance. Deliberately not bit-equal: XLA replicates activations
+       for some sites, a grouping no local contraction reproduces.
     3. the compiled all-reduce count of the bucketed program is <= HALF
        the legacy one (the collective_budget ceiling checked in for
        kfac_zero1_dp8_bucketed enforces the same on the production gate

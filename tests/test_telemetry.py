@@ -637,7 +637,7 @@ def test_trace_classify_buckets():
 def test_trace_summarize_events_interval_merge_and_normalization():
     """Nested same-bucket events are merged (no double count), buckets are
     keyed per (pid, tid), and --steps/--devices produce the per-step
-    per-device numbers bench.py embeds in MULTICHIP_r*.json."""
+    per-device numbers tools/trace_summary.py prints."""
     from bert_pytorch_tpu.telemetry.trace import summarize_events
 
     us = 1000.0  # 1 ms in trace-event microseconds

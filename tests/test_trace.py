@@ -198,7 +198,7 @@ def test_collective_kind_split():
     """collective_kind_ms buckets every collective root into its class —
     all-gather / all-reduce / reduce-scatter / collective-permute /
     all-to-all, everything else under 'other' — with the same per-thread
-    interval merge as the totals, so MULTICHIP breakdowns can say WHICH
+    interval merge as the totals, so multi-chip breakdowns can say WHICH
     collective class a variant pays for."""
     from bert_pytorch_tpu.telemetry.trace import collective_kind
 

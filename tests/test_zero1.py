@@ -292,7 +292,7 @@ def test_zero1_overlap_bit_identical(stacked):
                              ("ovl", s_ovl, step_ovl)):
             # one compile serves both the HLO inspection and the run; the
             # counter is the analyzer's (shared with the graphcheck budget
-            # pass and bench --multichip), not a per-test regex
+            # pass), not a per-test regex
             compiled = fn.lower(st, batch, jax.random.PRNGKey(0)).compile()
             gathers[name] = collective_counts(
                 compiled.as_text())["all-gather"]
@@ -648,8 +648,8 @@ def test_no_involuntary_reshard_on_2x2_mesh(capfd):
     compile (don't just trace) the production train step — gathered MLM
     head, NSP, ZeRO-1 sharded LAMB — under a 2x2 (data x model) CPU mesh
     and assert XLA's SPMD partitioner emitted zero 'Involuntary full
-    rematerialization' warnings, so sharding regressions fail CI instead of
-    only the bench driver's MULTICHIP run.
+    rematerialization' warnings, so sharding regressions fail CI and not
+    only the standalone dryrun.
 
     The mesh is data x model (DP+TP), the combination where every
     annotated tensor has a consistent home; data x fsdp at this tiny size

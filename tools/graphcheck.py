@@ -4,10 +4,10 @@
 The program-structure bug class — fail-open sharding gates (round 7),
 GSPMD forking the ZeRO-1 gather into extra all-gathers (round 11),
 silently-dropped buffer donation — is invisible to unit tests until a
-multichip bench runs. This tool lowers + compiles the PRODUCTION step
+multichip run. This tool lowers + compiles the PRODUCTION step
 builders (build_pretrain_step / build_kfac_pretrain_step, the exact
 functions run_pretraining wires) for a named set of config x mesh combos
-on a forced 8-device CPU mesh — no TPU, no bench run — parses the
+on a forced 8-device CPU mesh — no TPU — parses the
 compiled HLO into structured reports (bert_pytorch_tpu/analysis/hlo.py),
 and diffs them against checked-in budgets with the rule framework
 (analysis/passes.py):
@@ -26,7 +26,7 @@ and diffs them against checked-in budgets with the rule framework
       # files, and say why in the commit message.
 
   python tools/graphcheck.py --validate-budgets
-      # jax-free (login host / CI front door, mirrors tools/perfboard.py):
+      # jax-free (login host / CI front door):
       # schema-check the budget file, and when results/graph_report.json
       # exists diff it against the budgets without recompiling anything.
 
@@ -804,7 +804,7 @@ def main(argv=None) -> int:
         if args.inject != "none" or args.combos:
             # a drill or subset report is partial/deliberately broken —
             # it must never overwrite the checked-in full-matrix artifact
-            # (perfboard indexes it; --validate-budgets diffs it)
+            # (--validate-budgets diffs it)
             import tempfile
 
             report_path = os.path.join(

@@ -8,7 +8,7 @@ closed loop self-throttles exactly when the server saturates and reports
 flattering percentiles. Jax-free (a load generator that imports the
 serving stack is measuring itself).
 
-Three modes:
+Four modes:
 
   python tools/loadtest.py --url http://127.0.0.1:8000 --label packed \
       --rates 20,50 --duration 3 --out /tmp/packed.json
@@ -17,13 +17,17 @@ Three modes:
       # occupancy over the window (delta of the server's cumulative
       # real/slot token counters, scraped from /metrics).
 
-  python tools/loadtest.py --assemble SERVE_r01.json packed.json padded.json
-      # merge mode files into the cross-mode SERVE artifact perfboard
-      # indexes and scripts/check_perf.sh gates.
+  python tools/loadtest.py --assemble serve.json packed.json padded.json
+      # merge mode files into the cross-mode SERVE artifact.
 
-  python tools/loadtest.py --validate SERVE_r01.json
+  python tools/loadtest.py --validate serve.json
       # jax-free schema check (scripts/check_serve.sh gates on it); exit
       # 2 on violations.
+
+  python tools/loadtest.py --check_distill distill.json 0.05
+      # accuracy floor over a distill artifact (--assemble --kind
+      # distill): exit 1 if any student leg lost more than 0.05 accuracy
+      # to its teacher (scripts/check_distill.sh gates on it).
 
 Exit codes (run mode): 0 with >=1 2xx response, 1 when every request
 failed (the server is down or shedding everything), 2 unusable input.
@@ -383,7 +387,7 @@ def saturation_from_rates(rates: Dict[str, Any],
         "p99_ms": best["p99_ms"] if best else None,
     }
     # cost at the saturation point — the "cost per 1k tokens at equal
-    # p99" number perfboard gates (lower-better)
+    # p99" number (lower-better)
     if best is not None:
         for k in ("cost_per_1k_tokens", "device_seconds"):
             if k in best:
@@ -488,7 +492,7 @@ def run_mode(url: str, label: str, rates: List[float], duration: float,
     return out
 
 
-# -- artifact assembly + validation (jax-free, perfboard-compatible) ----------
+# -- artifact assembly + validation (jax-free) --------------------------------
 
 
 def _sat_per_chip(mode: Dict[str, Any]) -> Optional[float]:
@@ -521,8 +525,7 @@ def assemble(mode_paths: List[str], kind: str = "serve",
                 modes[label][extra] = doc[extra]
         newest = max(newest, float(doc.get("time_unix") or 0))
     # replica scale-out ratio: each multi-replica mode vs the
-    # single-replica mode of the SAME dtype (the PR-17 acceptance
-    # number, gated by perfboard as scaleout higher-better)
+    # single-replica mode of the SAME dtype (higher is better)
     singles = {str(m.get("meta", {}).get("dtype", "")): m
                for m in modes.values()
                if m.get("meta", {}).get("replicas") == 1
@@ -610,6 +613,60 @@ def validate_serve(doc: Any) -> List[str]:
     return errors
 
 
+def validate_distill(doc: Any, floor: float
+                     ) -> Tuple[List[str], List[str]]:
+    """Accuracy-floor gate over ONE distill artifact: every student leg
+    (meta.model_tag set and != 'teacher') must carry an accuracy_delta
+    (teacher accuracy minus its own) no larger than floor.
+    Direction-aware: a student BEATING its teacher (delta <= 0) passes
+    by any margin; only quality lost to compression trips. A student
+    leg with no delta recorded fails loudly — an unmeasured student is
+    not a passing student. Returns (failures, notes)."""
+    if not isinstance(doc, dict) or doc.get("kind") != "distill":
+        kind = doc.get("kind") if isinstance(doc, dict) else None
+        return [f"GATE: artifact is kind {kind!r}, not a distill artifact "
+                "(tools/loadtest.py --assemble --kind distill)"], []
+    failures: List[str] = []
+    notes: List[str] = []
+    students = 0
+    for label, mode in sorted((doc.get("modes") or {}).items()):
+        if not isinstance(mode, dict):
+            continue
+        tag = str((mode.get("meta") or {}).get("model_tag") or "")
+        if not tag or tag == "teacher":
+            continue
+        students += 1
+        delta = mode.get("accuracy_delta")
+        if not isinstance(delta, (int, float)) or isinstance(delta, bool):
+            failures.append(
+                f"GATE: student leg '{label}' ({tag}) carries no "
+                "accuracy_delta — unmeasured students do not pass")
+        elif delta > floor:
+            failures.append(
+                f"GATE: student leg '{label}' ({tag}) lost {delta:g} "
+                f"accuracy vs its teacher (> floor {floor:g})")
+        else:
+            notes.append(
+                f"ok: '{label}' ({tag}) accuracy_delta {delta:g} "
+                f"<= {floor:g}"
+                + (" (beats teacher)" if delta < 0 else ""))
+    if students == 0:
+        failures.append(
+            "GATE: no student legs (modes with meta.model_tag != "
+            "'teacher') in artifact — nothing to gate")
+    return failures, notes
+
+
+def _load_artifact(path: str) -> Any:
+    """The JSON document at path, or None (with the reason printed)."""
+    try:
+        with open(path, encoding="utf-8") as f:
+            return json.load(f)
+    except (OSError, ValueError) as e:
+        print(f"loadtest: unreadable {path}: {e}")
+        return None
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description=__doc__,
@@ -632,8 +689,7 @@ def main(argv=None) -> int:
                     metavar="KEY=VALUE",
                     help="mode metadata recorded in the artifact "
                          "(replicas=2, dtype=f32, n_chips=2, ...); "
-                         "repeatable — perfboard renders replica/dtype "
-                         "columns from it")
+                         "repeatable")
     ap.add_argument("--duration", type=float, default=3.0,
                     help="seconds per rate sweep")
     ap.add_argument("--tasks", default="squad,ner",
@@ -658,8 +714,8 @@ def main(argv=None) -> int:
     ap.add_argument("--model_tag", default=None,
                     help="which model this leg serves (teacher, "
                          "student_6l_768, ...); recorded as "
-                         "meta.model_tag so perfboard can index "
-                         "teacher/student legs from one artifact")
+                         "meta.model_tag: --assemble --kind distill "
+                         "tells teacher and student legs apart by it")
     ap.add_argument("--out", default=None, help="mode JSON output path")
     ap.add_argument("--assemble", nargs="+", default=None,
                     metavar=("OUT", "MODE_JSON"),
@@ -683,14 +739,40 @@ def main(argv=None) -> int:
                          "server measures the outage, not the server")
     ap.add_argument("--validate", default=None, metavar="SERVE_JSON",
                     help="schema-check a SERVE artifact and exit")
+    ap.add_argument("--check_distill", nargs=2, default=None,
+                    metavar=("DISTILL_JSON", "FLOOR"),
+                    help="accuracy-floor gate over one distill artifact: "
+                         "exit 1 if any student leg lost more than FLOOR "
+                         "accuracy to the teacher (or carries no "
+                         "measured delta); students that beat the "
+                         "teacher always pass")
     args = ap.parse_args(argv)
 
-    if args.validate:
+    if args.check_distill:
+        path = args.check_distill[0]
         try:
-            with open(args.validate, encoding="utf-8") as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"loadtest: unreadable {args.validate}: {e}")
+            floor = float(args.check_distill[1])
+        except ValueError:
+            print(f"loadtest: --check_distill FLOOR must be a number, "
+                  f"got {args.check_distill[1]!r}")
+            return 2
+        doc = _load_artifact(path)
+        if doc is None:
+            return 2
+        failures, notes = validate_distill(doc, floor)
+        for line in notes + failures:
+            print(line)
+        if failures:
+            print(f"loadtest: distill accuracy gate FAILED "
+                  f"({len(failures)} problem(s), floor {floor:g}, {path})")
+            return 1
+        print(f"loadtest: distill accuracy gate ok (floor {floor:g}, "
+              f"{path})")
+        return 0
+
+    if args.validate:
+        doc = _load_artifact(args.validate)
+        if doc is None:
             return 2
         errors = validate_serve(doc)
         for e in errors:

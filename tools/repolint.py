@@ -48,7 +48,7 @@ from typing import List, Set, Tuple
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 DEFAULT_TARGETS = ("bert_pytorch_tpu", "tools", "scripts", "tests", "data",
-                   "bench.py", "run_pretraining.py", "run_squad.py",
+                   "run_pretraining.py", "run_squad.py",
                    "run_ner.py", "__graft_entry__.py")
 
 # names the interpreter/jax inject that a module-coarse pass cannot see

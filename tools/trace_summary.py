@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Collective/compute/host time attribution from a jax.profiler trace.
 
-The multichip bench measures WHAT a variant costs; this tool says WHERE the
-time goes. Point it at a profiler log dir (the `--profile_steps` output of
-run_pretraining, a `BENCH_PROFILE_DIR`, or the per-variant trace dirs
-bench.py --multichip writes) and it buckets every op event into
+A step time says WHAT a program costs; this tool says WHERE the time
+goes. Point it at a profiler log dir (the `--profile_steps` output of
+run_pretraining) and it buckets every op event into
 
   collective  — all-gather / all-reduce / reduce-scatter / collective-permute
                 / all-to-all (async -start/-done and fusions included),
@@ -15,15 +14,14 @@ bench.py --multichip writes) and it buckets every op event into
 with same-bucket overlaps interval-merged per thread so nothing is counted
 twice (telemetry/trace.py is the engine; stdlib-only, runs anywhere).
 
-  python tools/trace_summary.py --trace results/phase1/traces
+  python tools/trace_summary.py --trace <output_dir>/traces
   python tools/trace_summary.py --trace traces/ --steps 10 --devices 8
   python tools/trace_summary.py --trace traces/ --json out.json
 
 --steps / --devices add per-step / per-device normalizations (a
 single-process n-device mesh logs every device's ops into one trace, so raw
 bucket totals are device-seconds). Exit 0 with a table on stdout; --json
-additionally writes the machine-readable summary (the same dict bench.py
-embeds in MULTICHIP_r*.json per variant).
+additionally writes the machine-readable summary.
 
 --requests switches to SERVING request-trace mode: point --trace at a
 /v1/traces export (what `tools/loadtest.py --save_traces` writes) and the
