@@ -292,7 +292,9 @@ class BertLayer(nn.Module):
             # Tag the (B, S, F) wide activations so remat_policy="mlp_only"
             # can drop just these (4x hidden width — the bulk of per-layer
             # activation memory) and keep attention saved. No-op without
-            # nn.remat.
+            # nn.remat. The third value of that width a layer's backward
+            # pass reads, the erf-GELU's derivative, takes the same name
+            # where it is made (ops/activations.py).
             inter = checkpoint_name(inter, "mlp_wide")
             inter = act(inter)
             inter = checkpoint_name(inter, "mlp_wide")
@@ -338,10 +340,12 @@ class _EncoderBody(nn.Module):
 # two pay for their bytes on a v5e: a 0.27 ms and a 0.38 ms matmul spared
 # for 50 MB and 17 MB that cross HBM three times (stacked by the forward
 # scan, sliced out and read by the backward scan). The attention output
-# projection and anything (T, F)-wide (the intermediate projection, the
-# activation) cost more to keep than to redo, the latter because XLA then
-# fuses the erf-GELU into the input of its consumers (PERF.md, PR 25: the
-# subsets raced at BERT-Large b64 s128).
+# projection and anything (T, F)-wide (the intermediate projection, and the
+# erf-GELU's two residuals: its output, which the mlp_output matmul keeps
+# for its weight gradient, and its derivative, ops/activations.py) cost
+# more to keep than to redo, the latter because XLA then fuses the erf-GELU
+# into the input of its consumers (PERF.md, PR 25: the subsets raced at
+# BERT-Large b64 s128, where the GELU still kept three values of its own).
 DENSE_SAVED = ("qkv_out", "mlp_out")
 
 _REMAT_POLICIES = {

@@ -16,7 +16,8 @@ from bert_pytorch_tpu.models import (
     BertModel,
     losses,
 )
-from bert_pytorch_tpu.ops import gelu, layer_norm
+from bert_pytorch_tpu.models.bert import _REMAT_POLICIES
+from bert_pytorch_tpu.ops import bias_gelu, gelu, layer_norm
 
 TINY = BertConfig(
     vocab_size=128, hidden_size=32, num_hidden_layers=2,
@@ -53,6 +54,115 @@ def test_gelu_is_exact_erf():
     want = np.array([0.5 * v * (1 + math.erf(v / math.sqrt(2))) for v in x])
     np.testing.assert_allclose(np.asarray(gelu(jnp.array(x))), want,
                                rtol=1e-5, atol=1e-6)
+
+
+def _gelu_grid(dtype):
+    """[-8, 8] with 0, both tails and the derivative's root near -0.75."""
+    x = np.concatenate([np.linspace(-8, 8, 2049),
+                        [0.0, -0.75, -0.7518, 1e-3, -1e-3]])
+    return jnp.asarray(x, dtype)
+
+
+def _erf_gelu_grad(x):
+    """jax.grad of the textbook float32 formula at x (any dtype)."""
+    def ref(v):
+        return 0.5 * v * (1.0 + jax.lax.erf(v / np.sqrt(2.0)))
+    return np.asarray(jax.vmap(jax.grad(ref))(x.astype(jnp.float32)))
+
+
+def _grad_of(fn, x):
+    y, vjp = jax.vjp(fn, x)
+    return np.asarray(vjp(jnp.ones_like(y))[0], np.float32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gelu_forward_is_jax_nn_gelu_bit_for_bit(dtype):
+    """ops.activations writes out jax.nn.gelu(approximate=False) to share
+    its erfc with the derivative; a JAX release that changes that formula
+    has to show here. Both the primal and the forward rule."""
+    x = _gelu_grid(dtype)
+    want = np.asarray(jax.nn.gelu(x, approximate=False))
+    np.testing.assert_array_equal(np.asarray(gelu(x)), want)
+    np.testing.assert_array_equal(np.asarray(jax.vjp(gelu, x)[0]), want)
+    np.testing.assert_array_equal(
+        np.asarray(bias_gelu(jnp.zeros((), dtype), x)), want)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_gelu_forward_rule_evaluates_one_erfc_and_backward_none():
+    x = _gelu_grid(jnp.bfloat16)
+
+    def primitives(fn):
+        return [e.primitive.name for e in _eqns(jax.make_jaxpr(fn)(x).jaxpr)]
+
+    fwd = primitives(lambda v: jax.vjp(gelu, v)[0])
+    assert fwd.count("erfc") == 1 and fwd.count("erf") == 0
+    assert fwd.count("exp") == 1
+    # the whole of value-and-gradient: still one, and the residual is one
+    # array of x's shape and dtype
+    both = primitives(jax.value_and_grad(lambda u: gelu(u).sum()))
+    assert both.count("erfc") == 1 and both.count("exp") == 1
+    _, vjp = jax.vjp(gelu, x)
+    res = jax.tree_util.tree_leaves(vjp)
+    assert [(r.shape, r.dtype) for r in res] == [(x.shape, x.dtype)]
+
+
+_both_gelus = pytest.mark.parametrize("fn", [gelu, lambda v: bias_gelu(
+    jnp.zeros((), v.dtype), v)], ids=["gelu", "bias_gelu"])
+
+
+@_both_gelus
+def test_gelu_gradient_float32(fn):
+    x = _gelu_grid(jnp.float32)
+    np.testing.assert_allclose(_grad_of(fn, x), _erf_gelu_grad(x),
+                               rtol=0, atol=4 * np.finfo(np.float32).eps)
+
+
+@_both_gelus
+def test_gelu_gradient_bfloat16_is_closer_than_autodiff(fn):
+    """gelu'(x) = Phi(x) + x*phi(x) is built from the forward's bf16 erfc
+    (whose argument the forward formula also rounds to bf16) and rounded
+    once more, so the yardstick is the bf16 ulp of the largest of the
+    derivative and its two terms: they cancel at the root near x = -0.75
+    and in the negative tail. Over [-8, 8] the rule reads within 1.32 such
+    ulps of float32 (two allowed); autodiff of jax.nn.gelu, which rounds
+    after every operation, reads up to 34 (the negative tail)."""
+    x = _gelu_grid(jnp.bfloat16)
+    x64 = np.asarray(x, np.float64)
+    want = _erf_gelu_grad(x)
+    x_phi = x64 * np.exp(-0.5 * x64 * x64) / np.sqrt(2 * np.pi)
+    largest = np.max(np.abs([want, x_phi, want - x_phi]), axis=0)
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(largest, 1e-300))) - 7)
+    got = _grad_of(fn, x)
+    assert np.isfinite(got).all()
+    err = np.abs(got - want)
+    # + float32's eps: the reference's own 1 + erf cancels in that tail
+    bad = err > 2 * ulp + np.finfo(np.float32).eps
+    assert not bad.any(), (x64[bad], err[bad], ulp[bad])
+    parent = np.abs(_grad_of(
+        lambda v: jax.nn.gelu(v, approximate=False), x) - want)
+    assert err.max() <= parent.max() and err.mean() <= parent.mean()
+    assert _grad_of(fn, jnp.zeros((1,), jnp.bfloat16))[0] == 0.5
+
+
+def test_gelu_rule_under_vmap_and_remat():
+    x = _gelu_grid(jnp.float32)[:64].reshape(8, 8)
+    want = _grad_of(gelu, x)
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(jax.grad(lambda r: gelu(r).sum()))(x)), want)
+    np.testing.assert_array_equal(np.asarray(jax.grad(
+        lambda v: jax.checkpoint(gelu)(v).sum())(x)), want)
+    for policy in ("nothing", "mlp_only"):
+        np.testing.assert_array_equal(np.asarray(jax.grad(
+            lambda v: jax.checkpoint(
+                gelu, policy=_REMAT_POLICIES[policy])(v).sum())(x)), want)
 
 
 def test_bert_model_shapes():

@@ -9,13 +9,17 @@ import os
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bert_pytorch_tpu.models.bert import REMAT_AUTO_ORDER  # noqa: E402
+from bert_pytorch_tpu.config import BertConfig  # noqa: E402
+from bert_pytorch_tpu.models.bert import (  # noqa: E402
+    REMAT_AUTO_ORDER, BertEncoder)
 from bert_pytorch_tpu.training.pretrain import (  # noqa: E402
     StepProgram, resolve_remat_policy)
+from tests.test_model import _eqns  # noqa: E402
 from tests.test_step_scopes import _OP_NAME, _toy_step  # noqa: E402
 
 PROJECTIONS = ("attention/qkv", "attention/output", "mlp/intermediate",
@@ -65,6 +69,48 @@ def test_policy_is_not_read_without_the_flag():
             state, batch, jax.random.PRNGKey(0)).as_text())
     assert len(texts) == 1
     assert "rematted_computation" not in texts.pop()
+
+
+@pytest.mark.parametrize("policy, stacked", [
+    (None, 2), ("dense", 0), ("nothing", 0), ("mlp_only", 2)])
+def test_wide_values_the_forward_scan_stacks(policy, stacked):
+    """What the forward layer scan writes into (L, B, S, F) stacks for the
+    backward scan, counted in the jaxpr of value_and_grad. Without remat:
+    the activation's output (the mlp_output matmul's residual) and the
+    erf-GELU's derivative (ops/activations.py), where autodiff of
+    jax.nn.gelu stacked three of its own beside the output. "dense" and
+    "nothing" stack nothing that wide. "mlp_only" stacks the same two as no
+    remat (four before the GELU kept one): JAX's
+    save_anything_except_these_names refuses the NAMED copy of a value and
+    saves the un-named one that feeds the name, so it never dropped them."""
+    L, B, S, H, F = 3, 2, 16, 32, 80
+    cfg = BertConfig(
+        vocab_size=128, hidden_size=H, num_hidden_layers=L,
+        num_attention_heads=4, intermediate_size=F,
+        max_position_embeddings=64, fused_ops=False, attention_impl="xla",
+        checkpoint_activations=policy is not None,
+        **({} if policy is None else {"remat_policy": policy}))
+    encoder = BertEncoder(cfg, dtype=jnp.bfloat16)
+    hidden = jnp.ones((B, S, H), jnp.bfloat16)
+    bias = jnp.zeros((B, 1, 1, S), jnp.float32)
+    params = encoder.init(jax.random.PRNGKey(0), hidden, bias)
+
+    def loss(p, h):
+        return encoder.apply(p, h, bias).astype(jnp.float32).sum()
+
+    forward, backward = [
+        e for e in _eqns(jax.make_jaxpr(jax.value_and_grad(loss))(
+            params, hidden).jaxpr) if e.primitive.name == "scan"]
+    wide = [v.aval for v in forward.outvars if v.aval.shape == (L, B, S, F)]
+    assert len(wide) == stacked
+    assert all(a.dtype == jnp.bfloat16 for a in wide)
+    assert not [v for v in backward.outvars
+                if v.aval.shape == (L, B, S, F)]
+    # the derivative reads the erfc through an optimization barrier (so
+    # that XLA evaluates it once); a forward pass that keeps no derivative
+    # (the first one under remat) must not carry the barrier either
+    assert [e.primitive.name for e in _eqns(forward.params["jaxpr"].jaxpr)
+            ].count("optimization_barrier") == (1 if stacked else 0)
 
 
 class _FakeProgram:
