@@ -1,9 +1,12 @@
-"""Driver of a training cell (traffic files with `"driver": "train"`).
+"""Driver of a training cell (traffic files with `"driver": "train"`),
+whatever its model family.
 
 The parent: writes the seeded corpus, starts the one child that reaches the
 chip (harness/train_child.py), waits, decides `correct` from the numbers the
 child compared, and turns the child's record into the cell's metrics. It
-never imports JAX's backends.
+never imports JAX's backends. What names a model is the cell's family
+module (benchmark/families/<family>.py): its FLOPs for the MFU line and the
+readers, and the checks it adds to `decide_correct`.
 """
 
 from __future__ import annotations
@@ -19,7 +22,6 @@ import sys
 import tempfile
 
 from benchmark.harness import corpus as corpus_lib
-from benchmark.harness import flops as flops_lib
 from benchmark.harness import spec as spec_lib
 
 CHILD = os.path.join(spec_lib.BENCH_DIR, "harness", "train_child.py")
@@ -43,7 +45,7 @@ def _child_env(chips: int, rehearse: bool) -> dict:
 
 def _effective(cell: dict, rehearse: bool, work: str) -> dict:
     """The cell as run: under --rehearse the traffic file's `rehearse` block
-    overrides sizes, and a toy model configuration is written."""
+    overrides sizes and limits, and a toy model configuration is written."""
     cell = dict(cell)
     if not rehearse:
         return cell
@@ -51,6 +53,7 @@ def _effective(cell: dict, rehearse: bool, work: str) -> dict:
     traffic = dict(cell["traffic"])
     traffic.update({k: v for k, v in r.items()
                     if k not in ("config", "extra_args", "limits")})
+    traffic["limits"] = dict(traffic["limits"], **r.get("limits", {}))
     config = dict(cell["config"], **r.get("config", {}))
     path = os.path.join(work, "rehearse_config.json")
     with open(path, "w", encoding="utf-8") as f:
@@ -64,70 +67,92 @@ def _within(value: float, limit) -> bool:
     return limit is not None and value <= limit
 
 
-def _check(ok_list: list, name: str, value, limit, ok: bool) -> None:
-    say(f"correct? {name}: {value} (limit {limit}) -> "
-        f"{'ok' if ok else 'NOT OK'}")
-    ok_list.append(bool(ok))
+class Checks:
+    """The numbers compared: each is printed beside its limit as it is
+    checked (`what` says it in full), and kept under a short name for the
+    result's line."""
+
+    def __init__(self, tag: str = "correct?"):
+        self.tag, self.rows = tag, []
+
+    def __call__(self, name: str, what: str, value, limit, ok: bool) -> None:
+        shown = f"{value:.3e}" if isinstance(value, float) else value
+        say(f"{self.tag} {what}: {shown} (limit {limit}) -> "
+            f"{'ok' if ok else 'NOT OK'}")
+        if isinstance(value, float) and not math.isfinite(value):
+            value = str(value)      # the result's line stays strict JSON
+        self.rows.append({"name": name, "value": value, "limit": limit,
+                          "ok": bool(ok)})
+
+    def all_ok(self) -> bool:
+        return all(row["ok"] for row in self.rows)
+
+    def finish(self, line: dict):
+        """Each number compared beside its limit, as the run's last lines
+        on standard error and as the last key of its result's line."""
+        for row in self.rows:
+            print(f"[bench] compared {row['name']}: {row['value']} (limit "
+                  f"{row['limit']}){'' if row['ok'] else ' NOT OK'}",
+                  file=sys.stderr, flush=True)
+        line["compared"] = {row["name"]: {k: row[k] for k in (
+            "value", "limit", "ok")} for row in self.rows}
+        return 0, line
 
 
-def decide_correct(cell: dict, record: dict, rehearse: bool) -> bool:
+def decide_correct(cell: dict, record: dict, rehearse: bool, family,
+                   check: Checks) -> bool:
     """Every number compared, printed beside its limit. Limits live in the
     cell's traffic file (`limits`), each with the readings it was set from
-    in PERF.md."""
+    in PERF.md. `record["compare"]` holds the program's numbers, or the
+    control's in the program's place."""
     t, lim = cell["traffic"], cell["traffic"]["limits"]
-    if rehearse:
-        lim = dict(lim, **t.get("rehearse", {}).get("limits", {}))
     w, c = record["window"], record["compare"]
-    oks: list = []
     for i, rel in enumerate(c["loss_rel"]):
-        _check(oks, f"step {i + 1} loss vs reference, relative "
-               f"({c['program_losses'][i]:.6f} vs "
-               f"{c['reference_losses'][i]:.6f})", f"{rel:.3e}",
-               lim["loss_rel"], _within(rel, lim["loss_rel"]))
+        check(f"loss_rel_step{i + 1}",
+              f"step {i + 1} loss vs reference, relative "
+              f"({c['program_losses'][i]:.6f} vs "
+              f"{c['reference_losses'][i]:.6f})", rel,
+              lim["loss_rel"], _within(rel, lim["loss_rel"]))
     for key, what in (("grad", "first gradient, worst leaf's norm gap"),
                       ("delta", "parameters' change after the followed "
                                 "steps, worst leaf's norm gap")):
         g = c[key]
-        _check(oks, f"{what} (at {g['leaf']})", f"{g['gap']:.3e}",
-               lim[f"{key}_gap"], _within(g["gap"], lim[f"{key}_gap"]))
-    _check(oks, "first gradient, mean relative norm of the difference over "
-           "sampled encoder matrices", f"{c['grad_diff']:.3e}",
-           lim["grad_diff"], _within(c["grad_diff"], lim["grad_diff"]))
+        check(f"{key}_gap", f"{what} (at {g['leaf']})", g["gap"],
+              lim[f"{key}_gap"], _within(g["gap"], lim[f"{key}_gap"]))
+    check("grad_diff", "first gradient, mean relative norm of the "
+          "difference over sampled matrices", c["grad_diff"],
+          lim["grad_diff"], _within(c["grad_diff"], lim["grad_diff"]))
     lo, hi = lim["loss_band"]
     losses = w["losses"]
     bad = [x for x in losses if not (math.isfinite(x) and lo <= x <= hi)]
-    _check(oks, f"window losses in band (min {min(losses):.4f} max "
-           f"{max(losses):.4f}, {len(bad)} outside)", len(bad),
-           f"[{lo}, {hi}]", not bad)
+    check("losses_outside_band", f"window losses in band (min "
+          f"{min(losses):.4f} max {max(losses):.4f}, {len(bad)} outside)",
+          len(bad), f"[{lo}, {hi}]", not bad)
     first, last = w["perf_open"], w["perf"][-1]
     compiled = last["compiles"] - first["compiles"]
-    _check(oks, "compiles inside the window", compiled, 0, compiled == 0)
+    check("compiles_in_window", "compiles inside the window", compiled, 0,
+          compiled == 0)
     if not rehearse:
         counts = w["kernel_counts"]
         for name in t.get("expect_kernels", []):
-            _check(oks, f"kernel {name} in the compiled step",
-                   counts.get(name, 0), ">= 1", counts.get(name, 0) >= 1)
+            check(f"kernel_{name}", f"kernel {name} in the compiled step",
+                  counts.get(name, 0), ">= 1", counts.get(name, 0) >= 1)
     if cell["chips"] > 1:
         counts = w["kernel_counts"]
         if not rehearse:
             gathers = counts.get("all-gather", 0)
             reduces = (counts.get("all-reduce", 0)
                        + counts.get("reduce-scatter", 0))
-            _check(oks, "collectives in the compiled step (all-gather, "
-                   "all-reduce + reduce-scatter)", (gathers, reduces),
-                   ">= 1 each", gathers >= 1 and reduces >= 1)
+            check("collectives", "collectives in the compiled step "
+                  "(all-gather, all-reduce + reduce-scatter)",
+                  (gathers, reduces), ">= 1 each",
+                  gathers >= 1 and reduces >= 1)
         share = w["opt_share"]
-        _check(oks, "optimizer state on one chip / whole state",
-               f"{share:.4f}", lim["opt_share_max"],
-               share <= lim["opt_share_max"])
-    if "control" in c:
-        k = c["control"]
-        say(f"control ({k['precision']}): loss_rel "
-            f"{[f'{x:.3e}' for x in k['loss_rel']]} gradient's difference "
-            f"over sampled matrices {k['grad_diff']:.3e} worst-leaf gap "
-            f"{k['grad']['gap']:.3e} at {k['grad']['leaf']} delta gap "
-            f"{k['delta']['gap']:.3e} at {k['delta']['leaf']}")
-    return all(oks)
+        check("opt_share", "optimizer state on one chip / whole state",
+              share, lim["opt_share_max"],
+              share <= lim["opt_share_max"])
+    family.decide(cell, record, check)
+    return check.all_ok()
 
 
 def program_peak_bytes(mem: dict, rehearse: bool):
@@ -162,6 +187,7 @@ def run(cell: dict, args, manifest: dict):
     work = tempfile.mkdtemp(prefix="bench_train_")
     proc = None
     try:
+        family = spec_lib.load_family(cell["family"])
         cell = _effective(cell, rehearse, work)
         t = cell["traffic"]
         vocab = int(cell["config"]["vocab_size"])
@@ -175,7 +201,7 @@ def run(cell: dict, args, manifest: dict):
             "seconds": float(args.seconds), "trace": int(args.trace),
             "rehearse": rehearse, "control": args.control,
             "fault": args.fault, "chips": cell["chips"],
-            "keep_norms": bool(args.keep),
+            "keep_norms": bool(args.keep), "family": cell["family"],
             "config": cell["config"], "config_path": cell["config_path"],
             "traffic": t, "data_dir": data_dir,
             "out_dir": os.path.join(work, "out"),
@@ -225,36 +251,45 @@ def run(cell: dict, args, manifest: dict):
             f"{c['reference_seconds']:.1f}s after the window; memory "
             f"{w['memory']}; kernels/collectives in the step "
             f"{w['kernel_counts']}; compile record at the window's end "
-            f"{ {k: w['perf'][-1].get(k) for k in ('compiles', 'compile_secs', 'compile_cache_hits')} }")
+            f"{ {k: w['perf'][-1].get(k) for k in ('compiles', 'compile_secs', 'compile_cache_hits', 'remat_saves_dense')} }")
+        say("first gradient, norm of the difference over the reference's, "
+            f"by sampled matrix: {c['grad_diff_by_matrix']}")
         peak_mem = program_peak_bytes(w["memory"], rehearse)
         if peak_mem is None:
             return 1, None
         dev_out = {"platform": device["platform"], "kind": device["kind"],
                    "count": device["count"], "memory_peak_bytes": peak_mem}
         if not rehearse:
-            peak = flops_lib.peaks(device["kind"])
-            sizes_rows = (vocab + 127) // 128 * 128
-            rows = t["local_batch"] * t["accum"] * t.get("data_shards", 1)
-            flops_step = rows * flops_lib.train_flops_per_row(
-                cell["config"], int(t["seq_len"]), sizes_rows,
-                int(t["max_predictions"]))
-            mfu = (flops_step * w["steps"] / w["seconds"]
-                   / (chips * peak["flops_per_s_bf16"]))
-            say(f"MFU {mfu:.4f} (analytic fwd+bwd FLOPs of the slots, no "
+            peak = family.flops.peaks(device["kind"])
+            flops, what = family.window_flops(cell, w)
+            mfu = flops / w["seconds"] / (chips * peak["flops_per_s_bf16"])
+            say(f"MFU {mfu:.4f} (analytic fwd+bwd FLOPs {what}, no "
                 f"recompute, over {chips} x {peak['flops_per_s_bf16']:.3g})")
-        correct = decide_correct(cell, record, rehearse)
+        checks = Checks()
+        correct = decide_correct(cell, record, rehearse, family, checks)
+        if "control" in c:
+            # the control's numbers through the same decision, in the
+            # program's place: it has to come out as not correct
+            say(f"control ({c['control']['precision']}), held to the "
+                "program's limits:")
+            control = decide_correct(
+                cell, {"compare": c["control"], "window": w}, rehearse,
+                family, Checks("control?"))
+            say(f"control comes out as correct: {str(control).lower()}"
+                + ("" if not control else
+                   " (no limit tells it from a sound run)"))
         failed = sum(1 for x in w["losses"] if not math.isfinite(x))
         line = {"correct": correct, "attempted": w["steps"],
                 "failed": failed, "metrics": {}, "device": dev_out}
         if rehearse:
-            return 0, line
+            return checks.finish(line)
         if not args.trace:
             line["metrics"] = {
                 "train_tokens_per_s_chip": {"value": tokens_per_s_chip,
                                             "unit": "tokens/s"},
                 "setup_s": {"value": w["setup_s"], "unit": "s"},
             }
-            return 0, line
+            return checks.finish(line)
         from benchmark.harness import trace_reduce
 
         traces = glob.glob(os.path.join(spec["out_dir"], "traces", "**",
@@ -271,8 +306,8 @@ def run(cell: dict, args, manifest: dict):
                 json.dump(trace_reduce.cut(events), f)
         reduced = trace_reduce.reduce(events)
         ctx = {"cell": cell, "record": record, "trace": reduced,
-               "peaks": flops_lib.peaks(device["kind"]), "flops": flops_lib,
-               "chips": chips}
+               "peaks": family.flops.peaks(device["kind"]),
+               "flops": family.flops, "chips": chips}
         line["metrics"] = spec_lib.read_layer_metrics(
             manifest, cell["name"], ctx)
         dev_out["busy_s"] = reduced["busy_s"]
@@ -280,7 +315,7 @@ def run(cell: dict, args, manifest: dict):
         line["breakdown"] = reduced["breakdown"]
         say(f"trace: {reduced['steps']} whole steps, window "
             f"{reduced['window_s']:.4f}s, busy {reduced['busy_s']:.4f}s")
-        return 0, line
+        return checks.finish(line)
     finally:
         if proc is not None and proc.poll() is None:
             proc.kill()

@@ -1,9 +1,16 @@
-"""Between the reference's weight layout and the program's parameter tree,
+"""Between a reference's weight layout and the program's parameter tree,
 and the per-leaf norms both sides are compared by.
 
-The mapping is reshapes only (so it carries gradients and parameter changes
-as well as weights, and keeps every norm). It is the one place that knows the
-program's tree; a program leaf it does not know is an error.
+What every family's adapter shares is written here once: placing a tree as
+the program's is placed (`place_like`, `place_for_reference`), the per-leaf
+norms (`norm_functions`), the worst leaf's gap of norms (`worst_gap`), the
+norm of the difference over sampled matrices (`diff_by_matrix`, `diff_gap`)
+and a step's key as words (`key_data`). The rest is BERT's own
+(families/bert.py binds it): the mapping from reference/bert_ref.py's layout
+to the program's stacked-encoder tree, reshapes only (so it carries
+gradients and parameter changes as well as weights, and keeps every norm),
+and the encoder matrices compared whole. A program leaf the mapping does
+not know is an error.
 """
 
 from __future__ import annotations
@@ -81,41 +88,48 @@ def place_like(ours: dict, theirs):
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def _norms(tree):
-    def norm(path, x):
-        stacked = any(str(getattr(k, "key", k)) == "layers" for k in path)
-        x = x.astype(jnp.float32)
-        axes = tuple(range(1 if stacked else 0, x.ndim))
-        return jnp.sqrt(jnp.sum(jnp.square(x), axis=axes)).reshape(-1)
+def place_for_reference(tree, rows_axis_sharded: bool):
+    """On several chips the reference runs data-parallel over all of them:
+    weights replicated, a micro-batch's rows split. One chip: as it is."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    return jax.tree_util.tree_map_with_path(norm, tree)
-
-
-_leaf_norms = jax.jit(_norms)
-
-
-def _by_path(norms) -> dict:
-    return {jax.tree_util.keystr(k): np.asarray(v, np.float64)
-            for k, v in jax.tree_util.tree_flatten_with_path(
-                jax.device_get(norms))[0]}
+    devices = jax.local_devices()
+    if len(devices) == 1:
+        return jax.device_put(tree, devices[0])
+    mesh = Mesh(np.array(devices), ("d",))
+    spec = P("d") if rows_axis_sharded else P()
+    return jax.device_put(tree, NamedSharding(mesh, spec))
 
 
-def leaf_norms(tree) -> dict:
-    """{path: norms}: one norm per leaf, one per layer for a leaf stacked
-    over layers (what LAMB treats as one tensor)."""
-    return _by_path(_leaf_norms(tree))
+def norm_functions(stacked):
+    """(leaf_norms, leaf_diff_norms) of a family's trees. `stacked(path)`
+    says whether the leaf at `path` is a stack along its first axis of what
+    LAMB treats as one tensor each (BERT: a leaf stacked over layers; a
+    routed layer's stack of experts): such a leaf has one norm per entry.
+    leaf_norms(tree) -> {path: norms}; leaf_diff_norms(a, b): of a - b."""
+    def norms(tree):
+        def norm(path, x):
+            x = x.astype(jnp.float32)
+            axes = tuple(range(1 if stacked(path) else 0, x.ndim))
+            return jnp.sqrt(jnp.sum(jnp.square(x), axis=axes)).reshape(-1)
+
+        return jax.tree_util.tree_map_with_path(norm, tree)
+
+    def by_path(tree) -> dict:
+        return {jax.tree_util.keystr(k): np.asarray(v, np.float64)
+                for k, v in jax.tree_util.tree_flatten_with_path(
+                    jax.device_get(tree))[0]}
+
+    of_tree = jax.jit(norms)
+    of_diff = jax.jit(lambda a, b: norms(jax.tree.map(
+        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b)))
+    return (lambda tree: by_path(of_tree(tree)),
+            lambda a, b: by_path(of_diff(a, b)))
 
 
-@jax.jit
-def _leaf_diff_norms(a, b):
-    return _norms(
-        jax.tree.map(lambda x, y: x.astype(jnp.float32)
-                     - y.astype(jnp.float32), a, b))
-
-
-def leaf_diff_norms(a, b) -> dict:
-    """leaf_norms of a - b."""
-    return _by_path(_leaf_diff_norms(a, b))
+# BERT: one norm per layer for a leaf stacked over layers
+leaf_norms, leaf_diff_norms = norm_functions(lambda path: any(
+    str(getattr(k, "key", k)) == "layers" for k in path))
 
 
 SAMPLED = ("['attention']['qkv']['kernel']", "['attention']['output']['kernel']",
@@ -139,16 +153,20 @@ def sample_matrices(tree) -> dict:
     return out
 
 
-def diff_gap(got: dict, want: dict) -> float:
-    """The MEAN over the sampled matrices of |got - want| / |want|: the norm
-    of the DIFFERENCE, which rounding moves in first order (a gap of norms
+def diff_by_matrix(got: dict, want: dict) -> dict:
+    """{name: |got - want| / |want|} of the sampled matrices: the norm of
+    the DIFFERENCE, which rounding moves in first order (a gap of norms
     moves in second order only, PERF.md section 2)."""
     if sorted(got) != sorted(want):
         raise KeyError(f"sampled matrices differ: "
                        f"{sorted(set(got) ^ set(want))}")
-    rel = [np.linalg.norm((got[k] - want[k]).ravel())
-           / max(np.linalg.norm(want[k].ravel()), 1e-30) for k in want]
-    return float(np.mean(rel))
+    return {k: np.linalg.norm((got[k] - want[k]).ravel())
+            / max(np.linalg.norm(want[k].ravel()), 1e-30) for k in want}
+
+
+def diff_gap(got: dict, want: dict) -> float:
+    """The MEAN of diff_by_matrix over the sampled matrices."""
+    return float(np.mean(list(diff_by_matrix(got, want).values())))
 
 
 def key_data(rng) -> np.ndarray:

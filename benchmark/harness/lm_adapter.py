@@ -1,17 +1,17 @@
 """Between the lfm2_moe reference's weight layout (reference/
 lfm2_moe_ref.py) and the program's parameter tree (models/lfm2_moe.py), and
-the per-leaf norms both sides are compared by. Renames only: the two sides
-store every tensor in the same shape. Placement, the worst-leaf gap and the
-norm of a difference are harness/adapter.py's.
+the matrices compared whole. Renames only: the two sides store every tensor
+in the same shape. Placement, the per-leaf norms, the worst-leaf gap and the
+norm of a difference are harness/adapter.py's (families/lfm2_moe.py binds
+both).
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from benchmark.harness.adapter import _by_path
+from benchmark.harness.adapter import norm_functions
 
 _CONV = {"w_in": ("in_proj", "kernel"), "conv_w": ("conv_weight",),
          "w_out": ("out_proj", "kernel")}
@@ -47,34 +47,9 @@ def to_program_tree(ref: dict) -> dict:
     return tree
 
 
-def _norms(tree):
-    def norm(path, x):
-        # a stack of experts: one norm per expert (LAMB's tensors)
-        stacked = str(getattr(path[-1], "key", path[-1])).startswith(
-            "experts_")
-        x = x.astype(jnp.float32)
-        axes = tuple(range(1 if stacked else 0, x.ndim))
-        return jnp.sqrt(jnp.sum(jnp.square(x), axis=axes)).reshape(-1)
-
-    return jax.tree_util.tree_map_with_path(norm, tree)
-
-
-_leaf_norms = jax.jit(_norms)
-
-
-def leaf_norms(tree) -> dict:
-    """{path: norms}: one per leaf, one per expert for a stack of experts."""
-    return _by_path(_leaf_norms(tree))
-
-
-@jax.jit
-def _leaf_diff_norms(a, b):
-    return _norms(jax.tree.map(
-        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b))
-
-
-def leaf_diff_norms(a, b) -> dict:
-    return _by_path(_leaf_diff_norms(a, b))
+# a stack of experts: one norm per expert (LAMB's tensors)
+leaf_norms, leaf_diff_norms = norm_functions(lambda path: str(getattr(
+    path[-1], "key", path[-1])).startswith("experts_"))
 
 
 def sample_matrices(tree, kinds) -> dict:

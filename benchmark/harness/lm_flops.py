@@ -1,5 +1,5 @@
-"""Operations the `lfm2_moe` family's algorithms need, for the cells of
-drivers/train_lm.py: the arithmetic of the MFU line and of the rooflines of
+"""Operations the `lfm2_moe` family's algorithms need (families/
+lfm2_moe.py binds it): the arithmetic of the MFU line and of the rooflines of
 the kernels the family brought (the grouped expert products, causal
 grouped-head flash attention). The chip's peaks and the roofline's form are
 harness/flops.py's. Recomputed operations (activation checkpointing, flash

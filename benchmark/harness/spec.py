@@ -1,11 +1,12 @@
 """Finds a cell's files by the names in BENCHMARK.json.
 
 A cell (`workloads` entry) names a configuration and a traffic mix; the
-configuration's entry names its file, the traffic mix is
-`benchmark/traffic/<traffic>.json`, each per-layer metric is
-`benchmark/layer_metrics/<name>.json` and its reader is the function `read`
-of `benchmark/readers/<reader>.py`. Adding any of them is adding a file and
-an entry; nothing here knows a name.
+configuration's entry names its file, whose `model_type` names the model
+family (`benchmark/families/<model_type>.py`; a file without the key is
+`bert`), the traffic mix is `benchmark/traffic/<traffic>.json`, each
+per-layer metric is `benchmark/layer_metrics/<name>.json` and its reader is
+the function `read` of `benchmark/readers/<reader>.py`. Adding any of them
+is adding a file and an entry; nothing here knows a name.
 """
 
 from __future__ import annotations
@@ -49,11 +50,12 @@ def find_cell(manifest: dict, name: str, root: str = ROOT) -> dict:
     for path in (os.path.join(root, config_entry["file"]), traffic_path):
         if not os.path.isfile(path):
             raise SpecError(f"workload {name!r}: missing file {path}")
+    config = load_json(os.path.join(root, config_entry["file"]))
     return {
         "name": name, "chips": int(cell["chips"]), "entry": cell,
         "config_entry": config_entry,
         "config_path": os.path.join(root, config_entry["file"]),
-        "config": load_json(os.path.join(root, config_entry["file"])),
+        "config": config, "family": config.get("model_type", "bert"),
         "traffic_path": traffic_path, "traffic": load_json(traffic_path),
     }
 
@@ -99,6 +101,32 @@ def load_driver(driver: str, root: str = ROOT):
     if not callable(getattr(module, "run", None)):
         raise SpecError(f"driver {driver!r} defines no run(cell, args)")
     return module.run
+
+
+# What a family module defines. The driver's side, called without JAX:
+# `flops` (the module the readers get as ctx["flops"]: `peaks`,
+# `roofline_seconds` and the arithmetic of the family's rooflines),
+# `window_flops(cell, window)` for the MFU line, `decide(cell, record,
+# check)` for checks on top of the driver's. The child's side: `sizes`,
+# `program_args`, `weights` (from the seed, in the program's layout),
+# `adapter_functions` (leaf norms, norms of a difference, the matrices
+# compared whole), `follow` (the reference over the followed steps),
+# `followed_by_program`, `window_extras`, `compare_extras` (what the family
+# adds to the child's record). families/bert.py documents each.
+FAMILY_NAMES = ("flops", "window_flops", "decide", "sizes", "program_args",
+                "weights", "adapter_functions", "follow",
+                "followed_by_program", "window_extras", "compare_extras")
+
+
+def load_family(family: str, root: str = ROOT):
+    """benchmark/families/<family>.py: everything the benchmark knows about
+    one model family (a cell's is `find_cell(...)["family"]`)."""
+    module = _load_module(
+        os.path.join(root, "benchmark", "families", family + ".py"), "family")
+    missing = [n for n in FAMILY_NAMES if not hasattr(module, n)]
+    if missing:
+        raise SpecError(f"family {family!r} does not define {missing}")
+    return module
 
 
 def read_layer_metrics(manifest: dict, cell_name: str, ctx: dict,

@@ -1,27 +1,30 @@
-"""The one process of a training run that reaches the chip.
+"""The one process of a training run that reaches the chip, whatever the
+model family.
 
 A thin wrapper of the benchmark's around the user's entry point: it calls
 `run_pretraining.main(argv)` in this process, so the program's own host
 loop, data plane and compiled step are what is timed. Around that call it
 
 - hands the program the benchmark's weights (made from --seed by the
-  reference's initialiser, `bert_ref.init_params`) where the program would
-  have drawn its own, so that the reference never takes anything the program
-  made;
+  family's reference) where the program would have drawn its own, so that
+  the reference never takes anything the program made;
 - watches the ONE compiled step object the program builds: its first FOLLOW
   calls (set-up) are observed (inputs copied to the host, the optimizer's
   first moment after one step, the parameters' change after FOLLOW), then
-  WARM more steps run, then the SAME object is timed for --seconds;
+  WARM more steps run, then the SAME object is timed for --seconds (and for
+  at least the traffic file's `min_window_steps` whole steps);
 - takes the window's clock itself: the window opens and closes at the
   program's host read of a step's loss (`TelemetryRun.log_train` is called
   right after it), on this process's clock;
-- after the window has closed and the program's state is freed, runs the
-  reference over the first FOLLOW steps' inputs and writes every number
-  compared, beside what the parent needs for the metrics, to --out.
+- after the window has closed and the program's state is freed, has the
+  family's reference follow the first FOLLOW steps' inputs and writes every
+  number compared, beside what the parent needs for the metrics, to --out.
 
 Nothing of the program is edited; the three hooks replace names the entry
 point looks up when it runs (`make_sharded_state`, `StepProgram`,
-`TelemetryRun.log_train` / `log_perf`).
+`TelemetryRun.log_train` / `log_perf`). What names a model (the reference,
+the adapter, the batch's fields, the family's counters) is the family
+module's, benchmark/families/<family>.py, found by the name in the spec.
 """
 
 from __future__ import annotations
@@ -56,6 +59,7 @@ class Obs:
         self.delta_norms = None     # per-leaf norms of p_FOLLOW - p_0
         self.opt_share = None       # optimizer bytes on device 0 / total
         self.loss_reads = []        # (clock, step, loss)
+        self.scalars = {}           # step -> every value logged with its loss
         self.perf = []              # the program's [perf] records
         self.step_program = None
         self.t_open = self.t_close = None
@@ -63,15 +67,12 @@ class Obs:
         self.window_error = None
 
 
-def _program_argv(spec: dict, out_dir: str) -> list:
+def _program_argv(spec: dict, family, out_dir: str) -> list:
     t = spec["traffic"]
     mesh_shards = int(t.get("data_shards", 1))
     argv = [
         "--model_config_file", spec["config_path"],
         "--input_dir", spec["data_dir"], "--output_dir", out_dir,
-        "--max_predictions_per_seq", str(t["max_predictions"]),
-        "--masked_token_fraction", str(t["masked_lm_prob"]),
-        "--mask_token_index", "103",
         "--learning_rate", str(t["learning_rate"]),
         "--warmup_proportion", str(t["warmup_proportion"]),
         "--max_steps", str(t["max_steps"]), "--steps", "1000000",
@@ -80,7 +81,7 @@ def _program_argv(spec: dict, out_dir: str) -> list:
         "--local_batch_size", str(t["local_batch"]),
         "--skip_checkpoint", "--log_freq", "1", "--tensorboard", "off",
         "--seed", str(spec["seed"] % 2147483647), "--log_prefix", "bench",
-    ] + list(t.get("extra_args", []))
+    ] + family.program_args(t) + list(t.get("extra_args", []))
     if spec["rehearse"]:
         argv += list(t.get("rehearse", {}).get("extra_args", []))
     if spec["trace"]:
@@ -89,7 +90,7 @@ def _program_argv(spec: dict, out_dir: str) -> list:
     return argv
 
 
-def _install_hooks(spec: dict, obs: Obs, sizes: dict):
+def _install_hooks(spec: dict, obs: Obs, family, sizes: dict):
     import jax
 
     import bert_pytorch_tpu.training as training
@@ -97,20 +98,20 @@ def _install_hooks(spec: dict, obs: Obs, sizes: dict):
     from bert_pytorch_tpu.telemetry.run import TelemetryRun
 
     from benchmark.harness import adapter
-    from benchmark.reference import bert_ref
 
-    heads = sizes["heads"]
-
-    def our_weights():
-        return adapter.to_program_tree(
-            bert_ref.init_params(spec["seed"], sizes), heads)
+    leaf_norms, leaf_diff_norms, sample_matrices = family.adapter_functions(
+        sizes)
+    # the window holds --seconds AND at least this many whole steps (the
+    # traffic file says why, with its readings)
+    min_steps = 1 if spec["rehearse"] else int(
+        spec["traffic"].get("min_window_steps", 1))
 
     orig_make = training.make_sharded_state
 
     def make_sharded_state(*args, **kwargs):
         state, shardings = orig_make(*args, **kwargs)
-        state = state.replace(
-            params=adapter.place_like(our_weights(), state.params))
+        state = state.replace(params=adapter.place_like(
+            family.weights(spec, sizes), state.params))
         return state, shardings
 
     training.make_sharded_state = make_sharded_state
@@ -130,19 +131,19 @@ def _install_hooks(spec: dict, obs: Obs, sizes: dict):
                 mu = state.opt_state.mu
                 obs.grad_norms = {
                     k: v / (1.0 - LAMB_B1)
-                    for k, v in adapter.leaf_norms(mu).items()}
+                    for k, v in leaf_norms(mu).items()}
                 obs.grad_sample = {
                     k: v / (1.0 - LAMB_B1)
-                    for k, v in adapter.sample_matrices(mu).items()}
+                    for k, v in sample_matrices(mu).items()}
                 total = local = 0
                 for leaf in jax.tree.leaves(mu):
                     total += leaf.nbytes
                     local += leaf.addressable_shards[0].data.nbytes
                 obs.opt_share = local / max(total, 1)
             if n == FOLLOW:
-                obs.delta_norms = adapter.leaf_diff_norms(
-                    state.params,
-                    adapter.place_like(our_weights(), state.params))
+                obs.delta_norms = leaf_diff_norms(
+                    state.params, adapter.place_like(
+                        family.weights(spec, sizes), state.params))
             if n > FOLLOW:
                 # read after the window: real tokens of each timed step
                 obs.masks[n] = batch["attention_mask"]
@@ -173,11 +174,13 @@ def _install_hooks(spec: dict, obs: Obs, sizes: dict):
         if tag == "train" and "step_loss" in vals:
             step = int(step)
             obs.loss_reads.append((now, step, float(vals["step_loss"])))
+            obs.scalars[step] = vals
             if step == FOLLOW + WARM:
                 obs.t_open, obs.step_open = now, step
                 obs.wall_open = time.time()
             elif (obs.t_open is not None
-                  and now - obs.t_open >= spec["seconds"]):
+                  and now - obs.t_open >= spec["seconds"]
+                  and step - obs.step_open >= min_steps):
                 obs.t_close, obs.step_close = now, step
                 orig_log_train(self, step, tag, **vals)
                 raise WindowClosed()
@@ -191,75 +194,6 @@ def _install_hooks(spec: dict, obs: Obs, sizes: dict):
 
     TelemetryRun.log_train = log_train
     TelemetryRun.log_perf = log_perf
-
-
-def _place_for_reference(tree, rows_axis_sharded: bool):
-    """On several chips the reference runs data-parallel over all of them:
-    weights replicated, a micro-batch's rows split. One chip: as it is."""
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    devices = jax.local_devices()
-    if len(devices) == 1:
-        return jax.device_put(tree, devices[0])
-    mesh = Mesh(np.array(devices), ("d",))
-    spec = P("d") if rows_axis_sharded else P()
-    return jax.device_put(tree, NamedSharding(mesh, spec))
-
-
-def _follow_with_reference(spec: dict, obs: Obs, sizes: dict,
-                           quant=None) -> dict:
-    """The reference's losses, first clipped gradient and parameter change
-    over the observed steps' own inputs."""
-    import jax
-
-    from benchmark.harness import adapter
-    from benchmark.reference import bert_ref
-
-    t = spec["traffic"]
-    heads = sizes["heads"]
-    max_pred = int(obs.max_pred_row)
-    params = _place_for_reference(
-        bert_ref.init_params(spec["seed"], sizes), False)
-    opt = bert_ref.lamb_init(params)
-    losses, grad_norms, grad_sample = [], None, None
-    cfg = spec["config"]
-    rates = (float(cfg.get("hidden_dropout_prob", 0.0)),
-             float(cfg.get("attention_probs_dropout_prob", 0.0)))
-    # the program drops attention probabilities inside its flash kernel
-    # beyond 256 positions (ops/attention.py, impl "auto")
-    flash = int(t["seq_len"]) > 256
-    for batch, key in zip(obs.batches, obs.keys):
-        accum = next(iter(batch.values())).shape[0]
-        micros = [_place_for_reference(
-            {k: v[i] for k, v in batch.items()}, True)
-            for i in range(accum)]
-        dropout = None
-        if max(rates) > 0.0:
-            dropout = rates + (flash, bert_ref.dropout_seeds(
-                jax.numpy.asarray(key), accum, sizes["layers"], flash))
-        loss, grads = bert_ref.step_loss_and_grad(
-            params, micros, heads, max_pred, quant, dropout)
-        losses.append(float(loss))
-        if grad_norms is None:
-            clipped, _ = jax.jit(bert_ref.clipped_gradient)(grads)
-            clipped = adapter.to_program_tree(clipped, heads)
-            grad_norms = adapter.leaf_norms(clipped)
-            grad_sample = adapter.sample_matrices(clipped)
-            del clipped
-        params, opt = bert_ref.lamb_step(
-            params, grads, opt, float(t["learning_rate"]),
-            int(t["max_steps"]), float(t["warmup_proportion"]))
-        del grads
-    del opt
-    start = _place_for_reference(
-        bert_ref.init_params(spec["seed"], sizes), False)
-    delta_norms = adapter.leaf_diff_norms(
-        adapter.to_program_tree(params, heads),
-        adapter.to_program_tree(start, heads))
-    return {"losses": losses, "grad_norms": grad_norms,
-            "grad_sample": grad_sample, "delta_norms": delta_norms}
 
 
 def _kernel_counts(text, names: list) -> dict:
@@ -303,6 +237,35 @@ def _memory_peak(obs: Obs) -> dict:
     return dict(parts, runtime_peak_bytes=runtime, bytes_limit=limit)
 
 
+def _compared(got: dict, ref: dict, family, keep_norms: bool) -> dict:
+    """The numbers `correct` rests on: `got` (the program's followed steps,
+    or the control's in the program's place) against the reference's; with
+    --keep, every leaf's norms beside them."""
+    import numpy as np
+
+    from benchmark.harness import adapter
+
+    by_matrix = adapter.diff_by_matrix(got["grad_sample"],
+                                       ref["grad_sample"])
+    norms = {}
+    if keep_norms:
+        norms["norms"] = {
+            f"{who}_{what}": {k: v.tolist() for k, v in
+                              side[f"{what}_norms"].items()}
+            for who, side in (("got", got), ("reference", ref))
+            for what in ("grad", "delta")}
+    return dict(
+        program_losses=got["losses"], reference_losses=ref["losses"],
+        loss_rel=[abs(a - b) / abs(b)
+                  for a, b in zip(got["losses"], ref["losses"])],
+        grad=adapter.worst_gap(got["grad_norms"], ref["grad_norms"]),
+        delta=adapter.worst_gap(got["delta_norms"], ref["delta_norms"]),
+        grad_diff=float(np.mean(list(by_matrix.values()))),   # = diff_gap
+        grad_diff_by_matrix={k: float(v) for k, v in by_matrix.items()},
+        **norms,
+        **family.compare_extras(got, ref))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--spec", required=True)
@@ -325,20 +288,19 @@ def main(argv=None) -> int:
               f"sees {device}", flush=True)
         return 3
 
-    from benchmark.reference import bert_ref
+    from benchmark.harness import spec as spec_lib
 
-    cfg = spec["config"]
-    sizes = bert_ref.sizes_from_config(
-        cfg, int(spec["traffic"].get("vocab_pad_multiple", 128)))
+    family = spec_lib.load_family(spec["family"], spec["root"])
+    sizes = family.sizes(spec["config"], spec["traffic"])
     obs = Obs()
-    _install_hooks(spec, obs, sizes)
+    _install_hooks(spec, obs, family, sizes)
     out_dir = spec["out_dir"]
 
     import run_pretraining
 
     closed = False
     try:
-        run_pretraining.main(_program_argv(spec, out_dir))
+        run_pretraining.main(_program_argv(spec, family, out_dir))
     except WindowClosed:
         closed = True
     except SystemExit as e:
@@ -357,18 +319,13 @@ def main(argv=None) -> int:
         return 4
 
     steps_in = list(range(obs.step_open + 1, obs.step_close + 1))
+    in_window = set(steps_in)
     real = {n: int(np.asarray(jax.device_get(m)).sum())
             for n, m in obs.masks.items()}
     slots = {n: int(np.prod(m.shape)) for n, m in obs.masks.items()}
     obs.masks.clear()
-    # sum over documents of length squared, per step: what attention needs
-    # when a token attends only inside its own document
-    doc_sq = {}
-    for n, seg in obs.segs.items():
-        seg = np.asarray(jax.device_get(seg)).reshape(-1, seg.shape[-1])
-        counts = np.stack([np.bincount(row, minlength=int(seg.max()) + 1)
-                           for row in seg])[:, 1:]
-        doc_sq[n] = int((counts.astype(np.int64) ** 2).sum())
+    segs = {n: np.asarray(jax.device_get(seg))
+            for n, seg in obs.segs.items()}
     obs.segs.clear()
     memory = _memory_peak(obs)
     t = spec["traffic"]
@@ -380,72 +337,49 @@ def main(argv=None) -> int:
 
         scopes = trace_reduce.scopes_from_hlo(hlo)
     del hlo
-    # the per-row budget of the gathered MLM head, as the program sets it
-    seq_len = int(t["seq_len"])
-    obs.max_pred_row = int(t["max_predictions"])
-    if "segment_ids" in obs.batches[0]:
-        seg = int(t.get("packing_max_segments", 8))
-        obs.max_pred_row = min(seq_len, seg * int(t["max_predictions"]),
-                               int(seq_len * float(t["masked_lm_prob"]))
-                               + seg)
-    result["window"] = {
+    result["window"] = dict({
         "seconds": obs.t_close - obs.t_open,
         "steps": len(steps_in), "first_step": steps_in[0],
         "last_step": steps_in[-1],
         "real_tokens": sum(real.get(n, 0) for n in steps_in),
         "slot_tokens": sum(slots.get(n, 0) for n in steps_in),
         "setup_s": obs.wall_open - spec["start_time"],
-        "losses": [l for _, s, l in obs.loss_reads if s in set(steps_in)],
+        "losses": [l for _, s, l in obs.loss_reads if s in in_window],
         "loss_reads": [(c - obs.t_open, s) for c, s, _ in obs.loss_reads],
-        "perf": [p for p in obs.perf if p["step"] in set(steps_in)],
+        "perf": [p for p in obs.perf if p["step"] in in_window],
         "perf_open": next((p for p in obs.perf
                            if p["step"] == obs.step_open), None),
         "kernel_counts": counts, "opt_share": obs.opt_share,
-        "scopes": scopes, "doc_len_sq": doc_sq, "real_by_step": real,
+        "scopes": scopes, "real_by_step": real,
         "traced_first_step": FOLLOW + WARM + 3,
-        "max_pred_row": obs.max_pred_row,
         "memory": memory,
-    }
+    }, **family.window_extras(segs, obs.scalars))
+    del segs
+    got = dict({"losses": [l for _, s, l in obs.loss_reads if s <= FOLLOW],
+                "grad_norms": obs.grad_norms, "grad_sample": obs.grad_sample,
+                "delta_norms": obs.delta_norms},
+               **family.followed_by_program(obs.scalars, FOLLOW))
     obs.step_program = None
     gc.collect()
+    # the reference may need the whole device (at lfm2's widths a row's
+    # float32 gradient pass beside 469 M float32 weights, their gradient
+    # and LAMB's moments): whatever of the program's is still on it goes.
+    # Everything the comparison needs of the program is on the host.
+    for array in jax.live_arrays():
+        array.delete()
 
     t0 = time.perf_counter()
-    ref = _follow_with_reference(spec, obs, sizes)
+    ref = family.follow(spec, sizes, obs.batches, obs.keys)
     ref_seconds = time.perf_counter() - t0
-    from benchmark.harness import adapter
 
-    prog_losses = [l for _, s, l in obs.loss_reads if s <= FOLLOW]
-    compare = {
-        "reference_seconds": ref_seconds,
-        "program_losses": prog_losses, "reference_losses": ref["losses"],
-        "loss_rel": [abs(a - b) / abs(b)
-                     for a, b in zip(prog_losses, ref["losses"])],
-        "grad": adapter.worst_gap(obs.grad_norms, ref["grad_norms"]),
-        "delta": adapter.worst_gap(obs.delta_norms, ref["delta_norms"]),
-        "grad_diff": adapter.diff_gap(obs.grad_sample, ref["grad_sample"]),
-    }
-    if spec.get("keep_norms"):
-        as_lists = lambda d: {k: v.tolist() for k, v in d.items()}  # noqa
-        compare["norms"] = {
-            "program_grad": as_lists(obs.grad_norms),
-            "program_delta": as_lists(obs.delta_norms),
-            "reference_grad": as_lists(ref["grad_norms"]),
-            "reference_delta": as_lists(ref["delta_norms"])}
+    keep = bool(spec.get("keep_norms"))
+    compare = dict(_compared(got, ref, family, keep),
+                   reference_seconds=ref_seconds)
     if spec.get("control"):
-        ctl = _follow_with_reference(spec, obs, sizes, spec["control"])
-        compare["control"] = {
-            "precision": spec["control"],
-            "loss_rel": [abs(a - b) / abs(b)
-                         for a, b in zip(ctl["losses"], ref["losses"])],
-            "grad": adapter.worst_gap(ctl["grad_norms"], ref["grad_norms"]),
-            "delta": adapter.worst_gap(ctl["delta_norms"],
-                                       ref["delta_norms"]),
-            "grad_diff": adapter.diff_gap(ctl["grad_sample"],
-                                          ref["grad_sample"]),
-        }
-        if spec.get("keep_norms"):
-            compare["norms"]["control_grad"] = as_lists(ctl["grad_norms"])
-            compare["norms"]["control_delta"] = as_lists(ctl["delta_norms"])
+        ctl = family.follow(spec, sizes, obs.batches, obs.keys,
+                            spec["control"])
+        compare["control"] = dict(_compared(ctl, ref, family, keep),
+                                  precision=spec["control"])
     result["compare"] = compare
     with open(args.out, "w", encoding="utf-8") as f:
         json.dump(result, f)

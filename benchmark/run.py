@@ -6,12 +6,16 @@
 runs one cell of BENCHMARK.json on the machine it is started on and prints,
 as the LAST line of its standard output, one JSON object with `correct`,
 `attempted`, `failed`, `metrics` and `device` (and `breakdown` with
---trace 1). Everything else worth reading goes on earlier lines. Without
-the chips the cell asks for it exits non-zero and prints no result.
+--trace 1), and last in it `compared`: each number `correct` rests on
+beside its limit, which are also the last lines on standard error.
+Everything else worth reading goes on earlier lines. Without the chips the
+cell asks for it exits non-zero and prints no result.
 
 This process stays off JAX: one child reaches the chip (benchmark/harness/
 train_child.py). What kind of load a cell is, is the `driver` key of its
-traffic file; the driver is benchmark/drivers/<driver>.py.
+traffic file; the driver is benchmark/drivers/<driver>.py. What model
+family it runs is its configuration's `model_type`; all the benchmark knows
+of a family is benchmark/families/<model_type>.py.
 
 Options beyond the contract's four, for builders and tests only:
   --rehearse     tiny shapes on the CPU, no metrics (`device` says cpu)

@@ -30,7 +30,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 RUN = os.path.join(ROOT, "benchmark", "run.py")
 COMPARED = re.compile(r"\[bench\] correct\? (.*?)(?: \(.*?\))?: (\S+) \(limit")
-CONTROL = re.compile(r"\[bench\] control .*")
+CONTROL = re.compile(r"\[bench\] control.*")
 
 
 def spread(values: list) -> float:

@@ -1,11 +1,14 @@
 """The manifest and the files it names: the contract's form, every file
-found by name, and that a cell, a configuration, a traffic mix, a per-layer
-metric and a reader are added as NEW files with no edit of an existing one."""
+found by name whatever the model family, and that a cell, a configuration,
+a traffic mix, a per-layer metric, a reader and a whole model family are
+added as NEW files with no edit of an existing one."""
 
+import filecmp
 import json
 import os
 import re
 import shutil
+import subprocess
 
 import pytest
 
@@ -75,7 +78,8 @@ def test_metric_entry(metric):
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_files_found_by_name(cell):
     found = spec.find_cell(MANIFEST, cell, ROOT)
-    assert found["config"]["hidden_size"] in (768, 1024)
+    assert found["family"] == found["config"].get("model_type", "bert")
+    spec.load_family(found["family"], ROOT)
     traffic = found["traffic"]
     spec.load_driver(traffic["driver"], ROOT)
     assert traffic["data_shards"] == found["chips"]
@@ -91,14 +95,21 @@ def test_cell_files_found_by_name(cell):
 @pytest.mark.parametrize("config", MANIFEST["configs"],
                          ids=lambda c: c["name"])
 def test_configuration_file(config):
+    """What holds for any family's configuration, and still catches a toy:
+    the cut is stated on both sides, and it names no width."""
     cfg = spec.load_json(os.path.join(ROOT, config["file"]))
     assert config["file"].startswith("benchmark/configs/")
     assert cfg["source"] == config["source"]
     assert sorted(cfg["reduced"]) == sorted(config["reduced"])
-    widths = ("hidden_size", "intermediate_size", "num_attention_heads")
+    widths = ("hidden_size", "intermediate_size", "moe_intermediate_size",
+              "num_attention_heads", "num_key_value_heads",
+              "num_experts_per_tok")
     assert not [k for k in config["reduced"]
                 if k in widths or k.endswith(("_dim", "_rank"))]
-    assert cfg["hidden_size"] // cfg["num_attention_heads"] == 64
+    # a key that is cut is explained where the sizes are
+    assert all(cfg["reduced"][k] for k in config["reduced"])
+    assert cfg["assumed"] and cfg["layout"]
+    spec.load_family(cfg.get("model_type", "bert"), ROOT)
 
 
 @pytest.mark.parametrize("name", LAYER)
@@ -114,51 +125,105 @@ def test_layer_metric_file_matches_the_manifest(name):
         assert "workloads" not in moved or cell in moved["workloads"]
 
 
-def test_additions_are_new_files_only(tmp_path):
-    """A later PR's cell, configuration, traffic mix, metric and reader:
-    five new files and four new entries, nothing edited."""
-    root = str(tmp_path)
+THIRD = "third-lm.cell"
+
+
+@pytest.fixture(scope="module")
+def additions(tmp_path_factory):
+    """A later `model_config` PR as it has to look: a copy of the benchmark
+    plus a family module under a name of its own (binding the lfm2
+    reference and adapter), a configuration of that family at sizes no
+    other has, a traffic mix, a cell, a per-layer metric and its reader:
+    six new files and four new entries, nothing edited. The copy links the
+    program (which trains its `lfm2_moe` model: the traffic's toy
+    configuration says so, since the program cannot know the new name)."""
+    root = str(tmp_path_factory.mktemp("additions"))
     shutil.copytree(os.path.join(ROOT, "benchmark"),
-                    os.path.join(root, "benchmark"))
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for name in ("bert_pytorch_tpu", "run_pretraining.py"):
+        os.symlink(os.path.join(ROOT, name), os.path.join(root, name))
+    base = spec.find_cell(MANIFEST, "lfm2-ep8-clm-8k-packed", ROOT)
+    traffic = json.loads(json.dumps(base["traffic"]))
+    traffic["rehearse"]["config"].update(
+        model_type="lfm2_moe", hidden_size=96, num_attention_heads=2,
+        num_key_value_heads=1)
+    for path, content in {
+        "families/third_lm.py":
+            "from benchmark.families.lfm2_moe import *  # noqa: F403\n",
+        "configs/third-lm.json": json.dumps(dict(
+            base["config"], model_type="third_lm", hidden_size=1536,
+            num_attention_heads=16, num_key_value_heads=4,
+            source="paper:third")),
+        "traffic/third-mix.json": json.dumps(traffic),
+        "layer_metrics/dummy_ms.json": json.dumps({
+            "layer": "host loop and data plane", "unit": "ms",
+            "better": "lower", "source": "program_span",
+            "moves": "train_tokens_per_s_chip", "reader": "dummy_reader",
+            "args": {"scale": 2.0}}),
+        "readers/dummy_reader.py":
+            "def read(ctx, scale):\n    return ctx['x'] * scale\n",
+    }.items():
+        with open(os.path.join(root, "benchmark", path), "w") as f:
+            f.write(content)
     manifest = json.loads(json.dumps(MANIFEST))
-    base = spec.find_cell(MANIFEST, CELLS[0], ROOT)
-    cfg = dict(base["config"], num_hidden_layers=2, source="paper:dummy")
-    with open(os.path.join(root, "benchmark/configs/dummy.json"), "w") as f:
-        json.dump(cfg, f)
-    with open(os.path.join(root, "benchmark/traffic/dummy-mix.json"),
-              "w") as f:
-        json.dump(dict(base["traffic"], seq_len=64), f)
-    with open(os.path.join(root, "benchmark/layer_metrics/dummy_ms.json"),
-              "w") as f:
-        json.dump({"layer": "host loop and data plane", "unit": "ms",
-                   "better": "lower", "source": "program_span",
-                   "moves": "train_tokens_per_s_chip",
-                   "reader": "dummy_reader", "args": {"scale": 2.0}}, f)
-    with open(os.path.join(root, "benchmark/readers/dummy_reader.py"),
-              "w") as f:
-        f.write("def read(ctx, scale):\n    return ctx['x'] * scale\n")
     manifest["configs"].append({
-        "name": "dummy", "source": "paper:dummy",
-        "file": "benchmark/configs/dummy.json",
-        "reduced": ["num_hidden_layers"], "why": "test"})
+        "name": "third-lm", "source": "paper:third",
+        "file": "benchmark/configs/third-lm.json",
+        "reduced": base["config_entry"]["reduced"], "why": "test"})
     manifest["workloads"].append({
-        "name": "dummy.cell", "config": "dummy", "traffic": "dummy-mix",
+        "name": THIRD, "config": "third-lm", "traffic": "third-mix",
         "chips": 1, "why": "test"})
     manifest["per_layer"].append({
         "name": "dummy_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "host loop and data plane",
-        "moves": "train_tokens_per_s_chip", "workloads": ["dummy.cell"]})
-    found = spec.find_cell(manifest, "dummy.cell", root)
-    assert found["config"]["num_hidden_layers"] == 2
-    assert found["traffic"]["seq_len"] == 64
+        "moves": "train_tokens_per_s_chip", "workloads": [THIRD]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest, f)
+    return root, manifest
+
+
+@pytest.mark.parametrize("fault,correct", [(None, True),
+                                           ("noop_step", False)],
+                         ids=["sound", "step-returns-state-unchanged"])
+def test_additions_are_new_files_only(additions, fault, correct):
+    root, manifest = additions
+    found = spec.find_cell(manifest, THIRD, root)
+    cfg = found["config"]
+    assert found["family"] == "third_lm"
+    # sizes no configuration of the benchmark has: 16 heads of 96
+    assert (cfg["hidden_size"], cfg["num_attention_heads"]) == (1536, 16)
+    assert spec.load_family("third_lm", root).__file__.startswith(root)
+    assert callable(spec.load_driver(found["traffic"]["driver"], root))
     got = spec.read_layer_metrics(
         {**manifest, "per_layer": manifest["per_layer"][-1:]},
-        "dummy.cell", {"x": 21.0}, root)
+        THIRD, {"x": 21.0}, root)
     assert got == {"dummy_ms": {"value": 42.0, "unit": "ms"}}
-    # and the cells that were there do not report the new metric
+    # the cells that were there do not report the new metric
     assert "dummy_ms" not in [
         m["name"] for m in spec.metrics_of_cell(manifest, CELLS[0],
                                                 "per_layer")]
+
+    # and no file that was there differs from the repository's
+    def differing(cmp):
+        yield from cmp.diff_files + cmp.left_only
+        for sub in cmp.subdirs.values():
+            yield from differing(sub)
+    assert not list(differing(filecmp.dircmp(
+        os.path.join(ROOT, "benchmark"), os.path.join(root, "benchmark"),
+        ignore=["__pycache__"])))
+
+    # the whole of a run of the new cell but the look for a chip
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"),
+         "--workload", THIRD, "--seed", str(2**31 + 23), "--seconds", "1",
+         "--trace", "0", "--rehearse"] + (["--fault", fault] if fault else []),
+        cwd=root, env=dict(os.environ, JAX_ENABLE_COMPILATION_CACHE="0"),
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    last = json.loads(proc.stdout.splitlines()[-1])
+    assert last["correct"] is correct, proc.stdout[-4000:]
+    assert "held-expert tokens" in proc.stdout      # the family's own checks
 
 
 def test_unknown_names_are_errors():
@@ -166,6 +231,8 @@ def test_unknown_names_are_errors():
         spec.find_cell(MANIFEST, "no-such-cell", ROOT)
     with pytest.raises(spec.SpecError):
         spec.load_reader("no_such_reader", ROOT)
+    with pytest.raises(spec.SpecError):
+        spec.load_family("no_such_family", ROOT)
 
 
 @pytest.mark.parametrize("peak,limit,rehearse,want", [
