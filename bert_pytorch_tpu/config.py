@@ -298,7 +298,175 @@ class Lfm2MoeConfig:
         return tuple(kinds)
 
 
-MODEL_FAMILIES = {"bert": BertConfig, "lfm2_moe": Lfm2MoeConfig}
+@dataclasses.dataclass(frozen=True)
+class KimiLinearConfig:
+    """Architecture config of the `kimi_linear` family (Moonshot Kimi
+    Linear): a pre-norm decoder of gated delta-rule linear-attention layers
+    (KDA) and latent-attention layers without positions (MLA, NoPE) in the
+    published 3 : 1 order, a dense SwiGLU MLP in the leading layers and
+    sigmoid-routed experts beside a shared expert after them, an untied
+    head (models/kimi_linear.py has the equations).
+
+    Keys are the source's (`config.json` of the model; `linear_attn_config`
+    is its nested group: layer numbers count from 1). A run may hold one
+    expert-parallel rank's share, as Lfm2MoeConfig's: `num_experts` experts,
+    the range `experts_held` of `experts_total`, `vocab_size` rows of the
+    vocabulary, and the source layers `layers_kept` (numbered from 1 like
+    the group's lists). `kda_chunk_size` and `kda_gate_rank` are not in the
+    source: the family's conventions (64; the head width).
+    """
+
+    model_type: str = "kimi_linear"
+    vocab_size: int = 163840
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 27
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    num_expert_group: int = 1
+    topk_group: int = 1
+    moe_renormalize: bool = True
+    moe_router_activation_func: str = "sigmoid"
+    routed_scaling_factor: float = 2.446
+    rms_norm_eps: float = 1e-5
+    tie_word_embeddings: bool = False
+    # linear_attn_config, flattened
+    kda_layers: Tuple[int, ...] = ()
+    full_attn_layers: Tuple[int, ...] = ()
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kda_chunk_size: int = 64
+    kda_gate_rank: Optional[int] = None
+    layers_kept: Optional[Tuple[int, ...]] = None
+    experts_total: Optional[int] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    initializer_range: float = 0.02
+    model_name: Optional[str] = None
+    # run settings, as BertConfig's
+    dtype: str = "bfloat16"
+    checkpoint_activations: bool = False
+    remat_policy: str = "auto"
+    attention_impl: str = "auto"
+
+    # source keys that carry no size of this program's
+    _IGNORED = ("head_dim", "hidden_act", "model_max_length",
+                "moe_layer_freq", "num_nextn_predict_layers", "rope_scaling",
+                "rope_theta", "use_grouped_topk", "vocab_rows_total",
+                "vocab_rows_held", "linear_attn_config")
+    _GROUP = {"kda_layers": "kda_layers",
+              "full_attn_layers": "full_attn_layers",
+              "num_heads": "kda_num_heads", "head_dim": "kda_head_dim",
+              "short_conv_kernel_size": "short_conv_kernel_size"}
+
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any]) -> "KimiLinearConfig":
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = [k for k in d if k not in known
+                   and k not in DOCUMENTED_DATA_KEYS
+                   and k not in cls._IGNORED]
+        group = d.get("linear_attn_config") or {}
+        unknown += [f"linear_attn_config.{k}" for k in group
+                    if k not in cls._GROUP]
+        if unknown:
+            raise ValueError(
+                f"kimi_linear model config: unknown key(s) {sorted(unknown)}")
+        kw = {k: v for k, v in d.items() if k in known}
+        kw.update({cls._GROUP[k]: v for k, v in group.items()})
+        for key in ("kda_layers", "full_attn_layers", "layers_kept",
+                    "experts_held"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        cfg = cls(**kw)
+        cfg.layer_kinds  # raises on an inconsistent cut
+        return cfg
+
+    @classmethod
+    def from_json_file(cls, path: str) -> "KimiLinearConfig":
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_dict(json.load(f))
+
+    def to_dict(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    def replace(self, **kw: Any) -> "KimiLinearConfig":
+        return dataclasses.replace(self, **kw)
+
+    # the names models/lfm2_moe.py's shared modules read
+    norm_eps = property(lambda self: self.rms_norm_eps)
+    num_experts_per_tok = property(lambda self: self.num_experts_per_token)
+    norm_topk_prob = property(lambda self: self.moe_renormalize)
+    use_expert_bias = property(lambda self: True)
+
+    @property
+    def gate_rank(self) -> int:
+        return int(self.kda_gate_rank or self.kda_head_dim)
+
+    @property
+    def router_width(self) -> int:
+        return int(self.experts_total or self.num_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of "
+                f"num_experts={self.num_experts} out of {self.router_width}")
+        return int(lo), int(hi)
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, ffn) of every layer of the stack as run: mixer "kda" or
+        "mla", ffn "dense" or "moe"."""
+        kept = (self.layers_kept if self.layers_kept is not None
+                else tuple(range(1, self.num_hidden_layers + 1)))
+        if len(kept) != self.num_hidden_layers:
+            raise ValueError(
+                f"layers_kept {kept} does not name num_hidden_layers="
+                f"{self.num_hidden_layers} layers")
+        unsupported = [
+            name for name, bad in (
+                ("q_lora_rank", self.q_lora_rank is not None),
+                ("mla_use_nope=false", not self.mla_use_nope),
+                ("num_expert_group", self.num_expert_group != 1
+                 or self.topk_group != 1),
+                ("moe_router_activation_func",
+                 self.moe_router_activation_func != "sigmoid"),
+                ("tie_word_embeddings", self.tie_word_embeddings),
+                ("num_key_value_heads",
+                 self.num_key_value_heads != self.num_attention_heads),
+                ("num_shared_experts", self.num_shared_experts != 1)) if bad]
+        if unsupported:
+            raise NotImplementedError(
+                f"kimi_linear: not written for {unsupported} (the source "
+                "model uses none of them)")
+        self.held_range
+        kinds = []
+        for j, i in enumerate(kept):
+            if (i in self.kda_layers) == (i in self.full_attn_layers):
+                raise ValueError(
+                    f"source layer {i} must stand in exactly one of "
+                    "linear_attn_config's kda_layers and full_attn_layers")
+            kinds.append(("kda" if i in self.kda_layers else "mla",
+                          "dense" if j < self.first_k_dense_replace
+                          else "moe"))
+        return tuple(kinds)
+
+
+MODEL_FAMILIES = {"bert": BertConfig, "lfm2_moe": Lfm2MoeConfig,
+                  "kimi_linear": KimiLinearConfig}
 
 
 def load_model_config(path: str):

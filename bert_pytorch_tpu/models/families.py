@@ -17,7 +17,7 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import MODEL_FAMILIES
-from bert_pytorch_tpu.models import lfm2_moe
+from bert_pytorch_tpu.models import kimi_linear, lfm2_moe
 from bert_pytorch_tpu.models.bert import BertForPreTraining
 from bert_pytorch_tpu.telemetry.expert_load import ExpertLoadCounters
 from bert_pytorch_tpu.telemetry.stepwatch import flops_per_seq
@@ -51,13 +51,32 @@ def _bert_init_inputs(batch) -> Tuple:
                  ("input_ids", "token_type_ids", "attention_mask"))
 
 
-def _lfm2_refusal(args) -> Optional[str]:
+def _decoder_refusal(args) -> Optional[str]:
+    """The decoder families' one refusal (lfm2_moe, kimi_linear)."""
     if not (args.kfac or args.stream_dir or args.stacked_params != "auto"
             or args.steps_per_loop > 1):
         return None
-    return ("model_type 'lfm2_moe' trains through the offline data plane "
-            "with LAMB/Adam, one step a dispatch: --kfac, --stream_dir, "
-            "--stacked_params and --steps_per_loop do not apply to it")
+    return ("the decoder families (model_type 'lfm2_moe', 'kimi_linear') "
+            "train through the offline data plane with LAMB/Adam, one step "
+            "a dispatch: --kfac, --stream_dir, --stacked_params and "
+            "--steps_per_loop do not apply to them")
+
+
+def _decoder_family(module, model_cls) -> Family:
+    """A causal-LM family over packed rows, from its model module."""
+    return Family(
+        make_model=lambda config, dtype: model_cls(config, dtype=dtype),
+        init_inputs=lfm2_moe.init_inputs,    # a packed causal-LM batch's
+        objective="clm",
+        mlm_head=False,
+        step_kwargs={"loss_fn_builder": module.pretrain_loss_fn_builder,
+                     "keep_float32": module.keep_float32},
+        # the family's own formula (never BERT's): an upper estimate for
+        # packed rows, whose documents attend less than a full row
+        train_flops_per_row=lambda config, seq_len, n_pred:
+            module.train_flops_per_row(config, seq_len),
+        refusal=_decoder_refusal,
+        make_counters=ExpertLoadCounters)
 
 
 FAMILIES = {
@@ -72,20 +91,9 @@ FAMILIES = {
             config, seq_len, config.vocab_size, n_pred),
         refusal=lambda args: None,
         make_counters=lambda: None),
-    "lfm2_moe": Family(
-        make_model=lambda config, dtype: lfm2_moe.Lfm2MoeForCausalLM(
-            config, dtype=dtype),
-        init_inputs=lfm2_moe.init_inputs,
-        objective="clm",
-        mlm_head=False,
-        step_kwargs={"loss_fn_builder": lfm2_moe.pretrain_loss_fn_builder,
-                     "keep_float32": lfm2_moe.keep_float32},
-        # the family's own formula (never BERT's): an upper estimate for
-        # packed rows, whose documents attend less than a full row
-        train_flops_per_row=lambda config, seq_len, n_pred:
-            lfm2_moe.train_flops_per_row(config, seq_len),
-        refusal=_lfm2_refusal,
-        make_counters=ExpertLoadCounters),
+    "lfm2_moe": _decoder_family(lfm2_moe, lfm2_moe.Lfm2MoeForCausalLM),
+    "kimi_linear": _decoder_family(kimi_linear,
+                                   kimi_linear.KimiLinearForCausalLM),
 }
 
 

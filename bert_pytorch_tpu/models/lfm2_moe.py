@@ -38,7 +38,7 @@ beside the layer's input (LM_REMAT_POLICIES).
 
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -72,7 +72,9 @@ LM_REMAT_POLICIES = {
 LM_REMAT_POLICIES["auto"] = LM_REMAT_POLICIES["dense"]
 
 
-def _init(cfg: Lfm2MoeConfig) -> Callable:
+# RMSNorm, _Linear, DenseMLP and RoutedExperts are the decoder families'
+# (models/kimi_linear.py imports them): `config` is either family's.
+def _init(cfg) -> Callable:
     return nn.initializers.normal(stddev=cfg.initializer_range)
 
 
@@ -91,7 +93,7 @@ class _Linear(nn.Module):
     """x @ kernel, no bias: `dtype` operands, float32 accumulation, the
     result in `out_dtype` (default `dtype`)."""
     features: int
-    config: Lfm2MoeConfig
+    config: Any
     dtype: Dtype = jnp.bfloat16
     out_dtype: Any = None
 
@@ -156,13 +158,15 @@ class Attention(nn.Module):
 
 
 class DenseMLP(nn.Module):
-    config: Lfm2MoeConfig
+    """SwiGLU MLP of `features` (default: the config's dense width)."""
+    config: Any
     dtype: Dtype = jnp.bfloat16
+    features: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        f = cfg.intermediate_size
+        f = self.features or cfg.intermediate_size
         gate = _Linear(f, cfg, self.dtype, name="w1")(x)
         up = _Linear(f, cfg, self.dtype, name="w3")(x)
         hidden = (jax.nn.silu(gate.astype(jnp.float32))
@@ -174,7 +178,7 @@ class RoutedExperts(nn.Module):
     """The routed FFN over the experts this rank holds. Returns (the partial
     sum (B, S, E) in `dtype`, tokens per held expert (E_held,) int32, held
     pairs not computed () int32)."""
-    config: Lfm2MoeConfig
+    config: Any
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
@@ -289,6 +293,20 @@ def keep_float32(path: Tuple) -> bool:
     return keys[-1] in ("router", "expert_bias")
 
 
+def expert_scalars(count, pairs_routed: int, load, dropped) -> dict:
+    """A micro-batch's scalars of the decoder families (telemetry/
+    expert_load.py sums them): predicted positions, (token, expert) pairs
+    routed, and per routed layer each held expert's tokens and the held
+    pairs not computed."""
+    scalars = {"lm_positions": count,
+               "moe_pairs_routed": jnp.asarray(pairs_routed, jnp.int32)}
+    for layer in range(load.shape[0]):
+        scalars[f"moe_l{layer}_dropped"] = dropped[layer]
+        for j in range(load.shape[1]):
+            scalars[f"moe_l{layer}_e{j}"] = load[layer, j]
+    return scalars
+
+
 def pretrain_loss_fn_builder(model) -> Callable:
     """loss_fn_builder of training/pretrain.build_pretrain_step: next-token
     cross-entropy over packed rows, and the layers' expert counters as
@@ -301,14 +319,9 @@ def pretrain_loss_fn_builder(model) -> Callable:
             loss, count = losses.next_token_loss(
                 logits, batch["input_ids"], batch["segment_ids"])
         with jax.named_scope("metrics"):
-            scalars = {"lm_positions": count,
-                       "moe_pairs_routed": jnp.asarray(
-                           batch["input_ids"].size
-                           * model.config.num_experts_per_tok, jnp.int32)}
-            for layer in range(load.shape[0]):
-                scalars[f"moe_l{layer}_dropped"] = dropped[layer]
-                for j in range(load.shape[1]):
-                    scalars[f"moe_l{layer}_e{j}"] = load[layer, j]
+            scalars = expert_scalars(
+                count, batch["input_ids"].size
+                * model.config.num_experts_per_tok, load, dropped)
         return loss, {"scalars": scalars}
 
     return loss_fn

@@ -248,6 +248,15 @@ def mlm_accuracy(mlm_logits: jax.Array, labels: jax.Array
     return correct.sum(), valid.sum()
 
 
+def _next_token_labels(input_ids: jax.Array,
+                       segment_ids: jax.Array) -> jax.Array:
+    """The successor's id where it lies in the same document, else -1."""
+    nxt_ids = jnp.pad(input_ids[:, 1:], ((0, 0), (0, 1)))
+    nxt_seg = jnp.pad(segment_ids[:, 1:], ((0, 0), (0, 1)))
+    return jnp.where((segment_ids > 0) & (nxt_seg == segment_ids),
+                     nxt_ids, -1)
+
+
 def next_token_loss(logits: jax.Array, input_ids: jax.Array,
                     segment_ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Causal language modelling over packed rows: the mean, over positions
@@ -255,10 +264,7 @@ def next_token_loss(logits: jax.Array, input_ids: jax.Array,
     successor's id. logits (B, S, V); input_ids, segment_ids (B, S) (the
     packing contract's segments, 0 = pad). Returns (loss, the number of
     such positions)."""
-    nxt_ids = jnp.pad(input_ids[:, 1:], ((0, 0), (0, 1)))
-    nxt_seg = jnp.pad(segment_ids[:, 1:], ((0, 0), (0, 1)))
-    labels = jnp.where((segment_ids > 0) & (nxt_seg == segment_ids),
-                       nxt_ids, -1)
+    labels = _next_token_labels(input_ids, segment_ids)
     # logsumexp minus the label's logit, not log_softmax then a gather: over
     # (32768, 8192) float32 logits XLA:TPU runs the latter's fused
     # exp-reduce six times slower (133 against 22 ms forward and backward on
@@ -270,3 +276,37 @@ def next_token_loss(logits: jax.Array, input_ids: jax.Array,
     nll = jnp.where(valid, jax.nn.logsumexp(logits, axis=-1) - picked, 0.0)
     count = valid.sum()
     return nll.sum() / jnp.maximum(count, 1), count.astype(jnp.int32)
+
+
+def next_token_loss_blocked(hidden: jax.Array, head: jax.Array,
+                            input_ids: jax.Array, segment_ids: jax.Array,
+                            block_rows: int) -> Tuple[jax.Array, jax.Array]:
+    """`next_token_loss` of logits = hidden @ head^T (hidden (B, S, E), head
+    (V, E), float32 accumulation) without the (B S, V) logits: the head and
+    the cross-entropy run over `block_rows` tokens at a time, each block
+    rematerialised in the backward pass, so one block's logits and their
+    gradient are alive. B S that `block_rows` does not divide is one block.
+    Scopes `lm_head` and `loss` (training/pretrain.LM_STEP_SCOPES)."""
+    tokens = input_ids.size
+    rows = block_rows if tokens % block_rows == 0 else tokens
+    labels = _next_token_labels(input_ids, segment_ids).reshape(-1, rows)
+    hidden = hidden.reshape(-1, rows, hidden.shape[-1])
+
+    @jax.checkpoint
+    def block(total, inputs):
+        x, labels = inputs
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(x, head.T, preferred_element_type=jnp.float32)
+        with jax.named_scope("loss"):
+            valid = labels >= 0
+            picked = jnp.take_along_axis(
+                logits, jnp.where(valid, labels, 0)[:, None], axis=-1)[:, 0]
+            nll = jnp.where(
+                valid, jax.nn.logsumexp(logits, axis=-1) - picked, 0.0)
+            return total + nll.sum(), None
+
+    total, _ = jax.lax.scan(block, jnp.zeros([], jnp.float32),
+                            (hidden, labels))
+    with jax.named_scope("loss"):
+        count = (labels >= 0).sum()
+        return total / jnp.maximum(count, 1), count.astype(jnp.int32)

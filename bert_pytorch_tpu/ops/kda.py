@@ -1,0 +1,264 @@
+"""Kimi Delta Attention's recurrence: the gated delta rule with a decay per
+channel, computed in chunks, forward and a hand-written backward.
+
+Per head, with a state S (Dk x Dv) that is zero before the first token of
+each document (and at every padding slot),
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+(g_t <= 0 the log-decay per key channel, beta_t in (0, 1)). Writing
+u_t = beta_t (v_t - S_{t-1}^T (exp(g_t) * k_t)), the state is a decayed sum
+of k_i u_i^T, so inside a chunk of C tokens that starts from S_0, with
+G_t = g_1 + ... + g_t:
+
+    A_ti = beta_t (k_t * exp(G_t)) . (k_i * exp(-G_i))      (i < t)
+    (I + A) U = beta * V - (beta * K * exp(G)) S_0           (the WY form)
+    O = (Q * exp(G)) S_0 + tril(Q exp(G) (K exp(-G))^T) U    (i <= t)
+    S_C = exp(G_C) * S_0 + (K * exp(G_C - G))^T U
+
+`T = (I + A)^-1` of the unit lower-triangular system is the product
+(I - A)(I + A^2)(I + A^4)... (A is nilpotent), in float32 at the highest
+matmul precision. A document boundary is a mask: pairs of different
+documents leave A and the triangle of Q K^T, and a token past a boundary
+in its chunk does not see S_0 (nor does S_C, past one). exp(G) and exp(-G)
+are taken about the chunk's middle token where they multiply each other,
+which keeps both finite while a channel's log-decay over half a chunk
+stays above -88 (float32); beyond that the chunk size is too long for the
+decay and the results are not finite.
+
+What runs where: the chunk-local algebra (`_prepare`) over a block of
+`block` chunks at once, as batched matrix products; the carried state
+through the block's chunks one after another (`_chunk_fwd`, a `lax.scan`);
+blocks one after another (an outer scan), so that the temporaries are one
+block's. The backward pass is written here, not derived from the scans:
+per block, from the state at its start (kept by the forward pass: one
+state per block), the chunk states again, then the chunks in reverse
+carrying dS (`_chunk_bwd`), then the chunk-local algebra's cotangents
+(`jax.vjp` of `_prepare`, whose inverse has its own rule, dA = -T^T dT
+T^T). Matrix products take `mm_dtype` operands and accumulate in float32;
+decays, their sums, beta, the inverse and the carried state are float32.
+
+Scope `kda/scan` (training/pretrain.LM_STEP_SCOPES), opened in every body:
+inside a scan or a custom rule an operation keeps the scopes of the body.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+SCOPE = "kda/scan"
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(eq: str, a, b, dtype):
+    return jnp.einsum(eq, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _mm32(a, b):
+    return jnp.matmul(a, b, precision=_HIGHEST)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """(I + a)^-1 for strictly lower-triangular a (..., C, C), float32:
+    (I - a)(I + a^2)(I + a^4)..., exact because a^C = 0."""
+    c = a.shape[-1]
+    t = jnp.eye(c, dtype=a.dtype) - a
+    power, n = a, 2
+    while n < c:
+        power = _mm32(power, power)         # a^n
+        t = t + _mm32(t, power)
+        n *= 2
+    return t
+
+
+def _inverse_fwd(a):
+    t = unit_lower_inverse(a)
+    return t, t
+
+
+def _inverse_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-_mm32(_mm32(tt, dt), tt),)
+
+
+unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _prepare(q, k, v, g, beta, rid, prev, mm_dtype):
+    """The chunk-local algebra of any number of chunks at once. q, k, v, g
+    (..., H, C, D), beta (..., H, C), rid (..., C) the tokens' document
+    runs, prev (...) the run of the token before the chunk. Returns what
+    the recurrence reads: W, U' (the solved right-hand sides for K and V),
+    the masked triangle P of Q K^T, Q and K decayed from and to the chunk's
+    ends, and the state's decay over the chunk."""
+    c = q.shape[-2]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    same = (rid[..., :, None] == rid[..., None, :])[..., None, :, :]
+    seen = (rid == prev[..., None])[..., None, :, None]     # sees S_0
+    to_end = same[..., c - 1, :, None]                      # reaches S_C
+    lower = jnp.tril(jnp.ones((c, c), bool), -1)
+    cum = jnp.cumsum(g.astype(jnp.float32), axis=-2)
+    about = cum - jax.lax.stop_gradient(cum[..., c // 2:c // 2 + 1, :])
+    up, down = jnp.exp(about), jnp.exp(-about)
+    from_start = jnp.exp(cum)
+    k_down = k * down
+    bk = beta[..., None]
+    # masked BEFORE beta multiplies: above the diagonal the product of the
+    # two exponentials may be inf or nan, and 0 * inf in beta's cotangent
+    # is not 0
+    a = bk * jnp.where(same & lower, _mm("...td,...id->...ti", k * up,
+                                         k_down, mm_dtype), 0.0)
+    t = unit_lower_inverse(a)
+    w = _mm("...ti,...id->...td", t,
+            jnp.where(seen, bk * k * from_start, 0.0), mm_dtype)
+    u = _mm("...ti,...id->...td", t, bk * v, mm_dtype)
+    p = jnp.where(same & (lower | jnp.eye(c, dtype=bool)),
+                  _mm("...td,...id->...ti", q * up, k_down, mm_dtype), 0.0)
+    q_start = jnp.where(seen, q * from_start, 0.0)
+    k_end = jnp.where(to_end, k * jnp.exp(cum[..., c - 1:, :] - cum), 0.0)
+    decay = jnp.where(seen[..., c - 1, :], from_start[..., c - 1, :], 0.0)
+    return w, u, p, q_start, k_end, decay
+
+
+def _chunk_fwd(mm_dtype, state, chunk):
+    """One chunk of every head: state (B, H, Dk, Dv) float32 in, (state
+    out, (outputs (B, H, C, Dv), the state it started from))."""
+    with jax.named_scope(SCOPE):
+        w, u, p, q_start, k_end, decay = chunk
+        u = u - _mm("bhck,bhkv->bhcv", w, state, mm_dtype)
+        out = (_mm("bhck,bhkv->bhcv", q_start, state, mm_dtype)
+               + _mm("bhct,bhtv->bhcv", p, u, mm_dtype))
+        new = decay[..., None] * state + _mm("bhck,bhcv->bhkv", k_end, u,
+                                             mm_dtype)
+        return new, (out, state)
+
+
+def _chunk_bwd(mm_dtype, dstate, chunk):
+    """The same chunk in reverse: dstate is the cotangent of the state it
+    hands on; returns that of the state it started from and of everything
+    `_prepare` gave it."""
+    with jax.named_scope(SCOPE):
+        (w, u, p, q_start, k_end, decay), state, dout = chunk
+        u = u - _mm("bhck,bhkv->bhcv", w, state, mm_dtype)
+        du = (_mm("bhct,bhcv->bhtv", p, dout, mm_dtype)
+              + _mm("bhck,bhkv->bhcv", k_end, dstate, mm_dtype))
+        dprep = (-_mm("bhcv,bhkv->bhck", du, state, mm_dtype), du,
+                 _mm("bhcv,bhtv->bhct", dout, u, mm_dtype),
+                 _mm("bhcv,bhkv->bhck", dout, state, mm_dtype),
+                 _mm("bhcv,bhkv->bhck", u, dstate, mm_dtype),
+                 jnp.sum(dstate * state, axis=-1))
+        dstate = (_mm("bhck,bhcv->bhkv", q_start, dout, mm_dtype)
+                  + decay[..., None] * dstate
+                  - _mm("bhck,bhcv->bhkv", w, du, mm_dtype))
+        return dstate, dprep
+
+
+def _block_fwd(mm_dtype, state, block):
+    with jax.named_scope(SCOPE):
+        prep = _prepare(*block, mm_dtype)
+    return jax.lax.scan(functools.partial(_chunk_fwd, mm_dtype), state, prep)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _blocks(q, k, v, g, beta, rid, prev, mm_dtype):
+    """Blocks of chunks, chunk-major: q, k, v, g (blocks, chunks, B, H, C,
+    D), beta (blocks, chunks, B, H, C), rid (blocks, chunks, B, C), prev
+    (blocks, chunks, B) -> outputs like v, float32."""
+    return _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype)[0]
+
+
+def _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype):
+    b, h, d = q.shape[2], q.shape[3], q.shape[5]
+
+    def body(state, block):
+        new, (out, _) = _block_fwd(mm_dtype, state, block)
+        return new, (out, state)
+
+    _, (out, starts) = jax.lax.scan(
+        body, jnp.zeros((b, h, d, v.shape[5]), jnp.float32),
+        (q, k, v, g, beta, rid, prev))
+    return out, (q, k, v, g, beta, rid, prev, starts)
+
+
+def _blocks_bwd(mm_dtype, saved, dout):
+    q, k, v, g, beta, rid, prev, starts = saved
+
+    def body(dstate, block):
+        state, dout, *inputs = block
+        with jax.named_scope(SCOPE):
+            prep, pull = jax.vjp(
+                lambda *x: _prepare(*x, *inputs[5:], mm_dtype), *inputs[:5])
+        _, (_, states) = jax.lax.scan(
+            functools.partial(_chunk_fwd, mm_dtype), state, prep)
+        dstate, dprep = jax.lax.scan(
+            functools.partial(_chunk_bwd, mm_dtype), dstate,
+            (prep, states, dout), reverse=True)
+        with jax.named_scope(SCOPE):
+            return dstate, pull(dprep)
+
+    _, grads = jax.lax.scan(
+        body, jnp.zeros_like(starts[0]),
+        (starts, dout, q, k, v, g, beta, rid, prev), reverse=True)
+    zero = jax.custom_derivatives.zero_from_primal
+    return (*grads, zero(rid), zero(prev))
+
+
+_blocks.defvjp(_blocks_fwd, _blocks_bwd)
+
+
+def chunk_counts(seq_len: int, chunk: int, block: int) -> Tuple[int, int]:
+    """(chunks a row is computed in, chunks a block): the row is padded to
+    whole blocks."""
+    n = -(-seq_len // chunk)
+    per_block = min(block, n)
+    return -(-n // per_block) * per_block, per_block
+
+
+def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+             beta: jax.Array, starts: jax.Array, chunk: int = 64,
+             block: int = 32, mm_dtype=jnp.bfloat16) -> jax.Array:
+    """The recurrence of the module docstring over rows: q, k (B, S, H, Dk),
+    v (B, S, H, Dv), g (B, S, H, Dk) float32 log-decays, beta (B, S, H),
+    starts (B, S) bool, true where the state restarts BEFORE the token (a
+    document's first token; every padding slot). Returns o (B, S, H, Dv)
+    float32. `chunk` tokens a chunk, `block` chunks a block (memory: one
+    block's temporaries are alive)."""
+    with jax.named_scope(SCOPE):
+        b, s, h, _ = q.shape
+        n, per_block = chunk_counts(s, chunk, block)
+        pad = n * chunk - s
+
+        def chunked(x, fill=0):
+            # (B, S, ...) -> (blocks, chunks, B, ..., C, trailing)
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2),
+                        constant_values=fill)
+            x = x.reshape((b, n // per_block, per_block, chunk)
+                          + x.shape[2:])
+            return jnp.moveaxis(x, 0, 2)
+
+        def heads_first(x):     # (..., B, C, H, D) -> (..., B, H, C, D)
+            return jnp.swapaxes(chunked(x), 3, 4)
+
+        # a padded slot restarts the state: it touches nothing before it
+        run = jnp.cumsum(jnp.pad(starts, ((0, 0), (0, pad)),
+                                 constant_values=True), axis=1,
+                         dtype=jnp.int32)
+        before = jnp.pad(run, ((0, 0), (1, 0)))[:, :-1:chunk]   # (B, n)
+        rid = jnp.moveaxis(run.reshape(b, n // per_block, per_block, chunk),
+                           0, 2)
+        prev = jnp.moveaxis(before.reshape(b, n // per_block, per_block),
+                            0, 2)
+        out = _blocks(heads_first(q), heads_first(k), heads_first(v),
+                      heads_first(g),
+                      jnp.swapaxes(chunked(beta.astype(jnp.float32)), 3, 4),
+                      rid, prev, mm_dtype)
+        # (blocks, chunks, B, H, C, Dv) -> (B, S, H, Dv)
+        out = jnp.moveaxis(jnp.swapaxes(out, 3, 4), 2, 0)
+        return out.reshape(b, n * chunk, h, -1)[:, :s]

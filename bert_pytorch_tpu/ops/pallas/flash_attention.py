@@ -63,7 +63,9 @@ than q — the decoder families, models/lfm2_moe.py): a query attends to
 earlier-or-equal positions only, of its own segment where rows are packed.
 The mask is one more condition of the same `jnp.where`, and a (q, k) tile
 that lies wholly above the diagonal is skipped like a tile of disjoint
-segments. With H query heads over H/G key/value heads the kernels read the
+segments. Values may have a width of their own (latent attention,
+models/kimi_linear.py: keys of 192, values of 128): the kernels slice v, dO
+and the output at that width, in the bh layout with the split backward. With H query heads over H/G key/value heads the kernels read the
 key/value head of a query head through the block index maps (no repeated
 copy of K and V); dk and dv come out per query head in float32 and the G of
 a group are summed outside. Both take the bh layout whatever the shape.
@@ -362,6 +364,7 @@ def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
     row, group, qi = ids
     bq = q_ref.shape[1]
     d = q_ref.shape[2] // heads_per_prog
+    dv = v_ref.shape[2] // heads_per_prog   # the values' own width
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
     q0 = qi * bq if causal else 0   # first row of this tile (causal only)
@@ -377,6 +380,7 @@ def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
 
     for t in range(heads_per_prog):
         lanes = slice(t * d, (t + 1) * d)
+        vlanes = slice(t * dv, (t + 1) * dv)
         bh = row * heads_per_row + group * heads_per_prog + t
         # matmul inputs stay in the stored dtype (bf16): the MXU multiplies
         # bf16 x bf16 into an fp32 accumulator at full rate, while fp32
@@ -386,14 +390,14 @@ def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
         q, tile_scale = _scale_operand(q_ref[0, :, lanes], scale)
         carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
                  jnp.zeros((bq, 1), jnp.float32),
-                 jnp.zeros((bq, d), jnp.float32))
+                 jnp.zeros((bq, dv), jnp.float32))
 
         for j in range(nk):
 
-            def tile(carry, lanes=lanes, bh=bh, j=j, q=q):
+            def tile(carry, lanes=lanes, vlanes=vlanes, bh=bh, j=j, q=q):
                 m, l, acc = carry
                 kb = k_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
-                vb = v_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
+                vb = v_ref[0, j * blk_k:(j + 1) * blk_k, vlanes]
                 s = jax.lax.dot_general(
                     q, kb, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -437,7 +441,7 @@ def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
             # (B, S, E) hiddens (K-FAC factor taps) bit-independent of the
             # kernel configuration.
             out = jnp.where(segq > 0, out, 0.0)
-        o_ref[0, :, lanes] = out.astype(o_ref.dtype)
+        o_ref[0, :, vlanes] = out.astype(o_ref.dtype)
         lse_ref[0, 0, t, :] = (m + jnp.log(l_safe))[:, 0]
 
 
@@ -786,10 +790,12 @@ class _Layout(NamedTuple):
         return row if self.native else row // self.heads
 
 
-def _layout(b: int, s: int, h: int, d: int, group: int = 1) -> _Layout:
+def _layout(b: int, s: int, h: int, d: int, group: int = 1,
+            dv: int = 0) -> _Layout:
     """`group` query heads to a key/value head: grouped heads take the bh
-    layout, where a program's key/value head is a block index."""
-    if group == 1 and _use_native(s, h, d):
+    layout, where a program's key/value head is a block index; so do values
+    of another width `dv` than the keys' (latent attention)."""
+    if group == 1 and dv in (0, d) and _use_native(s, h, d):
         hp = _heads_per_prog(h, d)
         return _Layout(True, h, b, h // hp, hp)
     return _Layout(False, h, b * h, 1, 1)
@@ -843,13 +849,14 @@ def _sum_groups(x, b: int, hkv: int, group: int, dtype):
 def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
                     dropout_rate: float = 0.0, interpret: bool = False,
                     causal: bool = False):
-    """q: (B, S, H, D); k/v: (B, S, Hkv, D) with H a multiple of Hkv (query
-    head i reads key/value head i // (H // Hkv)); `causal`: a query attends
+    """q: (B, S, H, D); k: (B, S, Hkv, D), v: (B, S, Hkv, Dv) with H a
+    multiple of Hkv (query head i reads key/value head i // (H // Hkv)) and
+    Dv = D but for latent attention; `causal`: a query attends
     to positions <= its own. bias: (B, 1, 1, S) additive or None;
     segment_ids: (B, S) int32 packing segments (1..n, 0 = pad) or None —
     attention is restricted to q_seg == k_seg blocks, the packed-sequence
     block-diagonal mask. dropout_seed: () or (1,) int32 array (traced OK);
-    required when dropout_rate > 0. Returns (B, S, H, D) in q.dtype.
+    required when dropout_rate > 0. Returns (B, S, H, Dv) in q.dtype.
 
     NOTE: bias is treated as NON-differentiable (its cotangent is zero) —
     it exists for padding masks, which are data, not parameters. A trainable
@@ -861,11 +868,18 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
     return out
 
 
+def _kernel_name(name: str, d: int, dv: int) -> str:
+    """The kernels at values of a width of their own (latent attention:
+    keys 192, values 128) carry names of their own in the HLO and the
+    device trace, so that what reads `flash_fwd` never reads them."""
+    return name if d == dv else "mla_" + name
+
+
 def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
                causal=False):
     b, s, h, d = q.shape
-    hkv = k.shape[2]
-    if h % hkv or k.shape != v.shape:
+    hkv, dv = k.shape[2], v.shape[3]
+    if h % hkv or k.shape[:3] != v.shape[:3]:
         raise ValueError(f"flash_attention: {h} query heads over k"
                          f"{tuple(k.shape)} v{tuple(v.shape)}")
     kv_row = _kv_row(h, hkv)
@@ -877,7 +891,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_segments = segment_ids is not None
-    lay = _layout(b, s, h, d, h // hkv)
+    lay = _layout(b, s, h, d, h // hkv, dv)
     hp = lay.heads_per_prog
     lanes = hp * d
     # shared by both layouts: the cross-layout bit-parity contract depends
@@ -889,6 +903,8 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
 
     q_bs = pl.BlockSpec((1, blk_q, lanes), lambda r, g, qi: (r, qi, g))
     kv_bs = pl.BlockSpec((1, s, lanes), lambda r, g, qi: (kv_row(r), 0, g))
+    v_bs = pl.BlockSpec((1, s, hp * dv), lambda r, g, qi: (kv_row(r), 0, g))
+    o_bs = pl.BlockSpec((1, blk_q, hp * dv), lambda r, g, qi: (r, qi, g))
     skip_rows = _skip_pad_rows(has_segments, (s // blk_q) * (s // blk_k))
     live_spec, live = _live_rows(seg2, skip_rows)
     out, lse = pl.pallas_call(
@@ -901,7 +917,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
         grid=(lay.rows, lay.groups, s // blk_q),
         in_specs=live_spec + [
             pl.BlockSpec((1,), lambda r, g, qi: (0,)),      # seed
-            q_bs, kv_bs, kv_bs,
+            q_bs, kv_bs, v_bs,
             _per_batch_spec(has_bias, s,
                             lambda r, g, qi: (lay.batch(r), 0, 0)),
             _per_batch_spec(has_segments, blk_q,
@@ -910,14 +926,15 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
                             lambda r, g, qi: (lay.batch(r), 0, 0)),
         ],
         out_specs=[
-            q_bs,
+            o_bs,
             pl.BlockSpec((1, 1, hp, blk_q), lambda r, g, qi: (r, g, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qx.shape, q.dtype),
+            jax.ShapeDtypeStruct(qx.shape[:2] + (qx.shape[2] // d * dv,),
+                                 q.dtype),
             jax.ShapeDtypeStruct((lay.rows, lay.groups, hp, s), jnp.float32),
         ],
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", d, dv),
         interpret=interpret,
         **_long_seq_params(s, lanes),
     )(*live, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
@@ -927,7 +944,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
         # results and does not run it again
         out = checkpoint_name(out, "flash_out")
         lse = checkpoint_name(lse, "flash_lse")
-    return lay.unpack(out, b, s, d), (qx, kx, vx, bias2, seg2, lse, out)
+    return lay.unpack(out, b, s, dv), (qx, kx, vx, bias2, seg2, lse, out)
 
 
 def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret,
@@ -945,6 +962,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         has_segments = saved
     b, s, h, d = qshape
     hkv = kx.size // (b * s * d)
+    dv = vx.size // (b * s * hkv)
     group = h // hkv
     kv_row = _kv_row(h, hkv)
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
@@ -952,7 +970,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
     skip_rows = _skip_pad_rows(has_segments, (s // blk_q) * (s // blk_k))
     live_spec, live = _live_rows(seg2, skip_rows)
     scale = 1.0 / (d ** 0.5)
-    lay = _layout(b, s, h, d, group)
+    lay = _layout(b, s, h, d, group, dv)
     hp = lay.heads_per_prog
     lanes = hp * d
     gx = lay.pack(g)
@@ -961,7 +979,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
     # delta = rowsum(dO * O) per head (cheap elementwise — jnp, not a kernel)
     delta = jnp.sum(
         (gx.astype(jnp.float32) * outx.astype(jnp.float32))
-        .reshape(lay.rows, s, lay.groups, hp, d), axis=-1
+        .reshape(lay.rows, s, lay.groups, hp, dv), axis=-1
     ).transpose(0, 2, 3, 1)
     if rate > 0.0:
         # the kernels subtract it from the UNscaled dp of the kept pairs and
@@ -972,7 +990,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
               has_segments=has_segments, **({"causal": True} if causal
                                             else {}))
 
-    if s * lanes <= _FUSED_BWD_MAX_PANEL:
+    if s * lanes <= _FUSED_BWD_MAX_PANEL and dv == d:
         # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated
         # once instead of twice
         qkv_bs = pl.BlockSpec((1, s, lanes), lambda r, g: (r, 0, g))
@@ -980,7 +998,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
                                 lambda r, g: (kv_row(r), 0, g))
         stat_bs = pl.BlockSpec((1, 1, hp, s), lambda r, g: (r, g, 0, 0))
         per_batch = lambda r, g: (lay.batch(r), 0, 0)  # noqa: E731
-        dq, dk, dv = pl.pallas_call(
+        dq, dk, dvx = pl.pallas_call(
             _program(
                 functools.partial(_dqkv_kernel, blk_q=blk_q, blk_k=blk_k,
                                   heads_per_prog=hp,
@@ -1001,17 +1019,22 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             interpret=interpret,
         )(*live, seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx)
     else:
-        # split kernels, bh layout only (_use_native excludes these shapes)
+        # split kernels, bh layout only (_use_native excludes these shapes;
+        # values of a width of their own take them at any length)
         lse = lse.reshape(b * h, 1, s)
         delta = delta.reshape(b * h, 1, s)
         row_bs = pl.BlockSpec((1, 1, s), lambda bh, i: (bh, 0, 0))
         full_bs = pl.BlockSpec((1, s, d), lambda bh, i: (bh, 0, 0))
+        do_full_bs = pl.BlockSpec((1, s, dv), lambda bh, i: (bh, 0, 0))
         kv_full_bs = pl.BlockSpec((1, s, d),
                                   lambda bh, i: (kv_row(bh), 0, 0))
+        v_full_bs = pl.BlockSpec((1, s, dv),
+                                 lambda bh, i: (kv_row(bh), 0, 0))
         per_batch = lambda bh, i: (bh // h, 0, 0)  # noqa: E731
         per_batch_blk = lambda bh, i: (bh // h, 0, i)  # noqa: E731
 
         blk_bs = pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0))
+        do_blk_bs = pl.BlockSpec((1, blk_q, dv), lambda bh, qi: (bh, qi, 0))
         stat_blk_bs = pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi))
         dq = pl.pallas_call(
             _program(functools.partial(_dq_kernel, blk_k=blk_k, **kw),
@@ -1019,37 +1042,41 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             grid=(b * h, s // blk_q),
             in_specs=live_spec + [
                 pl.BlockSpec((1,), lambda bh, qi: (0,)),
-                blk_bs, kv_full_bs, kv_full_bs,
+                blk_bs, kv_full_bs, v_full_bs,
                 _per_batch_spec(has_bias, s, per_batch),
                 _per_batch_spec(has_segments, blk_q, per_batch_blk),
                 _per_batch_spec(has_segments, s, per_batch),
-                stat_blk_bs, stat_blk_bs, blk_bs,
+                stat_blk_bs, stat_blk_bs, do_blk_bs,
             ],
             out_specs=blk_bs,
             out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
-            name="flash_bwd_dq",
+            name=_kernel_name("flash_bwd_dq", d, dv),
             interpret=interpret,
             **_long_seq_params(s, lanes),
         )(*live, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
 
         blk_bs = pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0))
+        dv_blk_bs = pl.BlockSpec((1, blk_k, dv), lambda bh, kj: (bh, kj, 0))
         kv_blk_bs = pl.BlockSpec((1, blk_k, d),
                                  lambda bh, kj: (kv_row(bh), kj, 0))
-        dk, dv = pl.pallas_call(
+        v_blk_bs = pl.BlockSpec((1, blk_k, dv),
+                                lambda bh, kj: (kv_row(bh), kj, 0))
+        dk, dvx = pl.pallas_call(
             _program(functools.partial(_dkv_kernel, blk_q=blk_q, **kw),
                      2, 2, lay.batch if skip_rows else None),
             grid=(b * h, s // blk_k),
             in_specs=live_spec + [
                 pl.BlockSpec((1,), lambda bh, kj: (0,)),
-                full_bs, kv_blk_bs, kv_blk_bs,
+                full_bs, kv_blk_bs, v_blk_bs,
                 _per_batch_spec(has_bias, blk_k, per_batch_blk),
                 _per_batch_spec(has_segments, s, per_batch),
                 _per_batch_spec(has_segments, blk_k, per_batch_blk),
-                row_bs, row_bs, full_bs,
+                row_bs, row_bs, do_full_bs,
             ],
-            out_specs=[blk_bs, blk_bs],
-            out_shape=[jax.ShapeDtypeStruct(qx.shape, dkv_dtype)] * 2,
-            name="flash_bwd_dkv",
+            out_specs=[blk_bs, dv_blk_bs],
+            out_shape=[jax.ShapeDtypeStruct(qx.shape, dkv_dtype),
+                       jax.ShapeDtypeStruct(gx.shape, dkv_dtype)],
+            name=_kernel_name("flash_bwd_dkv", d, dv),
             interpret=interpret,
             **_long_seq_params(s, lanes),
         )(*live, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
@@ -1066,10 +1093,10 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
     dq = lay.unpack(dq, b, s, d)
     if group > 1:
         dk = _from_bh(_sum_groups(dk, b, hkv, group, kx.dtype), b, hkv)
-        dv = _from_bh(_sum_groups(dv, b, hkv, group, kx.dtype), b, hkv)
+        dvx = _from_bh(_sum_groups(dvx, b, hkv, group, kx.dtype), b, hkv)
     else:
-        dk, dv = lay.unpack(dk, b, s, d), lay.unpack(dv, b, s, d)
-    return dq, dk, dv, dbias, dseg, dseed
+        dk, dvx = lay.unpack(dk, b, s, d), lay.unpack(dvx, b, s, dv)
+    return dq, dk, dvx, dbias, dseg, dseed
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -273,6 +273,10 @@ def default_weight_decay_mask(params: Any) -> Any:
         # bias (a buffer: zero gradient, and with no decay no update)
         if joined.endswith("_norm/scale") or joined.endswith("expert_bias"):
             return False
+        # kimi_linear's KDA mixer: the heads' norm gain, the decay's A_log
+        # and dt_bias, the output gate's bias
+        if str(keys[-1]) in ("o_norm", "A_log", "dt_bias", "g_bias"):
+            return False
         return True
 
     return jax.tree_util.tree_map_with_path(lambda p, _: is_decay(p), params)

@@ -1,7 +1,9 @@
 """Cumulative counters of the routed (mixture-of-experts) layers for the
 `[perf]` record, summed on the host from the per-expert scalars a step of
-the decoder families returns (models/lfm2_moe.pretrain_loss_fn_builder:
-`moe_l<layer>_e<expert>`, `moe_l<layer>_dropped`, `moe_pairs_routed`).
+the decoder families returns (models/lfm2_moe.expert_scalars:
+`moe_l<layer>_e<expert>`, `moe_l<layer>_dropped`, `moe_pairs_routed`), and
+of the gated delta-rule scans (models/kimi_linear.py: `kda_tokens`,
+`kda_resets`, passed on as they are summed).
 
 Per routed layer L, since the run began: `moe_l<L>_pairs` (token, expert)
 pairs routed to the experts held here; `moe_l<L>_load_min/_mean/_max` the
@@ -17,6 +19,7 @@ from typing import Dict
 
 _LOAD = re.compile(r"^moe_l(\d+)_e(\d+)$")
 _DROPPED = re.compile(r"^moe_l(\d+)_dropped$")
+_KDA = ("kda_tokens", "kda_resets")
 
 
 class ExpertLoadCounters:
@@ -24,6 +27,7 @@ class ExpertLoadCounters:
         self.load: Dict[int, Dict[int, float]] = {}
         self.dropped: Dict[int, float] = {}
         self.routed = 0.0
+        self.kda: Dict[str, float] = {}
 
     def update(self, vals: Dict[str, float]) -> None:
         """Add one step's scalars (a dict of the step's metrics; keys that
@@ -42,9 +46,11 @@ class ExpertLoadCounters:
                                        + float(value))
             elif key == "moe_pairs_routed":
                 self.routed += float(value)
+            elif key in _KDA:
+                self.kda[key] = self.kda.get(key, 0.0) + float(value)
 
     def fields(self) -> Dict[str, float]:
-        out = {}
+        out = dict(self.kda)
         for layer, loads in sorted(self.load.items()):
             values = [loads[e] for e in sorted(loads)]
             pairs = sum(values)

@@ -53,16 +53,19 @@ STEP_SCOPES = (
     "loss", "optimizer", "grad_norm", "health", "param_cast", "metrics",
     "encoder", "bert", "grad_accum",
 )
-# The same account for a step of the decoder families (models/lfm2_moe.py),
-# whose model opens other scopes: `rmsnorm` first (the q/k norms inside
-# `attention` are norms), `mlp` the dense FFN only, `moe` with its
-# `moe/router`, `moe/dispatch`, `moe/experts`, `moe/combine` inside, all
-# under `decoder`. The last two are not scopes of the program: XLA:TPU
+# The same account for a step of the decoder families (models/lfm2_moe.py,
+# models/kimi_linear.py), whose models open other scopes: `kda` first (the
+# gated delta-rule mixer whole: `kda/conv`, `kda/gates`, `kda/scan`,
+# `kda/out` inside), then `rmsnorm` (the q/k norms inside `attention` are
+# norms), `mlp` the dense FFN only, `moe` with its `moe/router`,
+# `moe/dispatch`, `moe/experts`, `moe/combine` (and kimi_linear's
+# `moe/shared`) inside, all under `decoder`. The last two are not scopes of the program: XLA:TPU
 # lowers `lax.ragged_dot` (ops/moe.py's grouped products) to kernels of its
 # own whose `op_name` is the compiler's and carries no scope. The
-# benchmark's unscoped_share.lm.train carries a copy of the list.
+# benchmark's unscoped_share.kimi.train carries a copy of the list, and
+# unscoped_share.lm.train of the list without `kda` (lfm2 has none).
 LM_STEP_SCOPES = (
-    "rmsnorm", "moe", "conv", "attention", "mlp", "lm_head", "loss",
+    "kda", "rmsnorm", "moe", "conv", "attention", "mlp", "lm_head", "loss",
     "embeddings", "optimizer", "grad_norm", "health", "param_cast",
     "metrics", "decoder", "grad_accum",
     "ragged-dot-none", "ragged-dot-metadata",
@@ -699,10 +702,18 @@ def build_pretrain_step(
 
     if rs:
         one_micro = _build_rs_micro(model, zero1, max_predictions)
+
+        def aux_of(params, micro: Batch, rng):
+            return one_micro(params, micro, rng)[1]
     else:
         def one_micro(params, micro: Batch, rng):
             (loss, aux), grads = grad_fn(params, micro, rng)
             return loss, aux, grads
+
+        def aux_of(params, micro: Batch, rng):
+            # the forward pass alone says what aux holds; tracing the
+            # gradient too for its shapes would double the step's tracing
+            return loss_fn(params, micro, rng)[1]
 
     def train_step(state: TrainState, batch: Batch, rng: jax.Array):
         gparams = _use_params(state, zero1, cast_params, nan_inject_step)
@@ -758,9 +769,7 @@ def build_pretrain_step(
                 return carry, None
 
             micro0 = jax.tree.map(lambda x: x[0], batch)
-            aux_shape = jax.eval_shape(
-                lambda p, m, r: one_micro(p, m, r)[1],
-                gparams, micro0, rngs[0])
+            aux_shape = jax.eval_shape(aux_of, gparams, micro0, rngs[0])
             aux_zeros = jax.tree.map(
                 lambda sd: jnp.zeros(sd.shape, sd.dtype), aux_shape)
             init = (zeros, jnp.zeros([], jnp.float32), aux_zeros)

@@ -17,7 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from bert_pytorch_tpu.config import (MODEL_FAMILIES, BertConfig,  # noqa: E402
-                                     Lfm2MoeConfig)
+                                     KimiLinearConfig, Lfm2MoeConfig)
 from bert_pytorch_tpu.models.families import FAMILIES, family_of  # noqa: E402
 
 VOCAB, SEQ = 2048, 64
@@ -34,6 +34,18 @@ LFM2_TOY = {
     "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
     "max_position_embeddings": 1024,
 }
+KIMI_TOY = {
+    "model_type": "kimi_linear", "vocab_size": VOCAB, "hidden_size": 32,
+    "intermediate_size": 64, "moe_intermediate_size": 16,
+    "num_hidden_layers": 2, "layers_kept": [3, 4], "first_k_dense_replace": 1,
+    "num_attention_heads": 2, "num_key_value_heads": 2, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 8,
+    "linear_attn_config": {"kda_layers": [1, 2, 3], "full_attn_layers": [4],
+                           "num_heads": 2, "head_dim": 8,
+                           "short_conv_kernel_size": 4},
+    "num_experts": 2, "experts_total": 4, "experts_held": [0, 2],
+    "num_experts_per_token": 2, "kda_chunk_size": 16,
+}
 TINY = {
     "bert": BertConfig(
         vocab_size=VOCAB, hidden_size=32, num_hidden_layers=2,
@@ -41,6 +53,8 @@ TINY = {
         max_position_embeddings=SEQ, dtype="float32", fused_ops=False,
         attention_impl="xla"),
     "lfm2_moe": Lfm2MoeConfig.from_dict(LFM2_TOY).replace(dtype="float32"),
+    "kimi_linear": KimiLinearConfig.from_dict(KIMI_TOY).replace(
+        dtype="float32"),
 }
 
 
@@ -122,11 +136,14 @@ def test_record_builds_initialises_and_steps_its_family(name, tmp_path):
 @pytest.mark.parametrize("flags", [
     ["--kfac"], ["--stream_dir", "corpus"], ["--stacked_params", "true"],
     ["--steps_per_loop", "2"]], ids=lambda f: f[0].lstrip("-"))
-def test_lfm2_moe_refuses_what_it_cannot_run_with(flags, tmp_path):
+@pytest.mark.parametrize("toy", [LFM2_TOY, KIMI_TOY],
+                         ids=["lfm2_moe", "kimi_linear"])
+def test_decoder_families_refuse_what_they_cannot_run_with(flags, toy,
+                                                           tmp_path):
     import run_pretraining
 
     cfg_path = tmp_path / "toy.json"
-    cfg_path.write_text(json.dumps(LFM2_TOY))
+    cfg_path.write_text(json.dumps(toy))
     argv = ["--model_config_file", str(cfg_path), "--output_dir",
             str(tmp_path / "out"), "--tensorboard", "off"] + flags
     if "--stream_dir" not in flags:
@@ -134,7 +151,7 @@ def test_lfm2_moe_refuses_what_it_cannot_run_with(flags, tmp_path):
     with pytest.raises(SystemExit) as e:
         run_pretraining.main(argv)
     message = str(e.value)
-    assert "model_type 'lfm2_moe'" in message
+    assert "'lfm2_moe', 'kimi_linear'" in message
     for flag in ("--kfac", "--stream_dir", "--stacked_params",
                  "--steps_per_loop"):
         assert flag in message
@@ -147,6 +164,8 @@ def test_bert_refuses_none_of_them():
                               stacked_params="true", steps_per_loop=2)
     assert FAMILIES["bert"].refusal(args) is None
     assert FAMILIES["lfm2_moe"].refusal(args)
+    # the decoder families' ONE refusal
+    assert FAMILIES["kimi_linear"].refusal is FAMILIES["lfm2_moe"].refusal
     args = argparse.Namespace(kfac=False, stream_dir=None,
                               stacked_params="auto", steps_per_loop=1)
     assert FAMILIES["lfm2_moe"].refusal(args) is None
