@@ -17,9 +17,8 @@ Layout contract: q/k/v are (B, S, H, D); bias broadcastable (B, 1, 1, S)
 additive mask. S must divide by the q/k block size (ops/attention.py gates).
 
 Two kernel-grid layouts exist behind the same public function, chosen by
-shape alone (`_use_native`) and served by the SAME forward / fused-backward
-kernels, which address a (rows, S, lanes) array on a (rows, head-groups, ...)
-grid:
+shape alone (`_use_native`); the fused backward kernel serves both, the
+forward has a kernel for each:
 
 - **native** (wherever heads tile into 128-lane blocks and the fused
   backward fits them — every BERT-Base/Large shape up to S=1024): the kernels
@@ -30,7 +29,21 @@ grid:
   slices. No (B,S,H,D)->(BH,S,D) transpose pass on q/k/v/do/outputs.
 - **bh** (other head shapes, and S*D beyond the fused backward's VMEM
   bound, where the split backward kernels take over): the (BH, S, D) view
-  with a transpose pass either side — one head per program, unbounded S.
+  with a transpose pass either side, unbounded S. A program owns several
+  heads (`_bh_heads_per_prog`: the query heads of a key/value head, or as
+  many heads as the kernels' VMEM holds panels of), as (heads, blk, D)
+  blocks of the same arrays, and walks the k blocks (the q blocks in the dkv
+  kernel) OUTSIDE, unrolled, and its heads INSIDE, as a rolled loop under
+  ONE skip test (`_each_head`): whether a (q block, k block) pair holds an
+  allowed pair is a function of the row and the two blocks alone. A
+  long-sequence program is S / blk unrolled tile bodies, each run at most
+  once a head, and with one head a program its instructions were fetched
+  anew for every program: what the kernels lost their time to on the v5e
+  was not the tiles' arithmetic but their instructions (PERF.md section 6,
+  PR 34: with the heads unrolled beside each other, four times the code, the
+  kernels ran 25-40 % SLOWER; rolled, the same tiles run in half the time).
+  A pair's tile is now fetched once and run for every head of the program.
+  The fused backward keeps one head a program.
 
 Both layouts draw identical dropout masks (the kernels' counter is
 batch * H + head in either addressing), so they are the same training run.
@@ -65,10 +78,12 @@ The mask is one more condition of the same `jnp.where`, and a (q, k) tile
 that lies wholly above the diagonal is skipped like a tile of disjoint
 segments. Values may have a width of their own (latent attention,
 models/kimi_linear.py: keys of 192, values of 128): the kernels slice v, dO
-and the output at that width, in the bh layout with the split backward. With H query heads over H/G key/value heads the kernels read the
-key/value head of a query head through the block index maps (no repeated
-copy of K and V); dk and dv come out per query head in float32 and the G of
-a group are summed outside. Both take the bh layout whatever the shape.
+and the output at that width. With H query heads over H/G key/value heads a
+program owns the G query heads of ONE key/value head: it fetches that head's
+K and V panels once (no repeated copy of K and V), and the dkv kernel adds
+the group's dk and dv in its float32 accumulators and writes one block a
+key/value head, in the parameters' dtype. Both take the bh layout and the
+split backward whatever the shape.
 """
 
 from __future__ import annotations
@@ -178,22 +193,47 @@ def _seg_overlap(qrange, krange):
     return (qmx > 0) & (kmx > 0) & (qmx >= kmn) & (kmx >= qmn)
 
 
-def _maybe_skip(tile_fn, carry, qrange=None, krange=None, live=None):
-    """Run tile_fn(carry) -> carry, skipping it when the blocks' segment
-    ranges prove the tile all-masked. Without segments (ranges None, also
-    under FLASH_SEG_SKIP=0) the tile always runs; masked tiles then
-    contribute exact zeros, so both settings produce bit-identical non-pad
-    outputs. `live` (causal attention): a scalar that is false where the
-    tile lies wholly above the diagonal; None where attention is
-    bidirectional."""
+def _skip_pred(qrange=None, krange=None, live=None):
+    """Scalar bool, or None where the tile always runs: does the (q, k) tile
+    hold any allowed pair? By the blocks' segment ranges (None without
+    segments, also under FLASH_SEG_SKIP=0) and by `live` (causal attention:
+    false where the tile lies wholly above the diagonal; None where
+    attention is bidirectional). A tile that runs all masked contributes
+    exact zeros, so skipped and masked are bit-identical on non-pad rows."""
     pred = None
     if qrange is not None:
         pred = _seg_overlap(qrange, krange)
     if live is not None:
         pred = live if pred is None else pred & live
+    return pred
+
+
+def _maybe_skip(tile_fn, carry, qrange=None, krange=None, live=None):
+    """Run tile_fn(carry) -> carry unless `_skip_pred` proves the tile
+    all-masked."""
+    pred = _skip_pred(qrange, krange, live)
     if pred is None:
         return tile_fn(carry)
     return jax.lax.cond(pred, tile_fn, lambda c: c, carry)
+
+
+def _each_head(heads: int, body, qrange=None, krange=None, live=None):
+    """Run body(t) for t in 0 .. heads - 1 (its results go to refs) unless
+    `_skip_pred` proves the heads' tiles of this (q block, k block) pair
+    all-masked: ONE test for every head of the program, and the heads a
+    ROLLED loop, so that the pair's tile is compiled once and its
+    instructions, once fetched, serve every head."""
+    def run():
+        if heads == 1:
+            body(0)
+        else:
+            jax.lax.fori_loop(0, heads, lambda t, _: body(t), None)
+
+    pred = _skip_pred(qrange, krange, live)
+    if pred is None:
+        run()
+    else:
+        pl.when(pred)(run)
 
 
 def _tile_skip(has_segments: bool, tiles: int) -> bool:
@@ -230,20 +270,56 @@ def _live_rows(seg2, skip_pad_rows: bool) -> tuple:
     return [pl.BlockSpec(memory_space=pltpu.SMEM)], [live]
 
 
-def _program(kernel, grid_rank: int, n_out: int, batch_of=None):
+def _block_ranges(seg2, blk_q: int, blk_k: int, wanted: bool) -> tuple:
+    """(in_specs, operands) of the operand a bh-layout kernel reads its
+    blocks' `_seg_range`s from where it skips tiles (`_tile_skip`):
+    (B, 2, S / blk_q + S / blk_k) int32 in SMEM, the q blocks' (min, max)
+    first, then the k blocks'. Taken here, once a call, for the reason
+    `_live_rows` gives: inside a kernel each range is a vector reduction
+    that travels to the scalar unit, 2 + 2 S / blk of them a program. Both
+    empty where not `wanted`."""
+    if not wanted:
+        return [], []
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, s = seg2.shape
+
+    def ranges(blk):
+        ids = seg2.reshape(b, s // blk, blk)
+        return jnp.stack([jnp.min(jnp.where(ids > 0, ids, _SEG_BIG), axis=-1),
+                          jnp.max(ids, axis=-1)], axis=1)
+
+    both = jnp.concatenate([ranges(blk_q), ranges(blk_k)], axis=-1)
+    return [pl.BlockSpec(memory_space=pltpu.SMEM)], [both]
+
+
+def _block_range(ranges_ref, batch, block):
+    """A block's `_seg_range` from `_block_ranges`' operand; None where the
+    kernel has none, because it skips no tile."""
+    if ranges_ref is None:
+        return None
+    return ranges_ref[batch, 0, block], ranges_ref[batch, 1, block]
+
+
+def _program(kernel, grid_rank: int, n_out: int, batch_of=None,
+             ranges: bool = False, n_scratch: int = 0):
     """The body of a pallas_call from `kernel(ids, *refs)`: `ids` is the
     program's grid position, read here, at the top (interpret mode
     resolves `program_id` nowhere else). With `batch_of` (grid row ->
     batch index; `_skip_pad_rows`) the first operand is `_live_rows` and a
     row of nothing but pad — an empty slot of a batch of fixed size — is
-    skipped: its program writes zeros to its `n_out` outputs and runs
-    nothing else. ONE test around the whole program, so the heads' tiles
-    inside stay one block for the scheduler. A skipped row's logsumexp
-    reads 0 and no kernel reads it back, its backward program being
-    skipped by the same test."""
+    skipped: its program writes zeros to its `n_out` outputs (the refs
+    before the last `n_scratch`) and runs nothing else. ONE test around the
+    whole program, so the heads' tiles inside stay one block for the
+    scheduler. A skipped row's logsumexp reads 0 and no kernel reads it
+    back, its backward program being skipped by the same test. With
+    `ranges` the first operand is `_block_ranges`', handed to the kernel as
+    `ranges_ref` (never both: a kernel skips tiles or whole rows)."""
 
     def program(*refs):
         ids = tuple(pl.program_id(axis) for axis in range(grid_rank))
+        if ranges:
+            return kernel(ids, *refs[1:], ranges_ref=refs[0])
         if batch_of is None:
             return kernel(ids, *refs)
         live = refs[0][batch_of(ids[0])] > 0
@@ -252,7 +328,8 @@ def _program(kernel, grid_rank: int, n_out: int, batch_of=None):
 
         @pl.when(jnp.logical_not(live))
         def _():
-            for ref in refs[-n_out:]:
+            for ref in refs[len(refs) - n_scratch - n_out:
+                            len(refs) - n_scratch]:
                 ref[...] = jnp.zeros(ref.shape, ref.dtype)
 
     return program
@@ -349,22 +426,66 @@ def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
     return _keep_tile(_keep_rows(q0, bq), _keep_cols(seed, bh, k0, bk), rate)
 
 
+def _fwd_tile(carry, q, kb, vb, tile_scale, bias, pos, segq, segk, keep,
+              rate: float):
+    """One (blk_q, blk_k) tile of the online softmax: (m, l, acc) with the
+    keys kb and values vb taken in. `bias` (None, or the pad bias's
+    (1, blk_k) row), `pos` (`_causal_pos`) and `keep` (the dropout keep
+    mask, rate > 0) are thunks, called where the tile needs them.
+    Matmul inputs stay in the stored dtype (bf16): the MXU multiplies
+    bf16 x bf16 into an fp32 accumulator at full rate, while fp32 inputs
+    run at a fraction of it. Softmax statistics and accumulators are fp32 —
+    identical numerics to the XLA attention path (probs cast to the compute
+    dtype before the PV matmul)."""
+    m, l, acc = carry
+    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if tile_scale is not None:
+        s = s * tile_scale
+    if bias is not None:
+        s = s + bias()
+    s = _mask(s, *pos(), segq, segk)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    p_acc = jnp.where(keep(), p, 0.0) if rate > 0.0 else p
+    acc = acc * alpha + jnp.dot(p_acc.astype(vb.dtype), vb,
+                                preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def _fwd_out(l, acc, segq, rate: float):
+    """A q block's output from its last (l, acc), and l floored: its
+    logsumexp is m + log of that."""
+    l_safe = jnp.maximum(l, 1e-30)
+    out = acc / l_safe
+    if rate > 0.0:
+        out = out / (1.0 - rate)
+    if segq is not None:
+        # pad (segment-0) rows attend nowhere; without this their softmax
+        # degenerates to skip-/tile-layout-dependent garbage (uniform over
+        # whatever tiles ran). Zeroing makes every path — skip on/off, both
+        # layouts, XLA fallback — emit identical pad activations, which
+        # keeps downstream consumers of full (B, S, E) hiddens (K-FAC
+        # factor taps) bit-independent of the kernel configuration.
+        out = jnp.where(segq > 0, out, 0.0)
+    return out, l_safe
+
+
 def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                 segk_ref, o_ref, lse_ref, *, scale: float, blk_k: int,
                 rate: float, has_bias: bool, has_segments: bool,
                 heads_per_prog: int, heads_per_row: int,
                 causal: bool = False):
-    """One program per (row, head group, q-block) of a (rows, S, lanes)
-    array; it loops the `heads_per_prog` heads that share its lane block
-    (static lane slices of width D), then the k-blocks. Serves both layouts
-    (_Layout): `heads_per_row` is H for the native layout (a row is a batch
-    element) and 1 for bh (a row is already one (batch, head)), so the
-    dropout counter `row * heads_per_row + head` is the same
-    batch * H + head in both."""
+    """Native layout: one program per (batch row, lane block, q-block) of
+    the (B, S, H * D) view; it loops the `heads_per_prog` heads that share
+    its lane block (static lane slices of width D), then the k-blocks. The
+    dropout counter is batch * H + head (`heads_per_row` = H), as the bh
+    kernel's."""
     row, group, qi = ids
     bq = q_ref.shape[1]
     d = q_ref.shape[2] // heads_per_prog
-    dv = v_ref.shape[2] // heads_per_prog   # the values' own width
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
     q0 = qi * bq if causal else 0   # first row of this tile (causal only)
@@ -380,69 +501,101 @@ def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
 
     for t in range(heads_per_prog):
         lanes = slice(t * d, (t + 1) * d)
-        vlanes = slice(t * dv, (t + 1) * dv)
         bh = row * heads_per_row + group * heads_per_prog + t
-        # matmul inputs stay in the stored dtype (bf16): the MXU multiplies
-        # bf16 x bf16 into an fp32 accumulator at full rate, while fp32
-        # inputs run at a fraction of it. Softmax statistics and
-        # accumulators are fp32 — identical numerics to the XLA attention
-        # path (probs cast to the compute dtype before the PV matmul).
         q, tile_scale = _scale_operand(q_ref[0, :, lanes], scale)
         carry = (jnp.full((bq, 1), NEG_INF, jnp.float32),
                  jnp.zeros((bq, 1), jnp.float32),
-                 jnp.zeros((bq, dv), jnp.float32))
+                 jnp.zeros((bq, d), jnp.float32))
 
         for j in range(nk):
+            cols = slice(j * blk_k, (j + 1) * blk_k)
 
-            def tile(carry, lanes=lanes, vlanes=vlanes, bh=bh, j=j, q=q):
-                m, l, acc = carry
-                kb = k_ref[0, j * blk_k:(j + 1) * blk_k, lanes]
-                vb = v_ref[0, j * blk_k:(j + 1) * blk_k, vlanes]
-                s = jax.lax.dot_general(
-                    q, kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                if tile_scale is not None:
-                    s = s * tile_scale
-                if has_bias:
-                    s = s + bias_ref[0, 0,
-                                     j * blk_k:(j + 1) * blk_k][None, :]
-                s = _mask(s, *_causal_pos(causal, q0, j * blk_k, bq, blk_k),
-                          segq, segks[j])
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-                if rate > 0.0:
-                    keep = _keep_tile(
+            def tile(carry, lanes=lanes, bh=bh, j=j, cols=cols, q=q):
+                return _fwd_tile(
+                    carry, q, k_ref[0, cols, lanes], v_ref[0, cols, lanes],
+                    tile_scale,
+                    (lambda: bias_ref[0, 0, cols][None, :]) if has_bias
+                    else None,
+                    lambda: _causal_pos(causal, q0, j * blk_k, bq, blk_k),
+                    segq, segks[j],
+                    lambda: _keep_tile(
                         keep_rows,
-                        _keep_cols(seed_ref[0], bh, j * blk_k, blk_k), rate)
-                    p_acc = jnp.where(keep, p, 0.0)
-                else:
-                    p_acc = p
-                acc = acc * alpha + jnp.dot(
-                    p_acc.astype(vb.dtype), vb,
-                    preferred_element_type=jnp.float32)
-                return m_new, l, acc
+                        _keep_cols(seed_ref[0], bh, j * blk_k, blk_k), rate),
+                    rate)
 
             carry = _maybe_skip(tile, carry, qrange, kranges[j],
                                 _causal_live(causal, q0, bq, j * blk_k))
 
         m, l, acc = carry
-        l_safe = jnp.maximum(l, 1e-30)
-        out = acc / l_safe
-        if rate > 0.0:
-            out = out / (1.0 - rate)
-        if has_segments:
-            # pad (segment-0) rows attend nowhere; without this their
-            # softmax degenerates to skip-/tile-layout-dependent garbage
-            # (uniform over whatever tiles ran). Zeroing makes every path —
-            # skip on/off, both layouts, XLA fallback — emit identical pad
-            # activations, which keeps downstream consumers of full
-            # (B, S, E) hiddens (K-FAC factor taps) bit-independent of the
-            # kernel configuration.
-            out = jnp.where(segq > 0, out, 0.0)
-        o_ref[0, :, vlanes] = out.astype(o_ref.dtype)
+        out, l_safe = _fwd_out(l, acc, segq, rate)
+        o_ref[0, :, lanes] = out.astype(o_ref.dtype)
         lse_ref[0, 0, t, :] = (m + jnp.log(l_safe))[:, 0]
+
+
+# The bh-layout kernels. A program owns `hp` heads: q_ref / do_ref / the
+# outputs are (hp, blk, D) blocks of the (B * H, S, D) arrays, k_ref and
+# v_ref their heads' panels ((hp, S, D)) or, where the hp query heads are
+# the group of ONE key/value head, that head's ((1, S, D)); the per-head
+# row statistics (hp, 1, blk) blocks; the dropout counter of head t of grid
+# row r is r * hp + t = batch * H + head. Every kernel walks its k blocks
+# (q blocks in the dkv kernel) OUTSIDE, unrolled, and its heads INSIDE,
+# rolled (`_each_head`), with the heads' running results in VMEM scratch.
+
+
+def _kv_at(kv_ref, t):
+    """Where head t's panel is in a block of key/value panels."""
+    return t if kv_ref.shape[0] > 1 else 0
+
+
+def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+                   segk_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, blk_k: int, rate: float, has_bias: bool,
+                   has_segments: bool, batch_of, causal: bool = False,
+                   ranges_ref=None):
+    """One program per (grid row, q-block): the forward of its heads."""
+    row, _, qi = ids
+    hp, bq, _ = q_ref.shape
+    s_len = k_ref.shape[1]
+    nk = s_len // blk_k
+    q0 = qi * bq if causal else 0   # first row of this tile (causal only)
+    batch = batch_of(row)
+    # what belongs to the q block alone, once for every head and k block
+    segq = segq_ref[0, 0][:, None] if has_segments else None
+    qrange = _block_range(ranges_ref, batch, qi)
+    keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    for j in range(nk):
+        cols = slice(j * blk_k, (j + 1) * blk_k)
+        # and to a k block alone, once for every head
+        segk = (_seg_keys(segk_ref[0, 0, cols])[None, :]
+                if has_segments else None)
+
+        def head(t, j=j, cols=cols, segk=segk):
+            q, tile_scale = _scale_operand(q_ref[t], scale)
+            m_ref[t], l_ref[t], acc_ref[t] = _fwd_tile(
+                (m_ref[t], l_ref[t], acc_ref[t]), q,
+                k_ref[_kv_at(k_ref, t), cols, :],
+                v_ref[_kv_at(v_ref, t), cols, :], tile_scale,
+                (lambda: bias_ref[0, 0, cols][None, :]) if has_bias else None,
+                lambda: _causal_pos(causal, q0, j * blk_k, bq, blk_k),
+                segq, segk,
+                lambda: _keep_tile(
+                    keep_rows,
+                    _keep_cols(seed_ref[0], row * hp + t, j * blk_k, blk_k),
+                    rate),
+                rate)
+
+        _each_head(hp, head, qrange,
+                   _block_range(ranges_ref, batch, s_len // bq + j),
+                   _causal_live(causal, q0, bq, j * blk_k))
+
+    for t in range(hp):
+        out, l_safe = _fwd_out(l_ref[t], acc_ref[t], segq, rate)
+        o_ref[t] = out.astype(o_ref.dtype)
+        lse_ref[t, 0, :] = (m_ref[t] + jnp.log(l_safe))[:, 0]
 
 
 def _dropout_late(scale: float, rate: float) -> float:
@@ -456,99 +609,100 @@ def _dropout_late(scale: float, rate: float) -> float:
 
 
 def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
-               segk_ref, lse_ref, delta_ref, do_ref, dq_ref, *, scale: float,
-               blk_k: int, rate: float, has_bias: bool, has_segments: bool,
-               causal: bool = False):
-    bh, qi = ids
-    bq = q_ref.shape[1]
+               segk_ref, lse_ref, delta_ref, do_ref, dq_ref, acc_ref, *,
+               scale: float, blk_k: int, rate: float, has_bias: bool,
+               has_segments: bool, batch_of, causal: bool = False,
+               ranges_ref=None):
+    """One program per (grid row, q-block): dq of its heads."""
+    row, qi = ids
+    hp, bq, _ = q_ref.shape
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
     out_scale = _dropout_late(scale, rate)
-    skip = _tile_skip(has_segments, (s_len // bq) * nk)
+    batch = batch_of(row)
 
-    q, tile_scale = _scale_operand(q_ref[0], scale)
-    do = do_ref[0]
     segq = segq_ref[0, 0][:, None] if has_segments else None
-    qrange = _seg_range(segq_ref[0, 0][None, :]) if skip else None
+    qrange = _block_range(ranges_ref, batch, qi)
     keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
     q0 = qi * bq if causal else 0
-    lse = lse_ref[0, 0][:, None]
-    delta = delta_ref[0, 0][:, None]
-    dq = jnp.zeros((q.shape[0], q.shape[1]), jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     for j in range(nk):
-        segk = (_seg_keys(segk_ref[0, 0, j * blk_k:(j + 1) * blk_k])[None, :]
+        cols = slice(j * blk_k, (j + 1) * blk_k)
+        segk = (_seg_keys(segk_ref[0, 0, cols])[None, :]
                 if has_segments else None)
 
-        def tile(dq, j=j, segk=segk):
-            kb = k_ref[0, j * blk_k:(j + 1) * blk_k, :]
-            vb = v_ref[0, j * blk_k:(j + 1) * blk_k, :]
+        def head(t, j=j, cols=cols, segk=segk):
+            q, tile_scale = _scale_operand(q_ref[t], scale)
+            kb = k_ref[_kv_at(k_ref, t), cols, :]
+            vb = v_ref[_kv_at(v_ref, t), cols, :]
             s = jax.lax.dot_general(
                 q, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if tile_scale is not None:
                 s = s * tile_scale
             if has_bias:
-                s = s + bias_ref[0, 0, j * blk_k:(j + 1) * blk_k][None, :]
+                s = s + bias_ref[0, 0, cols][None, :]
             s = _mask(s, *_causal_pos(causal, q0, j * blk_k, bq, blk_k),
                       segq, segk)
-            p = jnp.exp(s - lse)
+            p = jnp.exp(s - lse_ref[t, 0][:, None])
             dp = jax.lax.dot_general(
-                do, vb, (((1,), (1,)), ((), ())),
+                do_ref[t], vb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if rate > 0.0:
                 keep = _keep_tile(
-                    keep_rows, _keep_cols(seed_ref[0], bh, j * blk_k, blk_k),
+                    keep_rows,
+                    _keep_cols(seed_ref[0], row * hp + t, j * blk_k, blk_k),
                     rate)
                 dp = jnp.where(keep, dp, 0.0)
-            ds = p * (dp - delta)
-            return dq + jnp.dot(ds.astype(kb.dtype), kb,
-                                preferred_element_type=jnp.float32) \
+            ds = p * (dp - delta_ref[t, 0][:, None])
+            acc_ref[t] += jnp.dot(ds.astype(kb.dtype), kb,
+                                  preferred_element_type=jnp.float32) \
                 * out_scale
 
-        dq = _maybe_skip(tile, dq, qrange,
-                         _seg_range(segk) if skip else None,
-                         _causal_live(causal, q0, bq, j * blk_k))
+        _each_head(hp, head, qrange,
+                   _block_range(ranges_ref, batch, s_len // bq + j),
+                   _causal_live(causal, q0, bq, j * blk_k))
 
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
-                segk_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref, *,
-                scale: float, blk_q: int, rate: float, has_bias: bool,
-                has_segments: bool, causal: bool = False):
-    bh, kj = ids
+                segk_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
+                dk_acc_ref, dv_acc_ref, *, scale: float, blk_q: int,
+                rate: float, has_bias: bool, has_segments: bool, batch_of,
+                causal: bool = False, ranges_ref=None):
+    """One program per (grid row, k-block): dk and dv of its heads'
+    key/value heads. The query heads of a group add into the float32
+    accumulators of their one key/value head, and ONE block a key/value
+    head is written, in the parameters' dtype."""
+    row, kj = ids
+    hp = q_ref.shape[0]
     bk = k_ref.shape[1]
     s_len = q_ref.shape[1]
     nq = s_len // blk_q
     out_scale = _dropout_late(scale, rate)
-    skip = _tile_skip(has_segments, nq * (s_len // bk))
+    batch = batch_of(row)
 
-    kb = k_ref[0]
-    # the resident block takes the scale here: (q . k * scale); dk needs
-    # the q blocks as they are
-    ks, tile_scale = _scale_operand(kb, scale)
-    vb = v_ref[0]
     segk = _seg_keys(segk_ref[0, 0])[None, :] if has_segments else None
-    krange = _seg_range(segk) if skip else None
-    keep_cols = (_keep_cols(seed_ref[0], bh, kj * bk, bk)
-                 if rate > 0.0 else None)
+    krange = _block_range(ranges_ref, batch, nq + kj)
     k0 = kj * bk if causal else 0
     if has_bias:
         bias = bias_ref[0, 0][None, :]  # (1, BLK_K)
-    carry = (jnp.zeros(kb.shape, jnp.float32),
-             jnp.zeros(vb.shape, jnp.float32))
+    dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
+    dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
 
     for i in range(nq):
+        rows = slice(i * blk_q, (i + 1) * blk_q)
 
-        def tile(carry, i=i):
-            dk, dv = carry
-            qb = q_ref[0, i * blk_q:(i + 1) * blk_q, :]
-            dob = do_ref[0, i * blk_q:(i + 1) * blk_q, :]
-            lse = lse_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
-            delta = delta_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
-            segq = (segq_ref[0, 0, i * blk_q:(i + 1) * blk_q][:, None]
-                    if has_segments else None)
+        def head(t, i=i, rows=rows):
+            kv = _kv_at(k_ref, t)
+            # the resident block takes the scale here: (q . k * scale); dk
+            # needs the q blocks as they are
+            ks, tile_scale = _scale_operand(k_ref[kv], scale)
+            qb = q_ref[t, rows, :]
+            dob = do_ref[t, rows, :]
+            segq = segq_ref[0, 0, rows][:, None] if has_segments else None
             s = jax.lax.dot_general(
                 qb, ks, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -558,39 +712,35 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                 s = s + bias
             s = _mask(s, *_causal_pos(causal, i * blk_q, k0, blk_q, bk),
                       segq, segk)
-            p = jnp.exp(s - lse)
+            p = jnp.exp(s - lse_ref[t, 0, rows][:, None])
             if rate > 0.0:
-                keep = _keep_tile(_keep_rows(i * blk_q, blk_q), keep_cols,
-                                  rate)
+                keep = _keep_tile(
+                    _keep_rows(i * blk_q, blk_q),
+                    _keep_cols(seed_ref[0], row * hp + t, kj * bk, bk), rate)
                 p_keep = jnp.where(keep, p, 0.0)
             else:
                 p_keep = p
-            dv = dv + jax.lax.dot_general(
+            dv_acc_ref[kv] += jax.lax.dot_general(
                 p_keep.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(
-                dob, vb, (((1,), (1,)), ((), ())),
+                dob, v_ref[kv], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if rate > 0.0:
                 dp = jnp.where(keep, dp, 0.0)
-            ds = p * (dp - delta)
-            dk = dk + jax.lax.dot_general(
+            ds = p * (dp - delta_ref[t, 0, rows][:, None])
+            dk_acc_ref[kv] += jax.lax.dot_general(
                 ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) * out_scale
-            return dk, dv
 
-        # the range from the lane-dense ids; their (blk_q, 1) form is the
-        # tile's, which a skipped tile does not pay
-        qrange = (_seg_range(segq_ref[0, 0, i * blk_q:(i + 1) * blk_q]
-                             [None, :]) if skip else None)
-        carry = _maybe_skip(tile, carry, qrange, krange,
-                            _causal_live(causal, i * blk_q, blk_q, k0))
+        _each_head(hp, head, _block_range(ranges_ref, batch, i), krange,
+                   _causal_live(causal, i * blk_q, blk_q, k0))
 
-    dk, dv = carry
+    dv = dv_acc_ref[...]
     if rate > 0.0:
         dv = dv / (1.0 - rate)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
 def _dqkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref,
@@ -714,10 +864,17 @@ _FUSED_BWD_MAX_PANEL = 2048 * 64
 
 # Beyond the fused backward's bound a program holds whole (S, D) panels of
 # K and V (or Q and dO) beside its unrolled tiles: at S = 8192 that passes
-# Mosaic's default 16 MiB of scoped VMEM (17.6 MB asked for the dq kernel).
-# Such calls ask for this much instead (a v5e core has 128 MiB); shorter
-# sequences pass no parameter and compile as they always did.
+# Mosaic's default 16 MiB of scoped VMEM (17.6 MB asked for the dq kernel
+# at one head a program). Such calls ask for this much instead (a v5e core
+# has 128 MiB); shorter sequences pass no parameter and compile as they
+# always did.
 _LONG_SEQ_VMEM_BYTES = 64 * 1024 * 1024
+# What a bh-layout program takes of it beside its heads' panels
+# (`_bh_heads_per_prog`): ONE head's tile (float32 (512, 512) scores,
+# probabilities, their bf16 copies), the q / dO / output blocks and the
+# heads' accumulators. Compiled for a described v5e: kimi's dq kernel at four
+# heads asks 56.4 MiB, 48 of them panels.
+_TILE_VMEM_BYTES = 16 * 1024 * 1024
 
 
 def _long_seq_params(s: int, lanes: int) -> dict:
@@ -727,6 +884,16 @@ def _long_seq_params(s: int, lanes: int) -> dict:
 
     return {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=_LONG_SEQ_VMEM_BYTES)}
+
+
+def _panel_spec(block: tuple, index_map, long_seq: dict) -> pl.BlockSpec:
+    """BlockSpec of a program's whole-sequence panels. Their block index
+    changes once per S / blk programs, so where they are long
+    (`_long_seq_params`: the bh layout alone) they are held in one buffer,
+    not the pipeline's two."""
+    if long_seq:
+        return pl.BlockSpec(block, index_map, pipeline_mode=pl.Buffered(1))
+    return pl.BlockSpec(block, index_map)
 
 
 def _to_bh(x):
@@ -760,18 +927,20 @@ def _use_native(s: int, h: int, d: int) -> bool:
 
 
 class _Layout(NamedTuple):
-    """How (B, S, H, D) operands are presented to the kernels as a
-    (rows, S, groups * lanes) array walked by a (rows, groups, ...) grid."""
+    """How (B, S, H, D) operands are presented to the kernels: native, the
+    (B, S, H * D) view walked by a (B, lane blocks, ...) grid; bh, the
+    (B * H, S, D) view walked by a (B * H // heads_per_prog, 1, ...) grid
+    whose blocks take `heads_per_prog` of its rows."""
     native: bool
     heads: int           # H
-    rows: int            # B (native) or B*H (bh)
+    rows: int            # grid rows
     groups: int          # lane blocks per row: H // heads_per_prog, or 1
     heads_per_prog: int
 
     @property
     def heads_per_row(self) -> int:
         """Stride of the kernels' dropout counter (row * this + head)."""
-        return self.heads if self.native else 1
+        return self.heads if self.native else self.heads_per_prog
 
     def pack(self, x):
         if self.native:
@@ -782,23 +951,79 @@ class _Layout(NamedTuple):
     def unpack(self, x, b, s, d):
         if self.native:
             return x.reshape(b, s, self.heads, d)
-        return _from_bh(x, b, self.heads)
+        return _from_bh(x, b, x.shape[0] // b)
 
     def batch(self, row):
         """Grid row -> batch index (for the per-batch bias / segment
         operands)."""
-        return row if self.native else row // self.heads
+        return row if self.native else row // (self.heads
+                                               // self.heads_per_prog)
+
+    def block(self, length: int, width: int, heads: int = 0) -> tuple:
+        """Block shape of `heads` heads (default: a program's) over
+        `length` positions."""
+        n = heads or self.heads_per_prog
+        return (1, length, n * width) if self.native else (n, length, width)
+
+    def row_sums(self, x, s: int):
+        """Float32 sums over the width of each head's rows of a packed
+        array, in the layout of the forward kernel's logsumexp: native
+        (B, groups, heads_per_prog, S), bh (B * H, 1, S)."""
+        if self.native:
+            return jnp.sum(x.reshape(self.rows, s, self.groups,
+                                     self.heads_per_prog, -1),
+                           axis=-1).transpose(0, 2, 3, 1)
+        return jnp.sum(x, axis=-1)[:, None]
+
+    def one_head(self) -> "_Layout":
+        """The fused backward's grid: in the bh layout ONE head a program,
+        whose (S, D) accumulators fill its VMEM bound."""
+        if self.native:
+            return self
+        return self._replace(rows=self.rows * self.heads_per_prog,
+                             heads_per_prog=1)
+
+
+# Most heads of one bh-layout program (`_bh_heads_per_prog`; why a program
+# owns several: the module docstring). Four is the most a cell has measured
+# (lfm2: a group; kimi: 2 -> 4 heads took another fifth off its kernels).
+_MAX_HEADS_PER_PROG = 4
+
+
+def _panel_bytes(s: int, d: int, dv: int) -> int:
+    """VMEM of ONE head's resident (S, D) and (S, Dv) panels (K and V in
+    the forward and dq kernels, Q and dO in the dkv kernel): bf16, lanes
+    padded to 128, in one buffer each (`_panel_spec`)."""
+    pad = lambda w: -(-w // 128) * 128  # noqa: E731
+    return 2 * s * (pad(d) + pad(dv))
+
+
+def _bh_heads_per_prog(s: int, h: int, d: int, dv: int, group: int) -> int:
+    """Heads of a bh-layout program, by shape alone: the query heads of a
+    key/value head where heads are grouped (they read ONE K/V panel, and the
+    dkv kernel adds their dk and dv in VMEM); otherwise the largest divisor
+    of H up to `_MAX_HEADS_PER_PROG` whose resident panels leave
+    `_TILE_VMEM_BYTES` of `_LONG_SEQ_VMEM_BYTES` to a tile."""
+    if group > 1:
+        return group
+    for hp in range(min(h, _MAX_HEADS_PER_PROG), 1, -1):
+        if h % hp == 0 and (hp * _panel_bytes(s, d, dv)
+                            <= _LONG_SEQ_VMEM_BYTES - _TILE_VMEM_BYTES):
+            return hp
+    return 1
 
 
 def _layout(b: int, s: int, h: int, d: int, group: int = 1,
             dv: int = 0) -> _Layout:
     """`group` query heads to a key/value head: grouped heads take the bh
-    layout, where a program's key/value head is a block index; so do values
-    of another width `dv` than the keys' (latent attention)."""
-    if group == 1 and dv in (0, d) and _use_native(s, h, d):
+    layout, where a program owns the group and its one key/value head; so do
+    values of another width `dv` than the keys' (latent attention)."""
+    dv = dv or d
+    if group == 1 and dv == d and _use_native(s, h, d):
         hp = _heads_per_prog(h, d)
         return _Layout(True, h, b, h // hp, hp)
-    return _Layout(False, h, b * h, 1, 1)
+    hp = _bh_heads_per_prog(s, h, d, dv, group)
+    return _Layout(False, h, b * h // hp, 1, hp)
 
 
 def _seg_operand(segment_ids, b, s):
@@ -824,25 +1049,6 @@ def _per_batch_spec(present: bool, width: int, index_map):
     if present:
         return pl.BlockSpec((1, 1, width), index_map)
     return pl.BlockSpec(_DUMMY_BLOCK, lambda *_: (0, 0, 0))
-
-
-def _kv_row(h: int, hkv: int):
-    """Grid row of a query head (bh layout: batch * H + head) -> row of its
-    key/value head in the (B * Hkv, S, D) arrays. The identity without
-    grouping, so that the ungrouped kernels' index maps stay as they were."""
-    if h == hkv:
-        return lambda r: r
-    group = h // hkv
-    return lambda r: (r // h) * hkv + (r % h) // group
-
-
-def _sum_groups(x, b: int, hkv: int, group: int, dtype):
-    """(B * H, S, D) per-query-head dk or dv -> (B * Hkv, S, D)."""
-    if group == 1:
-        return x
-    _, s, d = x.shape
-    return x.reshape(b, hkv, group, s, d).sum(axis=2).astype(dtype) \
-        .reshape(b * hkv, s, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -875,6 +1081,14 @@ def _kernel_name(name: str, d: int, dv: int) -> str:
     return name if d == dv else "mla_" + name
 
 
+def _scratch(*shapes) -> list:
+    """float32 VMEM scratch of a bh-layout kernel: its heads' running
+    results."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [pltpu.VMEM(shape, jnp.float32) for shape in shapes]
+
+
 def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
                causal=False):
     b, s, h, d = q.shape
@@ -882,7 +1096,6 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     if h % hkv or k.shape[:3] != v.shape[:3]:
         raise ValueError(f"flash_attention: {h} query heads over k"
                          f"{tuple(k.shape)} v{tuple(v.shape)}")
-    kv_row = _kv_row(h, hkv)
     # keyword only where set, so that the bidirectional kernels trace as
     # they always did
     ckw = {"causal": True} if causal else {}
@@ -893,7 +1106,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     has_segments = segment_ids is not None
     lay = _layout(b, s, h, d, h // hkv, dv)
     hp = lay.heads_per_prog
-    lanes = hp * d
+    kvh = hp * hkv // h     # key/value heads of a program's heads
     # shared by both layouts: the cross-layout bit-parity contract depends
     # on identical bias flattening and seed packing, so they are built once
     bias2 = (bias.reshape(b, 1, s).astype(jnp.float32) if has_bias
@@ -901,23 +1114,37 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     seg2 = _seg_operand(segment_ids, b, s)
     qx, kx, vx = lay.pack(q), lay.pack(k), lay.pack(v)
 
-    q_bs = pl.BlockSpec((1, blk_q, lanes), lambda r, g, qi: (r, qi, g))
-    kv_bs = pl.BlockSpec((1, s, lanes), lambda r, g, qi: (kv_row(r), 0, g))
-    v_bs = pl.BlockSpec((1, s, hp * dv), lambda r, g, qi: (kv_row(r), 0, g))
-    o_bs = pl.BlockSpec((1, blk_q, hp * dv), lambda r, g, qi: (r, qi, g))
-    skip_rows = _skip_pad_rows(has_segments, (s // blk_q) * (s // blk_k))
+    tiles = (s // blk_q) * (s // blk_k)
+    skip_rows = _skip_pad_rows(has_segments, tiles)
     live_spec, live = _live_rows(seg2, skip_rows)
+    kw = dict(scale=scale, blk_k=blk_k, rate=rate, has_bias=has_bias,
+              has_segments=has_segments, **ckw)
+    params = _long_seq_params(s, hp * d)
+    if lay.native:
+        rng_spec, rng, scratch = [], [], []
+        kernel = functools.partial(_fwd_kernel, heads_per_prog=hp,
+                                   heads_per_row=lay.heads_per_row, **kw)
+        lse_bs = pl.BlockSpec((1, 1, hp, blk_q),
+                              lambda r, g, qi: (r, g, 0, qi))
+        lse_shape = (lay.rows, lay.groups, hp, s)
+    else:
+        rng_spec, rng = _block_ranges(seg2, blk_q, blk_k,
+                                      _tile_skip(has_segments, tiles))
+        scratch = _scratch((hp, blk_q, 1), (hp, blk_q, 1), (hp, blk_q, dv))
+        kernel = functools.partial(_fwd_bh_kernel, batch_of=lay.batch, **kw)
+        lse_bs = pl.BlockSpec((hp, 1, blk_q), lambda r, g, qi: (r, 0, qi))
+        lse_shape = (b * h, 1, s)
     out, lse = pl.pallas_call(
-        _program(
-            functools.partial(_fwd_kernel, scale=scale, blk_k=blk_k,
-                              rate=rate, has_bias=has_bias,
-                              has_segments=has_segments, heads_per_prog=hp,
-                              heads_per_row=lay.heads_per_row, **ckw),
-            3, 2, lay.batch if skip_rows else None),
+        _program(kernel, 3, 2, lay.batch if skip_rows else None, bool(rng),
+                 len(scratch)),
         grid=(lay.rows, lay.groups, s // blk_q),
-        in_specs=live_spec + [
+        in_specs=live_spec + rng_spec + [
             pl.BlockSpec((1,), lambda r, g, qi: (0,)),      # seed
-            q_bs, kv_bs, v_bs,
+            pl.BlockSpec(lay.block(blk_q, d), lambda r, g, qi: (r, qi, g)),
+            _panel_spec(lay.block(s, d, kvh), lambda r, g, qi: (r, 0, g),
+                        params),
+            _panel_spec(lay.block(s, dv, kvh), lambda r, g, qi: (r, 0, g),
+                        params),
             _per_batch_spec(has_bias, s,
                             lambda r, g, qi: (lay.batch(r), 0, 0)),
             _per_batch_spec(has_segments, blk_q,
@@ -926,18 +1153,19 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
                             lambda r, g, qi: (lay.batch(r), 0, 0)),
         ],
         out_specs=[
-            o_bs,
-            pl.BlockSpec((1, 1, hp, blk_q), lambda r, g, qi: (r, g, 0, qi)),
+            pl.BlockSpec(lay.block(blk_q, dv), lambda r, g, qi: (r, qi, g)),
+            lse_bs,
         ],
         out_shape=[
             jax.ShapeDtypeStruct(qx.shape[:2] + (qx.shape[2] // d * dv,),
                                  q.dtype),
-            jax.ShapeDtypeStruct((lay.rows, lay.groups, hp, s), jnp.float32),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
+        scratch_shapes=scratch,
         name=_kernel_name("flash_fwd", d, dv),
         interpret=interpret,
-        **_long_seq_params(s, lanes),
-    )(*live, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
+        **params,
+    )(*live, *rng, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
     if causal:
         # names a rematerialising caller may keep (models/lfm2_moe.py
         # DENSE_SAVED): with both saved the backward pass finds the kernel's
@@ -957,30 +1185,23 @@ def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret,
 
 def _flash_bwd_rule(rate, interpret, causal, saved, g):
     # residuals are in the kernel layout _flash_fwd chose (same
-    # deterministic shape gate); lse is (rows, groups, heads_per_prog, S)
+    # deterministic shape gate); lse is in `_Layout.row_sums`' layout
     (qx, kx, vx, bias2, seg2, lse, outx), seed, qshape, has_bias, \
         has_segments = saved
     b, s, h, d = qshape
     hkv = kx.size // (b * s * d)
     dv = vx.size // (b * s * hkv)
-    group = h // hkv
-    kv_row = _kv_row(h, hkv)
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
     blk_k = _pick_block(s, DEFAULT_BLK_K)
-    skip_rows = _skip_pad_rows(has_segments, (s // blk_q) * (s // blk_k))
+    tiles = (s // blk_q) * (s // blk_k)
+    skip_rows = _skip_pad_rows(has_segments, tiles)
     live_spec, live = _live_rows(seg2, skip_rows)
     scale = 1.0 / (d ** 0.5)
-    lay = _layout(b, s, h, d, group, dv)
-    hp = lay.heads_per_prog
-    lanes = hp * d
+    lay = _layout(b, s, h, d, h // hkv, dv)
     gx = lay.pack(g)
-    # per-query-head dk/dv of a group are summed in float32
-    dkv_dtype = kx.dtype if group == 1 else jnp.float32
     # delta = rowsum(dO * O) per head (cheap elementwise — jnp, not a kernel)
-    delta = jnp.sum(
-        (gx.astype(jnp.float32) * outx.astype(jnp.float32))
-        .reshape(lay.rows, s, lay.groups, hp, dv), axis=-1
-    ).transpose(0, 2, 3, 1)
+    delta = lay.row_sums(gx.astype(jnp.float32) * outx.astype(jnp.float32),
+                         s)
     if rate > 0.0:
         # the kernels subtract it from the UNscaled dp of the kept pairs and
         # put the dropout rescale on their (blk, D) results (_dropout_late)
@@ -990,96 +1211,108 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
               has_segments=has_segments, **({"causal": True} if causal
                                             else {}))
 
-    if s * lanes <= _FUSED_BWD_MAX_PANEL and dv == d:
+    one = lay.one_head()
+    if (s * one.heads_per_prog * d <= _FUSED_BWD_MAX_PANEL and dv == d
+            and hkv == h):
         # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated
         # once instead of twice
+        hp = one.heads_per_prog
+        lanes = hp * d
         qkv_bs = pl.BlockSpec((1, s, lanes), lambda r, g: (r, 0, g))
-        kv_in_bs = pl.BlockSpec((1, s, lanes),
-                                lambda r, g: (kv_row(r), 0, g))
         stat_bs = pl.BlockSpec((1, 1, hp, s), lambda r, g: (r, g, 0, 0))
-        per_batch = lambda r, g: (lay.batch(r), 0, 0)  # noqa: E731
+        stat_shape = (one.rows, one.groups, hp, s)
+        per_batch = lambda r, g: (one.batch(r), 0, 0)  # noqa: E731
         dq, dk, dvx = pl.pallas_call(
             _program(
                 functools.partial(_dqkv_kernel, blk_q=blk_q, blk_k=blk_k,
                                   heads_per_prog=hp,
-                                  heads_per_row=lay.heads_per_row, **kw),
-                2, 3, lay.batch if skip_rows else None),
-            grid=(lay.rows, lay.groups),
+                                  heads_per_row=one.heads_per_row, **kw),
+                2, 3, one.batch if skip_rows else None),
+            grid=(one.rows, one.groups),
             in_specs=live_spec + [
                 pl.BlockSpec((1,), lambda r, g: (0,)),
-                qkv_bs, kv_in_bs, kv_in_bs,
+                qkv_bs, qkv_bs, qkv_bs,
                 _per_batch_spec(has_bias, s, per_batch),
                 _per_batch_spec(has_segments, s, per_batch),
                 stat_bs, stat_bs, qkv_bs,
             ],
             out_specs=[qkv_bs, qkv_bs, qkv_bs],
-            out_shape=[jax.ShapeDtypeStruct(qx.shape, qx.dtype)]
-            + [jax.ShapeDtypeStruct(qx.shape, dkv_dtype)] * 2,
+            out_shape=[jax.ShapeDtypeStruct(qx.shape, qx.dtype)] * 3,
             name="flash_bwd_dqkv",
             interpret=interpret,
-        )(*live, seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx)
+        )(*live, seed_arr, qx, kx, vx, bias2, seg2,
+          lse.reshape(stat_shape), delta.reshape(stat_shape), gx)
     else:
         # split kernels, bh layout only (_use_native excludes these shapes;
-        # values of a width of their own take them at any length)
-        lse = lse.reshape(b * h, 1, s)
-        delta = delta.reshape(b * h, 1, s)
-        row_bs = pl.BlockSpec((1, 1, s), lambda bh, i: (bh, 0, 0))
-        full_bs = pl.BlockSpec((1, s, d), lambda bh, i: (bh, 0, 0))
-        do_full_bs = pl.BlockSpec((1, s, dv), lambda bh, i: (bh, 0, 0))
-        kv_full_bs = pl.BlockSpec((1, s, d),
-                                  lambda bh, i: (kv_row(bh), 0, 0))
-        v_full_bs = pl.BlockSpec((1, s, dv),
-                                 lambda bh, i: (kv_row(bh), 0, 0))
-        per_batch = lambda bh, i: (bh // h, 0, 0)  # noqa: E731
-        per_batch_blk = lambda bh, i: (bh // h, 0, i)  # noqa: E731
+        # grouped heads and values of a width of their own take them at any
+        # length)
+        hp = lay.heads_per_prog
+        kvh = hp * hkv // h
+        params = _long_seq_params(s, hp * d)
+        rng_spec, rng = _block_ranges(seg2, blk_q, blk_k,
+                                      _tile_skip(has_segments, tiles))
+        batch_of = lay.batch if skip_rows else None
+        per_batch = lambda r, i: (lay.batch(r), 0, 0)  # noqa: E731
+        per_batch_blk = lambda r, i: (lay.batch(r), 0, i)  # noqa: E731
+        whole = lambda r, i: (r, 0, 0)  # noqa: E731
+        blk = lambda r, i: (r, i, 0)  # noqa: E731
 
-        blk_bs = pl.BlockSpec((1, blk_q, d), lambda bh, qi: (bh, qi, 0))
-        do_blk_bs = pl.BlockSpec((1, blk_q, dv), lambda bh, qi: (bh, qi, 0))
-        stat_blk_bs = pl.BlockSpec((1, 1, blk_q), lambda bh, qi: (bh, 0, qi))
+        q_blk_bs = pl.BlockSpec((hp, blk_q, d), blk)
+        stat_blk_bs = pl.BlockSpec((hp, 1, blk_q), lambda r, qi: (r, 0, qi))
         dq = pl.pallas_call(
-            _program(functools.partial(_dq_kernel, blk_k=blk_k, **kw),
-                     2, 1, lay.batch if skip_rows else None),
-            grid=(b * h, s // blk_q),
-            in_specs=live_spec + [
-                pl.BlockSpec((1,), lambda bh, qi: (0,)),
-                blk_bs, kv_full_bs, v_full_bs,
+            _program(functools.partial(_dq_kernel, blk_k=blk_k,
+                                       batch_of=lay.batch, **kw),
+                     2, 1, batch_of, bool(rng), 1),
+            grid=(lay.rows, s // blk_q),
+            in_specs=live_spec + rng_spec + [
+                pl.BlockSpec((1,), lambda r, qi: (0,)),
+                q_blk_bs,
+                _panel_spec((kvh, s, d), whole, params),
+                _panel_spec((kvh, s, dv), whole, params),
                 _per_batch_spec(has_bias, s, per_batch),
                 _per_batch_spec(has_segments, blk_q, per_batch_blk),
                 _per_batch_spec(has_segments, s, per_batch),
-                stat_blk_bs, stat_blk_bs, do_blk_bs,
+                stat_blk_bs, stat_blk_bs,
+                pl.BlockSpec((hp, blk_q, dv), blk),
             ],
-            out_specs=blk_bs,
+            out_specs=q_blk_bs,
             out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
+            scratch_shapes=_scratch((hp, blk_q, d)),
             name=_kernel_name("flash_bwd_dq", d, dv),
             interpret=interpret,
-            **_long_seq_params(s, lanes),
-        )(*live, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
+            **params,
+        )(*live, *rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,
+          gx)
 
-        blk_bs = pl.BlockSpec((1, blk_k, d), lambda bh, kj: (bh, kj, 0))
-        dv_blk_bs = pl.BlockSpec((1, blk_k, dv), lambda bh, kj: (bh, kj, 0))
-        kv_blk_bs = pl.BlockSpec((1, blk_k, d),
-                                 lambda bh, kj: (kv_row(bh), kj, 0))
-        v_blk_bs = pl.BlockSpec((1, blk_k, dv),
-                                lambda bh, kj: (kv_row(bh), kj, 0))
+        # ONE dk / dv block a key/value head, in the parameters' dtype: the
+        # query heads of a group are added in the kernel's accumulators
+        k_blk_bs = pl.BlockSpec((kvh, blk_k, d), blk)
+        v_blk_bs = pl.BlockSpec((kvh, blk_k, dv), blk)
+        stat_bs = _panel_spec((hp, 1, s), whole, params)
         dk, dvx = pl.pallas_call(
-            _program(functools.partial(_dkv_kernel, blk_q=blk_q, **kw),
-                     2, 2, lay.batch if skip_rows else None),
-            grid=(b * h, s // blk_k),
-            in_specs=live_spec + [
-                pl.BlockSpec((1,), lambda bh, kj: (0,)),
-                full_bs, kv_blk_bs, v_blk_bs,
+            _program(functools.partial(_dkv_kernel, blk_q=blk_q,
+                                       batch_of=lay.batch, **kw),
+                     2, 2, batch_of, bool(rng), 2),
+            grid=(lay.rows, s // blk_k),
+            in_specs=live_spec + rng_spec + [
+                pl.BlockSpec((1,), lambda r, kj: (0,)),
+                _panel_spec((hp, s, d), whole, params),
+                k_blk_bs, v_blk_bs,
                 _per_batch_spec(has_bias, blk_k, per_batch_blk),
                 _per_batch_spec(has_segments, s, per_batch),
                 _per_batch_spec(has_segments, blk_k, per_batch_blk),
-                row_bs, row_bs, do_full_bs,
+                stat_bs, stat_bs,
+                _panel_spec((hp, s, dv), whole, params),
             ],
-            out_specs=[blk_bs, dv_blk_bs],
-            out_shape=[jax.ShapeDtypeStruct(qx.shape, dkv_dtype),
-                       jax.ShapeDtypeStruct(gx.shape, dkv_dtype)],
+            out_specs=[k_blk_bs, v_blk_bs],
+            out_shape=[jax.ShapeDtypeStruct(kx.shape, kx.dtype),
+                       jax.ShapeDtypeStruct(vx.shape, vx.dtype)],
+            scratch_shapes=_scratch((kvh, blk_k, d), (kvh, blk_k, dv)),
             name=_kernel_name("flash_bwd_dkv", d, dv),
             interpret=interpret,
-            **_long_seq_params(s, lanes),
-        )(*live, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta, gx)
+            **params,
+        )(*live, *rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,
+          gx)
 
     # bias is non-differentiable by contract (zero cotangent; see the
     # flash_attention docstring), segment ids and seed likewise — the
@@ -1090,13 +1323,8 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         .zero_from_primal(seg2.reshape(b, s))
     dseed = None if seed is None else jax.custom_derivatives \
         .zero_from_primal(jnp.asarray(seed, jnp.int32))
-    dq = lay.unpack(dq, b, s, d)
-    if group > 1:
-        dk = _from_bh(_sum_groups(dk, b, hkv, group, kx.dtype), b, hkv)
-        dvx = _from_bh(_sum_groups(dvx, b, hkv, group, kx.dtype), b, hkv)
-    else:
-        dk, dvx = lay.unpack(dk, b, s, d), lay.unpack(dvx, b, s, dv)
-    return dq, dk, dvx, dbias, dseg, dseed
+    return (lay.unpack(dq, b, s, d), lay.unpack(dk, b, s, d),
+            lay.unpack(dvx, b, s, dv), dbias, dseg, dseed)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

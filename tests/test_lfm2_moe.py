@@ -278,7 +278,7 @@ def test_no_leak_across_a_document_boundary(operator, monkeypatch):
     (256, 4, 2, True, False), (256, 4, 1, False, False),
     (256, 4, 2, True, True), (384, 8, 2, True, True),
     (256, 4, 4, True, False), (768, 4, 2, True, True),
-], ids=["fused-gqa2-packed", "fused-mqa", "split-gqa2-packed",
+], ids=["one-tile-gqa2-packed", "one-tile-mqa", "split-gqa2-packed",
         "split-gqa4-packed", "fused-mha-packed",
         "split-blocks-of-128-with-unmasked-interior-tiles"])
 def test_causal_grouped_flash_matches_xla_in_interpret_mode(
@@ -286,7 +286,10 @@ def test_causal_grouped_flash_matches_xla_in_interpret_mode(
     monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
     fa = importlib.import_module(
         "bert_pytorch_tpu.ops.pallas.flash_attention")
-    if split:       # the kernels long sequences take (S x D over the bound)
+    # grouped heads take the split kernels at any length (a program owns a
+    # key/value head's query heads); `split`: S x D over the fused
+    # backward's bound, as long sequences are
+    if split:
         monkeypatch.setattr(fa, "_FUSED_BWD_MAX_PANEL", 128 * 64)
     if s == 768:    # 6 x 6 tiles: documents long enough to hold whole tiles
         monkeypatch.setattr(fa, "DEFAULT_BLK_Q", 128)

@@ -464,22 +464,25 @@ def _tile_equations(fn, *args, tile):
 
 # per kernel: vector equations on ONE (blk_q, blk_k) tile, as the cells
 # trace them. Before PR 27: 27 / 34 (packed-512), 14 / 16 / 17 (causal +
-# segments), 4 / 7 (plain)
-@pytest.mark.parametrize("case,shape,kw,ceilings", [
+# segments), 4 / 7 (plain). `conds`: the `lax.cond`s a program traces
+@pytest.mark.parametrize("case,shape,kw,ceilings,conds", [
     # large-pretrain-512-packed: native layout, fused backward, pad bias +
-    # segments + dropout, one tile a head and two heads a program
+    # segments + dropout, one tile a head and two heads a program; the two
+    # conds are `_program`'s test of a row of nothing but pad
     ("packed-512", (1, 512, 2, 2, 64),
      dict(bias=True, segments=True, rate=0.1),
-     {"flash_fwd": 17, "flash_bwd_dqkv": 22}),
+     {"flash_fwd": 17, "flash_bwd_dqkv": 22}, 2),
     # lfm2's kernels at a short S: bh layout, grouped heads, causal +
-    # segments, split backward, one head and two k (or q) blocks a program
+    # segments, split backward; a program owns the two query heads of a
+    # key/value head and walks two k (or q) blocks: TWO tile bodies, each
+    # run for both heads under ONE test of its (q block, k block) pair
     ("causal-segments", (1, 1024, 4, 2, 64),
      dict(segments=True, causal=True, split=True),
-     {"flash_fwd": 8, "flash_bwd_dq": 10, "flash_bwd_dkv": 11}),
+     {"flash_fwd": 8, "flash_bwd_dq": 10, "flash_bwd_dkv": 11}, 2),
     ("plain", (1, 512, 2, 2, 64), dict(),
-     {"flash_fwd": 3, "flash_bwd_dqkv": 6}),
+     {"flash_fwd": 3, "flash_bwd_dqkv": 6}, 0),
 ])
-def test_flash_tile_equation_ceilings(case, shape, kw, ceilings,
+def test_flash_tile_equation_ceilings(case, shape, kw, ceilings, conds,
                                       force_flash_path):
     """Nothing that is a function of the row or the column alone is
     computed on the (q, k) tile: per kernel, the count of tile-shaped
@@ -487,7 +490,10 @@ def test_flash_tile_equation_ceilings(case, shape, kw, ceilings,
     operand at D = 64, hash prefix and pad test on vectors, dropout rescale
     on the (blk, D) results, one compare per mask condition). An edit that
     puts per-row or per-column work back on the tile fails here by the
-    kernel's name."""
+    kernel's name. And in the bh layout the tile and the skip test of a
+    (q block, k block) pair are traced once for every head of a program
+    (PR 34): S / blk tile bodies and S / blk conds a program, not
+    heads x S / blk."""
     fa = importlib.import_module(
         "bert_pytorch_tpu.ops.pallas.flash_attention")
     b, s, h, hkv, d = shape
@@ -495,9 +501,12 @@ def test_flash_tile_equation_ceilings(case, shape, kw, ceilings,
         force_flash_path("bh", "split")
     blk_q = fa._pick_block(s, fa.DEFAULT_BLK_Q)
     blk_k = fa._pick_block(s, fa.DEFAULT_BLK_K)
-    # tile bodies a program traces: its heads x the k (or q) blocks it walks
-    # (the fused backward walks both)
-    heads = 1 if kw.get("split") else fa._heads_per_prog(h, d)
+    # tile bodies a program traces. Native: its heads x the k blocks it
+    # walks (the fused backward walks q blocks too). bh: its heads are a
+    # rolled loop inside each (q block, k block) pair, ONE body a pair
+    lay = fa._layout(b, s, h, d, h // hkv)
+    assert lay.heads_per_prog == 2
+    heads = 2 if lay.native else 1
     tiles = {"flash_fwd": heads * (s // blk_k),
              "flash_bwd_dq": s // blk_k, "flash_bwd_dkv": s // blk_q,
              "flash_bwd_dqkv": heads * (s // blk_q) * (s // blk_k)}
@@ -519,6 +528,99 @@ def test_flash_tile_equation_ceilings(case, shape, kw, ceilings,
     over = {name: (n, ceilings[name]) for name, n in per_tile.items()
             if n > ceilings[name]}
     assert not over, f"{case}: (tile equations, ceiling) {over}"
+    found = _kernel_equations(grads, q, k, k,
+                              count=lambda e: e.primitive.name == "cond")
+    assert found == dict.fromkeys(ceilings, conds), f"{case}: conds {found}"
+
+
+def _documents(b, s, cuts):
+    """(B, S) segment ids: row r's documents end at cuts[r]; the rest of
+    the row is pad."""
+    seg = np.zeros((b, s), np.int32)
+    for r, ends in enumerate(cuts):
+        for n, (lo, hi) in enumerate(zip((0,) + ends[:-1], ends)):
+            seg[r, lo:hi] = n + 1
+    return jnp.asarray(seg)
+
+
+@pytest.mark.parametrize("hp", [1, 2, 4])
+@pytest.mark.parametrize("form", ["causal-grouped", "keys192-values128",
+                                  "dropout"])
+def test_flash_bh_heads_of_a_program(form, hp, monkeypatch,
+                                     force_flash_path):
+    """The bh-layout kernels (forward, split dq and dkv) at 1, 2 and 4
+    heads a program, with 128-wide blocks so that every program walks
+    several (q block, k block) pairs under their one skip test: lfm2's form
+    (causal, packed, the `hp` query heads of a key/value head), kimi's
+    (keys of 192, values of 128) and BERT's long rows (bidirectional,
+    packed, dropout). Outputs, dq, dk and dv against the XLA path; the outputs of
+    skipped against masked tiles bit for bit where a position is no pad; with
+    dropout against the native layout and its fused backward, whose keep
+    masks the bh layout has to draw bit for bit (V picks the probabilities
+    of one key block out, so a dropped pair is an exact zero)."""
+    from bert_pytorch_tpu.ops.attention import dot_product_attention
+
+    fa = importlib.import_module(
+        "bert_pytorch_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "DEFAULT_BLK_Q", 128)
+    monkeypatch.setattr(fa, "DEFAULT_BLK_K", 128)
+    monkeypatch.setattr(fa, "_MAX_HEADS_PER_PROG", hp)
+    b, s, d, dv, hkv = {"causal-grouped": (2, 512, 64, 64, 2),
+                        "keys192-values128": (1, 256, 192, 128, 4),
+                        "dropout": (2, 256, 128, 128, 4)}[form]
+    h = hkv * hp if form == "causal-grouped" else hkv
+    causal = form != "dropout"
+    rate = 0.0 if causal else 0.25
+    seed = None if causal else jnp.int32(11)
+    seg = _documents(b, s, [(s // 3, s - s // 5 - 9, s - 17),
+                            (s // 2 + 5, s)][:b])
+    real = np.asarray(seg) > 0
+    keys = jax.random.split(jax.random.PRNGKey(hp), 4)
+    q = jax.random.normal(keys[0], (b, s, h, d)) * 0.5
+    k = jax.random.normal(keys[1], (b, s, hkv, d)) * 0.5
+    v = jax.random.normal(keys[2], (b, s, hkv, dv)) * 0.5
+    w = jax.random.normal(keys[3], (b, s, h, dv)) * real[:, :, None, None]
+
+    def run(v=v, layout="bh", skip="1"):
+        force_flash_path(layout, "split" if layout == "bh" else "fused")
+        monkeypatch.setenv("FLASH_SEG_SKIP", skip)
+        assert fa._layout(b, s, h, d, h // hkv, dv).heads_per_prog == (
+            hp if layout == "bh" else 1)
+
+        def f(q, k, v):
+            out = flash_attention(q, k, v, None, seg, seed, rate, True,
+                                  causal)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return [np.asarray(x) for x in (out,) + grads]
+
+    got, masked = run(), run(skip="0")
+    np.testing.assert_array_equal(got[0][real], masked[0][real])
+    for a, m in zip(got[1:], masked[1:]):   # float32 sums in another order
+        np.testing.assert_allclose(a[real], m[real], atol=1e-6)
+    if causal:
+        def xla(q, k, v):
+            out = dot_product_attention(q, k, v, segment_ids=seg,
+                                        impl="xla", causal=True)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(
+            xla, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        wants = (out,) + grads
+    else:
+        wants = run(layout="native")
+        # the keep masks: probabilities of the pairs with one key block
+        for block in range(s // dv):
+            pick = jnp.zeros((s, dv)).at[block * dv:(block + 1) * dv].set(
+                jnp.eye(dv))
+            pick = jnp.broadcast_to(pick[None, :, None], v.shape)
+            probs, want = run(pick)[0], run(pick, "native")[0]
+            assert 0.15 < (probs[real] == 0).mean() < 0.95
+            np.testing.assert_array_equal(probs == 0, want == 0)
+    np.testing.assert_allclose(got[0][real], np.asarray(wants[0])[real],
+                               atol=2e-5)
+    for a, want in zip(got[1:], wants[1:]):
+        np.testing.assert_allclose(a, np.asarray(want), atol=5e-5)
 
 
 @pytest.mark.parametrize("layout,bwd,skip", [
@@ -620,6 +722,30 @@ def test_flash_layout_chosen_by_shape_alone(monkeypatch):
     assert not _use_native(4096, 16, 64)   # long context: split kernels
     assert not _use_native(512, 3, 64)     # odd head count at D=64
     assert not _use_native(512, 4, 48)     # D does not tile 128 lanes
+
+
+@pytest.mark.parametrize("shape,heads,rows", [
+    # lfm2-ep8-clm-8k-packed: the four query heads of a key/value head
+    (dict(b=4, s=8192, h=32, d=64, group=4), 4, 32),
+    # kimi-linear-ep32-clm-16k-packed: keys 192, values 128; four heads'
+    # K and V panels are 48 of the kernels' 64 MiB of VMEM
+    (dict(b=1, s=16384, h=32, d=192, dv=128), 4, 8),
+    # BERT-Large beyond the native layout: the fused backward's bound
+    # (the forward alone takes the heads), and the split kernels
+    (dict(b=4, s=2048, h=16, d=64), 4, 16),
+    (dict(b=2, s=4096, h=16, d=64), 4, 8),
+    (dict(b=1, s=32768, h=16, d=64), 2, 8),   # 16 MiB of panels a head
+    (dict(b=8, s=512, h=3, d=64), 3, 8),      # heads that tile no lanes
+])
+def test_flash_bh_heads_a_program_by_shape(shape, heads, rows):
+    """Heads of a bh-layout program are a function of shapes alone
+    (`_bh_heads_per_prog`): pinned for the two decoder cells and for
+    BERT's long rows."""
+    from bert_pytorch_tpu.ops.pallas.flash_attention import _layout
+
+    lay = _layout(**shape)
+    assert not lay.native
+    assert (lay.heads_per_prog, lay.rows) == (heads, rows)
 
 
 @pytest.mark.parametrize("seq,interpret,match", [

@@ -120,6 +120,37 @@ def test_flash_split_backward_compiles(v5e, bias, segments,
                        grad=True) == SPLIT
 
 
+@pytest.mark.parametrize("cell,b,s,h,hkv,d,dv,heads", [
+    # lfm2-ep8-clm-8k-packed: a program owns a key/value head's four query
+    # heads
+    ("lfm2", 4, 8192, 32, 8, 64, 64, 4),
+    # kimi-linear-ep32-clm-16k-packed: four heads' K and V panels, 48 MiB in
+    # one buffer each
+    ("kimi", 1, 16384, 32, 32, 192, 128, 4),
+])
+def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
+                                                   d, dv, heads):
+    """The causal split kernels at the two decoder cells' shapes, at the
+    heads a program that `_layout` picks, inside the VMEM the calls ask for
+    (`_LONG_SEQ_VMEM_BYTES`: the compiler refuses a kernel that needs
+    more): dynamic head indices into the blocks, the rolled loop over the
+    heads and the scratch accumulators are what interpret mode cannot
+    refuse."""
+    assert fa._layout(b, s, h, d, h // hkv, dv).heads_per_prog == heads
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+
+    def bwd(q, k, v, seg):
+        return jax.grad(lambda q, k, v: fa.flash_attention(
+            q, k, v, None, seg, None, 0.0, False, True)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    name = (lambda n: n) if d == dv else (lambda n: "mla_" + n)
+    assert _kernels(bwd, sds((b, s, h, d), jnp.bfloat16),
+                    sds((b, s, hkv, d), jnp.bfloat16),
+                    sds((b, s, hkv, dv), jnp.bfloat16),
+                    sds((b, s), jnp.int32)) == {name(n): 1 for n in SPLIT}
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("b,s,split", [
     (32, 384, False),   # SQuAD finetune length (scripts/run_squad.sh)
