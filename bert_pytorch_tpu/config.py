@@ -256,6 +256,10 @@ class Lfm2MoeConfig:
     def replace(self, **kw: Any) -> "Lfm2MoeConfig":
         return dataclasses.replace(self, **kw)
 
+    # what models/lfm2_moe.RoutedExperts reads of every decoder family
+    router_scores = property(lambda self: "sigmoid")
+    expert_activation = property(lambda self: "silu")
+
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
@@ -408,6 +412,8 @@ class KimiLinearConfig:
     num_experts_per_tok = property(lambda self: self.num_experts_per_token)
     norm_topk_prob = property(lambda self: self.moe_renormalize)
     use_expert_bias = property(lambda self: True)
+    router_scores = property(lambda self: self.moe_router_activation_func)
+    expert_activation = property(lambda self: "silu")
 
     @property
     def gate_rank(self) -> int:
@@ -465,8 +471,146 @@ class KimiLinearConfig:
         return tuple(kinds)
 
 
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerConfig:
+    """Architecture config of the `smallthinker` family (PowerInfer
+    SmallThinker): a pre-norm decoder whose every layer is causal
+    grouped-query attention, over the whole document WITHOUT positions or
+    over the last `sliding_window_size` tokens WITH rotary positions
+    (`sliding_window_layout` and `rope_layout`, one 0/1 a layer), then
+    softmax-routed ReLU-gated experts whose router reads the layer's input,
+    ahead of the attention; an untied head (models/smallthinker.py has the
+    equations).
+
+    Keys are the source's (`config.json` of the model). A run may hold one
+    expert-parallel rank's share, as Lfm2MoeConfig's:
+    `moe_num_primary_experts` experts, the range `experts_held` of
+    `experts_total` (the router keeps that width) and `vocab_size` rows of
+    the vocabulary; a cut stack gives the two layouts of the layers it
+    keeps (`num_hidden_layers` entries each).
+    """
+
+    model_type: str = "smallthinker"
+    vocab_size: int = 151936
+    hidden_size: int = 2560
+    head_dim: int = 128
+    num_hidden_layers: int = 52
+    num_attention_heads: int = 28
+    num_key_value_heads: int = 4
+    moe_ffn_hidden_size: int = 768
+    moe_num_primary_experts: int = 64
+    moe_num_active_primary_experts: int = 6
+    moe_primary_router_apply_softmax: bool = True
+    norm_topk_prob: bool = True
+    rope_layout: Tuple[int, ...] = ()
+    sliding_window_layout: Tuple[int, ...] = ()
+    sliding_window_size: int = 4096
+    rope_theta: float = 1500000.0
+    rope_scaling: Optional[Any] = None
+    rms_norm_eps: float = 1e-6
+    max_position_embeddings: int = 16384
+    tie_word_embeddings: bool = False
+    experts_total: Optional[int] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    initializer_range: float = 0.02
+    model_name: Optional[str] = None
+    # run settings, as BertConfig's
+    dtype: str = "bfloat16"
+    checkpoint_activations: bool = False
+    remat_policy: str = "auto"
+    attention_impl: str = "auto"
+
+    # keys of the configuration's file that carry no size of this program's
+    _IGNORED = ("vocab_rows_total", "vocab_rows_held")
+
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any]) -> "SmallThinkerConfig":
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = [k for k in d if k not in known
+                   and k not in DOCUMENTED_DATA_KEYS
+                   and k not in cls._IGNORED]
+        if unknown:
+            raise ValueError(
+                f"smallthinker model config: unknown key(s) {sorted(unknown)}")
+        kw = {k: v for k, v in d.items() if k in known}
+        for key in ("rope_layout", "sliding_window_layout", "experts_held"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        cfg = cls(**kw)
+        cfg.layer_kinds  # raises on an inconsistent cut
+        return cfg
+
+    @classmethod
+    def from_json_file(cls, path: str) -> "SmallThinkerConfig":
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_dict(json.load(f))
+
+    def to_dict(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    def replace(self, **kw: Any) -> "SmallThinkerConfig":
+        return dataclasses.replace(self, **kw)
+
+    # the names models/lfm2_moe.py's shared modules read
+    norm_eps = property(lambda self: self.rms_norm_eps)
+    num_experts = property(lambda self: self.moe_num_primary_experts)
+    num_experts_per_tok = property(
+        lambda self: self.moe_num_active_primary_experts)
+    moe_intermediate_size = property(lambda self: self.moe_ffn_hidden_size)
+    use_expert_bias = property(lambda self: False)
+    routed_scaling_factor = property(lambda self: 1.0)
+    router_scores = property(lambda self: "softmax")
+    expert_activation = property(lambda self: "relu")
+
+    @property
+    def router_width(self) -> int:
+        return int(self.experts_total or self.moe_num_primary_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        n = self.moe_num_primary_experts
+        lo, hi = self.experts_held or (0, n)
+        if hi - lo != n or not 0 <= lo < hi <= self.router_width:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of "
+                f"moe_num_primary_experts={n} out of {self.router_width}")
+        return int(lo), int(hi)
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[int, bool], ...]:
+        """(window, rope) of every layer of the stack as run: the band's
+        width (0: the whole document) and whether q and k take rotary
+        positions."""
+        n = self.num_hidden_layers
+        if len(self.rope_layout) != n or len(self.sliding_window_layout) != n:
+            raise ValueError(
+                "rope_layout and sliding_window_layout must give one entry "
+                f"for each of num_hidden_layers={n} layers (have "
+                f"{len(self.rope_layout)} and "
+                f"{len(self.sliding_window_layout)})")
+        unsupported = [
+            name for name, bad in (
+                ("moe_primary_router_apply_softmax=false",
+                 not self.moe_primary_router_apply_softmax),
+                ("rope_scaling", self.rope_scaling is not None),
+                ("tie_word_embeddings", self.tie_word_embeddings),
+                ("sliding_window_size < 1", self.sliding_window_size < 1),
+                ("num_attention_heads not a multiple of num_key_value_heads",
+                 self.num_attention_heads % self.num_key_value_heads != 0),
+            ) if bad]
+        if unsupported:
+            raise NotImplementedError(
+                f"smallthinker: not written for {unsupported} (the source "
+                "model uses none of them)")
+        self.held_range
+        return tuple((self.sliding_window_size if w else 0, bool(r))
+                     for w, r in zip(self.sliding_window_layout,
+                                     self.rope_layout))
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "lfm2_moe": Lfm2MoeConfig,
-                  "kimi_linear": KimiLinearConfig}
+                  "kimi_linear": KimiLinearConfig,
+                  "smallthinker": SmallThinkerConfig}
 
 
 def load_model_config(path: str):

@@ -17,7 +17,7 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import MODEL_FAMILIES
-from bert_pytorch_tpu.models import kimi_linear, lfm2_moe
+from bert_pytorch_tpu.models import kimi_linear, lfm2_moe, smallthinker
 from bert_pytorch_tpu.models.bert import BertForPreTraining
 from bert_pytorch_tpu.telemetry.expert_load import ExpertLoadCounters
 from bert_pytorch_tpu.telemetry.stepwatch import flops_per_seq
@@ -52,11 +52,14 @@ def _bert_init_inputs(batch) -> Tuple:
 
 
 def _decoder_refusal(args) -> Optional[str]:
-    """The decoder families' one refusal (lfm2_moe, kimi_linear)."""
+    """The decoder families' one refusal (every record `_decoder_family`
+    makes)."""
     if not (args.kfac or args.stream_dir or args.stacked_params != "auto"
             or args.steps_per_loop > 1):
         return None
-    return ("the decoder families (model_type 'lfm2_moe', 'kimi_linear') "
+    names = ", ".join(repr(name) for name, family in FAMILIES.items()
+                      if family.refusal is _decoder_refusal)
+    return (f"the decoder families (model_type {names}) "
             "train through the offline data plane with LAMB/Adam, one step "
             "a dispatch: --kfac, --stream_dir, --stacked_params and "
             "--steps_per_loop do not apply to them")
@@ -94,6 +97,8 @@ FAMILIES = {
     "lfm2_moe": _decoder_family(lfm2_moe, lfm2_moe.Lfm2MoeForCausalLM),
     "kimi_linear": _decoder_family(kimi_linear,
                                    kimi_linear.KimiLinearForCausalLM),
+    "smallthinker": _decoder_family(smallthinker,
+                                    smallthinker.SmallThinkerForCausalLM),
 }
 
 

@@ -177,12 +177,16 @@ class DenseMLP(nn.Module):
 class RoutedExperts(nn.Module):
     """The routed FFN over the experts this rank holds. Returns (the partial
     sum (B, S, E) in `dtype`, tokens per held expert (E_held,) int32, held
-    pairs not computed () int32)."""
+    pairs not computed () int32). The router reads `router_input` where it
+    is given (models/smallthinker.py: the layer's input, ahead of the
+    attention) and the tokens the experts compute on otherwise; how it
+    scores and what gates an expert are the config's `router_scores` and
+    `expert_activation`."""
     config: Any
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         cfg = self.config
         bsz, s, e = x.shape
         f, n_held = cfg.moe_intermediate_size, cfg.num_experts
@@ -198,15 +202,18 @@ class RoutedExperts(nn.Module):
         w3 = self.param("experts_w3", init, (n_held, e, f), jnp.float32)
         w2 = self.param("experts_w2", init, (n_held, f, e), jnp.float32)
         tokens = x.reshape(bsz * s, e).astype(self.dtype)
-        routing = moe_ops.route(tokens, router, bias,
-                                cfg.num_experts_per_tok, cfg.norm_topk_prob,
-                                float(cfg.routed_scaling_factor))
+        routing = moe_ops.route(
+            tokens if router_input is None
+            else router_input.reshape(bsz * s, e),
+            router, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob,
+            float(cfg.routed_scaling_factor), cfg.router_scores)
         # a window holds twice this rank's even share of the pairs
         window_rows = -(-2 * bsz * s * cfg.num_experts_per_tok * n_held
                         // cfg.router_width // 512) * 512
         out, load, dropped = moe_ops.held_experts(
             tokens, routing, w1.astype(self.dtype), w3.astype(self.dtype),
-            w2.astype(self.dtype), cfg.held_range, window_rows)
+            w2.astype(self.dtype), cfg.held_range, window_rows,
+            cfg.expert_activation)
         return out.astype(self.dtype).reshape(bsz, s, e), load, dropped
 
 

@@ -1,0 +1,212 @@
+"""The `smallthinker` family: a pre-norm decoder whose layers all attend,
+three over a sliding window with rotary positions to one over the whole
+document without positions, each followed by softmax-routed ReLU-gated
+experts whose router reads the layer's input.
+
+Written from the family's public config (config.SmallThinkerConfig names
+the keys). For x of shape (T, hidden), layer l with w_l =
+`sliding_window_layout[l]` and p_l = `rope_layout[l]` computes
+
+    r = x W_r                                  router logits, float32, from
+                                               the layer's INPUT
+    a = RMSNorm(x; input_layernorm)
+    q, k, v = a Wq (H heads of D), a Wk, a Wv (Hkv heads); no bias, no norm
+    if p_l: q, k = rotary(q), rotary(k)        all of D, rotate-half,
+                                               positions restart per document
+    s_ij = q_i . k_j / sqrt D  over j of i's document with j <= i, and
+                               i - j < sliding_window_size if w_l
+    h = x + concat_heads(softmax(s) v) Wo      query head n reads key/value
+                                               head n // (H / Hkv)
+    m = RMSNorm(h; post_attention_layernorm)
+    E_i = the k largest of r_i;  g_ie = exp(r_ie) / sum_{e' in E_i} exp(r_ie')
+    y = h + sum_{e in E_i, e held} g_ie W2_e(relu(W1_e m_i) * W3_e m_i)
+
+with RMSNorm as models/lfm2_moe.py's (eps 1e-6 here). After the last layer
+one more RMSNorm (`final_norm`), and the logits are that times an UNTIED
+`lm_head` (V, hidden) transposed. No dense layer, no shared expert, no
+selection bias, no scaling factor. The layer holds the experts
+`experts_held` (ops/moe.py): what the absent experts would add is left out.
+
+A padding slot (segment 0) attends nowhere; it is routed like any token.
+
+Norms, the router's logits and the softmax over the selected, rotary,
+attention's softmax and the loss are float32; matrix products take `dtype`
+operands (bfloat16) and accumulate in float32. The router's gradient
+reaches x, not m.
+
+Layers are separate modules in a Python loop (their window and positions are
+static, so one scan does not carry them), each rematerialised under
+`checkpoint_activations` (`remat_policy`: lfm2_moe.LM_REMAT_POLICIES, the
+same names saved). The model hands back the final norm's output and the
+head, not logits: the loss (losses.next_token_loss_blocked) takes the head a
+block of tokens at a time.
+
+Scopes: a layer's attention is `attention/attention_window` or
+`attention/attention_full` (both under `attention`, so that what reads the
+one reads both kinds), the experts `moe/router|dispatch|experts|combine`
+(ops/moe.py), `rmsnorm`, `lm_head`, `loss`.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from bert_pytorch_tpu.config import SmallThinkerConfig
+from bert_pytorch_tpu.models import losses
+from bert_pytorch_tpu.models.lfm2_moe import (LM_REMAT_POLICIES, RMSNorm,
+                                              RoutedExperts, _init, _Linear,
+                                              expert_scalars)
+# the router is read in float32, as lfm2's: models/families.py takes the
+# family's `keep_float32` from this module
+from bert_pytorch_tpu.models.lfm2_moe import keep_float32  # noqa: F401
+from bert_pytorch_tpu.ops.attention import dot_product_attention
+from bert_pytorch_tpu.ops.decoder_ops import rotary
+
+Dtype = Any
+
+# tokens a block of the loss: (2048, 18992) float32 logits are 156 MB
+LOSS_BLOCK_ROWS = 2048
+
+
+class Attention(nn.Module):
+    config: SmallThinkerConfig
+    window: int         # the band's width; 0: the whole document
+    rope: bool
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, segment_ids, position_ids):
+        cfg = self.config
+        h, hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.head_dim)
+        bsz, s, e = x.shape
+        with jax.named_scope("attention_window" if self.window
+                             else "attention_full"):
+            # three tensors (LAMB takes one trust ratio each), one product
+            kernels = [self.param(f"{n}_proj", _init(cfg), (e, heads * d),
+                                  jnp.float32)
+                       for n, heads in (("q", h), ("k", hkv), ("v", hkv))]
+            qkv = jnp.dot(
+                x.astype(self.dtype),
+                jnp.concatenate(kernels, axis=1).astype(self.dtype),
+                preferred_element_type=jnp.float32).astype(self.dtype)
+            qkv = checkpoint_name(qkv, "in_proj_out")
+            q, k, v = jnp.split(qkv, [h * d, (h + hkv) * d], axis=-1)
+            q = q.reshape(bsz, s, h, d)
+            k = k.reshape(bsz, s, hkv, d)
+            v = v.reshape(bsz, s, hkv, d)
+            if self.rope:
+                q = rotary(q, position_ids, cfg.rope_theta).astype(self.dtype)
+                k = rotary(k, position_ids, cfg.rope_theta).astype(self.dtype)
+            ctx = dot_product_attention(
+                q, k, v, segment_ids=segment_ids, impl=cfg.attention_impl,
+                causal=True, window=self.window or None)
+            return _Linear(e, cfg, self.dtype, name="out_proj")(
+                ctx.reshape(bsz, s, h * d))
+
+
+class DecoderLayer(nn.Module):
+    config: SmallThinkerConfig
+    window: int
+    rope: bool
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, segment_ids, position_ids):
+        cfg = self.config
+        normed = RMSNorm(cfg.norm_eps, self.dtype, name="input_layernorm")(x)
+        h = x + Attention(cfg, self.window, self.rope, self.dtype,
+                          name="attention")(normed, segment_ids, position_ids)
+        normed = RMSNorm(cfg.norm_eps, self.dtype,
+                         name="post_attention_layernorm")(h)
+        # the router reads the layer's input, ahead of the attention
+        out, load, dropped = RoutedExperts(cfg, self.dtype, name="moe")(
+            normed, router_input=x)
+        return h + out, load, dropped
+
+
+class SmallThinkerForCausalLM(nn.Module):
+    """(input_ids, segment_ids, position_ids), each (B, S) -> (the final
+    norm's output (B, S, hidden) in `dtype`, the head (V, hidden) in
+    `dtype`, per layer: tokens per held expert (L, E_held) int32 and held
+    pairs not computed (L,) int32). segment_ids: the packing contract's
+    (1..n per row, 0 = pad); position_ids restart at each document."""
+    config: SmallThinkerConfig
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, input_ids, segment_ids, position_ids):
+        cfg = self.config
+        layer_cls = DecoderLayer
+        if cfg.checkpoint_activations:
+            layer_cls = nn.remat(DecoderLayer,
+                                 policy=LM_REMAT_POLICIES[cfg.remat_policy])
+        with jax.named_scope("decoder"):
+            table = self.param("embed_tokens", _init(cfg),
+                               (cfg.vocab_size, cfg.hidden_size),
+                               jnp.float32)
+            head = self.param("lm_head", _init(cfg),
+                              (cfg.vocab_size, cfg.hidden_size), jnp.float32)
+            with jax.named_scope("embeddings"):
+                x = table.astype(self.dtype)[input_ids]
+            loads, drops = [], []
+            for i, (window, rope) in enumerate(cfg.layer_kinds):
+                x, load, dropped = layer_cls(
+                    cfg, window, rope, self.dtype, name=f"layer_{i}")(
+                        x, segment_ids, position_ids)
+                loads.append(load)
+                drops.append(dropped)
+            x = RMSNorm(cfg.norm_eps, self.dtype, name="final_norm")(x)
+        return x, head.astype(self.dtype), jnp.stack(loads), jnp.stack(drops)
+
+
+def pretrain_loss_fn_builder(model) -> Callable:
+    """loss_fn_builder of training/pretrain.build_pretrain_step: next-token
+    cross-entropy over packed rows, the head a block of tokens at a time,
+    and the layers' expert counters as lfm2's."""
+    cfg = model.config
+
+    def loss_fn(params, batch, dropout_rng, deterministic: bool = False):
+        hidden, head, load, dropped = model.apply(
+            {"params": params}, batch["input_ids"], batch["segment_ids"],
+            batch["position_ids"])
+        loss, count = losses.next_token_loss_blocked(
+            hidden, head, batch["input_ids"], batch["segment_ids"],
+            LOSS_BLOCK_ROWS)
+        with jax.named_scope("metrics"):
+            scalars = expert_scalars(
+                count, batch["input_ids"].size * cfg.num_experts_per_tok,
+                load, dropped)
+        return loss, {"scalars": scalars}
+
+    return loss_fn
+
+
+def band_pairs(length: int, window: int) -> int:
+    """(query, key) pairs of one document of `length` tokens: key <= query,
+    and under a band (`window` > 0) query - key < window."""
+    if not window or length <= window:
+        return length * (length + 1) // 2
+    return window * (window + 1) // 2 + (length - window) * window
+
+
+def train_flops_per_row(cfg: SmallThinkerConfig, seq_len: int) -> float:
+    """Forward + backward FLOPs of one full row of seq_len tokens, as this
+    rank computes them: 6 x weights x tokens for the dense products (each
+    token through num_experts_per_tok * held / total experts on average) +
+    12 x heads x D for every (query, key) pair of a layer's causal triangle
+    or band. An upper estimate for packed rows (documents shorter than the
+    row attend less)."""
+    e, d = cfg.hidden_size, cfg.head_dim
+    h, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    layer = (e * (h + 2 * hkv) * d + h * d * e + e * cfg.router_width
+             + 3 * e * cfg.moe_intermediate_size * cfg.num_experts_per_tok
+             * cfg.num_experts / cfg.router_width)
+    weights = cfg.vocab_size * e + cfg.num_hidden_layers * layer
+    pairs = sum(band_pairs(seq_len, window) for window, _ in cfg.layer_kinds)
+    return 6.0 * weights * seq_len + 12.0 * h * d * pairs

@@ -212,6 +212,7 @@ def dot_product_attention(
     trainable_bias: bool = False,
     hash_dropout_impl: bool = True,
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Returns (B, Sq, H, D) in q.dtype.
 
@@ -220,6 +221,9 @@ def dot_product_attention(
     heads: query head i reads key/value head i // (H // Hkv)). Both are
     served by the flash kernels and the plain XLA path; neither by the ring
     path nor under a mesh that shards the kernel (an error there).
+    `window` (with `causal` only; None: no band): a query attends to the
+    last `window` positions, its own among them: 0 <= q_pos - k_pos <
+    window, counted inside the query's own segment.
 
     impl="auto" resolves by sequence length: measured on v5e, the plain XLA
     path (bf16 probs, fp32 softmax stats) beats the blockwise Pallas kernel
@@ -243,6 +247,10 @@ def dot_product_attention(
     """
     seq = q.shape[1]
     requested = impl
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"attention window={window!r} needs causal=True and a width of "
+            "at least 1 (the bidirectional paths have no band)")
     if impl == "auto":
         impl = "pallas" if seq > 256 else "xla"
     interpret = jax.default_backend() != "tpu" and _pallas_interpret()
@@ -294,7 +302,9 @@ def dot_product_attention(
                 return flash_attention(q, k, v, bias=bias,
                                        segment_ids=segment_ids,
                                        dropout_seed=seed, dropout_rate=rate,
-                                       interpret=interpret, causal=causal)
+                                       interpret=interpret, causal=causal,
+                                       **({"window": int(window)}
+                                          if window else {}))
             out = _flash_sharded(mesh, q, k, v, bias, segment_ids, seed,
                                  rate, interpret)
             if out is not None:
@@ -316,7 +326,7 @@ def dot_product_attention(
 
     return _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                           dropout_rate, deterministic, hash_dropout_impl,
-                          causal)
+                          causal, window)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -358,7 +368,8 @@ hash_dropout.defvjp(_hash_dropout_fwd, _hash_dropout_bwd)
 def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                    dropout_rate: float, deterministic: bool,
                    hash_dropout_impl: bool = True,
-                   causal: bool = False) -> jax.Array:
+                   causal: bool = False,
+                   window: Optional[int] = None) -> jax.Array:
     if k.shape[2] != q.shape[2]:    # grouped heads: one copy per query head
         group = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, group, axis=2)
@@ -374,8 +385,11 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
         scores = scores + make_segment_attention_bias(segment_ids)
     if causal:
         sq, sk = scores.shape[-2:]
-        scores = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :],
-                           scores, SEGMENT_MASK_BIAS)
+        rows, cols = jnp.arange(sq)[:, None], jnp.arange(sk)[None, :]
+        allowed = rows >= cols
+        if window is not None:
+            allowed &= rows - cols < window
+        scores = jnp.where(allowed, scores, SEGMENT_MASK_BIAS)
     # softmax statistics in fp32; the probabilities are cast to the compute
     # dtype BEFORE dropout so the (B, H, S, S) tensors XLA saves for the
     # backward pass (probs + dropped probs) are bf16 — this halves attention

@@ -42,14 +42,27 @@ class Routing(NamedTuple):
 
 @jax.named_scope("moe/router")
 def route(x: jax.Array, kernel: jax.Array, expert_bias, k: int,
-          norm_topk: bool, scaling: float) -> Routing:
-    """Sigmoid scores of x (T, H) over the router's E outputs, in float32:
-    the k experts with the largest score + `expert_bias` are selected (the
-    bias is a buffer: it takes no gradient), the weights are the selected
-    scores WITHOUT the bias, divided by their sum + 1e-6 if `norm_topk`,
-    times `scaling`."""
+          norm_topk: bool, scaling: float,
+          scores: str = "sigmoid") -> Routing:
+    """Scores of x (T, H) over the router's E outputs, in float32.
+    `scores` = "sigmoid": the k experts with the largest sigmoid score +
+    `expert_bias` are selected (the bias is a buffer: it takes no gradient),
+    the weights are the selected scores WITHOUT the bias, divided by their
+    sum + 1e-6 if `norm_topk`, times `scaling`. "softmax": the k largest
+    logits are selected and the weights are the softmax over those k (the
+    full softmax renormalised over the selected, so `norm_topk` changes
+    nothing), times `scaling`; no selection bias."""
     logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if scores == "softmax":
+        if expert_bias is not None:
+            raise ValueError("route: softmax scores take no selection bias")
+        _, experts = jax.lax.top_k(jax.lax.stop_gradient(logits), k)
+        gates = jax.nn.softmax(
+            jnp.take_along_axis(logits, experts, axis=-1), axis=-1)
+        return Routing(experts.astype(jnp.int32), gates * scaling)
+    if scores != "sigmoid":
+        raise ValueError(f"route: unknown scores {scores!r}")
     scores = jax.nn.sigmoid(logits)
     select = scores if expert_bias is None else (
         scores + expert_bias.astype(jnp.float32))
@@ -68,18 +81,23 @@ def _window_sizes(sizes: jax.Array, start, rows: int) -> jax.Array:
                     - jnp.maximum(starts, start), 0, None).astype(jnp.int32)
 
 
-def _swiglu_experts(xs, w1, w3, w2, sizes):
-    """Grouped SwiGLU: rows of xs grouped by expert (`sizes` rows each, in
-    order) through w2_e(silu(w1_e x) * w3_e x)."""
+_GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _swiglu_experts(xs, w1, w3, w2, sizes, activation: str = "silu"):
+    """Grouped gated MLP: rows of xs grouped by expert (`sizes` rows each,
+    in order) through w2_e(act(w1_e x) * w3_e x), act SiLU (SwiGLU) or ReLU
+    (ReGLU)."""
     h1 = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=xs.dtype)
     h3 = jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=xs.dtype)
-    h = (jax.nn.silu(h1.astype(jnp.float32))
+    h = (_GATE_ACTIVATIONS[activation](h1.astype(jnp.float32))
          * h3.astype(jnp.float32)).astype(xs.dtype)
     return jax.lax.ragged_dot(h, w2, sizes,
                               preferred_element_type=jnp.float32)
 
 
-def _window(out, done, x, w1, w3, w2, tokens, gates, sizes, n_pairs, start):
+def _window(out, done, x, w1, w3, w2, tokens, gates, sizes, n_pairs, start,
+            activation: str = "silu"):
     """`out` (T, H) float32 plus the contribution of the sorted pairs in
     [start, start + len(tokens)), and `done` plus how many of them were
     computed: gather their tokens, the grouped experts, gate, and add into
@@ -92,7 +110,7 @@ def _window(out, done, x, w1, w3, w2, tokens, gates, sizes, n_pairs, start):
         xs = jnp.where(live, x[tokens], jnp.zeros([], x.dtype))
         group_rows = _window_sizes(sizes, start, rows)
     with jax.named_scope("moe/experts"):
-        ys = _swiglu_experts(xs, w1, w3, w2, group_rows)
+        ys = _swiglu_experts(xs, w1, w3, w2, group_rows, activation)
     with jax.named_scope("moe/combine"):
         ys = jnp.where(live, ys * gates[:, None], 0.0)
         return out.at[tokens].add(ys), done + jnp.sum(group_rows)
@@ -100,12 +118,16 @@ def _window(out, done, x, w1, w3, w2, tokens, gates, sizes, n_pairs, start):
 
 def held_experts(x: jax.Array, routing: Routing, w1: jax.Array,
                  w3: jax.Array, w2: jax.Array, held: Tuple[int, int],
-                 window_rows: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """sum over the selected AND held experts e of gate_e * w2_e(silu(w1_e
+                 window_rows: int, activation: str = "silu"
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """sum over the selected AND held experts e of gate_e * w2_e(act(w1_e
     x) * w3_e x), for x (T, H); w1, w3 (E_held, H, F), w2 (E_held, F, H);
-    `held` = (lo, hi) of the router's outputs. Returns (the sum (T, H)
+    `held` = (lo, hi) of the router's outputs; `activation` "silu" or
+    "relu". Returns (the sum (T, H)
     float32, tokens per held expert (E_held,) int32, held pairs NOT computed
     () int32: zero by construction, counted so that it is seen to be)."""
+    if activation not in _GATE_ACTIVATIONS:
+        raise ValueError(f"held_experts: unknown activation {activation!r}")
     lo, hi = held
     n_held = hi - lo
     t, k = routing.experts.shape
@@ -130,7 +152,7 @@ def held_experts(x: jax.Array, routing: Routing, w1: jax.Array,
         return jax.lax.cond(
             start < n_pairs,
             lambda: _window(out, done, x, w1, w3, w2, tokens, gates, sizes,
-                            n_pairs, start),
+                            n_pairs, start, activation),
             lambda: (out, done))
 
     def body(carry, inp):

@@ -84,6 +84,20 @@ K and V panels once (no repeated copy of K and V), and the dkv kernel adds
 the group's dk and dv in its float32 accumulators and writes one block a
 key/value head, in the parameters' dtype. Both take the bh layout and the
 split backward whatever the shape.
+
+**A band** (`window=W` with `causal=True`; models/smallthinker.py's
+sliding-window layers): a query attends to the last W positions up to its
+own, `0 <= q_pos - k_pos < W`, of its own segment where rows are packed
+(documents are contiguous, so the distance inside a row is the distance
+inside the document). One more condition of the same mask, and a lower edge
+of the same skip test: a (q block, k block) pair wholly BEHIND the band is
+skipped like one above the diagonal, under the one test a pair for all
+heads of the program (at S = 16,384, W = 4,096 and 512 x 512 tiles 252 of
+the causal triangle's 528 pairs run). The banded calls take the bh layout
+and the split backward whatever the shape, and carry kernel names of their
+own (`flash_win_fwd`, `flash_win_bwd_dq`, `flash_win_bwd_dkv`), so that a
+trace tells the two kinds of layer apart. A program still holds its whole
+(S, D) panels; W + one tile of them would do (PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -335,10 +349,18 @@ def _program(kernel, grid_rank: int, n_out: int, batch_of=None,
     return program
 
 
-def _causal_live(causal: bool, q0, bq: int, k0):
+def _causal_live(causal: bool, q0, bq: int, k0, bk: int = 0,
+                 window: int = 0):
     """Does the (q, k) tile at rows q0.., columns k0.. hold any pair with
-    k <= q? None where attention is not causal."""
-    return (k0 <= q0 + (bq - 1)) if causal else None
+    k <= q (and, under a band of `window` positions, q - k < window: the
+    tile's nearest pair is its first row against its last column)? None
+    where attention is not causal."""
+    if not causal:
+        return None
+    live = k0 <= q0 + (bq - 1)
+    if window:
+        live = live & (q0 - (k0 + (bk - 1)) < window)
+    return live
 
 
 def _causal_pos(causal: bool, q0, k0, bq: int, bk: int):
@@ -350,15 +372,18 @@ def _causal_pos(causal: bool, q0, k0, bq: int, bk: int):
     return _rows(jnp.int32, bq), _cols(jnp.int32, bk) + (k0 - q0)
 
 
-def _mask(s, rows, cols, segq, segk):
+def _mask(s, rows, cols, segq, segk, window: int = 0):
     """Scores with the disallowed pairs at NEG_INF: other segments and pad
     (packed rows: segq (bq, 1), segk (1, bk) from `_seg_keys`, allowed
     where equal; None otherwise), later positions (causal: `_causal_pos`;
-    None otherwise). One compare per condition on the tile; everything else
-    is the vectors'."""
+    None otherwise), and positions `window` or more back (a band; 0: none).
+    One compare per condition on the tile; everything else is the
+    vectors'."""
     allowed = None if segq is None else segq == segk
     if rows is not None:
         tri = rows >= cols
+        if window:
+            tri = tri & (rows < cols + window)
         allowed = tri if allowed is None else allowed & tri
     return s if allowed is None else jnp.where(allowed, s, NEG_INF)
 
@@ -427,7 +452,7 @@ def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
 
 
 def _fwd_tile(carry, q, kb, vb, tile_scale, bias, pos, segq, segk, keep,
-              rate: float):
+              rate: float, window: int = 0):
     """One (blk_q, blk_k) tile of the online softmax: (m, l, acc) with the
     keys kb and values vb taken in. `bias` (None, or the pad bias's
     (1, blk_k) row), `pos` (`_causal_pos`) and `keep` (the dropout keep
@@ -444,7 +469,7 @@ def _fwd_tile(carry, q, kb, vb, tile_scale, bias, pos, segq, segk, keep,
         s = s * tile_scale
     if bias is not None:
         s = s + bias()
-    s = _mask(s, *pos(), segq, segk)
+    s = _mask(s, *pos(), segq, segk, window)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
@@ -551,7 +576,7 @@ def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                    segk_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                    scale: float, blk_k: int, rate: float, has_bias: bool,
                    has_segments: bool, batch_of, causal: bool = False,
-                   ranges_ref=None):
+                   window: int = 0, ranges_ref=None):
     """One program per (grid row, q-block): the forward of its heads."""
     row, _, qi = ids
     hp, bq, _ = q_ref.shape
@@ -586,11 +611,11 @@ def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                     keep_rows,
                     _keep_cols(seed_ref[0], row * hp + t, j * blk_k, blk_k),
                     rate),
-                rate)
+                rate, window)
 
         _each_head(hp, head, qrange,
                    _block_range(ranges_ref, batch, s_len // bq + j),
-                   _causal_live(causal, q0, bq, j * blk_k))
+                   _causal_live(causal, q0, bq, j * blk_k, blk_k, window))
 
     for t in range(hp):
         out, l_safe = _fwd_out(l_ref[t], acc_ref[t], segq, rate)
@@ -612,7 +637,7 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                segk_ref, lse_ref, delta_ref, do_ref, dq_ref, acc_ref, *,
                scale: float, blk_k: int, rate: float, has_bias: bool,
                has_segments: bool, batch_of, causal: bool = False,
-               ranges_ref=None):
+               window: int = 0, ranges_ref=None):
     """One program per (grid row, q-block): dq of its heads."""
     row, qi = ids
     hp, bq, _ = q_ref.shape
@@ -644,7 +669,7 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
             if has_bias:
                 s = s + bias_ref[0, 0, cols][None, :]
             s = _mask(s, *_causal_pos(causal, q0, j * blk_k, bq, blk_k),
-                      segq, segk)
+                      segq, segk, window)
             p = jnp.exp(s - lse_ref[t, 0][:, None])
             dp = jax.lax.dot_general(
                 do_ref[t], vb, (((1,), (1,)), ((), ())),
@@ -662,7 +687,7 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
 
         _each_head(hp, head, qrange,
                    _block_range(ranges_ref, batch, s_len // bq + j),
-                   _causal_live(causal, q0, bq, j * blk_k))
+                   _causal_live(causal, q0, bq, j * blk_k, blk_k, window))
 
     dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
@@ -671,7 +696,7 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                 segk_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
                 dk_acc_ref, dv_acc_ref, *, scale: float, blk_q: int,
                 rate: float, has_bias: bool, has_segments: bool, batch_of,
-                causal: bool = False, ranges_ref=None):
+                causal: bool = False, window: int = 0, ranges_ref=None):
     """One program per (grid row, k-block): dk and dv of its heads'
     key/value heads. The query heads of a group add into the float32
     accumulators of their one key/value head, and ONE block a key/value
@@ -711,7 +736,7 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
             if has_bias:
                 s = s + bias
             s = _mask(s, *_causal_pos(causal, i * blk_q, k0, blk_q, bk),
-                      segq, segk)
+                      segq, segk, window)
             p = jnp.exp(s - lse_ref[t, 0, rows][:, None])
             if rate > 0.0:
                 keep = _keep_tile(
@@ -734,7 +759,7 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                 preferred_element_type=jnp.float32) * out_scale
 
         _each_head(hp, head, _block_range(ranges_ref, batch, i), krange,
-                   _causal_live(causal, i * blk_q, blk_q, k0))
+                   _causal_live(causal, i * blk_q, blk_q, k0, bk, window))
 
     dv = dv_acc_ref[...]
     if rate > 0.0:
@@ -877,13 +902,21 @@ _LONG_SEQ_VMEM_BYTES = 64 * 1024 * 1024
 _TILE_VMEM_BYTES = 16 * 1024 * 1024
 
 
-def _long_seq_params(s: int, lanes: int) -> dict:
+def _long_seq_params(s: int, lanes: int, panels: int = 0) -> dict:
+    """`panels`: the bytes of a bh-layout program's resident panels
+    (`_panel_bytes` times its heads). A group of query heads is a program
+    whatever its size (`_bh_heads_per_prog`), so where its panels leave less
+    than `_TILE_VMEM_BYTES` of `_LONG_SEQ_VMEM_BYTES` (seven heads of 128 at
+    S = 16,384: the dkv kernel's Q and dO panels are 56 MiB) the call asks
+    for the panels plus that much; every other call asks what it always
+    did."""
     if s * lanes <= _FUSED_BWD_MAX_PANEL:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=_LONG_SEQ_VMEM_BYTES)}
+        vmem_limit_bytes=max(_LONG_SEQ_VMEM_BYTES,
+                             panels + _TILE_VMEM_BYTES))}
 
 
 def _panel_spec(block: tuple, index_map, long_seq: dict) -> pl.BlockSpec:
@@ -1014,12 +1047,13 @@ def _bh_heads_per_prog(s: int, h: int, d: int, dv: int, group: int) -> int:
 
 
 def _layout(b: int, s: int, h: int, d: int, group: int = 1,
-            dv: int = 0) -> _Layout:
+            dv: int = 0, window: int = 0) -> _Layout:
     """`group` query heads to a key/value head: grouped heads take the bh
     layout, where a program owns the group and its one key/value head; so do
-    values of another width `dv` than the keys' (latent attention)."""
+    values of another width `dv` than the keys' (latent attention) and a
+    band (`window`)."""
     dv = dv or d
-    if group == 1 and dv == d and _use_native(s, h, d):
+    if group == 1 and dv == d and not window and _use_native(s, h, d):
         hp = _heads_per_prog(h, d)
         return _Layout(True, h, b, h // hp, hp)
     hp = _bh_heads_per_prog(s, h, d, dv, group)
@@ -1051,14 +1085,16 @@ def _per_batch_spec(present: bool, width: int, index_map):
     return pl.BlockSpec(_DUMMY_BLOCK, lambda *_: (0, 0, 0))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
                     dropout_rate: float = 0.0, interpret: bool = False,
-                    causal: bool = False):
+                    causal: bool = False, window: int = 0):
     """q: (B, S, H, D); k: (B, S, Hkv, D), v: (B, S, Hkv, Dv) with H a
     multiple of Hkv (query head i reads key/value head i // (H // Hkv)) and
     Dv = D but for latent attention; `causal`: a query attends
-    to positions <= its own. bias: (B, 1, 1, S) additive or None;
+    to positions <= its own; `window` (with `causal`; 0: none): and to the
+    last `window` positions only, its own among them. bias: (B, 1, 1, S)
+    additive or None;
     segment_ids: (B, S) int32 packing segments (1..n, 0 = pad) or None —
     attention is restricted to q_seg == k_seg blocks, the packed-sequence
     block-diagonal mask. dropout_seed: () or (1,) int32 array (traced OK);
@@ -1070,14 +1106,17 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
     path, which differentiates through the bias correctly. segment_ids are
     integer data (zero/float0 cotangent), like the seed."""
     out, _ = _flash_fwd(q, k, v, bias, segment_ids, dropout_seed,
-                        dropout_rate, interpret, causal)
+                        dropout_rate, interpret, causal, window)
     return out
 
 
-def _kernel_name(name: str, d: int, dv: int) -> str:
+def _kernel_name(name: str, d: int, dv: int, window: int = 0) -> str:
     """The kernels at values of a width of their own (latent attention:
-    keys 192, values 128) carry names of their own in the HLO and the
-    device trace, so that what reads `flash_fwd` never reads them."""
+    keys 192, values 128) and the banded kernels (`flash_win_fwd`, ...)
+    carry names of their own in the HLO and the device trace, so that what
+    reads `flash_fwd` never reads them."""
+    if window:
+        name = name.replace("flash_", "flash_win_", 1)
     return name if d == dv else "mla_" + name
 
 
@@ -1089,22 +1128,33 @@ def _scratch(*shapes) -> list:
     return [pltpu.VMEM(shape, jnp.float32) for shape in shapes]
 
 
+def _band_kw(causal: bool, window: int) -> dict:
+    """The kernels' `causal` and `window` keywords, each only where set, so
+    that the bidirectional kernels, and the causal ones without a band,
+    trace as they always did."""
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True (the "
+                         "bidirectional kernels have no band)")
+    if window < 0:
+        raise ValueError(f"flash_attention: window {window}")
+    return dict({"causal": True} if causal else {},
+                **({"window": int(window)} if window else {}))
+
+
 def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
-               causal=False):
+               causal=False, window=0):
     b, s, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[3]
     if h % hkv or k.shape[:3] != v.shape[:3]:
         raise ValueError(f"flash_attention: {h} query heads over k"
                          f"{tuple(k.shape)} v{tuple(v.shape)}")
-    # keyword only where set, so that the bidirectional kernels trace as
-    # they always did
-    ckw = {"causal": True} if causal else {}
+    ckw = _band_kw(causal, window)
     blk_q = _pick_block(s, DEFAULT_BLK_Q)
     blk_k = _pick_block(s, DEFAULT_BLK_K)
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_segments = segment_ids is not None
-    lay = _layout(b, s, h, d, h // hkv, dv)
+    lay = _layout(b, s, h, d, h // hkv, dv, window)
     hp = lay.heads_per_prog
     kvh = hp * hkv // h     # key/value heads of a program's heads
     # shared by both layouts: the cross-layout bit-parity contract depends
@@ -1119,7 +1169,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     live_spec, live = _live_rows(seg2, skip_rows)
     kw = dict(scale=scale, blk_k=blk_k, rate=rate, has_bias=has_bias,
               has_segments=has_segments, **ckw)
-    params = _long_seq_params(s, hp * d)
+    params = _long_seq_params(s, hp * d, hp * _panel_bytes(s, d, dv))
     if lay.native:
         rng_spec, rng, scratch = [], [], []
         kernel = functools.partial(_fwd_kernel, heads_per_prog=hp,
@@ -1162,7 +1212,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=scratch,
-        name=_kernel_name("flash_fwd", d, dv),
+        name=_kernel_name("flash_fwd", d, dv, window),
         interpret=interpret,
         **params,
     )(*live, *rng, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
@@ -1176,14 +1226,14 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
 
 
 def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret,
-                    causal=False):
+                    causal=False, window=0):
     out, res = _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
-                          causal)
+                          causal, window)
     return out, (res, seed, q.shape, bias is not None,
                  segment_ids is not None)
 
 
-def _flash_bwd_rule(rate, interpret, causal, saved, g):
+def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
     # residuals are in the kernel layout _flash_fwd chose (same
     # deterministic shape gate); lse is in `_Layout.row_sums`' layout
     (qx, kx, vx, bias2, seg2, lse, outx), seed, qshape, has_bias, \
@@ -1197,7 +1247,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
     skip_rows = _skip_pad_rows(has_segments, tiles)
     live_spec, live = _live_rows(seg2, skip_rows)
     scale = 1.0 / (d ** 0.5)
-    lay = _layout(b, s, h, d, h // hkv, dv)
+    lay = _layout(b, s, h, d, h // hkv, dv, window)
     gx = lay.pack(g)
     # delta = rowsum(dO * O) per head (cheap elementwise — jnp, not a kernel)
     delta = lay.row_sums(gx.astype(jnp.float32) * outx.astype(jnp.float32),
@@ -1208,12 +1258,11 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         delta = delta * (1.0 - rate)
     seed_arr = _seed_operand(seed)
     kw = dict(scale=scale, rate=rate, has_bias=has_bias,
-              has_segments=has_segments, **({"causal": True} if causal
-                                            else {}))
+              has_segments=has_segments, **_band_kw(causal, window))
 
     one = lay.one_head()
     if (s * one.heads_per_prog * d <= _FUSED_BWD_MAX_PANEL and dv == d
-            and hkv == h):
+            and hkv == h and not window):
         # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated
         # once instead of twice
         hp = one.heads_per_prog
@@ -1248,7 +1297,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
         # length)
         hp = lay.heads_per_prog
         kvh = hp * hkv // h
-        params = _long_seq_params(s, hp * d)
+        params = _long_seq_params(s, hp * d, hp * _panel_bytes(s, d, dv))
         rng_spec, rng = _block_ranges(seg2, blk_q, blk_k,
                                       _tile_skip(has_segments, tiles))
         batch_of = lay.batch if skip_rows else None
@@ -1278,7 +1327,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             out_specs=q_blk_bs,
             out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
             scratch_shapes=_scratch((hp, blk_q, d)),
-            name=_kernel_name("flash_bwd_dq", d, dv),
+            name=_kernel_name("flash_bwd_dq", d, dv, window),
             interpret=interpret,
             **params,
         )(*live, *rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,
@@ -1308,7 +1357,7 @@ def _flash_bwd_rule(rate, interpret, causal, saved, g):
             out_shape=[jax.ShapeDtypeStruct(kx.shape, kx.dtype),
                        jax.ShapeDtypeStruct(vx.shape, vx.dtype)],
             scratch_shapes=_scratch((kvh, blk_k, d), (kvh, blk_k, dv)),
-            name=_kernel_name("flash_bwd_dkv", d, dv),
+            name=_kernel_name("flash_bwd_dkv", d, dv, window),
             interpret=interpret,
             **params,
         )(*live, *rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,

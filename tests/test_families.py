@@ -17,7 +17,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from bert_pytorch_tpu.config import (MODEL_FAMILIES, BertConfig,  # noqa: E402
-                                     KimiLinearConfig, Lfm2MoeConfig)
+                                     KimiLinearConfig, Lfm2MoeConfig,
+                                     SmallThinkerConfig)
 from bert_pytorch_tpu.models.families import FAMILIES, family_of  # noqa: E402
 
 VOCAB, SEQ = 2048, 64
@@ -46,6 +47,14 @@ KIMI_TOY = {
     "num_experts": 2, "experts_total": 4, "experts_held": [0, 2],
     "num_experts_per_token": 2, "kda_chunk_size": 16,
 }
+SMALLTHINKER_TOY = {
+    "model_type": "smallthinker", "vocab_size": VOCAB, "hidden_size": 32,
+    "head_dim": 8, "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "moe_ffn_hidden_size": 16,
+    "moe_num_primary_experts": 2, "experts_total": 4, "experts_held": [0, 2],
+    "moe_num_active_primary_experts": 2, "rope_layout": [0, 1],
+    "sliding_window_layout": [0, 1], "sliding_window_size": 8,
+}
 TINY = {
     "bert": BertConfig(
         vocab_size=VOCAB, hidden_size=32, num_hidden_layers=2,
@@ -54,6 +63,8 @@ TINY = {
         attention_impl="xla"),
     "lfm2_moe": Lfm2MoeConfig.from_dict(LFM2_TOY).replace(dtype="float32"),
     "kimi_linear": KimiLinearConfig.from_dict(KIMI_TOY).replace(
+        dtype="float32"),
+    "smallthinker": SmallThinkerConfig.from_dict(SMALLTHINKER_TOY).replace(
         dtype="float32"),
 }
 

@@ -120,31 +120,37 @@ def test_flash_split_backward_compiles(v5e, bias, segments,
                        grad=True) == SPLIT
 
 
-@pytest.mark.parametrize("cell,b,s,h,hkv,d,dv,heads", [
+@pytest.mark.parametrize("cell,b,s,h,hkv,d,dv,heads,window", [
     # lfm2-ep8-clm-8k-packed: a program owns a key/value head's four query
     # heads
-    ("lfm2", 4, 8192, 32, 8, 64, 64, 4),
+    ("lfm2", 4, 8192, 32, 8, 64, 64, 4, 0),
     # kimi-linear-ep32-clm-16k-packed: four heads' K and V panels, 48 MiB in
     # one buffer each
-    ("kimi", 1, 16384, 32, 32, 192, 128, 4),
+    ("kimi", 1, 16384, 32, 32, 192, 128, 4, 0),
+    # smallthinker-ep8-clm-16k-fullrow: a program owns the SEVEN query heads
+    # of a key/value head of 128; the dkv kernel's Q and dO panels are
+    # 56 MiB, so the calls ask 72 MiB (`_long_seq_params`); its full layers
+    # and its layers banded at 4,096
+    ("smallthinker-full", 1, 16384, 28, 4, 128, 128, 7, 0),
+    ("smallthinker-band", 1, 16384, 28, 4, 128, 128, 7, 4096),
 ])
 def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
-                                                   d, dv, heads):
-    """The causal split kernels at the two decoder cells' shapes, at the
-    heads a program that `_layout` picks, inside the VMEM the calls ask for
-    (`_LONG_SEQ_VMEM_BYTES`: the compiler refuses a kernel that needs
-    more): dynamic head indices into the blocks, the rolled loop over the
-    heads and the scratch accumulators are what interpret mode cannot
-    refuse."""
-    assert fa._layout(b, s, h, d, h // hkv, dv).heads_per_prog == heads
+                                                   d, dv, heads, window):
+    """The causal split kernels at the decoder cells' shapes, at the heads a
+    program that `_layout` picks, inside the VMEM the calls ask for
+    (`_long_seq_params`: the compiler refuses a kernel that needs more):
+    dynamic head indices into the blocks, the rolled loop over the heads
+    and the scratch accumulators are what interpret mode cannot refuse."""
+    assert fa._layout(b, s, h, d, h // hkv, dv,
+                      window).heads_per_prog == heads
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
 
     def bwd(q, k, v, seg):
         return jax.grad(lambda q, k, v: fa.flash_attention(
-            q, k, v, None, seg, None, 0.0, False, True)
+            q, k, v, None, seg, None, 0.0, False, True, window)
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    name = (lambda n: n) if d == dv else (lambda n: "mla_" + n)
+    name = lambda n: fa._kernel_name(n, d, dv, window)  # noqa: E731
     assert _kernels(bwd, sds((b, s, h, d), jnp.bfloat16),
                     sds((b, s, hkv, d), jnp.bfloat16),
                     sds((b, s, hkv, dv), jnp.bfloat16),
