@@ -74,7 +74,7 @@ from bert_pytorch_tpu.models.lfm2_moe import (LM_REMAT_POLICIES, DenseMLP,
                                               expert_scalars)
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 from bert_pytorch_tpu.ops.decoder_ops import short_conv
-from bert_pytorch_tpu.ops.kda import kda_scan
+from bert_pytorch_tpu.ops.kda import kda_scan, kernel_mode
 
 Dtype = Any
 
@@ -298,7 +298,10 @@ def pretrain_loss_fn_builder(model) -> Callable:
     the routed layers' counters as lfm2's, and the KDA scans' useful work,
     counted from the rows the scans are given: tokens that are no padding
     (times the KDA layers) and documents started (state resets a layer,
-    padding slots included), each summed over the micro-batches."""
+    padding slots included), each summed over the micro-batches; and
+    `kda_kernel_tokens`, the `kda_tokens` of the layers whose scan walks its
+    chunks with the Pallas kernels (ops/kda.kernel_mode, asked when the step
+    is traced, as `kda_scan` asks it: all of them or, on the XLA scans, 0)."""
     cfg = model.config
     kda_layers = sum(mixer == "kda" for mixer, _ in cfg.layer_kinds)
 
@@ -310,11 +313,16 @@ def pretrain_loss_fn_builder(model) -> Callable:
             hidden, head, batch["input_ids"], batch["segment_ids"],
             LOSS_BLOCK_ROWS)
         with jax.named_scope("metrics"):
+            kda_tokens = kda_layers * jnp.sum(batch["segment_ids"] > 0,
+                                              dtype=jnp.int32)
+            on_kernels = kernel_mode(cfg.kda_head_dim, cfg.kda_head_dim,
+                                     cfg.kda_chunk_size,
+                                     KDA_BLOCK_CHUNKS) is not None
             scalars = dict(
                 expert_scalars(count, batch["input_ids"].size
                                * cfg.num_experts_per_tok, load, dropped),
-                kda_tokens=kda_layers * jnp.sum(batch["segment_ids"] > 0,
-                                                dtype=jnp.int32),
+                kda_tokens=kda_tokens,
+                kda_kernel_tokens=kda_tokens * int(on_kernels),
                 kda_resets=jnp.sum(batch["position_ids"] == 0,
                                    dtype=jnp.int32))
         return loss, {"scalars": scalars}

@@ -28,19 +28,34 @@ stays above -88 (float32); beyond that the chunk size is too long for the
 decay and the results are not finite.
 
 What runs where: the chunk-local algebra (`_prepare`) over a block of
-`block` chunks at once, as batched matrix products; the carried state
-through the block's chunks one after another (`_chunk_fwd`, a `lax.scan`);
-blocks one after another (an outer scan), so that the temporaries are one
-block's. The backward pass is written here, not derived from the scans:
-per block, from the state at its start (kept by the forward pass: one
-state per block), the chunk states again, then the chunks in reverse
-carrying dS (`_chunk_bwd`), then the chunk-local algebra's cotangents
-(`jax.vjp` of `_prepare`, whose inverse has its own rule, dA = -T^T dT
-T^T). Matrix products take `mm_dtype` operands and accumulate in float32;
-decays, their sums, beta, the inverse and the carried state are float32.
+`block` chunks at once, as batched matrix products in plain XLA; the carried
+state through the block's chunks one after another (`_chunks_fwd`); blocks
+one after another (an outer scan, one start state kept a block), so that
+the temporaries are one block's. The backward pass is written here, not
+derived: per block, from the state at its start, the chunks' start states
+again and then the chunks in reverse carrying dS (`_chunks_bwd`), then the
+chunk-local algebra's cotangents (`jax.vjp` of `_prepare`, whose inverse
+has its own rule, dA = -T^T dT T^T). Matrix products take `mm_dtype`
+operands and accumulate in float32; decays, their sums, beta, the inverse
+and the carried state are float32.
 
-Scope `kda/scan` (training/pretrain.LM_STEP_SCOPES), opened in every body:
-inside a scan or a custom rule an operation keeps the scopes of the body.
+The chunks of a block are walked in one of two ways, chosen by what can be
+observed when the call is traced (`kernel_mode`; no flag):
+- on a TPU backend (elsewhere only under BPT_PALLAS_INTERPRET=1, in
+  interpret mode), on one device, where the head widths are multiples of 128
+  and the chunk a multiple of 8: the Pallas kernels `kda_fwd` / `kda_bwd`
+  (ops/pallas/kda.py), one call a block and pass, the state, its cotangent
+  and (backward) every chunk's start state in VMEM, so that no loop over
+  chunks is left in the program;
+- otherwise `_chunk_fwd` / `_chunk_bwd` under `lax.scan`: every CPU run, the
+  toy widths of the tests, a mesh, and the kernels' oracle (the same
+  products, float32 sums in another order).
+models/kimi_linear.py counts the tokens of the first way as
+`kda_kernel_tokens` beside `kda_tokens`.
+
+Scope `kda/scan` (training/pretrain.LM_STEP_SCOPES), opened in every body
+and around the kernels' calls: inside a scan or a custom rule an operation
+keeps the scopes of the body.
 """
 
 from __future__ import annotations
@@ -160,34 +175,100 @@ def _chunk_bwd(mm_dtype, dstate, chunk):
         return dstate, dprep
 
 
-def _block_fwd(mm_dtype, state, block):
+def kernel_mode(head_dim: int, value_dim: int, chunk: int, block: int):
+    """How the chunks of a block are walked, by what can be observed: None,
+    the XLA scans (`_chunk_fwd` / `_chunk_bwd`); else the Pallas kernels of
+    ops/pallas/kda.py, and the value is their `interpret` argument: False on
+    a TPU backend, True elsewhere under BPT_PALLAS_INTERPRET=1 (the
+    convention of ops/attention.py and ops/layernorm.py). The kernels want
+    head widths that fill the lanes (multiples of 128), a chunk of whole
+    sublanes (a multiple of 8), a block whose chunk states fit VMEM beside
+    the tiles (`kda_bwd` keeps eight heads' between its two walks: 16 MiB
+    at 32 chunks of 128 x 128; up to 64 MiB), and one device: a mesh cannot
+    split them."""
+    from bert_pytorch_tpu.ops.attention import _pallas_interpret, active_mesh
+
+    on_tpu = jax.default_backend() == "tpu"
+    if (head_dim % 128 or value_dim % 128 or chunk % 8
+            or block * 8 * head_dim * value_dim * 4 > 64 * 1024 * 1024
+            or not (on_tpu or _pallas_interpret())
+            or active_mesh() is not None):
+        return None
+    return not on_tpu
+
+
+def _row_major(x):
+    """x, held to the layout its shape spells. The kernels take and return
+    chunk-major arrays in that layout; the stacked gradients that leave the
+    backward pass's scan over blocks are free, and with the kernels in its
+    body XLA:TPU's layout assignment lays them out tokens-major for the
+    transposition that follows the scan, and every fusion of the body with
+    them: transposing copies and strided fusions around each call (the
+    compiled step then holds 1,188 arrays in such layouts where the scans'
+    step holds 20, and the kernels' gain goes to them)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def _chunks_fwd(mm_dtype, kernels, state, prep):
+    """The chunks of a block one after another from `state`: (the state
+    after the last, the outputs). The state is (B, H, Dk, Dv) under the
+    scans and its transpose under the kernels (ops/pallas/kda.py: nothing
+    else reads it)."""
+    if kernels is None:
+        new, (out, _) = jax.lax.scan(
+            functools.partial(_chunk_fwd, mm_dtype), state, prep)
+        return new, out
+    from bert_pytorch_tpu.ops.pallas.kda import kda_fwd
+
     with jax.named_scope(SCOPE):
-        prep = _prepare(*block, mm_dtype)
-    return jax.lax.scan(functools.partial(_chunk_fwd, mm_dtype), state, prep)
+        return kda_fwd(state, *prep, mm_dtype=mm_dtype, interpret=kernels)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _blocks(q, k, v, g, beta, rid, prev, mm_dtype):
+def _chunks_bwd(mm_dtype, kernels, state, dstate, prep, dout):
+    """The same chunks again from `state`, for the state each starts from,
+    then in reverse from the cotangent of the state they hand on: (that of
+    the state they started from, those of `prep`)."""
+    if kernels is None:
+        _, (_, states) = jax.lax.scan(
+            functools.partial(_chunk_fwd, mm_dtype), state, prep)
+        return jax.lax.scan(functools.partial(_chunk_bwd, mm_dtype), dstate,
+                            (prep, states, dout), reverse=True)
+    from bert_pytorch_tpu.ops.pallas.kda import kda_bwd
+
+    with jax.named_scope(SCOPE):
+        return kda_bwd(state, dstate, *prep, dout, mm_dtype=mm_dtype,
+                       interpret=kernels)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _blocks(q, k, v, g, beta, rid, prev, mm_dtype, kernels):
     """Blocks of chunks, chunk-major: q, k, v, g (blocks, chunks, B, H, C,
     D), beta (blocks, chunks, B, H, C), rid (blocks, chunks, B, C), prev
-    (blocks, chunks, B) -> outputs like v, float32."""
-    return _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype)[0]
+    (blocks, chunks, B) -> outputs like v, float32. `kernels`:
+    `kernel_mode`'s answer."""
+    return _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype, kernels)[0]
 
 
-def _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype):
-    b, h, d = q.shape[2], q.shape[3], q.shape[5]
+def _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype, kernels):
+    b, h, d, dv = q.shape[2], q.shape[3], q.shape[5], v.shape[5]
 
     def body(state, block):
-        new, (out, _) = _block_fwd(mm_dtype, state, block)
+        with jax.named_scope(SCOPE):
+            prep = _prepare(*block, mm_dtype)
+        new, out = _chunks_fwd(mm_dtype, kernels, state, prep)
         return new, (out, state)
 
     _, (out, starts) = jax.lax.scan(
-        body, jnp.zeros((b, h, d, v.shape[5]), jnp.float32),
+        body, jnp.zeros((b, h, d, dv) if kernels is None else (b, h, dv, d),
+                        jnp.float32),
         (q, k, v, g, beta, rid, prev))
     return out, (q, k, v, g, beta, rid, prev, starts)
 
 
-def _blocks_bwd(mm_dtype, saved, dout):
+def _blocks_bwd(mm_dtype, kernels, saved, dout):
     q, k, v, g, beta, rid, prev, starts = saved
 
     def body(dstate, block):
@@ -195,13 +276,13 @@ def _blocks_bwd(mm_dtype, saved, dout):
         with jax.named_scope(SCOPE):
             prep, pull = jax.vjp(
                 lambda *x: _prepare(*x, *inputs[5:], mm_dtype), *inputs[:5])
-        _, (_, states) = jax.lax.scan(
-            functools.partial(_chunk_fwd, mm_dtype), state, prep)
-        dstate, dprep = jax.lax.scan(
-            functools.partial(_chunk_bwd, mm_dtype), dstate,
-            (prep, states, dout), reverse=True)
+        dstate, dprep = _chunks_bwd(mm_dtype, kernels, state, dstate, prep,
+                                    dout)
         with jax.named_scope(SCOPE):
-            return dstate, pull(dprep)
+            grads = pull(dprep)
+            if kernels is not None:
+                grads = tuple(_row_major(x) for x in grads)
+            return dstate, grads
 
     _, grads = jax.lax.scan(
         body, jnp.zeros_like(starts[0]),
@@ -258,7 +339,9 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         out = _blocks(heads_first(q), heads_first(k), heads_first(v),
                       heads_first(g),
                       jnp.swapaxes(chunked(beta.astype(jnp.float32)), 3, 4),
-                      rid, prev, mm_dtype)
+                      rid, prev, mm_dtype,
+                      kernel_mode(q.shape[-1], v.shape[-1], chunk,
+                                  per_block))
         # (blocks, chunks, B, H, C, Dv) -> (B, S, H, Dv)
         out = jnp.moveaxis(jnp.swapaxes(out, 3, 4), 2, 0)
         return out.reshape(b, n * chunk, h, -1)[:, :s]

@@ -6,6 +6,9 @@ native CUDA dependencies (SURVEY §2.3):
                          the attention inner loop without materializing SxS)
   fused_optim.py      <- apex amp_C multi_tensor_lamb stage1+2 / FusedLAMB
                          (optimization.py:27-33, run_squad.py:703-725)
+  kda.py              <- (no reference equivalent; the chunks of the gated
+                         delta-rule recurrence of ops/kda.py with the carried
+                         state in VMEM, imported where ops/kda.py takes them)
 
 History note on fused_optim: earlier rounds deliberately skipped a
 multi-tensor update kernel — measured on v5e (BERT-Large, batch 48) the

@@ -3,7 +3,7 @@
 the decoder families returns (models/lfm2_moe.expert_scalars:
 `moe_l<layer>_e<expert>`, `moe_l<layer>_dropped`, `moe_pairs_routed`), and
 of the gated delta-rule scans (models/kimi_linear.py: `kda_tokens`,
-`kda_resets`, passed on as they are summed).
+`kda_kernel_tokens`, `kda_resets`, passed on as they are summed).
 
 Per routed layer L, since the run began: `moe_l<L>_pairs` (token, expert)
 pairs routed to the experts held here; `moe_l<L>_load_min/_mean/_max` the
@@ -19,7 +19,7 @@ from typing import Dict
 
 _LOAD = re.compile(r"^moe_l(\d+)_e(\d+)$")
 _DROPPED = re.compile(r"^moe_l(\d+)_dropped$")
-_KDA = ("kda_tokens", "kda_resets")
+_KDA = ("kda_tokens", "kda_kernel_tokens", "kda_resets")
 
 
 class ExpertLoadCounters:

@@ -10,6 +10,7 @@ in interpret mode; the entry point's family selection and counters."""
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -26,7 +27,7 @@ from bert_pytorch_tpu.config import (KimiLinearConfig,  # noqa: E402
                                      load_model_config)
 from bert_pytorch_tpu.models import kimi_linear, lfm2_moe  # noqa: E402
 from bert_pytorch_tpu.ops.attention import dot_product_attention  # noqa: E402
-from bert_pytorch_tpu.ops.kda import (kda_scan,  # noqa: E402
+from bert_pytorch_tpu.ops.kda import (kda_scan, kernel_mode,  # noqa: E402
                                       unit_lower_inverse)
 
 TOY = {
@@ -171,6 +172,47 @@ def test_loss_gradients_and_counts_match_the_reference(toy):
             assert _rel(got, ref_leaf) < 2e-5, name
 
 
+@pytest.mark.parametrize("width", [16, 128], ids=["xla-scans", "kernels"])
+def test_kda_kernel_tokens_counts_the_scans_that_took_the_kernels(
+        width, monkeypatch):
+    """`kda_kernel_tokens` is `kda_tokens` where the scans walk their chunks
+    with the Pallas kernels and 0 where they do not: under
+    BPT_PALLAS_INTERPRET=1 a head width of 16 does not tile and keeps the
+    XLA scans, 128 takes the kernels; without the variable both scan."""
+    raw = dict(TOY, linear_attn_config=dict(TOY["linear_attn_config"],
+                                            head_dim=width))
+    cfg = KimiLinearConfig.from_dict(raw).replace(
+        dtype="float32", checkpoint_activations=True)
+    model = kimi_linear.KimiLinearForCausalLM(cfg, dtype=jnp.float32)
+    ids, seg, pos = _packed(rows=1)
+    batch = {"input_ids": jnp.asarray(ids), "segment_ids": jnp.asarray(seg),
+             "position_ids": jnp.asarray(pos)}
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), batch["input_ids"],
+                                 batch["segment_ids"],
+                                 batch["position_ids"])["params"]
+    loss_fn = kimi_linear.pretrain_loss_fn_builder(model)
+
+    def scalars_and_calls():
+        # the four KDA layers call ONE jitted `kda_fwd` (traced once a
+        # signature: ops/pallas/kda._once_a_signature), by its name
+        fn = lambda p: loss_fn(p, batch, None)  # noqa: E731
+        text = str(jax.make_jaxpr(fn)(params))
+        assert text.count("name=kda_fwd") == ("_fwd_block" in text)
+        return (jax.jit(fn)(params)[1]["scalars"],
+                len(re.findall(r"\b_fwd_block\b", text)) >= 4)
+
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    scalars, calls = scalars_and_calls()
+    assert int(scalars["kda_tokens"]) == 4 * (128 - 8)
+    took = width == 128
+    assert calls == took
+    assert int(scalars["kda_kernel_tokens"]) == (
+        int(scalars["kda_tokens"]) if took else 0)
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "0")
+    scalars, calls = scalars_and_calls()
+    assert not calls and int(scalars["kda_kernel_tokens"]) == 0
+
+
 def test_one_lamb_step_matches_the_reference(toy):
     import run_pretraining
     from bert_pytorch_tpu.optim import schedulers
@@ -207,21 +249,36 @@ def test_one_lamb_step_matches_the_reference(toy):
         assert float(moved[f"['layer_1']['kda']['{name}']"][0]) > 0.0
 
 
-@pytest.mark.parametrize("chunk,length,block,decay", [
-    (16, 200, 3, 0.5), (64, 333, 32, 0.5), (64, 128, 1, 0.5),
-    (16, 64, 32, 0.5), (64, 256, 2, 2.0)],
+@pytest.mark.parametrize("chunk,length,block,decay,heads,width", [
+    (16, 200, 3, 0.5, 2, 16), (64, 333, 32, 0.5, 2, 16),
+    (64, 128, 1, 0.5, 2, 16), (16, 64, 32, 0.5, 2, 16),
+    (64, 256, 2, 2.0, 2, 16),
+    # the Pallas kernels (ops/pallas/kda.py) in interpret mode, at a head
+    # width that fills the lanes: documents shorter than a chunk and a
+    # boundary inside one over five blocks of three chunks; a ragged length
+    # in one block; eight heads a program, two programs, a chunk a block;
+    # a chunk of eight tokens; strong decay over two blocks
+    (16, 200, 3, 0.5, 2, 128), (64, 333, 32, 0.5, 2, 128),
+    (64, 128, 1, 0.5, 16, 128), (8, 100, 4, 0.5, 2, 128),
+    (64, 256, 2, 2.0, 2, 128)],
     ids=["c16-s200", "c64-s333", "c64-s128-block1", "c16-s64",
-         "c64-strong-decay"])
-def test_chunked_kda_matches_the_token_by_token_recurrence(chunk, length,
-                                                           block, decay):
+         "c64-strong-decay", "kernels-c16-s200", "kernels-c64-s333",
+         "kernels-c64-s128-block1-h16", "kernels-c8-s100",
+         "kernels-c64-strong-decay"])
+def test_chunked_kda_matches_the_token_by_token_recurrence(
+        chunk, length, block, decay, heads, width, monkeypatch):
     """ops/kda.py against the reference's recurrence, forward and the
     hand-written backward, over documents shorter than a chunk, across
     several chunks and blocks, and a padded tail of one-slot documents.
     Strong decay (up to e^-2 a token and more: a chunk's exp(G) exp(-G)
     products overflow above the diagonal, which the masks have to keep out
     of every cotangent; on the chip the first step read nan before they
-    did, PR 33)."""
-    b, h, d = 2, 2, 16
+    did, PR 33). At a head width of 128 under BPT_PALLAS_INTERPRET=1 the
+    chunks of a block are walked by the kernels `kda_fwd` / `kda_bwd`, held
+    to the XLA scans (their oracle: the same products in another order) as
+    well as to the recurrence; at 16 the scans run whatever the variable
+    says."""
+    b, h, d = 2, heads, width
     keys = jax.random.split(jax.random.PRNGKey(chunk + length), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = unit(jax.random.normal(keys[0], (b, length, h, d)))
@@ -246,14 +303,32 @@ def test_chunked_kda_matches_the_token_by_token_recurrence(chunk, length,
             ref._delta_rule(*(a[r] for a in x), starts[r])
             for r in range(b)]))
 
-    with jax.default_matmul_precision("highest"):
-        got, got_grads = jax.value_and_grad(chunked, argnums=range(5))(
-            q, k, v, g, beta)
-        want, want_grads = jax.value_and_grad(stepwise, argnums=range(5))(
-            q, k, v, g, beta)
+    def run(fn):
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(fn, argnums=range(5))(q, k, v, g, beta)
+
+    def kernels_traced():
+        text = str(jax.make_jaxpr(jax.grad(chunked, argnums=range(5)))(
+            q, k, v, g, beta))
+        return {n: text.count(f"name={n}") for n in ("kda_fwd", "kda_bwd")}
+
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    taken = kernel_mode(width, width, chunk, block)
+    assert taken is (True if width == 128 else None)
+    assert kernels_traced() == ({"kda_fwd": 1, "kda_bwd": 1} if taken
+                                else {"kda_fwd": 0, "kda_bwd": 0})
+    got, got_grads = run(chunked)
+    want, want_grads = run(stepwise)
     assert float(got) == pytest.approx(float(want), rel=1e-5, abs=1e-4)
     for a, w in zip(got_grads, want_grads):
         assert bool(jnp.isfinite(a).all()) and _rel(a, w) < 2e-5
+    if taken:
+        monkeypatch.setenv("BPT_PALLAS_INTERPRET", "0")
+        assert kernels_traced() == {"kda_fwd": 0, "kda_bwd": 0}
+        scans, scans_grads = run(chunked)
+        assert float(got) == pytest.approx(float(scans), rel=1e-6)
+        for a, w in zip(got_grads, scans_grads):
+            assert _rel(a, w) < 2e-6
 
 
 def test_unit_lower_inverse_and_its_rule():
@@ -433,6 +508,9 @@ def test_entry_point_trains_the_family_and_counts_its_work(tmp_path):
     # the step's tokens that are no padding (slots = routed pairs / top-2)
     for key in ("kda_tokens", "kda_resets"):
         assert last[key] == sum(r[key] for r in train[:-1]) > 0
+    # head width 16 on the CPU: the XLA scans, and the record says so
+    assert last["kda_kernel_tokens"] == 0
+    assert all(r["kda_kernel_tokens"] == 0 for r in train)
     print(sorted(train[0]))
     slots = train[0]["moe_pairs_routed"] // 2
     assert 0.5 * 4 * slots < train[0]["kda_tokens"] < 4 * slots

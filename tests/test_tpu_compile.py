@@ -157,6 +157,55 @@ def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
                     sds((b, s), jnp.int32)) == {name(n): 1 for n in SPLIT}
 
 
+def test_kda_scan_kernels_compile_at_the_kimi_cell(v5e, monkeypatch):
+    """A differentiated `ops/kda.kda_scan` at kimi-linear-ep32-clm-16k-packed's
+    shape (a row of 16,384, 32 heads of 128, chunks of 64, 32 a block, bf16
+    products): `kda_fwd` and `kda_bwd`, eight heads a program and four
+    chunks a grid step, inside the VMEM the calls ask for (the backward's
+    with 16.5 MiB of scratch for the block's states between its two walks);
+    dynamic chunk indices into the blocks, the one-row slices of the decay's
+    tile, the two walks of the backward's grid and the transposes in the
+    rolled loop are what interpret mode cannot refuse. Both under the scope
+    `kda/scan`, which the benchmark's `kda_scan_share.train` and
+    `kda_scan_roofline` read; and the gradients that leave the backward
+    pass's scan hold the layout their shapes spell (`ops/kda._row_major`):
+    with the kernels in its body XLA:TPU otherwise lays them, and every
+    fusion around the calls, out tokens-major."""
+    import re
+
+    from bert_pytorch_tpu.ops import kda
+    from bert_pytorch_tpu.ops.pallas import kda as kda_kernels
+
+    # `kernel_mode` asks the live backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s, h, d, chunk, block = 1, 16384, 32, 128, 64, 32
+    assert kda.kernel_mode(d, d, chunk, block) is False
+    assert kda.kernel_mode(d, d, chunk, 256) is None   # 128 MiB of states
+    assert kda_kernels._tiles(h, block) == (8, 4)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+    x = sds((b, s, h, d), jnp.bfloat16)
+
+    def bwd(q, k, v, g, beta, starts):
+        return jax.grad(lambda *a: kda.kda_scan(
+            *a, starts, chunk=chunk, block=block,
+            mm_dtype=jnp.bfloat16).sum(), argnums=range(5))(q, k, v, g, beta)
+
+    text = jax.jit(bwd).lower(
+        x, x, x, sds((b, s, h, d), jnp.float32), sds((b, s, h), jnp.float32),
+        sds((b, s), jnp.bool_)).compile().as_text()
+    assert hlo.kernel_counts(text) == {"kda_fwd": 1, "kda_bwd": 1}
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    assert len(calls) == 2 and all(
+        re.search(r'op_name="[^"]*kda/scan/[^"]*"', ln) for ln in calls)
+    # (chunks, B, H, C, D) with C major over the chunks and heads: some 300
+    # such arrays without `_row_major`, 4 with it (and in the scans' program)
+    assert len(re.findall(r"\{4,2,0,3,1|\{5,3,1,4,2,0|\{3,1,0,2", text)) < 20
+    # and no transposing copy of the inverse's (C, C) matrices: six a block
+    # where `kda_bwd` takes P and not its transpose
+    assert not re.findall(r"%copy[.0-9]* = f32\[32,32,64,64\]", text)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("b,s,split", [
     (32, 384, False),   # SQuAD finetune length (scripts/run_squad.sh)

@@ -15,7 +15,11 @@ f32 at highest matmul precision:
   the IDENTICAL counter-hash keep mask, as tests/test_pallas.py does);
 - LayerNorm and fused residual+dropout+LayerNorm, forward + backward
   (the XLA fallbacks in ops/layernorm.py share the kernels' dropout hash);
-- both fused-LAMB stages against their one-definition math.
+- both fused-LAMB stages against their one-definition math;
+- the gated delta-rule recurrence's kernels (ops/pallas/kda.py: `kda_fwd`,
+  `kda_bwd`) through `ops/kda.kda_scan` at the published widths (heads of
+  128, chunks of 64, 32 a block), forward and its five gradients, over a row
+  of documents, against the XLA scans of the same call in f32.
 
     python tools/kernel_parity.py            # exit 0 = all within tolerance
     python tools/kernel_parity.py --seq 256 --heads 4   # a quicker shape
@@ -41,6 +45,9 @@ RATE = 0.1
 # matmul, so kernel-vs-f32-reference errors sit near 1e-2 of the output
 # scale; a wrong head slice or mask is O(1)
 FWD_TOL, BWD_TOL = 3e-2, 5e-2
+# the KDA check's row is KDA_ROW x S tokens of 2 H heads of KDA_D: at the
+# defaults 4,096 tokens (two blocks of 32 chunks) of 32 heads of 128
+KDA_ROW, KDA_D, KDA_CHUNK, KDA_BLOCK = 8, 128, 64, 32
 
 
 def _rel_err(got, want) -> float:
@@ -194,6 +201,54 @@ def check_layernorm(interpret: bool, report) -> None:
             report(f"{name} d_arg{i}", _rel_err(g, w), BWD_TOL)
 
 
+def check_kda(interpret: bool, report) -> None:
+    """`kda_scan` with the chunks of a block walked by the kernels, bf16
+    products, against the same call on the XLA scans with float32 products
+    at highest precision: a row of four documents and a padded tail."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bert_pytorch_tpu.ops import kda
+
+    s, h, d = KDA_ROW * S, 2 * H, KDA_D
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = (unit(jax.random.normal(keys[0], (B, s, h, d))) * d ** -0.5)
+    k = unit(jax.random.normal(keys[1], (B, s, h, d)))
+    v = jax.random.normal(keys[2], (B, s, h, d))
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    g = -0.1 * jax.nn.softplus(jax.random.normal(keys[3], (B, s, h, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (B, s, h)))
+    weight = jax.random.normal(keys[5], (B, s, h, d))
+    starts = np.zeros((B, s), bool)
+    starts[:, [0, 37, s // 3, s // 2 + 5]] = True
+    starts[:, s - s // 50:] = True          # padding: one-slot documents
+    starts = jnp.asarray(starts)
+
+    def loss(mm_dtype):
+        def fn(q, k, v, g, beta):
+            out = kda.kda_scan(q, k, v, g, beta, starts, chunk=KDA_CHUNK,
+                               block=KDA_BLOCK, mm_dtype=mm_dtype)
+            return jnp.sum(weight * out), out
+        return jax.jit(jax.value_and_grad(fn, argnums=range(5),
+                                          has_aux=True))
+
+    mode = kda.kernel_mode
+    try:
+        kda.kernel_mode = lambda *a: interpret      # the kernels
+        (_, got), got_g = loss(jnp.bfloat16)(q, k, v, g, beta)
+        kda.kernel_mode = lambda *a: None           # the XLA scans
+        with jax.default_matmul_precision("highest"):
+            (_, want), want_g = loss(jnp.float32)(
+                *(x.astype(jnp.float32) for x in (q, k, v)), g, beta)
+    finally:
+        kda.kernel_mode = mode
+    report("kda fwd", _rel_err(got, want), FWD_TOL)
+    for which, a, w in zip(("q", "k", "v", "g", "beta"), got_g, want_g):
+        report(f"kda d{which}", _rel_err(a, w), BWD_TOL)
+
+
 def check_fused_lamb(report) -> None:
     """Stage kernels vs the XLA evaluation of the same math. f32 both
     sides: the block flattening reassociates at most an FMA."""
@@ -255,6 +310,7 @@ def main(argv=None) -> int:
     check_flash(fa, interpret, report)
     check_layernorm(interpret, report)
     check_fused_lamb(report)
+    check_kda(interpret, report)
     if failures:
         print(f"kernel_parity: {len(failures)} check(s) FAILED: "
               + ", ".join(failures))
