@@ -18,6 +18,10 @@ structured report the rule framework (analysis/passes.py) and the CI gate
 - kernel inventory: the Pallas kernels in the program (`tpu_custom_call`
   instructions) counted by the name their `pallas_call` gave them — how a
   run proves it took the flash / LayerNorm kernels and not the XLA path;
+- scope inventory: instructions under each given path of the program's
+  named scopes (training/pretrain.STEP_SUBSCOPES), by their `op_name` — how
+  a run proves its executable carries the scopes its per-layer metrics read
+  (a warm persistent cache can serve an older program's metadata);
 - the input→output buffer-donation table: which donated parameters XLA
   actually aliased (`input_output_alias`) vs accepted-but-never-aliased
   (`buffer_donor` — the double-HBM miss `donate_argnums` silently allows);
@@ -77,6 +81,17 @@ _GROUPS_LIST_RE = re.compile(r"replica_groups=\{(\{[^}]*\})")
 _KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _INNERMOST_RE = re.compile(r"\(([^()]*)\)+$")
+
+
+def scope_pattern(path: str) -> "re.Pattern":
+    """Matches an `op_name` under the scope `path`: its `/`-joined
+    components each a whole component of the name and next to each other; a
+    transform's wrapper (`transpose(jvp(attention))/qkv`) does not hide one.
+    The benchmark's readers/scope_sum_share.under is the same pattern
+    (tests/test_step_scopes.py holds the two equal)."""
+    parts = [re.escape(p) + r"\)*" for p in path.split("/")]
+    return re.compile(r"(?:^|[/(])" + "/".join(parts) + r"(?:/|$)")
+
 
 _ALIAS_ENTRY_RE = re.compile(
     r"\{[0-9,\s]*\}:\s*\(\s*(\d+)\s*,\s*\{[0-9,\s]*\}\s*(?:,\s*[\w-]+\s*)?\)")
@@ -162,13 +177,19 @@ def _est_bytes_moved(kind: str, bytes_out: int, group_size: Optional[int]
     return bytes_out  # collective-permute / all-to-all
 
 
-def parse_hlo_module(text: str) -> Dict[str, Any]:
+def parse_hlo_module(text: str, scopes: Sequence[str] = ()
+                     ) -> Dict[str, Any]:
     """Compiled HLO text -> the structural summary (stdlib only).
 
     Counts opcodes (async `-start` forms count once; `-done` halves are
     skipped so nothing double-counts), sizes collective results, and parses
-    the module header's donation tables. Deterministic for fixed input.
+    the module header's donation tables. With `scopes` (paths of named
+    scopes), also `scope_counts`: the instructions whose `op_name` is under
+    each, in the same pass. Deterministic for fixed input.
     """
+    scope_counts: Dict[str, int] = {path: 0 for path in scopes}
+    scope_pats = [(path, scope_pattern(path)) for path in scopes]
+    scopes_of: Dict[str, tuple] = {}    # op_name -> the paths it is under
     counts: Dict[str, int] = {k: 0 for k in COLLECTIVE_KINDS}
     op_counts: Dict[str, int] = {k: 0 for k in TRACKED_OPS}
     coll_bytes: Dict[str, int] = {k: 0 for k in COLLECTIVE_KINDS}
@@ -188,6 +209,16 @@ def parse_hlo_module(text: str) -> Dict[str, Any]:
         if m is None:
             continue
         op = m.group("op")
+        if scope_pats:
+            named = _OP_NAME_RE.search(line)
+            if named is not None:
+                name = named.group(1)
+                under = scopes_of.get(name)
+                if under is None:
+                    under = scopes_of[name] = tuple(
+                        path for path, pat in scope_pats if pat.search(name))
+                for path in under:
+                    scope_counts[path] += 1
         if op == "custom-call" and _KERNEL_TARGET in line:
             name = _kernel_name(line)
             kernels[name] = kernels.get(name, 0) + 1
@@ -232,6 +263,8 @@ def parse_hlo_module(text: str) -> Dict[str, Any]:
         "op_counts": op_counts,
         "kernel_counts": dict(sorted(kernels.items())),
         "donation": donation,
+        # only where asked for: the checked-in graph reports carry none
+        **({"scope_counts": scope_counts} if scopes else {}),
     }
 
 
@@ -489,14 +522,37 @@ def fingerprint_of(report: Dict[str, Any]) -> Dict[str, Any]:
         # informational (not part of the hash): which Pallas kernels the
         # program runs. Absent where it runs none, e.g. every CPU program
         fp["kernel_counts"] = report["kernel_counts"]
+    if report.get("scope_counts"):
+        # informational too: instructions under each declared sub-scope
+        fp["scope_counts"] = report["scope_counts"]
     return fp
 
 
-def program_fingerprint(compiled: Any) -> Dict[str, Any]:
+def stale_scopes_warning(fp: Dict[str, Any]) -> Optional[str]:
+    """What to tell the operator when a declared scope counts no
+    instruction in the executable, or None. JAX keeps `op_name`s out of the
+    persistent compile cache's key, so two programs that differ only in
+    named scopes share an entry and whichever compiled first gives the
+    executable its metadata: a trace of such a run reads the older
+    program's scopes."""
+    missing = [path for path, n in fp.get("scope_counts", {}).items()
+               if n == 0]
+    if not missing:
+        return None
+    return ("executable older than the program's scopes: no instruction "
+            f"under {', '.join(missing)} (the compile cache served an "
+            "executable compiled before these scopes were opened, and the "
+            "per-layer metrics that read them will be silent): clear the "
+            "compile cache before tracing")
+
+
+def program_fingerprint(compiled: Any, scopes: Sequence[str] = ()
+                        ) -> Dict[str, Any]:
     """fingerprint_of(parse) straight from a compiled object, stamped with
     the live platform (fingerprints are only comparable same-platform —
-    backends lower to different collective schedules)."""
-    fp = fingerprint_of(parse_hlo_module(compiled.as_text()))
+    backends lower to different collective schedules). `scopes`: as
+    parse_hlo_module's."""
+    fp = fingerprint_of(parse_hlo_module(compiled.as_text(), scopes))
     try:
         import jax
 

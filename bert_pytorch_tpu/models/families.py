@@ -102,9 +102,15 @@ FAMILIES = {
 }
 
 
-def family_of(config) -> Family:
-    """The record of the family whose config class `config` is."""
+def family_name(config) -> str:
+    """The key (of MODEL_FAMILIES, FAMILIES and the families of
+    training/pretrain.STEP_SUBSCOPES) whose config class `config` is."""
     for name, cls in MODEL_FAMILIES.items():
         if type(config) is cls:
-            return FAMILIES[name]
+            return name
     raise ValueError(f"no model family for {type(config).__name__}")
+
+
+def family_of(config) -> Family:
+    """The record of the family whose config class `config` is."""
+    return FAMILIES[family_name(config)]

@@ -120,8 +120,11 @@ class ShortConv(nn.Module):
                             (e, cfg.conv_L_cache), jnp.float32)
         if cfg.conv_bias:
             raise NotImplementedError("conv_bias: the source has none")
-        b, c, xg = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
-        y = c * short_conv(b * xg, weight, position_ids)
+        # what is no projection (STEP_SUBSCOPES: `conv` -> `in_proj`, `mix`,
+        # `out_proj`): the float32 split, the two gates, the convolution
+        with jax.named_scope("mix"):
+            b, c, xg = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+            y = c * short_conv(b * xg, weight, position_ids)
         return _Linear(e, cfg, self.dtype, name="out_proj")(y)
 
 

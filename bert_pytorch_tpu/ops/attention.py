@@ -40,6 +40,28 @@ import jax
 import jax.numpy as jnp
 
 
+# Everything between the projected q, k, v and the returned context, on
+# whichever path (training/pretrain.STEP_SUBSCOPES: `attention` ->
+# `attn_core`). JAX carries the scope into the custom rules' backward passes
+# (the flash kernels', hash_dropout's): tests/test_step_scopes.py reads it in
+# the compiled text.
+CORE_SCOPE = "attn_core"
+
+
+def _scoped(name: str):
+    """Decorator: what the function traces sits under the named scope
+    `name`. A context of its own for every call: jax.named_scope's object
+    keeps its state on itself, so one shared by all calls is not
+    re-entrant."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 def _pallas_interpret() -> bool:
     """BPT_PALLAS_INTERPRET=1 routes the Pallas kernels through interpret
     mode on non-TPU backends, so the multi-chip dryrun (virtual CPU mesh)
@@ -199,6 +221,7 @@ def make_segment_attention_bias(segment_ids: jax.Array,
     return jnp.where(allowed, 0.0, SEGMENT_MASK_BIAS).astype(dtype)
 
 
+@_scoped(CORE_SCOPE)
 def dot_product_attention(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Sk, H, D)

@@ -55,7 +55,14 @@ models/kimi_linear.py counts the tokens of the first way as
 
 Scope `kda/scan` (training/pretrain.LM_STEP_SCOPES), opened in every body
 and around the kernels' calls: inside a scan or a custom rule an operation
-keeps the scopes of the body.
+keeps the scopes of the body. Under it (training/pretrain.STEP_SUBSCOPES)
+`kda/scan/prepare` around every call of `_prepare` and of its pullback, and
+`kda/scan/prepare/inverse` around `unit_lower_inverse` and in both of its
+rules; each is opened by its whole path, because a transform's wrapper
+(`transpose(jvp(...))`) comes between a scope opened outside it and one
+opened inside. What stays directly under `kda/scan` is the chunk-major
+transposes, the outer scan's slices and the chunks' walk (the kernels, or
+the two scans).
 """
 
 from __future__ import annotations
@@ -67,6 +74,8 @@ import jax
 import jax.numpy as jnp
 
 SCOPE = "kda/scan"
+PREPARE_SCOPE = SCOPE + "/prepare"
+INVERSE_SCOPE = PREPARE_SCOPE + "/inverse"
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -83,14 +92,15 @@ def _mm32(a, b):
 def unit_lower_inverse(a: jax.Array) -> jax.Array:
     """(I + a)^-1 for strictly lower-triangular a (..., C, C), float32:
     (I - a)(I + a^2)(I + a^4)..., exact because a^C = 0."""
-    c = a.shape[-1]
-    t = jnp.eye(c, dtype=a.dtype) - a
-    power, n = a, 2
-    while n < c:
-        power = _mm32(power, power)         # a^n
-        t = t + _mm32(t, power)
-        n *= 2
-    return t
+    with jax.named_scope(INVERSE_SCOPE):
+        c = a.shape[-1]
+        t = jnp.eye(c, dtype=a.dtype) - a
+        power, n = a, 2
+        while n < c:
+            power = _mm32(power, power)         # a^n
+            t = t + _mm32(t, power)
+            n *= 2
+        return t
 
 
 def _inverse_fwd(a):
@@ -99,8 +109,9 @@ def _inverse_fwd(a):
 
 
 def _inverse_bwd(t, dt):
-    tt = jnp.swapaxes(t, -1, -2)
-    return (-_mm32(_mm32(tt, dt), tt),)
+    with jax.named_scope(INVERSE_SCOPE):
+        tt = jnp.swapaxes(t, -1, -2)
+        return (-_mm32(_mm32(tt, dt), tt),)
 
 
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
@@ -256,7 +267,7 @@ def _blocks_fwd(q, k, v, g, beta, rid, prev, mm_dtype, kernels):
     b, h, d, dv = q.shape[2], q.shape[3], q.shape[5], v.shape[5]
 
     def body(state, block):
-        with jax.named_scope(SCOPE):
+        with jax.named_scope(PREPARE_SCOPE):
             prep = _prepare(*block, mm_dtype)
         new, out = _chunks_fwd(mm_dtype, kernels, state, prep)
         return new, (out, state)
@@ -273,16 +284,17 @@ def _blocks_bwd(mm_dtype, kernels, saved, dout):
 
     def body(dstate, block):
         state, dout, *inputs = block
-        with jax.named_scope(SCOPE):
+        with jax.named_scope(PREPARE_SCOPE):
             prep, pull = jax.vjp(
                 lambda *x: _prepare(*x, *inputs[5:], mm_dtype), *inputs[:5])
         dstate, dprep = _chunks_bwd(mm_dtype, kernels, state, dstate, prep,
                                     dout)
-        with jax.named_scope(SCOPE):
+        with jax.named_scope(PREPARE_SCOPE):
             grads = pull(dprep)
-            if kernels is not None:
+        if kernels is not None:
+            with jax.named_scope(SCOPE):
                 grads = tuple(_row_major(x) for x in grads)
-            return dstate, grads
+        return dstate, grads
 
     _, grads = jax.lax.scan(
         body, jnp.zeros_like(starts[0]),

@@ -310,9 +310,6 @@ class StepWatch:
                 if listener is not None:
                     listener(name, False)
 
-    def add_phase(self, name: str, seconds: float) -> None:
-        self._phases[name] = self._phases.get(name, 0.0) + seconds
-
     @contextmanager
     def pause(self):
         """Exclude a non-training span (mid-epoch eval, restore) from the

@@ -16,13 +16,13 @@ run_pretraining.py:436 — same result, computed exactly).
 
 from __future__ import annotations
 
-import re
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 
+from bert_pytorch_tpu.analysis.hlo import scope_pattern
 from bert_pytorch_tpu.models import losses
 from bert_pytorch_tpu.models.bert import REMAT_AUTO_ORDER
 from bert_pytorch_tpu.telemetry.health import (HealthConfig,
@@ -54,15 +54,13 @@ STEP_SCOPES = (
     "encoder", "bert", "grad_accum",
 )
 # The same account for a step of the decoder families (models/lfm2_moe.py,
-# models/kimi_linear.py), whose models open other scopes: `kda` first (the
-# gated delta-rule mixer whole: `kda/conv`, `kda/gates`, `kda/scan`,
-# `kda/out` inside), then `rmsnorm` (the q/k norms inside `attention` are
-# norms), `mlp` the dense FFN only, `moe` with its `moe/router`,
-# `moe/dispatch`, `moe/experts`, `moe/combine` (and kimi_linear's
-# `moe/shared`) inside, all under `decoder`. The last two are not scopes of the program: XLA:TPU
-# lowers `lax.ragged_dot` (ops/moe.py's grouped products) to kernels of its
-# own whose `op_name` is the compiler's and carries no scope. The
-# benchmark's unscoped_share.kimi.train carries a copy of the list, and
+# models/kimi_linear.py, models/smallthinker.py), whose models open other
+# scopes, all under `decoder`: `kda` first, then `rmsnorm` (the q/k norms
+# inside `attention` are norms), `mlp` the dense FFN only. The last two
+# entries are not scopes of the program: XLA:TPU lowers `lax.ragged_dot`
+# (ops/moe.py's grouped products) to kernels of its own whose `op_name` is
+# the compiler's and carries no scope. The benchmark's
+# unscoped_share.kimi.train carries a copy of the list, and
 # unscoped_share.lm.train of the list without `kda` (lfm2 has none).
 LM_STEP_SCOPES = (
     "kda", "rmsnorm", "moe", "conv", "attention", "mlp", "lm_head", "loss",
@@ -70,10 +68,59 @@ LM_STEP_SCOPES = (
     "metrics", "decoder", "grad_accum",
     "ragged-dot-none", "ragged-dot-metadata",
 )
+# The second level of the account: under an entry of the two lists above (or
+# a path under one), the children the program opens and a metric of the
+# benchmark reads, each with the families (config.MODEL_FAMILIES' keys) whose
+# step opens it. A child's path is `parent/child`, the components next to
+# each other in the `op_name` as benchmark/readers/scope_sum_share.under
+# wants them: smallthinker opens `attn_core` under its two kinds of layer,
+# so those are parents here. What a parent holds beside its children belongs
+# to the parent alone (under `attention`: BERT's LayerNorm kernels, lfm2's
+# and kimi's projections and q/k norms; under `kda/scan`: the chunk-major
+# transposes, the outer scan's slices and the walk over a block's chunks;
+# under `moe`: the held experts' casts and, in the backward pass, the
+# windows' scan's running sums of the held weights' gradients, which JAX
+# names after the scan alone). tests/test_step_scopes.py finds
+# every child in each family's compiled step, forward and backward;
+# `program_scopes` in the run's second header counts them in the executable
+# (run_pretraining.py); docs/OBSERVABILITY.md draws the tree.
+_FLAT_ATTENTION = ("bert", "lfm2_moe", "kimi_linear")
+_ROUTED_FAMILIES = ("lfm2_moe", "kimi_linear", "smallthinker")
+STEP_SUBSCOPES = {
+    # ops/attention.dot_product_attention: q, k, v in, context out
+    "attention": {"attn_core": _FLAT_ATTENTION,
+                  "qkv": ("bert",), "output": ("bert",),
+                  # a layer's whole attention, by kind
+                  "attention_window": ("smallthinker",),
+                  "attention_full": ("smallthinker",)},
+    "attention/attention_window": {"attn_core": ("smallthinker",)},
+    "attention/attention_full": {"attn_core": ("smallthinker",)},
+    # models/lfm2_moe.ShortConv: `mix` is what is no projection
+    "conv": {"in_proj": ("lfm2_moe",), "mix": ("lfm2_moe",),
+             "out_proj": ("lfm2_moe",)},
+    # models/kimi_linear.py's mixer; ops/kda.py opens what is under `scan`
+    "kda": {"conv": ("kimi_linear",), "gates": ("kimi_linear",),
+            "scan": ("kimi_linear",), "out": ("kimi_linear",)},
+    "kda/scan": {"prepare": ("kimi_linear",)},
+    "kda/scan/prepare": {"inverse": ("kimi_linear",)},
+    # ops/moe.py; kimi_linear's shared expert beside the routed ones
+    "moe": {"router": _ROUTED_FAMILIES, "dispatch": _ROUTED_FAMILIES,
+            "experts": _ROUTED_FAMILIES, "combine": _ROUTED_FAMILIES,
+            "shared": ("kimi_linear",)},
+}
+
+
+def step_subscopes(family: Optional[str] = None) -> Tuple[str, ...]:
+    """The paths of STEP_SUBSCOPES (`parent/child`) that `family`'s step
+    opens; every path where `family` is None."""
+    return tuple(f"{parent}/{child}"
+                 for parent, children in STEP_SUBSCOPES.items()
+                 for child, families in children.items()
+                 if family is None or family in families)
+
+
 _SCOPE_PATTERNS = {
-    scopes: tuple(
-        (name, re.compile(rf"(?:^|[/(]){re.escape(name)}\)*(?:/|$)"))
-        for name in scopes)
+    scopes: tuple((name, scope_pattern(name)) for name in scopes)
     for scopes in (STEP_SCOPES, LM_STEP_SCOPES)}
 
 
@@ -910,15 +957,16 @@ class StepProgram:
         except Exception:
             return 0
 
-    def fingerprint(self) -> Optional[Dict[str, Any]]:
+    def fingerprint(self, scopes=()) -> Optional[Dict[str, Any]]:
         """Structural identity (collective counts + donation hash) of the
         compiled program, or None if nothing AOT-compiled (fallback mode).
+        `scopes` (step_subscopes(family)): also the instructions under each.
         """
         if self.compiled is None:
             return None
         from bert_pytorch_tpu.analysis.hlo import program_fingerprint
 
-        return program_fingerprint(self.compiled)
+        return program_fingerprint(self.compiled, scopes)
 
 
 def resolve_remat_policy(build: Callable[[str], StepProgram], args,

@@ -611,7 +611,7 @@ def main(argv=None):
     from bert_pytorch_tpu.config import load_model_config, pad_vocab_size
     from bert_pytorch_tpu.data.sharded import (
         HostShardSampler, PretrainingDataLoader, ShardIndex)
-    from bert_pytorch_tpu.models.families import family_of
+    from bert_pytorch_tpu.models.families import family_name, family_of
     from bert_pytorch_tpu.optim import schedulers
     from bert_pytorch_tpu.parallel import dist, mesh as mesh_lib
     from bert_pytorch_tpu.telemetry import (
@@ -623,10 +623,12 @@ def main(argv=None):
     from bert_pytorch_tpu.resilience.watchdog import arm_watchdog
     from bert_pytorch_tpu.training import (
         CheckpointManager, build_pretrain_step, make_sharded_state)
+    from bert_pytorch_tpu.analysis.hlo import stale_scopes_warning
     from bert_pytorch_tpu.training.pretrain import (StepProgram,
                                                     stack_microbatches,
                                                     chain_steps,
-                                                    resolve_remat_policy)
+                                                    resolve_remat_policy,
+                                                    step_subscopes)
 
     # set-up spans (telemetry/stepwatch.SetupWatch): backend | data | state
     # | lower | first_step, closed by the first step's loss on the host;
@@ -1419,8 +1421,9 @@ def main(argv=None):
             HLO is tens of MB and must not stall dispatch 2; the header is
             logged from the MAIN thread once the result lands (MetricLogger
             is not thread-safe)."""
+            scopes = step_subscopes(family_name(config))
             for prog, n in ((jit_chunk, steps_per_loop), (jit_step, 1)):
-                f = prog.fingerprint() if prog is not None else None
+                f = prog.fingerprint(scopes) if prog is not None else None
                 if f is not None:
                     fp = dict(f, steps_per_loop=n)
                     if recorder is not None:
@@ -1449,7 +1452,19 @@ def main(argv=None):
                         fp["collective_counts"].items())),
                 program_kernels=" ".join(
                     f"{k}={v}" for k, v in sorted(
-                        fp.get("kernel_counts", {}).items())))
+                        fp.get("kernel_counts", {}).items())),
+                # instructions under each sub-scope the family's step opens
+                # (training/pretrain.STEP_SUBSCOPES), as the executable
+                # carries them
+                program_scopes=" ".join(
+                    f"{k}={v}"
+                    for k, v in fp.get("scope_counts", {}).items()))
+            # only an executable the persistent cache served can be older
+            # than the program; a fresh compile that counts zero is a config
+            # with no layer of that kind
+            stale = stale_scopes_warning(fp)
+            if stale and compile_watch.cache_hits:
+                logger.info(f"WARNING: {stale}")
 
         def flush_pending():
             nonlocal pending

@@ -523,6 +523,15 @@ def test_entry_point_trains_the_family_and_counts_its_work(tmp_path):
     mine = kimi_linear.train_flops_per_row(cfg, 128)
     assert last["model_flops_per_sec"] / last["seq_per_sec"] == \
         pytest.approx(mine, rel=1e-3)
+    # the second header counts, in the executable, the instructions under
+    # every sub-scope the family's step is declared to open
+    from bert_pytorch_tpu.training.pretrain import step_subscopes
+
+    (said,) = [r["program_scopes"] for r in records
+               if r.get("tag") == "header" and "program_scopes" in r]
+    counts = dict(kv.split("=") for kv in said.split())
+    assert list(counts) == list(step_subscopes("kimi_linear"))
+    assert all(int(n) > 0 for n in counts.values())
 
 
 def test_every_instruction_of_the_step_is_under_an_lm_scope(toy):

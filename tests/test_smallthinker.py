@@ -558,5 +558,13 @@ def test_the_step_carries_both_attention_scopes_and_both_kernel_sets(
     assert all("attention_window" in n for n in names if "flash_win_" in n)
     assert all("attention_full" in n for n in names
                if re.search(r"[/(]flash_(fwd|bwd)", n))
+    # and every kernel, forward and backward (custom_vjp rules), under the
+    # core's scope inside its layer's (STEP_SUBSCOPES)
+    for kind in ("window", "full"):
+        core = re.compile(rf"[/(]attention/attention_{kind}/attn_core\)*/")
+        kernels = [n for n in names if re.search(r"[/(]flash_", n)
+                   and f"attention_{kind}" in n]
+        assert kernels and all(core.search(n) for n in kernels)
+        assert any("transpose(" in n for n in kernels)
     assert smallthinker.keep_float32((jax.tree_util.DictKey("router"),))
     assert not smallthinker.keep_float32((jax.tree_util.DictKey("q_proj"),))

@@ -1,8 +1,15 @@
 """The account of the compiled step (training/pretrain.STEP_SCOPES): both
 step builders put every operation they trace under an entry of the one
 list, the benchmark's readers carry that same list, and the scopes the
-benchmark's metrics read by name are in it."""
+benchmark's metrics read by name are in it. Its second level
+(STEP_SUBSCOPES): every child a family's step is declared to open is in
+that family's compiled step, forward and backward, under the pattern the
+benchmark's reader looks with; the metric files name declared paths only;
+an executable without a declared path is told from one that has them."""
 
+import functools
+import glob
+import importlib
 import json
 import os
 import re
@@ -12,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bert_pytorch_tpu.analysis import hlo
 from bert_pytorch_tpu.config import BertConfig
 from bert_pytorch_tpu.models import BertForPreTraining
 from bert_pytorch_tpu.optim import schedulers
@@ -20,10 +28,11 @@ from bert_pytorch_tpu.optim.lamb import default_weight_decay_mask, lamb
 from bert_pytorch_tpu.telemetry import HealthConfig, init_telemetry_state
 from bert_pytorch_tpu.training import (build_pretrain_step, init_kfac_state,
                                        make_sharded_state)
-from bert_pytorch_tpu.training.pretrain import (STEP_SCOPES,
+from bert_pytorch_tpu.training.pretrain import (LM_STEP_SCOPES, STEP_SCOPES,
+                                                STEP_SUBSCOPES,
                                                 build_kfac_pretrain_step,
                                                 stack_microbatches,
-                                                step_scope)
+                                                step_scope, step_subscopes)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 METRICS = os.path.join(ROOT, "benchmark", "layer_metrics")
@@ -137,3 +146,158 @@ def test_the_benchmarks_readers_carry_the_programs_list():
         assert _metric(name)["args"]["scope"] in STEP_SCOPES, name
     assert _metric("recompute_share.train")["args"]["scope"] \
         not in STEP_SCOPES       # it stands beside the sum, not in it
+
+
+# -- the second level ---------------------------------------------------------
+
+# a decoder family's toy: the module of its own tests (TOY, SEED, `ref`,
+# `_packed`), its config class and its model module
+DECODERS = {
+    "lfm2_moe": ("tests.test_lfm2_moe", "Lfm2MoeConfig"),
+    "kimi_linear": ("tests.test_kimi_linear", "KimiLinearConfig"),
+    "smallthinker": ("tests.test_smallthinker", "SmallThinkerConfig"),
+}
+FAMILIES = ("bert",) + tuple(DECODERS)
+
+
+def _decoder_step(family):
+    """The family's toy step as its own tests build it: packed rows, two
+    micro-batches, remat, bf16 gradients, LAMB."""
+    import run_pretraining
+    from bert_pytorch_tpu import config as configs
+    from bert_pytorch_tpu.models.families import FAMILIES as FAMILY_RECORDS
+    from bert_pytorch_tpu.training.state import TrainState
+
+    toy_module, config_cls = DECODERS[family]
+    toy = importlib.import_module(toy_module)
+    models = importlib.import_module("bert_pytorch_tpu.models." + family)
+    cfg = getattr(configs, config_cls).from_dict(toy.TOY).replace(
+        dtype="float32", checkpoint_activations=True, attention_impl="xla")
+    params = toy.ref.init_params(toy.SEED, toy.ref.sizes_from_config(toy.TOY))
+    if family == "lfm2_moe":
+        params = toy.lm_adapter.to_program_tree(params)
+    model = FAMILY_RECORDS[family].make_model(cfg, jnp.float32)
+    sched = schedulers.make_schedule("poly", 0.004, 100, warmup=0.1)
+    tx = run_pretraining.make_optimizer("lamb", sched)
+    state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
+                       opt_state=tx.init(params))
+    step = build_pretrain_step(
+        model, tx, schedule=sched, accum_steps=2, grad_dtype=jnp.bfloat16,
+        loss_fn_builder=models.pretrain_loss_fn_builder,
+        keep_float32=models.keep_float32)
+    batch = {k: jnp.stack([jnp.asarray(v)] * 2) for k, v in zip(
+        ("input_ids", "segment_ids", "position_ids"), toy._packed())}
+    return step, state, batch
+
+
+@functools.lru_cache(maxsize=None)
+def _family_text(family):
+    """The compiled text of the family's toy step."""
+    step, state, batch = (_toy_step(False) if family == "bert"
+                          else _decoder_step(family))
+    return jax.jit(step).lower(state, batch,
+                               jax.random.PRNGKey(0)).compile().as_text()
+
+
+def _op_names(text):
+    return {op for op in _OP_NAME.findall(text) if op.startswith("jit(")}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_declared_subscope_is_in_the_familys_step(family):
+    """Found with the reader's own pattern, in the forward pass and in the
+    backward pass (an op_name that holds `transpose(`); and the count the
+    run's fingerprint takes of the same text misses none."""
+    from benchmark.readers.scope_sum_share import under
+
+    paths = step_subscopes(family)
+    assert paths
+    names = _op_names(_family_text(family))
+    for path in paths:
+        hits = [n for n in names if under(path).search(n)]
+        assert hits, path
+        assert any("transpose(" in n for n in hits), path
+    fp = hlo.fingerprint_of(hlo.parse_hlo_module(_family_text(family),
+                                                 paths))
+    assert list(fp["scope_counts"]) == list(paths)
+    assert all(fp["scope_counts"].values()), fp["scope_counts"]
+    assert hlo.stale_scopes_warning(fp) is None
+    # nothing another family opens: the declaration says who opens what
+    for path in set(step_subscopes()) - set(paths):
+        assert not [n for n in names if under(path).search(n)][:3], path
+
+
+def test_an_executable_without_a_declared_scope_is_told_by_name():
+    """A warm compile cache can serve the executable of a program that
+    opened fewer scopes (JAX keeps op_names out of the cache key)."""
+    paths = step_subscopes("bert")
+    older = re.sub(r'/attn_core(?=[/"])', "", _family_text("bert"))
+    fp = hlo.fingerprint_of(hlo.parse_hlo_module(older, paths))
+    assert fp["scope_counts"]["attention/attn_core"] == 0
+    assert fp["scope_counts"]["attention/qkv"] > 0
+    warning = hlo.stale_scopes_warning(fp)
+    assert "attention/attn_core" in warning and "qkv" not in warning
+    assert "clear the compile cache" in warning
+    # no scopes asked for: none counted, nothing to warn of
+    plain = hlo.fingerprint_of(hlo.parse_hlo_module(older))
+    assert "scope_counts" not in plain
+    assert hlo.stale_scopes_warning(plain) is None
+
+
+def test_the_second_level_is_a_tree_under_the_first():
+    from benchmark.readers.scope_sum_share import under
+    from bert_pytorch_tpu.config import MODEL_FAMILIES
+
+    paths = step_subscopes()
+    assert len(set(paths)) == len(paths)
+    for parent, children in STEP_SUBSCOPES.items():
+        # a parent is an entry of the first level or a declared path
+        assert (parent in STEP_SCOPES + LM_STEP_SCOPES
+                or parent in paths), parent
+        for child, families in children.items():
+            assert families and set(families) <= set(MODEL_FAMILIES), child
+            if parent in paths:     # whoever opens a child opens its parent
+                up, name = parent.rsplit("/", 1)
+                assert set(families) <= set(STEP_SUBSCOPES[up][name])
+    for path in paths:
+        # the first level's answer does not change: a path is its root's
+        root = path.split("/")[0]
+        assert step_scope("jit(train_step)/" + path + "/dot",
+                          LM_STEP_SCOPES) == root
+        # the program's pattern is the reader's
+        assert hlo.scope_pattern(path).pattern == under(path).pattern
+    assert {f for c in STEP_SUBSCOPES.values() for fs in c.values()
+            for f in fs} == set(MODEL_FAMILIES)
+
+
+def _metric_files():
+    return sorted(glob.glob(os.path.join(METRICS, "*.json")))
+
+
+@pytest.mark.parametrize("path", _metric_files(),
+                         ids=lambda p: os.path.basename(p)[:-5])
+def test_a_metric_names_declared_scopes_and_kernels_only(path):
+    """A scope path in a metric's file is an entry of the first level or a
+    path of STEP_SUBSCOPES; a kernel whose share of the busy time is read
+    (`kernel_share`) is the `name=` of a `pallas_call` of ops/pallas/ (the
+    roofline readers' flash kernels take their names from
+    flash_attention._kernel_name, and each cell's traffic file expects them
+    in the step's HLO)."""
+    with open(path, encoding="utf-8") as f:
+        metric = json.load(f)
+    args = metric.get("args", {})
+    first = set(STEP_SCOPES + LM_STEP_SCOPES)
+    paths = set(step_subscopes())
+    # by one name: the first level, a child's own name (scope_share's
+    # files: `attention_window`), or what cuts across the list
+    single = first | {p.rsplit("/", 1)[1] for p in paths} | {
+        "rematted_computation"}
+    scopes = list(args.get("scopes", [])) + list(args.get("outside", []))
+    scopes += [args["scope"]] if "scope" in args else []
+    assert not [s for s in scopes
+                if s not in (paths if "/" in s else single)]
+    if metric["reader"] == "kernel_share":
+        sources = "".join(
+            open(p, encoding="utf-8").read() for p in glob.glob(os.path.join(
+                ROOT, "bert_pytorch_tpu", "ops", "pallas", "*.py")))
+        assert not [k for k in args["kernels"] if f'"{k}"' not in sources]
