@@ -319,8 +319,8 @@ def pretrain_loss_fn_builder(model) -> Callable:
                                      cfg.kda_chunk_size,
                                      KDA_BLOCK_CHUNKS) is not None
             scalars = dict(
-                expert_scalars(count, batch["input_ids"].size
-                               * cfg.num_experts_per_tok, load, dropped),
+                expert_scalars(cfg, count, batch["input_ids"].size, load,
+                               dropped),
                 kda_tokens=kda_tokens,
                 kda_kernel_tokens=kda_tokens * int(on_kernels),
                 kda_resets=jnp.sum(batch["position_ids"] == 0,

@@ -177,6 +177,14 @@ class DenseMLP(nn.Module):
         return _Linear(cfg.hidden_size, cfg, self.dtype, name="w2")(hidden)
 
 
+def routed_window_rows(cfg, n_tokens: int) -> int:
+    """Sorted pairs a window of ops/moe.held_experts works on, for a
+    micro-batch of `n_tokens`: twice this rank's even share of the pairs."""
+    pairs = n_tokens * cfg.num_experts_per_tok
+    return min(-(-2 * pairs * cfg.num_experts // cfg.router_width // 512)
+               * 512, pairs)
+
+
 class RoutedExperts(nn.Module):
     """The routed FFN over the experts this rank holds. Returns (the partial
     sum (B, S, E) in `dtype`, tokens per held expert (E_held,) int32, held
@@ -210,13 +218,10 @@ class RoutedExperts(nn.Module):
             else router_input.reshape(bsz * s, e),
             router, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob,
             float(cfg.routed_scaling_factor), cfg.router_scores)
-        # a window holds twice this rank's even share of the pairs
-        window_rows = -(-2 * bsz * s * cfg.num_experts_per_tok * n_held
-                        // cfg.router_width // 512) * 512
         out, load, dropped = moe_ops.held_experts(
             tokens, routing, w1.astype(self.dtype), w3.astype(self.dtype),
-            w2.astype(self.dtype), cfg.held_range, window_rows,
-            cfg.expert_activation)
+            w2.astype(self.dtype), cfg.held_range,
+            routed_window_rows(cfg, bsz * s), cfg.expert_activation)
         return out.astype(self.dtype).reshape(bsz, s, e), load, dropped
 
 
@@ -303,15 +308,20 @@ def keep_float32(path: Tuple) -> bool:
     return keys[-1] in ("router", "expert_bias")
 
 
-def expert_scalars(count, pairs_routed: int, load, dropped) -> dict:
+def expert_scalars(cfg, count, n_tokens: int, load, dropped) -> dict:
     """A micro-batch's scalars of the decoder families (telemetry/
     expert_load.py sums them): predicted positions, (token, expert) pairs
-    routed, and per routed layer each held expert's tokens and the held
-    pairs not computed."""
+    routed, and per routed layer each held expert's tokens, the held pairs
+    not computed and the windows the layer's loop ran (its trip count, from
+    the pairs it was handed: ops/moe.live_windows)."""
     scalars = {"lm_positions": count,
-               "moe_pairs_routed": jnp.asarray(pairs_routed, jnp.int32)}
+               "moe_pairs_routed": jnp.asarray(
+                   n_tokens * cfg.num_experts_per_tok, jnp.int32)}
+    window_rows = routed_window_rows(cfg, n_tokens)
     for layer in range(load.shape[0]):
         scalars[f"moe_l{layer}_dropped"] = dropped[layer]
+        scalars[f"moe_l{layer}_windows"] = moe_ops.live_windows(
+            jnp.sum(load[layer]), window_rows)
         for j in range(load.shape[1]):
             scalars[f"moe_l{layer}_e{j}"] = load[layer, j]
     return scalars
@@ -329,9 +339,8 @@ def pretrain_loss_fn_builder(model) -> Callable:
             loss, count = losses.next_token_loss(
                 logits, batch["input_ids"], batch["segment_ids"])
         with jax.named_scope("metrics"):
-            scalars = expert_scalars(
-                count, batch["input_ids"].size
-                * model.config.num_experts_per_tok, load, dropped)
+            scalars = expert_scalars(model.config, count,
+                                     batch["input_ids"].size, load, dropped)
         return loss, {"scalars": scalars}
 
     return loss_fn

@@ -179,9 +179,8 @@ def pretrain_loss_fn_builder(model) -> Callable:
             hidden, head, batch["input_ids"], batch["segment_ids"],
             LOSS_BLOCK_ROWS)
         with jax.named_scope("metrics"):
-            scalars = expert_scalars(
-                count, batch["input_ids"].size * cfg.num_experts_per_tok,
-                load, dropped)
+            scalars = expert_scalars(cfg, count, batch["input_ids"].size,
+                                     load, dropped)
         return loss, {"scalars": scalars}
 
     return loss_fn

@@ -13,22 +13,37 @@ they are sorted by expert and the three matrix products are grouped products
 over the held experts (`jax.lax.ragged_dot`, which XLA:TPU lowers to its own
 grouped-matmul kernel whose tiles cover the groups' rows and no others).
 Shapes are static, so the sorted pairs are worked in windows of
-`window_rows` rows (a `lax.scan`, up to all T x k pairs if every selected
-expert of every token were held): a window past the held pairs is skipped by
-a `lax.cond`, so at an even load one window runs, and each window is
-rematerialised, so one window's temporaries are alive whatever the
-imbalance. (Running the first window in line and keeping its gathered tokens
-and up-projections for the backward pass was tried: the step then needs
-18.5 of the chip's 15.75 GiB.)
+`window_rows` rows (up to all T x k pairs if every selected expert of every
+token were held), and the loop over the windows runs the LIVE ones only: its
+trip count is `live_windows(n_pairs, window_rows)`, a value of the input,
+so at an even load one window runs and no other is walked. Reverse mode
+cannot differentiate a loop whose bound is data, and a `lax.scan` over all
+windows with a `lax.cond` around each, which it can, costs more than the
+live window's work: every skipped window hands back zeros of the held
+weights' and the tokens' size, and the scan sums them (PERF.md section 6,
+PR 39). So the loop has a rule of its own (`_live_windows`, a
+`jax.custom_vjp`). Forward: the live windows
+in order, each adding into the sum. Backward: every window adds into the
+sum, so each takes the same cotangent; a window's own cotangents are
+`jax.vjp` of the same `_window`, recomputed there, so one window's
+temporaries are alive whatever the imbalance and nothing of a window is
+kept (running the first window in line and keeping its gathered tokens and
+up-projections for the backward pass was tried: the step then needs 18.5 of
+the chip's 15.75 GiB). The last live window's cotangents ARE the sums of x,
+w1, w3 and w2; an earlier live window adds its own into them, in the order
+reverse mode did, so the gradients are the scan's bit for bit. The rule's
+residuals are its inputs.
 
 Scopes (training/pretrain.LM_STEP_SCOPES): `moe/router`, `moe/dispatch`,
-`moe/experts`, `moe/combine`, each opened here under its whole name: inside
-the scan, the cond and the remat an operation's `op_name` keeps the scopes
-opened in the body, not the caller's.
+`moe/experts`, `moe/combine`, and `moe/accumulate` for what the backward
+loop adds across windows, each opened here under its whole name: inside the
+loop and the rule an operation's `op_name` keeps the scopes opened in the
+body, not the caller's.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -116,6 +131,79 @@ def _window(out, done, x, w1, w3, w2, tokens, gates, sizes, n_pairs, start,
         return out.at[tokens].add(ys), done + jnp.sum(group_rows)
 
 
+def live_windows(n_pairs, window_rows: int):
+    """Windows of `window_rows` sorted rows that hold a held pair: the trip
+    count of the loops below (the first window runs whatever it holds)."""
+    return jnp.maximum(-(-n_pairs // window_rows), 1).astype(jnp.int32)
+
+
+def _window_of(i, window_rows, tokens, gates):
+    """Window i of the sorted pairs: (its tokens, its gates, its first
+    row)."""
+    start = i * window_rows
+    return (jax.lax.dynamic_slice(tokens, (start,), (window_rows,)),
+            jax.lax.dynamic_slice(gates, (start,), (window_rows,)), start)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _live_windows(window_rows, activation, x, w1, w3, w2, tokens, gates,
+                  sizes, n_pairs):
+    """(the windows' sum (T, H) float32, pairs computed) over the live
+    windows of the sorted pairs, in order. The trip count is data, which
+    reverse mode cannot differentiate through: the rule below is the
+    loop's."""
+    def body(i, carry):
+        tk, gt, start = _window_of(i, window_rows, tokens, gates)
+        return _window(*carry, x, w1, w3, w2, tk, gt, sizes, n_pairs, start,
+                       activation)
+
+    return jax.lax.fori_loop(
+        0, live_windows(n_pairs, window_rows), body,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros([], jnp.int32)))
+
+
+def _live_windows_fwd(window_rows, activation, *args):
+    # nothing of a window is kept: the inputs, which are alive anyway
+    return _live_windows(window_rows, activation, *args), args
+
+
+def _live_windows_bwd(window_rows, activation, args, cotangents):
+    """The live windows again, last to first: every window adds into `out`,
+    so each takes the same cotangent `g` of the sum, and its own cotangents
+    are `jax.vjp` of `_window`, recomputed here (one window's temporaries
+    alive). The last live window's ARE the sums of x, w1, w3, w2; an
+    earlier one's are added in place."""
+    x, w1, w3, w2, tokens, gates, sizes, n_pairs = args
+    g, _ = cotangents
+
+    def cotangents_of(i):
+        tk, gt, start = _window_of(i, window_rows, tokens, gates)
+        _, pull = jax.vjp(
+            lambda x, w1, w3, w2, gt: _window(
+                jnp.zeros(x.shape, jnp.float32), 0, x, w1, w3, w2, tk, gt,
+                sizes, n_pairs, start, activation)[0], x, w1, w3, w2, gt)
+        return pull(g), start
+
+    last = live_windows(n_pairs, window_rows) - 1
+    (dx, dw1, dw3, dw2, dgt), start = cotangents_of(last)
+    with jax.named_scope("moe/accumulate"):
+        dgates = jax.lax.dynamic_update_slice(
+            jnp.zeros(gates.shape, gates.dtype), dgt, (start,))
+
+    def body(j, sums):
+        (*own, dgt), start = cotangents_of(last - j)
+        with jax.named_scope("moe/accumulate"):
+            return (*jax.tree.map(jnp.add, sums[:4], tuple(own)),
+                    jax.lax.dynamic_update_slice(sums[4], dgt, (start,)))
+
+    dx, dw1, dw3, dw2, dgates = jax.lax.fori_loop(
+        1, last + 1, body, (dx, dw1, dw3, dw2, dgates))
+    return dx, dw1, dw3, dw2, None, dgates, None, None
+
+
+_live_windows.defvjp(_live_windows_fwd, _live_windows_bwd)
+
+
 def held_experts(x: jax.Array, routing: Routing, w1: jax.Array,
                  w3: jax.Array, w2: jax.Array, held: Tuple[int, int],
                  window_rows: int, activation: str = "silu"
@@ -146,21 +234,6 @@ def held_experts(x: jax.Array, routing: Routing, w1: jax.Array,
         tokens = jnp.pad((order // k).astype(jnp.int32), (0, pad))
         gates = jnp.pad(routing.gates.reshape(-1)[order], (0, pad))
 
-    @jax.checkpoint
-    def window(out, done, x, w1, w3, w2, tokens, gates, start):
-        # a skipped window hands the sum on untouched
-        return jax.lax.cond(
-            start < n_pairs,
-            lambda: _window(out, done, x, w1, w3, w2, tokens, gates, sizes,
-                            n_pairs, start, activation),
-            lambda: (out, done))
-
-    def body(carry, inp):
-        return window(*carry, x, w1, w3, w2, *inp), None
-
-    (out, done), _ = jax.lax.scan(
-        body, (jnp.zeros(x.shape, jnp.float32), jnp.zeros([], jnp.int32)),
-        (tokens.reshape(n_windows, window_rows),
-         gates.reshape(n_windows, window_rows),
-         jnp.arange(n_windows, dtype=jnp.int32) * window_rows))
+    out, done = _live_windows(window_rows, activation, x, w1, w3, w2, tokens,
+                              gates, sizes, n_pairs)
     return out, sizes, n_pairs - done

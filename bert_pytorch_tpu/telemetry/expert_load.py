@@ -1,15 +1,19 @@
 """Cumulative counters of the routed (mixture-of-experts) layers for the
 `[perf]` record, summed on the host from the per-expert scalars a step of
 the decoder families returns (models/lfm2_moe.expert_scalars:
-`moe_l<layer>_e<expert>`, `moe_l<layer>_dropped`, `moe_pairs_routed`), and
+`moe_l<layer>_e<expert>`, `moe_l<layer>_dropped`, `moe_l<layer>_windows`,
+`moe_pairs_routed`), and
 of the gated delta-rule scans (models/kimi_linear.py: `kda_tokens`,
 `kda_kernel_tokens`, `kda_resets`, passed on as they are summed).
 
 Per routed layer L, since the run began: `moe_l<L>_pairs` (token, expert)
 pairs routed to the experts held here; `moe_l<L>_load_min/_mean/_max` the
 held experts' tokens; `moe_l<L>_dropped` held pairs not computed (the layer
-is dropless: always 0); `moe_l<L>_held_share` the share of ALL routed pairs
-that stayed on this rank (near held / total experts).
+is dropless: always 0); `moe_l<L>_windows` windows of sorted pairs the
+layer's loop ran (ops/moe.live_windows: one a layer pass, a micro-batch, at
+an even load; more only where the held experts drew more than a window's
+pairs); `moe_l<L>_held_share` the share of ALL routed pairs that stayed on
+this rank (near held / total experts).
 """
 
 from __future__ import annotations
@@ -18,7 +22,7 @@ import re
 from typing import Dict
 
 _LOAD = re.compile(r"^moe_l(\d+)_e(\d+)$")
-_DROPPED = re.compile(r"^moe_l(\d+)_dropped$")
+_SUMMED = re.compile(r"^moe_l(\d+)_(dropped|windows)$")
 _KDA = ("kda_tokens", "kda_kernel_tokens", "kda_resets")
 
 
@@ -26,6 +30,7 @@ class ExpertLoadCounters:
     def __init__(self):
         self.load: Dict[int, Dict[int, float]] = {}
         self.dropped: Dict[int, float] = {}
+        self.windows: Dict[int, float] = {}
         self.routed = 0.0
         self.kda: Dict[str, float] = {}
 
@@ -39,11 +44,10 @@ class ExpertLoadCounters:
                 expert = int(m.group(2))
                 layer[expert] = layer.get(expert, 0.0) + float(value)
                 continue
-            m = _DROPPED.match(key)
+            m = _SUMMED.match(key)
             if m:
-                layer = int(m.group(1))
-                self.dropped[layer] = (self.dropped.get(layer, 0.0)
-                                       + float(value))
+                layer, sums = int(m.group(1)), getattr(self, m.group(2))
+                sums[layer] = sums.get(layer, 0.0) + float(value)
             elif key == "moe_pairs_routed":
                 self.routed += float(value)
             elif key in _KDA:
@@ -59,5 +63,6 @@ class ExpertLoadCounters:
             out[f"moe_l{layer}_load_mean"] = pairs / len(values)
             out[f"moe_l{layer}_load_max"] = max(values)
             out[f"moe_l{layer}_dropped"] = self.dropped.get(layer, 0.0)
+            out[f"moe_l{layer}_windows"] = self.windows.get(layer, 0.0)
             out[f"moe_l{layer}_held_share"] = pairs / max(self.routed, 1.0)
         return out

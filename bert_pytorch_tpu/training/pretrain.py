@@ -78,9 +78,9 @@ LM_STEP_SCOPES = (
 # to the parent alone (under `attention`: BERT's LayerNorm kernels, lfm2's
 # and kimi's projections and q/k norms; under `kda/scan`: the chunk-major
 # transposes, the outer scan's slices and the walk over a block's chunks;
-# under `moe`: the held experts' casts and, in the backward pass, the
-# windows' scan's running sums of the held weights' gradients, which JAX
-# names after the scan alone). tests/test_step_scopes.py finds
+# under `moe`: the held experts' casts, the loops' slices of the sorted
+# pairs and `moe/accumulate`, which only a second live window's backward
+# pass runs: ops/moe._live_windows). tests/test_step_scopes.py finds
 # every child in each family's compiled step, forward and backward;
 # `program_scopes` in the run's second header counts them in the executable
 # (run_pretraining.py); docs/OBSERVABILITY.md draws the tree.

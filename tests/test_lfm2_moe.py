@@ -13,6 +13,7 @@ import os
 import sys
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -225,6 +226,163 @@ def test_every_token_to_one_held_expert_drops_nothing(both_held):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
 
 
+def _loop_as_it_was(x, routing, w1, w3, w2, held, window_rows,
+                    activation="silu"):
+    """ops/moe.held_experts before its loop had a rule of its own, kept here
+    as the statement the rule is held to: a `lax.scan` over ALL windows, a
+    skipped one through the untaken branch of a `lax.cond`, each window
+    rematerialised, and reverse mode left to JAX (which hands back zeros of
+    the weights' size from every skipped window and sums them)."""
+    lo, hi = held
+    n_held = hi - lo
+    t, k = routing.experts.shape
+    pairs = t * k
+    window_rows = min(int(window_rows), pairs)
+    n_windows = -(-pairs // window_rows)
+    expert = routing.experts.reshape(-1)
+    local = jnp.where((expert >= lo) & (expert < hi), expert - lo, n_held)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    n_pairs = jnp.sum(sizes)
+    pad = n_windows * window_rows - pairs
+    tokens = jnp.pad((order // k).astype(jnp.int32), (0, pad))
+    gates = jnp.pad(routing.gates.reshape(-1)[order], (0, pad))
+
+    @jax.checkpoint
+    def window(out, done, x, w1, w3, w2, tokens, gates, start):
+        return jax.lax.cond(
+            start < n_pairs,
+            lambda: moe_ops._window(out, done, x, w1, w3, w2, tokens, gates,
+                                    sizes, n_pairs, start, activation),
+            lambda: (out, done))
+
+    (out, done), _ = jax.lax.scan(
+        lambda carry, inp: (window(*carry, x, w1, w3, w2, *inp), None),
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros([], jnp.int32)),
+        (tokens.reshape(n_windows, window_rows),
+         gates.reshape(n_windows, window_rows),
+         jnp.arange(n_windows, dtype=jnp.int32) * window_rows))
+    return out, sizes, n_pairs - done
+
+
+# held experts 2..5 of 16, 256 tokens x 2 selections in windows of 128 rows:
+# the selection bias says where the tokens go, and so how many windows live
+_LOADS = {"even": ({}, 1), "one_held_expert": ({5: 10.0, 4: 5.0}, 4),
+          "none_held": ({0: 10.0, 1: 5.0}, 1)}
+
+
+def _routed_case(load, dtype=jnp.float32):
+    t, e, window_rows = 256, 64, 128
+    sizes = ref.sizes_from_config(dict(TOY, experts_total=16))
+    lp = ref.init_params(SEED, sizes)["layers"][1]
+    bias = jnp.zeros((16,))
+    for expert, value in _LOADS[load][0].items():
+        bias = bias.at[expert].set(value)
+    x = jax.random.normal(jax.random.PRNGKey(1), (t, e), jnp.float32)
+    weight = jnp.cos(jnp.arange(t * e, dtype=jnp.float32)).reshape(t, e)
+
+    def loss(held_experts, activation, x, w1, w3, w2, kernel):
+        routing = moe_ops.route(x, kernel, bias, 2, True, 1.0)
+        out, load, dropped = held_experts(
+            x.astype(dtype), routing, w1.astype(dtype), w3.astype(dtype),
+            w2.astype(dtype), (2, 6), window_rows, activation)
+        # through the gates too: the router's kernel takes their cotangent
+        return jnp.sum(out * weight), (load, dropped)
+
+    return loss, (x, lp["ew1"], lp["ew3"], lp["ew2"], lp["wg"]), window_rows
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+@pytest.mark.parametrize("load", sorted(_LOADS))
+def test_the_loops_rule_gives_the_gradients_of_the_loop_as_it_was(
+        load, activation):
+    """Value and gradients (x, w1, w3, w2 and, through the gates, the
+    router's kernel) of held_experts, whose loop over windows has a
+    hand-written rule, against the plain statement of that loop: bit for
+    bit, with one live window and with several (the rule adds the live
+    windows in the order reverse mode did, and the zeros of the skipped ones
+    added nothing)."""
+    loss, args, window_rows = _routed_case(load)
+    with jax.default_matmul_precision("highest"):
+        (got, (held, dropped)), g_got = jax.jit(jax.value_and_grad(
+            lambda *a: loss(moe_ops.held_experts, activation, *a),
+            argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        (want, _), g_want = jax.jit(jax.value_and_grad(
+            lambda *a: loss(_loop_as_it_was, activation, *a),
+            argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    live = int(moe_ops.live_windows(jnp.sum(held), window_rows))
+    assert live == _LOADS[load][1] and int(dropped) == 0
+    assert (int(jnp.sum(held)) == 0) == (load == "none_held")
+    assert float(got) == float(want)
+    for name, a, b in zip(("x", "w1", "w3", "w2", "router"), g_got, g_want):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        assert load == "none_held" or float(jnp.max(jnp.abs(a))) > 0, name
+
+
+def _loops(jaxpr, found=None):
+    """Every `while` equation of a jaxpr, nested ones too."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _loops(sub, found)
+    return found
+
+
+def _zero_fills(jaxpr, found=None):
+    """Shapes of the arrays a jaxpr (and what it calls) fills with one
+    value."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "broadcast_in_dim"
+                and not eqn.invars[0].aval.shape):
+            found.append(tuple(eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _zero_fills(sub, found)
+    return found
+
+
+def test_the_backward_loop_is_bound_by_data_and_fills_no_weights():
+    """The differentiated layer is two loops (the windows forward, the
+    earlier live windows backward), each bound by a value computed from the
+    routing and not by a constant; no `cond` is left, so no window is
+    walked that computes nothing; and the backward loop's body adds into
+    its carry, under `moe/accumulate`, without filling an array of the
+    weights' shape."""
+    loss, args, _ = _routed_case("even", jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: loss(moe_ops.held_experts, "silu", *a)[0],
+        argnums=(0, 1, 2, 3, 4)))(*args).jaxpr
+    text = str(jaxpr)
+    assert " cond[" not in text and "scan[" not in text
+    loops = _loops(jaxpr)
+    assert len(loops) == 2
+    for eqn in loops:
+        # fori_loop(lo, hi): the bound is among the loop's operands; a
+        # bound known when tracing would sit in cond_jaxpr as a literal
+        cond = eqn.params["cond_jaxpr"].jaxpr
+        (lt,) = [e for e in cond.eqns if e.primitive.name == "lt"]
+        assert not any(isinstance(v, jax.extend.core.Literal) for v in lt.invars)
+    backward = loops[-1].params["body_jaxpr"].jaxpr
+    sums = [e.primitive.name for e in backward.eqns
+            if str(e.source_info.name_stack) == "moe/accumulate"]
+    # x, w1, w3, w2 added, the window's gates written to their rows
+    assert sums.count("add") >= 4 and "dynamic_update_slice" in sums
+    # (the fills there are: a live window's own, of its rows and of the
+    # tokens' shape: the base of its scatter of x's cotangent, as before)
+    w1, w2 = args[1].shape, args[3].shape
+    fills = _zero_fills(backward)
+    assert fills and not [s for s in fills if s in (w1, w2)]
+    # what the loop as it was made of the same layer: zeros of the weights'
+    # shape out of every skipped window
+    old = jax.make_jaxpr(jax.grad(
+        lambda *a: loss(_loop_as_it_was, "silu", *a)[0],
+        argnums=(0, 1, 2, 3, 4)))(*args).jaxpr
+    assert [s for s in _zero_fills(old) if s in (w1, w2)]
+
+
 def _two_documents(s=256, cut=100):
     seg = np.ones((1, s), np.int32)
     seg[0, cut:] = 2
@@ -423,6 +581,11 @@ def test_entry_point_trains_the_family_and_counts_expert_load(tmp_path):
         assert last[f"moe_l{layer}_pairs"] == sum(
             r[f"moe_l{layer}_e{e}"] for r in train[:-1] for e in range(4))
         assert 0.2 < last[f"moe_l{layer}_held_share"] < 0.8     # 4 of 8
+        # half the experts held: a window is all the pairs, so the loop
+        # runs it once a layer pass, whatever the routing
+        windows = [r[f"moe_l{layer}_windows"] for r in train[:-1]]
+        assert last[f"moe_l{layer}_windows"] == sum(windows)
+        assert windows[0] >= 1 and set(windows) == {windows[0]}
         assert (last[f"moe_l{layer}_load_min"]
                 <= last[f"moe_l{layer}_load_mean"]
                 <= last[f"moe_l{layer}_load_max"])
