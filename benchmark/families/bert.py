@@ -133,11 +133,13 @@ def followed_by_program(scalars: dict, steps: int) -> dict:
     return {}
 
 
-def window_extras(segs: dict, scalars: dict) -> dict:
+def window_extras(segs: dict, scalars: dict, cell: dict) -> dict:
     """What the family adds to the window's record (`segs`: the timed
     steps' segment ids where rows are packed, `scalars`: every step's
-    logged values): per step the sum over documents of length squared,
-    what attention needs when a token attends only inside its document."""
+    logged values, `cell`: the cell's `config` and `traffic` as the child
+    has them; not needed here): per step the sum over documents of length
+    squared, what attention needs when a token attends only inside its
+    document."""
     doc_sq = {}
     for n, seg in segs.items():
         seg = seg.reshape(-1, seg.shape[-1])

@@ -22,6 +22,7 @@ import numpy as np
 from benchmark.families import lfm2_moe as routed_lm
 from benchmark.harness import kimi_flops as flops  # the readers' ctx["flops"]
 
+TOPK_KEY = "num_experts_per_token"  # this family's spelling (routed_lm's)
 followed_by_program = routed_lm.followed_by_program
 compare_extras = routed_lm.compare_extras
 program_args = routed_lm.program_args
@@ -39,14 +40,11 @@ def window_flops(cell: dict, window: dict):
 
 
 def decide(cell: dict, record: dict, check) -> None:
-    """The routed layers' checks of families/lfm2_moe.decide (this family's
-    config calls the experts a token selects `num_experts_per_token`), and
-    the scans' counter: the tokens the program counted on the device (slots
-    that are no padding, times the KDA layers) against the real tokens the
-    harness counted in the same steps' inputs."""
-    routed_lm.decide({"config": {
-        "num_experts_per_tok": cell["config"]["num_experts_per_token"]}},
-        record, check)
+    """The routed layers' checks of families/lfm2_moe.decide (told this
+    family's `TOPK_KEY`), and the scans' counter: the tokens the program
+    counted on the device (slots that are no padding, times the KDA layers)
+    against the real tokens the harness counted in the same steps' inputs."""
+    routed_lm.decide(cell, record, check, TOPK_KEY)
     w = record["window"]
     kda_layers = sum(1 for mixer, _ in flops.layer_kinds(cell["config"])
                      if mixer == "kda")
@@ -106,8 +104,8 @@ def follow(spec: dict, sz: dict, batches: list, keys: list,
     grad_norms = grad_sample = None
     tie_tol = float(t["limits"]["tie_tol"])
     for batch in batches:
-        padding.append(routed_lm._pad_slots(ref, params, batch, sz, quant,
-                                            tie_tol))
+        padding.append(routed_lm.pad_slots(ref, params, batch, sz, quant,
+                                           tie_tol))
         micros = [place_for_reference(
             {k: batch[k][i] for k in ("input_ids", "segment_ids")}, False)
             for i in range(batch["input_ids"].shape[0])]
@@ -137,11 +135,11 @@ def follow(spec: dict, sz: dict, batches: list, keys: list,
             "expert_counts": counts, "near_ties": ties, "padding": padding}
 
 
-def window_extras(segs: dict, scalars: dict) -> dict:
+def window_extras(segs: dict, scalars: dict, cell: dict) -> dict:
     """What the family adds to the window's record: lfm2's (each timed
     step's causal pairs, the held pairs left out over the whole run) and
     the tokens that are no padding each timed step counted for its KDA
     scans."""
-    return dict(routed_lm.window_extras(segs, scalars),
+    return dict(routed_lm.window_extras(segs, scalars, cell),
                 kda_tokens={n: int(scalars[n].get("kda_tokens", 0))
                             for n in segs if n in scalars})

@@ -23,6 +23,10 @@ import numpy as np
 from benchmark.harness import lm_flops as flops  # the readers' ctx["flops"]
 
 _EXPERT_LOAD = re.compile(r"^moe_l(\d+)_e(\d+)$")
+# the configuration's key for the experts a token selects: a family of
+# routed experts that spells it otherwise keeps a value of its own under
+# this name and hands it to `decide` with its cell (families/kimi_linear.py)
+TOPK_KEY = "num_experts_per_tok"
 
 
 # -- the driver's side (this process stays off JAX) ------------------------
@@ -35,7 +39,8 @@ def window_flops(cell: dict, window: dict):
             "of the slots and of the documents' causal attention")
 
 
-def decide(cell: dict, record: dict, check) -> None:
+def decide(cell: dict, record: dict, check,
+           topk_key: str = TOPK_KEY) -> None:
     """Top-k selection is discrete: a token whose k-th and (k+1)-th
     selection scores lie within rounding of each other may pick another
     expert than the reference. Per followed step and routed layer: the
@@ -43,13 +48,14 @@ def decide(cell: dict, record: dict, check) -> None:
     the reference's count of tokens within `tie_tol` of a tie; the gap has
     to stay under the latter (of all 64 experts' flips only those that
     touch a held expert move a count here). A step's padding slots are ONE
-    token repeated some hundreds of times (`_pad_slots`), so one selection
+    token repeated some hundreds of times (`pad_slots`), so one selection
     that rounding turns moves them all: they are taken off the reference's
     counts and its near ties, and off the program's at whichever k experts
     or fewer that leaves the smallest gap. And no held pair may have been
-    left out."""
+    left out. `topk_key`: where the cell's configuration says how many
+    experts a token selects."""
     e = record["compare"]["experts"]
-    k = int(cell["config"]["num_experts_per_tok"])
+    k = int(cell["config"][topk_key])
     for step, (got, want, ties, pad) in enumerate(zip(
             e["program"], e["reference"], e["near_ties"], e["padding"])):
         n = pad["slots"]
@@ -109,7 +115,7 @@ def adapter_functions(sz: dict):
             lambda tree: a.sample_matrices(tree, sz["kinds"]))
 
 
-def _pad_slots(ref, params, batch, sz, quant, tie_tol) -> dict:
+def pad_slots(ref, params, batch, sz, quant, tie_tol) -> dict:
     """A step's padding slots (segment 0) all hold one id at position 0 and
     see no other token, so they are one token: how many there are, and
     through the reference which held experts that token selects in each
@@ -134,6 +140,9 @@ def _pad_slots(ref, params, batch, sz, quant, tie_tol) -> dict:
             "near_ties": np.asarray(jax.device_get(ties)).tolist()}
 
 
+_pad_slots = pad_slots      # the name it had until PR 40
+
+
 def follow(spec: dict, sz: dict, batches: list, keys: list,
            quant=None) -> dict:
     """The reference's losses, first clipped gradient, parameter change and
@@ -151,7 +160,7 @@ def follow(spec: dict, sz: dict, batches: list, keys: list,
     grad_norms = grad_sample = None
     tie_tol = float(t["limits"]["tie_tol"])
     for batch in batches:
-        padding.append(_pad_slots(ref, params, batch, sz, quant, tie_tol))
+        padding.append(pad_slots(ref, params, batch, sz, quant, tie_tol))
         accum = batch["input_ids"].shape[0]
         micros = [place_for_reference(
             {k: batch[k][i] for k in ("input_ids", "segment_ids")}, False)
@@ -210,9 +219,10 @@ def _causal_pairs(seg) -> int:
     return total
 
 
-def window_extras(segs: dict, scalars: dict) -> dict:
+def window_extras(segs: dict, scalars: dict, cell: dict) -> dict:
     """What the family adds to the window's record (`segs`: the timed
-    steps' segment ids, `scalars`: every step's logged values): each timed
+    steps' segment ids, `scalars`: every step's logged values, `cell`: the
+    cell's `config` and `traffic` as the child has them): each timed
     step's causal pairs, and the held pairs left out over the whole run."""
     return {"causal_pairs": {n: _causal_pairs(seg)
                              for n, seg in segs.items()},

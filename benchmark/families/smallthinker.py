@@ -23,13 +23,13 @@ import numpy as np
 from benchmark.families import lfm2_moe as routed_lm
 from benchmark.harness import smallthinker_flops as flops  # ctx["flops"]
 
+TOPK_KEY = "moe_num_active_primary_experts"     # routed_lm's, as spelt here
 followed_by_program = routed_lm.followed_by_program
 compare_extras = routed_lm.compare_extras
 program_args = routed_lm.program_args
 
 _ATTENTION = ("attention/q_proj", "attention/out_proj/kernel")
 _ROUTED = ("moe/experts_w1", "moe/experts_w2", "moe/router")
-_BAND = {}      # "window": the configuration's sliding_window_size (`sizes`)
 
 
 # -- the driver's side (this process stays off JAX) ------------------------
@@ -51,11 +51,9 @@ def window_flops(cell: dict, window: dict):
 
 
 def decide(cell: dict, record: dict, check) -> None:
-    """The routed layers' checks of families/lfm2_moe.decide (this family's
-    config calls the experts a token selects
-    `moe_num_active_primary_experts`)."""
-    routed_lm.decide({"config": {"num_experts_per_tok": cell["config"][
-        "moe_num_active_primary_experts"]}}, record, check)
+    """The routed layers' checks of families/lfm2_moe.decide (told this
+    family's `TOPK_KEY`)."""
+    routed_lm.decide(cell, record, check, TOPK_KEY)
 
 
 # -- the child's side ------------------------------------------------------
@@ -63,10 +61,6 @@ def decide(cell: dict, record: dict, check) -> None:
 def sizes(config: dict, traffic: dict) -> dict:
     from benchmark.reference import smallthinker_ref
 
-    # `window_extras` is handed the timed steps' segment ids and not the
-    # cell: the band its `window_pairs` count is the configuration's, kept
-    # from here (the child calls `sizes` first, on this module)
-    _BAND["window"] = int(config["sliding_window_size"])
     return smallthinker_ref.sizes_from_config(config)
 
 
@@ -151,11 +145,11 @@ def adapter_functions(sz: dict):
 
 
 def _pad_slots(ref, params, batch, sz, quant, tie_tol) -> dict:
-    """families/lfm2_moe._pad_slots (a step's padding slots are one token,
+    """families/lfm2_moe.pad_slots (a step's padding slots are one token,
     counted once through the reference) for a stack whose every layer
     routes: where a step has no such slots, as in the cell's full rows,
     lfm2's counts the routed layers by its own kinds and finds none here."""
-    out = routed_lm._pad_slots(ref, params, batch, sz, quant, tie_tol)
+    out = routed_lm.pad_slots(ref, params, batch, sz, quant, tie_tol)
     if not out["slots"]:
         layers, held = len(sz["kinds"]), sz["held"][1] - sz["held"][0]
         out = dict(out, near_ties=[0] * layers,
@@ -222,14 +216,16 @@ def document_pairs(seg, window: int) -> int:
     return int(total)
 
 
-def window_extras(segs: dict, scalars: dict) -> dict:
+def window_extras(segs: dict, scalars: dict, cell: dict) -> dict:
     """What the family adds to the window's record (`segs`: the timed
-    steps' segment ids, `scalars`: every step's logged values): each timed
-    step's pairs of a full layer (`causal_pairs`) and of a windowed layer
-    (`window_pairs`, under the configuration's band), and the held pairs
-    left out over the whole run (lfm2's count)."""
+    steps' segment ids, `scalars`: every step's logged values, `cell`: the
+    cell's `config` and `traffic`): each timed step's pairs of a full layer
+    (`causal_pairs`) and of a windowed layer (`window_pairs`, under the
+    configuration's band), and the held pairs left out over the whole run
+    (lfm2's count)."""
+    band = int(cell["config"]["sliding_window_size"])
     return dict(
-        routed_lm.window_extras(segs, scalars),
-        window_pairs={n: document_pairs(seg, _BAND["window"])
+        routed_lm.window_extras(segs, scalars, cell),
+        window_pairs={n: document_pairs(seg, band)
                       for n, seg in segs.items()})
 

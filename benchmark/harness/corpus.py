@@ -4,8 +4,14 @@ the `corpus` block of a traffic file.
 
 One generator for every mix: `lengths` names a distribution and its
 parameters; every seed gets the SAME multiset of document lengths (quantiles
-of the distribution) in another order, so that the work of a run does not
-move with the seed, and its own random tokens.
+of the distribution) and its own random tokens. The seed also orders the
+documents, unless the mix states an `order_seed`: the program reads a corpus
+front to back, and where a window holds a small part of it (a tenth of the
+heavy-tailed 16k mix) the order decides how many real tokens and causal
+pairs the window draws, so tokens/s moved with the seed by more than the
+bound (PERF.md section 6, PR 40). With `order_seed` the order is drawn from
+that constant, every seed's run packs the same rows, and the work of a
+window does not move with the seed.
 """
 
 from __future__ import annotations
@@ -50,7 +56,10 @@ def write_shards(out_dir: str, corpus: dict, seq_len: int, vocab_size: int,
     os.makedirs(out_dir, exist_ok=True)
     n, n_shards = int(corpus["samples"]), int(corpus.get("shards", 2))
     rng = np.random.Generator(np.random.PCG64(int(seed)))
-    lengths = rng.permutation(quantile_lengths(corpus["lengths"], n))
+    order = rng
+    if "order_seed" in corpus:
+        order = np.random.Generator(np.random.PCG64(int(corpus["order_seed"])))
+    lengths = order.permutation(quantile_lengths(corpus["lengths"], n))
     ids = rng.integers(FIRST_WORD_ID, vocab_size, (n, seq_len),
                        dtype=np.int32)
     col = np.arange(seq_len)[None, :]

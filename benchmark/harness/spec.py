@@ -111,8 +111,10 @@ def load_driver(driver: str, root: str = ROOT):
 # `program_args`, `weights` (from the seed, in the program's layout),
 # `adapter_functions` (leaf norms, norms of a difference, the matrices
 # compared whole), `follow` (the reference over the followed steps),
-# `followed_by_program`, `window_extras`, `compare_extras` (what the family
-# adds to the child's record). families/bert.py documents each.
+# `followed_by_program`, `window_extras(segs, scalars, cell)` (`cell`: the
+# child's spec, which holds the cell's `config` and `traffic`),
+# `compare_extras` (what the family adds to the child's record).
+# families/bert.py documents each.
 FAMILY_NAMES = ("flops", "window_flops", "decide", "sizes", "program_args",
                 "weights", "adapter_functions", "follow",
                 "followed_by_program", "window_extras", "compare_extras")

@@ -152,7 +152,11 @@ def _step_window(dev: dict) -> tuple:
 
 def self_times(ops: list) -> tuple:
     """(self time of each operation, whether it holds others): an
-    operation's duration minus that of the operations directly inside it."""
+    operation's duration minus that of the operations directly inside it.
+    A child of no duration (the zero-length `custom-call` markers the
+    runtime puts inside big fusions) takes nothing off its parent and does
+    not make it a holder: the parent is a leaf, and the device is busy
+    while it runs."""
     order = sorted(range(len(ops)), key=lambda i: (ops[i][1], -ops[i][2]))
     own = [float(o[2]) for o in ops]
     holds = [False] * len(ops)
@@ -164,7 +168,8 @@ def self_times(ops: list) -> tuple:
         for parent_end, parent in reversed(stack):
             if end <= parent_end:       # the nearest operation that holds it
                 own[parent] -= ops[i][2]
-                holds[parent] = True
+                if ops[i][2] > 0:
+                    holds[parent] = True
                 break
         stack.append((end, i))
     return own, holds

@@ -353,7 +353,7 @@ def main(argv=None) -> int:
         "scopes": scopes, "real_by_step": real,
         "traced_first_step": FOLLOW + WARM + 3,
         "memory": memory,
-    }, **family.window_extras(segs, obs.scalars))
+    }, **family.window_extras(segs, obs.scalars, spec))
     del segs
     got = dict({"losses": [l for _, s, l in obs.loss_reads if s <= FOLLOW],
                 "grad_norms": obs.grad_norms, "grad_sample": obs.grad_sample,

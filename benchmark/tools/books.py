@@ -79,8 +79,10 @@ def holders(events: dict, reduced: dict) -> dict:
     """Self time, as a share of busy time, of operations that hold others,
     by kind. `reduce` counts the device busy only while a LEAF runs, so a
     holder's self time is in by_scope and not in busy_s (why the scope
-    shares sum past 100 %), and reads as idle: right for the gaps inside a
-    `while`, wrong for a fusion that a zero-length marker made a holder."""
+    shares sum past 100 %), and reads as idle: the gaps inside a `while`.
+    A fusion with nothing but zero-length markers inside is a leaf (since
+    PR 40; `trace_reduce.self_times`), so "other" stays empty unless an
+    operation of some length runs inside another that is no loop."""
     plane = sorted(events["devices"])[0]
     dev = events["devices"][plane]
     lo, hi = trace_reduce._step_window(dev)[:2]

@@ -62,6 +62,43 @@ def test_shards_schema_and_same_work_for_every_seed(tmp_path, seed):
     assert 0 < specials[:, 1].min() and (specials[:, 1] < specials[:, 2]).all()
 
 
+def _lengths(path):
+    import h5py
+
+    out = []
+    for s in range(2):
+        with h5py.File(path / f"shard_{s}.hdf5") as f:
+            out.append(f["special_token_positions"][:][:, 2] + 1)
+    return np.concatenate(out)
+
+
+@pytest.mark.parametrize("order_seed", [None, 0, 5])
+def test_an_order_seed_takes_the_documents_order_from_the_seed(tmp_path,
+                                                                order_seed):
+    """With `order_seed` every seed reads the same documents in the same
+    order (a window of the run then holds the same work), with its own
+    tokens; without it the seed orders them."""
+    import h5py
+
+    spec = {"samples": 64, "shards": 2, "lengths": DOCS}
+    if order_seed is not None:
+        spec["order_seed"] = order_seed
+    a = corpus.write_shards(str(tmp_path / "a"), spec, 512, 30522, 3)
+    b = corpus.write_shards(str(tmp_path / "b"), spec, 512, 30522, 2**31 + 4)
+    assert a == b
+    la, lb = _lengths(tmp_path / "a"), _lengths(tmp_path / "b")
+    assert sorted(la) == sorted(lb) == sorted(
+        corpus.quantile_lengths(DOCS, 64))
+    assert (la == lb).all() == (order_seed is not None)
+    with h5py.File(tmp_path / "a" / "shard_0.hdf5") as f, \
+            h5py.File(tmp_path / "b" / "shard_0.hdf5") as g:
+        assert (f["input_ids"][:] != g["input_ids"][:]).any()
+    if order_seed is not None:          # and another constant, another order
+        other = dict(spec, order_seed=order_seed + 1)
+        corpus.write_shards(str(tmp_path / "c"), other, 512, 30522, 3)
+        assert (_lengths(tmp_path / "c") != la).any()
+
+
 LARGE = {"hidden_size": 1024, "intermediate_size": 4096,
          "num_hidden_layers": 24}
 

@@ -196,13 +196,18 @@ def test_additions_are_new_files_only(additions, fault, correct):
     assert spec.load_family("third_lm", root).__file__.startswith(root)
     assert callable(spec.load_driver(found["traffic"]["driver"], root))
     got = spec.read_layer_metrics(
-        {**manifest, "per_layer": manifest["per_layer"][-1:]},
+        {**manifest, "per_layer": [m for m in manifest["per_layer"]
+                                   if m["name"] == "dummy_ms"]},
         THIRD, {"x": 21.0}, root)
     assert got == {"dummy_ms": {"value": 42.0, "unit": "ms"}}
+    # the new cell reports what every cell reports, under the same names
+    assert {"setup_lower_s", "step_hbm_share", "attention_core_share.train",
+            "device_idle_share.train"} <= {
+        m["name"] for m in spec.metrics_of_cell(manifest, THIRD, "per_layer")}
     # the cells that were there do not report the new metric
     assert "dummy_ms" not in [
-        m["name"] for m in spec.metrics_of_cell(manifest, CELLS[0],
-                                                "per_layer")]
+        m["name"] for m in spec.metrics_of_cell(
+            manifest, "large-pretrain-128", "per_layer")]
 
     # and no file that was there differs from the repository's
     def differing(cmp):
@@ -224,6 +229,52 @@ def test_additions_are_new_files_only(additions, fault, correct):
     last = json.loads(proc.stdout.splitlines()[-1])
     assert last["correct"] is correct, proc.stdout[-4000:]
     assert "held-expert tokens" in proc.stdout      # the family's own checks
+
+
+# what each family's module asks of the manifest and its files alone
+FAMILY_TESTS = [
+    "lfm2.py::test_the_cells_own_metrics",
+    "lfm2.py::test_configuration_states_the_cut",
+    "kimi_linear.py::test_the_cells_own_metrics",
+    "kimi_linear.py::test_configuration_states_the_cut_and_every_width_as_"
+    "published",
+    "smallthinker.py::test_the_cells_own_metrics",
+    "smallthinker.py::test_configuration_states_the_cut_and_every_width_as_"
+    "published"]
+
+
+def test_the_benchmarks_tests_hold_on_the_additions(additions):
+    """The guard of benchmark/README.md's last rule under "Adding things":
+    a copy of tests/benchmark/ beside the `additions` tree (so that each
+    module's ROOT is that tree, with its seventh cell and its appended
+    metric), and there, in a process of their own, the tests that read
+    only the manifest and its files: all of test_bench_subscopes.py, this
+    module without the tests of the additions themselves, and each
+    family's cell-and-metrics and configuration tests. A test that counts
+    or indexes the manifest, or takes its cells for the ones it knows,
+    fails here before a PR that adds a cell meets it."""
+    root, manifest = additions
+    shutil.copytree(os.path.join(ROOT, "tests", "benchmark"),
+                    os.path.join(root, "tests", "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"),
+                    dirs_exist_ok=True)
+    chosen = ["tests/benchmark/test_bench_subscopes.py",
+              "tests/benchmark/test_bench_manifest.py"]
+    chosen += ["tests/benchmark/test_bench_" + test for test in FAMILY_TESTS]
+    env = {k: v for k, v in os.environ.items() if not k.startswith("PYTEST_")}
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-k", "not additions", *chosen],
+        cwd=root, env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=600)
+    out = proc.stdout[-6000:] + proc.stderr[-2000:]
+    assert proc.returncode == 0, out
+    passed = re.search(r"(\d+) passed", proc.stdout)
+    # at the least a case a cell and a case a per-layer metric, the new
+    # ones among them, and nothing left out
+    assert passed and int(passed.group(1)) >= len(
+        manifest["workloads"]) + len(manifest["per_layer"]), out
+    assert "skipped" not in proc.stdout.splitlines()[-1], out
 
 
 def test_unknown_names_are_errors():

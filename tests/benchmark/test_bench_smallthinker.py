@@ -180,9 +180,12 @@ def test_window_pairs_against_a_brute_force_count(window):
                 want += int(row[i] > 0 and row[i] == row[j]
                             and (not window or i - j < window))
     assert family.document_pairs(seg, window) == want
-    family.sizes(dict(spec.find_cell(MANIFEST, CELL, ROOT)["config"],
-                      sliding_window_size=window or 1), {})
-    extras = family.window_extras({7: seg}, {7: {"moe_l0_dropped": 0}})
+    # the band is the cell's, whatever `sizes` was last called with
+    found = spec.find_cell(MANIFEST, CELL, ROOT)
+    family.sizes(found["config"], {})
+    cell = dict(found, config=dict(found["config"],
+                                   sliding_window_size=window or 1))
+    extras = family.window_extras({7: seg}, {7: {"moe_l0_dropped": 0}}, cell)
     assert extras["causal_pairs"][7] == family.document_pairs(seg, 0)
     assert extras["window_pairs"][7] == family.document_pairs(
         seg, window or 1)

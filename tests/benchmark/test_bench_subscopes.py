@@ -3,8 +3,13 @@ first level of the compiled step's scope tree, read through
 `harness/spec.read_layer_metrics` over a scope map made by hand from the
 `op_name`s the four families' steps carry (forward, backward, recomputed);
 the identities the new shares have to keep with the shares that were
-there; a program without the new scopes (the parent) reads none of them and
-nothing raises; the new reader `perf_last_ratio`."""
+there (and, since PR 40, `moe_rest_share.train` with them); a program
+without the new scopes (the parent) reads none of them and nothing raises;
+the new reader `perf_last_ratio`.
+
+The six cells and the thirteen metrics are named here. Nothing counts or
+indexes the manifest, which later PRs append to: `test_bench_manifest.py`
+runs this module over a manifest with a seventh cell and one more metric."""
 
 import os
 import sys
@@ -24,8 +29,13 @@ NEW = ["attention_core_share.train", "attention_proj_share.train",
        "kda_inverse_share.train", "kda_kernel_share.train",
        "moe_shared_share.kimi.train", "moe_router_share.kimi.train",
        "moe_products_share.kimi.train", "setup_lower_s", "step_hbm_share"]
-CELLS = [w["name"] for w in MANIFEST["workloads"]]
-BERT, LFM2, KIMI, SMALLTHINKER = CELLS[:3], CELLS[3], CELLS[4], CELLS[5]
+BERT = ["large-pretrain-128", "base-pretrain-128",
+        "large-pretrain-512-packed"]
+LFM2 = "lfm2-ep8-clm-8k-packed"
+KIMI = "kimi-linear-ep32-clm-16k-packed"
+SMALLTHINKER = "smallthinker-ep8-clm-16k-fullrow"
+DECODERS = [LFM2, KIMI, SMALLTHINKER]
+CELLS = BERT + DECODERS
 
 STEP = "jit(train_step)/grad_accum/while/body/closed_call/"
 
@@ -170,18 +180,28 @@ def _share(cell, tags):
     return 100.0 * sum(t for _, t, tag in rows if tag in tags) / busy
 
 
-def test_the_new_entries_are_the_manifests_last_and_list_their_cells():
-    last = MANIFEST["per_layer"][-len(NEW):]
-    assert [m["name"] for m in last] == NEW
-    assert all(m["workloads"] for m in last)
-    by_name = {m["name"]: m["workloads"] for m in last}
+def _asked(cell, names=NEW):
+    """Which of `names` the manifest asks of the cell, with a list of cells
+    on the metric or without one (`spec.metrics_of_cell`)."""
+    return [m["name"] for m in spec_lib.metrics_of_cell(
+        MANIFEST, cell, "per_layer") if m["name"] in names]
+
+
+def test_the_thirteen_are_in_the_manifest_and_asked_of_their_cells():
+    assert sorted(m["name"] for m in MANIFEST["per_layer"]
+                  if m["name"] in NEW) == sorted(NEW)
+    of = {name: [cell for cell in CELLS if name in _asked(cell)]
+          for name in NEW}
+    # every cell reports these three (since PR 40 their entries carry no
+    # list of cells, so a cell that a later PR adds reports them too:
+    # test_bench_manifest.py sees that of its seventh cell)
     for name in ("attention_core_share.train", "setup_lower_s",
                  "step_hbm_share"):
-        assert by_name[name] == CELLS
-    assert by_name["attention_proj_share.train"] == BERT
-    assert {tuple(v) for k, v in by_name.items() if k.startswith("conv_")} \
+        assert of[name] == CELLS
+    assert of["attention_proj_share.train"] == BERT
+    assert {tuple(v) for k, v in of.items() if k.startswith("conv_")} \
         == {(LFM2,)}
-    assert {tuple(v) for k, v in by_name.items()
+    assert {tuple(v) for k, v in of.items()
             if k.startswith("kda_") or ".kimi." in k} == {(KIMI,)}
     readers = {spec_lib.load_layer_metric(name, ROOT)["reader"]
                for name in NEW}
@@ -192,8 +212,7 @@ def test_the_new_entries_are_the_manifests_last_and_list_their_cells():
 @pytest.mark.parametrize("cell", CELLS)
 def test_each_cell_reads_its_own_of_the_thirteen(cell):
     got = _read(cell, NEW, _ctx(cell))
-    listed = [m["name"] for m in MANIFEST["per_layer"][-len(NEW):]
-              if cell in m["workloads"]]
+    listed = _asked(cell)
     assert sorted(got) == sorted(listed)
     for name, tags in TAGS.items():
         if name in listed:
@@ -232,6 +251,51 @@ def test_the_new_shares_close_on_the_shares_that_were_there():
             "attention_proj_share.train", 0.0)
         assert parts + _share(cell, {"attention"}) == pytest.approx(
             got["attention_share.train"]), cell
+
+
+REST = "moe_rest_share.train"
+MOE = {LFM2: "moe_share.train", KIMI: "moe_share.kimi.train",
+       SMALLTHINKER: "moe_share.smallthinker.train"}
+MOE_CHILDREN = ["moe_router_share.kimi.train", "moe_shared_share.kimi.train",
+                "moe_products_share.kimi.train",
+                "moe_dispatch_share.kimi.train"]
+
+
+@pytest.mark.parametrize("cell", DECODERS)
+def test_what_is_left_under_moe_closes_the_routed_layers_share(cell):
+    """PR 40's `moe_rest_share.train` (reader `scope_under_share`: under
+    `moe` and under none of its five children; the held experts' casts, the
+    backward loop's `moe/accumulate`): asked of the three decoder cells; on
+    the kimi map the children and it are `moe_share.kimi.train`, on the
+    others the routed layers' share less the router and the grouped
+    products; silent where nothing is left, never 0."""
+    assert _asked(cell, [REST]) == [REST]
+    assert not any(_asked(other, [REST]) for other in BERT)
+    spec = spec_lib.load_layer_metric(REST, ROOT)
+    assert spec["reader"] == "scope_under_share"
+    assert spec["args"]["scope"] == "moe" and sorted(
+        spec["args"]["outside"]) == ["moe/combine", "moe/dispatch",
+                                     "moe/experts", "moe/router",
+                                     "moe/shared"]
+    rows = ROWS[cell] + [
+        (STEP + "transpose(jvp(Decoder))/decoder/layer_1/moe/moe/accumulate/"
+                "add", 0.25, "moe")]
+    got = _read(cell, [REST, MOE[cell]] + MOE_CHILDREN, _ctx(cell, rows))
+    busy = sum(t for _, t, _ in rows) + 1.0
+    left = sum(t for _, t, tag in rows if tag == "moe")
+    assert got[REST] == pytest.approx(100.0 * left / busy)
+    if cell == KIMI:
+        assert left == 0.75         # the cast the map had, and the add
+        assert sum(got[name] for name in MOE_CHILDREN) + got[REST] == \
+            pytest.approx(got[MOE[cell]])
+    else:
+        named = sum(t for path, t, _ in rows if "/moe/router/" in path
+                    or path == "ragged-dot-none")
+        assert got[MOE[cell]] - got[REST] == pytest.approx(
+            100.0 * named / busy)
+    # a step that leaves nothing under `moe` outside the five children
+    bare = [row for row in rows if row[2] != "moe"]
+    assert REST not in _read(cell, [REST, MOE[cell]], _ctx(cell, bare))
 
 
 @pytest.mark.parametrize("cell", CELLS)
