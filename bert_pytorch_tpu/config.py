@@ -608,9 +608,199 @@ class SmallThinkerConfig:
                                      self.rope_layout))
 
 
+@dataclasses.dataclass(frozen=True)
+class LagunaConfig:
+    """Architecture config of the `laguna` family (poolside Laguna): a
+    pre-norm decoder whose every layer is causal grouped-query attention
+    with a sigmoid gate per head on its output, over the last
+    `sliding_window` tokens or over the whole document (`layer_types`), with
+    a head count PER LAYER (`num_attention_heads_per_layer`) and rotary
+    tables per kind of layer (`rope_parameters`: the windowed layers rotate
+    the whole head, the full layers a part of it under YaRN); a dense SwiGLU
+    MLP or sigmoid-routed experts beside one shared expert after it
+    (`mlp_layer_types`); an untied head (models/laguna.py has the
+    equations).
+
+    Keys are the source's (`config.json` of the model; `rope_parameters` is
+    its nested group, one sub-group a kind of layer). A run may hold one
+    expert-parallel rank's share, as Lfm2MoeConfig's: `num_experts` experts,
+    the range `experts_held` of `experts_total` (the router keeps that
+    width) and `vocab_size` rows of the vocabulary; a cut stack gives the
+    three per-layer lists of the layers it keeps (`num_hidden_layers`
+    entries each).
+    """
+
+    model_type: str = "laguna"
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 40
+    num_attention_heads: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    max_position_embeddings: int = 262144
+    attention_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 256
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    tie_word_embeddings: bool = False
+    gating: bool = True
+    sliding_window: int = 512
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    num_attention_heads_per_layer: Tuple[int, ...] = ()
+    moe_apply_router_weight_on_input: bool = False
+    partial_rotary_factor: float = 0.5
+    moe_routed_scaling_factor: float = 2.5
+    # rope_parameters, a sub-group a kind of layer, each as sorted
+    # (key, value) pairs (the config is a static field of the modules)
+    rope_full_attention: Tuple[Tuple[str, Any], ...] = ()
+    rope_sliding_attention: Tuple[Tuple[str, Any], ...] = ()
+    experts_total: Optional[int] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    initializer_range: float = 0.02
+    model_name: Optional[str] = None
+    # run settings, as BertConfig's
+    dtype: str = "bfloat16"
+    checkpoint_activations: bool = False
+    remat_policy: str = "auto"
+    attention_impl: str = "auto"
+
+    # keys of the configuration's file that carry no size of this program's
+    _IGNORED = ("vocab_rows_total", "vocab_rows_held", "rope_parameters")
+    _ROPE_KEYS = ("rope_theta", "rope_type", "factor",
+                  "original_max_position_embeddings", "beta_slow",
+                  "beta_fast", "attention_factor", "partial_rotary_factor")
+
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any]) -> "LagunaConfig":
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = [k for k in d if k not in known
+                   and k not in DOCUMENTED_DATA_KEYS
+                   and k not in cls._IGNORED]
+        kw = {k: v for k, v in d.items() if k in known}
+        for kind, group in (d.get("rope_parameters") or {}).items():
+            if kind == "original_max_position_embeddings":
+                continue        # the full layers' sub-group repeats it
+            if kind not in ("full_attention", "sliding_attention"):
+                unknown.append(f"rope_parameters.{kind}")
+                continue
+            unknown += [f"rope_parameters.{kind}.{k}" for k in group
+                        if k not in cls._ROPE_KEYS]
+            kw[f"rope_{kind}"] = tuple(sorted(group.items()))
+        if unknown:
+            raise ValueError(
+                f"laguna model config: unknown key(s) {sorted(unknown)}")
+        for key in ("layer_types", "mlp_layer_types",
+                    "num_attention_heads_per_layer", "experts_held"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        cfg = cls(**kw)
+        cfg.layer_kinds  # raises on an inconsistent cut
+        return cfg
+
+    @classmethod
+    def from_json_file(cls, path: str) -> "LagunaConfig":
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_dict(json.load(f))
+
+    def to_dict(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    def replace(self, **kw: Any) -> "LagunaConfig":
+        return dataclasses.replace(self, **kw)
+
+    # the names models/lfm2_moe.py's shared modules read. The config says
+    # nothing of how the router scores, renormalises or selects: the
+    # convention of its family of models (256 experts, 8 a token, a scaling
+    # factor, one shared expert), which is what ops/moe.route has
+    norm_eps = property(lambda self: self.rms_norm_eps)
+    norm_topk_prob = property(lambda self: True)
+    use_expert_bias = property(lambda self: True)
+    routed_scaling_factor = property(
+        lambda self: self.moe_routed_scaling_factor)
+    router_scores = property(lambda self: "sigmoid")
+    expert_activation = property(lambda self: "silu")
+
+    @property
+    def router_width(self) -> int:
+        return int(self.experts_total or self.num_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of "
+                f"num_experts={self.num_experts} out of {self.router_width}")
+        return int(lo), int(hi)
+
+    def rope(self, kind: str) -> Dict[str, Any]:
+        """The rotary parameters of a kind of layer ("full" or "sliding"):
+        its sub-group of `rope_parameters`, `partial_rotary_factor` from the
+        top level where the sub-group has none."""
+        group = dict(getattr(self, f"rope_{kind}_attention"))
+        group.setdefault("partial_rotary_factor", self.partial_rotary_factor)
+        return group
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, int, str], ...]:
+        """(attention, query heads, ffn) of every layer of the stack as run:
+        attention "sliding" or "full", ffn "dense" or "moe"."""
+        n = self.num_hidden_layers
+        lists = (self.layer_types, self.mlp_layer_types,
+                 self.num_attention_heads_per_layer)
+        if any(len(x) != n for x in lists):
+            raise ValueError(
+                "layer_types, mlp_layer_types and "
+                "num_attention_heads_per_layer must give one entry for each "
+                f"of num_hidden_layers={n} layers (have "
+                f"{[len(x) for x in lists]})")
+        attention = {"sliding_attention": "sliding",
+                     "full_attention": "full"}
+        ffn = {"dense": "dense", "sparse": "moe"}
+        bad = [t for t in self.layer_types if t not in attention]
+        bad += [t for t in self.mlp_layer_types if t not in ffn]
+        if bad:
+            raise ValueError(f"unknown layer type(s) {sorted(set(bad))}")
+        ropes = {kind: self.rope(kind) for kind in ("full", "sliding")
+                 if kind in (attention[t] for t in self.layer_types)}
+        unsupported = [
+            name for name, bad in (
+                ("attention_bias", self.attention_bias),
+                ("gating=false", self.gating is not True),
+                ("tie_word_embeddings", self.tie_word_embeddings),
+                ("moe_apply_router_weight_on_input",
+                 self.moe_apply_router_weight_on_input),
+                ("sliding_window < 1", self.sliding_window < 1),
+                ("shared_expert_intermediate_size != moe_intermediate_size",
+                 self.shared_expert_intermediate_size
+                 != self.moe_intermediate_size),
+                ("a head count that is no multiple of num_key_value_heads",
+                 any(h % self.num_key_value_heads
+                     for h in self.num_attention_heads_per_layer)),
+                ("rope_parameters without rope_theta",
+                 any("rope_theta" not in r for r in ropes.values())),
+                ("rope_type other than default and yarn",
+                 any(r.get("rope_type", "default") not in ("default", "yarn")
+                     for r in ropes.values())),
+            ) if bad]
+        if unsupported:
+            raise NotImplementedError(
+                f"laguna: not written for {unsupported} (the source model "
+                "uses none of them)")
+        self.held_range
+        return tuple((attention[a], int(h), ffn[m]) for a, h, m in zip(*[
+            self.layer_types, self.num_attention_heads_per_layer,
+            self.mlp_layer_types]))
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "lfm2_moe": Lfm2MoeConfig,
                   "kimi_linear": KimiLinearConfig,
-                  "smallthinker": SmallThinkerConfig}
+                  "smallthinker": SmallThinkerConfig,
+                  "laguna": LagunaConfig}
 
 
 def load_model_config(path: str):

@@ -17,7 +17,8 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import MODEL_FAMILIES
-from bert_pytorch_tpu.models import kimi_linear, lfm2_moe, smallthinker
+from bert_pytorch_tpu.models import (kimi_linear, laguna, lfm2_moe,
+                                     smallthinker)
 from bert_pytorch_tpu.models.bert import BertForPreTraining
 from bert_pytorch_tpu.telemetry.expert_load import ExpertLoadCounters
 from bert_pytorch_tpu.telemetry.stepwatch import flops_per_seq
@@ -99,6 +100,7 @@ FAMILIES = {
                                    kimi_linear.KimiLinearForCausalLM),
     "smallthinker": _decoder_family(smallthinker,
                                     smallthinker.SmallThinkerForCausalLM),
+    "laguna": _decoder_family(laguna, laguna.LagunaForCausalLM),
 }
 
 

@@ -54,8 +54,8 @@ STEP_SCOPES = (
     "encoder", "bert", "grad_accum",
 )
 # The same account for a step of the decoder families (models/lfm2_moe.py,
-# models/kimi_linear.py, models/smallthinker.py), whose models open other
-# scopes, all under `decoder`: `kda` first, then `rmsnorm` (the q/k norms
+# models/kimi_linear.py, models/smallthinker.py, models/laguna.py), whose
+# models open other scopes, all under `decoder`: `kda` first, then `rmsnorm` (the q/k norms
 # inside `attention` are norms), `mlp` the dense FFN only. The last two
 # entries are not scopes of the program: XLA:TPU lowers `lax.ragged_dot`
 # (ops/moe.py's grouped products) to kernels of its own whose `op_name` is
@@ -73,8 +73,8 @@ LM_STEP_SCOPES = (
 # benchmark reads, each with the families (config.MODEL_FAMILIES' keys) whose
 # step opens it. A child's path is `parent/child`, the components next to
 # each other in the `op_name` as benchmark/readers/scope_sum_share.under
-# wants them: smallthinker opens `attn_core` under its two kinds of layer,
-# so those are parents here. What a parent holds beside its children belongs
+# wants them: smallthinker and laguna open `attn_core` under their two kinds
+# of layer, so those are parents here. What a parent holds beside its children belongs
 # to the parent alone (under `attention`: BERT's LayerNorm kernels, lfm2's
 # and kimi's projections and q/k norms; under `kda/scan`: the chunk-major
 # transposes, the outer scan's slices and the walk over a block's chunks;
@@ -85,16 +85,21 @@ LM_STEP_SCOPES = (
 # `program_scopes` in the run's second header counts them in the executable
 # (run_pretraining.py); docs/OBSERVABILITY.md draws the tree.
 _FLAT_ATTENTION = ("bert", "lfm2_moe", "kimi_linear")
-_ROUTED_FAMILIES = ("lfm2_moe", "kimi_linear", "smallthinker")
+_BY_KIND_ATTENTION = ("smallthinker", "laguna")
+_ROUTED_FAMILIES = ("lfm2_moe", "kimi_linear", "smallthinker", "laguna")
 STEP_SUBSCOPES = {
     # ops/attention.dot_product_attention: q, k, v in, context out
     "attention": {"attn_core": _FLAT_ATTENTION,
                   "qkv": ("bert",), "output": ("bert",),
                   # a layer's whole attention, by kind
-                  "attention_window": ("smallthinker",),
-                  "attention_full": ("smallthinker",)},
-    "attention/attention_window": {"attn_core": ("smallthinker",)},
-    "attention/attention_full": {"attn_core": ("smallthinker",)},
+                  "attention_window": _BY_KIND_ATTENTION,
+                  "attention_full": _BY_KIND_ATTENTION,
+                  # models/laguna.py: the rotation of q and k (a part of the
+                  # head, at a table of its kind) and the per-head gate on
+                  # the context, beside the kind's projections and kernels
+                  "rotary": ("laguna",), "gate": ("laguna",)},
+    "attention/attention_window": {"attn_core": _BY_KIND_ATTENTION},
+    "attention/attention_full": {"attn_core": _BY_KIND_ATTENTION},
     # models/lfm2_moe.ShortConv: `mix` is what is no projection
     "conv": {"in_proj": ("lfm2_moe",), "mix": ("lfm2_moe",),
              "out_proj": ("lfm2_moe",)},
@@ -103,10 +108,10 @@ STEP_SUBSCOPES = {
             "scan": ("kimi_linear",), "out": ("kimi_linear",)},
     "kda/scan": {"prepare": ("kimi_linear",)},
     "kda/scan/prepare": {"inverse": ("kimi_linear",)},
-    # ops/moe.py; kimi_linear's shared expert beside the routed ones
+    # ops/moe.py; kimi_linear's and laguna's shared expert beside the routed
     "moe": {"router": _ROUTED_FAMILIES, "dispatch": _ROUTED_FAMILIES,
             "experts": _ROUTED_FAMILIES, "combine": _ROUTED_FAMILIES,
-            "shared": ("kimi_linear",)},
+            "shared": ("kimi_linear", "laguna")},
 }
 
 

@@ -17,8 +17,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from bert_pytorch_tpu.config import (MODEL_FAMILIES, BertConfig,  # noqa: E402
-                                     KimiLinearConfig, Lfm2MoeConfig,
-                                     SmallThinkerConfig)
+                                     KimiLinearConfig, LagunaConfig,
+                                     Lfm2MoeConfig, SmallThinkerConfig)
 from bert_pytorch_tpu.models.families import FAMILIES, family_of  # noqa: E402
 
 VOCAB, SEQ = 2048, 64
@@ -55,6 +55,25 @@ SMALLTHINKER_TOY = {
     "moe_num_active_primary_experts": 2, "rope_layout": [0, 1],
     "sliding_window_layout": [0, 1], "sliding_window_size": 8,
 }
+LAGUNA_TOY = {
+    "model_type": "laguna", "vocab_size": VOCAB, "hidden_size": 32,
+    "intermediate_size": 64, "moe_intermediate_size": 16,
+    "shared_expert_intermediate_size": 16, "num_hidden_layers": 2,
+    "num_attention_heads": 2, "num_key_value_heads": 2, "head_dim": 8,
+    "num_attention_heads_per_layer": [2, 4],
+    "layer_types": ["full_attention", "sliding_attention"],
+    "mlp_layer_types": ["dense", "sparse"], "sliding_window": 8,
+    "num_experts": 2, "experts_total": 4, "experts_held": [0, 2],
+    "num_experts_per_tok": 2,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "original_max_position_embeddings": 4096, "beta_slow": 1,
+            "beta_fast": 64, "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1}},
+}
 TINY = {
     "bert": BertConfig(
         vocab_size=VOCAB, hidden_size=32, num_hidden_layers=2,
@@ -66,6 +85,7 @@ TINY = {
         dtype="float32"),
     "smallthinker": SmallThinkerConfig.from_dict(SMALLTHINKER_TOY).replace(
         dtype="float32"),
+    "laguna": LagunaConfig.from_dict(LAGUNA_TOY).replace(dtype="float32"),
 }
 
 
@@ -147,8 +167,8 @@ def test_record_builds_initialises_and_steps_its_family(name, tmp_path):
 @pytest.mark.parametrize("flags", [
     ["--kfac"], ["--stream_dir", "corpus"], ["--stacked_params", "true"],
     ["--steps_per_loop", "2"]], ids=lambda f: f[0].lstrip("-"))
-@pytest.mark.parametrize("toy", [LFM2_TOY, KIMI_TOY],
-                         ids=["lfm2_moe", "kimi_linear"])
+@pytest.mark.parametrize("toy", [LFM2_TOY, KIMI_TOY, LAGUNA_TOY],
+                         ids=["lfm2_moe", "kimi_linear", "laguna"])
 def test_decoder_families_refuse_what_they_cannot_run_with(flags, toy,
                                                            tmp_path):
     import run_pretraining
@@ -162,7 +182,7 @@ def test_decoder_families_refuse_what_they_cannot_run_with(flags, toy,
     with pytest.raises(SystemExit) as e:
         run_pretraining.main(argv)
     message = str(e.value)
-    assert "'lfm2_moe', 'kimi_linear'" in message
+    assert "'lfm2_moe', 'kimi_linear', 'smallthinker', 'laguna'" in message
     for flag in ("--kfac", "--stream_dir", "--stacked_params",
                  "--steps_per_loop"):
         assert flag in message
@@ -177,6 +197,7 @@ def test_bert_refuses_none_of_them():
     assert FAMILIES["lfm2_moe"].refusal(args)
     # the decoder families' ONE refusal
     assert FAMILIES["kimi_linear"].refusal is FAMILIES["lfm2_moe"].refusal
+    assert FAMILIES["laguna"].refusal is FAMILIES["lfm2_moe"].refusal
     args = argparse.Namespace(kfac=False, stream_dir=None,
                               stacked_params="auto", steps_per_loop=1)
     assert FAMILIES["lfm2_moe"].refusal(args) is None

@@ -156,6 +156,7 @@ DECODERS = {
     "lfm2_moe": ("tests.test_lfm2_moe", "Lfm2MoeConfig"),
     "kimi_linear": ("tests.test_kimi_linear", "KimiLinearConfig"),
     "smallthinker": ("tests.test_smallthinker", "SmallThinkerConfig"),
+    "laguna": ("tests.test_laguna", "LagunaConfig"),
 }
 FAMILIES = ("bert",) + tuple(DECODERS)
 

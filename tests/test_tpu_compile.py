@@ -133,6 +133,12 @@ def test_flash_split_backward_compiles(v5e, bias, segments,
     # and its layers banded at 4,096
     ("smallthinker-full", 1, 16384, 28, 4, 128, 128, 7, 0),
     ("smallthinker-band", 1, 16384, 28, 4, 128, 128, 7, 4096),
+    # laguna-ep8-clm-16k-packed: a windowed layer's program owns the EIGHT
+    # query heads of a key/value head of 128 under a band of 512, one tile
+    # wide (the dkv call's Q and dO panels are 64 MiB: 80 MiB asked of a
+    # core's 128); a full layer's owns six
+    ("laguna-band", 1, 16384, 64, 8, 128, 128, 8, 512),
+    ("laguna-full", 1, 16384, 48, 8, 128, 128, 6, 0),
 ])
 def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
                                                    d, dv, heads, window):
