@@ -85,19 +85,33 @@ the group's dk and dv in its float32 accumulators and writes one block a
 key/value head, in the parameters' dtype. Both take the bh layout and the
 split backward whatever the shape.
 
-**A band** (`window=W` with `causal=True`; models/smallthinker.py's
-sliding-window layers): a query attends to the last W positions up to its
-own, `0 <= q_pos - k_pos < W`, of its own segment where rows are packed
-(documents are contiguous, so the distance inside a row is the distance
-inside the document). One more condition of the same mask, and a lower edge
-of the same skip test: a (q block, k block) pair wholly BEHIND the band is
-skipped like one above the diagonal, under the one test a pair for all
-heads of the program (at S = 16,384, W = 4,096 and 512 x 512 tiles 252 of
-the causal triangle's 528 pairs run). The banded calls take the bh layout
-and the split backward whatever the shape, and carry kernel names of their
-own (`flash_win_fwd`, `flash_win_bwd_dq`, `flash_win_bwd_dkv`), so that a
-trace tells the two kinds of layer apart. A program still holds its whole
-(S, D) panels; W + one tile of them would do (PERF.md section 7).
+**A band** (`window=W` with `causal=True`; the sliding-window layers of
+models/smallthinker.py and models/laguna.py): a query attends to the last W
+positions up to its own, `0 <= q_pos - k_pos < W`, of its own segment where
+rows are packed (documents are contiguous, so the distance inside a row is
+the distance inside the document). One more condition of the same mask. The
+banded calls take the bh layout and the split backward whatever the shape,
+and carry kernel names of their own (`flash_win_fwd`, `flash_win_bwd_dq`,
+`flash_win_bwd_dkv`), so that a trace tells the two kinds of layer apart.
+Their form follows their shapes (`_band_steps`; no flag chooses):
+
+- where the band spans fewer blocks than the row (`band_blocks`: 2 of 32 at
+  S = 16,384, W = 512 and 512 x 512 tiles, 9 at W = 4,096) the loop over
+  the other side's blocks is OUT of the program's text: the grid's last,
+  sequential axis walks the band's blocks only, K and V (Q, dO and the row
+  statistics in the dkv kernel) arrive as BLOCKS by an index map clamped
+  into the row, and a program is ONE tile body with its heads' running
+  results in VMEM scratch from the band's first step to its last
+  (`_fwd_band_kernel`, `_dq_band_kernel`, `_dkv_band_kernel`). No (S, D)
+  panel is held (16 MiB of VMEM asked at S = 16,384 where panels asked 80),
+  and a head's programs visit `band_tiles` pairs (63, 252), all of them
+  live, in the order the unrolled loop ran them: the same sums, bit for
+  bit. What an S / blk-body program paid for a thin band is in PERF.md
+  section 6, PR 42;
+- where the band spans every block of the row it is a lower edge of the
+  panel-walking programs' skip test: a (q block, k block) pair wholly
+  BEHIND the band is skipped like one above the diagonal, under the one
+  test a pair for all heads of the program.
 """
 
 from __future__ import annotations
@@ -363,6 +377,35 @@ def _causal_live(causal: bool, q0, bq: int, k0, bk: int = 0,
     return live
 
 
+def band_blocks(blk: int, window: int) -> int:
+    """The k blocks that hold a q block's band of `window` positions (its
+    own and those up to `window` - 1 positions back: `_causal_live` of every
+    other is false), where both blocks are `blk` long: 2 of a row's 32 at
+    S = 16,384, blk 512 and a band of 512, 9 at a band of 4,096. 0 without a
+    band; a row may have fewer."""
+    return -(-(window - 1) // blk) + 1 if window else 0
+
+
+def band_tiles(s: int, blk: int, window: int) -> int:
+    """The (q block, k block) pairs of a row inside the band, every one of
+    them live (`_causal_live`): what a banded program set visits for a
+    head, 63 of the row's 32 x 32 and 252 in `band_blocks`' two cases."""
+    nb = band_blocks(blk, window)
+    return sum(min(qi + 1, nb) for qi in range(s // blk))
+
+
+def _band_steps(s: int, blk_q: int, blk_k: int, window: int) -> int:
+    """The form of a banded call, by its shapes alone: `band_blocks` where
+    the band spans fewer blocks than the row (the kernels then walk the
+    band's blocks as a grid axis of that many steps: `_fwd_band_kernel`),
+    0 where it spans them all, or there is no band (the panel-walking
+    kernels)."""
+    if blk_q != blk_k:
+        return 0
+    nb = band_blocks(blk_q, window)
+    return nb if nb < s // blk_q else 0
+
+
 def _causal_pos(causal: bool, q0, k0, bq: int, bk: int):
     """(rows, cols) of `_mask` for the tile at rows q0.., columns k0..: a
     pair is allowed where rows >= cols. A (bq, 1) and a (1, bk) vector,
@@ -572,6 +615,48 @@ def _kv_at(kv_ref, t):
     return t if kv_ref.shape[0] > 1 else 0
 
 
+def _fwd_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, acc_ref,
+               *, row, q0, bk: int, segq, keep_rows, scale: float,
+               rate: float, has_bias: bool, causal: bool, window: int):
+    """head(t, cols, k0, segk) of a forward program: head t's tile against
+    the keys and values at `cols` of k_ref / v_ref (a static slice of its
+    panel, or the whole of a block), whose first position is `k0`, folded
+    into the head's running (m, l, acc)."""
+    hp, bq, _ = q_ref.shape
+
+    def head(t, cols, k0, segk):
+        q, tile_scale = _scale_operand(q_ref[t], scale)
+        m_ref[t], l_ref[t], acc_ref[t] = _fwd_tile(
+            (m_ref[t], l_ref[t], acc_ref[t]), q,
+            k_ref[_kv_at(k_ref, t), cols, :],
+            v_ref[_kv_at(v_ref, t), cols, :], tile_scale,
+            (lambda: bias_ref[0, 0, cols][None, :]) if has_bias else None,
+            lambda: _causal_pos(causal, q0, k0, bq, bk),
+            segq, segk,
+            lambda: _keep_tile(
+                keep_rows, _keep_cols(seed_ref[0], row * hp + t, k0, bk),
+                rate),
+            rate, window)
+
+    return head
+
+
+def _fwd_start(m_ref, l_ref, acc_ref):
+    """The heads' running max, sum and accumulator before their first
+    tile."""
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+
+def _fwd_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref, segq, rate: float):
+    """The heads' outputs and logsumexps after their last tile."""
+    for t in range(o_ref.shape[0]):
+        out, l_safe = _fwd_out(l_ref[t], acc_ref[t], segq, rate)
+        o_ref[t] = out.astype(o_ref.dtype)
+        lse_ref[t, 0, :] = (m_ref[t] + jnp.log(l_safe))[:, 0]
+
+
 def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                    segk_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                    scale: float, blk_k: int, rate: float, has_bias: bool,
@@ -588,39 +673,23 @@ def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
     segq = segq_ref[0, 0][:, None] if has_segments else None
     qrange = _block_range(ranges_ref, batch, qi)
     keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
-    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    _fwd_start(m_ref, l_ref, acc_ref)
+    head = _fwd_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref,
+                      acc_ref, row=row, q0=q0, bk=blk_k, segq=segq,
+                      keep_rows=keep_rows, scale=scale, rate=rate,
+                      has_bias=has_bias, causal=causal, window=window)
 
     for j in range(nk):
         cols = slice(j * blk_k, (j + 1) * blk_k)
         # and to a k block alone, once for every head
         segk = (_seg_keys(segk_ref[0, 0, cols])[None, :]
                 if has_segments else None)
-
-        def head(t, j=j, cols=cols, segk=segk):
-            q, tile_scale = _scale_operand(q_ref[t], scale)
-            m_ref[t], l_ref[t], acc_ref[t] = _fwd_tile(
-                (m_ref[t], l_ref[t], acc_ref[t]), q,
-                k_ref[_kv_at(k_ref, t), cols, :],
-                v_ref[_kv_at(v_ref, t), cols, :], tile_scale,
-                (lambda: bias_ref[0, 0, cols][None, :]) if has_bias else None,
-                lambda: _causal_pos(causal, q0, j * blk_k, bq, blk_k),
-                segq, segk,
-                lambda: _keep_tile(
-                    keep_rows,
-                    _keep_cols(seed_ref[0], row * hp + t, j * blk_k, blk_k),
-                    rate),
-                rate, window)
-
-        _each_head(hp, head, qrange,
-                   _block_range(ranges_ref, batch, s_len // bq + j),
+        _each_head(hp, functools.partial(head, cols=cols, k0=j * blk_k,
+                                         segk=segk),
+                   qrange, _block_range(ranges_ref, batch, s_len // bq + j),
                    _causal_live(causal, q0, bq, j * blk_k, blk_k, window))
 
-    for t in range(hp):
-        out, l_safe = _fwd_out(l_ref[t], acc_ref[t], segq, rate)
-        o_ref[t] = out.astype(o_ref.dtype)
-        lse_ref[t, 0, :] = (m_ref[t] + jnp.log(l_safe))[:, 0]
+    _fwd_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref, segq, rate)
 
 
 def _dropout_late(scale: float, rate: float) -> float:
@@ -633,6 +702,46 @@ def _dropout_late(scale: float, rate: float) -> float:
     return scale / (1.0 - rate) if rate > 0.0 else scale
 
 
+def _dq_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, lse_ref, delta_ref,
+              do_ref, acc_ref, *, row, q0, bk: int, segq, keep_rows,
+              scale: float, rate: float, has_bias: bool, causal: bool,
+              window: int):
+    """head(t, cols, k0, segk) of a dq program: what the keys and values at
+    `cols` of k_ref / v_ref (as `_fwd_heads`), whose first position is
+    `k0`, add to head t's dq."""
+    hp, bq, _ = q_ref.shape
+    out_scale = _dropout_late(scale, rate)
+
+    def head(t, cols, k0, segk):
+        q, tile_scale = _scale_operand(q_ref[t], scale)
+        kb = k_ref[_kv_at(k_ref, t), cols, :]
+        vb = v_ref[_kv_at(v_ref, t), cols, :]
+        s = jax.lax.dot_general(
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if tile_scale is not None:
+            s = s * tile_scale
+        if has_bias:
+            s = s + bias_ref[0, 0, cols][None, :]
+        s = _mask(s, *_causal_pos(causal, q0, k0, bq, bk), segq, segk,
+                  window)
+        p = jnp.exp(s - lse_ref[t, 0][:, None])
+        dp = jax.lax.dot_general(
+            do_ref[t], vb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if rate > 0.0:
+            keep = _keep_tile(
+                keep_rows, _keep_cols(seed_ref[0], row * hp + t, k0, bk),
+                rate)
+            dp = jnp.where(keep, dp, 0.0)
+        ds = p * (dp - delta_ref[t, 0][:, None])
+        acc_ref[t] += jnp.dot(ds.astype(kb.dtype), kb,
+                              preferred_element_type=jnp.float32) \
+            * out_scale
+
+    return head
+
+
 def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                segk_ref, lse_ref, delta_ref, do_ref, dq_ref, acc_ref, *,
                scale: float, blk_k: int, rate: float, has_bias: bool,
@@ -643,7 +752,6 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
     hp, bq, _ = q_ref.shape
     s_len = k_ref.shape[1]
     nk = s_len // blk_k
-    out_scale = _dropout_late(scale, rate)
     batch = batch_of(row)
 
     segq = segq_ref[0, 0][:, None] if has_segments else None
@@ -651,45 +759,85 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
     keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
     q0 = qi * bq if causal else 0
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    head = _dq_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, lse_ref,
+                     delta_ref, do_ref, acc_ref, row=row, q0=q0, bk=blk_k,
+                     segq=segq, keep_rows=keep_rows, scale=scale, rate=rate,
+                     has_bias=has_bias, causal=causal, window=window)
 
     for j in range(nk):
         cols = slice(j * blk_k, (j + 1) * blk_k)
         segk = (_seg_keys(segk_ref[0, 0, cols])[None, :]
                 if has_segments else None)
-
-        def head(t, j=j, cols=cols, segk=segk):
-            q, tile_scale = _scale_operand(q_ref[t], scale)
-            kb = k_ref[_kv_at(k_ref, t), cols, :]
-            vb = v_ref[_kv_at(v_ref, t), cols, :]
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if tile_scale is not None:
-                s = s * tile_scale
-            if has_bias:
-                s = s + bias_ref[0, 0, cols][None, :]
-            s = _mask(s, *_causal_pos(causal, q0, j * blk_k, bq, blk_k),
-                      segq, segk, window)
-            p = jnp.exp(s - lse_ref[t, 0][:, None])
-            dp = jax.lax.dot_general(
-                do_ref[t], vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if rate > 0.0:
-                keep = _keep_tile(
-                    keep_rows,
-                    _keep_cols(seed_ref[0], row * hp + t, j * blk_k, blk_k),
-                    rate)
-                dp = jnp.where(keep, dp, 0.0)
-            ds = p * (dp - delta_ref[t, 0][:, None])
-            acc_ref[t] += jnp.dot(ds.astype(kb.dtype), kb,
-                                  preferred_element_type=jnp.float32) \
-                * out_scale
-
-        _each_head(hp, head, qrange,
-                   _block_range(ranges_ref, batch, s_len // bq + j),
+        _each_head(hp, functools.partial(head, cols=cols, k0=j * blk_k,
+                                         segk=segk),
+                   qrange, _block_range(ranges_ref, batch, s_len // bq + j),
                    _causal_live(causal, q0, bq, j * blk_k, blk_k, window))
 
     dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _dkv_heads(seed_ref, q_ref, k_ref, v_ref, segq_ref, lse_ref, delta_ref,
+               do_ref, dk_acc_ref, dv_acc_ref, *, row, kj, k0, bq: int, bias,
+               segk, scale: float, rate: float, has_segments: bool,
+               causal: bool, window: int):
+    """head(t, rows, q0) of a dkv program: what the queries at `rows` of
+    q_ref / do_ref and their statistics (a static slice of the panels, or
+    the whole of a block), whose first position is `q0`, add to the dk and
+    dv of head t's key/value head, k block `kj` (`k0`: its first position
+    where attention is causal). `bias`: the k block's (1, bk) row, or
+    None."""
+    hp = q_ref.shape[0]
+    bk = k_ref.shape[1]
+    out_scale = _dropout_late(scale, rate)
+
+    def head(t, rows, q0):
+        kv = _kv_at(k_ref, t)
+        # the resident block takes the scale here: (q . k * scale); dk
+        # needs the q blocks as they are
+        ks, tile_scale = _scale_operand(k_ref[kv], scale)
+        qb = q_ref[t, rows, :]
+        dob = do_ref[t, rows, :]
+        segq = segq_ref[0, 0, rows][:, None] if has_segments else None
+        s = jax.lax.dot_general(
+            qb, ks, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if tile_scale is not None:
+            s = s * tile_scale
+        if bias is not None:
+            s = s + bias
+        s = _mask(s, *_causal_pos(causal, q0, k0, bq, bk), segq, segk,
+                  window)
+        p = jnp.exp(s - lse_ref[t, 0, rows][:, None])
+        if rate > 0.0:
+            keep = _keep_tile(
+                _keep_rows(q0, bq),
+                _keep_cols(seed_ref[0], row * hp + t, kj * bk, bk), rate)
+            p_keep = jnp.where(keep, p, 0.0)
+        else:
+            p_keep = p
+        dv_acc_ref[kv] += jax.lax.dot_general(
+            p_keep.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            dob, v_ref[kv], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if rate > 0.0:
+            dp = jnp.where(keep, dp, 0.0)
+        ds = p * (dp - delta_ref[t, 0, rows][:, None])
+        dk_acc_ref[kv] += jax.lax.dot_general(
+            ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * out_scale
+
+    return head
+
+
+def _dkv_finish(dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, rate: float):
+    """A k block's dk and dv from the sums over its q blocks and heads."""
+    dv = dv_acc_ref[...]
+    if rate > 0.0:
+        dv = dv / (1.0 - rate)
+    dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
 def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
@@ -706,66 +854,139 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
     bk = k_ref.shape[1]
     s_len = q_ref.shape[1]
     nq = s_len // blk_q
-    out_scale = _dropout_late(scale, rate)
     batch = batch_of(row)
 
     segk = _seg_keys(segk_ref[0, 0])[None, :] if has_segments else None
     krange = _block_range(ranges_ref, batch, nq + kj)
     k0 = kj * bk if causal else 0
-    if has_bias:
-        bias = bias_ref[0, 0][None, :]  # (1, BLK_K)
+    bias = bias_ref[0, 0][None, :] if has_bias else None    # (1, BLK_K)
     dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
     dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
+    head = _dkv_heads(seed_ref, q_ref, k_ref, v_ref, segq_ref, lse_ref,
+                      delta_ref, do_ref, dk_acc_ref, dv_acc_ref, row=row,
+                      kj=kj, k0=k0, bq=blk_q, bias=bias, segk=segk,
+                      scale=scale, rate=rate, has_segments=has_segments,
+                      causal=causal, window=window)
 
     for i in range(nq):
         rows = slice(i * blk_q, (i + 1) * blk_q)
-
-        def head(t, i=i, rows=rows):
-            kv = _kv_at(k_ref, t)
-            # the resident block takes the scale here: (q . k * scale); dk
-            # needs the q blocks as they are
-            ks, tile_scale = _scale_operand(k_ref[kv], scale)
-            qb = q_ref[t, rows, :]
-            dob = do_ref[t, rows, :]
-            segq = segq_ref[0, 0, rows][:, None] if has_segments else None
-            s = jax.lax.dot_general(
-                qb, ks, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if tile_scale is not None:
-                s = s * tile_scale
-            if has_bias:
-                s = s + bias
-            s = _mask(s, *_causal_pos(causal, i * blk_q, k0, blk_q, bk),
-                      segq, segk, window)
-            p = jnp.exp(s - lse_ref[t, 0, rows][:, None])
-            if rate > 0.0:
-                keep = _keep_tile(
-                    _keep_rows(i * blk_q, blk_q),
-                    _keep_cols(seed_ref[0], row * hp + t, kj * bk, bk), rate)
-                p_keep = jnp.where(keep, p, 0.0)
-            else:
-                p_keep = p
-            dv_acc_ref[kv] += jax.lax.dot_general(
-                p_keep.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                dob, v_ref[kv], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if rate > 0.0:
-                dp = jnp.where(keep, dp, 0.0)
-            ds = p * (dp - delta_ref[t, 0, rows][:, None])
-            dk_acc_ref[kv] += jax.lax.dot_general(
-                ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * out_scale
-
-        _each_head(hp, head, _block_range(ranges_ref, batch, i), krange,
+        _each_head(hp, functools.partial(head, rows=rows, q0=i * blk_q),
+                   _block_range(ranges_ref, batch, i), krange,
                    _causal_live(causal, i * blk_q, blk_q, k0, bk, window))
 
-    dv = dv_acc_ref[...]
-    if rate > 0.0:
-        dv = dv / (1.0 - rate)
-    dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    _dkv_finish(dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, rate)
+
+
+# The banded kernels (`_band_steps`): the same programs with the loop over
+# the other side's blocks taken OUT of their text. The grid's last axis,
+# sequential, walks the `nb` blocks of the band: step `d` of q block qi is k
+# block j = qi - (nb - 1) + d, in ascending order as the unrolled loop ran
+# its live tiles, so the sums are the same bit for bit (step d of k block kj
+# is q block i = kj + d in the dkv kernel). k_ref / v_ref (q_ref, do_ref and
+# the statistics in dkv) are BLOCKS fetched by an index map that clamps j at
+# 0 (i at the row's last block): a step outside the row runs nothing and,
+# its block index unchanged, fetches nothing. ONE tile body a kernel where
+# the panel programs hold S / blk, and no (S, D) panel in VMEM.
+
+
+def _fwd_band_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+                     segk_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                     scale: float, nb: int, nblk: int, rate: float,
+                     has_bias: bool, has_segments: bool, batch_of,
+                     window: int, ranges_ref=None):
+    """One program per (grid row, q-block, block of its band): the forward
+    of its heads, begun at the band's first step and written at its last."""
+    row, qi, step = ids
+    hp, bq, _ = q_ref.shape
+    bk = k_ref.shape[1]
+    j = qi - (nb - 1) + step
+    batch = batch_of(row)
+    segq = segq_ref[0, 0][:, None] if has_segments else None
+    segk = _seg_keys(segk_ref[0, 0])[None, :] if has_segments else None
+    keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
+    pl.when(step == 0)(lambda: _fwd_start(m_ref, l_ref, acc_ref))
+    head = _fwd_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref,
+                      acc_ref, row=row, q0=qi * bq, bk=bk, segq=segq,
+                      keep_rows=keep_rows, scale=scale, rate=rate,
+                      has_bias=has_bias, causal=True, window=window)
+    _each_head(hp, functools.partial(head, cols=slice(None), k0=j * bk,
+                                     segk=segk),
+               _block_range(ranges_ref, batch, qi),
+               _block_range(ranges_ref, batch,
+                            nblk + jnp.maximum(j, 0)),
+               j >= 0)
+    pl.when(step == nb - 1)(lambda: _fwd_finish(
+        o_ref, lse_ref, m_ref, l_ref, acc_ref, segq, rate))
+
+
+def _dq_band_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+                    segk_ref, lse_ref, delta_ref, do_ref, dq_ref, acc_ref, *,
+                    scale: float, nb: int, nblk: int, rate: float,
+                    has_bias: bool, has_segments: bool, batch_of,
+                    window: int, ranges_ref=None):
+    """One program per (grid row, q-block, block of its band): dq of its
+    heads."""
+    row, qi, step = ids
+    hp, bq, _ = q_ref.shape
+    bk = k_ref.shape[1]
+    j = qi - (nb - 1) + step
+    batch = batch_of(row)
+    segq = segq_ref[0, 0][:, None] if has_segments else None
+    segk = _seg_keys(segk_ref[0, 0])[None, :] if has_segments else None
+    keep_rows = _keep_rows(qi * bq, bq) if rate > 0.0 else None
+
+    @pl.when(step == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    head = _dq_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, lse_ref,
+                     delta_ref, do_ref, acc_ref, row=row, q0=qi * bq, bk=bk,
+                     segq=segq, keep_rows=keep_rows, scale=scale, rate=rate,
+                     has_bias=has_bias, causal=True, window=window)
+    _each_head(hp, functools.partial(head, cols=slice(None), k0=j * bk,
+                                     segk=segk),
+               _block_range(ranges_ref, batch, qi),
+               _block_range(ranges_ref, batch,
+                            nblk + jnp.maximum(j, 0)),
+               j >= 0)
+
+    @pl.when(step == nb - 1)
+    def _():
+        dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _dkv_band_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
+                     segk_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
+                     dk_acc_ref, dv_acc_ref, *, scale: float, nb: int,
+                     nblk: int, rate: float, has_bias: bool,
+                     has_segments: bool, batch_of, window: int,
+                     ranges_ref=None):
+    """One program per (grid row, k-block, q block its band reaches): dk
+    and dv of its heads' key/value heads."""
+    row, kj, step = ids
+    hp, bq, _ = q_ref.shape
+    bk = k_ref.shape[1]
+    i = kj + step
+    batch = batch_of(row)
+    segk = _seg_keys(segk_ref[0, 0])[None, :] if has_segments else None
+    bias = bias_ref[0, 0][None, :] if has_bias else None
+
+    @pl.when(step == 0)
+    def _():
+        dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
+        dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
+
+    head = _dkv_heads(seed_ref, q_ref, k_ref, v_ref, segq_ref, lse_ref,
+                      delta_ref, do_ref, dk_acc_ref, dv_acc_ref, row=row,
+                      kj=kj, k0=kj * bk, bq=bq, bias=bias, segk=segk,
+                      scale=scale, rate=rate, has_segments=has_segments,
+                      causal=True, window=window)
+    _each_head(hp, functools.partial(head, rows=slice(None), q0=i * bq),
+               _block_range(ranges_ref, batch, jnp.minimum(i, nblk - 1)),
+               _block_range(ranges_ref, batch, nblk + kj),
+               i < nblk)
+    pl.when(step == nb - 1)(lambda: _dkv_finish(
+        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, rate))
 
 
 def _dqkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, seg_ref,
@@ -927,6 +1148,18 @@ def _panel_spec(block: tuple, index_map, long_seq: dict) -> pl.BlockSpec:
     if long_seq:
         return pl.BlockSpec(block, index_map, pipeline_mode=pl.Buffered(1))
     return pl.BlockSpec(block, index_map)
+
+
+def _band_params(s: int, lanes: int) -> dict:
+    """Compiler parameters of a banded call (`_band_steps`): the grid's
+    last axis carries the accumulators from step to step, and a long row's
+    program asks for a tile and its blocks, no panel."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    vmem = ({} if s * lanes <= _FUSED_BWD_MAX_PANEL
+            else {"vmem_limit_bytes": _TILE_VMEM_BYTES})
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"), **vmem)}
 
 
 def _to_bh(x):
@@ -1141,6 +1374,134 @@ def _band_kw(causal: bool, window: int) -> dict:
                 **({"window": int(window)} if window else {}))
 
 
+# The block specs of a banded call (`_band_steps`), whose grid is (rows,
+# blocks of the row, nb). `at(block, step)` is the operand's block along the
+# row: `_near`, the block the program owns (its q block in fwd and dq, its k
+# block in dkv), or the other side's block that the step walks to, clamped
+# into the row (`_far_k`, `_far_q`).
+
+
+def _near(block, step):
+    return block
+
+
+def _far_k(nb: int):
+    """Step `step` of q block `block` is k block block - (nb - 1) + step."""
+    return lambda block, step: jnp.maximum(block - (nb - 1) + step, 0)
+
+
+def _far_q(nblk: int):
+    """Step `step` of k block `block` is q block block + step."""
+    return lambda block, step: jnp.minimum(block + step, nblk - 1)
+
+
+def _band_block(heads: int, blk: int, width: int, at) -> pl.BlockSpec:
+    return pl.BlockSpec((heads, blk, width),
+                        lambda r, a, st: (r, at(a, st), 0))
+
+
+def _band_stat(heads: int, blk: int, at) -> pl.BlockSpec:
+    """A block of the heads' row statistics, (rows, 1, S) arrays."""
+    return pl.BlockSpec((heads, 1, blk), lambda r, a, st: (r, 0, at(a, st)))
+
+
+def _band_batch(present: bool, blk: int, lay: _Layout, at) -> pl.BlockSpec:
+    return _per_batch_spec(
+        present, blk, lambda r, a, st: (lay.batch(r), 0, at(a, st)))
+
+
+def _band_fwd_call(seed_arr, qx, kx, vx, bias2, seg2, *, lay, nb, blk, kvh,
+                   dv, interpret, has_bias, has_segments, window, **kw):
+    """The banded forward: `_fwd_band_kernel` over (rows, q blocks, nb)."""
+    hp, (_, s, d) = lay.heads_per_prog, qx.shape
+    nblk = s // blk
+    far = _far_k(nb)
+    rng_spec, rng = _block_ranges(seg2, blk, blk,
+                                  _tile_skip(has_segments, nblk * nblk))
+    scratch = _scratch((hp, blk, 1), (hp, blk, 1), (hp, blk, dv))
+    kernel = functools.partial(
+        _fwd_band_kernel, nb=nb, nblk=nblk, batch_of=lay.batch,
+        has_bias=has_bias, has_segments=has_segments, window=window, **kw)
+    return pl.pallas_call(
+        _program(kernel, 3, 2, None, bool(rng), len(scratch)),
+        grid=(lay.rows, nblk, nb),
+        in_specs=rng_spec + [
+            pl.BlockSpec((1,), lambda r, qi, st: (0,)),      # seed
+            _band_block(hp, blk, d, _near),
+            _band_block(kvh, blk, d, far),
+            _band_block(kvh, blk, dv, far),
+            _band_batch(has_bias, blk, lay, far),
+            _band_batch(has_segments, blk, lay, _near),
+            _band_batch(has_segments, blk, lay, far),
+        ],
+        out_specs=[_band_block(hp, blk, dv, _near),
+                   _band_stat(hp, blk, _near)],
+        out_shape=[jax.ShapeDtypeStruct(qx.shape[:2] + (dv,), qx.dtype),
+                   jax.ShapeDtypeStruct((qx.shape[0], 1, s), jnp.float32)],
+        scratch_shapes=scratch,
+        name=_kernel_name("flash_fwd", d, dv, window),
+        interpret=interpret,
+        **_band_params(s, hp * d),
+    )(*rng, seed_arr, qx, kx, vx, bias2, seg2, seg2)
+
+
+def _band_bwd_calls(seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx, *,
+                    lay, nb, blk, kvh, interpret, has_bias, has_segments,
+                    window, **kw):
+    """The banded backward: `_dq_band_kernel` over (rows, q blocks, nb) and
+    `_dkv_band_kernel` over (rows, k blocks, nb). -> dq, dk, dv."""
+    hp, (_, s, d), dv = lay.heads_per_prog, qx.shape, vx.shape[2]
+    nblk = s // blk
+    rng_spec, rng = _block_ranges(seg2, blk, blk,
+                                  _tile_skip(has_segments, nblk * nblk))
+    operands = (*rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,
+                gx)
+    kw = dict(kw, nb=nb, nblk=nblk, batch_of=lay.batch, has_bias=has_bias,
+              has_segments=has_segments, window=window)
+    common = dict(grid=(lay.rows, nblk, nb), interpret=interpret,
+                  **_band_params(s, hp * d))
+    seed_spec = pl.BlockSpec((1,), lambda r, a, st: (0,))
+
+    def specs(q_at, k_at):
+        """The ten operands after the ranges, q-side blocks at `q_at` and
+        k-side blocks at `k_at`."""
+        return rng_spec + [
+            seed_spec,
+            _band_block(hp, blk, d, q_at),
+            _band_block(kvh, blk, d, k_at),
+            _band_block(kvh, blk, dv, k_at),
+            _band_batch(has_bias, blk, lay, k_at),
+            _band_batch(has_segments, blk, lay, q_at),
+            _band_batch(has_segments, blk, lay, k_at),
+            _band_stat(hp, blk, q_at), _band_stat(hp, blk, q_at),
+            _band_block(hp, blk, dv, q_at),
+        ]
+
+    dq = pl.pallas_call(
+        _program(functools.partial(_dq_band_kernel, **kw), 3, 1, None,
+                 bool(rng), 1),
+        in_specs=specs(_near, _far_k(nb)),
+        out_specs=_band_block(hp, blk, d, _near),
+        out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
+        scratch_shapes=_scratch((hp, blk, d)),
+        name=_kernel_name("flash_bwd_dq", d, dv, window),
+        **common,
+    )(*operands)
+    dk, dvx = pl.pallas_call(
+        _program(functools.partial(_dkv_band_kernel, **kw), 3, 2, None,
+                 bool(rng), 2),
+        in_specs=specs(_far_q(nblk), _near),
+        out_specs=[_band_block(kvh, blk, d, _near),
+                   _band_block(kvh, blk, dv, _near)],
+        out_shape=[jax.ShapeDtypeStruct(kx.shape, kx.dtype),
+                   jax.ShapeDtypeStruct(vx.shape, vx.dtype)],
+        scratch_shapes=_scratch((kvh, blk, d), (kvh, blk, dv)),
+        name=_kernel_name("flash_bwd_dkv", d, dv, window),
+        **common,
+    )(*operands)
+    return dq, dk, dvx
+
+
 def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
                causal=False, window=0):
     b, s, h, d = q.shape
@@ -1165,6 +1526,16 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     qx, kx, vx = lay.pack(q), lay.pack(k), lay.pack(v)
 
     tiles = (s // blk_q) * (s // blk_k)
+    nb = _band_steps(s, blk_q, blk_k, window)
+    if nb:
+        out, lse = _band_fwd_call(
+            _seed_operand(seed), qx, kx, vx, bias2, seg2, lay=lay, nb=nb,
+            blk=blk_q, kvh=kvh, dv=dv, interpret=interpret, scale=scale,
+            rate=rate, has_bias=has_bias, has_segments=has_segments,
+            window=int(window))
+        out = checkpoint_name(out, "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
+        return lay.unpack(out, b, s, dv), (qx, kx, vx, bias2, seg2, lse, out)
     skip_rows = _skip_pad_rows(has_segments, tiles)
     live_spec, live = _live_rows(seg2, skip_rows)
     kw = dict(scale=scale, blk_k=blk_k, rate=rate, has_bias=has_bias,
@@ -1291,6 +1662,12 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
             interpret=interpret,
         )(*live, seed_arr, qx, kx, vx, bias2, seg2,
           lse.reshape(stat_shape), delta.reshape(stat_shape), gx)
+    elif nb := _band_steps(s, blk_q, blk_k, window):
+        dq, dk, dvx = _band_bwd_calls(
+            seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx, lay=lay,
+            nb=nb, blk=blk_q, kvh=lay.heads_per_prog * hkv // h,
+            interpret=interpret, scale=scale, rate=rate, has_bias=has_bias,
+            has_segments=has_segments, window=int(window))
     else:
         # split kernels, bh layout only (_use_native excludes these shapes;
         # grouped heads and values of a width of their own take them at any

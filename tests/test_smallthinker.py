@@ -248,7 +248,9 @@ def test_a_tile_pair_wholly_behind_the_band_is_skipped(monkeypatch):
     pair. And in the kernel: values that are NaN in the key blocks wholly
     behind the last query block's band would poison its rows through
     0 x NaN if those tiles ran masked; skipped, its rows are clean and
-    equal the XLA path's."""
+    equal the XLA path's. (A band of 100 over four blocks of 128 spans two
+    of them: the call takes the banded form, whose grid never reaches the
+    blocks behind the band; tests/test_flash_band.py poisons every side.)"""
     monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
     fa = importlib.import_module(
         "bert_pytorch_tpu.ops.pallas.flash_attention")

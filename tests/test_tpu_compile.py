@@ -120,35 +120,57 @@ def test_flash_split_backward_compiles(v5e, bias, segments,
                        grad=True) == SPLIT
 
 
-@pytest.mark.parametrize("cell,b,s,h,hkv,d,dv,heads,window", [
+_MIB = 1024 * 1024
+
+
+def _asked_vmem(text: str) -> list:
+    """Bytes of scoped VMEM each Mosaic call of a compiled executable's text
+    asked of the compiler (its `vmem_limit_bytes`; the default is 16 MiB)."""
+    import re
+
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    return [int(re.search(r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                          r'"offset":"\d+","size":"(\d+)"', ln).group(1))
+            for ln in calls]
+
+
+@pytest.mark.parametrize("cell,b,s,h,hkv,d,dv,heads,window,band,asked", [
     # lfm2-ep8-clm-8k-packed: a program owns a key/value head's four query
     # heads
-    ("lfm2", 4, 8192, 32, 8, 64, 64, 4, 0),
+    ("lfm2", 4, 8192, 32, 8, 64, 64, 4, 0, 0, 64),
     # kimi-linear-ep32-clm-16k-packed: four heads' K and V panels, 48 MiB in
     # one buffer each
-    ("kimi", 1, 16384, 32, 32, 192, 128, 4, 0),
+    ("kimi", 1, 16384, 32, 32, 192, 128, 4, 0, 0, 64),
     # smallthinker-ep8-clm-16k-fullrow: a program owns the SEVEN query heads
-    # of a key/value head of 128; the dkv kernel's Q and dO panels are
-    # 56 MiB, so the calls ask 72 MiB (`_long_seq_params`); its full layers
-    # and its layers banded at 4,096
-    ("smallthinker-full", 1, 16384, 28, 4, 128, 128, 7, 0),
-    ("smallthinker-band", 1, 16384, 28, 4, 128, 128, 7, 4096),
+    # of a key/value head of 128. Its full layers walk whole panels: the dkv
+    # kernel's Q and dO panels are 56 MiB, so the calls ask 72 MiB
+    # (`_long_seq_params`). Its layers banded at 4,096 walk the NINE blocks
+    # of the band as a grid axis and hold blocks, no panel: 16 MiB asked
+    # (`_band_params`)
+    ("smallthinker-full", 1, 16384, 28, 4, 128, 128, 7, 0, 0, 72),
+    ("smallthinker-band", 1, 16384, 28, 4, 128, 128, 7, 4096, 9, 16),
     # laguna-ep8-clm-16k-packed: a windowed layer's program owns the EIGHT
     # query heads of a key/value head of 128 under a band of 512, one tile
-    # wide (the dkv call's Q and dO panels are 64 MiB: 80 MiB asked of a
-    # core's 128); a full layer's owns six
-    ("laguna-band", 1, 16384, 64, 8, 128, 128, 8, 512),
-    ("laguna-full", 1, 16384, 48, 8, 128, 128, 6, 0),
+    # wide: TWO blocks a band, 16 MiB asked where whole panels asked 80; a
+    # full layer's owns six
+    ("laguna-band", 1, 16384, 64, 8, 128, 128, 8, 512, 2, 16),
+    ("laguna-full", 1, 16384, 48, 8, 128, 128, 6, 0, 0, 64),
 ])
 def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
-                                                   d, dv, heads, window):
+                                                   d, dv, heads, window,
+                                                   band, asked):
     """The causal split kernels at the decoder cells' shapes, at the heads a
-    program that `_layout` picks, inside the VMEM the calls ask for
-    (`_long_seq_params`: the compiler refuses a kernel that needs more):
-    dynamic head indices into the blocks, the rolled loop over the heads
-    and the scratch accumulators are what interpret mode cannot refuse."""
+    program that `_layout` picks, in the form `_band_steps` picks (`band`
+    steps of a grid axis over the band's blocks, or 0: the panel-walking
+    programs), inside the VMEM the calls ask for (`asked` MiB: the compiler
+    refuses a kernel that needs more): dynamic head indices into the blocks,
+    the rolled loop over the heads, the scratch accumulators carried from
+    grid step to grid step and the clamped block indices are what interpret
+    mode cannot refuse."""
     assert fa._layout(b, s, h, d, h // hkv, dv,
                       window).heads_per_prog == heads
+    assert fa._band_steps(s, 512, 512, window) == band
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
 
     def bwd(q, k, v, seg):
@@ -157,10 +179,12 @@ def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
             .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     name = lambda n: fa._kernel_name(n, d, dv, window)  # noqa: E731
-    assert _kernels(bwd, sds((b, s, h, d), jnp.bfloat16),
-                    sds((b, s, hkv, d), jnp.bfloat16),
-                    sds((b, s, hkv, dv), jnp.bfloat16),
-                    sds((b, s), jnp.int32)) == {name(n): 1 for n in SPLIT}
+    text = jax.jit(bwd).lower(
+        sds((b, s, h, d), jnp.bfloat16), sds((b, s, hkv, d), jnp.bfloat16),
+        sds((b, s, hkv, dv), jnp.bfloat16),
+        sds((b, s), jnp.int32)).compile().as_text()
+    assert hlo.kernel_counts(text) == {name(n): 1 for n in SPLIT}
+    assert _asked_vmem(text) == [asked * _MIB] * 3
 
 
 def test_kda_scan_kernels_compile_at_the_kimi_cell(v5e, monkeypatch):

@@ -13,6 +13,12 @@ f32 at highest matmul precision:
   select (both layouts; fused and split backward), with a padding bias,
   with packed segment ids, and with dropout (against a mirror that applies
   the IDENTICAL counter-hash keep mask, as tests/test_pallas.py does);
+- the banded causal kernels (`flash_win_fwd`, `flash_win_bwd_dq`,
+  `flash_win_bwd_dkv`) at the two cells' shapes, rows of 16,384 and heads of
+  128: 64 query heads over 8 under a band of 512 on packed rows (laguna),
+  28 over 4 under a band of 4,096 on full rows (smallthinker), forward and
+  the three gradients against float32 attention taken a block of queries
+  at a time over the keys its band reaches (`--band_seq`; 0 leaves it out);
 - LayerNorm and fused residual+dropout+LayerNorm, forward + backward
   (the XLA fallbacks in ops/layernorm.py share the kernels' dropout hash);
 - both fused-LAMB stages against their one-definition math;
@@ -45,6 +51,10 @@ RATE = 0.1
 # matmul, so kernel-vs-f32-reference errors sit near 1e-2 of the output
 # scale; a wrong head slice or mask is O(1)
 FWD_TOL, BWD_TOL = 3e-2, 5e-2
+# the banded check's cells: (name, query heads, key/value heads, band, packed)
+BAND_S, BAND_D = 16384, 128
+BAND_CASES = [("laguna", 64, 8, 512, True),
+              ("smallthinker", 28, 4, 4096, False)]
 # the KDA check's row is KDA_ROW x S tokens of 2 H heads of KDA_D: at the
 # defaults 4,096 tokens (two blocks of 32 chunks) of 32 heads of 128
 KDA_ROW, KDA_D, KDA_CHUNK, KDA_BLOCK = 8, 128, 64, 32
@@ -152,6 +162,92 @@ def check_flash(fa, interpret: bool, report) -> None:
     finally:
         fa._heads_per_prog = heads_per_prog
         fa._FUSED_BWD_MAX_PANEL = max_panel
+
+
+def _band_reference(q, k, v, seg, window: int, chunk: int):
+    """Float32 causal attention under a band of `window` positions, `chunk`
+    queries at a time against the `chunk` keys of their own block and the
+    blocks before it that the band reaches (the (S, S) scores of a row of
+    16,384 are 1 GiB a head); each block rematerialised in the backward
+    pass. seg: (B, S) packing segments or None."""
+    import jax
+    import jax.numpy as jnp
+
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    back = min(-(-(window - 1) // chunk) * chunk, s)
+    q = q.astype(jnp.float32).reshape(b, s // chunk, chunk, hkv, h // hkv, d)
+    front = lambda x, fill: jnp.pad(  # noqa: E731
+        x, [(0, 0), (back, 0)] + [(0, 0)] * (x.ndim - 2),
+        constant_values=fill)
+    k, v = (front(x.astype(jnp.float32), 0.0) for x in (k, v))
+    seg = jnp.ones((b, s), jnp.int32) if seg is None else seg
+    segk = front(seg, -1)
+    dist = (back + jnp.arange(chunk))[:, None] - jnp.arange(back + chunk)
+
+    @jax.checkpoint
+    def block(i):
+        at = i * chunk
+        kb = jax.lax.dynamic_slice_in_dim(k, at, back + chunk, 1)
+        vb = jax.lax.dynamic_slice_in_dim(v, at, back + chunk, 1)
+        sk = jax.lax.dynamic_slice_in_dim(segk, at, back + chunk, 1)
+        sq = jax.lax.dynamic_slice_in_dim(seg, at, chunk, 1)
+        sc = jnp.einsum("bqngd,bknd->bngqk", q[:, i], kb) / jnp.sqrt(d)
+        ok = ((dist >= 0) & (dist < window))[None] \
+            & (sq[:, :, None] == sk[:, None, :]) & (sq[:, :, None] > 0)
+        sc = jnp.where(ok[:, None, None], sc, -1e30)
+        p = jnp.where(ok[:, None, None], jax.nn.softmax(sc, axis=-1), 0.0)
+        return jnp.einsum("bngqk,bknd->bqngd", p, vb)
+
+    out = jax.lax.map(block, jnp.arange(s // chunk))    # (n, B, chunk, ...)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
+
+
+def check_flash_band(fa, interpret: bool, report, s: int) -> None:
+    """The banded kernels at the cells' head shapes over one row of `s`."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.RandomState(4)
+    seg_np = np.zeros((1, s), np.int32)     # lognormal documents, a pad tail
+    at, doc = 0, 1
+    while at < s - s // 64:
+        n = int(min(s - s // 64 - at, max(8, rng.lognormal(6.5, 1.3))))
+        seg_np[0, at:at + n] = doc
+        at, doc = at + n, doc + 1
+    for name, h, hkv, window, packed in BAND_CASES:
+        blk = fa._pick_block(s, fa.DEFAULT_BLK_Q)
+        nb = fa._band_steps(s, blk, fa._pick_block(s, fa.DEFAULT_BLK_K),
+                            window)
+        q, k, v, w = (jnp.asarray(rng.randn(1, s, n, BAND_D) * 0.5,
+                                  jnp.bfloat16) for n in (h, hkv, hkv, h))
+        seg = jnp.asarray(seg_np) if packed else None
+        valid = (jnp.asarray(seg_np > 0)[:, :, None, None] if packed
+                 else jnp.ones((1, s, 1, 1), bool))
+
+        def kernel(q, k, v):
+            return fa.flash_attention(q, k, v, None, seg, None, 0.0,
+                                      interpret, True, window)
+
+        def ref(q, k, v):
+            return _band_reference(q, k, v, seg, window, blk)
+
+        def both(fn):
+            def loss(q, k, v):
+                out = jnp.where(valid, fn(q, k, v).astype(jnp.float32), 0)
+                return jnp.sum(out * w.astype(jnp.float32)), out
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                              has_aux=True))
+
+        (_, got), got_g = both(kernel)(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            (_, want), want_g = both(ref)(q, k, v)
+        tag = (f"flash_win[{name}: {h}/{hkv} heads, band {window}, "
+               f"{nb or 'panel'} steps]")
+        report(f"{tag} fwd", _rel_err(got, want), FWD_TOL)
+        for which, g, wg in zip("qkv", got_g, want_g):
+            report(f"{tag} d{which}", _rel_err(g, wg), BWD_TOL)
 
 
 def check_layernorm(interpret: bool, report) -> None:
@@ -284,6 +380,10 @@ def main(argv=None) -> int:
                          "of the segment cases meaningful; smaller is for "
                          "interpret-mode rehearsals")
     ap.add_argument("--heads", type=int, default=H, help="of width 64")
+    ap.add_argument("--band_seq", type=int, default=BAND_S,
+                    help="row of the banded kernels' check (0: leave it "
+                         "out; a few blocks for an interpret-mode "
+                         "rehearsal)")
     args = ap.parse_args(argv)
     B, S, H = args.batch, args.seq, args.heads
 
@@ -308,6 +408,8 @@ def main(argv=None) -> int:
             failures.append(name)
 
     check_flash(fa, interpret, report)
+    if args.band_seq:
+        check_flash_band(fa, interpret, report, args.band_seq)
     check_layernorm(interpret, report)
     check_fused_lamb(report)
     check_kda(interpret, report)
