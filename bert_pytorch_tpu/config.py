@@ -797,10 +797,182 @@ class LagunaConfig:
             self.mlp_layer_types]))
 
 
+@dataclasses.dataclass(frozen=True)
+class KeyeConfig:
+    """Architecture config of the `keye` family (the language model of
+    Kwai Keye-VL-2.0): a pre-norm decoder whose every layer is causal
+    grouped-query attention over the keys a learned index SELECTS (`sa_*`:
+    an indexer of `sa_indexer_num_heads` heads on one key head scores every
+    earlier token of the document, and a query attends to its `sa_topk`
+    best), with per-head RMS norms on q and k and rotary positions over the
+    whole head, then softmax-routed SwiGLU experts; an untied head
+    (models/keye.py has the equations). The vision tower is not part of
+    this program.
+
+    Keys are the source's (`config.json` of the model); its two nested
+    groups are read into flat fields (`sa_config.topk` -> `sa_topk`,
+    `rope_scaling.mrope_section` -> `mrope_section`). A run may hold one
+    expert-parallel rank's share, as Lfm2MoeConfig's: `num_experts` (and
+    `num_local_experts`) experts, the range `experts_held` of
+    `experts_total` (the router keeps that width) and `vocab_size` rows of
+    the vocabulary.
+    """
+
+    model_type: str = "keye"
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    intermediate_size: int = 6144       # a dense layer's; the source has none
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    max_position_embeddings: int = 262144
+    max_window_layers: int = 48         # read with use_sliding_window only
+    attention_bias: bool = False
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-6
+    decoder_sparse_step: int = 1
+    mlp_only_layers: Tuple[int, ...] = ()
+    moe_intermediate_size: int = 768
+    norm_topk_prob: bool = True
+    num_experts: int = 128
+    num_local_experts: int = 128
+    num_experts_per_tok: int = 8
+    rope_theta: float = 10000000.0
+    mrope_section: Tuple[int, ...] = (16, 24, 24)
+    rope_type: str = "default"
+    sa_indexer_head_dim: int = 64
+    sa_indexer_num_heads: int = 16
+    sa_indexer_num_kv_heads: int = 1
+    sa_q_chunk_size: int = 512
+    sa_kv_chunk_size: int = 512
+    sa_topk: int = 2048
+    sliding_window: Optional[int] = None
+    use_sliding_window: bool = False
+    tie_word_embeddings: bool = False
+    experts_total: Optional[int] = None
+    experts_held: Optional[Tuple[int, int]] = None
+    initializer_range: float = 0.02
+    model_name: Optional[str] = None
+    # run settings, as BertConfig's
+    dtype: str = "bfloat16"
+    checkpoint_activations: bool = False
+    remat_policy: str = "auto"
+    attention_impl: str = "auto"
+
+    # keys of the configuration's file that carry no size of this program's
+    _IGNORED = ("vocab_rows_total", "vocab_rows_held", "published",
+                "rope_scaling", "sa_config")
+    _SA_KEYS = ("indexer_head_dim", "indexer_num_heads",
+                "indexer_num_kv_heads", "q_chunk_size", "kv_chunk_size",
+                "topk")
+    _ROPE_KEYS = ("mrope_section", "rope_type", "type")
+    _SA_CHUNK = 512     # DEFAULT_BLK_Q and DEFAULT_BLK_K of the kernels
+
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any]) -> "KeyeConfig":
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = [k for k in d if k not in known
+                   and k not in DOCUMENTED_DATA_KEYS
+                   and k not in cls._IGNORED]
+        kw = {k: v for k, v in d.items() if k in known}
+        for key, value in (d.get("sa_config") or {}).items():
+            if key in cls._SA_KEYS:
+                kw[f"sa_{key}"] = value
+            else:
+                unknown.append(f"sa_config.{key}")
+        rope = d.get("rope_scaling") or {}
+        unknown += [f"rope_scaling.{k}" for k in rope
+                    if k not in cls._ROPE_KEYS]
+        if "mrope_section" in rope:
+            kw["mrope_section"] = rope["mrope_section"]
+        for key in ("rope_type", "type"):
+            if key in rope:
+                kw["rope_type"] = rope[key]
+        if unknown:
+            raise ValueError(
+                f"keye model config: unknown key(s) {sorted(unknown)}")
+        for key in ("mlp_only_layers", "mrope_section", "experts_held"):
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        cfg = cls(**kw)
+        cfg.check()     # raises on what the program is not written for
+        return cfg
+
+    @classmethod
+    def from_json_file(cls, path: str) -> "KeyeConfig":
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_dict(json.load(f))
+
+    def to_dict(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    def replace(self, **kw: Any) -> "KeyeConfig":
+        return dataclasses.replace(self, **kw)
+
+    # the names models/lfm2_moe.py's shared modules read
+    norm_eps = property(lambda self: self.rms_norm_eps)
+    use_expert_bias = property(lambda self: False)
+    routed_scaling_factor = property(lambda self: 1.0)
+    router_scores = property(lambda self: "softmax")
+    expert_activation = property(lambda self: "silu")
+
+    @property
+    def router_width(self) -> int:
+        return int(self.experts_total or self.num_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of "
+                f"num_experts={self.num_experts} out of {self.router_width}")
+        return int(lo), int(hi)
+
+    def check(self) -> None:
+        """Raises where the config asks for what models/keye.py is not
+        written for, or is cut inconsistently."""
+        unsupported = [
+            name for name, bad in (
+                ("attention_bias", self.attention_bias),
+                ("hidden_act other than silu", self.hidden_act != "silu"),
+                ("decoder_sparse_step != 1", self.decoder_sparse_step != 1),
+                ("mlp_only_layers", bool(self.mlp_only_layers)),
+                ("norm_topk_prob=false", not self.norm_topk_prob),
+                ("use_sliding_window", self.use_sliding_window
+                 or self.sliding_window is not None),
+                ("tie_word_embeddings", self.tie_word_embeddings),
+                ("rope_type other than default", self.rope_type != "default"),
+                ("an mrope_section that does not add up to head_dim / 2",
+                 sum(self.mrope_section) * 2 != self.head_dim),
+                ("sa_config.indexer_num_kv_heads != 1",
+                 self.sa_indexer_num_kv_heads != 1),
+                ("sa_config.topk < 1", self.sa_topk < 1),
+                # the index scores are tiled as the flash kernels' blocks
+                # are (ops/pallas/flash_attention.select_blocks: 512 by
+                # 512; a shorter row is one smaller tile), which is the
+                # form the packed selection has
+                (f"sa_config.q_chunk_size / kv_chunk_size other than "
+                 f"{self._SA_CHUNK}",
+                 (self.sa_q_chunk_size, self.sa_kv_chunk_size)
+                 != (self._SA_CHUNK, self._SA_CHUNK)),
+                ("num_local_experts != num_experts",
+                 self.num_local_experts != self.num_experts),
+                ("num_attention_heads not a multiple of num_key_value_heads",
+                 self.num_attention_heads % self.num_key_value_heads != 0),
+            ) if bad]
+        if unsupported:
+            raise NotImplementedError(
+                f"keye: not written for {unsupported} (the source model "
+                "uses none of them)")
+        self.held_range
+
+
 MODEL_FAMILIES = {"bert": BertConfig, "lfm2_moe": Lfm2MoeConfig,
                   "kimi_linear": KimiLinearConfig,
                   "smallthinker": SmallThinkerConfig,
-                  "laguna": LagunaConfig}
+                  "laguna": LagunaConfig, "keye": KeyeConfig}
 
 
 def load_model_config(path: str):

@@ -17,7 +17,7 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import MODEL_FAMILIES
-from bert_pytorch_tpu.models import (kimi_linear, laguna, lfm2_moe,
+from bert_pytorch_tpu.models import (keye, kimi_linear, laguna, lfm2_moe,
                                      smallthinker)
 from bert_pytorch_tpu.models.bert import BertForPreTraining
 from bert_pytorch_tpu.telemetry.expert_load import ExpertLoadCounters
@@ -101,6 +101,7 @@ FAMILIES = {
     "smallthinker": _decoder_family(smallthinker,
                                     smallthinker.SmallThinkerForCausalLM),
     "laguna": _decoder_family(laguna, laguna.LagunaForCausalLM),
+    "keye": _decoder_family(keye, keye.KeyeForCausalLM),
 }
 
 

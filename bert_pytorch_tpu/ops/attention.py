@@ -236,6 +236,7 @@ def dot_product_attention(
     hash_dropout_impl: bool = True,
     causal: bool = False,
     window: Optional[int] = None,
+    select=None,
 ) -> jax.Array:
     """Returns (B, Sq, H, D) in q.dtype.
 
@@ -247,6 +248,11 @@ def dot_product_attention(
     `window` (with `causal` only; None: no band): a query attends to the
     last `window` positions, its own among them: 0 <= q_pos - k_pos <
     window, counted inside the query's own segment.
+    `select` (with `causal` only; None: every allowed key): the keys each
+    query token attends to, one selection for all of its heads, as
+    ops/sparse_index.py packs it (`by_q`, `by_k`: the flash kernels' form,
+    ops/pallas/flash_attention.select_blocks); a selected key that the
+    causal or the segment condition excludes stays excluded.
 
     impl="auto" resolves by sequence length: measured on v5e, the plain XLA
     path (bf16 probs, fp32 softmax stats) beats the blockwise Pallas kernel
@@ -274,6 +280,12 @@ def dot_product_attention(
         raise ValueError(
             f"attention window={window!r} needs causal=True and a width of "
             "at least 1 (the bidirectional paths have no band)")
+    if select is not None and (not causal or window is not None
+                               or bias is not None
+                               or not (deterministic or dropout_rate == 0.0)):
+        raise ValueError(
+            "attention select= needs causal=True and takes no window, bias "
+            "or dropout")
     if impl == "auto":
         impl = "pallas" if seq > 256 else "xla"
     interpret = jax.default_backend() != "tpu" and _pallas_interpret()
@@ -321,6 +333,12 @@ def dot_product_attention(
                 seed = jax.random.randint(dropout_rng, (), 0, 2 ** 31 - 1,
                                           dtype=jnp.int32)
             mesh = active_mesh()
+            if mesh is None and select is not None:
+                from bert_pytorch_tpu.ops.pallas.flash_attention import (
+                    flash_select_attention)
+
+                return flash_select_attention(q, k, v, segment_ids, *select,
+                                              interpret)
             if mesh is None:
                 return flash_attention(q, k, v, bias=bias,
                                        segment_ids=segment_ids,
@@ -349,7 +367,19 @@ def dot_product_attention(
 
     return _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                           dropout_rate, deterministic, hash_dropout_impl,
-                          causal, window)
+                          causal, window, select)
+
+
+def unpack_select(by_q: jax.Array) -> jax.Array:
+    """(B, S, S) bools from a selection packed by q block (B, W, S, blk):
+    query q selects key j * blk + c where bit j % 32 of word [j // 32, q, c]
+    is set (ops/pallas/flash_attention.py, at `_select_tile`). The XLA
+    path's dense mirror of the kernels' operand."""
+    b, planes, s, blk = by_q.shape
+    j = jnp.arange(s // blk)
+    words = by_q[:, j // 32]                               # (B, nk, S, blk)
+    bits = (words >> (j % 32)[None, :, None, None]) & 1
+    return (bits != 0).transpose(0, 2, 1, 3).reshape(b, s, s)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -392,7 +422,7 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                    dropout_rate: float, deterministic: bool,
                    hash_dropout_impl: bool = True,
                    causal: bool = False,
-                   window: Optional[int] = None) -> jax.Array:
+                   window: Optional[int] = None, select=None) -> jax.Array:
     if k.shape[2] != q.shape[2]:    # grouped heads: one copy per query head
         group = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, group, axis=2)
@@ -413,6 +443,9 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
         if window is not None:
             allowed &= rows - cols < window
         scores = jnp.where(allowed, scores, SEGMENT_MASK_BIAS)
+    if select is not None:
+        scores = jnp.where(unpack_select(select[0])[:, None], scores,
+                           SEGMENT_MASK_BIAS)
     # softmax statistics in fp32; the probabilities are cast to the compute
     # dtype BEFORE dropout so the (B, H, S, S) tensors XLA saves for the
     # backward pass (probs + dropped probs) are bf16 — this halves attention

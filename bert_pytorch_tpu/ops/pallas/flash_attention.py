@@ -415,20 +415,48 @@ def _causal_pos(causal: bool, q0, k0, bq: int, bk: int):
     return _rows(jnp.int32, bq), _cols(jnp.int32, bk) + (k0 - q0)
 
 
-def _mask(s, rows, cols, segq, segk, window: int = 0):
+def _mask(s, rows, cols, segq, segk, window: int = 0, sel=None):
     """Scores with the disallowed pairs at NEG_INF: other segments and pad
     (packed rows: segq (bq, 1), segk (1, bk) from `_seg_keys`, allowed
     where equal; None otherwise), later positions (causal: `_causal_pos`;
-    None otherwise), and positions `window` or more back (a band; 0: none).
-    One compare per condition on the tile; everything else is the
-    vectors'."""
+    None otherwise), positions `window` or more back (a band; 0: none), and
+    pairs a selection leaves out (`sel`: the tile's (bq, bk) bools from
+    `_select_tile`; None: no selection). One compare per condition on the
+    tile; everything else is the vectors'."""
     allowed = None if segq is None else segq == segk
     if rows is not None:
         tri = rows >= cols
         if window:
             tri = tri & (rows < cols + window)
         allowed = tri if allowed is None else allowed & tri
+    if sel is not None:
+        allowed = sel if allowed is None else allowed & sel
     return s if allowed is None else jnp.where(allowed, s, NEG_INF)
+
+
+# A selection (`flash_select_attention`): which keys each query attends to,
+# a (query, key) MATRIX that differs by row, so no pair of vectors carries
+# it. It comes bit-packed BY BLOCK: word [q, c] of `by_q` (B, W, S, blk_k)
+# int32 holds, at bit j % 32 of word plane j // 32, whether query q selects
+# key j * blk_k + c. A q block's (W, blk_q, blk_k) words are then ONE block
+# that serves every k block of the row: a program fetches 1 MiB once (S =
+# 16,384: 32 k blocks, W = 1) where an int8 matrix would stream 8 MiB, and a
+# tile's bools are one AND and one compare on words that already lie as the
+# scores do, no relayout (a mask packed along the keys or the queries would
+# have to be spread over lanes or sublanes first). 32 MiB a layer at
+# S = 16,384 against 256 MiB as int8. The dkv kernel walks q blocks, so it
+# reads the transposed packing `by_k` (B, W, blk_q, S): bit i % 32 of plane
+# i // 32 of word [r, k] says whether query i * blk_q + r selects key k.
+SELECT_WORD = 32
+
+
+def _select_tile(sel_ref, n):
+    """The (blk_q, blk_k) bools of a tile from a program's block of packed
+    words: bit n % 32 of plane n // 32 (`n`: the k block in the forward and
+    dq kernels, the q block in the dkv kernel; static)."""
+    bit = n % SELECT_WORD
+    mask = -(1 << 31) if bit == 31 else 1 << bit
+    return (sel_ref[0, n // SELECT_WORD] & jnp.int32(mask)) != 0
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -495,7 +523,7 @@ def _keep_mask(seed, bh, q0, k0, bq, bk, rate: float):
 
 
 def _fwd_tile(carry, q, kb, vb, tile_scale, bias, pos, segq, segk, keep,
-              rate: float, window: int = 0):
+              rate: float, window: int = 0, sel=None):
     """One (blk_q, blk_k) tile of the online softmax: (m, l, acc) with the
     keys kb and values vb taken in. `bias` (None, or the pad bias's
     (1, blk_k) row), `pos` (`_causal_pos`) and `keep` (the dropout keep
@@ -512,7 +540,7 @@ def _fwd_tile(carry, q, kb, vb, tile_scale, bias, pos, segq, segk, keep,
         s = s * tile_scale
     if bias is not None:
         s = s + bias()
-    s = _mask(s, *pos(), segq, segk, window)
+    s = _mask(s, *pos(), segq, segk, window, sel)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
@@ -610,6 +638,23 @@ def _fwd_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
 # rolled (`_each_head`), with the heads' running results in VMEM scratch.
 
 
+def _sel_kw(sel_ref, n) -> dict:
+    """A head body's `sel` keyword for block `n` of the side its kernel
+    walks: the tile's selected pairs as a thunk; nothing without a
+    selection."""
+    if sel_ref is None:
+        return {}
+    return {"sel": lambda: _select_tile(sel_ref, n)}
+
+
+def _with_select(kernel):
+    """`kernel` with the packed selection as its first operand (after what
+    `_program` takes off)."""
+    def selecting(ids, sel_ref, *refs, **kw):
+        return kernel(ids, *refs, sel_ref=sel_ref, **kw)
+    return selecting
+
+
 def _kv_at(kv_ref, t):
     """Where head t's panel is in a block of key/value panels."""
     return t if kv_ref.shape[0] > 1 else 0
@@ -624,7 +669,7 @@ def _fwd_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, acc_ref,
     into the head's running (m, l, acc)."""
     hp, bq, _ = q_ref.shape
 
-    def head(t, cols, k0, segk):
+    def head(t, cols, k0, segk, sel=None):
         q, tile_scale = _scale_operand(q_ref[t], scale)
         m_ref[t], l_ref[t], acc_ref[t] = _fwd_tile(
             (m_ref[t], l_ref[t], acc_ref[t]), q,
@@ -636,7 +681,7 @@ def _fwd_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, acc_ref,
             lambda: _keep_tile(
                 keep_rows, _keep_cols(seed_ref[0], row * hp + t, k0, bk),
                 rate),
-            rate, window)
+            rate, window, None if sel is None else sel())
 
     return head
 
@@ -661,8 +706,9 @@ def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                    segk_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                    scale: float, blk_k: int, rate: float, has_bias: bool,
                    has_segments: bool, batch_of, causal: bool = False,
-                   window: int = 0, ranges_ref=None):
-    """One program per (grid row, q-block): the forward of its heads."""
+                   window: int = 0, ranges_ref=None, sel_ref=None):
+    """One program per (grid row, q-block): the forward of its heads.
+    `sel_ref`: the q block's packed selection (`_select_tile`), or None."""
     row, _, qi = ids
     hp, bq, _ = q_ref.shape
     s_len = k_ref.shape[1]
@@ -685,7 +731,7 @@ def _fwd_bh_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
         segk = (_seg_keys(segk_ref[0, 0, cols])[None, :]
                 if has_segments else None)
         _each_head(hp, functools.partial(head, cols=cols, k0=j * blk_k,
-                                         segk=segk),
+                                         segk=segk, **_sel_kw(sel_ref, j)),
                    qrange, _block_range(ranges_ref, batch, s_len // bq + j),
                    _causal_live(causal, q0, bq, j * blk_k, blk_k, window))
 
@@ -712,7 +758,7 @@ def _dq_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, lse_ref, delta_ref,
     hp, bq, _ = q_ref.shape
     out_scale = _dropout_late(scale, rate)
 
-    def head(t, cols, k0, segk):
+    def head(t, cols, k0, segk, sel=None):
         q, tile_scale = _scale_operand(q_ref[t], scale)
         kb = k_ref[_kv_at(k_ref, t), cols, :]
         vb = v_ref[_kv_at(v_ref, t), cols, :]
@@ -724,7 +770,7 @@ def _dq_heads(seed_ref, q_ref, k_ref, v_ref, bias_ref, lse_ref, delta_ref,
         if has_bias:
             s = s + bias_ref[0, 0, cols][None, :]
         s = _mask(s, *_causal_pos(causal, q0, k0, bq, bk), segq, segk,
-                  window)
+                  window, None if sel is None else sel())
         p = jnp.exp(s - lse_ref[t, 0][:, None])
         dp = jax.lax.dot_general(
             do_ref[t], vb, (((1,), (1,)), ((), ())),
@@ -746,7 +792,7 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                segk_ref, lse_ref, delta_ref, do_ref, dq_ref, acc_ref, *,
                scale: float, blk_k: int, rate: float, has_bias: bool,
                has_segments: bool, batch_of, causal: bool = False,
-               window: int = 0, ranges_ref=None):
+               window: int = 0, ranges_ref=None, sel_ref=None):
     """One program per (grid row, q-block): dq of its heads."""
     row, qi = ids
     hp, bq, _ = q_ref.shape
@@ -769,7 +815,7 @@ def _dq_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
         segk = (_seg_keys(segk_ref[0, 0, cols])[None, :]
                 if has_segments else None)
         _each_head(hp, functools.partial(head, cols=cols, k0=j * blk_k,
-                                         segk=segk),
+                                         segk=segk, **_sel_kw(sel_ref, j)),
                    qrange, _block_range(ranges_ref, batch, s_len // bq + j),
                    _causal_live(causal, q0, bq, j * blk_k, blk_k, window))
 
@@ -790,7 +836,7 @@ def _dkv_heads(seed_ref, q_ref, k_ref, v_ref, segq_ref, lse_ref, delta_ref,
     bk = k_ref.shape[1]
     out_scale = _dropout_late(scale, rate)
 
-    def head(t, rows, q0):
+    def head(t, rows, q0, sel=None):
         kv = _kv_at(k_ref, t)
         # the resident block takes the scale here: (q . k * scale); dk
         # needs the q blocks as they are
@@ -806,7 +852,7 @@ def _dkv_heads(seed_ref, q_ref, k_ref, v_ref, segq_ref, lse_ref, delta_ref,
         if bias is not None:
             s = s + bias
         s = _mask(s, *_causal_pos(causal, q0, k0, bq, bk), segq, segk,
-                  window)
+                  window, None if sel is None else sel())
         p = jnp.exp(s - lse_ref[t, 0, rows][:, None])
         if rate > 0.0:
             keep = _keep_tile(
@@ -844,9 +890,11 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
                 segk_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
                 dk_acc_ref, dv_acc_ref, *, scale: float, blk_q: int,
                 rate: float, has_bias: bool, has_segments: bool, batch_of,
-                causal: bool = False, window: int = 0, ranges_ref=None):
+                causal: bool = False, window: int = 0, ranges_ref=None,
+                sel_ref=None):
     """One program per (grid row, k-block): dk and dv of its heads'
-    key/value heads. The query heads of a group add into the float32
+    key/value heads (`sel_ref`: the k block's packed selection, transposed:
+    `_select_tile` by q block). The query heads of a group add into the float32
     accumulators of their one key/value head, and ONE block a key/value
     head is written, in the parameters' dtype."""
     row, kj = ids
@@ -870,7 +918,8 @@ def _dkv_kernel(ids, seed_ref, q_ref, k_ref, v_ref, bias_ref, segq_ref,
 
     for i in range(nq):
         rows = slice(i * blk_q, (i + 1) * blk_q)
-        _each_head(hp, functools.partial(head, rows=rows, q0=i * blk_q),
+        _each_head(hp, functools.partial(head, rows=rows, q0=i * blk_q,
+                                         **_sel_kw(sel_ref, i)),
                    _block_range(ranges_ref, batch, i), krange,
                    _causal_live(causal, i * blk_q, blk_q, k0, bk, window))
 
@@ -1280,13 +1329,14 @@ def _bh_heads_per_prog(s: int, h: int, d: int, dv: int, group: int) -> int:
 
 
 def _layout(b: int, s: int, h: int, d: int, group: int = 1,
-            dv: int = 0, window: int = 0) -> _Layout:
+            dv: int = 0, window: int = 0, select: bool = False) -> _Layout:
     """`group` query heads to a key/value head: grouped heads take the bh
     layout, where a program owns the group and its one key/value head; so do
-    values of another width `dv` than the keys' (latent attention) and a
-    band (`window`)."""
+    values of another width `dv` than the keys' (latent attention), a
+    band (`window`) and a selection (`select`)."""
     dv = dv or d
-    if group == 1 and dv == d and not window and _use_native(s, h, d):
+    if (group == 1 and dv == d and not window and not select
+            and _use_native(s, h, d)):
         hp = _heads_per_prog(h, d)
         return _Layout(True, h, b, h // hp, hp)
     hp = _bh_heads_per_prog(s, h, d, dv, group)
@@ -1343,13 +1393,17 @@ def flash_attention(q, k, v, bias=None, segment_ids=None, dropout_seed=None,
     return out
 
 
-def _kernel_name(name: str, d: int, dv: int, window: int = 0) -> str:
+def _kernel_name(name: str, d: int, dv: int, window: int = 0,
+                 select: bool = False) -> str:
     """The kernels at values of a width of their own (latent attention:
-    keys 192, values 128) and the banded kernels (`flash_win_fwd`, ...)
-    carry names of their own in the HLO and the device trace, so that what
-    reads `flash_fwd` never reads them."""
+    keys 192, values 128), the banded kernels (`flash_win_fwd`, ...) and
+    those under a selection (`flash_sel_fwd`, ...) carry names of their own
+    in the HLO and the device trace, so that what reads `flash_fwd` never
+    reads them."""
     if window:
         name = name.replace("flash_", "flash_win_", 1)
+    if select:
+        name = name.replace("flash_", "flash_sel_", 1)
     return name if d == dv else "mla_" + name
 
 
@@ -1502,8 +1556,37 @@ def _band_bwd_calls(seed_arr, qx, kx, vx, bias2, seg2, lse, delta, gx, *,
     return dq, dk, dvx
 
 
+def select_blocks(s: int) -> tuple:
+    """(blk_q, blk_k, planes of the q blocks' words, planes of the k
+    blocks'): the tiles a selection over rows of `s` positions is packed
+    for (the module's comment at `_select_tile`)."""
+    blk_q, blk_k = _pick_block(s, DEFAULT_BLK_Q), _pick_block(s, DEFAULT_BLK_K)
+    return (blk_q, blk_k, -(-(s // blk_k) // SELECT_WORD),
+            -(-(s // blk_q) // SELECT_WORD))
+
+
+def _select_operand(words, lay: _Layout, block: tuple, at) -> tuple:
+    """(in_specs, operands) of a packed selection: `words` (B, W, ., .)
+    int32, a program's block all W planes of the `block` (rows, columns)
+    that `at(block index)` places."""
+    planes = words.shape[1]
+    return [pl.BlockSpec(
+        (1, planes) + block,
+        lambda r, *ids: (lay.batch(r), 0) + at(ids[-1]))], [words]
+
+
+def _check_select(select, b: int, s: int) -> None:
+    blk_q, blk_k, wk, wq = select_blocks(s)
+    want = ((b, wk, s, blk_k), (b, wq, blk_q, s))
+    got = tuple(tuple(x.shape) for x in select)
+    if got != want or any(x.dtype != jnp.int32 for x in select):
+        raise ValueError(
+            f"flash_select_attention: the selection packed by q block and "
+            f"by k block has to be int32 of shapes {want}, got {got}")
+
+
 def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
-               causal=False, window=0):
+               causal=False, window=0, select=None):
     b, s, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[3]
     if h % hkv or k.shape[:3] != v.shape[:3]:
@@ -1515,7 +1598,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_segments = segment_ids is not None
-    lay = _layout(b, s, h, d, h // hkv, dv, window)
+    lay = _layout(b, s, h, d, h // hkv, dv, window, select is not None)
     hp = lay.heads_per_prog
     kvh = hp * hkv // h     # key/value heads of a program's heads
     # shared by both layouts: the cross-layout bit-parity contract depends
@@ -1555,11 +1638,16 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
         kernel = functools.partial(_fwd_bh_kernel, batch_of=lay.batch, **kw)
         lse_bs = pl.BlockSpec((hp, 1, blk_q), lambda r, g, qi: (r, 0, qi))
         lse_shape = (b * h, 1, s)
+    sel_spec, sel = [], []
+    if select is not None:
+        sel_spec, sel = _select_operand(select[0], lay, (blk_q, blk_k),
+                                        lambda qi: (qi, 0))
+        kernel = _with_select(kernel)
     out, lse = pl.pallas_call(
         _program(kernel, 3, 2, lay.batch if skip_rows else None, bool(rng),
                  len(scratch)),
         grid=(lay.rows, lay.groups, s // blk_q),
-        in_specs=live_spec + rng_spec + [
+        in_specs=live_spec + rng_spec + sel_spec + [
             pl.BlockSpec((1,), lambda r, g, qi: (0,)),      # seed
             pl.BlockSpec(lay.block(blk_q, d), lambda r, g, qi: (r, qi, g)),
             _panel_spec(lay.block(s, d, kvh), lambda r, g, qi: (r, 0, g),
@@ -1583,10 +1671,10 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=scratch,
-        name=_kernel_name("flash_fwd", d, dv, window),
+        name=_kernel_name("flash_fwd", d, dv, window, select is not None),
         interpret=interpret,
         **params,
-    )(*live, *rng, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
+    )(*live, *rng, *sel, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
     if causal:
         # names a rematerialising caller may keep (models/lfm2_moe.py
         # DENSE_SAVED): with both saved the backward pass finds the kernel's
@@ -1604,7 +1692,7 @@ def _flash_fwd_rule(q, k, v, bias, segment_ids, seed, rate, interpret,
                  segment_ids is not None)
 
 
-def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
+def _flash_bwd_rule(rate, interpret, causal, window, saved, g, select=None):
     # residuals are in the kernel layout _flash_fwd chose (same
     # deterministic shape gate); lse is in `_Layout.row_sums`' layout
     (qx, kx, vx, bias2, seg2, lse, outx), seed, qshape, has_bias, \
@@ -1618,7 +1706,7 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
     skip_rows = _skip_pad_rows(has_segments, tiles)
     live_spec, live = _live_rows(seg2, skip_rows)
     scale = 1.0 / (d ** 0.5)
-    lay = _layout(b, s, h, d, h // hkv, dv, window)
+    lay = _layout(b, s, h, d, h // hkv, dv, window, select is not None)
     gx = lay.pack(g)
     # delta = rowsum(dO * O) per head (cheap elementwise — jnp, not a kernel)
     delta = lay.row_sums(gx.astype(jnp.float32) * outx.astype(jnp.float32),
@@ -1633,7 +1721,7 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
 
     one = lay.one_head()
     if (s * one.heads_per_prog * d <= _FUSED_BWD_MAX_PANEL and dv == d
-            and hkv == h and not window):
+            and hkv == h and not window and select is None):
         # fused dq/dk/dv kernel: scores, exp and dropout masks evaluated
         # once instead of twice
         hp = one.heads_per_prog
@@ -1685,12 +1773,24 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
 
         q_blk_bs = pl.BlockSpec((hp, blk_q, d), blk)
         stat_blk_bs = pl.BlockSpec((hp, 1, blk_q), lambda r, qi: (r, 0, qi))
+        dq_kernel = functools.partial(_dq_kernel, blk_k=blk_k,
+                                      batch_of=lay.batch, **kw)
+        dkv_kernel = functools.partial(_dkv_kernel, blk_q=blk_q,
+                                       batch_of=lay.batch, **kw)
+        selq_spec, selq, selk_spec, selk = [], [], [], []
+        if select is not None:
+            selq_spec, selq = _select_operand(
+                select[0], lay, (blk_q, blk_k), lambda qi: (qi, 0))
+            selk_spec, selk = _select_operand(
+                select[1], lay, (blk_q, blk_k), lambda kj: (0, kj))
+            dq_kernel = _with_select(dq_kernel)
+            dkv_kernel = _with_select(dkv_kernel)
+        named = functools.partial(_kernel_name, d=d, dv=dv, window=window,
+                                  select=select is not None)
         dq = pl.pallas_call(
-            _program(functools.partial(_dq_kernel, blk_k=blk_k,
-                                       batch_of=lay.batch, **kw),
-                     2, 1, batch_of, bool(rng), 1),
+            _program(dq_kernel, 2, 1, batch_of, bool(rng), 1),
             grid=(lay.rows, s // blk_q),
-            in_specs=live_spec + rng_spec + [
+            in_specs=live_spec + rng_spec + selq_spec + [
                 pl.BlockSpec((1,), lambda r, qi: (0,)),
                 q_blk_bs,
                 _panel_spec((kvh, s, d), whole, params),
@@ -1704,11 +1804,11 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
             out_specs=q_blk_bs,
             out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
             scratch_shapes=_scratch((hp, blk_q, d)),
-            name=_kernel_name("flash_bwd_dq", d, dv, window),
+            name=named("flash_bwd_dq"),
             interpret=interpret,
             **params,
-        )(*live, *rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,
-          gx)
+        )(*live, *rng, *selq, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse,
+          delta, gx)
 
         # ONE dk / dv block a key/value head, in the parameters' dtype: the
         # query heads of a group are added in the kernel's accumulators
@@ -1716,11 +1816,9 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
         v_blk_bs = pl.BlockSpec((kvh, blk_k, dv), blk)
         stat_bs = _panel_spec((hp, 1, s), whole, params)
         dk, dvx = pl.pallas_call(
-            _program(functools.partial(_dkv_kernel, blk_q=blk_q,
-                                       batch_of=lay.batch, **kw),
-                     2, 2, batch_of, bool(rng), 2),
+            _program(dkv_kernel, 2, 2, batch_of, bool(rng), 2),
             grid=(lay.rows, s // blk_k),
-            in_specs=live_spec + rng_spec + [
+            in_specs=live_spec + rng_spec + selk_spec + [
                 pl.BlockSpec((1,), lambda r, kj: (0,)),
                 _panel_spec((hp, s, d), whole, params),
                 k_blk_bs, v_blk_bs,
@@ -1734,11 +1832,11 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
             out_shape=[jax.ShapeDtypeStruct(kx.shape, kx.dtype),
                        jax.ShapeDtypeStruct(vx.shape, vx.dtype)],
             scratch_shapes=_scratch((kvh, blk_k, d), (kvh, blk_k, dv)),
-            name=_kernel_name("flash_bwd_dkv", d, dv, window),
+            name=named("flash_bwd_dkv"),
             interpret=interpret,
             **params,
-        )(*live, *rng, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse, delta,
-          gx)
+        )(*live, *rng, *selk, seed_arr, qx, kx, vx, bias2, seg2, seg2, lse,
+          delta, gx)
 
     # bias is non-differentiable by contract (zero cotangent; see the
     # flash_attention docstring), segment ids and seed likewise — the
@@ -1754,3 +1852,42 @@ def _flash_bwd_rule(rate, interpret, causal, window, saved, g):
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def flash_select_attention(q, k, v, segment_ids, select_by_q, select_by_k,
+                           interpret: bool = False):
+    """Causal attention over the keys each query SELECTS (a learned-sparse
+    layer, models/keye.py): `flash_attention(causal=True)` with one more
+    condition in the tiles' mask, through the same bh tile bodies under
+    kernel names of their own (`flash_sel_fwd`, `flash_sel_bwd_dq`,
+    `flash_sel_bwd_dkv`). The selection is ONE a query token, shared by its
+    heads, packed by block (`select_blocks`; ops/sparse_index.py packs it):
+    `select_by_q` (B, W, S, blk_k) for the forward and dq kernels,
+    `select_by_k` (B, W, blk_q, S) for the dkv kernel. A selected pair that
+    is later than its query or of another document stays masked (the
+    causal and segment conditions stand). Integer data: zero cotangents.
+    No bias, no dropout, no band."""
+    return _flash_select_fwd_rule(q, k, v, segment_ids, select_by_q,
+                                  select_by_k, interpret)[0]
+
+
+def _flash_select_fwd_rule(q, k, v, segment_ids, select_by_q, select_by_k,
+                           interpret):
+    _check_select((select_by_q, select_by_k), q.shape[0], q.shape[1])
+    out, res = _flash_fwd(q, k, v, None, segment_ids, None, 0.0, interpret,
+                          True, 0, (select_by_q, select_by_k))
+    return out, (res, q.shape, segment_ids is not None, select_by_q,
+                 select_by_k)
+
+
+def _flash_select_bwd_rule(interpret, saved, g):
+    res, qshape, has_segments, by_q, by_k = saved
+    dq, dk, dv, _, dseg, _ = _flash_bwd_rule(
+        0.0, interpret, True, 0, (res, None, qshape, False, has_segments), g,
+        select=(by_q, by_k))
+    zero = jax.custom_derivatives.zero_from_primal
+    return dq, dk, dv, dseg, zero(by_q), zero(by_k)
+
+
+flash_select_attention.defvjp(_flash_select_fwd_rule, _flash_select_bwd_rule)

@@ -84,9 +84,10 @@ LM_STEP_SCOPES = (
 # every child in each family's compiled step, forward and backward;
 # `program_scopes` in the run's second header counts them in the executable
 # (run_pretraining.py); docs/OBSERVABILITY.md draws the tree.
-_FLAT_ATTENTION = ("bert", "lfm2_moe", "kimi_linear")
+_FLAT_ATTENTION = ("bert", "lfm2_moe", "kimi_linear", "keye")
 _BY_KIND_ATTENTION = ("smallthinker", "laguna")
-_ROUTED_FAMILIES = ("lfm2_moe", "kimi_linear", "smallthinker", "laguna")
+_ROUTED_FAMILIES = ("lfm2_moe", "kimi_linear", "smallthinker", "laguna",
+                    "keye")
 STEP_SUBSCOPES = {
     # ops/attention.dot_product_attention: q, k, v in, context out
     "attention": {"attn_core": _FLAT_ATTENTION,
@@ -97,7 +98,13 @@ STEP_SUBSCOPES = {
                   # models/laguna.py: the rotation of q and k (a part of the
                   # head, at a table of its kind) and the per-head gate on
                   # the context, beside the kind's projections and kernels
-                  "rotary": ("laguna",), "gate": ("laguna",)},
+                  "rotary": ("laguna", "keye"), "gate": ("laguna",),
+                  # models/keye.py and ops/sparse_index.py: the learned
+                  # index's projections and scores, the exact selection of
+                  # each query's keys, and the KL term the index learns from
+                  # (forward and backward)
+                  "indexer": ("keye",), "select": ("keye",),
+                  "indexer_loss": ("keye",)},
     "attention/attention_window": {"attn_core": _BY_KIND_ATTENTION},
     "attention/attention_full": {"attn_core": _BY_KIND_ATTENTION},
     # models/lfm2_moe.ShortConv: `mix` is what is no projection
@@ -113,6 +120,12 @@ STEP_SUBSCOPES = {
             "experts": _ROUTED_FAMILIES, "combine": _ROUTED_FAMILIES,
             "shared": ("kimi_linear", "laguna")},
 }
+
+
+# Declared paths that hold no operation of the backward pass: the selection
+# is discrete (no cotangent passes through it) and what it packs is kept
+# for the backward kernels (models/keye.REMAT_POLICIES), not made again.
+FORWARD_ONLY_SUBSCOPES = ("attention/select",)
 
 
 def step_subscopes(family: Optional[str] = None) -> Tuple[str, ...]:
@@ -678,8 +691,10 @@ def build_pretrain_step(
     -> (loss, aux) replaces the built-in MLM + NSP loss (the decoder
     families: models/lfm2_moe.pretrain_loss_fn_builder). A dict under
     aux["scalars"] is summed over the step's micro-batches and returned
-    among the metrics as it is (a family's counters). `keep_float32`: see
-    _param_caster.
+    among the metrics as it is (a family's counters); one under
+    aux["means"] is averaged over them, as the loss is (the terms of a loss
+    that has several: models/keye.py's `lm_loss` and `indexer_kl`).
+    `keep_float32`: see _param_caster.
 
     `schedule` is only consulted for the lr metric (the optimizer owns its
     own schedule). `max_predictions` (pretraining only; ignored when a custom
@@ -794,6 +809,7 @@ def build_pretrain_step(
                 # disagree
                 metrics["mlm_dropped"] = aux["mlm_dropped"]
             metrics.update(aux.get("scalars", {}))
+            metrics.update(aux.get("means", {}))
             if schedule is not None:
                 metrics["learning_rate"] = schedule(state.step)
         return new_state, metrics
@@ -828,6 +844,9 @@ def build_pretrain_step(
             (grads, loss, aux), _ = jax.lax.scan(body, init, (batch, rngs))
             grads = jax.tree.map(lambda g: g / accum_steps, grads)
             loss = loss / accum_steps
+            if "means" in aux:
+                aux = dict(aux, means=jax.tree.map(
+                    lambda v: v / accum_steps, aux["means"]))
         return loss, aux, grads
 
     return train_step

@@ -17,8 +17,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from bert_pytorch_tpu.config import (MODEL_FAMILIES, BertConfig,  # noqa: E402
-                                     KimiLinearConfig, LagunaConfig,
-                                     Lfm2MoeConfig, SmallThinkerConfig)
+                                     KeyeConfig, KimiLinearConfig,
+                                     LagunaConfig, Lfm2MoeConfig,
+                                     SmallThinkerConfig)
 from bert_pytorch_tpu.models.families import FAMILIES, family_of  # noqa: E402
 
 VOCAB, SEQ = 2048, 64
@@ -74,6 +75,17 @@ LAGUNA_TOY = {
         "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
                               "partial_rotary_factor": 1}},
 }
+KEYE_TOY = {
+    "model_type": "keye", "vocab_size": VOCAB, "hidden_size": 32,
+    "moe_intermediate_size": 16, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8,
+    "num_experts": 2, "num_local_experts": 2, "experts_total": 4,
+    "experts_held": [0, 2], "num_experts_per_tok": 2,
+    "rope_scaling": {"mrope_section": [1, 1, 2], "rope_type": "default"},
+    "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 2,
+                  "indexer_num_kv_heads": 1, "q_chunk_size": 512,
+                  "kv_chunk_size": 512, "topk": 8},
+}
 TINY = {
     "bert": BertConfig(
         vocab_size=VOCAB, hidden_size=32, num_hidden_layers=2,
@@ -86,6 +98,7 @@ TINY = {
     "smallthinker": SmallThinkerConfig.from_dict(SMALLTHINKER_TOY).replace(
         dtype="float32"),
     "laguna": LagunaConfig.from_dict(LAGUNA_TOY).replace(dtype="float32"),
+    "keye": KeyeConfig.from_dict(KEYE_TOY).replace(dtype="float32"),
 }
 
 
@@ -149,8 +162,10 @@ def test_record_builds_initialises_and_steps_its_family(name, tmp_path):
         jax.random.PRNGKey(1))
     assert int(new_state.step) == 1
     # seeded weights: the loss is the uniform guess (BERT: plus NSP's,
-    # at most ln 2)
-    assert -0.5 < float(metrics["loss"]) - np.log(VOCAB) < np.log(2) + 0.5
+    # at most ln 2; keye: its second term beside it, which is not in it)
+    assert -0.5 < float(metrics.get("lm_loss", metrics["loss"])) \
+        - np.log(VOCAB) < np.log(2) + 0.5
+    assert ("indexer_kl" in metrics) == (name == "keye")
     moved = jax.tree.map(lambda a, b: bool(jnp.any(a != b)),
                          state.params, new_state.params)
     assert any(jax.tree.leaves(moved))
@@ -162,13 +177,14 @@ def test_record_builds_initialises_and_steps_its_family(name, tmp_path):
     if counters is not None:
         counters.update({k: float(v) for k, v in metrics.items()})
         assert counters.fields()["moe_l0_dropped"] == 0
+        assert ("dsa_selected_pairs" in counters.fields()) == (name == "keye")
 
 
 @pytest.mark.parametrize("flags", [
     ["--kfac"], ["--stream_dir", "corpus"], ["--stacked_params", "true"],
     ["--steps_per_loop", "2"]], ids=lambda f: f[0].lstrip("-"))
-@pytest.mark.parametrize("toy", [LFM2_TOY, KIMI_TOY, LAGUNA_TOY],
-                         ids=["lfm2_moe", "kimi_linear", "laguna"])
+@pytest.mark.parametrize("toy", [LFM2_TOY, KIMI_TOY, LAGUNA_TOY, KEYE_TOY],
+                         ids=["lfm2_moe", "kimi_linear", "laguna", "keye"])
 def test_decoder_families_refuse_what_they_cannot_run_with(flags, toy,
                                                            tmp_path):
     import run_pretraining
@@ -182,7 +198,8 @@ def test_decoder_families_refuse_what_they_cannot_run_with(flags, toy,
     with pytest.raises(SystemExit) as e:
         run_pretraining.main(argv)
     message = str(e.value)
-    assert "'lfm2_moe', 'kimi_linear', 'smallthinker', 'laguna'" in message
+    assert ("'lfm2_moe', 'kimi_linear', 'smallthinker', 'laguna', 'keye'"
+            in message)
     for flag in ("--kfac", "--stream_dir", "--stacked_params",
                  "--steps_per_loop"):
         assert flag in message
@@ -198,6 +215,7 @@ def test_bert_refuses_none_of_them():
     # the decoder families' ONE refusal
     assert FAMILIES["kimi_linear"].refusal is FAMILIES["lfm2_moe"].refusal
     assert FAMILIES["laguna"].refusal is FAMILIES["lfm2_moe"].refusal
+    assert FAMILIES["keye"].refusal is FAMILIES["lfm2_moe"].refusal
     args = argparse.Namespace(kfac=False, stream_dir=None,
                               stacked_params="auto", steps_per_loop=1)
     assert FAMILIES["lfm2_moe"].refusal(args) is None

@@ -187,6 +187,64 @@ def test_flash_decoder_cells_split_kernels_compile(v5e, cell, b, s, h, hkv,
     assert _asked_vmem(text) == [asked * _MIB] * 3
 
 
+def test_flash_select_kernels_compile_at_the_keye_cell(v5e):
+    """keye-ep8-clm-16k-fullrow: the causal split kernels under a selection
+    (`flash_sel_*`), a program owning the EIGHT query heads of a key/value
+    head of 128, the packed selection one more block of each program: a
+    (1, 512, 512) int32 block of the q blocks' words in the forward and dq
+    kernels, of the k blocks' in the dkv kernel, inside the 80 MiB the
+    panel-walking calls ask at this group. The AND and compare on the
+    words, under the rolled loop over the heads, are what interpret mode
+    cannot refuse."""
+    b, s, h, hkv, d = 1, 16384, 32, 4, 128
+    assert fa._layout(b, s, h, d, h // hkv, d, 0, True).heads_per_prog == 8
+    blk_q, blk_k, wk, wq = fa.select_blocks(s)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+
+    def bwd(q, k, v, seg, by_q, by_k):
+        return jax.grad(lambda q, k, v: fa.flash_select_attention(
+            q, k, v, seg, by_q, by_k, False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(bwd).lower(
+        sds((b, s, h, d), jnp.bfloat16), sds((b, s, hkv, d), jnp.bfloat16),
+        sds((b, s, hkv, d), jnp.bfloat16), sds((b, s), jnp.int32),
+        sds((b, wk, s, blk_k), jnp.int32),
+        sds((b, wq, blk_q, s), jnp.int32)).compile().as_text()
+    assert hlo.kernel_counts(text) == {
+        fa._kernel_name(n, d, d, 0, True): 1 for n in SPLIT}
+    assert sorted(hlo.kernel_counts(text)) == [
+        "flash_sel_bwd_dkv", "flash_sel_bwd_dq", "flash_sel_fwd"]
+    assert _asked_vmem(text) == [80 * _MIB] * 3
+
+
+def test_index_kernels_compile_at_the_keye_cell(v5e):
+    """ops/pallas/sparse_index.py's three kernels for one chunk of 512
+    queries of a 16,384-token row: 16 index heads of 64 on one key head,
+    32 query heads of 128 on 4 key/value heads; the chunk's number as a
+    prefetched scalar that clamps the key blocks' index maps; the rolled
+    loops over the heads with dynamic first-axis indices; the lane-dense
+    per-token vectors turned into columns."""
+    from bert_pytorch_tpu.ops.pallas import sparse_index as ker
+
+    c, s, j, di, h, hkv, d, blk = 512, 16384, 16, 64, 32, 4, 128, 512
+    assert ker.supported(c, blk, di, d) and not ker.supported(c, blk, 8, 16)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+    i, bf = sds((), jnp.int32), jnp.bfloat16
+    q_idx, k_idx = sds((c, j, di), bf), sds((s, di), bf)
+    w_idx, scores = sds((c, j), jnp.float32), sds((c, s), jnp.float32)
+    assert _kernels(lambda i, q, k, w: ker.index_scores(
+        i, q, k, w, blk, False), i, q_idx, k_idx, w_idx) == {
+            "dsa_index_fwd": 1}
+    assert _kernels(lambda i, q, k, w, g: ker.index_scores_grads(
+        i, q, k, w, g, blk, False), i, q_idx, k_idx, w_idx, scores) == {
+            "dsa_index_bwd": 1}
+    assert _kernels(lambda i, q, k, words: ker.mean_probs(
+        i, q, k, words, blk, False), i, sds((c, h, d), bf),
+        sds((s, hkv, d), bf), sds((1, c, blk), jnp.int32)) == {
+            "dsa_probs": 1}
+
+
 def test_kda_scan_kernels_compile_at_the_kimi_cell(v5e, monkeypatch):
     """A differentiated `ops/kda.kda_scan` at kimi-linear-ep32-clm-16k-packed's
     shape (a row of 16,384, 32 heads of 128, chunks of 64, 32 a block, bf16
