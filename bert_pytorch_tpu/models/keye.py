@@ -39,7 +39,14 @@ LayerNorm's) take their gradient from L_I alone (its input is detached, the
 selection is discrete, I enters nowhere else) and every other parameter
 from L_LM alone: the sparse training stage of DeepSeek-V3.2-Exp's sparse
 attention, carried from that model's one latent key head to Hkv key/value
-heads (ops/sparse_index.py has the op and its gradient rule).
+heads (ops/sparse_index.py has the two ops and the gradient rule).
+
+A layer's order: the index scores and the exact selection
+(`index_select`), the main attention's forward kernel over the selection,
+which hands out its log-sum-exp beside the context, then the KL term
+(`index_kl`), whose target p_ts is exp(s_tsn - lse_tn) averaged over the
+heads: the attention's own normaliser, so the scores are read once more and
+not twice.
 
 What the config does not say and this reading sets (the benchmark's
 configuration file lists each under `assumed` with its reason): q_norm and
@@ -61,8 +68,9 @@ and the losses are float32; matrix products (the indexer's among them) take
 
 Layers are separate modules in a Python loop, each rematerialised under
 `checkpoint_activations` (`remat_policy`: REMAT_POLICIES; "dense" keeps
-lfm2_moe.DENSE_SAVED and the selection with the KL term's small gradients,
-so the backward pass runs neither the forward kernel nor the index pass
+lfm2_moe.DENSE_SAVED (the forward kernel's context and log-sum-exp among
+them) and the selection with the KL term's small gradients, so the backward
+pass runs neither the forward kernel nor either of the two index passes
 again). The model hands back the final norm's output and the head, not
 logits: the loss (losses.next_token_loss_blocked) takes the head a block of
 tokens at a time.
@@ -93,7 +101,7 @@ from bert_pytorch_tpu.models.lfm2_moe import keep_float32  # noqa: F401
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 from bert_pytorch_tpu.ops.decoder_ops import rotary
 from bert_pytorch_tpu.ops.sparse_index import (full_row_selected_pairs,
-                                               index_select_loss)
+                                               index_kl, index_select)
 
 Dtype = Any
 
@@ -103,8 +111,9 @@ LOSS_BLOCK_ROWS = 2048
 # What the rematerialised layer keeps beside its input. "dense": lfm2's
 # names and, of ops/sparse_index.py, the packed selection and the KL term's
 # gradients with respect to the indexer's three small outputs (64 + 36 MB a
-# layer at 16,384 tokens), so that the backward pass runs the index pass
-# (scores, selection, the KL term's probabilities) not at all.
+# layer at 16,384 tokens), so that the backward pass runs neither index pass
+# (scores and selection; scores again, the KL term's probabilities and the
+# scores' backward) at all.
 REMAT_POLICIES = {
     "nothing": jax.checkpoint_policies.nothing_saveable,
     "dense": jax.checkpoint_policies.save_only_these_names(
@@ -177,16 +186,20 @@ class Attention(nn.Module):
             k_idx = rotary(k_idx[:, :, None, :], position_ids,
                            cfg.rope_theta)[:, :, 0].astype(self.dtype)
             w_idx = w_idx / float(j * di) ** 0.5
-        picked = index_select_loss(
-            q_idx, k_idx, w_idx, jax.lax.stop_gradient(q),
-            jax.lax.stop_gradient(k), segment_ids, cfg.sa_topk,
-            cfg.attention_impl)
-        ctx = dot_product_attention(
+        # the selection, the main attention over it, then the KL term,
+        # which takes its target's normaliser from the attention's kernel
+        picked = index_select(q_idx, k_idx, w_idx, segment_ids, cfg.sa_topk,
+                              cfg.attention_impl)
+        ctx, lse = dot_product_attention(
             q, k, v, segment_ids=segment_ids, impl=cfg.attention_impl,
-            causal=True, select=(picked.by_q, picked.by_k))
+            causal=True, select=(picked.by_q, picked.by_k), with_lse=True)
+        kl_sum = index_kl(
+            q_idx, k_idx, w_idx, jax.lax.stop_gradient(q),
+            jax.lax.stop_gradient(k), jax.lax.stop_gradient(lse),
+            picked.by_q, cfg.attention_impl)
         out = _Linear(e, cfg, self.dtype, name="out_proj")(
             ctx.reshape(bsz, s, h * d))
-        return out, (picked.kl_sum, picked.block_pairs, picked.candidates)
+        return out, (kl_sum, picked.block_pairs, picked.candidates)
 
 
 class DecoderLayer(nn.Module):
@@ -204,7 +217,13 @@ class DecoderLayer(nn.Module):
                          name="post_attention_layernorm")(h)
         out, load, dropped = RoutedExperts(cfg, self.dtype, name="moe")(
             normed)
-        return h + out, load, dropped, picked
+        # the KL term feeds nothing of the layer: tied to the layer's
+        # output, it runs before the next layer starts, and the q, k and
+        # words it reads are not left waiting while further layers run
+        # (the output is dead in the backward pass's recomputation, so the
+        # tie makes nothing run twice)
+        y, picked = jax.lax.optimization_barrier((h + out, picked))
+        return y, load, dropped, picked
 
 
 class KeyeForCausalLM(nn.Module):

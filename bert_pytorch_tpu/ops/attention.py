@@ -237,6 +237,7 @@ def dot_product_attention(
     causal: bool = False,
     window: Optional[int] = None,
     select=None,
+    with_lse: bool = False,
 ) -> jax.Array:
     """Returns (B, Sq, H, D) in q.dtype.
 
@@ -253,6 +254,12 @@ def dot_product_attention(
     ops/sparse_index.py packs it (`by_q`, `by_k`: the flash kernels' form,
     ops/pallas/flash_attention.select_blocks); a selected key that the
     causal or the segment condition excludes stays excluded.
+    `with_lse` (with `select` only): returns (the context, lse (B, H, Sq)
+    float32), each head's log-sum-exp of its scaled scores over the query's
+    selected keys: the flash forward kernel's own residual, or the XLA
+    path's `logsumexp` of its masked scores. Data for a caller that reads
+    the same scores again (ops/sparse_index.index_kl), not a second
+    differentiable output of the kernels.
 
     impl="auto" resolves by sequence length: measured on v5e, the plain XLA
     path (bf16 probs, fp32 softmax stats) beats the blockwise Pallas kernel
@@ -286,6 +293,10 @@ def dot_product_attention(
         raise ValueError(
             "attention select= needs causal=True and takes no window, bias "
             "or dropout")
+    if with_lse and select is None:
+        raise ValueError(
+            "attention with_lse=True hands out the log-sum-exp over a "
+            "selection's keys: it needs select=")
     if impl == "auto":
         impl = "pallas" if seq > 256 else "xla"
     interpret = jax.default_backend() != "tpu" and _pallas_interpret()
@@ -337,8 +348,9 @@ def dot_product_attention(
                 from bert_pytorch_tpu.ops.pallas.flash_attention import (
                     flash_select_attention)
 
-                return flash_select_attention(q, k, v, segment_ids, *select,
-                                              interpret)
+                out = flash_select_attention(q, k, v, segment_ids, *select,
+                                             interpret)
+                return out if with_lse else out[0]
             if mesh is None:
                 return flash_attention(q, k, v, bias=bias,
                                        segment_ids=segment_ids,
@@ -367,19 +379,21 @@ def dot_product_attention(
 
     return _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                           dropout_rate, deterministic, hash_dropout_impl,
-                          causal, window, select)
+                          causal, window, select, with_lse)
 
 
-def unpack_select(by_q: jax.Array) -> jax.Array:
-    """(B, S, S) bools from a selection packed by q block (B, W, S, blk):
-    query q selects key j * blk + c where bit j % 32 of word [j // 32, q, c]
-    is set (ops/pallas/flash_attention.py, at `_select_tile`). The XLA
+def unpack_select(by_q: jax.Array, keys: Optional[int] = None) -> jax.Array:
+    """(B, rows, keys) bools from a selection packed by q block
+    (B, W, rows, blk): query q selects key j * blk + c where bit j % 32 of
+    word [j // 32, q, c] is set (ops/pallas/flash_attention.py, at
+    `_select_tile`). `keys`: the row's length where `by_q` holds some of its
+    queries only (a chunk's words); None: as many as the rows. The XLA
     path's dense mirror of the kernels' operand."""
-    b, planes, s, blk = by_q.shape
-    j = jnp.arange(s // blk)
-    words = by_q[:, j // 32]                               # (B, nk, S, blk)
+    b, planes, rows, blk = by_q.shape
+    j = jnp.arange((keys or rows) // blk)
+    words = by_q[:, j // 32]                               # (B, nk, rows, blk)
     bits = (words >> (j % 32)[None, :, None, None]) & 1
-    return (bits != 0).transpose(0, 2, 1, 3).reshape(b, s, s)
+    return (bits != 0).transpose(0, 2, 1, 3).reshape(b, rows, -1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -422,7 +436,8 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
                    dropout_rate: float, deterministic: bool,
                    hash_dropout_impl: bool = True,
                    causal: bool = False,
-                   window: Optional[int] = None, select=None) -> jax.Array:
+                   window: Optional[int] = None, select=None,
+                   with_lse: bool = False) -> jax.Array:
     if k.shape[2] != q.shape[2]:    # grouped heads: one copy per query head
         group = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, group, axis=2)
@@ -476,4 +491,6 @@ def _xla_attention(q, k, v, bias, segment_ids, dropout_rng,
         # is uniform garbage. Zero them to match the flash kernels' pad
         # contract exactly (flash_attention.py module docstring).
         out = out * (segment_ids > 0).astype(out.dtype)[:, :, None, None]
+    if with_lse:
+        return out, jax.nn.logsumexp(scores, axis=-1)
     return out

@@ -1867,7 +1867,13 @@ def flash_select_attention(q, k, v, segment_ids, select_by_q, select_by_k,
     `select_by_k` (B, W, blk_q, S) for the dkv kernel. A selected pair that
     is later than its query or of another document stays masked (the
     causal and segment conditions stand). Integer data: zero cotangents.
-    No bias, no dropout, no band."""
+    No bias, no dropout, no band.
+
+    -> (the context (B, S, H, D), lse (B, H, S) float32): the second is the
+    forward kernel's residual, each head's log-sum-exp of its scaled scores
+    over the query's selected keys (`_fwd_finish`), handed out for the
+    caller that reads the same scores again (ops/sparse_index.index_kl's
+    probabilities). It is data: a cotangent given to it is dropped."""
     return _flash_select_fwd_rule(q, k, v, segment_ids, select_by_q,
                                   select_by_k, interpret)[0]
 
@@ -1877,15 +1883,18 @@ def _flash_select_fwd_rule(q, k, v, segment_ids, select_by_q, select_by_k,
     _check_select((select_by_q, select_by_k), q.shape[0], q.shape[1])
     out, res = _flash_fwd(q, k, v, None, segment_ids, None, 0.0, interpret,
                           True, 0, (select_by_q, select_by_k))
-    return out, (res, q.shape, segment_ids is not None, select_by_q,
-                 select_by_k)
+    # a selection takes the bh layout: the residual is (B * H, 1, S)
+    *_, lse, _ = res
+    lse = lse.reshape(q.shape[0], q.shape[2], q.shape[1])
+    return (out, lse), (res, q.shape, segment_ids is not None, select_by_q,
+                        select_by_k)
 
 
-def _flash_select_bwd_rule(interpret, saved, g):
+def _flash_select_bwd_rule(interpret, saved, cts):
     res, qshape, has_segments, by_q, by_k = saved
     dq, dk, dv, _, dseg, _ = _flash_bwd_rule(
-        0.0, interpret, True, 0, (res, None, qshape, False, has_segments), g,
-        select=(by_q, by_k))
+        0.0, interpret, True, 0, (res, None, qshape, False, has_segments),
+        cts[0], select=(by_q, by_k))
     zero = jax.custom_derivatives.zero_from_primal
     return dq, dk, dv, dseg, zero(by_q), zero(by_k)
 

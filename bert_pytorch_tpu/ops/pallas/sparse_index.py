@@ -18,16 +18,17 @@ clamps at i) and zeros are written.
 - `index_scores` (`dsa_index_fwd`): I = sum_j w_j relu(qI_j . kI^T), the J
   heads' products never leaving VMEM;
 - `mean_probs` (`dsa_probs`): the mean over the H heads of the main
-  attention's probabilities over each row's selected keys. Two walks over
-  the key blocks: the first takes each head's running max and sum (its
-  softmax's normaliser over the selected keys), the second writes
+  attention's probabilities over each row's selected keys, in ONE walk over
+  the key blocks: each head's normaliser over the selected keys comes in,
+  as the log-sum-exp that the main attention's forward kernel wrote for the
+  same scores (`flash_select_attention`'s second output), and a tile writes
   (1/H) sum_n exp(s_n - lse_n);
 - `index_scores_grads` (`dsa_index_bwd`): the cotangents of qI, kI and w
   for a cotangent of I, the products recomputed a tile at a time; dqI and dw
   stay in VMEM over the walk, dkI is written a key block a step (the
   chunk's own share: the caller adds the chunks').
 
-Per-token vectors (w, dw) travel lane-dense, (J, 1, C), and turn into
+Per-token vectors (w, dw, lse) travel lane-dense, (J, 1, C), and turn into
 columns inside the kernels, as the flash kernels' row statistics do.
 """
 
@@ -53,7 +54,7 @@ def supported(c: int, blk_k: int, d_idx: int, d: int) -> bool:
 
 
 def _call(kernel, name: str, grid: tuple, in_specs, out_specs, out_shape,
-          scratch=(), interpret: bool = False):
+          interpret: bool = False):
     """pallas_call with the chunk's number as the one prefetched scalar
     (the index maps' last argument, the kernel's first ref): every axis of
     the grid is sequential."""
@@ -63,9 +64,7 @@ def _call(kernel, name: str, grid: tuple, in_specs, out_specs, out_shape,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
-                            for shape in scratch]),
+            out_specs=out_specs),
         out_shape=out_shape, name=name, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
@@ -145,71 +144,51 @@ def _selected(sel_ref, j):
     return (sel_ref[j // SELECT_WORD] & bit) != 0
 
 
-def _probs_kernel(i_ref, q_ref, k_ref, sel_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, scale: float, group: int):
-    phase, j = pl.program_id(0), pl.program_id(1)
+def _probs_kernel(i_ref, q_ref, k_ref, lse_ref, sel_ref, o_ref, *,
+                  scale: float, group: int):
+    j = pl.program_id(0)
     heads = q_ref.shape[0]
-    live = j <= i_ref[0]
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
-    def scores(t):
-        s = jax.lax.dot_general(
-            q_ref[t], k_ref[t // group], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        return jnp.where(_selected(sel_ref, j), s, NEG_INF)
-
-    @pl.when((phase == 0) & (j == 0))
+    @pl.when(j <= i_ref[0])
     def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-
-    @pl.when((phase == 0) & live)
-    def _():
-        def head(t, _):
-            s = scores(t)
-            m = jnp.maximum(m_ref[t], jnp.max(s, axis=-1, keepdims=True))
-            l_ref[t] = l_ref[t] * jnp.exp(m_ref[t] - m) + jnp.sum(
-                jnp.exp(s - m), axis=-1, keepdims=True)
-            m_ref[t] = m
-
-        jax.lax.fori_loop(0, heads, head, None)
-
-    @pl.when((phase == 1) & live)
-    def _():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        selected = _selected(sel_ref, j)
 
         def head(t, _):
-            lse = m_ref[t] + jnp.log(jnp.maximum(l_ref[t], 1e-30))
-            acc_ref[...] += jnp.exp(scores(t) - lse)
+            # the forward kernel's scores (ops/pallas/flash_attention.py at
+            # `_fwd_heads`: D = 128, the scale on the tile), so that its
+            # log-sum-exp is these scores' own
+            s = jax.lax.dot_general(
+                q_ref[t], k_ref[t // group], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            o_ref[...] += jnp.exp(jnp.where(selected, s, NEG_INF)
+                                  - lse_ref[t, 0][:, None])
 
         jax.lax.fori_loop(0, heads, head, None)
         # the mask once more: a row that selects nothing at all has every
-        # score at NEG_INF and a normaliser to match, and reads 1 a key
-        o_ref[...] = jnp.where(_selected(sel_ref, j), acc_ref[...] / heads,
-                               0.0)
-
-    @pl.when((phase == 1) & jnp.logical_not(live))
-    def _():
-        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+        # score at NEG_INF and a log-sum-exp to match, and reads 1 a key
+        o_ref[...] = jnp.where(selected, o_ref[...] / heads, 0.0)
 
 
-def mean_probs(i, q, k, by_q, blk_k: int, interpret: bool):
+def mean_probs(i, q, k, lse, by_q, blk_k: int, interpret: bool):
     """(C, S) float32: the mean over the H heads of the attention's
     probabilities over each row's selected keys, for chunk number `i`:
-    q (C, H, D), k (S, Hkv, D), by_q (W, C, blk_k) the chunk's packed
-    selection."""
+    q (C, H, D), k (S, Hkv, D), lse (H, C) float32 each head's log-sum-exp
+    of its scores over the row's selected keys, by_q (W, C, blk_k) the
+    chunk's packed selection. Key blocks after the chunk's own read
+    zeros."""
     c, h, d = q.shape
     s, hkv = k.shape[0], k.shape[1]
     return _call(
         functools.partial(_probs_kernel, scale=1.0 / (d ** 0.5),
                           group=h // hkv),
-        "dsa_probs", (2, s // blk_k),
+        "dsa_probs", (s // blk_k,),
         [_whole((h, c, d)), _key_block((hkv, blk_k, d), 1),
-         _whole(by_q.shape)],
-        # the first walk writes nothing: its block stays the first
-        pl.BlockSpec((c, blk_k), lambda ph, j, i_ref: (0, j * ph)),
-        jax.ShapeDtypeStruct((c, s), jnp.float32),
-        scratch=((h, c, 1), (h, c, 1), (c, blk_k)), interpret=interpret,
-    )(_chunk(i), q.transpose(1, 0, 2), k.transpose(1, 0, 2), by_q)
+         _whole((h, 1, c)), _whole(by_q.shape)],
+        pl.BlockSpec((c, blk_k), lambda j, i_ref: (0, j)),
+        jax.ShapeDtypeStruct((c, s), jnp.float32), interpret=interpret,
+    )(_chunk(i), q.transpose(1, 0, 2), k.transpose(1, 0, 2),
+      lse[:, None, :], by_q)
 
 
 def _index_bwd_kernel(i_ref, q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref,

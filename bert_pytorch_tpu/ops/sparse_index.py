@@ -10,23 +10,31 @@ For one packed row, query t and key s <= t of t's document (n_t such keys):
     p_ts = (1/H) sum_n softmax_{s in S_t}(q_tn . k_s,n//G / sqrt D)
     KL_t = sum_{s in S_t} p_ts (log p_ts - log softmax_{s in S_t}(I_ts))
 
-`index_select_loss` returns the selection in the form the flash kernels read
+Two ops, in the order a layer runs them (models/keye.Attention):
+`index_select` makes the selection in the form the flash kernels read
 (ops/pallas/flash_attention.py at `_select_tile`: bit-packed by block, by q
-block and by k block), sum_t KL_t over the real tokens, and the selection's
-counters. The index scores, the selection and both softmaxes are float32;
-the products take their operands as given (bfloat16) and accumulate in
-float32.
+block and by k block) and its counters; the main attention's forward kernel
+runs over it; `index_kl` then takes that kernel's own log-sum-exp over the
+selected keys (lse_tn: `flash_select_attention`'s second output), so that
+p_ts = (1/H) sum_n exp(s_tsn - lse_tn) is ONE reading of the scores, and
+returns sum_t KL_t over the real tokens. The index scores, the selection and
+both softmaxes are float32; the products take their operands as given
+(bfloat16) and accumulate in float32.
 
-Nothing of size (S, S) is alive: the row is worked a chunk of queries at a
-time (the kernels' q block, 512 at the default blocks, which is the source's
-`q_chunk_size`), each chunk against every key: (chunk, S) scores, the exact
-K-th largest of each row, the chunk's selected pairs packed, and the KL
-term's probabilities (the main attention's own scores, a key/value head's
-group of query heads at a time). On a TPU the chunk's three heavy passes
-(the index scores, the head-mean probabilities, the index scores' backward
-pass) are ops/pallas/sparse_index.py's kernels, whose per-head products
-stay in VMEM; what is written here in plain XLA is what runs elsewhere and
-what those are tested against.
+Nothing of size (S, S) is alive: each op works the row a chunk of queries at
+a time (the kernels' q block, 512 at the default blocks, which is the
+source's `q_chunk_size`), each chunk against every key. `index_select`:
+(chunk, S) scores, the exact K-th largest of each row, the chunk's selected
+pairs packed. `index_kl`: the chunk's scores AGAIN ((S, S) float32 scores
+are 1 GB a row; a chunk's are a quarter of the products of the walk over the
+main attention's scores that the log-sum-exp spares), the selected pairs
+unpacked from the chunk's words, the head-mean probabilities (a key/value
+head's group of query heads at a time), the KL term and the index scores'
+backward pass. On a TPU the chunk's three heavy passes (the index scores,
+the head-mean probabilities, the index scores' backward pass) are
+ops/pallas/sparse_index.py's kernels, whose per-head products stay in VMEM;
+what is written here in plain XLA is what runs elsewhere and what those are
+tested against.
 
 The exact K-th largest (`kth_largest`): the scores' bits, reordered so that
 unsigned integers sort as the floats do, and a bisection from the top bit
@@ -36,22 +44,24 @@ number and a sort of every row: on the chip the index pass of a layer and
 row took 282 ms with it and 71 ms with the bisection (PERF.md section 6,
 PR 43), so the bisection is the one way.
 
-The gradient is a rule of its own (`jax.custom_vjp`). KL_t's gradient with
-respect to the scores is softmax(I) - p on the selected set, so the forward
-pass, which holds a chunk's scores and probabilities anyway, applies it
-there (`index_scores_grads`) and keeps the cotangents of
+The selection is discrete and takes no gradient (`index_select` detaches
+its inputs). `index_kl`'s gradient is a rule of its own (`jax.custom_vjp`).
+KL_t's gradient with respect to the scores is softmax(I) - p on the selected
+set, so the forward pass, which holds a chunk's scores and probabilities
+anyway, applies it there (`index_scores_grads`) and keeps the cotangents of
 the three SMALL inputs (qI, kI, w: 36 MB at 16,384 tokens) as residuals,
 named `dsa_kl_grads` beside the packed selection `dsa_select`: a
 rematerialising caller that saves both names (models/keye.REMAT_POLICIES)
-runs this pass once a layer and step, and the backward pass is two
-multiplications by the loss's cotangent. The main attention's q and k enter
-as data (the target is detached), as do the segment ids.
+runs each pass once a layer and step, and the backward pass is two
+multiplications by the loss's cotangent. The main attention's q, k and
+log-sum-exp enter as data (the target is detached), as do the packed words.
 
 Scopes, each opened under its whole name (a loop's body keeps the scopes
-opened in it, not the caller's): `attention/indexer` (the scores),
-`attention/select` (the K-th score, the selected pairs, their packing and
-counters), `attention/indexer_loss` (the probabilities, the KL term and the
-index scores' backward pass).
+opened in it, not the caller's): `attention/indexer` (the scores the
+selection is made from), `attention/select` (the K-th score, the selected
+pairs, their packing and counters), `attention/indexer_loss` (all of
+`index_kl`: the scores again, the probabilities, the KL term and the index
+scores' backward pass).
 """
 
 from __future__ import annotations
@@ -64,6 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from bert_pytorch_tpu.ops.attention import _pallas_interpret, unpack_select
 from bert_pytorch_tpu.ops.pallas import sparse_index as kernels
 from bert_pytorch_tpu.ops.pallas.flash_attention import (SELECT_WORD,
                                                          select_blocks)
@@ -74,7 +85,6 @@ NEG_INF = -1e30
 class Selection(NamedTuple):
     by_q: jax.Array         # (B, W, S, blk_k) int32: the fwd and dq kernels'
     by_k: jax.Array         # (B, W, blk_q, S) int32: the dkv kernel's
-    kl_sum: jax.Array       # () float32: sum over real tokens of KL_t
     block_pairs: jax.Array  # (S // blk_k,) int32: selected pairs by k block
     candidates: jax.Array   # (2,) int32: causal pairs inside documents, as
     #                         [count // COUNT_UNIT, count % COUNT_UNIT]
@@ -155,24 +165,30 @@ def select_keys(scores, allowed, topk: int):
         lambda: tied)
 
 
-def mean_probs(q, k, sel) -> jax.Array:
+def mean_probs(q, k, sel, lse=None) -> jax.Array:
     """(C, S) float32: the mean over the H query heads of the attention's
     probabilities over each row's selected keys; q (C, H, D), k (S, Hkv, D),
-    a key/value head's group at a time. A row that selects nothing reads
-    zeros."""
+    a key/value head's group at a time; `lse` (H, C): each head's
+    log-sum-exp of its scores over the row's selected keys (None: taken
+    here). A row that selects nothing reads zeros."""
     c, h, d = q.shape
     hkv = k.shape[1]
     groups = q.reshape(c, hkv, h // hkv, d).transpose(1, 0, 2, 3)
+    if lse is not None:
+        lse = lse.reshape(hkv, h // hkv, c)
 
     def group(total, inputs):
-        qg, kg = inputs
+        qg, kg, lg = inputs
         s = jnp.einsum("cgd,sd->gcs", qg, kg,
                        preferred_element_type=jnp.float32) / math.sqrt(d)
-        p = jax.nn.softmax(jnp.where(sel, s, NEG_INF), axis=-1)
+        s = jnp.where(sel, s, NEG_INF)
+        if lg is None:
+            lg = jax.nn.logsumexp(s, axis=-1)
+        p = jnp.exp(s - lg[:, :, None])
         return total + jnp.sum(jnp.where(sel, p, 0.0), axis=0), None
 
     total, _ = jax.lax.scan(group, jnp.zeros(sel.shape, jnp.float32),
-                            (groups, k.transpose(1, 0, 2)))
+                            (groups, k.transpose(1, 0, 2), lse))
     return total / h
 
 
@@ -189,39 +205,40 @@ def _pack_by_q(sel, blk_k: int):
 
 
 def _use_kernels(impl: str, blk_q: int, blk_k: int, d_idx: int,
-                 d: int) -> tuple:
-    """(run the chunk's three heavy passes as ops/pallas/sparse_index.py's
+                 d: int = 128) -> tuple:
+    """(run the chunk's heavy passes as ops/pallas/sparse_index.py's
     kernels?, in interpret mode?): on a TPU (or under BPT_PALLAS_INTERPRET=1)
-    unless `impl` is "xla", where the shapes are ones the kernels take."""
-    from bert_pytorch_tpu.ops.attention import _pallas_interpret
-
+    unless `impl` is "xla", where the shapes are ones the kernels take (`d`:
+    the main attention's head width, where its scores are read)."""
     interpret = jax.default_backend() != "tpu" and _pallas_interpret()
     on = (impl != "xla" and (jax.default_backend() == "tpu" or interpret)
           and blk_q == blk_k and kernels.supported(blk_q, blk_k, d_idx, d))
     return on, interpret
 
 
-def _row(q_idx, k_idx, w_idx, q, k, seg, topk: int, impl: str,
-         with_grads: bool):
-    """One row's selection, KL sum and counters, and with `with_grads` the
-    KL sum's gradients with respect to q_idx, k_idx and w_idx."""
+def _chunks(x, n: int, axis: int = 0):
+    """`x` as the scan over a row's n chunks reads it: `axis` cut into n
+    and the cuts moved to the front."""
+    x = x.reshape(x.shape[:axis] + (n, -1) + x.shape[axis + 1:])
+    return jnp.moveaxis(x, axis, 0)
+
+
+def _select_row(q_idx, k_idx, w_idx, seg, topk: int,
+                impl: str) -> Selection:
+    """One row's selection and counters."""
     s = seg.shape[0]
     blk_q, blk_k, _, wq = select_blocks(s)
     nq, nk = s // blk_q, s // blk_k
     cols = jnp.arange(s, dtype=jnp.int32)
-    fused, interpret = _use_kernels(impl, blk_q, blk_k, q_idx.shape[-1],
-                                    q.shape[-1])
+    fused, interpret = _use_kernels(impl, blk_q, blk_k, q_idx.shape[-1])
 
     def chunk(carry, inputs):
-        by_k, dk_idx, block_pairs, candidates, kl_sum = carry
-        i, qi, wi, qm, segc = inputs
+        by_k, block_pairs, candidates = carry
+        i, qi, wi, segc = inputs
         rows = i * blk_q + jnp.arange(blk_q, dtype=jnp.int32)
         with jax.named_scope("attention/indexer"):
-            if fused:
-                scores = kernels.index_scores(i, qi, k_idx, wi, blk_k,
-                                              interpret)
-            else:
-                scores, products = index_scores(qi, k_idx, wi, products=True)
+            scores = (kernels.index_scores(i, qi, k_idx, wi, blk_k, interpret)
+                      if fused else index_scores(qi, k_idx, wi))
         with jax.named_scope("attention/select"):
             allowed = ((segc[:, None] == seg[None, :]) & (segc[:, None] > 0)
                        & (cols[None, :] <= rows[:, None]))
@@ -237,9 +254,60 @@ def _row(q_idx, k_idx, w_idx, q, k, seg, topk: int, impl: str,
             count = jnp.sum(allowed, dtype=jnp.int32)
             candidates = candidates + jnp.stack(
                 [count // COUNT_UNIT, count % COUNT_UNIT])
+        return (by_k, block_pairs, candidates), by_q
+
+    init = (jnp.zeros((wq, blk_q, s), jnp.int32),
+            jnp.zeros((nk,), jnp.int32), jnp.zeros((2,), jnp.int32))
+    (by_k, block_pairs, candidates), by_q = jax.lax.scan(
+        chunk, init, (jnp.arange(nq, dtype=jnp.int32), _chunks(q_idx, nq),
+                      _chunks(w_idx, nq), _chunks(seg, nq)))
+    # (nq, W, blk_q, blk_k) -> (W, S, blk_k)
+    by_q = by_q.transpose(1, 0, 2, 3).reshape(-1, s, blk_k)
+    return Selection(by_q, by_k, block_pairs, candidates)
+
+
+def index_select(q_idx, k_idx, w_idx, segment_ids, topk: int,
+                 impl: str = "auto") -> Selection:
+    """q_idx (B, S, J, d), k_idx (B, S, d), w_idx (B, S, J) float32: the
+    index's queries, its one key head and its head weights, rotated and
+    scaled; segment_ids (B, S), the packing contract's. -> Selection (the
+    module docstring): integers, made from detached inputs.
+    `impl`: "xla" keeps the chunk's passes in plain XLA, anything else
+    takes the kernels of ops/pallas/sparse_index.py where they run
+    (`_use_kernels`)."""
+    q_idx, k_idx, w_idx = (jax.lax.stop_gradient(x)
+                           for x in (q_idx, k_idx, w_idx))
+    rows = [_select_row(q_idx[b], k_idx[b], w_idx[b], segment_ids[b], topk,
+                        impl) for b in range(q_idx.shape[0])]
+    return Selection(
+        checkpoint_name(jnp.stack([r.by_q for r in rows]), "dsa_select"),
+        checkpoint_name(jnp.stack([r.by_k for r in rows]), "dsa_select"),
+        sum(r.block_pairs for r in rows), sum(r.candidates for r in rows))
+
+
+def _kl_row(q_idx, k_idx, w_idx, q, k, lse, by_q, impl: str,
+            with_grads: bool):
+    """One row's KL sum, and with `with_grads` its gradients with respect to
+    q_idx, k_idx and w_idx; lse (H, S), by_q (W, S, blk_k)."""
+    s = k_idx.shape[0]
+    blk_q, blk_k, _, _ = select_blocks(s)
+    nq = s // blk_q
+    fused, interpret = _use_kernels(impl, blk_q, blk_k, q_idx.shape[-1],
+                                    q.shape[-1])
+
+    def chunk(carry, inputs):
+        dk_idx, kl_sum = carry
+        i, qi, wi, qm, lse_c, words = inputs
         with jax.named_scope("attention/indexer_loss"):
-            p = (kernels.mean_probs(i, qm, k, by_q, blk_k, interpret)
-                 if fused else mean_probs(qm, k, sel))
+            sel = unpack_select(words[None], s)[0]
+            if fused:
+                scores = kernels.index_scores(i, qi, k_idx, wi, blk_k,
+                                              interpret)
+                p = kernels.mean_probs(i, qm, k, lse_c, words, blk_k,
+                                       interpret)
+            else:
+                scores, products = index_scores(qi, k_idx, wi, products=True)
+                p = mean_probs(qm, k, sel, lse_c)
             log_pi = jnp.where(sel, scores, NEG_INF)
             log_pi = log_pi - jax.nn.logsumexp(log_pi, axis=-1, keepdims=True)
             live = sel & (p > 0.0)
@@ -255,76 +323,61 @@ def _row(q_idx, k_idx, w_idx, q, k, seg, topk: int, impl: str,
                                                      g))
                 dk_idx = dk_idx + dki
                 grads = (dqi, dwi)
-        return (by_k, dk_idx, block_pairs, candidates, kl_sum), (by_q, grads)
+        return (dk_idx, kl_sum), grads
 
-    init = (jnp.zeros((wq, blk_q, s), jnp.int32),
-            jnp.zeros(k_idx.shape, jnp.float32),
-            jnp.zeros((nk,), jnp.int32), jnp.zeros((2,), jnp.int32),
-            jnp.zeros([], jnp.float32))
-    chunks = lambda x: x.reshape((nq, blk_q) + x.shape[1:])  # noqa: E731
-    sums, (by_q, grads) = jax.lax.scan(
-        chunk, init, (jnp.arange(nq, dtype=jnp.int32), chunks(q_idx),
-                      chunks(w_idx), chunks(q), chunks(seg)))
-    by_k, dk_idx, block_pairs, candidates, kl_sum = sums
-    # (nq, W, blk_q, blk_k) -> (W, S, blk_k)
-    by_q = by_q.transpose(1, 0, 2, 3).reshape(-1, s, blk_k)
-    out = Selection(by_q, by_k, kl_sum, block_pairs, candidates)
+    (dk_idx, kl_sum), grads = jax.lax.scan(
+        chunk, (jnp.zeros(k_idx.shape, jnp.float32),
+                jnp.zeros([], jnp.float32)),
+        (jnp.arange(nq, dtype=jnp.int32), _chunks(q_idx, nq),
+         _chunks(w_idx, nq), _chunks(q, nq), _chunks(lse, nq, 1),
+         _chunks(by_q, nq, 1)))
     if not with_grads:
-        return out, ()
+        return kl_sum, ()
     dq_idx, dw_idx = grads
-    return out, (dq_idx.reshape(q_idx.shape).astype(q_idx.dtype),
-                 dk_idx.astype(k_idx.dtype),
-                 dw_idx.reshape(w_idx.shape).astype(w_idx.dtype))
+    return kl_sum, (dq_idx.reshape(q_idx.shape).astype(q_idx.dtype),
+                    dk_idx.astype(k_idx.dtype),
+                    dw_idx.reshape(w_idx.shape).astype(w_idx.dtype))
 
 
-def _rows(q_idx, k_idx, w_idx, q, k, segment_ids, topk, impl, with_grads):
-    """`_row` over the batch: the packed words stacked, the sums added."""
-    outs = [_row(q_idx[b], k_idx[b], w_idx[b], q[b], k[b], segment_ids[b],
-                 topk, impl, with_grads) for b in range(q.shape[0])]
-    sels, grads = zip(*outs)
-    out = Selection(jnp.stack([o.by_q for o in sels]),
-                    jnp.stack([o.by_k for o in sels]),
-                    *(sum(getattr(o, f) for o in sels)
-                      for f in Selection._fields[2:]))
-    return out, tuple(jnp.stack(g) for g in zip(*grads))
+def _kl_rows(q_idx, k_idx, w_idx, q, k, lse, by_q, impl, with_grads):
+    """`_kl_row` over the batch: the sums added, the gradients stacked."""
+    sums, grads = zip(*(
+        _kl_row(q_idx[b], k_idx[b], w_idx[b], q[b], k[b], lse[b], by_q[b],
+                impl, with_grads) for b in range(q.shape[0])))
+    return sum(sums), tuple(jnp.stack(g) for g in zip(*grads))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def index_select_loss(q_idx, k_idx, w_idx, q, k, segment_ids, topk: int,
-                      impl: str = "auto") -> Selection:
-    """q_idx (B, S, J, d), k_idx (B, S, d), w_idx (B, S, J) float32: the
-    index's queries, its one key head and its head weights, rotated and
-    scaled; q (B, S, H, D), k (B, S, Hkv, D): the main attention's, as its
-    kernels read them (data here: no gradient reaches them); segment_ids
-    (B, S), the packing contract's. -> Selection (the module docstring).
-    Differentiable in q_idx, k_idx and w_idx through `kl_sum` alone.
-    `impl`: "xla" keeps the chunk's passes in plain XLA, anything else
-    takes the kernels of ops/pallas/sparse_index.py where they run
-    (`_use_kernels`)."""
-    return _rows(q_idx, k_idx, w_idx, q, k, segment_ids, topk, impl,
-                 False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def index_kl(q_idx, k_idx, w_idx, q, k, lse, by_q,
+             impl: str = "auto") -> jax.Array:
+    """sum over the real tokens t of KL_t (the module docstring), () float32.
+    q_idx, k_idx, w_idx: as `index_select` took them; q (B, S, H, D),
+    k (B, S, Hkv, D): the main attention's, as its kernels read them;
+    lse (B, H, S) float32: its log-sum-exp over each query's selected keys
+    (`dot_product_attention(..., select=, with_lse=True)`); by_q: the
+    selection's. Differentiable in q_idx, k_idx and w_idx; the rest is data
+    (no gradient reaches it). `impl`: as `index_select`'s."""
+    return _kl_rows(q_idx, k_idx, w_idx, q, k, lse, by_q, impl, False)[0]
 
 
-def _fwd(q_idx, k_idx, w_idx, q, k, segment_ids, topk, impl):
-    out, grads = _rows(q_idx, k_idx, w_idx, q, k, segment_ids, topk, impl,
-                       True)
-    out = out._replace(by_q=checkpoint_name(out.by_q, "dsa_select"),
-                       by_k=checkpoint_name(out.by_k, "dsa_select"))
+def _kl_fwd(q_idx, k_idx, w_idx, q, k, lse, by_q, impl):
+    kl_sum, grads = _kl_rows(q_idx, k_idx, w_idx, q, k, lse, by_q, impl,
+                             True)
     grads = tuple(checkpoint_name(g, "dsa_kl_grads") for g in grads)
-    return out, (grads, q, k, segment_ids)
+    return kl_sum, (grads, q, k, lse, by_q)
 
 
-def _bwd(topk, impl, saved, cts):
-    (dq_idx, dk_idx, dw_idx), q, k, segment_ids = saved
-    g = cts.kl_sum
+def _kl_bwd(impl, saved, g):
+    (dq_idx, dk_idx, dw_idx), q, k, lse, by_q = saved
     with jax.named_scope("attention/indexer_loss"):
         scaled = tuple((g * d.astype(jnp.float32)).astype(d.dtype)
                        for d in (dq_idx, dk_idx, dw_idx))
     return scaled + (jnp.zeros_like(q), jnp.zeros_like(k),
-                     jax.custom_derivatives.zero_from_primal(segment_ids))
+                     jnp.zeros_like(lse),
+                     jax.custom_derivatives.zero_from_primal(by_q))
 
 
-index_select_loss.defvjp(_fwd, _bwd)
+index_kl.defvjp(_kl_fwd, _kl_bwd)
 
 
 def full_row_selected_pairs(seq_len: int, topk: int) -> int:

@@ -1,6 +1,7 @@
 """The keye family (models/keye.py; ops/sparse_index.py: index scores, the
-exact selection of each query's best keys, the KL term and its gradient
-rule; the flash kernels under a selection, `flash_sel_*`; ops/moe.py's
+exact selection of each query's best keys, then, after the main attention,
+the KL term on that attention's own log-sum-exp and its gradient rule; the
+flash kernels under a selection, `flash_sel_*`; ops/moe.py's
 softmax router at top-8 of 128) against its plain reference
 (benchmark/reference/keye_ref.py), on the CPU at toy widths with seeded
 weights and a selection of 12 keys on rows of 96 that pack documents both
@@ -266,6 +267,32 @@ def _index_inputs(b, s, heads=8, kv=1, d=16, j=4, di=8, seed=0):
             jax.random.normal(keys[5], (b, s, kv, d)))
 
 
+def _layer_order(q_idx, k_idx, w_idx, q, k, v, seg, topk, impl="auto"):
+    """The three calls of models/keye.Attention, in its order: (the
+    selection, the context, the log-sum-exp, the KL sum)."""
+    picked = sparse_index.index_select(q_idx, k_idx, w_idx, seg, topk, impl)
+    ctx, lse = dot_product_attention(
+        q, k, v, segment_ids=seg, impl=impl, causal=True,
+        select=(picked.by_q, picked.by_k), with_lse=True)
+    return picked, ctx, lse, sparse_index.index_kl(
+        q_idx, k_idx, w_idx, q, k, lse, picked.by_q, impl)
+
+
+def _dense_kl(q_idx, k_idx, w_idx, q, k, dense):
+    """sum_t KL_t of one row written out over the whole (S, S) matrix:
+    `dense` the selected pairs, the target's softmax taken here."""
+    h, hkv = q.shape[1], k.shape[1]
+    scores = sparse_index.index_scores(q_idx, k_idx, w_idx)
+    s = jnp.einsum("qhd,khd->hqk", q, jnp.repeat(k, h // hkv, axis=1),
+                   preferred_element_type=jnp.float32) / q.shape[-1] ** 0.5
+    p = jnp.where(dense, jax.nn.softmax(jnp.where(dense, s, -1e30), -1),
+                  0.0).mean(0)
+    log_pi = jax.nn.log_softmax(jnp.where(dense, scores, -1e30), -1)
+    live = dense & (p > 0)
+    return jnp.sum(jnp.where(
+        live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_pi), 0.0))
+
+
 # rows of 384 = three tiles of 128: boundaries inside a tile (40, 300, 350),
 # documents shorter (40, 30) and longer (260, 50, 384) than the 48 selected
 CUTS_384 = [[0, 40, 300, 350, 380], [0, 384]]
@@ -278,9 +305,9 @@ def test_every_query_selects_its_best_keys_exactly(small_blocks, k):
     top-k says which); both packings say the same; the counters add up."""
     k = 48
     _, seg, pos = _packed(2, 384, cuts=CUTS_384)
-    q_idx, k_idx, w_idx, q, kk, _ = _index_inputs(2, 384)
-    picked = jax.jit(sparse_index.index_select_loss, static_argnums=(6,))(
-        q_idx, k_idx, w_idx, q, kk, jnp.asarray(seg), k)
+    q_idx, k_idx, w_idx, _, _, _ = _index_inputs(2, 384)
+    picked = jax.jit(sparse_index.index_select, static_argnums=(4,))(
+        q_idx, k_idx, w_idx, jnp.asarray(seg), k)
     assert picked.by_q.shape == (2, 1, 384, 128)
     assert picked.by_k.shape == (2, 1, 128, 384)
     dense = np.asarray(unpack_select(picked.by_q))
@@ -304,7 +331,12 @@ def test_every_query_selects_its_best_keys_exactly(small_blocks, k):
     np.testing.assert_array_equal(
         np.asarray(picked.block_pairs),
         dense.reshape(2, 384, 3, 128).sum(axis=(0, 1, 3)))
-    assert float(picked.kl_sum) > 0
+    # and the KL pass's own unpacking of a chunk's words is the dense one
+    for i in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(unpack_select(
+                picked.by_q[:, :, i * 128:(i + 1) * 128], 384)),
+            dense[:, i * 128:(i + 1) * 128])
 
 
 def test_equal_scores_go_to_the_lower_key():
@@ -347,41 +379,58 @@ def test_the_kth_largest_by_bisection_is_the_sorts(k):
     assert not np.asarray(sparse_index.kth_largest(few, k)).any()
 
 
-def test_the_kl_terms_rule_is_the_gradient_of_its_forward(small_blocks):
-    """ops/sparse_index.py's custom rule (the gradients taken in the forward
-    pass) against jax.grad of the same mathematics written plainly, and
-    against a finite difference along one direction."""
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_the_kl_terms_rule_is_the_gradient_of_its_forward(small_blocks, impl,
+                                                          monkeypatch):
+    """The layer's order (selection, main attention, then `index_kl` on the
+    attention's log-sum-exp) and `index_kl`'s custom rule (the gradients
+    taken in the forward pass): `kl_sum` and its gradients with respect to
+    the indexer's three outputs against jax.grad of the KL written out
+    densely, which takes its own softmax; in plain XLA and with every kernel
+    of the layer (`flash_sel_fwd`, `dsa_index_fwd`, `dsa_probs`,
+    `dsa_index_bwd`) in interpret mode."""
+    if impl == "interpret":
+        monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
     k = 48
     _, seg, _ = _packed(1, 384, cuts=CUTS_384)
     seg = jnp.asarray(seg)
-    q_idx, k_idx, w_idx, q, kk, _ = _index_inputs(1, 384, seed=3)
+    q_idx, k_idx, w_idx, q, kk, v = _index_inputs(1, 384, heads=4, kv=2,
+                                                  d=128, j=2, di=64, seed=3)
+    impl = "xla" if impl == "xla" else "auto"
 
     def rule(q_idx, k_idx, w_idx):
-        return sparse_index.index_select_loss(q_idx, k_idx, w_idx, q, kk,
-                                              seg, k).kl_sum
+        return _layer_order(q_idx, k_idx, w_idx, q, kk, v, seg, k, impl)[3]
 
-    dense = unpack_select(sparse_index.index_select_loss(
-        q_idx, k_idx, w_idx, q, kk, seg, k).by_q)[0]
+    text = str(jax.make_jaxpr(rule)(q_idx, k_idx, w_idx))
+    assert ("dsa_probs" in text) == ("flash_sel_fwd" in text) == (
+        impl == "auto")
+    dense = unpack_select(sparse_index.index_select(
+        q_idx, k_idx, w_idx, seg, k, impl).by_q)[0]
 
     def plain(q_idx, k_idx, w_idx):
-        scores = sparse_index.index_scores(q_idx[0], k_idx[0], w_idx[0])
-        p = sparse_index.mean_probs(q[0], kk[0], dense)
-        log_pi = jax.nn.log_softmax(jnp.where(dense, scores, -1e30), -1)
-        live = dense & (p > 0)
-        return jnp.sum(jnp.where(
-            live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_pi), 0.0))
+        return _dense_kl(q_idx[0], k_idx[0], w_idx[0], q[0], kk[0], dense)
 
     value, grads = jax.jit(jax.value_and_grad(rule, argnums=(0, 1, 2)))(
         q_idx, k_idx, w_idx)
     want_value, want = jax.jit(jax.value_and_grad(
         plain, argnums=(0, 1, 2)))(q_idx, k_idx, w_idx)
     assert float(value) == pytest.approx(float(want_value), rel=1e-5)
+    assert float(value) > 0
     for got, w in zip(grads, want):
         assert _rel(got, w) < 1e-4
     # scaled by the cotangent
     doubled = jax.grad(lambda *a: 2.0 * rule(*a), argnums=2)(
         q_idx, k_idx, w_idx)
     np.testing.assert_allclose(doubled, 2.0 * grads[2], rtol=1e-6)
+    # q, k and the log-sum-exp are data: no gradient reaches them
+    picked = sparse_index.index_select(q_idx, k_idx, w_idx, seg, k, impl)
+    lse = dot_product_attention(
+        q, kk, v, segment_ids=seg, impl=impl, causal=True,
+        select=(picked.by_q, picked.by_k), with_lse=True)[1]
+    for g in jax.grad(lambda q, kk, lse: sparse_index.index_kl(
+            q_idx, k_idx, w_idx, q, kk, lse, picked.by_q, impl),
+            argnums=(0, 1, 2))(q, kk, lse):
+        assert not np.asarray(g).any()
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -395,8 +444,7 @@ def test_documents_no_longer_than_the_selection_attend_plainly(
     _, seg, _ = _packed(1, 256, cuts=cuts)
     seg = jnp.asarray(seg)
     q_idx, k_idx, w_idx, q, kk, v = _index_inputs(1, 256, seed=5)
-    picked = sparse_index.index_select_loss(q_idx, k_idx, w_idx, q, kk, seg,
-                                            70)
+    picked = sparse_index.index_select(q_idx, k_idx, w_idx, seg, 70)
     assert int(picked.block_pairs.sum()) == _candidates(picked)
     attend = jax.jit(lambda **kw: dot_product_attention(
         q, kk, v, segment_ids=seg, impl=impl, causal=True, **kw))
@@ -419,8 +467,7 @@ def test_flash_select_kernels_against_the_xla_path(small_blocks,
     seg = jnp.asarray(seg)
     q_idx, k_idx, w_idx, q, kk, v = _index_inputs(2, s, heads=8, kv=1, d=d,
                                                   seed=8)
-    picked = sparse_index.index_select_loss(q_idx, k_idx, w_idx, q, kk, seg,
-                                            48)
+    picked = sparse_index.index_select(q_idx, k_idx, w_idx, seg, 48)
     select = (picked.by_q, picked.by_k)
     weight = jax.random.normal(jax.random.PRNGKey(9), q.shape) * (
         seg > 0)[:, :, None, None]             # no loss term reads padding
@@ -457,42 +504,132 @@ def test_flash_select_kernels_against_the_xla_path(small_blocks,
 
 def test_index_kernels_against_their_plain_forms(small_blocks, monkeypatch):
     """ops/pallas/sparse_index.py's kernels in interpret mode, through
-    `index_select_loss` as the layer calls it (index heads of 64, main heads
-    of 128: the shapes the kernels take): the same selection to the bit,
-    the KL sum and all three gradients against the plain-XLA passes; rows
-    with document boundaries inside a tile and a padded tail."""
+    `index_select`, the main attention and `index_kl` as the layer calls
+    them (index heads of 64, main heads of 128: the shapes the kernels
+    take): the same selection to the bit, the KL sum and all three gradients
+    against the plain-XLA passes; rows with document boundaries inside a
+    tile and a padded tail."""
     _, seg, _ = _packed(2, 384, cuts=CUTS_384)
     seg = jnp.asarray(seg)
-    q_idx, k_idx, w_idx, q, kk, _ = _index_inputs(2, 384, heads=4, kv=2,
+    q_idx, k_idx, w_idx, q, kk, v = _index_inputs(2, 384, heads=4, kv=2,
                                                   d=128, j=2, di=64, seed=4)
 
     def run(impl):
         def f(a, b, c):
-            return sparse_index.index_select_loss(a, b, c, q, kk, seg, 48,
-                                                  impl)
-        grads = jax.jit(jax.grad(lambda *a: f(*a).kl_sum,
-                                 argnums=(0, 1, 2)))(q_idx, k_idx, w_idx)
-        return jax.jit(f)(q_idx, k_idx, w_idx), grads
+            picked, _, _, kl_sum = _layer_order(a, b, c, q, kk, v, seg, 48,
+                                                impl)
+            return kl_sum, picked
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))(q_idx, k_idx, w_idx)
 
-    want, want_grads = run("xla")
-    assert "dsa_probs" not in str(jax.make_jaxpr(
-        lambda: sparse_index.index_select_loss(
-            q_idx, k_idx, w_idx, q, kk, seg, 48))())    # no TPU, no kernels
+    traced = lambda: str(jax.make_jaxpr(lambda: _layer_order(  # noqa: E731
+        q_idx, k_idx, w_idx, q, kk, v, seg, 48))())
+    (want_kl, want), want_grads = run("xla")
+    assert "dsa_probs" not in traced()                  # no TPU, no kernels
     monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
-    text = str(jax.make_jaxpr(lambda: sparse_index.index_select_loss(
-        q_idx, k_idx, w_idx, q, kk, seg, 48))())
-    assert "dsa_index_fwd" in text and "dsa_probs" in text
-    got, got_grads = run("auto")
+    text = traced()
+    # the scores once for the selection and once more for the KL term
+    assert text.count("name=dsa_index_fwd") == 2 * 2
+    assert text.count("name=dsa_probs") == 2
+    (got_kl, got), got_grads = run("auto")
     np.testing.assert_array_equal(np.asarray(got.by_q),
                                   np.asarray(want.by_q))
     np.testing.assert_array_equal(np.asarray(got.by_k),
                                   np.asarray(want.by_k))
-    assert float(got.kl_sum) == pytest.approx(float(want.kl_sum), rel=1e-5)
+    assert float(got_kl) == pytest.approx(float(want_kl), rel=1e-5)
     for a, w in zip(got_grads, want_grads):
         assert _rel(a, w) < 1e-5
     # toy widths (index heads of 8) stay in plain XLA
     from bert_pytorch_tpu.ops.pallas import sparse_index as ker
     assert not ker.supported(128, 128, 8, 16)
+
+
+@pytest.mark.parametrize("chunk", [0, 1, 2])
+def test_one_walk_probs_on_the_forward_kernels_log_sum_exp(
+        small_blocks, chunk, monkeypatch):
+    """`dsa_probs` in interpret mode, fed the log-sum-exp that
+    `flash_select_attention` hands out for the same q, k and selection,
+    against the plain-XLA `mean_probs`, which takes its own softmax: tiles
+    of 128, two documents in a chunk (boundaries at 40, 300, 350), a padded
+    tail (380..383: rows that select nothing read zeros), key blocks after
+    the chunk's own zeros. ONE walk: the call's grid has one axis, and no
+    scratch carries a normaliser from step to step."""
+    from bert_pytorch_tpu.ops.pallas import sparse_index as ker
+
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    fa = small_blocks
+    _, seg, _ = _packed(2, 384, cuts=CUTS_384)
+    seg = jnp.asarray(seg)
+    q_idx, k_idx, w_idx, q, kk, v = _index_inputs(2, 384, heads=4, kv=2,
+                                                  d=128, j=2, di=64, seed=6)
+    picked = sparse_index.index_select(q_idx, k_idx, w_idx, seg, 48)
+    _, lse = fa.flash_select_attention(q, kk, v, seg, picked.by_q,
+                                       picked.by_k, True)
+    assert lse.shape == (2, 4, 384) and lse.dtype == jnp.float32
+    dense = unpack_select(picked.by_q)
+    rows = slice(chunk * 128, (chunk + 1) * 128)
+    for b in range(2):
+        got = ker.mean_probs(jnp.int32(chunk), q[b, rows], kk[b],
+                             lse[b][:, rows], picked.by_q[b][:, rows], 128,
+                             True)
+        want = sparse_index.mean_probs(q[b, rows], kk[b], dense[b, rows])
+        np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-5)
+        assert not np.asarray(got)[:, (chunk + 1) * 128:].any()
+        real = np.asarray(seg[b, rows]) > 0
+        np.testing.assert_allclose(np.asarray(got).sum(-1), real, atol=1e-5)
+        # the XLA form on the same log-sum-exp is the same numbers
+        np.testing.assert_allclose(
+            sparse_index.mean_probs(q[b, rows], kk[b], dense[b, rows],
+                                    lse[b][:, rows]), want, atol=1e-6,
+            rtol=1e-5)
+    jaxpr = jax.make_jaxpr(lambda: ker.mean_probs(
+        jnp.int32(chunk), q[0, rows], kk[0], lse[0][:, rows],
+        picked.by_q[0][:, rows], 128, True))()
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "dsa_probs"
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (3,) and mapping.num_scratch_operands == 0
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_attention_hands_out_its_log_sum_exp_only_with_a_selection(
+        small_blocks, impl, monkeypatch):
+    """`dot_product_attention(..., with_lse=True)`: a pair (context, lse
+    (B, H, S) float32) with `select=`, the kernel's residual or the XLA
+    path's `logsumexp` (equal on the real rows); refused without a
+    selection; a bare array wherever it is not asked for, so that no other
+    family's call changes."""
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    _, seg, _ = _packed(2, 384, cuts=CUTS_384)
+    seg = jnp.asarray(seg)
+    q_idx, k_idx, w_idx, q, kk, v = _index_inputs(2, 384, heads=8, kv=1,
+                                                  d=64, seed=8)
+    picked = sparse_index.index_select(q_idx, k_idx, w_idx, seg, 48)
+    select = (picked.by_q, picked.by_k)
+    attend = lambda **kw: dot_product_attention(  # noqa: E731
+        q, kk, v, segment_ids=seg, impl=impl, causal=True, **kw)
+    bare = attend(select=select)
+    assert isinstance(bare, jax.Array) and bare.shape == q.shape
+    assert isinstance(attend(), jax.Array)
+    ctx, lse = attend(select=select, with_lse=True)
+    np.testing.assert_array_equal(np.asarray(ctx), np.asarray(bare))
+    assert lse.shape == (2, 8, 384) and lse.dtype == jnp.float32
+    with pytest.raises(ValueError, match="with_lse=True .* needs select="):
+        attend(with_lse=True)
+    # the log-sum-exp of the scaled scores over each query's selected keys
+    dense = unpack_select(picked.by_q)
+    s = jnp.einsum("bqhd,bkd->bhqk", q, kk[:, :, 0]) / 8.0
+    want = jax.nn.logsumexp(jnp.where(dense[:, None], s, -1e30), axis=-1)
+    real = np.broadcast_to((np.asarray(seg) > 0)[:, None, :], lse.shape)
+    np.testing.assert_allclose(np.asarray(lse)[real],
+                               np.asarray(want)[real], atol=2e-5)
+    if impl == "pallas":
+        # the kernel's residual is data, not a second differentiable
+        # output: a cotangent given to it is dropped
+        grad = jax.grad(lambda q: dot_product_attention(
+            q, kk, v, segment_ids=seg, impl=impl, causal=True, select=select,
+            with_lse=True)[1].sum())(q)
+        assert not np.asarray(grad).any()
 
 
 def _mrope_angles(positions, inv_freq, section):

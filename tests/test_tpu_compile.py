@@ -203,14 +203,20 @@ def test_flash_select_kernels_compile_at_the_keye_cell(v5e):
 
     def bwd(q, k, v, seg, by_q, by_k):
         return jax.grad(lambda q, k, v: fa.flash_select_attention(
-            q, k, v, seg, by_q, by_k, False).astype(jnp.float32).sum(),
+            q, k, v, seg, by_q, by_k, False)[0].astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    text = jax.jit(bwd).lower(
-        sds((b, s, h, d), jnp.bfloat16), sds((b, s, hkv, d), jnp.bfloat16),
-        sds((b, s, hkv, d), jnp.bfloat16), sds((b, s), jnp.int32),
-        sds((b, wk, s, blk_k), jnp.int32),
-        sds((b, wq, blk_q, s), jnp.int32)).compile().as_text()
+    args = (sds((b, s, h, d), jnp.bfloat16), sds((b, s, hkv, d), jnp.bfloat16),
+            sds((b, s, hkv, d), jnp.bfloat16), sds((b, s), jnp.int32),
+            sds((b, wk, s, blk_k), jnp.int32),
+            sds((b, wq, blk_q, s), jnp.int32))
+    fwd = jax.jit(lambda *a: fa.flash_select_attention(*a, False)).lower(
+        *args).compile()
+    assert hlo.kernel_counts(fwd.as_text()) == {"flash_sel_fwd": 1}
+    ctx, lse = fwd.out_info
+    assert (ctx.shape, lse.shape, lse.dtype) == ((b, s, h, d), (b, h, s),
+                                                 jnp.float32)
+    text = jax.jit(bwd).lower(*args).compile().as_text()
     assert hlo.kernel_counts(text) == {
         fa._kernel_name(n, d, d, 0, True): 1 for n in SPLIT}
     assert sorted(hlo.kernel_counts(text)) == [
@@ -224,7 +230,9 @@ def test_index_kernels_compile_at_the_keye_cell(v5e):
     32 query heads of 128 on 4 key/value heads; the chunk's number as a
     prefetched scalar that clamps the key blocks' index maps; the rolled
     loops over the heads with dynamic first-axis indices; the lane-dense
-    per-token vectors turned into columns."""
+    per-token vectors (the index's weights, the main attention's
+    log-sum-exp, 32 rows of 512) turned into columns. `dsa_probs` is the
+    one-walk form: a grid of the 32 key blocks alone."""
     from bert_pytorch_tpu.ops.pallas import sparse_index as ker
 
     c, s, j, di, h, hkv, d, blk = 512, 16384, 16, 64, 32, 4, 128, 512
@@ -239,10 +247,49 @@ def test_index_kernels_compile_at_the_keye_cell(v5e):
     assert _kernels(lambda i, q, k, w, g: ker.index_scores_grads(
         i, q, k, w, g, blk, False), i, q_idx, k_idx, w_idx, scores) == {
             "dsa_index_bwd": 1}
-    assert _kernels(lambda i, q, k, words: ker.mean_probs(
-        i, q, k, words, blk, False), i, sds((c, h, d), bf),
-        sds((s, hkv, d), bf), sds((1, c, blk), jnp.int32)) == {
-            "dsa_probs": 1}
+    probs = (lambda i, q, k, lse, words: ker.mean_probs(  # noqa: E731
+        i, q, k, lse, words, blk, False))
+    probs_args = (i, sds((c, h, d), bf), sds((s, hkv, d), bf),
+                  sds((h, c), jnp.float32), sds((1, c, blk), jnp.int32))
+    assert _kernels(probs, *probs_args) == {"dsa_probs": 1}
+    call, = [e for e in jax.make_jaxpr(probs)(*probs_args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (s // blk,)
+
+
+def test_kl_pass_compiles_at_the_keye_cell(v5e, monkeypatch):
+    """One row of `ops/sparse_index.index_kl` with its gradient rule, as a
+    keye layer runs it after the main attention's forward kernel: a scan
+    over the row's 32 chunks whose body holds one `dsa_index_fwd` (the
+    chunk's scores, again), one `dsa_probs` on the chunk's rows of the
+    attention's log-sum-exp, and one `dsa_index_bwd`; nothing of size
+    (S, S) in the program (a row's float32 scores would be 1 GiB)."""
+    import re
+
+    from bert_pytorch_tpu.ops import sparse_index
+
+    # `_use_kernels` asks the live backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s, j, di, h, hkv, d = 1, 16384, 16, 64, 32, 4, 128
+    _, blk_k, wk, _ = fa.select_blocks(s)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+    bf = jnp.bfloat16
+
+    def kl(q_idx, k_idx, w_idx, q, k, lse, by_q):
+        return jax.value_and_grad(
+            lambda *a: sparse_index.index_kl(*a, q, k, lse, by_q),
+            argnums=(0, 1, 2))(q_idx, k_idx, w_idx)
+
+    compiled = jax.jit(kl).lower(
+        sds((b, s, j, di), bf), sds((b, s, di), bf),
+        sds((b, s, j), jnp.float32), sds((b, s, h, d), bf),
+        sds((b, s, hkv, d), bf), sds((b, h, s), jnp.float32),
+        sds((b, wk, s, blk_k), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert hlo.kernel_counts(text) == {"dsa_index_fwd": 1, "dsa_probs": 1,
+                                       "dsa_index_bwd": 1}
+    assert not re.search(r"\[16384,16384\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 def test_kda_scan_kernels_compile_at_the_kimi_cell(v5e, monkeypatch):
