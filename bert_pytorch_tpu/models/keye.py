@@ -64,7 +64,11 @@ nothing to L_I; it is routed like any token.
 
 Norms, the router, rotary, both softmaxes, the index scores' sum over heads
 and the losses are float32; matrix products (the indexer's among them) take
-`dtype` operands (bfloat16) and accumulate in float32.
+`dtype` operands (bfloat16) and accumulate in float32. The rotation of q
+and k (ops/decoder_ops.rotary) takes the head norms' float32 and hands on
+`dtype`: at heads of 128 on a TPU one kernel call a direction
+(ops/pallas/rotary.py: `rotary_fwd` / `rotary_bwd`), plain jax.numpy
+everywhere else and for the index heads of 64.
 
 Layers are separate modules in a Python loop, each rematerialised under
 `checkpoint_activations` (`remat_policy`: REMAT_POLICIES; "dense" keeps
@@ -165,8 +169,8 @@ class Attention(nn.Module):
         q = RMSNorm(cfg.norm_eps, jnp.float32, name="q_norm")(q)
         k = RMSNorm(cfg.norm_eps, jnp.float32, name="k_norm")(k)
         with jax.named_scope("rotary"):
-            q = rotary(q, position_ids, cfg.rope_theta).astype(self.dtype)
-            k = rotary(k, position_ids, cfg.rope_theta).astype(self.dtype)
+            q, k = (rotary(u, position_ids, cfg.rope_theta,
+                           out_dtype=self.dtype) for u in (q, k))
 
         index_kernels = [
             self.param(f"index_{n}_proj", _init(cfg), (e, width), jnp.float32)

@@ -57,7 +57,11 @@ A padding slot (segment 0) attends nowhere; it is routed like any token.
 
 Norms, the router, rotary, attention's softmax, the gate's sigmoid and the
 loss are float32; matrix products take `dtype` operands (bfloat16) and
-accumulate in float32.
+accumulate in float32. The rotation (ops/decoder_ops.rotary) reads q's and
+k's heads where the fused projection left them and hands them on in
+`dtype`: at heads of 128 on a TPU one kernel call a direction
+(ops/pallas/rotary.py: `rotary_fwd` / `rotary_bwd`, float32 in VMEM), plain
+jax.numpy everywhere else.
 
 Layers are separate modules in a Python loop (their kind, head count and
 table are static), each rematerialised under `checkpoint_activations`
@@ -124,14 +128,13 @@ class Attention(nn.Module):
                 jnp.concatenate(kernels, axis=1).astype(self.dtype),
                 preferred_element_type=jnp.float32).astype(self.dtype)
             qkv = checkpoint_name(qkv, "in_proj_out")
-            q, k, v = jnp.split(qkv, [h * d, (h + hkv) * d], axis=-1)
-            q = q.reshape(bsz, s, h, d)
-            k = k.reshape(bsz, s, hkv, d)
-            v = v.reshape(bsz, s, hkv, d)
+            v = qkv[..., (h + hkv) * d:].reshape(bsz, s, hkv, d)
         with jax.named_scope("rotary"):
-            q, k = (rotary(u, position_ids, inv_freq=inv_freq,
-                           rotated=rotated, factor=factor).astype(self.dtype)
-                    for u in (q, k))
+            # q's and k's heads, read where the product left them
+            q, k = (rotary(qkv, position_ids, inv_freq=inv_freq,
+                           rotated=rotated, factor=factor,
+                           out_dtype=self.dtype, heads=(first, n, d))
+                    for first, n in ((0, h), (h, hkv)))
         with jax.named_scope(layer):
             ctx = dot_product_attention(
                 q, k, v, segment_ids=segment_ids, impl=cfg.attention_impl,

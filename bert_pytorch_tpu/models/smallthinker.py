@@ -32,7 +32,11 @@ A padding slot (segment 0) attends nowhere; it is routed like any token.
 Norms, the router's logits and the softmax over the selected, rotary,
 attention's softmax and the loss are float32; matrix products take `dtype`
 operands (bfloat16) and accumulate in float32. The router's gradient
-reaches x, not m.
+reaches x, not m. The rotation (ops/decoder_ops.rotary) reads q's and k's
+heads where the fused projection left them and hands them on in `dtype`: at
+heads of 128 on a TPU one kernel call a direction (ops/pallas/rotary.py:
+`rotary_fwd` / `rotary_bwd`, float32 in VMEM), plain jax.numpy everywhere
+else.
 
 Layers are separate modules in a Python loop (their window and positions are
 static, so one scan does not carry them), each rematerialised under
@@ -43,8 +47,10 @@ block of tokens at a time.
 
 Scopes: a layer's attention is `attention/attention_window` or
 `attention/attention_full` (both under `attention`, so that what reads the
-one reads both kinds), the experts `moe/router|dispatch|experts|combine`
-(ops/moe.py), `rmsnorm`, `lm_head`, `loss`.
+one reads both kinds), a banded layer's rotation
+`attention/attention_window/rotary`, the experts
+`moe/router|dispatch|experts|combine` (ops/moe.py), `rmsnorm`, `lm_head`,
+`loss`.
 """
 
 from __future__ import annotations
@@ -96,13 +102,14 @@ class Attention(nn.Module):
                 jnp.concatenate(kernels, axis=1).astype(self.dtype),
                 preferred_element_type=jnp.float32).astype(self.dtype)
             qkv = checkpoint_name(qkv, "in_proj_out")
-            q, k, v = jnp.split(qkv, [h * d, (h + hkv) * d], axis=-1)
-            q = q.reshape(bsz, s, h, d)
-            k = k.reshape(bsz, s, hkv, d)
-            v = v.reshape(bsz, s, hkv, d)
+            q, k, v = (u.reshape(bsz, s, -1, d) for u in jnp.split(
+                qkv, [h * d, (h + hkv) * d], axis=-1))
             if self.rope:
-                q = rotary(q, position_ids, cfg.rope_theta).astype(self.dtype)
-                k = rotary(k, position_ids, cfg.rope_theta).astype(self.dtype)
+                with jax.named_scope("rotary"):
+                    # q's and k's heads, read where the product left them
+                    q, k = (rotary(qkv, position_ids, cfg.rope_theta,
+                                   out_dtype=self.dtype, heads=(first, n, d))
+                            for first, n in ((0, h), (h, hkv)))
             ctx = dot_product_attention(
                 q, k, v, segment_ids=segment_ids, impl=cfg.attention_impl,
                 causal=True, window=self.window or None)

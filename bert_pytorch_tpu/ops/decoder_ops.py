@@ -1,9 +1,11 @@
 """Elementwise pieces of the decoder families (models/lfm2_moe.py): RMSNorm,
 rotary positions (over the whole head or a part of it, at a given table of
 frequencies), and the depthwise causal short convolution. Plain
-jax.numpy in float32 (XLA fuses each into its neighbours); every one is
-per-token or looks back a fixed number of tokens, and none looks across a
-document boundary of a packed row.
+jax.numpy in float32 (XLA fuses each into its neighbours), but for the
+rotation of heads of 128 lanes on a TPU, which is one kernel call a
+direction (ops/pallas/rotary.py; `rotary` says which calls take it); every
+one is per-token or looks back a fixed number of tokens, and none looks
+across a document boundary of a packed row.
 """
 
 from __future__ import annotations
@@ -26,24 +28,83 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float,
     return (y * scale.astype(jnp.float32)).astype(dtype or x.dtype)
 
 
+def _rotary_angles(position_ids, r: int, theta, inv_freq):
+    """position * inv_freq, (B, S, r/2) float32."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32)
+                                    / r))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
+    return position_ids.astype(jnp.float32)[:, :, None] * inv_freq
+
+
+def _rotary_kernel_mode(s: int, d: int):
+    """None where the call takes the plain function; else the `interpret`
+    argument of ops/pallas/rotary.py's kernels (the convention of
+    ops/kda.kernel_mode), by what the call can see: heads of whole 128-lane
+    rows, rows that tile, a TPU backend (or BPT_PALLAS_INTERPRET=1
+    elsewhere), and one device: a mesh cannot split a kernel."""
+    from bert_pytorch_tpu.ops.attention import _pallas_interpret, active_mesh
+    from bert_pytorch_tpu.ops.pallas.rotary import supported
+
+    on_tpu = jax.default_backend() == "tpu"
+    if (not supported(s, d) or not (on_tpu or _pallas_interpret())
+            or active_mesh() is not None):
+        return None
+    return not on_tpu
+
+
 def rotary(x: jax.Array, position_ids: jax.Array, theta: float = None,
-           inv_freq=None, rotated: int = None,
-           factor: float = 1.0) -> jax.Array:
+           inv_freq=None, rotated: int = None, factor: float = 1.0,
+           out_dtype=None, heads: tuple = None) -> jax.Array:
     """Rotary position embedding in the rotate-half convention: x
     (B, S, H, D), position_ids (B, S) (restarting at each document of a
     packed row). The head's first `rotated` dims (default: all of D) turn,
     pair (i, i + rotated/2) by position * inv_freq[i]; the dims after them
     pass. `inv_freq` (rotated/2,) is the given table (`rotary_table`), or
     theta^(-2i/rotated) where none is given; `factor` multiplies cos and
-    sin (YaRN's attention factor). float32 in, float32 out."""
-    d = x.shape[-1]
+    sin (YaRN's attention factor). float32 arithmetic, the result in
+    `out_dtype` (float32 where none is given).
+
+    `heads` (first, H, D): x is a (B, S, W) matrix, a fused projection's
+    output, whose columns from first * D on are the H heads to turn; the
+    result is theirs, (B, S, H, D).
+
+    Heads of whole 128-lane rows on a TPU take one kernel call a direction
+    (ops/pallas/rotary.py: x read once where it lies and as it arrives, the
+    result written once in `out_dtype`, the same products and sums in
+    VMEM); every other call is the plain function below, which is also what
+    the kernels are tested against."""
+    first, h, d = heads or (0,) + x.shape[2:]
     r = d if rotated is None else int(rotated)
-    if inv_freq is None:
-        inv_freq = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32)
-                                    / r))
-    else:
-        inv_freq = jnp.asarray(inv_freq, jnp.float32)
-    angles = position_ids.astype(jnp.float32)[:, :, None] * inv_freq
+    interpret = _rotary_kernel_mode(x.shape[1], d)
+    if interpret is not None:
+        from bert_pytorch_tpu.ops.pallas.rotary import rotate
+
+        angles = _rotary_angles(position_ids, r, theta, inv_freq)
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        if factor != 1.0:
+            cos, sin = cos * factor, sin * factor
+        # the kernels' tables, (B, S, D): cos on the lanes that turn and 1
+        # on those that pass; -sin on the first half of the turning lanes,
+        # +sin on the second, 0 elsewhere
+        lanes = [(0, 0), (0, 0)]
+        c = jnp.pad(jnp.concatenate([cos, cos], -1), lanes + [(0, d - r)],
+                    constant_values=1.0)
+        sa = jnp.pad(-sin, lanes + [(0, d - r // 2)])
+        sb = jnp.pad(sin, lanes + [(r // 2, d - r)])
+        return rotate(x.reshape(x.shape[:2] + (-1,)), c, sa, sb, first, h, r,
+                      jnp.dtype(out_dtype or jnp.float32), interpret)
+    if heads is not None:
+        x = x[..., first * d:(first + h) * d].reshape(x.shape[:2] + (h, d))
+    out = _rotary_plain(x, position_ids, theta, inv_freq, r, factor)
+    return out if out_dtype is None else out.astype(out_dtype)
+
+
+def _rotary_plain(x, position_ids, theta, inv_freq, r: int, factor: float):
+    """`rotary` in plain jax.numpy: float32 in, float32 out."""
+    d = x.shape[-1]
+    angles = _rotary_angles(position_ids, r, theta, inv_freq)
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[:, :, None, :]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[:, :, None, :]
     if factor != 1.0:

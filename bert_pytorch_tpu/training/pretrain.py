@@ -96,8 +96,10 @@ STEP_SUBSCOPES = {
                   "attention_window": _BY_KIND_ATTENTION,
                   "attention_full": _BY_KIND_ATTENTION,
                   # models/laguna.py: the rotation of q and k (a part of the
-                  # head, at a table of its kind) and the per-head gate on
-                  # the context, beside the kind's projections and kernels
+                  # head, at a table of its kind; ops/decoder_ops.rotary, on
+                  # a TPU the kernels of ops/pallas/rotary.py) and the
+                  # per-head gate on the context, beside the kind's
+                  # projections and kernels
                   "rotary": ("laguna", "keye"), "gate": ("laguna",),
                   # models/keye.py and ops/sparse_index.py: the learned
                   # index's projections and scores, the exact selection of
@@ -105,7 +107,9 @@ STEP_SUBSCOPES = {
                   # (forward and backward)
                   "indexer": ("keye",), "select": ("keye",),
                   "indexer_loss": ("keye",)},
-    "attention/attention_window": {"attn_core": _BY_KIND_ATTENTION},
+    # models/smallthinker.py: the banded layers alone rotate q and k
+    "attention/attention_window": {"attn_core": _BY_KIND_ATTENTION,
+                                   "rotary": ("smallthinker",)},
     "attention/attention_full": {"attn_core": _BY_KIND_ATTENTION},
     # models/lfm2_moe.ShortConv: `mix` is what is no projection
     "conv": {"in_proj": ("lfm2_moe",), "mix": ("lfm2_moe",),

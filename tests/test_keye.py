@@ -195,6 +195,39 @@ def test_logits_match_the_reference(toy):
     assert np.asarray(picked[1]).sum(-1).tolist() == [want, want]
 
 
+def test_the_rotation_kernels_match_the_reference(monkeypatch):
+    """Heads of 128 under the test switch: q and k take
+    ops/pallas/rotary.py's kernels (interpret mode) on the float32 the head
+    norms hand them, the index heads of 16 keep the plain function; both
+    loss terms and every gradient leaf against the reference."""
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    wide = dict(TOY, head_dim=128, rope_scaling=dict(
+        TOY["rope_scaling"], mrope_section=[16, 24, 24]))
+    cfg = KeyeConfig.from_dict(wide).replace(
+        dtype="float32", checkpoint_activations=True, attention_impl="xla")
+    sizes = ref.sizes_from_config(wide)
+    params = ref.init_params(SEED, sizes)
+    model = keye.KeyeForCausalLM(cfg, dtype=jnp.float32)
+    batch = {k: jnp.asarray(v) for k, v in zip(
+        ("input_ids", "segment_ids", "position_ids"), _packed())}
+    loss_fn = keye.pretrain_loss_fn_builder(model)
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    text = str(jax.make_jaxpr(grad_fn)(params, batch, None))
+    # 2 layers x (q, k), forward and recomputed; the rule once each
+    assert text.count("name=rotary_fwd") == 8
+    assert text.count("name=rotary_bwd") == 4
+    with jax.default_matmul_precision("highest"):
+        (loss, aux), grads = jax.jit(grad_fn)(params, batch, None)
+    want, want_grads, details = ref.step_loss_and_grad(
+        params, [batch], sizes, None, 0.01, 0.01)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    assert float(aux["means"]["indexer_kl"]) == pytest.approx(
+        float(details["indexer_kl"]), rel=2e-5)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref_leaf in zip(flat, jax.tree.leaves(want_grads)):
+        assert _rel(got, ref_leaf) < 3e-5, jax.tree_util.keystr(path)
+
+
 def test_both_loss_terms_gradients_and_counts_match_the_reference(toy):
     cfg, sizes, params, model, batch = toy
     loss_fn = keye.pretrain_loss_fn_builder(model)

@@ -171,6 +171,37 @@ def test_loss_gradients_and_counts_match_the_reference(toy):
             assert _rel(got, ref_leaf) < 2e-5, name
 
 
+def test_the_rotation_kernels_match_the_reference(monkeypatch):
+    """Heads of 128 under the test switch: q and k of both kinds of layer
+    take ops/pallas/rotary.py's kernels (interpret mode), whole head and
+    half a head under YaRN, read from the fused projection; loss and every
+    gradient leaf against the reference."""
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    wide = dict(TOY, head_dim=128)
+    cfg = LagunaConfig.from_dict(wide).replace(
+        dtype="float32", checkpoint_activations=True, attention_impl="xla")
+    sizes = ref.sizes_from_config(wide)
+    params = ref.init_params(SEED, sizes)
+    model = laguna.LagunaForCausalLM(cfg, dtype=jnp.float32)
+    batch = {k: jnp.asarray(v) for k, v in zip(
+        ("input_ids", "segment_ids", "position_ids"), _packed())}
+    loss_fn = laguna.pretrain_loss_fn_builder(model)
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    text = str(jax.make_jaxpr(grad_fn)(params, batch, None))
+    # 5 layers x (q, k), forward and recomputed; the rule once each
+    assert text.count("name=rotary_fwd") == 20
+    assert text.count("name=rotary_bwd") == 10
+    with jax.default_matmul_precision("highest"):
+        (loss, _), grads = jax.jit(grad_fn)(params, batch, None)
+    want, want_grads, _, _ = ref.step_loss_and_grad(params, [batch], sizes)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref_leaf in zip(flat, jax.tree.leaves(want_grads)):
+        name = jax.tree_util.keystr(path)
+        if not name.endswith("['expert_bias']"):
+            assert _rel(got, ref_leaf) < 2e-5, name
+
+
 def test_yarn_table_at_the_published_parameters():
     """lo, hi, f_0, f_31 and c by hand: R = 64 of the head's 128 dims turn;
     dim(64) = 64 ln(4096 / (128 pi)) / (2 ln 500000) = 5.66 -> lo 5;

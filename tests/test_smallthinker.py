@@ -136,6 +136,34 @@ def test_loss_gradients_and_counts_match_the_reference(toy):
         assert _rel(got, ref_leaf) < 2e-5, jax.tree_util.keystr(path)
 
 
+def test_the_rotation_kernels_match_the_reference(monkeypatch):
+    """Heads of 128 under the test switch: q and k of the banded layers take
+    ops/pallas/rotary.py's kernels (interpret mode), read from the fused
+    projection; loss and every gradient leaf against the reference."""
+    monkeypatch.setenv("BPT_PALLAS_INTERPRET", "1")
+    wide = dict(TOY, head_dim=128)
+    cfg = SmallThinkerConfig.from_dict(wide).replace(
+        dtype="float32", checkpoint_activations=True, attention_impl="xla")
+    sizes = ref.sizes_from_config(wide)
+    params = ref.init_params(SEED, sizes)
+    model = smallthinker.SmallThinkerForCausalLM(cfg, dtype=jnp.float32)
+    batch = {k: jnp.asarray(v) for k, v in zip(
+        ("input_ids", "segment_ids", "position_ids"), _packed())}
+    loss_fn = smallthinker.pretrain_loss_fn_builder(model)
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    text = str(jax.make_jaxpr(grad_fn)(params, batch, None))
+    # 3 banded layers x (q, k), forward and recomputed; the rule once each
+    assert text.count("name=rotary_fwd") == 12
+    assert text.count("name=rotary_bwd") == 6
+    with jax.default_matmul_precision("highest"):
+        (loss, _), grads = jax.jit(grad_fn)(params, batch, None)
+    want, want_grads, _, _ = ref.step_loss_and_grad(params, [batch], sizes)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref_leaf in zip(flat, jax.tree.leaves(want_grads)):
+        assert _rel(got, ref_leaf) < 2e-5, jax.tree_util.keystr(path)
+
+
 def test_one_lamb_step_matches_the_reference(toy):
     import run_pretraining
     from bert_pytorch_tpu.optim import schedulers

@@ -257,6 +257,44 @@ def test_index_kernels_compile_at_the_keye_cell(v5e):
     assert call.params["grid_mapping"].grid == (s // blk,)
 
 
+@pytest.mark.parametrize("cell,first,heads,width,rotated,dtype", [
+    ("laguna-window-q", 0, 64, 80, 128, jnp.bfloat16),
+    ("laguna-window-k", 64, 8, 80, 128, jnp.bfloat16),
+    ("laguna-full-q", 0, 48, 64, 64, jnp.bfloat16),
+    ("laguna-full-k", 48, 8, 64, 64, jnp.bfloat16),
+    ("smallthinker-q", 0, 28, 36, 128, jnp.bfloat16),
+    ("smallthinker-k", 28, 4, 36, 128, jnp.bfloat16),
+    ("keye-q", 0, 32, 32, 128, jnp.float32),
+    ("keye-k", 0, 4, 4, 128, jnp.float32),
+])
+def test_rotary_kernels_compile_at_the_decoder_cells(v5e, cell, first, heads,
+                                                     width, rotated, dtype):
+    """ops/pallas/rotary.py's two kernels over a 16,384-token row at the
+    cells' head counts: q's and k's heads read as column blocks of the
+    fused projection's (1, S, width * 128) output (keye: the head norms'
+    float32 output, all of it) and written by head, the rule reading by
+    head and writing columns; the whole head and half of it (two different
+    lane rolls)."""
+    from bert_pytorch_tpu.ops.pallas import rotary as ker
+
+    s, d = 16384, 128
+    assert ker.supported(s, d) and not ker.supported(s, 64)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e)  # noqa: E731
+    x = sds((1, s, width * d), dtype)
+    table = sds((1, s, d), jnp.float32)
+
+    def both(x, c, sa, sb, dy):
+        # the rotation is linear: its rule needs no forward pass, so ask
+        # for the result beside the cotangent
+        y, pull = jax.vjp(lambda u: ker.rotate(
+            u, c, sa, sb, first, heads, rotated, jnp.bfloat16, False), x)
+        return y, pull(dy)
+
+    dy = sds((1, s, heads, d), jnp.bfloat16)
+    assert _kernels(both, x, table, table, table, dy) == {
+        "rotary_fwd": 1, "rotary_bwd": 1}
+
+
 def test_kl_pass_compiles_at_the_keye_cell(v5e, monkeypatch):
     """One row of `ops/sparse_index.index_kl` with its gradient rule, as a
     keye layer runs it after the main attention's forward kernel: a scan
