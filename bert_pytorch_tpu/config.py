@@ -21,6 +21,17 @@ import json
 import re
 from typing import Any, Dict, Optional, Tuple
 
+# The names `remat_policy` takes in every family: the keys of
+# models/bert._REMAT_POLICIES, models/decoder.LM_REMAT_POLICIES and
+# models/keye.REMAT_POLICIES.
+REMAT_POLICIES = ("nothing", "dense", "auto")
+
+
+def _check_remat_policy(name: str) -> None:
+    if name not in REMAT_POLICIES:
+        raise ValueError(f"remat_policy {name!r}: this program has "
+                         f"{list(REMAT_POLICIES)}")
+
 
 @dataclasses.dataclass(frozen=True)
 class BertConfig:
@@ -79,9 +90,6 @@ class BertConfig:
     #              to recompute on a v5e (PERF.md, PR 25)
     #   "nothing"  the layer's input alone: the whole layer runs twice (max
     #              memory savings — the reference's torch.utils.checkpoint)
-    #   "dots"     every matmul output, the attention core's (B, H, S, S)
-    #              scores and context included (dots_saveable)
-    #   "mlp_only" everything but the (B, S, F) wide-MLP activations
     # A value other than "auto" is taken as written.
     remat_policy: str = "auto"
     # lax.scan unroll factor for the layer stack. 1 = compiled while loop
@@ -138,6 +146,9 @@ class BertConfig:
     # element" guarantee of nn.Dropout's threefry stream.
     fused_dropout_ln: bool = True
 
+    def __post_init__(self):
+        _check_remat_policy(self.remat_policy)
+
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "BertConfig":
         known = {f.name for f in dataclasses.fields(cls)}
@@ -174,8 +185,84 @@ DOCUMENTED_DATA_KEYS = ("model_type", "source", "reduced", "assumed",
                         "layout")
 
 
+class DecoderConfig:
+    """What the config classes of the decoder families share
+    (models/decoder.py has the modules that read them). Each family's class
+    is a frozen dataclass of its source's keys beside this base, which has
+    no fields: a class's field order and `to_dict()` are its own.
+
+    A class says what its file may carry beside its fields (`_IGNORED`),
+    which fields a JSON list is read into as a tuple (`_TUPLES`: the config
+    is a static field of the modules) and, where the source nests groups,
+    how they are read into flat fields (`_read_groups`). Every class has,
+    as a field or a property, the names the shared modules read:
+    `num_experts` (held), `experts_total`, `experts_held`,
+    `num_experts_per_tok`, `moe_intermediate_size`, `norm_eps`,
+    `norm_topk_prob`, `use_expert_bias`, `routed_scaling_factor`,
+    `router_scores`, `expert_activation`."""
+
+    _IGNORED = ()
+    _TUPLES = ()
+
+    def __post_init__(self):
+        _check_remat_policy(self.remat_policy)
+
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any]):
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = [k for k in d if k not in known
+                   and k not in DOCUMENTED_DATA_KEYS
+                   and k not in cls._IGNORED]
+        kw = {k: v for k, v in d.items() if k in known}
+        unknown += cls._read_groups(d, kw)
+        if unknown:
+            raise ValueError(f"{cls.model_type} model config: unknown "
+                             f"key(s) {sorted(unknown)}")
+        for key in cls._TUPLES:
+            if kw.get(key) is not None:
+                kw[key] = tuple(kw[key])
+        cfg = cls(**kw)
+        cfg.check()
+        return cfg
+
+    @classmethod
+    def _read_groups(cls, d: Dict[str, Any], kw: Dict[str, Any]) -> list:
+        """Reads the source's nested groups into `kw`'s flat fields; returns
+        the groups' keys that no field takes."""
+        return []
+
+    def check(self) -> None:
+        """Raises where the config asks for what the family's program is not
+        written for, or is cut inconsistently."""
+        self.layer_kinds
+
+    @classmethod
+    def from_json_file(cls, path: str):
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_dict(json.load(f))
+
+    def to_dict(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    def replace(self, **kw: Any):
+        return dataclasses.replace(self, **kw)
+
+    @property
+    def router_width(self) -> int:
+        return int(self.experts_total or self.num_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of "
+                f"num_experts={self.num_experts} out of {self.router_width}")
+        return int(lo), int(hi)
+
+
 @dataclasses.dataclass(frozen=True)
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(DecoderConfig):
     """Architecture config of the `lfm2_moe` family (LiquidAI LFM2 with
     routed experts): a pre-norm decoder whose blocks differ in kind — a
     gated short convolution or causal grouped-query attention as the
@@ -224,58 +311,22 @@ class Lfm2MoeConfig:
 
     # source keys that carry no size of this program's (or a nested group)
     _IGNORED = ("rope_parameters", "vocab_rows_total", "vocab_rows_held")
+    _TUPLES = ("layer_types", "layers_kept", "experts_held")
 
     @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "Lfm2MoeConfig":
-        known = {f.name for f in dataclasses.fields(cls)}
-        unknown = [k for k in d if k not in known
-                   and k not in DOCUMENTED_DATA_KEYS
-                   and k not in cls._IGNORED]
-        if unknown:
-            raise ValueError(
-                f"lfm2_moe model config: unknown key(s) {sorted(unknown)}")
-        kw = {k: v for k, v in d.items() if k in known}
+    def _read_groups(cls, d: Dict[str, Any], kw: Dict[str, Any]) -> list:
         rope = d.get("rope_parameters") or {}
         if "rope_theta" in rope:
             kw["rope_theta"] = float(rope["rope_theta"])
-        for key in ("layer_types", "layers_kept", "experts_held"):
-            if kw.get(key) is not None:
-                kw[key] = tuple(kw[key])
-        cfg = cls(**kw)
-        cfg.layer_kinds  # raises on an inconsistent cut
-        return cfg
+        return []
 
-    @classmethod
-    def from_json_file(cls, path: str) -> "Lfm2MoeConfig":
-        with open(path, "r", encoding="utf-8") as f:
-            return cls.from_dict(json.load(f))
-
-    def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
-
-    def replace(self, **kw: Any) -> "Lfm2MoeConfig":
-        return dataclasses.replace(self, **kw)
-
-    # what models/lfm2_moe.RoutedExperts reads of every decoder family
+    # what models/decoder.RoutedExperts reads of every decoder family
     router_scores = property(lambda self: "sigmoid")
     expert_activation = property(lambda self: "silu")
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
-
-    @property
-    def router_width(self) -> int:
-        return int(self.experts_total or self.num_experts)
-
-    @property
-    def held_range(self) -> Tuple[int, int]:
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
-            raise ValueError(
-                f"experts_held {self.experts_held} is not a range of "
-                f"num_experts={self.num_experts} out of {self.router_width}")
-        return int(lo), int(hi)
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -303,7 +354,7 @@ class Lfm2MoeConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class KimiLinearConfig:
+class KimiLinearConfig(DecoderConfig):
     """Architecture config of the `kimi_linear` family (Moonshot Kimi
     Linear): a pre-norm decoder of gated delta-rule linear-attention layers
     (KDA) and latent-attention layers without positions (MLA, NoPE) in the
@@ -373,41 +424,18 @@ class KimiLinearConfig:
               "full_attn_layers": "full_attn_layers",
               "num_heads": "kda_num_heads", "head_dim": "kda_head_dim",
               "short_conv_kernel_size": "short_conv_kernel_size"}
+    _TUPLES = ("kda_layers", "full_attn_layers", "layers_kept",
+               "experts_held")
 
     @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "KimiLinearConfig":
-        known = {f.name for f in dataclasses.fields(cls)}
-        unknown = [k for k in d if k not in known
-                   and k not in DOCUMENTED_DATA_KEYS
-                   and k not in cls._IGNORED]
+    def _read_groups(cls, d: Dict[str, Any], kw: Dict[str, Any]) -> list:
         group = d.get("linear_attn_config") or {}
-        unknown += [f"linear_attn_config.{k}" for k in group
-                    if k not in cls._GROUP]
-        if unknown:
-            raise ValueError(
-                f"kimi_linear model config: unknown key(s) {sorted(unknown)}")
-        kw = {k: v for k, v in d.items() if k in known}
-        kw.update({cls._GROUP[k]: v for k, v in group.items()})
-        for key in ("kda_layers", "full_attn_layers", "layers_kept",
-                    "experts_held"):
-            if kw.get(key) is not None:
-                kw[key] = tuple(kw[key])
-        cfg = cls(**kw)
-        cfg.layer_kinds  # raises on an inconsistent cut
-        return cfg
+        kw.update({cls._GROUP[k]: v for k, v in group.items()
+                   if k in cls._GROUP})
+        return [f"linear_attn_config.{k}" for k in group
+                if k not in cls._GROUP]
 
-    @classmethod
-    def from_json_file(cls, path: str) -> "KimiLinearConfig":
-        with open(path, "r", encoding="utf-8") as f:
-            return cls.from_dict(json.load(f))
-
-    def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
-
-    def replace(self, **kw: Any) -> "KimiLinearConfig":
-        return dataclasses.replace(self, **kw)
-
-    # the names models/lfm2_moe.py's shared modules read
+    # the names models/decoder.py's modules read
     norm_eps = property(lambda self: self.rms_norm_eps)
     num_experts_per_tok = property(lambda self: self.num_experts_per_token)
     norm_topk_prob = property(lambda self: self.moe_renormalize)
@@ -418,19 +446,6 @@ class KimiLinearConfig:
     @property
     def gate_rank(self) -> int:
         return int(self.kda_gate_rank or self.kda_head_dim)
-
-    @property
-    def router_width(self) -> int:
-        return int(self.experts_total or self.num_experts)
-
-    @property
-    def held_range(self) -> Tuple[int, int]:
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
-            raise ValueError(
-                f"experts_held {self.experts_held} is not a range of "
-                f"num_experts={self.num_experts} out of {self.router_width}")
-        return int(lo), int(hi)
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -472,7 +487,7 @@ class KimiLinearConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class SmallThinkerConfig:
+class SmallThinkerConfig(DecoderConfig):
     """Architecture config of the `smallthinker` family (PowerInfer
     SmallThinker): a pre-norm decoder whose every layer is causal
     grouped-query attention, over the whole document WITHOUT positions or
@@ -522,36 +537,9 @@ class SmallThinkerConfig:
 
     # keys of the configuration's file that carry no size of this program's
     _IGNORED = ("vocab_rows_total", "vocab_rows_held")
+    _TUPLES = ("rope_layout", "sliding_window_layout", "experts_held")
 
-    @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "SmallThinkerConfig":
-        known = {f.name for f in dataclasses.fields(cls)}
-        unknown = [k for k in d if k not in known
-                   and k not in DOCUMENTED_DATA_KEYS
-                   and k not in cls._IGNORED]
-        if unknown:
-            raise ValueError(
-                f"smallthinker model config: unknown key(s) {sorted(unknown)}")
-        kw = {k: v for k, v in d.items() if k in known}
-        for key in ("rope_layout", "sliding_window_layout", "experts_held"):
-            if kw.get(key) is not None:
-                kw[key] = tuple(kw[key])
-        cfg = cls(**kw)
-        cfg.layer_kinds  # raises on an inconsistent cut
-        return cfg
-
-    @classmethod
-    def from_json_file(cls, path: str) -> "SmallThinkerConfig":
-        with open(path, "r", encoding="utf-8") as f:
-            return cls.from_dict(json.load(f))
-
-    def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
-
-    def replace(self, **kw: Any) -> "SmallThinkerConfig":
-        return dataclasses.replace(self, **kw)
-
-    # the names models/lfm2_moe.py's shared modules read
+    # the names models/decoder.py's modules read
     norm_eps = property(lambda self: self.rms_norm_eps)
     num_experts = property(lambda self: self.moe_num_primary_experts)
     num_experts_per_tok = property(
@@ -561,20 +549,6 @@ class SmallThinkerConfig:
     routed_scaling_factor = property(lambda self: 1.0)
     router_scores = property(lambda self: "softmax")
     expert_activation = property(lambda self: "relu")
-
-    @property
-    def router_width(self) -> int:
-        return int(self.experts_total or self.moe_num_primary_experts)
-
-    @property
-    def held_range(self) -> Tuple[int, int]:
-        n = self.moe_num_primary_experts
-        lo, hi = self.experts_held or (0, n)
-        if hi - lo != n or not 0 <= lo < hi <= self.router_width:
-            raise ValueError(
-                f"experts_held {self.experts_held} is not a range of "
-                f"moe_num_primary_experts={n} out of {self.router_width}")
-        return int(lo), int(hi)
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[int, bool], ...]:
@@ -609,7 +583,7 @@ class SmallThinkerConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class LagunaConfig:
+class LagunaConfig(DecoderConfig):
     """Architecture config of the `laguna` family (poolside Laguna): a
     pre-norm decoder whose every layer is causal grouped-query attention
     with a sigmoid gate per head on its output, over the last
@@ -673,14 +647,12 @@ class LagunaConfig:
     _ROPE_KEYS = ("rope_theta", "rope_type", "factor",
                   "original_max_position_embeddings", "beta_slow",
                   "beta_fast", "attention_factor", "partial_rotary_factor")
+    _TUPLES = ("layer_types", "mlp_layer_types",
+               "num_attention_heads_per_layer", "experts_held")
 
     @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "LagunaConfig":
-        known = {f.name for f in dataclasses.fields(cls)}
-        unknown = [k for k in d if k not in known
-                   and k not in DOCUMENTED_DATA_KEYS
-                   and k not in cls._IGNORED]
-        kw = {k: v for k, v in d.items() if k in known}
+    def _read_groups(cls, d: Dict[str, Any], kw: Dict[str, Any]) -> list:
+        unknown = []
         for kind, group in (d.get("rope_parameters") or {}).items():
             if kind == "original_max_position_embeddings":
                 continue        # the full layers' sub-group repeats it
@@ -690,29 +662,9 @@ class LagunaConfig:
             unknown += [f"rope_parameters.{kind}.{k}" for k in group
                         if k not in cls._ROPE_KEYS]
             kw[f"rope_{kind}"] = tuple(sorted(group.items()))
-        if unknown:
-            raise ValueError(
-                f"laguna model config: unknown key(s) {sorted(unknown)}")
-        for key in ("layer_types", "mlp_layer_types",
-                    "num_attention_heads_per_layer", "experts_held"):
-            if kw.get(key) is not None:
-                kw[key] = tuple(kw[key])
-        cfg = cls(**kw)
-        cfg.layer_kinds  # raises on an inconsistent cut
-        return cfg
+        return unknown
 
-    @classmethod
-    def from_json_file(cls, path: str) -> "LagunaConfig":
-        with open(path, "r", encoding="utf-8") as f:
-            return cls.from_dict(json.load(f))
-
-    def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
-
-    def replace(self, **kw: Any) -> "LagunaConfig":
-        return dataclasses.replace(self, **kw)
-
-    # the names models/lfm2_moe.py's shared modules read. The config says
+    # the names models/decoder.py's modules read. The config says
     # nothing of how the router scores, renormalises or selects: the
     # convention of its family of models (256 experts, 8 a token, a scaling
     # factor, one shared expert), which is what ops/moe.route has
@@ -723,19 +675,6 @@ class LagunaConfig:
         lambda self: self.moe_routed_scaling_factor)
     router_scores = property(lambda self: "sigmoid")
     expert_activation = property(lambda self: "silu")
-
-    @property
-    def router_width(self) -> int:
-        return int(self.experts_total or self.num_experts)
-
-    @property
-    def held_range(self) -> Tuple[int, int]:
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
-            raise ValueError(
-                f"experts_held {self.experts_held} is not a range of "
-                f"num_experts={self.num_experts} out of {self.router_width}")
-        return int(lo), int(hi)
 
     def rope(self, kind: str) -> Dict[str, Any]:
         """The rotary parameters of a kind of layer ("full" or "sliding"):
@@ -798,7 +737,7 @@ class LagunaConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class KeyeConfig:
+class KeyeConfig(DecoderConfig):
     """Architecture config of the `keye` family (the language model of
     Kwai Keye-VL-2.0): a pre-norm decoder whose every layer is causal
     grouped-query attention over the keys a learned index SELECTS (`sa_*`:
@@ -868,14 +807,11 @@ class KeyeConfig:
                 "topk")
     _ROPE_KEYS = ("mrope_section", "rope_type", "type")
     _SA_CHUNK = 512     # DEFAULT_BLK_Q and DEFAULT_BLK_K of the kernels
+    _TUPLES = ("mlp_only_layers", "mrope_section", "experts_held")
 
     @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "KeyeConfig":
-        known = {f.name for f in dataclasses.fields(cls)}
-        unknown = [k for k in d if k not in known
-                   and k not in DOCUMENTED_DATA_KEYS
-                   and k not in cls._IGNORED]
-        kw = {k: v for k, v in d.items() if k in known}
+    def _read_groups(cls, d: Dict[str, Any], kw: Dict[str, Any]) -> list:
+        unknown = []
         for key, value in (d.get("sa_config") or {}).items():
             if key in cls._SA_KEYS:
                 kw[f"sa_{key}"] = value
@@ -889,46 +825,14 @@ class KeyeConfig:
         for key in ("rope_type", "type"):
             if key in rope:
                 kw["rope_type"] = rope[key]
-        if unknown:
-            raise ValueError(
-                f"keye model config: unknown key(s) {sorted(unknown)}")
-        for key in ("mlp_only_layers", "mrope_section", "experts_held"):
-            if kw.get(key) is not None:
-                kw[key] = tuple(kw[key])
-        cfg = cls(**kw)
-        cfg.check()     # raises on what the program is not written for
-        return cfg
+        return unknown
 
-    @classmethod
-    def from_json_file(cls, path: str) -> "KeyeConfig":
-        with open(path, "r", encoding="utf-8") as f:
-            return cls.from_dict(json.load(f))
-
-    def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
-
-    def replace(self, **kw: Any) -> "KeyeConfig":
-        return dataclasses.replace(self, **kw)
-
-    # the names models/lfm2_moe.py's shared modules read
+    # the names models/decoder.py's modules read
     norm_eps = property(lambda self: self.rms_norm_eps)
     use_expert_bias = property(lambda self: False)
     routed_scaling_factor = property(lambda self: 1.0)
     router_scores = property(lambda self: "softmax")
     expert_activation = property(lambda self: "silu")
-
-    @property
-    def router_width(self) -> int:
-        return int(self.experts_total or self.num_experts)
-
-    @property
-    def held_range(self) -> Tuple[int, int]:
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if hi - lo != self.num_experts or not 0 <= lo < hi <= self.router_width:
-            raise ValueError(
-                f"experts_held {self.experts_held} is not a range of "
-                f"num_experts={self.num_experts} out of {self.router_width}")
-        return int(lo), int(hi)
 
     def check(self) -> None:
         """Raises where the config asks for what models/keye.py is not

@@ -289,15 +289,7 @@ class BertLayer(nn.Module):
                 name="intermediate")(hidden)
             if cfg.kfac_taps:
                 inter = self.perturb("intermediate_tap", inter)
-            # Tag the (B, S, F) wide activations so remat_policy="mlp_only"
-            # can drop just these (4x hidden width — the bulk of per-layer
-            # activation memory) and keep attention saved. No-op without
-            # nn.remat. The third value of that width a layer's backward
-            # pass reads, the erf-GELU's derivative, takes the same name
-            # where it is made (ops/activations.py).
-            inter = checkpoint_name(inter, "mlp_wide")
             inter = act(inter)
-            inter = checkpoint_name(inter, "mlp_wide")
             if cfg.kfac_taps:
                 self.sow("kfac_in", "mlp_output_tap", inter)
             mlp_out = nn.Dense(
@@ -351,12 +343,6 @@ DENSE_SAVED = ("qkv_out", "mlp_out")
 _REMAT_POLICIES = {
     "nothing": jax.checkpoint_policies.nothing_saveable,
     "dense": jax.checkpoint_policies.save_only_these_names(*DENSE_SAVED),
-    "dots": jax.checkpoint_policies.dots_saveable,
-    # recompute ONLY the (B, S, F) wide-MLP activations (tagged
-    # checkpoint_name "mlp_wide" in BertLayer); attention stays
-    # saved — cheapest-recompute way to shed the largest buffers
-    "mlp_only": jax.checkpoint_policies
-    .save_anything_except_these_names("mlp_wide"),
 }
 # remat_policy="auto", in order of preference: the entry point takes the
 # first whose compiled step the device holds and the last whatever it

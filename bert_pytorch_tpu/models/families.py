@@ -17,8 +17,8 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from bert_pytorch_tpu.config import MODEL_FAMILIES
-from bert_pytorch_tpu.models import (keye, kimi_linear, laguna, lfm2_moe,
-                                     smallthinker)
+from bert_pytorch_tpu.models import (decoder, keye, kimi_linear, laguna,
+                                     lfm2_moe, smallthinker)
 from bert_pytorch_tpu.models.bert import BertForPreTraining
 from bert_pytorch_tpu.telemetry.expert_load import ExpertLoadCounters
 from bert_pytorch_tpu.telemetry.stepwatch import flops_per_seq
@@ -70,7 +70,7 @@ def _decoder_family(module, model_cls) -> Family:
     """A causal-LM family over packed rows, from its model module."""
     return Family(
         make_model=lambda config, dtype: model_cls(config, dtype=dtype),
-        init_inputs=lfm2_moe.init_inputs,    # a packed causal-LM batch's
+        init_inputs=decoder.init_inputs,     # a packed causal-LM batch's
         objective="clm",
         mlm_head=False,
         step_kwargs={"loss_fn_builder": module.pretrain_loss_fn_builder,

@@ -72,7 +72,7 @@ everywhere else and for the index heads of 64.
 
 Layers are separate modules in a Python loop, each rematerialised under
 `checkpoint_activations` (`remat_policy`: REMAT_POLICIES; "dense" keeps
-lfm2_moe.DENSE_SAVED (the forward kernel's context and log-sum-exp among
+decoder.DENSE_SAVED (the forward kernel's context and log-sum-exp among
 them) and the selection with the KL term's small gradients, so the backward
 pass runs neither the forward kernel nor either of the two index passes
 again). The model hands back the final norm's output and the head, not
@@ -96,12 +96,12 @@ from jax.ad_checkpoint import checkpoint_name
 
 from bert_pytorch_tpu.config import KeyeConfig
 from bert_pytorch_tpu.models import losses
-from bert_pytorch_tpu.models.lfm2_moe import (DENSE_SAVED, RMSNorm,
-                                              RoutedExperts, _init, _Linear,
-                                              expert_scalars)
-# the router is read in float32, as lfm2's: models/families.py takes the
-# family's `keep_float32` from this module
-from bert_pytorch_tpu.models.lfm2_moe import keep_float32  # noqa: F401
+from bert_pytorch_tpu.models.decoder import (DENSE_SAVED, LOSS_BLOCK_ROWS,
+                                             RMSNorm, RoutedExperts, _init,
+                                             _Linear, expert_scalars)
+# models/families.py takes the family's `keep_float32` (the router is read
+# in float32) from this module
+from bert_pytorch_tpu.models.decoder import keep_float32  # noqa: F401
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 from bert_pytorch_tpu.ops.decoder_ops import rotary
 from bert_pytorch_tpu.ops.sparse_index import (full_row_selected_pairs,
@@ -109,10 +109,7 @@ from bert_pytorch_tpu.ops.sparse_index import (full_row_selected_pairs,
 
 Dtype = Any
 
-# tokens a block of the loss: (2048, 18992) float32 logits are 156 MB
-LOSS_BLOCK_ROWS = 2048
-
-# What the rematerialised layer keeps beside its input. "dense": lfm2's
+# What the rematerialised layer keeps beside its input. "dense": the shared
 # names and, of ops/sparse_index.py, the packed selection and the KL term's
 # gradients with respect to the indexer's three small outputs (64 + 36 MB a
 # layer at 16,384 tokens), so that the backward pass runs neither index pass

@@ -7,7 +7,7 @@ keys). For x of shape (T, hidden), layer l computes
     h = x + Mixer_l(RMSNorm(x; input_norm))
     y = h + FFN_l(RMSNorm(h; ffn_norm))
 
-with RMSNorm as models/lfm2_moe.py's. After the last layer one more RMSNorm
+with RMSNorm as models/decoder.py's. After the last layer one more RMSNorm
 (`final_norm`), and the logits are that times an UNTIED `lm_head`
 (V, hidden) transposed. Source layers count from 1; which are of which kind
 is `linear_attn_config`'s two lists (3 KDA : 1 MLA as published). The mixer
@@ -50,8 +50,9 @@ beta, the KDA state, softmax and the loss are float32; matrix products take
 `dtype` operands (bfloat16) and accumulate in float32.
 
 Layers are separate modules in a Python loop, each rematerialised under
-`checkpoint_activations` (`remat_policy`: lfm2_moe.LM_REMAT_POLICIES). The
-model hands back the final norm's output and the head, not logits: the loss
+`checkpoint_activations` (`remat_policy`: decoder.LM_REMAT_POLICIES). The
+trunk is the shared module's (decoder.CausalLMTrunk): it hands back the final
+norm's output and the head, not logits: the loss
 (losses.next_token_loss_blocked) takes the head a block of tokens at a time,
 so that no (T, V) float32 logits exist (16,384 x 20,480 x 4 B = 1.3 GB and
 as much again for their gradient).
@@ -68,18 +69,16 @@ from jax.ad_checkpoint import checkpoint_name
 
 from bert_pytorch_tpu.config import KimiLinearConfig
 from bert_pytorch_tpu.models import losses
-from bert_pytorch_tpu.models.lfm2_moe import (LM_REMAT_POLICIES, DenseMLP,
-                                              RMSNorm, RoutedExperts,
-                                              _init, _Linear,
-                                              expert_scalars)
+from bert_pytorch_tpu.models.decoder import (LOSS_BLOCK_ROWS, CausalLMTrunk,
+                                             DenseMLP, RMSNorm,
+                                             RoutedExperts, _init, _Linear,
+                                             expert_scalars)
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 from bert_pytorch_tpu.ops.decoder_ops import short_conv
 from bert_pytorch_tpu.ops.kda import kda_scan, kernel_mode
 
 Dtype = Any
 
-# tokens a block of the loss: (2048, 20480) float32 logits are 168 MB
-LOSS_BLOCK_ROWS = 2048
 # chunks a block of the KDA scan (ops/kda.py): 32 x 64 tokens
 KDA_BLOCK_CHUNKS = 32
 
@@ -217,6 +216,8 @@ class DecoderLayer(nn.Module):
     ffn: str
     dtype: Dtype = jnp.bfloat16
 
+    routed = property(lambda self: self.ffn == "moe")
+
     @nn.compact
     def __call__(self, x, segment_ids, position_ids):
         cfg = self.config
@@ -243,51 +244,17 @@ class DecoderLayer(nn.Module):
         return h + out, load, dropped
 
 
-class KimiLinearForCausalLM(nn.Module):
-    """(input_ids, segment_ids, position_ids), each (B, S) -> (the final
-    norm's output (B, S, hidden) in `dtype`, the head (V, hidden) in
-    `dtype`, per routed layer: tokens per held expert (n_routed, E_held)
-    int32 and held pairs not computed (n_routed,) int32). segment_ids: the
-    packing contract's (1..n per row, 0 = pad); position_ids restart at each
-    document and are 0 at padding."""
-    config: KimiLinearConfig
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, input_ids, segment_ids, position_ids):
-        cfg = self.config
-        layer_cls = DecoderLayer
-        if cfg.checkpoint_activations:
-            layer_cls = nn.remat(DecoderLayer,
-                                 policy=LM_REMAT_POLICIES[cfg.remat_policy])
-        with jax.named_scope("decoder"):
-            table = self.param("embed_tokens", _init(cfg),
-                               (cfg.vocab_size, cfg.hidden_size),
-                               jnp.float32)
-            head = self.param("lm_head", _init(cfg),
-                              (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-            with jax.named_scope("embeddings"):
-                x = table.astype(self.dtype)[input_ids]
-            loads, drops = [], []
-            for i, (mixer, ffn) in enumerate(cfg.layer_kinds):
-                x, load, dropped = layer_cls(
-                    cfg, mixer, ffn, self.dtype, name=f"layer_{i}")(
-                        x, segment_ids, position_ids)
-                if ffn == "moe":
-                    loads.append(load)
-                    drops.append(dropped)
-            x = RMSNorm(cfg.norm_eps, self.dtype, name="final_norm")(x)
-        n_held = cfg.num_experts
-        return (x, head.astype(self.dtype),
-                jnp.stack(loads) if loads
-                else jnp.zeros((0, n_held), jnp.int32),
-                jnp.stack(drops) if drops else jnp.zeros((0,), jnp.int32))
+class KimiLinearForCausalLM(CausalLMTrunk):
+    """decoder.CausalLMTrunk over this family's layers: the loads and drops
+    are the routed layers' (n_routed, E_held) and (n_routed,). position_ids
+    are 0 at padding."""
+    layer = DecoderLayer
 
 
 def keep_float32(path) -> bool:
     """Parameters the step reads in float32 whatever the compute dtype: the
-    router and its selection bias (as lfm2's), and the decay's A_log and
-    dt_bias (float32 by the family's equations)."""
+    router and its selection bias (decoder.keep_float32's), and the decay's
+    A_log and dt_bias (float32 by the family's equations)."""
     return str(getattr(path[-1], "key", path[-1])) in (
         "router", "expert_bias", "A_log", "dt_bias")
 
@@ -295,10 +262,10 @@ def keep_float32(path) -> bool:
 def pretrain_loss_fn_builder(model) -> Callable:
     """loss_fn_builder of training/pretrain.build_pretrain_step: next-token
     cross-entropy over packed rows, the head a block of tokens at a time;
-    the routed layers' counters as lfm2's, and the KDA scans' useful work,
-    counted from the rows the scans are given: tokens that are no padding
-    (times the KDA layers) and documents started (state resets a layer,
-    padding slots included), each summed over the micro-batches; and
+    the routed layers' counters (decoder.expert_scalars), and the KDA scans'
+    useful work, counted from the rows the scans are given: tokens that are
+    no padding (times the KDA layers) and documents started (state resets a
+    layer, padding slots included), each summed over the micro-batches; and
     `kda_kernel_tokens`, the `kda_tokens` of the layers whose scan walks its
     chunks with the Pallas kernels (ops/kda.kernel_mode, asked when the step
     is traced, as `kda_scan` asks it: all of them or, on the XLA scans, 0)."""

@@ -39,7 +39,7 @@ heads, D = `head_dim`:
              y = h + shared(m)
                    + sum_{e in E_i, e held} w_ie W2_e(silu(W1_e m_i) * W3_e m_i)
 
-with RMSNorm as models/lfm2_moe.py's (eps 1e-6 here), `shared` one SwiGLU
+with RMSNorm as models/decoder.py's (eps 1e-6 here), `shared` one SwiGLU
 expert of the experts' width on every token, unweighted. After the last
 layer one more RMSNorm (`final_norm`), and the logits are that times an
 UNTIED `lm_head` (V, hidden) transposed. The layer holds the experts
@@ -65,9 +65,9 @@ jax.numpy everywhere else.
 
 Layers are separate modules in a Python loop (their kind, head count and
 table are static), each rematerialised under `checkpoint_activations`
-(`remat_policy`: lfm2_moe.LM_REMAT_POLICIES, the same names saved). The
-model hands back the final norm's output and the head, not logits: the loss
-(losses.next_token_loss_blocked) takes the head a block of tokens at a time.
+(`remat_policy`: decoder.LM_REMAT_POLICIES). The trunk, the loss (the head a
+block of tokens at a time) and the router's float32 are the shared module's
+(models/decoder.py).
 
 Scopes: a layer's projections and kernels are `attention/attention_window`
 or `attention/attention_full` (as smallthinker's: what reads `attention`
@@ -79,7 +79,7 @@ reads both kinds), its rotation `attention/rotary`, its gate
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -87,21 +87,17 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from bert_pytorch_tpu.config import LagunaConfig
-from bert_pytorch_tpu.models import losses
-from bert_pytorch_tpu.models.lfm2_moe import (LM_REMAT_POLICIES, DenseMLP,
-                                              RMSNorm, RoutedExperts, _init,
-                                              _Linear, expert_scalars)
-# the router and its selection bias are read in float32, as lfm2's:
-# models/families.py takes the family's `keep_float32` from this module
-from bert_pytorch_tpu.models.lfm2_moe import keep_float32  # noqa: F401
-from bert_pytorch_tpu.models.smallthinker import band_pairs
+from bert_pytorch_tpu.models.decoder import (CausalLMTrunk, DenseMLP,
+                                             RMSNorm, RoutedExperts, _init,
+                                             _Linear, band_pairs)
+# models/families.py takes the family's loss builder and `keep_float32`
+# (the router and its selection bias are read in float32) from this module
+from bert_pytorch_tpu.models.decoder import (  # noqa: F401
+    keep_float32, pretrain_loss_fn_builder)
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 from bert_pytorch_tpu.ops.decoder_ops import rotary, rotary_table
 
 Dtype = Any
-
-# tokens a block of the loss: (2048, 12544) float32 logits are 103 MB
-LOSS_BLOCK_ROWS = 2048
 
 
 class Attention(nn.Module):
@@ -159,6 +155,8 @@ class DecoderLayer(nn.Module):
     ffn: str            # "dense" or "moe"
     dtype: Dtype = jnp.bfloat16
 
+    routed = property(lambda self: self.ffn == "moe")
+
     @nn.compact
     def __call__(self, x, segment_ids, position_ids):
         cfg = self.config
@@ -180,65 +178,10 @@ class DecoderLayer(nn.Module):
         return h + out, load, dropped
 
 
-class LagunaForCausalLM(nn.Module):
-    """(input_ids, segment_ids, position_ids), each (B, S) -> (the final
-    norm's output (B, S, hidden) in `dtype`, the head (V, hidden) in
-    `dtype`, per routed layer: tokens per held expert (n_routed, E_held)
-    int32 and held pairs not computed (n_routed,) int32). segment_ids: the
-    packing contract's (1..n per row, 0 = pad); position_ids restart at each
-    document."""
-    config: LagunaConfig
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, input_ids, segment_ids, position_ids):
-        cfg = self.config
-        layer_cls = DecoderLayer
-        if cfg.checkpoint_activations:
-            layer_cls = nn.remat(DecoderLayer,
-                                 policy=LM_REMAT_POLICIES[cfg.remat_policy])
-        with jax.named_scope("decoder"):
-            table = self.param("embed_tokens", _init(cfg),
-                               (cfg.vocab_size, cfg.hidden_size),
-                               jnp.float32)
-            head = self.param("lm_head", _init(cfg),
-                              (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-            with jax.named_scope("embeddings"):
-                x = table.astype(self.dtype)[input_ids]
-            loads, drops = [], []
-            for i, (kind, heads, ffn) in enumerate(cfg.layer_kinds):
-                x, load, dropped = layer_cls(
-                    cfg, kind, heads, ffn, self.dtype, name=f"layer_{i}")(
-                        x, segment_ids, position_ids)
-                if ffn == "moe":
-                    loads.append(load)
-                    drops.append(dropped)
-            x = RMSNorm(cfg.norm_eps, self.dtype, name="final_norm")(x)
-        return (x, head.astype(self.dtype),
-                jnp.stack(loads) if loads
-                else jnp.zeros((0, cfg.num_experts), jnp.int32),
-                jnp.stack(drops) if drops else jnp.zeros((0,), jnp.int32))
-
-
-def pretrain_loss_fn_builder(model) -> Callable:
-    """loss_fn_builder of training/pretrain.build_pretrain_step: next-token
-    cross-entropy over packed rows, the head a block of tokens at a time,
-    and the routed layers' expert counters as lfm2's."""
-    cfg = model.config
-
-    def loss_fn(params, batch, dropout_rng, deterministic: bool = False):
-        hidden, head, load, dropped = model.apply(
-            {"params": params}, batch["input_ids"], batch["segment_ids"],
-            batch["position_ids"])
-        loss, count = losses.next_token_loss_blocked(
-            hidden, head, batch["input_ids"], batch["segment_ids"],
-            LOSS_BLOCK_ROWS)
-        with jax.named_scope("metrics"):
-            scalars = expert_scalars(cfg, count, batch["input_ids"].size,
-                                     load, dropped)
-        return loss, {"scalars": scalars}
-
-    return loss_fn
+class LagunaForCausalLM(CausalLMTrunk):
+    """decoder.CausalLMTrunk over this family's layers: the loads and drops
+    are the routed layers' (n_routed, E_held) and (n_routed,)."""
+    layer = DecoderLayer
 
 
 def train_flops_per_row(cfg: LagunaConfig, seq_len: int) -> float:

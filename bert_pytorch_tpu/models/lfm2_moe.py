@@ -33,12 +33,14 @@ accumulate in float32.
 Layers are separate modules in a Python loop (they differ in kind, so one
 scan does not carry them). With `checkpoint_activations` each layer is
 rematerialised; `remat_policy` says what the backward pass finds saved
-beside the layer's input (LM_REMAT_POLICIES).
+beside the layer's input (models/decoder.LM_REMAT_POLICIES). What the
+decoder families share (RMSNorm, the products, the dense MLP, the routed
+experts, the counters) is models/decoder.py's.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
@@ -47,63 +49,15 @@ from jax.ad_checkpoint import checkpoint_name
 
 from bert_pytorch_tpu.config import Lfm2MoeConfig
 from bert_pytorch_tpu.models import losses
-from bert_pytorch_tpu.ops import moe as moe_ops
+from bert_pytorch_tpu.models.decoder import (LM_REMAT_POLICIES, DenseMLP,
+                                             RMSNorm, RoutedExperts, _init,
+                                             _Linear, expert_scalars)
+# models/families.py takes the family's `keep_float32` from this module
+from bert_pytorch_tpu.models.decoder import keep_float32  # noqa: F401
 from bert_pytorch_tpu.ops.attention import dot_product_attention
-from bert_pytorch_tpu.ops.decoder_ops import rms_norm, rotary, short_conv
+from bert_pytorch_tpu.ops.decoder_ops import rotary, short_conv
 
 Dtype = Any
-
-# What remat_policy="dense" keeps of a layer besides its input: the output
-# of the operator's input projection (attention's fused q/k/v, the
-# convolution's B/C/X), which spares the backward pass the operator's
-# RMSNorm and that matmul; and the causal flash kernel's output and
-# log-sum-exp (ops/pallas/flash_attention.py names them), which spares it a
-# second run of the forward kernel (67 of 303 ms of attention a step on a
-# v5e, PERF.md PR 26). The FFN's last product needs no saving: in a
-# pre-norm block nothing downstream of it is recomputed, so its recompute is
-# dead code. The same two policy names as models/bert.py, so that
-# training/pretrain.resolve_remat_policy decides for this block as it does
-# for BERT's.
-DENSE_SAVED = ("in_proj_out", "flash_out", "flash_lse")
-LM_REMAT_POLICIES = {
-    "nothing": jax.checkpoint_policies.nothing_saveable,
-    "dense": jax.checkpoint_policies.save_only_these_names(*DENSE_SAVED),
-}
-LM_REMAT_POLICIES["auto"] = LM_REMAT_POLICIES["dense"]
-
-
-# RMSNorm, _Linear, DenseMLP and RoutedExperts are the decoder families'
-# (models/kimi_linear.py imports them): `config` is either family's.
-def _init(cfg) -> Callable:
-    return nn.initializers.normal(stddev=cfg.initializer_range)
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        return rms_norm(x, scale, self.eps, self.dtype)
-
-
-class _Linear(nn.Module):
-    """x @ kernel, no bias: `dtype` operands, float32 accumulation, the
-    result in `out_dtype` (default `dtype`)."""
-    features: int
-    config: Any
-    dtype: Dtype = jnp.bfloat16
-    out_dtype: Any = None
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", _init(self.config),
-                            (x.shape[-1], self.features), jnp.float32)
-        return jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
-                       preferred_element_type=jnp.float32).astype(
-                           self.out_dtype or self.dtype)
 
 
 class ShortConv(nn.Module):
@@ -158,71 +112,6 @@ class Attention(nn.Module):
                                     impl=cfg.attention_impl, causal=True)
         return _Linear(e, cfg, self.dtype, name="out_proj")(
             ctx.reshape(bsz, s, h * d))
-
-
-class DenseMLP(nn.Module):
-    """SwiGLU MLP of `features` (default: the config's dense width)."""
-    config: Any
-    dtype: Dtype = jnp.bfloat16
-    features: Optional[int] = None
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        f = self.features or cfg.intermediate_size
-        gate = _Linear(f, cfg, self.dtype, name="w1")(x)
-        up = _Linear(f, cfg, self.dtype, name="w3")(x)
-        hidden = (jax.nn.silu(gate.astype(jnp.float32))
-                  * up.astype(jnp.float32)).astype(self.dtype)
-        return _Linear(cfg.hidden_size, cfg, self.dtype, name="w2")(hidden)
-
-
-def routed_window_rows(cfg, n_tokens: int) -> int:
-    """Sorted pairs a window of ops/moe.held_experts works on, for a
-    micro-batch of `n_tokens`: twice this rank's even share of the pairs."""
-    pairs = n_tokens * cfg.num_experts_per_tok
-    return min(-(-2 * pairs * cfg.num_experts // cfg.router_width // 512)
-               * 512, pairs)
-
-
-class RoutedExperts(nn.Module):
-    """The routed FFN over the experts this rank holds. Returns (the partial
-    sum (B, S, E) in `dtype`, tokens per held expert (E_held,) int32, held
-    pairs not computed () int32). The router reads `router_input` where it
-    is given (models/smallthinker.py: the layer's input, ahead of the
-    attention) and the tokens the experts compute on otherwise; how it
-    scores and what gates an expert are the config's `router_scores` and
-    `expert_activation`."""
-    config: Any
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x, router_input=None):
-        cfg = self.config
-        bsz, s, e = x.shape
-        f, n_held = cfg.moe_intermediate_size, cfg.num_experts
-        router = self.param("router", _init(cfg), (e, cfg.router_width),
-                            jnp.float32)
-        init = _init(cfg)
-        # the selection bias: a held buffer (no gradient, no update) drawn
-        # like the weights, so that a fresh model selects by score + bias
-        bias = (self.param("expert_bias", init, (cfg.router_width,),
-                           jnp.float32)
-                if cfg.use_expert_bias else None)
-        w1 = self.param("experts_w1", init, (n_held, e, f), jnp.float32)
-        w3 = self.param("experts_w3", init, (n_held, e, f), jnp.float32)
-        w2 = self.param("experts_w2", init, (n_held, f, e), jnp.float32)
-        tokens = x.reshape(bsz * s, e).astype(self.dtype)
-        routing = moe_ops.route(
-            tokens if router_input is None
-            else router_input.reshape(bsz * s, e),
-            router, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob,
-            float(cfg.routed_scaling_factor), cfg.router_scores)
-        out, load, dropped = moe_ops.held_experts(
-            tokens, routing, w1.astype(self.dtype), w3.astype(self.dtype),
-            w2.astype(self.dtype), cfg.held_range,
-            routed_window_rows(cfg, bsz * s), cfg.expert_activation)
-        return out.astype(self.dtype).reshape(bsz, s, e), load, dropped
 
 
 class DecoderLayer(nn.Module):
@@ -292,39 +181,6 @@ class Lfm2MoeForCausalLM(nn.Module):
                 jnp.stack(loads) if loads
                 else jnp.zeros((0, n_held), jnp.int32),
                 jnp.stack(drops) if drops else jnp.zeros((0,), jnp.int32))
-
-
-def init_inputs(batch) -> Tuple:
-    """model.init's inputs from one micro-batch of the loader's fields."""
-    return tuple(jnp.asarray(batch[k]) for k in
-                 ("input_ids", "segment_ids", "position_ids"))
-
-
-def keep_float32(path: Tuple) -> bool:
-    """Parameters the step reads in float32 whatever the compute dtype: the
-    router and its selection bias (the router is float32 by the family's
-    equations; a bfloat16 copy would move top-k selections)."""
-    keys = [str(getattr(k, "key", k)) for k in path]
-    return keys[-1] in ("router", "expert_bias")
-
-
-def expert_scalars(cfg, count, n_tokens: int, load, dropped) -> dict:
-    """A micro-batch's scalars of the decoder families (telemetry/
-    expert_load.py sums them): predicted positions, (token, expert) pairs
-    routed, and per routed layer each held expert's tokens, the held pairs
-    not computed and the windows the layer's loop ran (its trip count, from
-    the pairs it was handed: ops/moe.live_windows)."""
-    scalars = {"lm_positions": count,
-               "moe_pairs_routed": jnp.asarray(
-                   n_tokens * cfg.num_experts_per_tok, jnp.int32)}
-    window_rows = routed_window_rows(cfg, n_tokens)
-    for layer in range(load.shape[0]):
-        scalars[f"moe_l{layer}_dropped"] = dropped[layer]
-        scalars[f"moe_l{layer}_windows"] = moe_ops.live_windows(
-            jnp.sum(load[layer]), window_rows)
-        for j in range(load.shape[1]):
-            scalars[f"moe_l{layer}_e{j}"] = load[layer, j]
-    return scalars
 
 
 def pretrain_loss_fn_builder(model) -> Callable:

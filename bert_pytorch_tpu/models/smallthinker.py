@@ -21,7 +21,7 @@ the keys). For x of shape (T, hidden), layer l with w_l =
     E_i = the k largest of r_i;  g_ie = exp(r_ie) / sum_{e' in E_i} exp(r_ie')
     y = h + sum_{e in E_i, e held} g_ie W2_e(relu(W1_e m_i) * W3_e m_i)
 
-with RMSNorm as models/lfm2_moe.py's (eps 1e-6 here). After the last layer
+with RMSNorm as models/decoder.py's (eps 1e-6 here). After the last layer
 one more RMSNorm (`final_norm`), and the logits are that times an UNTIED
 `lm_head` (V, hidden) transposed. No dense layer, no shared expert, no
 selection bias, no scaling factor. The layer holds the experts
@@ -40,10 +40,9 @@ else.
 
 Layers are separate modules in a Python loop (their window and positions are
 static, so one scan does not carry them), each rematerialised under
-`checkpoint_activations` (`remat_policy`: lfm2_moe.LM_REMAT_POLICIES, the
-same names saved). The model hands back the final norm's output and the
-head, not logits: the loss (losses.next_token_loss_blocked) takes the head a
-block of tokens at a time.
+`checkpoint_activations` (`remat_policy`: decoder.LM_REMAT_POLICIES). The
+trunk, the loss (the head a block of tokens at a time) and the router's
+float32 are the shared module's (models/decoder.py).
 
 Scopes: a layer's attention is `attention/attention_window` or
 `attention/attention_full` (both under `attention`, so that what reads the
@@ -55,7 +54,7 @@ one reads both kinds), a banded layer's rotation
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -63,20 +62,17 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from bert_pytorch_tpu.config import SmallThinkerConfig
-from bert_pytorch_tpu.models import losses
-from bert_pytorch_tpu.models.lfm2_moe import (LM_REMAT_POLICIES, RMSNorm,
-                                              RoutedExperts, _init, _Linear,
-                                              expert_scalars)
-# the router is read in float32, as lfm2's: models/families.py takes the
-# family's `keep_float32` from this module
-from bert_pytorch_tpu.models.lfm2_moe import keep_float32  # noqa: F401
+from bert_pytorch_tpu.models.decoder import (CausalLMTrunk, RMSNorm,
+                                             RoutedExperts, _init, _Linear,
+                                             band_pairs)
+# models/families.py takes the family's loss builder and `keep_float32`
+# (the router is read in float32) from this module
+from bert_pytorch_tpu.models.decoder import (  # noqa: F401
+    keep_float32, pretrain_loss_fn_builder)
 from bert_pytorch_tpu.ops.attention import dot_product_attention
 from bert_pytorch_tpu.ops.decoder_ops import rotary
 
 Dtype = Any
-
-# tokens a block of the loss: (2048, 18992) float32 logits are 156 MB
-LOSS_BLOCK_ROWS = 2048
 
 
 class Attention(nn.Module):
@@ -123,6 +119,8 @@ class DecoderLayer(nn.Module):
     rope: bool
     dtype: Dtype = jnp.bfloat16
 
+    routed = True       # every layer's load is the trunk's to stack
+
     @nn.compact
     def __call__(self, x, segment_ids, position_ids):
         cfg = self.config
@@ -137,68 +135,10 @@ class DecoderLayer(nn.Module):
         return h + out, load, dropped
 
 
-class SmallThinkerForCausalLM(nn.Module):
-    """(input_ids, segment_ids, position_ids), each (B, S) -> (the final
-    norm's output (B, S, hidden) in `dtype`, the head (V, hidden) in
-    `dtype`, per layer: tokens per held expert (L, E_held) int32 and held
-    pairs not computed (L,) int32). segment_ids: the packing contract's
-    (1..n per row, 0 = pad); position_ids restart at each document."""
-    config: SmallThinkerConfig
-    dtype: Dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, input_ids, segment_ids, position_ids):
-        cfg = self.config
-        layer_cls = DecoderLayer
-        if cfg.checkpoint_activations:
-            layer_cls = nn.remat(DecoderLayer,
-                                 policy=LM_REMAT_POLICIES[cfg.remat_policy])
-        with jax.named_scope("decoder"):
-            table = self.param("embed_tokens", _init(cfg),
-                               (cfg.vocab_size, cfg.hidden_size),
-                               jnp.float32)
-            head = self.param("lm_head", _init(cfg),
-                              (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-            with jax.named_scope("embeddings"):
-                x = table.astype(self.dtype)[input_ids]
-            loads, drops = [], []
-            for i, (window, rope) in enumerate(cfg.layer_kinds):
-                x, load, dropped = layer_cls(
-                    cfg, window, rope, self.dtype, name=f"layer_{i}")(
-                        x, segment_ids, position_ids)
-                loads.append(load)
-                drops.append(dropped)
-            x = RMSNorm(cfg.norm_eps, self.dtype, name="final_norm")(x)
-        return x, head.astype(self.dtype), jnp.stack(loads), jnp.stack(drops)
-
-
-def pretrain_loss_fn_builder(model) -> Callable:
-    """loss_fn_builder of training/pretrain.build_pretrain_step: next-token
-    cross-entropy over packed rows, the head a block of tokens at a time,
-    and the layers' expert counters as lfm2's."""
-    cfg = model.config
-
-    def loss_fn(params, batch, dropout_rng, deterministic: bool = False):
-        hidden, head, load, dropped = model.apply(
-            {"params": params}, batch["input_ids"], batch["segment_ids"],
-            batch["position_ids"])
-        loss, count = losses.next_token_loss_blocked(
-            hidden, head, batch["input_ids"], batch["segment_ids"],
-            LOSS_BLOCK_ROWS)
-        with jax.named_scope("metrics"):
-            scalars = expert_scalars(cfg, count, batch["input_ids"].size,
-                                     load, dropped)
-        return loss, {"scalars": scalars}
-
-    return loss_fn
-
-
-def band_pairs(length: int, window: int) -> int:
-    """(query, key) pairs of one document of `length` tokens: key <= query,
-    and under a band (`window` > 0) query - key < window."""
-    if not window or length <= window:
-        return length * (length + 1) // 2
-    return window * (window + 1) // 2 + (length - window) * window
+class SmallThinkerForCausalLM(CausalLMTrunk):
+    """decoder.CausalLMTrunk over this family's layers: every layer is
+    routed, so the loads are (L, E_held) and the drops (L,)."""
+    layer = DecoderLayer
 
 
 def train_flops_per_row(cfg: SmallThinkerConfig, seq_len: int) -> float:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 _INV_SQRT_2PI = float(1.0 / np.sqrt(2.0 * np.pi))
 
@@ -58,9 +57,7 @@ def _gelu_fwd(x: jax.Array) -> tuple[jax.Array, tuple[jax.Array]]:
     phi = jnp.exp(-0.5 * x32 * x32) * _INV_SQRT_2PI
     e32 = lax.optimization_barrier(e).astype(jnp.float32)
     d = (0.5 * e32 + x32 * phi).astype(x.dtype)
-    # (B, S, F)-wide in BertLayer, which gives its other two values of that
-    # width the same name, for remat policies that go by names.
-    return y, (checkpoint_name(d, "mlp_wide"),)
+    return y, (d,)
 
 
 def _gelu_bwd(res: tuple[jax.Array], dy: jax.Array) -> tuple[jax.Array]:

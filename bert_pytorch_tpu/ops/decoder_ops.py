@@ -1,6 +1,7 @@
-"""Elementwise pieces of the decoder families (models/lfm2_moe.py): RMSNorm,
-rotary positions (over the whole head or a part of it, at a given table of
-frequencies), and the depthwise causal short convolution. Plain
+"""Elementwise pieces of the decoder families (models/decoder.py and the
+family modules beside it): RMSNorm, rotary positions (over the whole head
+or a part of it, at a given table of frequencies), and the depthwise
+causal short convolution. Plain
 jax.numpy in float32 (XLA fuses each into its neighbours), but for the
 rotation of heads of 128 lanes on a TPU, which is one kernel call a
 direction (ops/pallas/rotary.py; `rotary` says which calls take it); every

@@ -155,15 +155,8 @@ from jax.experimental import pallas as pl
 # counts what is left on the tile, per kernel.
 
 
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, default))
-    except (TypeError, ValueError):
-        return default
-
-
-DEFAULT_BLK_Q = _env_int("FLASH_BLK_Q", 512)
-DEFAULT_BLK_K = _env_int("FLASH_BLK_K", 512)
+# 256 / 512 / 1,024 raced on a v5e (PERF.md, PR 26)
+DEFAULT_BLK_Q = DEFAULT_BLK_K = 512
 NEG_INF = -1e30
 _SEG_BIG = 2 ** 30  # sentinel above any real segment index
 
@@ -1676,7 +1669,7 @@ def _flash_fwd(q, k, v, bias, segment_ids, seed, rate, interpret,
         **params,
     )(*live, *rng, *sel, _seed_operand(seed), qx, kx, vx, bias2, seg2, seg2)
     if causal:
-        # names a rematerialising caller may keep (models/lfm2_moe.py
+        # names a rematerialising caller may keep (models/decoder.py
         # DENSE_SAVED): with both saved the backward pass finds the kernel's
         # results and does not run it again
         out = checkpoint_name(out, "flash_out")

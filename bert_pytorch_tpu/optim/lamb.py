@@ -3,13 +3,7 @@
 The reference's large-batch path is apex `FusedLAMB` (run_pretraining.py:285),
 a fused CUDA multi-tensor implementation of NVLAMB. Semantics reproduced here
 as a pure optax GradientTransformation, jitted into the train step so XLA
-fuses the whole update. An optional multi-tensor Pallas path
-(`fused=True` -> ops/pallas/fused_optim.py, the amp_C stage1/stage2
-analogue) flattens the leaves into size-capped flat buckets and runs one
-launch per bucket per stage; off-TPU it auto-selects an XLA fallback that
-is bit-identical to the unfused chain (the kernel itself agrees to within
-a few ulps — see the numerics contract in fused_optim.py, pinned in
-tests/test_pallas.py). NVLAMB specifics honored:
+fuses the whole update, a fusion a leaf. NVLAMB specifics honored:
 
 1. optional pre-normalization of the *global* gradient by
    max(1, ||g||_global / max_grad_norm)  (apex FusedLAMB max_grad_norm=1.0),
@@ -58,8 +52,6 @@ def lamb(
     bias_correction: bool = True,
     trust_batch_axes: Optional[Callable[[Any], Any]] = None,
     norm_reducer: Optional[Any] = None,
-    fused: bool = False,
-    fused_impl: str = "auto",
 ) -> optax.GradientTransformation:
     """apex-FusedLAMB-semantics LAMB. `weight_decay_mask(params)` returns a
     pytree of bools — True where decay applies. `trust_batch_axes(params)`
@@ -76,24 +68,7 @@ def lamb(
     see graph_report kfac_zero1_dp8). Values are bit-identical to the
     per-tensor path (same local reduce, same per-element cross-device
     sum — pinned in tests); None keeps the original per-tensor code
-    byte-for-byte.
-
-    `fused=True` routes the elementwise update chain (moment update +
-    update direction, then the trust-ratio apply) through the bucketed
-    multi-tensor kernels in ops/pallas/fused_optim.py — one launch per
-    size-capped flat bucket per stage instead of one fusion per leaf. The
-    trust NORMS stay in this module's existing per-tensor/norm_reducer
-    path, so all reduction grouping is untouched. `fused_impl`: "auto"
-    (Pallas kernel on TPU; elsewhere an XLA fallback that evaluates the
-    same expressions per leaf and is BIT-identical to fused=False), or
-    "pallas"/"xla" to force — the kernel agrees with the fallback to
-    within a few ulps (cross-program FMA-contraction ambiguity; see the
-    numerics contract in fused_optim.py). Both pinned in
-    tests/test_pallas.py. With a ZeRO-1-sharded state
-    also pass `norm_reducer`: the fused stages reuse its mesh + leaf
-    specs to run shard_mapped on local shards (zero extra collectives);
-    without it GSPMD would reshard the leaves around each bucket
-    concat."""
+    byte-for-byte."""
 
     def init(params):
         zeros = lambda: jax.tree.map(
@@ -126,15 +101,12 @@ def lamb(
             g = g.astype(jnp.float32)
             return g / denom if denom is not None else g
 
-        if not fused:
-            # two traversals, one HLO: XLA CSEs the shared g/denom
-            # subexpression (the fused path computes the moments inside
-            # the stage1 bucket kernels instead)
-            mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * norm_g(g),
-                              state.mu, grads)
-            nu = jax.tree.map(
-                lambda v, g: b2 * v + (1 - b2) * jnp.square(norm_g(g)),
-                state.nu, grads)
+        # two traversals, one HLO: XLA CSEs the shared g/denom subexpression
+        mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * norm_g(g),
+                          state.mu, grads)
+        nu = jax.tree.map(
+            lambda v, g: b2 * v + (1 - b2) * jnp.square(norm_g(g)),
+            state.nu, grads)
 
         if bias_correction:
             c1 = 1.0 - b1 ** cf
@@ -154,53 +126,6 @@ def lamb(
             ba_tree = jax.tree.map(lambda _: 0, params)
 
         lr = learning_rate(count - 1) if callable(learning_rate) else learning_rate
-
-        if fused:
-            from bert_pytorch_tpu.ops.pallas import fused_optim
-
-            flat_g, treedef = jax.tree_util.tree_flatten(grads)
-            flat_p = jax.tree.leaves(params)
-            gf = [g.astype(jnp.float32) for g in flat_g]
-            pf_l = [p.astype(jnp.float32) for p in flat_p]
-            # a NormReducer carries the mesh + per-leaf specs the train
-            # step constrains everything to; reuse them so the bucket
-            # kernels run shard_mapped on local shards
-            mesh = getattr(norm_reducer, "mesh", None)
-            specs = getattr(norm_reducer, "_specs", None)
-            mu_l, nu_l, u_l = fused_optim.lamb_stage1(
-                gf, jax.tree.leaves(state.mu), jax.tree.leaves(state.nu),
-                pf_l, jax.tree.leaves(wd_tree),
-                denom=denom if denom is not None else 1.0,
-                c1=c1, c2=c2, b1=b1, b2=b2, eps=eps,
-                impl=fused_impl, mesh=mesh, specs=specs)
-            unf = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
-            pf_tree, u_tree = unf(pf_l), unf(u_l)
-            if norm_reducer is not None:
-                pn_tree, un_tree = norm_reducer.trust_norms(
-                    pf_tree, u_tree, ba_tree)
-            else:
-                def tnorm(x, nbatch):
-                    axes = tuple(range(nbatch, x.ndim))
-                    return jnp.sqrt(jnp.sum(jnp.square(x), axis=axes,
-                                            keepdims=True))
-
-                pn_tree = jax.tree.map(tnorm, pf_tree, ba_tree)
-                un_tree = jax.tree.map(tnorm, u_tree, ba_tree)
-
-            def ratio_t(u, pn, un):
-                ratio = jnp.where((pn > 0) & (un > 0),
-                                  pn / jnp.maximum(un, 1e-30), 1.0)
-                return jnp.broadcast_to(-lr * ratio, u.shape)
-
-            t_l = [ratio_t(u, pn, un) for u, pn, un in
-                   zip(u_l, jax.tree.leaves(pn_tree),
-                       jax.tree.leaves(un_tree))]
-            upd_l = fused_optim.lamb_stage2(t_l, u_l, impl=fused_impl,
-                                            mesh=mesh, specs=specs)
-            updates = unf([u.astype(p.dtype)
-                           for u, p in zip(upd_l, flat_p)])
-            return updates, LambState(count=count, mu=unf(mu_l),
-                                      nu=unf(nu_l))
 
         def per_tensor(p, m, v, wd, nbatch):
             pf = p.astype(jnp.float32)
@@ -250,7 +175,7 @@ def default_trust_batch_axes(params: Any) -> Any:
     def n_batch(path: tuple) -> int:
         keys = [str(getattr(k, "key", k)) for k in path]
         # a routed layer's (E, ...) expert stacks: one ratio per expert
-        # matrix (models/lfm2_moe.RoutedExperts), as for a scan's layers
+        # matrix (models/decoder.RoutedExperts), as for a scan's layers
         return 1 if ("layers" in keys
                      or keys[-1].startswith("experts_")) else 0
 

@@ -1,6 +1,6 @@
 """Cumulative counters of the routed (mixture-of-experts) layers for the
 `[perf]` record, summed on the host from the per-expert scalars a step of
-the decoder families returns (models/lfm2_moe.expert_scalars:
+the decoder families returns (models/decoder.expert_scalars:
 `moe_l<layer>_e<expert>`, `moe_l<layer>_dropped`, `moe_l<layer>_windows`,
 `moe_pairs_routed`), and
 of the gated delta-rule scans (models/kimi_linear.py: `kda_tokens`,

@@ -34,14 +34,10 @@ def git_sha(cwd: Optional[str] = None) -> str:
         return "unknown"
 
 
-def collect(mesh=None, device: bool = True,
+def collect(mesh=None,
             extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """One provenance dict for log headers.
-
-    device=False skips every field that would touch the jax backend — a
-    parent process must not initialize the TPU while its children try to
-    attach.
-    """
+    """One provenance dict for log headers. Touches the jax backend (the
+    device fields): for the process that holds the device."""
     import jax
     import jaxlib
 
@@ -54,15 +50,14 @@ def collect(mesh=None, device: bool = True,
         "time_unix": round(time.time(), 3),
         **pack_state(),
     }
-    if device:
-        try:
-            d = jax.devices()[0]
-            out["platform"] = d.platform
-            out["device_kind"] = d.device_kind
-            out["device_count"] = jax.device_count()
-            out["process_count"] = jax.process_count()
-        except Exception:
-            out["platform"] = "unknown"
+    try:
+        d = jax.devices()[0]
+        out["platform"] = d.platform
+        out["device_kind"] = d.device_kind
+        out["device_count"] = jax.device_count()
+        out["process_count"] = jax.process_count()
+    except Exception:
+        out["platform"] = "unknown"
     if mesh is not None:
         out["mesh"] = {k: int(v) for k, v in dict(mesh.shape).items()}
     if extra:

@@ -201,17 +201,6 @@ def parse_arguments(argv=None):
                              "tests against the all-reduce arm of the "
                              "same program); needs a data-only mesh "
                              "(every non-data axis trivial)")
-    parser.add_argument("--fused_optim", type=str, default="off",
-                        choices=["off", "auto", "xla", "pallas"],
-                        help="fused multi-tensor LAMB update (ops/pallas/"
-                             "fused_optim.py, the apex FusedLAMB / amp_C "
-                             "analogue): flatten the update math across "
-                             "leaves into fixed-size blocks — one kernel "
-                             "sweep instead of per-leaf op soup. 'auto' "
-                             "picks pallas on TPU, xla elsewhere; the xla "
-                             "impl is bit-identical to off, the pallas "
-                             "kernel agrees to a few ulps (lamb only; "
-                             "other --optimizer values ignore this)")
     parser.add_argument("--fsdp_overlap", action="store_true",
                         help="gather-on-use for fsdp-RESIDENT params "
                              "(parallel/zero.make_fsdp_plan): each param's "
@@ -557,14 +546,13 @@ class SLOBreachHalt(RuntimeError):
     past --slo_halt_after_s. Exits EXIT_SLO_BREACH (76) — retryable."""
 
 
-def make_optimizer(name: str, schedule, norm_reducer=None, fused="off"):
+def make_optimizer(name: str, schedule, norm_reducer=None):
     """The pretraining optimizer zoo, keyed by --optimizer. Module-level so
     tools/replay.py rebuilds the exact same transformation chain from a
     flight-recorder manifest — one construction site, no drift.
     `norm_reducer` (parallel/coalesce.NormReducer, --coalesce_reductions)
     buckets LAMB's trust-norm/global-norm all-reduces; the other
-    optimizers have no per-tensor norms to coalesce. `fused` is the
-    --fused_optim choice — the multi-tensor update path, LAMB only."""
+    optimizers have no per-tensor norms to coalesce."""
     from bert_pytorch_tpu.optim import adam
     from bert_pytorch_tpu.optim.lamb import (lamb,
                                              default_weight_decay_mask,
@@ -574,9 +562,7 @@ def make_optimizer(name: str, schedule, norm_reducer=None, fused="off"):
         return lamb(schedule, weight_decay=0.01,
                     weight_decay_mask=default_weight_decay_mask,
                     trust_batch_axes=default_trust_batch_axes,
-                    norm_reducer=norm_reducer,
-                    fused=fused != "off",
-                    fused_impl="auto" if fused in ("off", "auto") else fused)
+                    norm_reducer=norm_reducer)
     if name == "bert_adam":
         return adam.bert_adam(schedule, weight_decay=0.01,
                               weight_decay_mask=default_weight_decay_mask)
@@ -808,8 +794,7 @@ def main(argv=None):
             args.lr_decay, args.learning_rate, args.max_steps,
             warmup=args.warmup_proportion,
             offset=args.previous_phase_end_step)
-        tx = make_optimizer(args.optimizer, schedule,
-                            fused=args.fused_optim)
+        tx = make_optimizer(args.optimizer, schedule)
 
         kfac = None
         if args.kfac:
@@ -1057,8 +1042,7 @@ def main(argv=None):
             # identical (the state above restores/donates unchanged),
             # only the update's norm reductions re-route
             tx = make_optimizer(args.optimizer, schedule,
-                                norm_reducer=norm_reducer,
-                                fused=args.fused_optim)
+                                norm_reducer=norm_reducer)
             logger.info("coalesce_reductions: trust-norm/global-norm "
                         "all-reduces bucketed (parallel/coalesce.py)")
         elif coalesce and kfac is not None and kfac.bucketed:
@@ -1292,7 +1276,6 @@ def main(argv=None):
                                       and zero1_plan.gather_on_use),
                     "zero1_rs": (zero1_plan is not None
                                  and zero1_plan.reduce_scatter),
-                    "fused_optim": args.fused_optim,
                     "fsdp_overlap": (plan is not None
                                      and plan.axis == "fsdp"),
                     "mesh_config": mesh_config_name,

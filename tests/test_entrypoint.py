@@ -77,7 +77,7 @@ def test_run_pretraining_end_to_end_and_resume(workdir):
 
 @pytest.mark.slow
 def test_run_pretraining_zero1_rs_smoke(workdir):
-    """--zero1_rs + --fused_optim xla through the real entrypoint on the
+    """--zero1_rs through the real entrypoint on the
     8-device CPU mesh: the plan reports the psum_scatter exit, training
     completes, metrics flow. Value parity and collective counts are pinned
     elsewhere (tests/test_zero1.py, the zero1_rs_dp8 budget) — this is the
@@ -89,7 +89,7 @@ def test_run_pretraining_zero1_rs_smoke(workdir):
     argv = ["--config_file", str(run_path), "--input_dir", str(data),
             "--output_dir", str(out), "--mask_token_index", "3",
             "--dtype", "float32", "--vocab_pad_multiple", "8",
-            "--zero1", "true", "--zero1_rs", "--fused_optim", "xla",
+            "--zero1", "true", "--zero1_rs",
             "--coalesce_reductions", "on"]
     final_step, _ = run_pretraining.main(argv)
     assert final_step == 3

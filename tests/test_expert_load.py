@@ -1,5 +1,5 @@
 """The routed layers' counters (telemetry/expert_load.py) and the scalars
-the step hands them (models/lfm2_moe.expert_scalars): `moe_l<L>_windows` is
+the step hands them (models/decoder.expert_scalars): `moe_l<L>_windows` is
 the trip count of the layer's loop over windows (ops/moe.live_windows), one
 a layer pass at an even load and as many as the held pairs fill beyond
 that."""
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from bert_pytorch_tpu.config import Lfm2MoeConfig
-from bert_pytorch_tpu.models import lfm2_moe
+from bert_pytorch_tpu.models import decoder
 from bert_pytorch_tpu.ops import moe as moe_ops
 from bert_pytorch_tpu.telemetry.expert_load import ExpertLoadCounters
 
@@ -30,9 +30,9 @@ TOKENS = 512
     ([0, 512, 512, 0], 2),          # every token to two held experts
 ], ids=["even", "none", "full", "one_more", "all_held"])
 def test_windows_scalar_is_the_loops_trip_count(load, windows):
-    assert lfm2_moe.routed_window_rows(CFG, TOKENS) == 512
+    assert decoder.routed_window_rows(CFG, TOKENS) == 512
     load = jnp.asarray([load, [64, 64, 64, 64]], jnp.int32)
-    scalars = jax.jit(lambda load: lfm2_moe.expert_scalars(
+    scalars = jax.jit(lambda load: decoder.expert_scalars(
         CFG, jnp.int32(7), TOKENS, load, jnp.zeros((2,), jnp.int32)))(load)
     assert int(scalars["moe_l0_windows"]) == windows
     assert int(scalars["moe_l1_windows"]) == 1
@@ -51,11 +51,11 @@ def test_the_scalar_counts_what_the_layer_ran(bias, windows):
     for expert, value in bias.items():
         b = b.at[expert].set(value)
     w = jax.random.normal(jax.random.PRNGKey(2), (3, 4, 64, 32)) * 0.1
-    rows = lfm2_moe.routed_window_rows(CFG, TOKENS)
+    rows = decoder.routed_window_rows(CFG, TOKENS)
     _, load, dropped = moe_ops.held_experts(
         x, moe_ops.route(x, kernel, b, 2, True, 1.0), w[0], w[1],
         w[2].transpose(0, 2, 1), CFG.held_range, rows)
-    scalars = lfm2_moe.expert_scalars(CFG, jnp.int32(0), TOKENS, load[None],
+    scalars = decoder.expert_scalars(CFG, jnp.int32(0), TOKENS, load[None],
                                       dropped[None])
     assert int(dropped) == 0
     assert int(scalars["moe_l0_windows"]) == windows == -(

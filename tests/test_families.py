@@ -219,3 +219,124 @@ def test_bert_refuses_none_of_them():
     args = argparse.Namespace(kfac=False, stream_dir=None,
                               stacked_params="auto", steps_per_loop=1)
     assert FAMILIES["lfm2_moe"].refusal(args) is None
+
+
+DECODERS = sorted(set(FAMILIES) - {"bert"})
+MODELS_DIR = os.path.join(ROOT, "bert_pytorch_tpu", "models")
+
+
+def _models_imported(module: str) -> set:
+    """The modules of bert_pytorch_tpu/models that models/<module>.py
+    imports, read from its source."""
+    import ast
+
+    with open(os.path.join(MODELS_DIR, f"{module}.py")) as f:
+        tree = ast.parse(f.read())
+    prefix, found = "bert_pytorch_tpu.models", set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.module:
+            if node.module == prefix:
+                found.update(alias.name for alias in node.names)
+            elif node.module.startswith(prefix + "."):
+                found.add(node.module[len(prefix) + 1:].split(".")[0])
+        elif isinstance(node, ast.Import):
+            found.update(alias.name[len(prefix) + 1:].split(".")[0]
+                         for alias in node.names
+                         if alias.name.startswith(prefix + "."))
+    return found
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_a_family_module_imports_no_other_family(name):
+    """What the decoder families share is models/decoder.py's: a change to
+    one family's module is a change to one family's program."""
+    imported = _models_imported(name)
+    assert "decoder" in imported
+    assert not imported & (set(DECODERS) - {name}), imported
+
+
+def test_the_shared_decoder_module_imports_no_family():
+    assert not _models_imported("decoder") & set(DECODERS)
+    # and the block of the loss is one number in the package
+    assigned = [
+        path for path in Path(ROOT, "bert_pytorch_tpu").rglob("*.py")
+        if any(line.startswith("LOSS_BLOCK_ROWS =")
+               for line in path.read_text().splitlines())]
+    assert [p.name for p in assigned] == ["decoder.py"]
+
+
+# to_dict()'s keys of each decoder config class, in order, as they stood
+# before the classes took their shared members from config.DecoderConfig
+# (PR 46): a run's header, a bundle's manifest and a checkpoint's metadata
+# carry them
+TO_DICT_KEYS = {
+    "lfm2_moe": (
+        "model_type vocab_size hidden_size intermediate_size "
+        "moe_intermediate_size num_hidden_layers num_dense_layers "
+        "num_attention_heads num_key_value_heads num_experts "
+        "num_experts_per_tok layer_types layers_kept experts_total experts_held "
+        "conv_L_cache conv_bias norm_eps norm_topk_prob use_expert_bias "
+        "routed_scaling_factor rope_theta max_position_embeddings "
+        "initializer_range model_name dtype checkpoint_activations remat_policy "
+        "attention_impl"),
+    "kimi_linear": (
+        "model_type vocab_size hidden_size intermediate_size "
+        "moe_intermediate_size num_hidden_layers first_k_dense_replace "
+        "num_attention_heads num_key_value_heads kv_lora_rank q_lora_rank "
+        "qk_nope_head_dim qk_rope_head_dim v_head_dim mla_use_nope num_experts "
+        "num_experts_per_token num_shared_experts num_expert_group topk_group "
+        "moe_renormalize moe_router_activation_func routed_scaling_factor "
+        "rms_norm_eps tie_word_embeddings kda_layers full_attn_layers "
+        "kda_num_heads kda_head_dim short_conv_kernel_size kda_chunk_size "
+        "kda_gate_rank layers_kept experts_total experts_held initializer_range "
+        "model_name dtype checkpoint_activations remat_policy attention_impl"),
+    "smallthinker": (
+        "model_type vocab_size hidden_size head_dim num_hidden_layers "
+        "num_attention_heads num_key_value_heads moe_ffn_hidden_size "
+        "moe_num_primary_experts moe_num_active_primary_experts "
+        "moe_primary_router_apply_softmax norm_topk_prob rope_layout "
+        "sliding_window_layout sliding_window_size rope_theta rope_scaling "
+        "rms_norm_eps max_position_embeddings tie_word_embeddings experts_total "
+        "experts_held initializer_range model_name dtype checkpoint_activations "
+        "remat_policy attention_impl"),
+    "laguna": (
+        "model_type vocab_size hidden_size intermediate_size num_hidden_layers "
+        "num_attention_heads num_key_value_heads head_dim "
+        "max_position_embeddings attention_bias rms_norm_eps num_experts "
+        "num_experts_per_tok moe_intermediate_size "
+        "shared_expert_intermediate_size tie_word_embeddings gating "
+        "sliding_window layer_types mlp_layer_types "
+        "num_attention_heads_per_layer moe_apply_router_weight_on_input "
+        "partial_rotary_factor moe_routed_scaling_factor rope_full_attention "
+        "rope_sliding_attention experts_total experts_held initializer_range "
+        "model_name dtype checkpoint_activations remat_policy attention_impl"),
+    "keye": (
+        "model_type vocab_size hidden_size intermediate_size num_hidden_layers "
+        "num_attention_heads num_key_value_heads head_dim "
+        "max_position_embeddings max_window_layers attention_bias hidden_act "
+        "rms_norm_eps decoder_sparse_step mlp_only_layers moe_intermediate_size "
+        "norm_topk_prob num_experts num_local_experts num_experts_per_tok "
+        "rope_theta mrope_section rope_type sa_indexer_head_dim "
+        "sa_indexer_num_heads sa_indexer_num_kv_heads sa_q_chunk_size "
+        "sa_kv_chunk_size sa_topk sliding_window use_sliding_window "
+        "tie_word_embeddings experts_total experts_held initializer_range "
+        "model_name dtype checkpoint_activations remat_policy attention_impl"),
+}
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_decoder_config_classes_share_their_members(name):
+    from bert_pytorch_tpu.config import DecoderConfig
+
+    cls = MODEL_FAMILIES[name]
+    assert issubclass(cls, DecoderConfig)
+    for member in ("from_dict", "from_json_file", "to_dict", "replace",
+                   "router_width", "held_range"):
+        assert member not in vars(cls), member
+        assert member in vars(DecoderConfig), member
+    assert " ".join(cls().to_dict()) == TO_DICT_KEYS[name]
+    # the family's name is in the one unknown-key message
+    with pytest.raises(ValueError, match=f"{name} model config: unknown "
+                                         r"key\(s\) \['conv_L_cache_2'\]"):
+        cls.from_dict({"conv_L_cache_2": 3})
+    assert TINY[name].held_range == (0, 2) and TINY[name].router_width == 4

@@ -222,6 +222,33 @@ def test_replay_earlier_clean_step_matches(nan_run):
     assert math.isfinite(res["replayed"]["loss"])
 
 
+@pytest.mark.parametrize("recorded, replays", [("off", True),
+                                               ("pallas", False)])
+def test_replay_of_a_bundle_that_names_fused_optim(nan_run, tmp_path,
+                                                   recorded, replays):
+    """Bundles written before --fused_optim went carry the key in their run
+    block. "off" was the per-leaf update, which is the one path left: it
+    replays as before. Any other value was recorded on a path that differed
+    from it by ulps and is refused in one sentence."""
+    import tools.replay as replay
+
+    old = tmp_path / "old_bundle"
+    shutil.copytree(nan_run["bundles"][0], old)
+    manifest = json.load(open(old / "manifest.json"))
+    assert "fused_optim" not in manifest["run"]
+    manifest["run"]["fused_optim"] = recorded
+    (old / "manifest.json").write_text(json.dumps(manifest))
+    assert validate_bundle(str(old)) == []
+    if replays:
+        res = replay.main(["--bundle", str(old), "--step", "2"])
+        assert res["match"] is True, res["mismatches"]
+    else:
+        with pytest.raises(replay.ReplayError,
+                           match="--fused_optim pallas.*by ulps"):
+            replay.main(["--bundle", str(old)])
+        assert replay._cli(["--bundle", str(old)]) != 0
+
+
 # -- --validate schema check -------------------------------------------------
 
 def test_validate_ok(nan_run):

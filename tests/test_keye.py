@@ -32,7 +32,7 @@ sys.path.insert(0, ROOT)
 from benchmark.reference import keye_ref as ref  # noqa: E402
 from bert_pytorch_tpu.config import (KeyeConfig,  # noqa: E402
                                      load_model_config)
-from bert_pytorch_tpu.models import keye, lfm2_moe  # noqa: E402
+from bert_pytorch_tpu.models import decoder, keye  # noqa: E402
 from bert_pytorch_tpu.ops import sparse_index  # noqa: E402
 from bert_pytorch_tpu.ops.attention import (dot_product_attention,  # noqa: E402
                                             unpack_select)
@@ -121,7 +121,7 @@ def test_parameter_tree_is_the_references_and_no_gain_decays(toy):
 
     cfg, sizes, params, model, batch = toy
     init = jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                          *lfm2_moe.init_inputs(batch))
+                          *decoder.init_inputs(batch))
     shapes = jax.tree.map(jnp.shape, init["params"])
     assert shapes == jax.tree.map(jnp.shape, params)
     attention = shapes["layer_1"]["attention"]
@@ -180,7 +180,7 @@ def test_logits_match_the_reference(toy):
     row_forward = jax.jit(ref.row_forward, static_argnames=("sz",))
     with jax.default_matmul_precision("highest"):
         hidden, head, load, dropped, picked = jax.jit(model.apply)(
-            {"params": params}, *lfm2_moe.init_inputs(batch))
+            {"params": params}, *decoder.init_inputs(batch))
         logits = hidden @ head.T
         for r in range(2):
             want, counts, _ = row_forward(

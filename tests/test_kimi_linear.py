@@ -25,7 +25,7 @@ from benchmark.harness import kimi_adapter  # noqa: E402
 from benchmark.reference import kimi_linear_ref as ref  # noqa: E402
 from bert_pytorch_tpu.config import (KimiLinearConfig,  # noqa: E402
                                      load_model_config)
-from bert_pytorch_tpu.models import kimi_linear, lfm2_moe  # noqa: E402
+from bert_pytorch_tpu.models import decoder, kimi_linear  # noqa: E402
 from bert_pytorch_tpu.ops.attention import dot_product_attention  # noqa: E402
 from bert_pytorch_tpu.ops.kda import (kda_scan, kernel_mode,  # noqa: E402
                                       unit_lower_inverse)
@@ -89,7 +89,7 @@ def test_parameter_tree_is_the_references(toy):
     """The reference keeps its weights under the program's names: what the
     program initialises and what the benchmark hands it are one tree."""
     cfg, sizes, params, model, batch = toy
-    init = model.init(jax.random.PRNGKey(0), *lfm2_moe.init_inputs(batch))
+    init = model.init(jax.random.PRNGKey(0), *decoder.init_inputs(batch))
     assert (jax.tree.map(jnp.shape, init["params"])
             == jax.tree.map(jnp.shape, params))
     kda = params["layer_1"]["kda"]

@@ -27,7 +27,7 @@ sys.path.insert(0, ROOT)
 from benchmark.reference import laguna_ref as ref  # noqa: E402
 from bert_pytorch_tpu.config import (LagunaConfig,  # noqa: E402
                                      load_model_config)
-from bert_pytorch_tpu.models import laguna, lfm2_moe  # noqa: E402
+from bert_pytorch_tpu.models import decoder, laguna  # noqa: E402
 from bert_pytorch_tpu.ops.attention import dot_product_attention  # noqa: E402
 from bert_pytorch_tpu.ops.decoder_ops import (rotary,  # noqa: E402
                                               rotary_table)
@@ -109,7 +109,7 @@ def test_parameter_tree_is_the_references_and_no_gain_decays(toy):
 
     cfg, sizes, params, model, batch = toy
     init = jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                          *lfm2_moe.init_inputs(batch))
+                          *decoder.init_inputs(batch))
     shapes = jax.tree.map(jnp.shape, init["params"])
     assert shapes == jax.tree.map(jnp.shape, params)
     assert [shapes[f"layer_{i}"]["attention"]["q_proj"][1] // 16
@@ -131,7 +131,7 @@ def test_logits_match_the_reference(toy):
     row_forward = jax.jit(ref.row_forward, static_argnames=("sz",))
     with jax.default_matmul_precision("highest"):
         hidden, head, load, dropped = jax.jit(model.apply)(
-            {"params": params}, *lfm2_moe.init_inputs(batch))
+            {"params": params}, *decoder.init_inputs(batch))
         logits = hidden @ head.T
         for r in range(2):
             want, counts, _ = row_forward(
@@ -282,7 +282,7 @@ def test_per_layer_head_counts_and_tables_reach_the_attention_call(
     plain = laguna.LagunaForCausalLM(
         cfg.replace(checkpoint_activations=False), dtype=jnp.float32)
     jax.eval_shape(plain.apply, {"params": params},
-                   *lfm2_moe.init_inputs(batch))
+                   *decoder.init_inputs(batch))
     assert seen == [(6, 2, None, True), (8, 2, 12, True), (8, 2, 12, True),
                     (8, 2, 12, True), (6, 2, None, True)]
 

@@ -25,7 +25,7 @@ from benchmark.harness import adapter, lm_adapter  # noqa: E402
 from benchmark.reference import lfm2_moe_ref as ref  # noqa: E402
 from bert_pytorch_tpu.config import (BertConfig, Lfm2MoeConfig,  # noqa: E402
                                      load_model_config)
-from bert_pytorch_tpu.models import lfm2_moe  # noqa: E402
+from bert_pytorch_tpu.models import decoder, lfm2_moe  # noqa: E402
 from bert_pytorch_tpu.ops import moe as moe_ops  # noqa: E402
 from bert_pytorch_tpu.ops.attention import dot_product_attention  # noqa: E402
 from bert_pytorch_tpu.ops.decoder_ops import short_conv  # noqa: E402
@@ -77,7 +77,7 @@ def toy():
 def test_parameter_tree_is_the_adapters(toy):
     cfg, sizes, params, model, batch = toy
     shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), *lfm2_moe.init_inputs(batch)))["params"]
+        jax.random.PRNGKey(0), *decoder.init_inputs(batch)))["params"]
     ours = lm_adapter.to_program_tree(params)
     assert jax.tree.structure(shapes) == jax.tree.structure(ours)
     assert all(a.shape == b.shape for a, b in zip(jax.tree.leaves(shapes),
@@ -648,7 +648,7 @@ def test_every_instruction_of_the_step_is_under_an_lm_scope(toy):
         model, tx, schedule=schedule, accum_steps=2,
         grad_dtype=jnp.bfloat16, health=HealthConfig(action="log"),
         loss_fn_builder=lfm2_moe.pretrain_loss_fn_builder,
-        keep_float32=lfm2_moe.keep_float32)
+        keep_float32=decoder.keep_float32)
     stacked = {k: jnp.stack([v, v]) for k, v in batch.items()}
     text = jax.jit(step).lower(state, stacked,
                                jax.random.PRNGKey(0)).compile().as_text()
@@ -667,5 +667,5 @@ def test_every_instruction_of_the_step_is_under_an_lm_scope(toy):
     for inner in ("router", "dispatch", "experts", "combine"):
         assert any(f"/moe/{inner}/" in name for name in found["moe"]), inner
     # the router is read in float32, everything else in the compute dtype
-    assert lfm2_moe.keep_float32((jax.tree_util.DictKey("moe"),
-                                  jax.tree_util.DictKey("router")))
+    assert decoder.keep_float32((jax.tree_util.DictKey("moe"),
+                                 jax.tree_util.DictKey("router")))

@@ -159,10 +159,9 @@ def test_gelu_rule_under_vmap_and_remat():
         np.asarray(jax.vmap(jax.grad(lambda r: gelu(r).sum()))(x)), want)
     np.testing.assert_array_equal(np.asarray(jax.grad(
         lambda v: jax.checkpoint(gelu)(v).sum())(x)), want)
-    for policy in ("nothing", "mlp_only"):
-        np.testing.assert_array_equal(np.asarray(jax.grad(
-            lambda v: jax.checkpoint(
-                gelu, policy=_REMAT_POLICIES[policy])(v).sum())(x)), want)
+    np.testing.assert_array_equal(np.asarray(jax.grad(
+        lambda v: jax.checkpoint(
+            gelu, policy=_REMAT_POLICIES["nothing"])(v).sum())(x)), want)
 
 
 def test_bert_model_shapes():
@@ -267,7 +266,7 @@ def assert_remat_matches(make_model, cfg, policy, inputs, **kw):
 
 
 # None: the field's default ("auto"), as --checkpoint_activations leaves it
-REMAT_POLICIES = [None, "dense", "nothing", "dots", "mlp_only"]
+REMAT_POLICIES = [None, "dense", "nothing"]
 
 
 @pytest.mark.parametrize("policy", REMAT_POLICIES)

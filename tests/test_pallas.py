@@ -777,110 +777,3 @@ def test_explicit_pallas_runs_the_kernel_in_interpret_mode(monkeypatch):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-# -- multi-tensor -----------------------------------------------------------
-#
-# The fused LAMB update (round 16, ops/pallas/fused_optim.py — the apex
-# FusedLAMB / amp_C multi-tensor analogue). Parity tiers are deliberate:
-# the XLA fallback evaluates the SAME per-leaf math as the unfused
-# optimizer, so it must be BIT-identical; the Pallas kernel flattens
-# leaves into fixed blocks, which reassociates the odd FMA, so stage 1 is
-# gated at a few ulps while stage 2 (t + ratio*u, no reduction) stays
-# exact. See the module docstring for the measured ambiguity.
-
-
-def _fused_fixture(seed=0):
-    from bert_pytorch_tpu.optim import schedulers
-    from bert_pytorch_tpu.optim.lamb import (default_trust_batch_axes,
-                                             default_weight_decay_mask,
-                                             lamb)
-
-    rng = np.random.RandomState(seed)
-    params = {
-        "layers": {"kernel": jnp.asarray(rng.randn(2, 33, 65), jnp.float32),
-                   "bias": jnp.asarray(rng.randn(2, 65), jnp.float32)},
-        "emb": jnp.asarray(rng.randn(100, 33), jnp.float32),
-        "ln": {"scale": jnp.asarray(rng.randn(33), jnp.float32)},
-    }
-    grads = jax.tree.map(
-        lambda p: jnp.asarray(rng.randn(*p.shape), jnp.float32), params)
-    sched = schedulers.poly_warmup_schedule(1e-3, total_steps=100,
-                                            warmup=0.1)
-
-    def run(**kw):
-        import optax
-        tx = lamb(sched, weight_decay=0.01,
-                  weight_decay_mask=default_weight_decay_mask,
-                  trust_batch_axes=default_trust_batch_axes, **kw)
-        st = tx.init(params)
-        p = params
-        upd_fn = jax.jit(tx.update)
-        for _ in range(3):
-            upd, st = upd_fn(grads, st, p)
-            p = optax.apply_updates(p, upd)
-        return p, st
-
-    return run
-
-
-def test_fused_lamb_xla_fallback_bit_identical():
-    run = _fused_fixture()
-    base_p, base_st = run()
-    fp, fst = run(fused=True, fused_impl="xla")
-    for what, a, b in (("params", base_p, fp), ("mu", base_st.mu, fst.mu),
-                       ("nu", base_st.nu, fst.nu)):
-        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-            np.testing.assert_array_equal(
-                np.asarray(x), np.asarray(y),
-                err_msg=f"fused[xla] {what} drifted from unfused")
-
-
-def test_fused_lamb_pallas_matches_within_ulps():
-    run = _fused_fixture()
-    base_p, base_st = run()
-    fp, fst = run(fused=True, fused_impl="pallas")
-    # moments come out of stage 1's elementwise EMA — no reassociation
-    # crosses them, so they stay exact even from the kernel
-    for what, a, b in (("mu", base_st.mu, fst.mu),
-                       ("nu", base_st.nu, fst.nu)):
-        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-            np.testing.assert_array_equal(
-                np.asarray(x), np.asarray(y),
-                err_msg=f"fused[pallas] {what} drifted from unfused")
-    for x, y in zip(jax.tree.leaves(base_p), jax.tree.leaves(fp)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   rtol=1e-6, atol=5e-7)
-
-
-def test_fused_stage_kernels_vs_xla():
-    from bert_pytorch_tpu.ops.pallas import fused_optim
-
-    rng = np.random.RandomState(7)
-    leaves = [jnp.asarray(rng.randn(3, 257), jnp.float32),
-              jnp.asarray(rng.randn(5,), jnp.float32),
-              jnp.asarray(rng.randn(64, 128), jnp.float32)]
-    mus = [jnp.abs(jnp.asarray(rng.randn(*x.shape), jnp.float32))
-           for x in leaves]
-    nus = [jnp.abs(jnp.asarray(rng.randn(*x.shape), jnp.float32))
-           for x in leaves]
-    pfs = [jnp.asarray(rng.randn(*x.shape), jnp.float32) for x in leaves]
-    wds = [0.01, 0.0, 0.01]
-    outs = {}
-    for impl in ("xla", "pallas"):
-        outs[impl] = fused_optim.lamb_stage1(
-            leaves, mus, nus, pfs, wds, denom=1.37, c1=0.9, c2=0.99,
-            b1=0.9, b2=0.999, eps=1e-6, impl=impl, bucket_bytes=64 << 10)
-    for ga, gb in zip(outs["xla"], outs["pallas"]):
-        for a, b in zip(ga, gb):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=5e-7)
-    # stage 2 is a pure elementwise axpy — bit-exact across impls
-    ts = [jnp.asarray(rng.randn(*x.shape), jnp.float32) for x in leaves]
-    us = outs["xla"][2]
-    o_xla = fused_optim.lamb_stage2(ts, us, impl="xla",
-                                    bucket_bytes=64 << 10)
-    o_pls = fused_optim.lamb_stage2(ts, us, impl="pallas",
-                                    bucket_bytes=64 << 10)
-    for a, b in zip(o_xla, o_pls):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-

@@ -38,8 +38,7 @@ def _recomputed(**config):
 @pytest.mark.parametrize("policy, run_twice", [
     (None, ("attention/output", "mlp/intermediate")),
     ("dense", ("attention/output", "mlp/intermediate")),
-    ("nothing", PROJECTIONS),
-    ("dots", ())])
+    ("nothing", PROJECTIONS)])
 def test_which_operations_run_twice(policy, run_twice):
     """Under the default the qkv and mlp_output matmuls are not recomputed
     while the other two projections, the attention core and the
@@ -51,18 +50,42 @@ def test_which_operations_run_twice(policy, run_twice):
     assert again == run_twice
     assert any("attention_layer_norm" in op for op in ops)
     assert any("output_layer_norm" in op for op in ops)
-    if policy != "dots":
-        # QK^T and PV: dot_generals of `attention` that are no projection's
-        assert any("/attention/attention/" in op
-                   and op.endswith("dot_general")
-                   and not op.endswith(("qkv/dot_general",
-                                        "output/dot_general"))
-                   for op in ops), sorted(ops)[:40]
+    # QK^T and PV: dot_generals of `attention` that are no projection's
+    assert any("/attention/attention/" in op
+               and op.endswith("dot_general")
+               and not op.endswith(("qkv/dot_general",
+                                    "output/dot_general"))
+               for op in ops), sorted(ops)[:40]
+
+
+def test_a_policy_that_went_is_refused_at_load_with_the_three_that_exist(
+        tmp_path):
+    """"dots" lost PR 25's race and "mlp_only" never dropped a wide value
+    (PR 29): both went with PR 46. A config that names one fails where it is
+    loaded, not where the model is built, and the message lists what the
+    three model modules hold."""
+    from bert_pytorch_tpu.config import (REMAT_POLICIES, Lfm2MoeConfig,
+                                         load_model_config)
+    from bert_pytorch_tpu.models import decoder, keye
+    from bert_pytorch_tpu.models.bert import _REMAT_POLICIES
+
+    for held in (_REMAT_POLICIES, decoder.LM_REMAT_POLICIES,
+                 keye.REMAT_POLICIES):
+        assert set(held) == set(REMAT_POLICIES) == {"nothing", "dense",
+                                                    "auto"}
+    path = tmp_path / "cfg.json"
+    for policy in ("dots", "mlp_only"):
+        path.write_text(json.dumps({"vocab_size": 128,
+                                    "remat_policy": policy}))
+        with pytest.raises(ValueError, match=r"nothing.*dense.*auto"):
+            load_model_config(str(path))
+        with pytest.raises(ValueError, match=policy):
+            Lfm2MoeConfig(remat_policy=policy)
 
 
 def test_policy_is_not_read_without_the_flag():
     texts = set()
-    for policy in ("auto", "nothing", "dots"):
+    for policy in ("auto", "nothing", "dense"):
         step, state, batch = _toy_step(False, checkpoint_activations=False,
                                        remat_policy=policy)
         texts.add(jax.jit(step, donate_argnums=(0,)).lower(
@@ -72,17 +95,14 @@ def test_policy_is_not_read_without_the_flag():
 
 
 @pytest.mark.parametrize("policy, stacked", [
-    (None, 2), ("dense", 0), ("nothing", 0), ("mlp_only", 2)])
+    (None, 2), ("dense", 0), ("nothing", 0)])
 def test_wide_values_the_forward_scan_stacks(policy, stacked):
     """What the forward layer scan writes into (L, B, S, F) stacks for the
     backward scan, counted in the jaxpr of value_and_grad. Without remat:
     the activation's output (the mlp_output matmul's residual) and the
     erf-GELU's derivative (ops/activations.py), where autodiff of
     jax.nn.gelu stacked three of its own beside the output. "dense" and
-    "nothing" stack nothing that wide. "mlp_only" stacks the same two as no
-    remat (four before the GELU kept one): JAX's
-    save_anything_except_these_names refuses the NAMED copy of a value and
-    saves the un-named one that feeds the name, so it never dropped them."""
+    "nothing" stack nothing that wide."""
     L, B, S, H, F = 3, 2, 16, 32, 80
     cfg = BertConfig(
         vocab_size=128, hidden_size=H, num_hidden_layers=L,

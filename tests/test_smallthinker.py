@@ -28,7 +28,7 @@ from benchmark.families import smallthinker as bench_family  # noqa: E402
 from benchmark.reference import smallthinker_ref as ref  # noqa: E402
 from bert_pytorch_tpu.config import (SmallThinkerConfig,  # noqa: E402
                                      load_model_config)
-from bert_pytorch_tpu.models import lfm2_moe, smallthinker  # noqa: E402
+from bert_pytorch_tpu.models import decoder, smallthinker  # noqa: E402
 from bert_pytorch_tpu.ops import moe as moe_ops  # noqa: E402
 from bert_pytorch_tpu.ops.attention import dot_product_attention  # noqa: E402
 
@@ -89,7 +89,7 @@ def test_parameter_tree_is_the_references_and_no_gain_decays(toy):
     from bert_pytorch_tpu.optim.lamb import default_weight_decay_mask
 
     cfg, sizes, params, model, batch = toy
-    init = model.init(jax.random.PRNGKey(0), *lfm2_moe.init_inputs(batch))
+    init = model.init(jax.random.PRNGKey(0), *decoder.init_inputs(batch))
     assert (jax.tree.map(jnp.shape, init["params"])
             == jax.tree.map(jnp.shape, params))
     mask = jax.tree_util.tree_flatten_with_path(
@@ -104,7 +104,7 @@ def test_logits_match_the_reference(toy):
     cfg, sizes, params, model, batch = toy
     with jax.default_matmul_precision("highest"):
         hidden, head, load, dropped = model.apply(
-            {"params": params}, *lfm2_moe.init_inputs(batch))
+            {"params": params}, *decoder.init_inputs(batch))
         logits = hidden @ head.T
         for r in range(2):
             want, counts, _ = ref.row_forward(

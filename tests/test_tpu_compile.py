@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import pytest
 
 from bert_pytorch_tpu.analysis import hlo
-from bert_pytorch_tpu.ops.pallas import fused_optim
 from bert_pytorch_tpu.ops.pallas.layernorm import (
     add_dropout_layer_norm_pallas, layer_norm_pallas)
 
@@ -423,25 +422,3 @@ def test_layernorm_kernels_compile(v5e, shape, fused_residual):
     name = "add_dropout_layernorm" if fused_residual else "layernorm"
     assert _kernels(f, *args) == {f"{name}_fwd": 1}
     assert _kernels(g, *args) == {f"{name}_fwd": 1, f"{name}_bwd": 1}
-
-
-def test_fused_optimizer_stages_compile(v5e, monkeypatch):
-    """Both multi-tensor LAMB stages over one bucket of the default size
-    (parallel/coalesce.DEFAULT_BUCKET_BYTES)."""
-    # the stage dispatchers pick interpret mode from the live backend,
-    # which is the CPU here: steer them onto the kernel path in the test
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n = fused_optim.DEFAULT_BUCKET_BYTES // 4
-    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=v5e)
-    scal = jax.ShapeDtypeStruct((1, 3), jnp.float32, sharding=v5e)
-
-    def stage1(scal, g, mu, nu, pf, wd):
-        return fused_optim._stage1_flat(scal, g, mu, nu, pf, wd, b1=0.9,
-                                        b2=0.999, eps=1e-6, use_pallas=True)
-
-    def stage2(t, u):
-        return fused_optim._stage2_flat(t, u, use_pallas=True)
-
-    assert _kernels(stage1, scal, vec, vec, vec, vec, vec) == {
-        "lamb_stage1": 1}
-    assert _kernels(stage2, vec, vec) == {"lamb_stage2": 1}

@@ -21,7 +21,6 @@ f32 at highest matmul precision:
   at a time over the keys its band reaches (`--band_seq`; 0 leaves it out);
 - LayerNorm and fused residual+dropout+LayerNorm, forward + backward
   (the XLA fallbacks in ops/layernorm.py share the kernels' dropout hash);
-- both fused-LAMB stages against their one-definition math;
 - the gated delta-rule recurrence's kernels (ops/pallas/kda.py: `kda_fwd`,
   `kda_bwd`) through `ops/kda.kda_scan` at the published widths (heads of
   128, chunks of 64, 32 a block), forward and its five gradients, over a row
@@ -345,32 +344,6 @@ def check_kda(interpret: bool, report) -> None:
         report(f"kda d{which}", _rel_err(a, w), BWD_TOL)
 
 
-def check_fused_lamb(report) -> None:
-    """Stage kernels vs the XLA evaluation of the same math. f32 both
-    sides: the block flattening reassociates at most an FMA."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from bert_pytorch_tpu.ops.pallas import fused_optim
-
-    rng = np.random.RandomState(2)
-    n = 3 * fused_optim.ROWS * fused_optim.LANES + 77   # ragged tail
-    g, mu, pf = (jnp.asarray(rng.randn(n), jnp.float32) for _ in range(3))
-    nu = jnp.asarray(rng.rand(n), jnp.float32)
-    wd = jnp.full((n,), 0.01, jnp.float32)
-    scal = jnp.asarray([[1.3, 0.1, 0.001]], jnp.float32)
-    kw = dict(b1=0.9, b2=0.999, eps=1e-6)
-    got = fused_optim._stage1_flat(scal, g, mu, nu, pf, wd, use_pallas=True,
-                                   **kw)
-    want = fused_optim._stage1_flat(scal, g, mu, nu, pf, wd,
-                                    use_pallas=False, **kw)
-    for name, a, b in zip(("mu", "nu", "u"), got, want):
-        report(f"lamb_stage1 {name}", _rel_err(a, b), 1e-5)
-    report("lamb_stage2",
-           _rel_err(fused_optim._stage2_flat(g, pf, use_pallas=True),
-                    fused_optim._stage2_flat(g, pf, use_pallas=False)), 1e-6)
-
-
 def main(argv=None) -> int:
     global B, S, H
     ap = argparse.ArgumentParser(description=__doc__)
@@ -411,7 +384,6 @@ def main(argv=None) -> int:
     if args.band_seq:
         check_flash_band(fa, interpret, report, args.band_seq)
     check_layernorm(interpret, report)
-    check_fused_lamb(report)
     check_kda(interpret, report)
     if failures:
         print(f"kernel_parity: {len(failures)} check(s) FAILED: "

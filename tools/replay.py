@@ -182,6 +182,14 @@ def main(argv=None) -> dict:
 
     manifest = _load_manifest(bundle)
     run = manifest["run"]
+    # a run-block key of older bundles: the multi-tensor LAMB update that
+    # --fused_optim selected went with its flag
+    if run.get("fused_optim", "off") != "off":
+        raise ReplayError(
+            f"this bundle was recorded with --fused_optim "
+            f"{run['fused_optim']}, a path that differed from the one "
+            "LAMB update this program has by ulps, so a bit-exact replay "
+            "is not possible")
     npz = np.load(os.path.join(bundle, "batches.npz"))
 
     stream = manifest.get("stream")
@@ -239,11 +247,7 @@ def main(argv=None) -> dict:
         run["lr_decay"], run["learning_rate"], run["max_steps"],
         warmup=run["warmup_proportion"],
         offset=run["previous_phase_end_step"])
-    # round-16 run-block key (absent in older bundles -> "off"): the
-    # fused multi-tensor update must rebuild, or the replayed program's
-    # fingerprint would diverge from the recorded run
-    tx = make_optimizer(run["optimizer"], schedule,
-                        fused=run.get("fused_optim", "off"))
+    tx = make_optimizer(run["optimizer"], schedule)
 
     # same mesh as the run when this machine can host it; otherwise pure-DP
     # over whatever devices exist (cross-shape replay stays deterministic,
@@ -354,8 +358,7 @@ def main(argv=None) -> dict:
 
             norm_reducer = NormReducer(plan.grad_shardings, mesh)
             tx = make_optimizer(run["optimizer"], schedule,
-                                norm_reducer=norm_reducer,
-                                fused=run.get("fused_optim", "off"))
+                                norm_reducer=norm_reducer)
 
         if run.get("kfac"):
             from bert_pytorch_tpu.optim.kfac import KFAC, KFACConfig
